@@ -1,0 +1,506 @@
+"""chip_smoke.py — the quickest proof that the serving path starts on the chip.
+
+    python chip_smoke.py
+
+Drives HTTP -> proxy -> router -> replica -> ContinuousEngine once, through
+the entry points a user calls, at the full width of the widest model this
+repository has run (1024d x 8L, bf16, random weights from a seed), with one
+one-chip replica per chip of the host. Then, in an actor that holds a chip,
+it checks what was served against a cache-free forward pass of the same
+model, compiles both Pallas kernels with Mosaic and compares them with their
+XLA references.
+
+This parent process never imports JAX: a chip belongs to one process, and a
+worker sees one only by holding the `TPU` resource. It exits non-zero, and
+prints no `"ok": true` line, when the host has no TPU or any phase fails; it
+never serves from the CPU. On success the last line of its output is
+
+    {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}}
+
+with the device as JAX reports it inside the replicas. Set-up facts and
+correctness only: it measures no speed.
+"""
+
+from __future__ import annotations
+
+import concurrent.futures
+import json
+import os
+import random
+import socket
+import sys
+import time
+import urllib.request
+
+T_START = time.monotonic()
+#: The whole run, compilation included, has to end well inside 1200 s.
+DEADLINE = T_START + 1080.0
+
+MODEL = dict(vocab_size=32000, d_model=1024, n_layers=8, n_heads=16,
+             max_seq=1024, dtype="bfloat16")
+MAX_BATCH = 8
+DECODE_CHUNK = 16
+#: Programs of the serving path, as their compile-cache entries are named.
+SERVING_PROGRAMS = ("jit_prefill", "jit_chunk", "jit_place", "jit_sample1")
+#: A served greedy token may differ from the argmax of the cache-free
+#: reference only where the two logits are this close: the engine reads a
+#: bf16 cache through other programs than the reference runs, and with
+#: random weights the best two of 32000 logits are often one bf16 step
+#: apart. On the v5e the worst gap seen is 0.0143 (one bf16 step at a logit
+#: of 2 to 4 is 0.0156); a token from a wrong cache row or position is off
+#: by about 3.
+REFERENCE_LOGIT_TOL = 0.1
+
+
+def say(msg: str) -> None:
+    print(f"[{time.monotonic() - T_START:7.1f}s] {msg}", flush=True)
+
+
+def left(budget: float) -> float:
+    """A phase's time limit: its own budget, cut to what the run has left."""
+    return max(5.0, min(budget, DEADLINE - time.monotonic()))
+
+
+class PhaseFailed(Exception):
+    pass
+
+
+def require(cond: bool, msg: str) -> None:
+    if not cond:
+        raise PhaseFailed(msg)
+
+
+# ------------------------------------------------------------------ requests
+def _prompts() -> tuple[list[int], list[int]]:
+    """A 100-token and a 200-token prompt: prefill buckets 128 and 256."""
+    rng = random.Random(0)
+    vocab = MODEL["vocab_size"]
+    return ([rng.randrange(vocab) for _ in range(100)],
+            [rng.randrange(vocab) for _ in range(200)])
+
+
+def _request(prompt, max_tokens, temperature, stream, seed=0) -> dict:
+    return {"prompt": prompt, "max_tokens": max_tokens,
+            "temperature": temperature, "top_k": 50 if temperature else 0,
+            "seed": seed, "stream": stream}
+
+
+def warmup_requests() -> list[dict]:
+    """Sent one at a time. A request alone in the batch decodes in a fixed
+    sequence of chunks — 31 tokens as 16+8+4+2, 33 as 16+16+1 — so these
+    four compile both prefill buckets and every decode-chunk program there
+    is (sizes 1..16, greedy and sampled), whatever the timing."""
+    short, long_ = _prompts()
+    return [_request(short, 31, 0.0, False), _request(long_, 33, 0.0, True),
+            _request(long_, 31, 0.8, False), _request(short, 33, 0.8, True)]
+
+
+def wave_requests(n_replicas: int) -> list[dict]:
+    """Sent all at once, ten per replica for MAX_BATCH slots each, so that
+    some wait for a slot and admission, splice and retire all run: both
+    buckets, greedy and sampled, streamed and not, lengths that differ so
+    that requests retire at different steps."""
+    short, long_ = _prompts()
+    shapes = [  # (prompt, max_tokens, temperature, stream)
+        (short, 33, 0.0, False), (short, 33, 0.0, True),
+        (long_, 17, 0.0, False), (long_, 17, 0.0, True),
+        (short, 24, 0.8, False), (long_, 40, 0.8, True),
+        (short, 9, 0.0, True), (long_, 33, 1.0, False),
+        (short, 48, 0.0, False), (long_, 5, 0.7, True),
+    ]
+    return [_request(*shape, seed=7 + i + 100 * r)
+            for r in range(n_replicas) for i, shape in enumerate(shapes)]
+
+
+def complete(base: str, body: dict, timeout: float) -> dict:
+    """POST one completion; returns {"tokens", "finish"} for the streamed
+    (SSE) and the plain form alike."""
+    req = urllib.request.Request(
+        f"{base}/v1/completions", data=json.dumps(body).encode(),
+        headers={"Content-Type": "application/json"})
+    with urllib.request.urlopen(req, timeout=timeout) as resp:
+        if not body["stream"]:
+            doc = json.loads(resp.read())
+            return {"tokens": doc["token_ids"],
+                    "finish": doc["choices"][0]["finish_reason"]}
+        tokens, finish = [], None
+        for raw in resp:
+            line = raw.decode().strip()
+            if not line.startswith("data: "):
+                continue
+            if line[6:] == "[DONE]":
+                break
+            doc = json.loads(line[6:])
+            tokens += doc.get("token_ids", [])
+            finish = doc["choices"][0]["finish_reason"] or finish
+        return {"tokens": tokens, "finish": finish}
+
+
+def check_outputs(reqs: list[dict], outs: list[dict]) -> None:
+    vocab = MODEL["vocab_size"]
+    for r, o in zip(reqs, outs):
+        what = (f"{'sse' if r['stream'] else 'plain'} t={r['temperature']} "
+                f"plen={len(r['prompt'])}")
+        require(len(o["tokens"]) == r["max_tokens"],
+                f"{what}: {len(o['tokens'])} tokens, "
+                f"expected {r['max_tokens']}")
+        require(o["finish"] == "length",
+                f"{what}: finish_reason {o['finish']!r}, expected 'length'")
+        require(all(isinstance(t, int) and 0 <= t < vocab
+                    for t in o["tokens"]),
+                f"{what}: token ids outside [0, {vocab})")
+
+
+def replica_stats(base: str, n_replicas: int, timeout: float) -> list[dict]:
+    """/v1/stats answers from one replica per call; ask until every replica
+    (one pid each) has answered."""
+    seen: dict[int, dict] = {}
+    t_end = time.monotonic() + timeout
+    while len(seen) < n_replicas and time.monotonic() < t_end:
+        with urllib.request.urlopen(f"{base}/v1/stats", timeout=30) as r:
+            st = json.loads(r.read())
+        seen[st["pid"]] = st
+    require(len(seen) == n_replicas,
+            f"only {len(seen)} of {n_replicas} replicas answered /v1/stats")
+    return list(seen.values())
+
+
+# ------------------------------------------------- checks that need the chip
+class ChipProbe:
+    """Runs in a worker that holds one chip, after the replicas are gone."""
+
+    def reference(self, model: dict, cases: list) -> list[dict]:
+        """For each served greedy (prompt, tokens): how far below the best
+        logit of a cache-free forward pass of the same model (seeded weights,
+        no KV cache, the dispatcher's attention — the flash kernel here)
+        each served token's logit lies. One row per case."""
+        import jax
+        import jax.numpy as jnp
+        import numpy as np
+
+        from ray_tpu.llm import LLMConfig
+        from ray_tpu.llm.engine import model_config
+        from ray_tpu.models.transformer import Transformer
+
+        cfg = LLMConfig(**model)
+        net = Transformer(model_config(cfg))
+        params = net.init(jax.random.PRNGKey(cfg.seed),
+                          jnp.zeros((1, 8), jnp.int32))["params"]
+        params = jax.tree.map(  # as the engine holds them
+            lambda x: x.astype(jnp.bfloat16)
+            if x.dtype == jnp.float32 else x, params)
+        width = max(len(p) + len(t) for p, t in cases)
+        width = -(-width // 128) * 128  # a length the flash kernel tiles
+        forward = jax.jit(lambda p, t: net.apply({"params": p}, t)[0])
+        rows = []
+        for prompt, tokens in cases:
+            toks = np.zeros((1, width), np.int32)
+            toks[0, :len(prompt) + len(tokens)] = prompt + tokens
+            at = np.arange(len(tokens)) + len(prompt) - 1  # predicts token j
+            logits = np.asarray(forward(params, toks))[at]
+            gaps = logits.max(axis=-1) - logits[np.arange(len(at)), tokens]
+            rows.append({"plen": len(prompt), "n": len(tokens),
+                         "finite": bool(np.isfinite(logits).all()),
+                         "max_gap": float(gaps.max()),
+                         "argmax_matches": int((gaps == 0).sum())})
+        return rows
+
+    def kernels(self, model: dict, max_batch: int) -> dict:
+        """Compiles both Pallas kernels with Mosaic (interpret=False) and
+        compares each with its XLA reference. An exception from a kernel is
+        reported in its row, and the smoke fails on it."""
+        import time
+        import traceback
+
+        import jax
+        import jax.numpy as jnp
+        import numpy as np
+
+        from ray_tpu._private.rtconfig import CONFIG
+        from ray_tpu.ops.attention import _xla_attention
+        from ray_tpu.ops.decode_attention import (
+            _xla_decode_attention, choose_impl, decode_attention_pallas)
+        from ray_tpu.ops.flash_attention import (
+            derive_blocks, flash_attention, unsupported_reason)
+
+        dev = jax.devices()[0]
+        heads = model["n_heads"]
+        hd = model["d_model"] // heads
+        rows = []
+
+        def compare(name, kernel, ref):
+            row = {"kernel": name}
+            try:
+                t0 = time.monotonic()
+                got = np.asarray(jax.block_until_ready(kernel()), np.float32)
+                row["compile_and_run_s"] = round(time.monotonic() - t0, 2)
+                want = np.asarray(ref(), np.float32)
+                err = np.abs(got - want)
+                # bf16 keeps 8 bits: an output near 4 is rounded by ~0.016.
+                tol = 2e-2 + 2e-2 * np.abs(want)
+                row.update(max_abs_err=float(err.max()),
+                           ok=bool(np.isfinite(got).all()
+                                   and (err <= tol).all()))
+            except Exception:  # noqa: BLE001 - reported row by row
+                row.update(ok=False, error=traceback.format_exc(limit=6))
+            rows.append(row)
+
+        def qkv(b, s, h, d, seed):
+            ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+            return [jax.random.normal(k, (b, s, h, d), jnp.bfloat16)
+                    for k in ks]
+
+        # Prefill shapes of this model, then the bench shape.
+        for b, s, h, d in [(1, 128, heads, hd), (1, 256, heads, hd),
+                           (1, 1024, heads, hd), (4, 2048, 8, 128)]:
+            q, k, v = qkv(b, s, h, d, s)
+            compare(f"flash_attention b{b} s{s} h{h} d{d} "
+                    f"blocks{derive_blocks(s, s)}",
+                    lambda: flash_attention(q, k, v, causal=True,
+                                            interpret=False),
+                    lambda: _xla_attention(q, k, v, causal=True))
+
+        # Decode shapes: this model's cache, then the bench shape.
+        for b, s, h, d in [(max_batch, model["max_seq"], heads, hd),
+                           (4, 2048, 8, 128)]:
+            q, k, v = qkv(b, s, h, d, s + 1)
+            q1 = q[:, 0]
+            lens = jnp.asarray(
+                np.linspace(1, s, b).astype(np.int32))  # 1 .. S, ragged
+            compare(f"decode_attention_pallas b{b} s{s} h{h} d{d}",
+                    lambda: decode_attention_pallas(
+                        q1, k, v, lens, interpret=False),
+                    lambda: _xla_decode_attention(q1, k, v, lens))
+
+        served = choose_impl(
+            (max_batch, heads, hd), (max_batch, model["max_seq"], heads, hd),
+            2, backend=jax.default_backend(),
+            force=str(CONFIG.decode_kernel).lower())
+        return {"platform": dev.platform, "device_kind": dev.device_kind,
+                "rows": rows,
+                "served_decode_impl": list(served),
+                "prefill_flash_unsupported_reason": unsupported_reason(
+                    (1, 128, heads, hd), (1, 128, heads, hd))}
+
+
+# -------------------------------------------------------------------- phases
+def preflight() -> int:
+    """Chips of this host, found without JAX. Exits when there is none."""
+    platforms = os.environ.get("JAX_PLATFORMS", "").strip().lower()
+    if platforms and "tpu" not in platforms.split(","):
+        sys.exit(f"chip_smoke: no TPU: JAX_PLATFORMS={platforms} holds this "
+                 f"run to another platform; nothing was served")
+    from ray_tpu._private.accelerators import num_tpu_chips
+
+    chips = num_tpu_chips()
+    if chips < 1:
+        sys.exit("chip_smoke: no TPU found: this host has no /dev/accel* or "
+                 "/dev/vfio/* device files; nothing was served")
+    return chips
+
+
+def serve_phase(serve, LLMConfig, build_openai_app, chips: int) -> dict:
+    """Deploy, warm up, answer a wave of requests. Returns the replicas'
+    last /v1/stats and the served greedy (prompt, tokens) pairs."""
+    with socket.socket() as s:
+        s.bind(("127.0.0.1", 0))
+        port = s.getsockname()[1]
+    base = f"http://127.0.0.1:{port}"
+    wave = wave_requests(chips)
+    app = build_openai_app(
+        LLMConfig(**MODEL), num_replicas=chips, max_batch=MAX_BATCH,
+        decode_chunk=DECODE_CHUNK, ray_actor_options={"num_tpus": 1},
+        max_ongoing_requests=len(wave))
+    t0 = time.monotonic()
+    serve.run(app, port=port, timeout_s=left(300))
+    t_ready = time.monotonic() - t0
+    stats = replica_stats(base, chips, left(60))
+    for st in stats:
+        say(f"replica pid={st['pid']} platform={st['platform']} "
+            f"kind={st['device_kind']!r} device_ids={st['device_ids']} "
+            f"holds open {st['chip_files_open']} "
+            f"(booked TPU_VISIBLE_CHIPS={st['tpu_visible_chips']}) "
+            f"runtime_init={st['runtime_init_s']}s "
+            f"engine_init={st['engine_init_s']}s")
+        require(st["platform"] == "tpu",
+                f"replica {st['pid']} runs on {st['platform']!r}, not a TPU")
+        require(bool(st["device_kind"]), "replica reports no device_kind")
+        # Every narrowed process calls its one device id 0, so which chip a
+        # replica runs on is read from the device files its process holds
+        # open (/proc/self/fd), not from what the node agent told it.
+        require(len(st["device_ids"]) == 1 and len(st["chip_files_open"]) == 1,
+                f"one-chip replica {st['pid']} sees devices "
+                f"{st['device_ids']} and holds open {st['chip_files_open']}")
+    held = [tuple(st["chip_files_open"]) for st in stats]
+    require(len(set(held)) == chips,
+            f"replicas do not hold distinct chips open: {held}")
+    booked = [st["tpu_visible_chips"] for st in stats]
+    require(len(set(booked)) == chips and None not in booked,
+            f"the node agent did not book distinct chips: {booked}")
+    say(f"set-up: deployment ready in {t_ready:.1f}s (process start, TPU "
+        f"runtime init, parameters)")
+
+    warm = warmup_requests()
+    t0 = time.monotonic()
+    warm_outs = [complete(base, r, left(300)) for r in warm]
+    again = complete(base, warm[0], left(120))
+    t_warm = time.monotonic() - t0
+    check_outputs(warm, warm_outs)
+    require(again["tokens"] == warm_outs[0]["tokens"],
+            "two greedy runs of one prompt, alone in the batch, disagree")
+    mid = replica_stats(base, chips, left(60))
+    say(f"set-up: {len(warm) + 1} warm-up requests, one at a time, in "
+        f"{t_warm:.1f}s ({sum(s['compile_count'] for s in mid)} programs "
+        f"built, {sum(s['compile_s'] for s in mid):.1f}s in the compiler "
+        f"or its cache); two greedy runs of one prompt agree")
+
+    t0 = time.monotonic()
+    with concurrent.futures.ThreadPoolExecutor(len(wave)) as pool:
+        futs = [pool.submit(complete, base, r, left(300)) for r in wave]
+        outs = [f.result(timeout=left(310)) for f in futs]
+    t_wave = time.monotonic() - t0
+    check_outputs(wave, outs)
+    end = replica_stats(base, chips, left(60))
+    for st in end:
+        require(st["served"] >= 1, f"replica {st['pid']} served no request")
+        require(st["active"] == 0,
+                f"replica {st['pid']} still holds {st['active']} slots")
+    built = (sum(s["compile_count"] for s in end)
+             - sum(s["compile_count"] for s in mid))
+    say(f"requests: {len(wave)} at once answered in {t_wave:.1f}s; token "
+        f"counts and finish reasons hold; {built} programs built inside "
+        f"it; served per replica: {sorted(s['served'] for s in end)}")
+    greedy = {(tuple(r["prompt"]), tuple(o["tokens"]))
+              for r, o in zip(warm + wave, warm_outs + outs)
+              if r["temperature"] == 0.0}
+    return {"stats": end,
+            "greedy": [(list(p), list(t)) for p, t in sorted(greedy)]}
+
+
+def chip_phase(ray_tpu, greedy: list) -> None:
+    probe = ray_tpu.remote(num_cpus=0, num_tpus=1)(ChipProbe).remote()
+    try:
+        ref = (ray_tpu.get(probe.reference.remote(MODEL, greedy),
+                           timeout=left(300)) if greedy else [])
+        rep = ray_tpu.get(probe.kernels.remote(MODEL, MAX_BATCH),
+                          timeout=left(300))
+    finally:
+        ray_tpu.kill(probe)
+
+    say(f"chip actor on {rep['platform']} {rep['device_kind']!r}")
+    for row in ref:
+        say(f"  served greedy plen={row['plen']} n={row['n']}: "
+            f"{row['argmax_matches']}/{row['n']} tokens are the reference "
+            f"argmax, worst logit gap {row['max_gap']:.4f}")
+    for row in rep["rows"]:
+        if row["ok"]:
+            say(f"  ok   {row['kernel']}: max|err|={row['max_abs_err']:.4f} "
+                f"({row['compile_and_run_s']}s)")
+        else:
+            say(f"  FAIL {row['kernel']}: {row.get('error') or row}")
+    impl, why = rep["served_decode_impl"]
+    say(f"served decode shape: dispatcher chooses {impl} ({why})")
+    say("served prefill: the cached dense einsum of "
+        "Attention._cached_attention (decode=True); "
+        "dot_product_attention is not on the serving path. At the same "
+        "shape without a cache it chooses "
+        + ("the Pallas flash kernel"
+           if rep["prefill_flash_unsupported_reason"] is None
+           else f"XLA ({rep['prefill_flash_unsupported_reason']})"))
+    require(rep["platform"] == "tpu", "chip actor did not run on a TPU")
+    require(bool(ref), "no served greedy continuation to check")
+    off = [r for r in ref
+           if not r["finite"] or r["max_gap"] > REFERENCE_LOGIT_TOL]
+    require(not off, f"{len(off)} served continuations disagree with the "
+                     f"cache-free reference: {off}")
+    bad = [r["kernel"] for r in rep["rows"] if not r["ok"]]
+    require(not bad, f"{len(bad)} kernel checks failed: {bad}")
+
+
+def serving_entries(cache_dir: str) -> set[str]:
+    try:
+        return {n for n in os.listdir(cache_dir)
+                if n.startswith(SERVING_PROGRAMS)}
+    except FileNotFoundError:
+        return set()
+
+
+def main() -> int:
+    chips = preflight()
+
+    import ray_tpu
+    from ray_tpu import _native, serve
+    from ray_tpu._private import compile_cache
+    from ray_tpu.llm import LLMConfig
+    from ray_tpu.llm.openai import build_openai_app
+
+    # Where the workers will keep what they compile (this process compiles
+    # nothing, so its own environment is left alone).
+    cache_dir = compile_cache.apply(dict(os.environ))
+    entries_before = compile_cache.entries(cache_dir)
+    serving_before = serving_entries(cache_dir)
+    say(f"host chips: {chips}; compile cache: {cache_dir} "
+        f"({entries_before} entries, {len(serving_before)} of the serving "
+        f"programs)")
+
+    failed: list[str] = []
+
+    def phase(name: str, fn):
+        say(f"--- {name}")
+        try:
+            return fn()
+        except Exception as e:  # noqa: BLE001 - every phase is reported
+            failed.append(name)
+            say(f"FAILED {name}: {type(e).__name__}: {e}")
+            return None
+
+    native = _native.get_lib() is not None
+    say(f"_native.get_lib() loaded: {native}")
+    if not native:
+        failed.append("native")
+
+    device = None
+    t0 = time.monotonic()
+    ray_tpu.init()
+    try:
+        have = ray_tpu.cluster_resources().get("TPU", 0)
+        say(f"ray_tpu.init(): {time.monotonic() - t0:.1f}s, "
+            f"cluster TPU={have:g}")
+        if have != chips:
+            failed.append("init")
+            say(f"FAILED init: cluster advertises TPU={have}, "
+                f"host has {chips}")
+        served = phase("serve", lambda: serve_phase(
+            serve, LLMConfig, build_openai_app, chips))
+        phase("serve shutdown", serve.shutdown)
+        dead = [n["NodeID"][:8] for n in ray_tpu.nodes() if not n["Alive"]]
+        if dead:
+            failed.append("nodes")
+            say(f"FAILED nodes: declared dead during the run: {dead}")
+        if served:
+            stats = served["stats"]
+            device = {"platform": stats[0]["platform"],
+                      "kind": stats[0]["device_kind"],
+                      "count": sum(len(s["device_ids"]) for s in stats)}
+        if not dead:
+            phase("reference and kernels", lambda: chip_phase(
+                ray_tpu, served["greedy"] if served else []))
+    finally:
+        ray_tpu.shutdown()
+
+    entries_after = compile_cache.entries(cache_dir)
+    new_serving = sorted(serving_entries(cache_dir) - serving_before)
+    say(f"compile cache: {entries_before} -> {entries_after} entries; "
+        f"{len(new_serving)} new for the serving programs")
+    say(f"total: {time.monotonic() - T_START:.1f}s")
+    if "jax" in sys.modules:
+        failed.append("parent imported jax")
+    if failed or device is None:
+        print(json.dumps({"ok": False, "failed": failed}), flush=True)
+        return 1
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -3825,9 +3825,30 @@ class Controller:
     async def _health_loop(self):
         interval = CONFIG.heartbeat_interval_s
         timeout = interval * CONFIG.num_heartbeats_timeout
+        due = time.monotonic() + interval
         while True:
             await asyncio.sleep(interval)
             now = time.monotonic()
+            # A controller that was not running cannot judge who else was
+            # not. If this tick is late — the event loop was blocked, or
+            # the whole machine was paused — heartbeats that arrived
+            # meanwhile are still unread behind it, and the head node's
+            # agent, which shares this loop, was stopped for just as long:
+            # credit every node with the time this loop lost. (On a
+            # four-chip v5e host every process, this one included, stands
+            # still for 9 to 10 s while four workers start the TPU runtime
+            # at once; the healthy head node was declared dead with every
+            # replica on it.)
+            lost = now - due
+            due = now + interval
+            if lost > interval:
+                if lost > 2.0:
+                    logger.warning(
+                        "controller event loop lost %.1fs; node heartbeat "
+                        "deadlines extended by as much", lost)
+                for node in self.nodes.values():
+                    if node.last_beat:
+                        node.last_beat += lost
             for nid, node in list(self.nodes.items()):
                 if node.alive and node.last_beat and now - node.last_beat > timeout:
                     await self._node_died(nid)

@@ -90,6 +90,10 @@ def normalize_resources(
     r["CPU"] = float(num_cpus) if num_cpus is not None else default_cpus
     if num_tpus is not None:
         r["TPU"] = float(num_tpus)
+    if float(r.get("TPU", 0)) % 1:
+        raise ValueError(
+            f"TPU: {r['TPU']} is not a whole number of chips; a chip belongs "
+            f"to one process, so a fraction of one cannot be granted")
     if memory is not None:
         r["memory"] = float(memory)
     return ResourceSet({k: v for k, v in r.items() if v})

@@ -169,7 +169,7 @@ def disk_percent(path: str) -> float:
 # backend compiles and their cumulative seconds from the moment the worker
 # sampler first observes jax imported. Registration is idempotent and
 # NEVER imports jax itself (sys.modules gate — pool workers that stay
-# jax-free must not pay the ~2s plugin import for a gauge).
+# jax-free must not pay the jax import for a gauge).
 _compile_lock = threading.Lock()
 _compile_stats = {"count": 0, "seconds": 0.0}
 _compile_listener_installed = False
@@ -261,7 +261,7 @@ class WorkerSampler:
         # compute on them, and blocks the tick for seconds.
         xb = sys.modules.get("jax._src.xla_bridge")
         if jax is not None and xb is not None \
-                and getattr(xb, "_backends", None):
+                and xb.backends_are_initialized():
             used = peak = 0
             have = False
             try:

@@ -48,6 +48,26 @@ def _sampling_from_body(body: dict, default_max: int) -> SamplingParams:
     )
 
 
+def _device_report() -> dict:
+    """This process's devices as JAX reports them, the chip device files it
+    really holds open, the chips the node agent booked to it
+    (TPU_VISIBLE_CHIPS; None when it was granted none), and what it has
+    compiled since the server was built."""
+    import jax
+
+    from ray_tpu._private import accelerators, telemetry
+
+    devs = jax.local_devices()
+    compiled = telemetry.compile_stats()
+    return {"platform": devs[0].platform,
+            "device_kind": devs[0].device_kind,
+            "device_ids": [d.id for d in devs],
+            "chip_files_open": accelerators.open_chip_files(),
+            "tpu_visible_chips": os.environ.get("TPU_VISIBLE_CHIPS"),
+            "compile_count": compiled["count"],
+            "compile_s": round(compiled["seconds"], 3)}
+
+
 class OpenAIServer:
     """Deployment callable serving /v1/models, /v1/completions and
     /v1/chat/completions (reference LLMRouter + LLMServer collapsed into
@@ -61,6 +81,19 @@ class OpenAIServer:
         self.model_id = model_id
         self.default_max_tokens = default_max_tokens
         self.tok = ByteTokenizer()
+        self._served = 0
+        # Set-up times, reported by /v1/stats: starting the device runtime
+        # (the first call that needs a device) and building the engine
+        # (parameters made and placed; the serving programs compile on the
+        # first request of each shape).
+        import jax
+
+        from ray_tpu._private import telemetry
+
+        telemetry.ensure_compile_listener()
+        t0 = time.monotonic()
+        jax.local_devices()
+        self._runtime_init_s = time.monotonic() - t0
         # pipeline_stages > 1 swaps in the pipeline-parallel engine
         # (README "Pipeline-parallel serving"); None defers to RT_PP_STAGES
         # so a deployment can be re-pointed without a code change. The two
@@ -78,6 +111,7 @@ class OpenAIServer:
         else:
             self.engine = ContinuousEngine(
                 cfg, max_batch=max_batch, decode_chunk=decode_chunk)
+        self._engine_init_s = time.monotonic() - t0 - self._runtime_init_s
 
     # ------------------------------------------------------------ helpers
     def _encode_prompt(self, body: dict) -> list[int]:
@@ -117,12 +151,20 @@ class OpenAIServer:
         if path.endswith("/v1/stats") or path.endswith("/stats"):
             # Introspection for chaos tests / ops: which process hosts the
             # engine and how many slots are live (a leaked slot shows here).
+            # `served` counts the requests this replica has taken, and the
+            # device fields are what JAX reports inside this process — the
+            # proof of which chip a replica really runs on.
             out = {"pid": os.getpid(), "active": self.engine.num_active,
-                   "running": self.engine._running}
+                   "running": self.engine._running, "served": self._served,
+                   "runtime_init_s": round(self._runtime_init_s, 3),
+                   "engine_init_s": round(self._engine_init_s, 3),
+                   **_device_report()}
             stages = getattr(self.engine, "n_stages", 0)
             if stages:
                 out["pipeline_stages"] = stages
+                out["stages"] = self.engine.stage_devices()
             return out
+        self._served += 1
         body = request.json() or {}
         chat = "chat" in path or "messages" in body
         prompt = self._encode_prompt(body)
@@ -186,7 +228,19 @@ def build_openai_app(cfg: LLMConfig, *, name: str = "llm",
     deployment: cap ongoing requests near max_batch so excess load sheds
     fast 429s at the proxy instead of stacking onto the engine's queue."""
     from ray_tpu import serve
+    from ray_tpu._private.rtconfig import CONFIG
 
+    stages = (int(CONFIG.pp_stages) if pipeline_stages is None
+              else int(pipeline_stages))
+    opts = ray_actor_options or {}
+    if stages > 1 and (opts.get("num_tpus")
+                       or (opts.get("resources") or {}).get("TPU")):
+        # A chip belongs to one process: the replica of a pipelined
+        # deployment only schedules, and each of its stages asks for a chip
+        # of its own (llm/pipeline.py `_stage_options`).
+        raise ValueError(
+            f"pipeline_stages={stages}: deploy the replica without "
+            f"num_tpus; each pipeline stage asks for its own TPU chip")
     dep = serve.deployment(
         OpenAIServer, name=name, num_replicas=num_replicas,
         ray_actor_options=ray_actor_options,

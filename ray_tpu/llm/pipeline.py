@@ -282,6 +282,19 @@ class PipelineStage:
     def pid(self) -> int:
         return os.getpid()
 
+    def device(self) -> dict:
+        """Where this stage runs, said from inside its own process: the
+        replica that schedules a pipeline holds no chip, so /v1/stats
+        reports its stages' devices beside its own."""
+        from ray_tpu._private import accelerators
+
+        devs = self._jax.local_devices()
+        return {"stage": self.name, "pid": os.getpid(),
+                "platform": devs[0].platform,
+                "device_kind": devs[0].device_kind,
+                "chip_files_open": accelerators.open_chip_files(),
+                "tpu_visible_chips": os.environ.get("TPU_VISIBLE_CHIPS")}
+
     def server_addr(self) -> tuple:
         from ray_tpu._private.worker import global_worker
 
@@ -399,7 +412,8 @@ class PipelinedEngine:
         import ray_tpu
         from ray_tpu import dag as _dag
 
-        stage_cls = ray_tpu.remote(num_cpus=0)(PipelineStage)
+        stage_opts = self._stage_options()
+        stage_cls = ray_tpu.remote(**stage_opts)(PipelineStage)
         actors = []
         for s, layers in enumerate(self._splits):
             actors.append(stage_cls.remote(
@@ -428,6 +442,45 @@ class PipelinedEngine:
                 node = a.step.bind(node)
         self._dag = _dag.compile(node)
         self._actors = actors
+
+    def _stage_options(self) -> dict:
+        """Actor options of one stage. A chip belongs to one process, and a
+        worker sees one only by holding the TPU resource (node_agent.py
+        `_spawn_worker`): on a cluster with chips each stage asks for its
+        own, and the process that builds the pipeline must hold none — it
+        only schedules. Too few free chips is an error, never a stage on
+        the CPU unannounced. A cluster without any runs them on the CPU."""
+        import jax
+
+        import ray_tpu
+
+        total = int(ray_tpu.cluster_resources().get("TPU", 0))
+        if not total:
+            logger.info("no TPU in the cluster: %d pipeline stages run on "
+                        "the CPU", self.n_stages)
+            return {"num_cpus": 0}
+        if jax.default_backend() == "tpu":
+            # __init__ made the parameters with JAX, so this process has a
+            # chip open: one its stages can then never open.
+            raise RuntimeError(
+                f"pipeline of {self.n_stages} stages: the process that "
+                f"builds it holds a TPU chip itself. Deploy the pipelined "
+                f"replica without num_tpus (each stage asks for its own "
+                f"chip), and hold a driver that builds one to the CPU "
+                f"(JAX_PLATFORMS=cpu)")
+        # After a rebuild the chips of the killed stages come back only when
+        # their processes have exited: wait for those, not on a first build.
+        deadline = time.monotonic() + (
+            CONFIG.worker_register_timeout_s if self._rebuilds else 0.0)
+        while True:
+            free = int(ray_tpu.available_resources().get("TPU", 0))
+            if free >= self.n_stages:
+                return {"num_cpus": 0, "num_tpus": 1}
+            if time.monotonic() >= deadline:
+                raise RuntimeError(
+                    f"pipeline of {self.n_stages} stages needs one TPU chip "
+                    f"per stage; {free} of the cluster's {total} are free")
+            time.sleep(0.2)
 
     def _teardown_graph(self):
         import ray_tpu
@@ -486,6 +539,13 @@ class PipelinedEngine:
     @property
     def num_active(self) -> int:
         return self._n_active
+
+    def stage_devices(self) -> list[dict]:
+        """Each stage's `PipelineStage.device()`, in stage order."""
+        import ray_tpu
+
+        return ray_tpu.get([a.device.remote() for a in list(self._actors)],
+                           timeout=30)
 
     def pipeline_stats(self) -> dict:
         """Aggregated per-stage counters: device-edge pins, resolve tiers

@@ -6,8 +6,10 @@ TPU-native design notes:
   MLP matmuls split over "tp", parameters additionally over "fsdp"
   (ZeRO-3 analogue), activations between blocks sequence-sharded over "sp";
   XLA/GSPMD inserts the all-gathers/reduce-scatters over ICI.
-- Attention goes through ray_tpu.ops.dot_product_attention (Pallas flash
-  kernel on TPU, XLA reference elsewhere).
+- Attention goes through ray_tpu.ops.dot_product_attention: the Pallas flash
+  kernel on a TPU when no gradient is taken (the kernel has no VJP, so a
+  training step takes the XLA path), the XLA reference elsewhere. Serving
+  runs with decode=True and takes neither: `_cached_attention` below.
 - The reference framework has no model zoo of its own — this fills the role
   its vLLM/torch delegation played (llm/_internal/serve/.../vllm_models.py
   TP/PP passthrough), natively.
@@ -101,8 +103,10 @@ class Attention(nn.Module):
         sequence b is t <= positions[b, i]; rows above a sequence's current
         position are never visible, so stale pad/previous-request garbage
         in the slot can never leak into attention. Single-token steps
-        (S==1, the serving hot loop) use the Pallas decode kernel
-        (ops/decode_attention.py)."""
+        (S==1, the serving hot loop) go through the decode-attention
+        dispatcher (ops/decode_attention.py: fused XLA or the Pallas
+        kernel, by cache size); multi-token steps (prefill) run the dense
+        f32 einsum below over the whole cache."""
         cfg = self.cfg
         b, s = q.shape[0], q.shape[1]
         ck = self.variable("cache", "k", lambda: jnp.zeros(
@@ -229,11 +233,31 @@ def _seq_shard(x):
     """Sequence-parallel activation constraint between blocks: [B, S, D]
     sharded batch over (dp, fsdp) and sequence over sp. GSPMD gathers the
     sequence inside attention (Megatron-SP style); ring attention
-    (ray_tpu/ops/ring_attention.py) removes that gather when enabled."""
-    try:
-        return jax.lax.with_sharding_constraint(x, P(("dp", "fsdp"), "sp", None))
-    except Exception:
-        return x  # not under a mesh (single-device tests)
+    (ray_tpu/ops/ring_attention.py) removes that gather when enabled.
+
+    Applied when the mesh in context has all three axes; with no mesh
+    (single device) or a mesh without them (a tp-only serving mesh) there is
+    nothing to constrain."""
+    if not {"dp", "fsdp", "sp"} <= set(_context_mesh_axes()):
+        return x
+    return jax.lax.with_sharding_constraint(x, P(("dp", "fsdp"), "sp", None))
+
+
+def _context_mesh_axes() -> tuple[str, ...]:
+    """Axis names of the mesh in context, whichever way it was entered.
+
+    JAX 0.9.0 keeps two contexts that do not see each other:
+    `jax.set_mesh(mesh)` sets the abstract mesh, the older `with mesh:` sets
+    only the thread-local physical mesh, which has no public reader.
+    `with_sharding_constraint` honours a bare PartitionSpec under either, so
+    both are read here: a caller under `with mesh:` must not lose sequence
+    parallelism without an error."""
+    axes = jax.sharding.get_abstract_mesh().axis_names
+    if axes:
+        return axes
+    from jax._src.mesh import thread_resources
+
+    return thread_resources.env.physical_mesh.axis_names
 
 
 def param_specs(params) -> dict:

@@ -3,50 +3,74 @@
 The reference framework has no attention kernels of its own (it delegates to
 torch/vLLM); this module is the TPU-native equivalent of that delegated
 surface. `dot_product_attention` dispatches to the Pallas flash kernel on TPU
-when shapes allow (ray_tpu/ops/flash_attention.py), else to a fused-softmax
-XLA implementation that GSPMD can shard.
+when shapes allow and no gradient is taken (ray_tpu/ops/flash_attention.py),
+else to a fused-softmax XLA implementation that GSPMD can shard.
 """
 
 from __future__ import annotations
 
+import functools
 import logging
 
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.ops.flash_attention import flash_attention, unsupported_reason
+
 logger = logging.getLogger(__name__)
-# Warn once PER DISTINCT REASON (not once per process): a second, different
-# shape rejection must not be silently swallowed by the first one's flag.
-_warned_reasons: set[str] = set()
+# Each distinct choice is stated once per process, not once per call.
+_stated: set[str] = set()
+
+
+def _state_once(msg: str) -> None:
+    if msg not in _stated:
+        _stated.add(msg)
+        logger.info(msg)
 
 
 def dot_product_attention(q, k, v, *, causal: bool = True, use_pallas: bool | None = None):
     """q: [B, Sq, Hq, D], k/v: [B, Sk, Hkv, D] (GQA when Hq > Hkv).
 
     Returns [B, Sq, Hq, D]. Softmax in f32 regardless of input dtype
-    (bf16-safe), output in the input dtype. Dispatches to the Pallas flash
-    kernel on TPU; every fallback is LOGGED, never silent. The kernel's own
-    ValueError is the single source of truth for shape support (no
-    duplicated predicate to drift)."""
+    (bf16-safe), output in the input dtype.
+
+    The implementation is chosen up front from what can be observed, and
+    each choice is stated once at INFO:
+      - not on a TPU (or use_pallas=False): the XLA path;
+      - a shape the flash kernel cannot tile (`unsupported_reason`, the
+        kernel's own block derivation): the XLA path, O(Sq*Sk) memory;
+      - under differentiation: the XLA path for the forward and the
+        backward pass, because the flash kernel has no VJP;
+      - otherwise the Pallas flash kernel.
+    Nothing is caught: an error from the kernel is the caller's error."""
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu"
-    if use_pallas:
-        from ray_tpu.ops.flash_attention import flash_attention
+    if not use_pallas:
+        return _xla_attention(q, k, v, causal=causal)
+    reason = unsupported_reason(q.shape, k.shape)
+    if reason is not None:
+        _state_once(f"attention: XLA path, O(Sq*Sk) memory ({reason})")
+        return _xla_attention(q, k, v, causal=causal)
+    return _flash_or_xla_grad(q, k, v, causal)
 
-        try:
-            return flash_attention(q, k, v, causal=causal)
-        except ValueError as e:
-            reason = str(e)
-            if reason not in _warned_reasons:
-                _warned_reasons.add(reason)
-                logger.warning(
-                    "attention falling back to the XLA path (%s); "
-                    "O(Sq*Sk) memory", reason)
-        except Exception as e:
-            # Mosaic lowering limits, odd head dims, dtypes: loud safety net.
-            logger.warning("flash attention kernel failed (%r); "
-                           "falling back to XLA", e)
-    return _xla_attention(q, k, v, causal=causal)
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _flash_or_xla_grad(q, k, v, causal):
+    _state_once("attention: Pallas flash kernel")
+    return flash_attention(q, k, v, causal=causal)
+
+
+def _flash_or_xla_grad_fwd(q, k, v, causal):
+    _state_once("attention: XLA path under differentiation (the flash "
+                "kernel has no VJP)")
+    return jax.vjp(functools.partial(_xla_attention, causal=causal), q, k, v)
+
+
+def _flash_or_xla_grad_bwd(causal, vjp, g):
+    return vjp(g)
+
+
+_flash_or_xla_grad.defvjp(_flash_or_xla_grad_fwd, _flash_or_xla_grad_bwd)
 
 
 def _xla_attention(q, k, v, *, causal: bool):

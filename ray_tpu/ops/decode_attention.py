@@ -17,14 +17,11 @@ python/ray/llm delegates; no TPU equivalent exists in the reference).
 from __future__ import annotations
 
 import functools
-import logging
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-logger = logging.getLogger(__name__)
 
 NEG_INF = float("-inf")
 DEFAULT_BLOCK_K = 512
@@ -75,6 +72,22 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
         o_ref[0] = (acc_ref[:] / l).astype(o_ref.dtype)
 
 
+def unsupported_reason(q_shape, cache_shape,
+                       block_k: int = DEFAULT_BLOCK_K) -> str | None:
+    """Why `decode_attention_pallas` cannot take q [B, H, D] against a
+    [B, S, KV, D] cache, or None when it can. The kernel raises exactly
+    this; the dispatcher asks it first."""
+    _, hq, _ = q_shape
+    _, sk, hkv, _ = cache_shape
+    if hq % hkv:
+        return f"Hq={hq} not a multiple of Hkv={hkv}"
+    block_k = min(block_k, sk)
+    if sk % block_k or block_k % 128:
+        return (f"cache length {sk} not divisible by lane-aligned block "
+                f"{block_k}")
+    return None
+
+
 @functools.partial(jax.jit,
                    static_argnames=("block_k", "interpret"))
 def decode_attention_pallas(q, k_cache, v_cache, lengths, *,
@@ -85,14 +98,11 @@ def decode_attention_pallas(q, k_cache, v_cache, lengths, *,
     valid (INCLUDING the just-written current token). Returns [B, H, D]."""
     b, hq, d = q.shape
     _, sk, hkv, _ = k_cache.shape
-    if hq % hkv != 0:
-        raise ValueError(f"Hq={hq} not a multiple of Hkv={hkv}")
+    reason = unsupported_reason(q.shape, k_cache.shape, block_k)
+    if reason is not None:
+        raise ValueError(reason)
     rep = hq // hkv
     block_k = min(block_k, sk)
-    if sk % block_k or block_k % 128:
-        raise ValueError(
-            f"cache length {sk} not divisible by lane-aligned block "
-            f"{block_k}")
     scale = d ** -0.5
     n_k = sk // block_k
     # Pad the per-kv-head q group up to the 8-row sublane tile: padded rows
@@ -151,38 +161,63 @@ def _xla_decode_attention(q, k_cache, v_cache, lengths):
     return out.astype(q.dtype)
 
 
-_warned = False
-
 #: Cache bytes above which the Pallas kernel dispatches by default. At
 #: serving-typical sizes (B=8, KV=16, D=64, S=1024: ~2x16MB bf16) the
-#: fused XLA einsum WINS — measured 1.44 vs 2.83 ms per 8-layer decode
-#: step on v5e: per-layer pallas_call launch overhead dominates when the
-#: per-head score row is only [1, S]. The kernel's streaming VMEM schedule
-#: pays off once the per-call cache traffic is large enough to amortize
-#: launches (long context / big batch). RT_DECODE_KERNEL=pallas|xla
-#: overrides.
+#: fused XLA einsum was the faster of the two when last compared (1.44 vs
+#: 2.83 ms per 8-layer decode step; taken over a shared remote link, not
+#: measured on the chip since): per-layer pallas_call launch overhead
+#: dominates when the per-head score row is only [1, S]. The kernel's
+#: streaming VMEM schedule pays off once the per-call cache traffic is
+#: large enough to amortize launches (long context / big batch).
+#: RT_DECODE_KERNEL=pallas|xla overrides.
 PALLAS_MIN_CACHE_BYTES = 256 * 1024 * 1024
 
 
-def decode_attention(q, k_cache, v_cache, lengths, *, interpret: bool = False):
-    """Dispatcher: size-based choice between the fused XLA path and the
-    Pallas streaming kernel (env RT_DECODE_KERNEL forces one).
-    q: [B, H, D]; caches [B, S, KV, D]; lengths [B] -> [B, H, D]."""
-    global _warned
-    from ray_tpu._private.rtconfig import CONFIG
+def choose_impl(q_shape, cache_shape, cache_itemsize: int, *,
+                backend: str, force: str = "") -> tuple[str, str]:
+    """("pallas" | "xla", why) for one decode-attention call, from what can
+    be observed before it runs: the forced choice (RT_DECODE_KERNEL), the
+    backend, the cache size and whether the kernel can tile the shape."""
+    if force == "xla":
+        return "xla", "RT_DECODE_KERNEL=xla"
+    if force == "pallas":
+        return "pallas", "RT_DECODE_KERNEL=pallas"
+    if force:
+        raise ValueError(
+            f"RT_DECODE_KERNEL={force!r}: expected 'pallas', 'xla' or ''")
+    if backend != "tpu":
+        return "xla", f"backend is {backend}"
+    b, sk, hkv, d = cache_shape
+    cache_bytes = 2 * b * sk * hkv * d * cache_itemsize
+    if cache_bytes < PALLAS_MIN_CACHE_BYTES:
+        return "xla", (f"k+v cache of {cache_bytes} bytes is under "
+                       f"{PALLAS_MIN_CACHE_BYTES}")
+    reason = unsupported_reason(q_shape, cache_shape)
+    if reason is not None:
+        return "xla", reason
+    return "pallas", f"k+v cache of {cache_bytes} bytes"
 
-    force = str(CONFIG.decode_kernel).lower()
-    on_tpu = jax.devices()[0].platform == "tpu"
-    cache_bytes = 2 * k_cache.size * k_cache.dtype.itemsize
-    want_pallas = (force == "pallas"
-                   or (force != "xla"
-                       and cache_bytes >= PALLAS_MIN_CACHE_BYTES))
-    if (on_tpu and want_pallas) or interpret:
-        try:
-            return decode_attention_pallas(
-                q, k_cache, v_cache, lengths, interpret=interpret)
-        except Exception as e:
-            if not _warned:
-                _warned = True
-                logger.warning("decode attention falling back to XLA: %s", e)
+
+def decode_attention(q, k_cache, v_cache, lengths, *, interpret: bool = False):
+    """Dispatcher: `choose_impl` picks between the fused XLA path and the
+    Pallas streaming kernel up front and the choice is stated once at INFO;
+    nothing is caught, so a forced kernel on a shape it rejects, or a
+    kernel that fails to compile, raises. `interpret=True` runs the kernel
+    in the Pallas interpreter on any backend (tests).
+    q: [B, H, D]; caches [B, S, KV, D]; lengths [B] -> [B, H, D]."""
+    from ray_tpu._private.rtconfig import CONFIG
+    from ray_tpu.ops.attention import _state_once
+
+    if interpret:
+        impl, why = "pallas", "interpret mode"
+    else:
+        impl, why = choose_impl(
+            q.shape, k_cache.shape, k_cache.dtype.itemsize,
+            backend=jax.default_backend(),
+            force=str(CONFIG.decode_kernel).lower())
+    _state_once(f"decode attention: {impl} ({why}; q {tuple(q.shape)}, "
+                f"cache {tuple(k_cache.shape)} {k_cache.dtype})")
+    if impl == "pallas":
+        return decode_attention_pallas(
+            q, k_cache, v_cache, lengths, interpret=interpret)
     return _xla_decode_attention(q, k_cache, v_cache, lengths)

@@ -32,7 +32,7 @@ NEG_INF = float("-inf")
 def _auto_block(dim: int, preferred: int, align: int) -> int | None:
     """Largest divisor of `dim` that is a multiple of `align` (TPU sublane/
     lane tiling) and <= `preferred`. None when no aligned divisor exists
-    (the shape then falls back to the XLA path). Auto-deriving from the
+    (the dispatcher then takes the XLA path). Auto-deriving from the
     input shape keeps the tuned defaults for big sequences while accepting
     any lane-alignable Sq/Sk — e.g. Sq=Sk=640 picks 320/640, not a
     hard-coded 512/1024 that 640 doesn't divide."""
@@ -45,6 +45,25 @@ def _auto_block(dim: int, preferred: int, align: int) -> int | None:
     return best
 
 
+def _block_reasons(sq: int, sk: int, block_q: int | None,
+                   block_k: int | None):
+    """((block_q, block_k), None) for a [Sq, Sk] problem the kernel can
+    tile, else (None, reason)."""
+    bq = _auto_block(sq, block_q or DEFAULT_BLOCK_Q, 8)
+    if bq is None:
+        return None, (
+            f"Sq={sq} has no divisor aligned to the TPU sublane tile (8)"
+            + (f" at or under block_q={block_q}" if block_q else ""))
+    bk = _auto_block(sk, block_k or DEFAULT_BLOCK_K, 128)
+    if bk is None:
+        # block_k spans the LANE axis of the [block_q, block_k] score
+        # tile, so it needs 128-alignment (block_q only needs sublane 8).
+        return None, (
+            f"Sk={sk} has no divisor aligned to the TPU lane tile (128)"
+            + (f" at or under block_k={block_k}" if block_k else ""))
+    return (bq, bk), None
+
+
 def derive_blocks(sq: int, sk: int, block_q: int | None = None,
                   block_k: int | None = None) -> tuple[int, int]:
     """Resolve the (block_q, block_k) pair for a [Sq, Sk] problem, CLAMPED
@@ -54,21 +73,23 @@ def derive_blocks(sq: int, sk: int, block_q: int | None = None,
     a short sequence can never squeeze past the divisibility check as a
     tile-violating remnant (the r05 bench regression: a raw min() clamp
     produced blocks like 8/8 and the opaque "violate TPU tiling" reason).
-    Raises ValueError with the fallback reason when no valid tile exists —
-    the dispatcher's cue to take the XLA path."""
-    bq = _auto_block(sq, block_q or DEFAULT_BLOCK_Q, 8)
-    if bq is None:
-        raise ValueError(
-            f"Sq={sq} has no divisor aligned to the TPU sublane tile (8)"
-            + (f" at or under block_q={block_q}" if block_q else ""))
-    bk = _auto_block(sk, block_k or DEFAULT_BLOCK_K, 128)
-    if bk is None:
-        # block_k spans the LANE axis of the [block_q, block_k] score
-        # tile, so it needs 128-alignment (block_q only needs sublane 8).
-        raise ValueError(
-            f"Sk={sk} has no divisor aligned to the TPU lane tile (128)"
-            + (f" at or under block_k={block_k}" if block_k else ""))
-    return bq, bk
+    Raises ValueError with the reason when no valid tile exists."""
+    blocks, reason = _block_reasons(sq, sk, block_q, block_k)
+    if blocks is None:
+        raise ValueError(reason)
+    return blocks
+
+
+def unsupported_reason(q_shape, k_shape) -> str | None:
+    """Why `flash_attention` cannot take q [B, Sq, Hq, D] with k/v
+    [B, Sk, Hkv, D] at its default blocks, or None when it can. The
+    dispatcher (ops/attention.py) asks this before it picks the kernel; it
+    is the same derivation the kernel runs, so the two cannot drift."""
+    _, sq, hq, _ = q_shape
+    _, sk, hkv, _ = k_shape
+    if hq % hkv:
+        return f"Hq={hq} not a multiple of Hkv={hkv}"
+    return _block_reasons(sq, sk, None, None)[1]
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
@@ -138,8 +159,7 @@ def flash_attention(q, k, v, *, causal: bool = True,
     Returns [B, Sq, Hq, D]. block_q/block_k are upper-bound preferences;
     the actual blocks are tile-aligned divisors of Sq/Sk derived by
     derive_blocks (defaults: the tuned 512/1024). Raises ValueError for
-    shapes with no valid tiling (the dispatcher falls back to the XLA
-    path and logs)."""
+    shapes with no valid tiling (`unsupported_reason` says so beforehand)."""
     b, sq, hq, d = q.shape
     _, sk, hkv, _ = k.shape
     if hq % hkv != 0:

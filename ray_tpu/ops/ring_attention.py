@@ -73,17 +73,8 @@ def ring_attention(q, k, v, *, axis_name: str, causal: bool = True):
     # The outputs vary over the sp axis (they depend on axis_index); the
     # constant initial carries must be marked varying too or scan rejects
     # the carry type under shard_map.
-    for _mark in (lambda x: jax.lax.pcast(x, to="varying"),
-                  lambda x: jax.lax.pvary(x, axis_name),
-                  lambda x: x):
-        # Marking API differs across jax versions (pcast / pvary), and jax
-        # builds WITHOUT either (<=0.4.x) don't type-check carry variance
-        # under shard_map at all — the identity fallback is correct there.
-        try:
-            m0, l0, acc0 = (_mark(x) for x in (m0, l0, acc0))
-            break
-        except (AttributeError, TypeError):
-            continue
+    m0, l0, acc0 = (jax.lax.pcast(x, axis_name, to="varying")
+                    for x in (m0, l0, acc0))
     (m, l, acc, _k, _v), _ = jax.lax.scan(
         step, (m0, l0, acc0, k, v), jnp.arange(n))
     l = jnp.where(l == 0.0, 1.0, l)

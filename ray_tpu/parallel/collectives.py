@@ -17,21 +17,12 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-_shard_map_raw = jax.shard_map if hasattr(jax, "shard_map") else None
-if _shard_map_raw is None:  # pragma: no cover — older jax
-    from jax.experimental.shard_map import shard_map as _shard_map_raw  # type: ignore
-
-
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
     """jax.shard_map with the static-replication check relaxed by default:
     collective-heavy bodies (all_gather -> replicated out) routinely defeat
     the inference and the runtime sharding is still checked."""
-    try:
-        return _shard_map_raw(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_vma=check_vma)
-    except TypeError:  # pragma: no cover — pre-0.8 jax called it check_rep
-        return _shard_map_raw(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=check_vma)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def psum(x, axis_name: str):

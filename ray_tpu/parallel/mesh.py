@@ -63,20 +63,20 @@ class MeshConfig:
 def build_mesh(config: MeshConfig | None = None, devices=None) -> Mesh:
     """Build a Mesh over the given (default: all) devices.
 
-    On real TPU slices, `jax.devices()` ordering already follows the
-    physical torus, so contiguous reshape keeps ICI-neighbor axes adjacent;
-    `jax.experimental.mesh_utils.create_device_mesh` is used when available
-    for a topology-aware layout.
+    On a TPU, `jax.experimental.mesh_utils.create_device_mesh` lays the axes
+    out along the physical torus so that ICI neighbours stay adjacent. Other
+    platforms have no topology to follow: their devices are reshaped in
+    order.
     """
     config = config or MeshConfig()
     devices = list(devices if devices is not None else jax.devices())
     sizes = config.resolve(len(devices))
     shape = tuple(sizes[a] for a in AXIS_ORDER)
-    try:
+    if devices[0].platform == "tpu":
         from jax.experimental import mesh_utils
 
         dev_array = mesh_utils.create_device_mesh(shape, devices=devices)
-    except Exception:
+    else:
         dev_array = np.asarray(devices).reshape(shape)
     return Mesh(dev_array, AXIS_ORDER)
 

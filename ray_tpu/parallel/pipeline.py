@@ -111,18 +111,9 @@ def _pipeline_shard_fn(blocks, x_mb, cfg: PipelineConfig, n_stages: int):
         nxt = jax.lax.ppermute(y, "pp", perm_fwd)
         return nxt, y
 
-    zero = jnp.zeros(mb_shape, x_mb.dtype)
-    for _mark in (lambda x: jax.lax.pcast(x, to="varying"),
-                  lambda x: jax.lax.pvary(x, "pp"),
-                  lambda x: x):
-        # Marking API differs across jax versions (pcast / pvary); builds
-        # with NEITHER (<=0.4.x) don't type-check carry variance under
-        # shard_map (check_rep=False above), so identity is correct there.
-        try:
-            zero = _mark(zero)
-            break
-        except (AttributeError, TypeError):
-            continue
+    # The carry varies over pp (it depends on axis_index); the constant
+    # initial value must be marked varying too or scan rejects the carry.
+    zero = jax.lax.pcast(jnp.zeros(mb_shape, x_mb.dtype), "pp", to="varying")
     _, ys = jax.lax.scan(tick, zero, jnp.arange(T))
     # On the last stage, ys[t] for t in [S-1, S-1+M) are microbatches 0..M-1.
     outs = jax.lax.dynamic_slice_in_dim(ys, n_stages - 1, M, axis=0)
@@ -135,17 +126,15 @@ def _pipeline_shard_fn(blocks, x_mb, cfg: PipelineConfig, n_stages: int):
 def pipeline_loss_fn(cfg: PipelineConfig, mesh: Mesh):
     """Returns loss(params, tokens) whose block stack runs as a GPipe
     pipeline over the mesh's pp axis (embedding/head replicated)."""
-    from jax.experimental.shard_map import shard_map
-
     n_stages = mesh.shape["pp"]
     assert cfg.n_layers % n_stages == 0
 
-    pipe = shard_map(
+    pipe = jax.shard_map(
         functools.partial(_pipeline_shard_fn, cfg=cfg, n_stages=n_stages),
         mesh=mesh,
         in_specs=(P("pp"), P()),   # blocks stage-sharded; microbatches replicated
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
 
     def loss_fn(params, tokens):

@@ -451,8 +451,8 @@ TASK_TIMEOUTS = Counter(
 #: Tracing-plane latency histograms (README "Tracing & timeline"), observed
 #: ONLY inside sampled trace contexts — the unsampled hot path mints no
 #: records. Frame RTT catches control-plane hops a span tree summarizes;
-#: decode-step is the serve->engine host-link sync the BENCH_r05 22x gap
-#: hides in (each observation is one engine host readback round trip).
+#: decode-step is the serve->engine host-link sync (each observation is
+#: one engine host readback round trip).
 RPC_FRAME_SECONDS = Histogram(
     "rt_rpc_frame_seconds",
     description="traced RPC request round-trip time",

@@ -28,7 +28,7 @@ def test_baseline_ratios_ignores_metrics_without_baseline():
 
 
 def test_baseline_ratios_excludes_non_positive_lane_values():
-    # The BENCH_r05 regression shape: a broken timing window produced
+    # A regression seen once in a driver record: a broken timing window produced
     # -49.6 "TFLOP/s". Under the old max(r, 1e-9) clamp a single such
     # lane contributed log(1e-9) and cratered the geomean; the contract
     # is exclusion, so the healthy lanes fully determine the mean.

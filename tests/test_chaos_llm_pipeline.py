@@ -114,6 +114,37 @@ def test_stage_sigkill_attributes_streams_then_engine_rebuilds(
     assert pipe._actors == [] and pipe._dag is None
 
 
+def test_rebuild_waits_for_the_killed_stages_chips(shutdown_only):
+    """On a cluster with chips every stage holds one, and a rebuild needs
+    them all again: it waits until the killed stages' chips have come back
+    instead of refusing (or starting a stage on the CPU)."""
+    from ray_tpu.llm.pipeline import PipelinedEngine
+
+    ray_tpu.init(num_cpus=4, num_tpus=2)
+    pipe = PipelinedEngine(LLMConfig(**CFG_KW), n_stages=2, max_batch=4,
+                           microbatch=2)
+    try:
+        assert ray_tpu.available_resources().get("TPU", 0) == 0
+        sp = SamplingParams(temperature=0.0, max_tokens=4)
+        warm = pipe.generate([[1, 2]], sp)
+        old_pids = ray_tpu.get([a.pid.remote() for a in pipe._actors],
+                               timeout=30)
+        stream = pipe.submit([1, 2, 3], SamplingParams(temperature=0.0,
+                                                       max_tokens=200))
+        stream.next(timeout=30)
+        os.kill(old_pids[0], signal.SIGKILL)
+        with pytest.raises(DagStageError):
+            while True:
+                stream.next(timeout=DEADLINE_S + 10)
+        assert _drain_bounded(pipe.submit([1, 2], sp), budget_s=90.0) == warm[0]
+        new_pids = ray_tpu.get([a.pid.remote() for a in pipe._actors],
+                               timeout=30)
+        assert not set(new_pids) & set(old_pids)
+        assert ray_tpu.available_resources().get("TPU", 0) == 0
+    finally:
+        pipe.shutdown()
+
+
 def test_shutdown_mid_generation_never_hangs(ray_start_4cpu):
     """shutdown() with streams open ends every stream promptly (engine
     shut down => streams end; a consumer blocked in next() is released)."""

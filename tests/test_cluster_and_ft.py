@@ -169,3 +169,27 @@ def test_controller_persistence_restart(shutdown_only, tmp_path):
     assert w.kv("get", ns="app", key="cfg")["value"] == b"v42"
     reg2 = ray_tpu.get_actor("registry")
     assert ray_tpu.get(reg2.greet.remote(), timeout=120) == "hello-from-v1"
+
+
+def test_controller_stall_does_not_kill_healthy_nodes(shutdown_only):
+    """A controller that was not running cannot judge who else was not:
+    when its own event loop loses far more than the heartbeat timeout
+    (blocked, or the machine paused — a four-chip host stands still for 9
+    to 10 s while four workers start the TPU runtime at once), the head
+    node, whose agent shares that loop, must not be declared dead with
+    every actor on it."""
+    ray_tpu.init(num_cpus=1, _system_config={
+        "heartbeat_interval_s": 0.2, "num_heartbeats_timeout": 2})
+
+    @ray_tpu.remote(num_cpus=0)
+    class Pinger:
+        def ping(self):
+            return "pong"
+
+    a = Pinger.remote()
+    assert ray_tpu.get(a.ping.remote(), timeout=30) == "pong"
+    # Block the loop for 1.5 s, nearly four times the 0.4 s timeout.
+    ray_tpu._head.io.loop.call_soon_threadsafe(time.sleep, 1.5)
+    time.sleep(2.5)
+    assert all(n["Alive"] for n in ray_tpu.nodes())
+    assert ray_tpu.get(a.ping.remote(), timeout=30) == "pong"

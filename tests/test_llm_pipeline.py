@@ -89,6 +89,54 @@ def test_pipeline_greedy_parity_with_single_process(ray_start_4cpu):
         single.shutdown()
 
 
+def test_pipeline_stages_never_run_on_cpu_beside_chips(shutdown_only):
+    """On a cluster with chips each stage asks for one and the process that
+    builds the pipeline holds none: the engine refuses to start rather than
+    let a stage run on the CPU unannounced, or wait for a chip that its own
+    parent holds."""
+    import jax
+
+    from ray_tpu.llm.openai import build_openai_app
+    from ray_tpu.llm.pipeline import PipelinedEngine
+
+    def build():
+        return PipelinedEngine(LLMConfig(**CFG_KW), n_stages=2, max_batch=4,
+                               microbatch=2)
+
+    # Fewer chips than stages.
+    ray_tpu.init(num_cpus=2, num_tpus=1)
+    with pytest.raises(RuntimeError, match="1 of the cluster's 1 are free"):
+        build()
+    ray_tpu.shutdown()
+
+    # Enough chips in the cluster, but one is held (here by another actor,
+    # as the parent replica's own grant would be): free chips are counted,
+    # not the cluster's total.
+    ray_tpu.init(num_cpus=2, num_tpus=2)
+
+    @ray_tpu.remote(num_cpus=0, num_tpus=1)
+    class Holder:
+        def ping(self):
+            return True
+
+    holder = Holder.remote()
+    assert ray_tpu.get(holder.ping.remote(), timeout=60)
+    with pytest.raises(RuntimeError, match="1 of the cluster's 2 are free"):
+        build()
+
+    # A builder that has a chip open itself (its JAX backend is the TPU).
+    ray_tpu.kill(holder)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        with pytest.raises(RuntimeError, match="holds a TPU chip itself"):
+            build()
+
+    # And the serve entry point says so before anything is deployed.
+    with pytest.raises(ValueError, match="without num_tpus"):
+        build_openai_app(LLMConfig(**CFG_KW), pipeline_stages=2,
+                         ray_actor_options={"num_tpus": 1})
+
+
 def test_pipeline_sampled_decode_and_active_count(ray_start_4cpu):
     from ray_tpu.llm.pipeline import PipelinedEngine
 
@@ -247,6 +295,10 @@ def test_openai_serve_over_pipeline_engine(ray_start_4cpu):
         with urllib.request.urlopen(f"{base}/v1/stats", timeout=30) as r:
             stats = json.loads(r.read())
         assert stats["pipeline_stages"] == 2
+        # Each stage says where it runs, from inside its own process.
+        assert [st["stage"] for st in stats["stages"]] == ["pp0", "pp1"]
+        assert len({st["pid"] for st in stats["stages"]} | {stats["pid"]}) == 3
+        assert all(st["platform"] == "cpu" for st in stats["stages"])
     finally:
         serve.shutdown()
 
@@ -277,10 +329,10 @@ def _fake_tpu_devices(monkeypatch):
         lambda backend=None: [types.SimpleNamespace(platform="tpu")])
 
 
-def test_flash_bench_fallback_flag_on_value_error(monkeypatch):
-    """A kernel shape rejection is reported as an explicit
-    {"fallback": true, "reason": ...} detail — the lane never fabricates
-    a TFLOP/s number from a failed run."""
+def test_flash_bench_kernel_failure_fails_the_lane(monkeypatch):
+    """A kernel that rejects the bench shape (or fails to compile) fails
+    the chip lane, and with it the run: it is neither logged as "skipped"
+    nor turned into a fallback detail, and no TFLOP/s number is recorded."""
     import bench
     from ray_tpu.ops import flash_attention as fa_mod
 
@@ -291,10 +343,24 @@ def test_flash_bench_fallback_flag_on_value_error(monkeypatch):
 
     monkeypatch.setattr(fa_mod, "flash_attention", reject)
     results, details = {}, {}
-    bench._bench_flash_attention(results, details)
+    with pytest.raises(ValueError, match="lane tile"):
+        bench._bench_flash_attention(results, details)
     assert "flash_attention_tflops" not in results
-    assert details["flash_attention"]["fallback"] is True
-    assert "lane tile" in details["flash_attention"]["reason"]
+    assert "flash_attention" not in details
+
+
+def test_bench_unknown_device_kind_is_an_error():
+    """The peaks table is keyed by device_kind; a kind that is not in it
+    raises instead of dropping the utilization line."""
+    import types
+
+    import bench
+
+    peak, kind = bench.tpu_peak_flops(
+        types.SimpleNamespace(device_kind="TPU v5 lite"))
+    assert peak == 197e12 and kind == "TPU v5 lite"
+    with pytest.raises(KeyError, match="TPU_PEAK_BF16"):
+        bench.tpu_peak_flops(types.SimpleNamespace(device_kind="TPU v9"))
 
 
 def test_flash_bench_fallback_flag_on_nonmonotonic_timing(monkeypatch):

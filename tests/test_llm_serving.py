@@ -6,6 +6,7 @@ Parity target: reference python/ray/llm/_internal/serve — vLLM engine seat
 """
 
 import json
+import os
 import time
 import urllib.request
 
@@ -173,6 +174,16 @@ def test_serve_openai_http(ray_start_4cpu):
             out = json.loads(r.read())
         assert out["object"] == "chat.completion"
         assert out["choices"][0]["message"]["role"] == "assistant"
+        # /v1/stats: the replica says, from inside its own process, which
+        # device it runs on and how many requests it has taken.
+        with urllib.request.urlopen(f"{base}/v1/stats", timeout=30) as r:
+            st = json.loads(r.read())
+        assert st["pid"] != os.getpid()
+        assert st["platform"] == "cpu" and st["device_kind"]
+        assert st["device_ids"] and st["tpu_visible_chips"] is None
+        assert st["chip_files_open"] == []
+        assert st["served"] == 3 and st["active"] == 0
+        assert st["compile_count"] > 0 and st["engine_init_s"] > 0
     finally:
         serve.shutdown()
 

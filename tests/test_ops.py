@@ -117,35 +117,140 @@ def test_flash_attention_auto_blocks():
     assert float(jnp.max(jnp.abs(out - ref))) < 2e-5
 
 
-def test_attention_fallback_warns_per_reason(caplog):
-    """A second, DIFFERENT shape rejection must warn too (the old
-    once-per-process flag swallowed it); the same reason stays deduped."""
+def test_attention_dispatch_states_its_rule_once(caplog):
+    """The choice is made up front from the shapes (the kernel's own block
+    derivation) and stated once per distinct reason at INFO; a shape the
+    kernel cannot tile takes the XLA path without touching the kernel."""
     import logging
 
     from ray_tpu.ops import attention as attn_mod
     from ray_tpu.ops.attention import dot_product_attention
 
-    attn_mod._warned_reasons.clear()
-    q_bad_sq = jnp.zeros((1, 100, 2, 64), jnp.float32)  # Sq not 8-alignable
-    q_small = jnp.zeros((1, 64, 2, 64), jnp.float32)  # Sk < one lane tile
-    with caplog.at_level(logging.WARNING, logger="ray_tpu.ops.attention"):
-        dot_product_attention(q_bad_sq, q_bad_sq, q_bad_sq, use_pallas=True)
-        first = [r for r in caplog.records if "falling back" in r.message]
+    attn_mod._stated.clear()
+    q_bad_sq = jnp.ones((1, 100, 2, 64), jnp.float32)  # Sq not 8-alignable
+    q_small = jnp.ones((1, 64, 2, 64), jnp.float32)  # Sk < one lane tile
+
+    def stated():
+        return [r.message for r in caplog.records if "XLA path" in r.message]
+
+    with caplog.at_level(logging.INFO, logger="ray_tpu.ops.attention"):
+        out = dot_product_attention(q_bad_sq, q_bad_sq, q_bad_sq,
+                                    use_pallas=True)
+        assert len(stated()) == 1 and "sublane tile" in stated()[0]
         dot_product_attention(q_small, q_small, q_small, use_pallas=True)
-        second = [r for r in caplog.records if "falling back" in r.message]
-        # repeat of the first reason: deduped
+        assert len(stated()) == 2 and "lane tile" in stated()[1]
+        # repeat of the first reason: stated once
         dot_product_attention(q_bad_sq, q_bad_sq, q_bad_sq, use_pallas=True)
-        third = [r for r in caplog.records if "falling back" in r.message]
-    assert len(first) == 1
-    assert len(second) == 2, "second distinct reason was swallowed"
-    assert len(third) == 2, "duplicate reason was not deduped"
+        assert len(stated()) == 2
+    ref = _xla_attention(q_bad_sq, q_bad_sq, q_bad_sq, causal=True)
+    assert float(jnp.max(jnp.abs(out - ref))) == 0.0
+
+
+def test_attention_kernel_error_propagates():
+    """Nothing stands between the dispatcher and the kernel: at a shape the
+    kernel takes, its failure (here: Mosaic cannot compile for the CPU) is
+    the caller's error, not a quiet switch to XLA."""
+    from ray_tpu.ops.attention import dot_product_attention
+
+    q = jnp.ones((1, 256, 2, 64), jnp.float32)
+    with pytest.raises(Exception, match="[Ii]nterpret mode"):
+        dot_product_attention(q, q, q, use_pallas=True)
+
+
+def test_attention_gradient_takes_xla_path_by_rule(caplog):
+    """The flash kernel has no VJP. Under differentiation the dispatcher
+    takes the XLA path for forward and backward — by a rule it states, in
+    either order of jit and grad. On the CPU the kernel cannot be lowered
+    at all (test_attention_kernel_error_propagates), so a gradient that
+    comes out right here never ran it."""
+    import logging
+
+    from ray_tpu.ops import attention as attn_mod
+    from ray_tpu.ops.attention import dot_product_attention
+
+    attn_mod._stated.clear()
+    rng = np.random.RandomState(4)
+    q, k, v = (jnp.asarray(rng.randn(1, 128, 2, 32), jnp.float32)
+               for _ in range(3))
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(fn(q, k, v) ** 2)
+
+    dispatched = loss(lambda q, k, v: dot_product_attention(
+        q, k, v, causal=True, use_pallas=True))
+    want = jax.grad(loss(lambda q, k, v: _xla_attention(
+        q, k, v, causal=True)), argnums=(0, 1, 2))(q, k, v)
+    with caplog.at_level(logging.INFO, logger="ray_tpu.ops.attention"):
+        got = jax.jit(jax.grad(dispatched, argnums=(0, 1, 2)))(q, k, v)
+        got2 = jax.grad(jax.jit(dispatched), argnums=(0, 1, 2))(q, k, v)
+    for g, g2, w in zip(got, got2, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=1e-5)
+        np.testing.assert_allclose(np.asarray(g2), np.asarray(w), atol=1e-5)
+    said = [r.message for r in caplog.records if "no VJP" in r.message]
+    assert len(said) == 1
+
+
+def test_decode_attention_pallas_matches_xla():
+    """The decode kernel in the interpreter against the dense reference:
+    ragged lengths, GQA (q group padded to the sublane tile) and MHA."""
+    from ray_tpu.ops.decode_attention import (
+        _xla_decode_attention, decode_attention, decode_attention_pallas)
+
+    rng = np.random.RandomState(6)
+    for hq, hkv in [(4, 4), (8, 2)]:
+        b, s, d = 3, 256, 32
+        q = jnp.asarray(rng.randn(b, hq, d), jnp.float32)
+        k = jnp.asarray(rng.randn(b, s, hkv, d), jnp.float32)
+        v = jnp.asarray(rng.randn(b, s, hkv, d), jnp.float32)
+        lens = jnp.asarray([1, 130, 256], jnp.int32)
+        ref = _xla_decode_attention(q, k, v, lens)
+        out = decode_attention_pallas(q, k, v, lens, block_k=128,
+                                      interpret=True)
+        assert float(jnp.max(jnp.abs(out - ref))) < 2e-5
+        via = decode_attention(q, k, v, lens, interpret=True)
+        assert float(jnp.max(jnp.abs(via - ref))) < 2e-5
+
+
+def test_decode_dispatch_rule():
+    """choose_impl: forced choice, then backend, cache size and shape."""
+    from ray_tpu.ops.decode_attention import (
+        PALLAS_MIN_CACHE_BYTES, choose_impl)
+
+    served = ((8, 16, 64), (8, 1024, 16, 64), 2)  # 32 MiB of k+v
+    big = ((64, 16, 64), (64, 2048, 16, 64), 2)  # 512 MiB
+    assert 2 * 64 * 2048 * 16 * 64 * 2 >= PALLAS_MIN_CACHE_BYTES
+    assert choose_impl(*served, backend="tpu")[0] == "xla"
+    assert choose_impl(*big, backend="tpu")[0] == "pallas"
+    assert choose_impl(*big, backend="cpu") == ("xla", "backend is cpu")
+    # a big cache the kernel cannot tile stays on XLA, and says why
+    odd = ((64, 16, 64), (64, 2100, 16, 64), 2)
+    impl, why = choose_impl(*odd, backend="tpu")
+    assert impl == "xla" and "lane-aligned" in why
+    # forced choices win on any backend
+    assert choose_impl(*served, backend="cpu", force="pallas")[0] == "pallas"
+    assert choose_impl(*big, backend="tpu", force="xla")[0] == "xla"
+    with pytest.raises(ValueError, match="RT_DECODE_KERNEL"):
+        choose_impl(*served, backend="tpu", force="mosaic")
+
+
+def test_decode_forced_kernel_raises_on_a_shape_it_rejects(monkeypatch):
+    """RT_DECODE_KERNEL=pallas on a cache length the kernel cannot tile
+    raises the kernel's reason; it does not run XLA instead."""
+    from ray_tpu.ops.decode_attention import decode_attention
+
+    q = jnp.ones((2, 4, 32), jnp.float32)
+    cache = jnp.ones((2, 100, 4, 32), jnp.float32)
+    lens = jnp.asarray([5, 100], jnp.int32)
+    monkeypatch.setenv("RT_DECODE_KERNEL", "pallas")
+    with pytest.raises(ValueError, match="lane-aligned"):
+        decode_attention(q, cache, cache, lens)
 
 
 @pytest.mark.parametrize("causal", [True, False])
 def test_ring_attention_matches_full(causal):
     """4-way sp sharding on the CPU mesh: ring attention must equal
     single-device attention on the gathered sequence."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     devs = np.array(jax.devices()[:4])
@@ -171,7 +276,7 @@ def test_ring_attention_matches_full(causal):
 def test_ulysses_attention_matches_full(causal):
     """4-way Ulysses sequence parallelism (all-to-all head sharding) must
     equal single-device attention on the gathered sequence."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     from ray_tpu.ops.ulysses import ulysses_attention

@@ -88,9 +88,44 @@ def test_transformer_sharded_matches_single_device():
     shardings = jax.tree_util.tree_map(lambda s: NamedSharding(mesh, s), pspecs)
     params_s = jax.tree_util.tree_map(jax.device_put, params, shardings)
     tokens_s = jax.device_put(tokens, NamedSharding(mesh, P(("dp", "fsdp"), None)))
-    with mesh:
+    with jax.set_mesh(mesh):
         loss = float(jax.jit(lambda p, t: tfm.loss_fn(model, p, t))(params_s, tokens_s))
     assert abs(loss - ref_loss) < 1e-4
+
+
+@pytest.mark.parametrize("enter", [jax.set_mesh, lambda mesh: mesh],
+                         ids=["set_mesh", "with_mesh"])
+def test_seq_shard_constraint_is_applied(enter):
+    """The sequence-parallel constraint between blocks must really be there
+    under a dp/sp mesh, entered either way JAX 0.9.0 allows. A dropped
+    constraint leaves every loss equal, so the loss tests cannot see it:
+    this one looks at the sharding and at the traced program."""
+    from ray_tpu.models import transformer as tfm
+
+    cfg = tfm.TransformerConfig(vocab_size=256, d_model=64, n_layers=2, n_heads=4,
+                                n_kv_heads=4, d_ff=172, max_seq=32, dtype=jnp.float32)
+    model = tfm.Transformer(cfg)
+    tokens = jnp.zeros((4, 16), jnp.int32)
+    params = model.init(jax.random.PRNGKey(0), tokens)
+    mesh = build_mesh(MeshConfig(dp=2, sp=2, tp=2))
+    with enter(mesh):
+        out = jax.jit(tfm._seq_shard)(jnp.zeros((4, 16, 64)))
+        jaxpr = jax.make_jaxpr(lambda p, t: model.apply(p, t))(params, tokens)
+    # fsdp has size 1 here, so XLA's normalised spec leaves it out.
+    assert out.sharding.spec == P("dp", "sp")
+    assert out.sharding.shard_shape(out.shape) == (2, 8, 64)
+    assert str(jaxpr).count("sharding_constraint") == cfg.n_layers
+
+
+def test_seq_shard_skips_by_rule_without_the_axes():
+    """No mesh, or a mesh without dp/fsdp/sp (a tp-only serving mesh): the
+    constraint is skipped by an explicit test, not by a swallowed error."""
+    from ray_tpu.models import transformer as tfm
+
+    x = jnp.zeros((4, 16, 64))
+    assert "sharding_constraint" not in str(jax.make_jaxpr(tfm._seq_shard)(x))
+    with jax.set_mesh(local_mesh(8, axis="tp")):
+        assert "sharding_constraint" not in str(jax.make_jaxpr(tfm._seq_shard)(x))
 
 
 def test_gqa_attention_matches_mha_expansion():
@@ -136,7 +171,7 @@ def test_pipeline_matches_sequential():
         np.random.RandomState(0).randint(0, cfg.vocab_size, (8, 17)), jnp.int32)
     mesh = Mesh(np.array(jax.devices()[:2]).reshape(2), ("pp",))
     loss_fn = pipeline_loss_fn(cfg, mesh)
-    with mesh:
+    with jax.set_mesh(mesh):
         loss, grads = jax.jit(jax.value_and_grad(loss_fn))(params, tokens)
     ref_loss = float(reference_loss(cfg, params, tokens))
     assert abs(float(loss) - ref_loss) < 1e-5
@@ -169,7 +204,7 @@ def test_moe_ep_sharding_matches_single_device():
         lambda s: NamedSharding(mesh, s), tfm.param_specs(params))
     params_s = jax.tree_util.tree_map(jax.device_put, params, shardings)
     tokens_s = jax.device_put(tokens, NamedSharding(mesh, P(("dp", "fsdp"), None)))
-    with mesh:
+    with jax.set_mesh(mesh):
         loss = float(jax.jit(
             lambda p, t: tfm.loss_fn(model, p, t))(params_s, tokens_s))
     assert abs(loss - ref) < 1e-4

@@ -515,7 +515,7 @@ def test_serve_streaming_trace_accounts_request_wall_time(monkeypatch,
         assert covered >= 0.9 * wall, (
             f"spans cover only {covered / wall:.1%} of the request's "
             f"{wall * 1e3:.0f}ms wall time")
-        # Per-decode-iteration host syncs: the BENCH_r05 host-link cost,
+        # Per-decode-iteration host syncs: the host-link cost,
         # individually visible (>= 2 iterations for 24 tokens at chunk 4 /
         # depth 4).
         syncs = [s for s in spans if s["n"] == "engine.host_sync"]
