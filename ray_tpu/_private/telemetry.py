@@ -464,8 +464,13 @@ def jax_profile(seconds: float) -> dict:
 
     seconds = max(0.05, float(seconds))
     d = tempfile.mkdtemp(prefix="rt-jaxprof-")
+    # The Python tracer stays off: with it a second of trace stalled the
+    # traced process's threads for half a minute (PERF.md). The host tracer
+    # stays on: the engine's `TraceAnnotation`s are its events.
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
     try:
-        jax.profiler.start_trace(d)
+        jax.profiler.start_trace(d, profiler_options=options)
         time.sleep(seconds)
         jax.profiler.stop_trace()
         buf = io.BytesIO()

@@ -119,6 +119,9 @@ class GenStream:
         # prefill, chunk dispatch, host-sync readback — to the submitting
         # request's trace, making each per-chunk host round trip visible.
         self.trace: Optional[tuple] = None
+        # The stage of its life inside the engine the request is in, as
+        # (start, attributes): set only while `trace` is (see _stage_begin).
+        self._stage: Optional[tuple] = None
 
     def close(self):
         """Consumer abandoned the request (client disconnect): the engine
@@ -198,6 +201,7 @@ def _make_sampler(vocab: int):
     import jax
     import jax.numpy as jnp
 
+    @jax.named_scope("sampler")  # its name in a device trace
     def sample(logits, keys, temp, top_k, top_p):
         """logits [B, V] f32; keys [B, 2] uint32; temp/top_k/top_p [B].
         temp <= 0 -> greedy. top_k <= 0 -> disabled. top_p >= 1 -> disabled
@@ -231,6 +235,84 @@ class _Slot:
         self.sampling = sampling
         self.remaining = sampling.max_tokens
         self.emitted = 0
+
+
+# ------------------------------------------------------ engine tracing
+# A traced request's life inside the engine is four spans that follow one
+# another without a hole (README "Tracing & timeline"): engine.queue,
+# engine.prefill, engine.ready_wait, engine.first_token. The stream carries
+# the open stage's start; a stage's span is recorded when it ends. Called
+# only for streams whose `trace` is set.
+def _stage_begin(stream: GenStream, now: float, **attrs) -> None:
+    stream._stage = (now, attrs)
+
+
+def _stage_end(stream: GenStream, name: str, now: float, **attrs) -> None:
+    stage, stream._stage = stream._stage, None
+    if stage is not None:
+        _tracing.record_span_in(stream.trace, name, "engine", stage[0], now,
+                                {**stage[1], **attrs})
+
+
+class _Phases:
+    """One pass of the scheduler loop on two clocks, made only while
+    tracing is on. Each phase (admit, dispatch, sync, deliver, idle_wait)
+    is a `jax.profiler.TraceAnnotation` named `engine.<phase>`: outside a
+    profiler session that is a check of one flag, inside one the event
+    lands on this thread's line of the trace's host plane, on the device
+    planes' clock. The same boundaries, on the wall clock, add up to the
+    attributes of the pass's one `engine.iteration` span."""
+
+    __slots__ = ("_profiler", "_ann", "_name", "_t", "t0", "ms", "idle_ms")
+
+    def __init__(self, profiler):
+        self._profiler = profiler  # jax.profiler
+        self._ann = self._name = None
+        self._t = self.t0 = 0.0
+        self.ms: dict = {}
+        self.idle_ms = 0.0  # waited since the last recorded pass
+
+    def begin(self, name: str, **kw) -> float:
+        """Close the open phase and open `name` at one instant; returns it."""
+        now = self.end()
+        self._ann = self._profiler.TraceAnnotation("engine." + name, **kw)
+        self._ann.__enter__()
+        self._name, self._t = name, now
+        return now
+
+    def end(self, record=None) -> float:
+        """Close the open phase. `record(now)` runs before its annotation
+        ends, so that on the profiler's clock it is part of the phase."""
+        now = time.time()
+        ann, self._ann = self._ann, None
+        if ann is not None:
+            ms = (now - self._t) * 1e3
+            if self._name == "idle_wait":
+                self.idle_ms += ms
+            else:
+                self.ms[self._name] = self.ms.get(self._name, 0.0) + ms
+        if record is not None:
+            record(now)
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        return now
+
+    def start_pass(self) -> None:
+        self.ms = {}
+        self.t0 = self.begin("admit")
+
+    def end_pass(self, ctx: Optional[tuple], **counts) -> None:
+        """One `engine.iteration` span under `ctx` for the pass that ends."""
+        def record(now):
+            attrs = {k + "_ms": round(self.ms.get(k, 0.0), 3)
+                     for k in ("admit", "dispatch", "sync", "deliver")}
+            attrs["idle_ms"] = round(self.idle_ms, 3)
+            attrs.update(counts)
+            self.idle_ms = 0.0
+            _tracing.record_span_in(ctx, "engine.iteration", "engine",
+                                    self.t0, now, attrs)
+
+        self.end(record)
 
 
 # ------------------------------------------------------- stage slicing
@@ -289,6 +371,7 @@ def make_stage_net(mcfg, layers: tuple, first: bool, last: bool):
     stage_param_slice output applies directly and a 1-stage net is
     numerically the full Transformer."""
     import flax.linen as nn
+    import jax
     import jax.numpy as jnp
 
     from ray_tpu.models.transformer import Block, RMSNorm
@@ -308,8 +391,10 @@ def make_stage_net(mcfg, layers: tuple, first: bool, last: bool):
                                                    decode=decode)
             if last:
                 x = RMSNorm(name="final_norm")(x)
-                x = jnp.einsum("bsd,vd->bsv", x,
-                               emb.astype(mcfg.dtype)).astype(jnp.float32)
+                with jax.named_scope("lm_head"):
+                    x = jnp.einsum(
+                        "bsd,vd->bsv", x,
+                        emb.astype(mcfg.dtype)).astype(jnp.float32)
             return x
 
     return _StageNet()
@@ -545,6 +630,9 @@ class ContinuousEngine:
             if not self._running:
                 raise RuntimeError("engine is shut down")
             self._streams.add(stream)
+            if stream.trace is not None:
+                _stage_begin(stream, time.time(),
+                             pending=self._pending.qsize())
             self._pending.put((prompt, sampling, stream))
             self._lock.notify_all()
         return stream
@@ -621,18 +709,31 @@ class ContinuousEngine:
         lb = self._bucket(plen)
         toks = np.zeros((1, lb), np.int32)
         toks[0, :plen] = prompt
+        ann = None
+        if stream.trace is not None:
+            ann = self._jax.profiler.TraceAnnotation(
+                "engine.prefill_dispatch")
+            ann.__enter__()
         t_adm = time.time()
-        last_logits, cache_slice = self._prefill(
-            self.params, jnp.asarray(toks), plen)
-        key = self._jax.random.fold_in(
-            self._jax.random.PRNGKey(sampling.seed), stream.request_id)
-        first = self._sample1(
-            last_logits, key,
-            jnp.float32(sampling.temperature),
-            jnp.int32(sampling.top_k), jnp.float32(sampling.top_p))
+        try:
+            last_logits, cache_slice = self._prefill(
+                self.params, jnp.asarray(toks), plen)
+            key = self._jax.random.fold_in(
+                self._jax.random.PRNGKey(sampling.seed), stream.request_id)
+            first = self._sample1(
+                last_logits, key,
+                jnp.float32(sampling.temperature),
+                jnp.int32(sampling.top_k), jnp.float32(sampling.top_p))
+        finally:
+            if ann is not None:
+                ann.__exit__(None, None, None)
+        # The HOST's dispatch of the prefill programs (asynchronous: the
+        # device may run them later). The device's prefill time is
+        # `jit_prefill` in a device trace. The benchmark's `admit_wait_ms`
+        # reads this span's start.
         _tracing.record_span_in(
             stream.trace, "engine.prefill", "engine", t_adm, time.time(),
-            {"prompt_len": plen})
+            {"prompt_len": plen, "bucket": lb, "what": "dispatch"})
         return first, cache_slice, self._jax.random.fold_in(key, 1)
 
     def _prefill_loop(self):
@@ -653,6 +754,8 @@ class ContinuousEngine:
                     return
                 continue
             prompt, sampling, stream = item
+            if stream.trace is not None:
+                _stage_end(stream, "engine.queue", time.time())
             if not self._running:
                 # Shutdown raced the pop: terminate the stream instead of
                 # compiling/dispatching a prefill nobody will consume (a
@@ -678,6 +781,8 @@ class ContinuousEngine:
                 self._finish_stream(stream, e)
                 continue
             with self._lock:
+                if stream.trace is not None:
+                    _stage_begin(stream, time.time(), ready=len(self._ready))
                 self._ready.append(entry)
                 self._prefill_inflight -= 1
                 self._lock.notify_all()
@@ -687,6 +792,13 @@ class ContinuousEngine:
         """Install one prefilled request into batch row `slot` (scheduler
         thread only — this is the chunk-boundary splice point): scatter
         the cache slice, set the device mirrors, book the slot."""
+        if stream.trace is not None:
+            now = time.time()
+            # (inline admission leaves no stage open here: no ready_wait)
+            _stage_end(stream, "engine.ready_wait", now,
+                       active=self._n_active)
+            _stage_begin(stream, now, slot=slot,
+                         chunks_in_flight=len(self._q_chunks))
         if self._cache is None:
             self._cache = self._init_cache()
         self._cache = self._place(self._cache, cache_slice,
@@ -742,7 +854,11 @@ class ContinuousEngine:
         if finish is None and st.remaining <= 0:
             finish = "length"
         if out:
+            first = st.emitted == len(out) and st.stream.trace is not None
+            t_put = time.time() if first else 0.0
             st.stream._q.put(out)
+            if first:
+                _stage_end(st.stream, "engine.first_token", t_put)
             _count_tokens(len(out))
         if finish is not None:
             st.stream.finish_reason = finish
@@ -795,7 +911,15 @@ class ContinuousEngine:
         chunk still steps (the _cooling set)."""
         import jax.numpy as jnp
 
+        phases = _Phases(self._jax.profiler)
         while self._running:
+            # Tracing on: the pass's phases on the host's and the
+            # profiler's clock (_Phases). Off: this one read, nothing else.
+            ph = phases if _tracing.enabled() else None
+            iter_ctx = None  # the traced request the pass's span is bound to
+            spliced = dispatched = 0
+            if ph is not None:
+                ph.start_pass()
             # ---- 1. admissions: splice prefilled requests at the chunk
             # boundary (prefill lane), or run the classic inline admission
             # (lane off). Either way nothing here reads from device.
@@ -816,6 +940,7 @@ class ContinuousEngine:
                     try:
                         self._splice(free, plen, sampling, stream, first,
                                      cache_slice, key)
+                        spliced += 1
                     except Exception as e:
                         self._finish_stream(stream, e)
             else:
@@ -830,8 +955,11 @@ class ContinuousEngine:
                     if item is None:
                         continue
                     prompt, sampling, stream = item
+                    if stream.trace is not None:
+                        _stage_end(stream, "engine.queue", time.time())
                     try:
                         self._admit_async(free, prompt, sampling, stream)
+                        spliced += 1
                     except Exception as e:  # bad request or engine failure
                         self._finish_stream(stream, e)
             # First tokens are NOT read at admission: they join the next
@@ -839,15 +967,22 @@ class ContinuousEngine:
             # own blocking host sync).
             if (self._n_active == 0 and not self._q_chunks
                     and not self._pending_firsts):
+                if ph is not None:
+                    ph.begin("idle_wait")
                 with self._lock:
                     if (self._running and self._pending.empty()
                             and not self._ready
                             and self._prefill_inflight == 0):
                         self._lock.wait(timeout=0.1)
+                if ph is not None:
+                    ph.end()  # no span: the next pass carries its idle_ms
                 continue
             # ---- 2. fill the pipeline: dispatch up to pipeline_depth
             # chunks back to back (dispatches are asynchronous and nearly
             # free; only the readback costs a round trip)
+            if ph is not None:
+                # wall_ns ties the spans' wall clock to the trace's own.
+                ph.begin("dispatch", wall_ns=time.time_ns())
             while len(self._q_chunks) < self.pipeline_depth:
                 if (self._prefill_lane and self._ready
                         and self._n_active < self.max_batch
@@ -910,6 +1045,8 @@ class ContinuousEngine:
                     for i in active:
                         self._pending_toks[i] += n
                     self._q_chunks.append((toks_out, active, n, object()))
+                    dispatched += 1
+                    iter_ctx = iter_ctx or tctx
                 except Exception as e:
                     logger.exception("llm engine decode chunk failed")
                     for i in active:
@@ -951,7 +1088,7 @@ class ContinuousEngine:
                              if self._slots[s] is not None
                              and self._slots[s].stream.trace is not None),
                             None)
-                t_sync = time.time()
+                t_sync = ph.begin("sync") if ph is not None else time.time()
                 try:
                     all_np = np.asarray(
                         parts[0] if len(parts) == 1
@@ -967,8 +1104,11 @@ class ContinuousEngine:
                                 self._slots[i].stream._q.put(e)
                                 self._retire(i)
                     all_np = None
+                # sync_ms of the pass is engine.host_sync's own interval.
+                t_end = ph.begin("deliver") if ph is not None else None
+                iter_ctx = iter_ctx or sync_ctx
                 if sync_ctx is not None and all_np is not None:
-                    t_end = time.time()
+                    t_end = t_end or time.time()
                     _tracing.record_span_in(
                         sync_ctx, "engine.host_sync", "engine", t_sync,
                         t_end, {"chunks": len(q),
@@ -976,7 +1116,7 @@ class ContinuousEngine:
                     try:
                         from ray_tpu.util import metrics as _metrics
 
-                        _metrics.DECODE_STEP_SECONDS.observe(t_end - t_sync)
+                        _metrics.LLM_HOST_SYNC_SECONDS.observe(t_end - t_sync)
                     except Exception:
                         pass
                 off = 0
@@ -1004,3 +1144,7 @@ class ContinuousEngine:
                     off += pn
                     self._cooling = {s: t for s, t in self._cooling.items()
                                      if t is not tag}
+            if ph is not None:
+                ph.end_pass(iter_ctx, spliced=spliced, chunks=dispatched,
+                            in_flight=len(self._q_chunks),
+                            active=self._n_active)

@@ -123,20 +123,23 @@ class Attention(nn.Module):
 
             out = decode_attention(q[:, 0], keys, vals, pos[:, 0] + 1)
             return out[:, None].astype(cfg.dtype)
-        if cfg.n_kv_heads < cfg.n_heads:  # GQA: broadcast kv heads
-            rep = cfg.n_heads // cfg.n_kv_heads
-            keys = jnp.repeat(keys, rep, axis=2)
-            vals = jnp.repeat(vals, rep, axis=2)
-        scores = jnp.einsum("bshd,bthd->bhst", q.astype(jnp.float32),
-                            keys.astype(jnp.float32)) / (cfg.head_dim ** 0.5)
-        # cache row t is visible to query i of sequence b iff t <= pos[b, i]
-        t_pos = jnp.arange(cfg.max_seq)[None, None, None, :]
-        q_pos = pos[:, None, :, None]
-        scores = jnp.where(t_pos <= q_pos, scores, -jnp.inf)
-        probs = jax.nn.softmax(scores, axis=-1)
-        out = jnp.einsum("bhst,bthd->bshd", probs,
-                         vals.astype(jnp.float32))
-        return out.astype(cfg.dtype)
+        with jax.named_scope("prefill_attention"):
+            if cfg.n_kv_heads < cfg.n_heads:  # GQA: broadcast kv heads
+                rep = cfg.n_heads // cfg.n_kv_heads
+                keys = jnp.repeat(keys, rep, axis=2)
+                vals = jnp.repeat(vals, rep, axis=2)
+            scores = jnp.einsum(
+                "bshd,bthd->bhst", q.astype(jnp.float32),
+                keys.astype(jnp.float32)) / (cfg.head_dim ** 0.5)
+            # cache row t is visible to query i of sequence b iff
+            # t <= pos[b, i]
+            t_pos = jnp.arange(cfg.max_seq)[None, None, None, :]
+            q_pos = pos[:, None, :, None]
+            scores = jnp.where(t_pos <= q_pos, scores, -jnp.inf)
+            probs = jax.nn.softmax(scores, axis=-1)
+            out = jnp.einsum("bhst,bthd->bshd", probs,
+                             vals.astype(jnp.float32))
+            return out.astype(cfg.dtype)
 
 
 class SwiGLU(nn.Module):
@@ -226,7 +229,9 @@ class Transformer(nn.Module):
             x = Block(cfg, name=f"layer_{i}")(x, positions, decode=decode)
         x = RMSNorm(name="final_norm")(x)
         # Tied output head (vocab-sharded matmul over tp).
-        return jnp.einsum("bsd,vd->bsv", x, emb.astype(cfg.dtype)).astype(jnp.float32)
+        with jax.named_scope("lm_head"):
+            return jnp.einsum("bsd,vd->bsv", x,
+                              emb.astype(cfg.dtype)).astype(jnp.float32)
 
 
 def _seq_shard(x):

@@ -217,7 +217,9 @@ def decode_attention(q, k_cache, v_cache, lengths, *, interpret: bool = False):
             force=str(CONFIG.decode_kernel).lower())
     _state_once(f"decode attention: {impl} ({why}; q {tuple(q.shape)}, "
                 f"cache {tuple(k_cache.shape)} {k_cache.dtype})")
-    if impl == "pallas":
-        return decode_attention_pallas(
-            q, k_cache, v_cache, lengths, interpret=interpret)
-    return _xla_decode_attention(q, k_cache, v_cache, lengths)
+    # One name for both paths in a device trace (operation metadata only).
+    with jax.named_scope("decode_attention"):
+        if impl == "pallas":
+            return decode_attention_pallas(
+                q, k_cache, v_cache, lengths, interpret=interpret)
+        return _xla_decode_attention(q, k_cache, v_cache, lengths)

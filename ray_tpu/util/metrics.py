@@ -451,15 +451,16 @@ TASK_TIMEOUTS = Counter(
 #: Tracing-plane latency histograms (README "Tracing & timeline"), observed
 #: ONLY inside sampled trace contexts — the unsampled hot path mints no
 #: records. Frame RTT catches control-plane hops a span tree summarizes;
-#: decode-step is the serve->engine host-link sync (each observation is
-#: one engine host readback round trip).
+#: host-sync is the engine's blocking read of its oldest decode chunk (the
+#: `engine.host_sync` span's interval: a wait for the device, or for
+#: nothing; not the time of a decode step).
 RPC_FRAME_SECONDS = Histogram(
     "rt_rpc_frame_seconds",
     description="traced RPC request round-trip time",
     boundaries=[0.0002, 0.001, 0.005, 0.02, 0.1, 0.5, 2.0, 10.0],
     tag_keys=("method",))
-DECODE_STEP_SECONDS = Histogram(
-    "rt_decode_step_seconds",
+LLM_HOST_SYNC_SECONDS = Histogram(
+    "rt_llm_host_sync_seconds",
     description="llm engine host-sync readback duration per decode drain",
     boundaries=[0.0005, 0.002, 0.01, 0.05, 0.2, 1.0, 5.0])
 

@@ -1,0 +1,302 @@
+"""The engine's own tracing (README "Tracing & timeline", the engine's
+table): a traced request's four stages inside the engine as spans without a
+hole, the scheduler loop's passes as `engine.iteration` spans whose phases
+add up, the same phases as `TraceAnnotation`s on the profiler's clock, the
+names the decode step's parts carry in a device trace, and nothing of all
+that with RT_TRACING unset.
+
+The engine is driven in this process at a tiny size on the CPU; spans are
+caught where they are recorded (no cluster, no flusher). No pytest-timeout
+is installed: every wait below has a deadline of its own."""
+
+import glob
+import os
+import re
+import threading
+import time
+
+import pytest
+
+from ray_tpu._private import tracing
+from ray_tpu.llm import LLMConfig
+from ray_tpu.llm.engine import ContinuousEngine, SamplingParams
+
+CFG = dict(vocab_size=128, d_model=64, n_layers=2, n_heads=4, max_seq=64)
+STAGES = ["engine.queue", "engine.prefill", "engine.ready_wait",
+          "engine.first_token"]
+WAIT_S = 120.0
+
+
+@pytest.fixture
+def spans(monkeypatch):
+    """Tracing on in this process; every span recorded lands in the list."""
+    caught, lock = [], threading.Lock()
+
+    def record_span(trace_id, span_id, parent, name, kind, start, end,
+                    attrs=None):
+        with lock:
+            caught.append({"t": trace_id, "s": span_id, "p": parent,
+                           "n": name, "k": kind, "a": start, "b": end,
+                           "at": attrs or {},
+                           "tid": threading.get_ident()})
+
+    monkeypatch.setattr(tracing, "_ON", True)
+    monkeypatch.setattr(tracing, "record_span", record_span)
+    yield caught
+    tracing._ctx.set(None)
+
+
+@pytest.fixture
+def engine(request, monkeypatch):
+    lane = getattr(request, "param", True)
+    monkeypatch.setenv("RT_LLM_PREFILL_LANE", "1" if lane else "0")
+    eng = ContinuousEngine(LLMConfig(**CFG), max_batch=2, decode_chunk=4)
+    assert eng._prefill_lane is lane
+    yield eng
+    eng.shutdown()
+    assert not any(t.is_alive() for t in eng._threads)
+
+
+def serve(eng, n, max_tokens=12, traced=True):
+    """n requests at once on 2 slots, each under a trace context of its
+    own. Returns [(context, stream, time its first token reached us)]."""
+    out = []
+    for i in range(n):
+        ctx = (f"{i:032x}", f"{i:016x}") if traced else None
+        tracing._ctx.set(ctx)
+        stream = eng.submit([1 + i, 2, 3, 4 + i],
+                            SamplingParams(max_tokens=max_tokens,
+                                           temperature=0.7, top_k=8, seed=i))
+        out.append([ctx, stream, None])
+    tracing._ctx.set(None)
+    for row in out:
+        tokens = [row[1].next(timeout=WAIT_S)]
+        row[2] = time.time()
+        deadline = time.monotonic() + WAIT_S
+        while time.monotonic() < deadline:
+            try:
+                tokens.append(row[1].next(timeout=WAIT_S))
+            except StopIteration:
+                break
+        assert len(tokens) == max_tokens
+    return out
+
+
+def of_request(spans, ctx, names=STAGES):
+    return [s for s in spans if s["t"] == ctx[0] and s["n"] in names]
+
+
+@pytest.mark.parametrize("engine", [True, False], indirect=True,
+                         ids=["prefill-lane", "inline-admission"])
+def test_a_traced_request_has_each_stage_once_in_order(engine, spans):
+    want = [n for n in STAGES
+            if engine._prefill_lane or n != "engine.ready_wait"]
+    for ctx, stream, t_first in serve(engine, 5):
+        mine = sorted(of_request(spans, ctx), key=lambda s: s["a"])
+        assert [s["n"] for s in mine] == want
+        for s in mine:
+            assert s["k"] == "engine" and s["p"] == ctx[1]
+            assert s["b"] >= s["a"]
+        for before, after in zip(mine, mine[1:]):
+            assert before["b"] <= after["a"] + 1e-6, (before, after)
+        assert mine[-1]["b"] <= t_first
+        assert stream._stage is None  # every stage was closed
+        by_name = {s["n"]: s for s in mine}
+        assert by_name["engine.prefill"]["at"] == {
+            "prompt_len": 4, "bucket": 8, "what": "dispatch"}
+        assert set(by_name["engine.queue"]["at"]) == {"pending"}
+        assert set(by_name["engine.first_token"]["at"]) == {
+            "slot", "chunks_in_flight"}
+        if engine._prefill_lane:
+            assert set(by_name["engine.ready_wait"]["at"]) == {
+                "ready", "active"}
+
+
+def test_the_stages_leave_no_hole_from_submit_to_the_first_token(engine,
+                                                                 spans):
+    """queue + prefill + ready_wait + first_token cover the request's time
+    inside the engine but for the instants between two stamps."""
+    t_submit = time.time()
+    (ctx, _stream, t_first), = serve(engine, 1)
+    mine = of_request(spans, ctx)
+    covered = sum(s["b"] - s["a"] for s in mine)
+    start, end = min(s["a"] for s in mine), max(s["b"] for s in mine)
+    assert t_submit <= start and end <= t_first
+    assert covered >= 0.95 * (end - start) or (end - start) - covered < 0.005
+
+
+@pytest.mark.parametrize("engine", [True, False], indirect=True,
+                         ids=["prefill-lane", "inline-admission"])
+def test_an_iteration_spans_phases_add_up(engine, spans):
+    served = serve(engine, 4, max_tokens=20)
+    its = [s for s in spans if s["n"] == "engine.iteration"]
+    assert len(its) >= 5
+    ctxs = {ctx[0]: ctx[1] for ctx, _s, _t in served}
+    for it in its:
+        at = it["at"]
+        assert set(at) == {"admit_ms", "dispatch_ms", "sync_ms",
+                           "deliver_ms", "idle_ms", "spliced", "chunks",
+                           "in_flight", "active"}
+        assert it["k"] == "engine" and ctxs[it["t"]] == it["p"]
+        parts = (at["admit_ms"] + at["dispatch_ms"] + at["sync_ms"]
+                 + at["deliver_ms"])
+        assert parts <= (it["b"] - it["a"]) * 1e3 + 0.01, it
+        assert min(at.values()) >= 0
+    # every splice and every chunk is counted in exactly one pass
+    assert sum(it["at"]["spliced"] for it in its) == 4
+    chunks = [s for s in spans if s["n"] == "engine.dispatch_chunk"]
+    assert sum(it["at"]["chunks"] for it in its) == len(chunks)
+    # the sync_ms of a pass is the interval of its engine.host_sync
+    syncs = [s for s in spans if s["n"] == "engine.host_sync"]
+    assert syncs
+    for sync in syncs:
+        inside = [it for it in its
+                  if it["a"] <= sync["a"] and sync["b"] <= it["b"]]
+        assert len(inside) == 1, sync
+        assert inside[0]["at"]["sync_ms"] == pytest.approx(
+            (sync["b"] - sync["a"]) * 1e3, abs=0.002)
+
+
+def test_idle_time_is_carried_by_the_next_recorded_pass(engine, spans):
+    serve(engine, 1)
+    time.sleep(0.35)  # the scheduler waits with nothing to do
+    serve(engine, 1)
+    its = [s for s in spans if s["n"] == "engine.iteration"]
+    assert max(it["at"]["idle_ms"] for it in its) >= 200
+    # a pass that only waited records no span of its own
+    assert all(it["at"]["chunks"] or it["at"]["spliced"]
+               or it["at"]["sync_ms"] > 0 for it in its)
+
+
+class Counting:
+    """Stands in for jax.profiler.TraceAnnotation and counts its uses."""
+
+    entered = 0
+
+    def __init__(self, name, **kw):
+        self.name = name
+
+    def __enter__(self):
+        Counting.entered += 1
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+@pytest.mark.parametrize("engine", [True, False], indirect=True,
+                         ids=["prefill-lane", "inline-admission"])
+def test_with_tracing_off_the_engine_records_and_stamps_nothing(
+        engine, monkeypatch):
+    import jax
+
+    recorded = []
+    monkeypatch.setattr(tracing, "_ON", False)
+    monkeypatch.setattr(tracing, "record_span",
+                        lambda *a, **kw: recorded.append(a))
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Counting)
+    Counting.entered = 0
+    for ctx, stream, _t in serve(engine, 3, traced=False):
+        assert stream.trace is None and stream._stage is None
+    assert recorded == []
+    assert Counting.entered == 0
+
+
+def test_with_tracing_on_every_phase_is_an_annotation(engine, spans,
+                                                      monkeypatch):
+    import jax
+
+    names = []
+
+    class Named(Counting):
+        def __enter__(self):
+            names.append(self.name)
+            return self
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Named)
+    serve(engine, 2)
+    assert {"engine.admit", "engine.dispatch", "engine.sync",
+            "engine.deliver", "engine.prefill_dispatch"} <= set(names)
+    assert names.count("engine.prefill_dispatch") == 2
+
+
+def test_a_profile_of_a_traced_engine_holds_its_phases_in_a_host_plane(
+        engine, spans, tmp_path):
+    import jax
+
+    serve(engine, 1)  # builds the programs outside the profile
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        serve(engine, 2, max_tokens=16)
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                      recursive=True)
+    data = jax.profiler.ProfileData.from_file(path)
+    found = {}
+    for plane in data.planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("engine."):
+                    found.setdefault(ev.name, []).append((plane.name, ev))
+    assert {"engine.dispatch", "engine.sync", "engine.admit",
+            "engine.deliver", "engine.prefill_dispatch"} <= set(found)
+    assert {plane for plane, _ev in found["engine.sync"]} == {"/host:CPU"}
+    # engine.dispatch carries the wall clock, so that the spans' clock and
+    # the trace's can be tied together from the trace alone
+    plane, ev = found["engine.dispatch"][0]
+    wall_ns = dict(ev.stats)["wall_ns"]
+    assert abs(wall_ns - time.time_ns()) < 600e9
+    assert ev.duration_ns > 0
+
+
+def scopes_of(lowered) -> set:
+    """Every component of every operation's name in a compiled program."""
+    text = lowered.compile().as_text()
+    return {part for name in re.findall(r'op_name="([^"]+)"', text)
+            for part in name.split("/")}
+
+
+def test_the_decode_steps_parts_carry_their_names_in_the_program(engine):
+    """What `attn_dev_share` reads from a device trace: operation metadata,
+    so the program computes the same with or without the names."""
+    import jax.numpy as jnp
+
+    engine._cache = engine._init_cache()
+    chunk = engine._chunk.lower(
+        engine.params, engine._cache, engine._toks_dev, engine._lens_dev,
+        engine._keys, engine._temps_dev, engine._topks_dev,
+        engine._topps_dev, 2, False)
+    assert {"decode_attention", "mlp", "lm_head",
+            "sampler"} <= scopes_of(chunk)
+    prefill = engine._prefill.lower(
+        engine.params, jnp.zeros((1, 8), jnp.int32), 3)
+    assert {"prefill_attention", "mlp", "lm_head"} <= scopes_of(prefill)
+
+
+def test_a_capture_keeps_the_python_tracer_off_and_the_host_tracer_on(
+        monkeypatch):
+    import jax
+
+    from ray_tpu._private import telemetry
+
+    seen = {}
+
+    def start_trace(log_dir, profiler_options=None, **kw):
+        seen["options"] = profiler_options
+
+    monkeypatch.setattr(jax.profiler, "start_trace", start_trace)
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
+    rep = telemetry.jax_profile(0.05)
+    assert rep["mode"] == "jax" and rep["files"] == 0
+    assert seen["options"].python_tracer_level == 0
+    assert seen["options"].host_tracer_level > 0
+
+
+def test_the_host_sync_histogram_is_named_for_what_it_measures():
+    from ray_tpu.util import metrics
+
+    assert metrics.LLM_HOST_SYNC_SECONDS._name == "rt_llm_host_sync_seconds"
+    assert not hasattr(metrics, "DECODE_STEP_SECONDS")

@@ -29,16 +29,17 @@ from ray_tpu._private import rpc
 
 def _wait(pred, timeout: float, what: str):
     deadline = time.monotonic() + timeout
-    last = None
+    last = failed = None
     while time.monotonic() < deadline:
         try:
             last = pred()
             if last:
                 return last
-        except Exception:
-            pass
+        except Exception as e:  # noqa: BLE001 - named when the wait ends
+            failed = e
         time.sleep(0.2)
-    raise TimeoutError(f"timed out waiting for {what}")
+    raise TimeoutError(f"timed out waiting for {what} "
+                       f"(last result {last!r}, last failure {failed!r})")
 
 
 def _all_spans():
@@ -254,9 +255,9 @@ def _spawn_agent(controller_addr: str, session: str, num_cpus=2):
 def test_lease_failover_keeps_one_execute_span_per_attempt(monkeypatch):
     """Sever every owner->worker lease connection mid-batch (the PR 6
     failover + dedup-replay path): every ref still resolves, and the trace
-    plane shows EXACTLY one execute span per task, chained to that task's
-    submit span — the failover re-route neither loses nor duplicates
-    spans."""
+    plane shows EXACTLY one execute span per execution of a task, chained
+    to that task's submit span — the failover re-route neither loses nor
+    duplicates spans."""
     monkeypatch.setenv("RT_TRACING", "1")
     procs = []
     try:
@@ -305,6 +306,18 @@ def test_lease_failover_keeps_one_execute_span_per_attempt(monkeypatch):
         assert inj.sever("lease") >= 1, "no lease connections to sever"
         assert ray_tpu.get(refs, timeout=120) == list(range(n))
 
+        # What really executed, from the tasks' own marker file. The agent's
+        # dedup of a failover re-dispatch races the worker's report of the
+        # spec it was running (two connections, no order between them): on
+        # a loaded host a task may run twice, which
+        # test_chaos_direct_dispatch pins, not this test. The trace plane's
+        # part is one execute span per EXECUTION, each under the task's one
+        # submit span.
+        with open(log) as f:
+            ran = [int(ln) for ln in f if ln.strip()]
+        runs = {tid: ran.count(i) for i, tid in enumerate(task_ids)}
+        assert all(runs.values()), runs
+
         def _one_exec_each():
             spans = _all_spans()
             by_task: dict = {}
@@ -317,21 +330,25 @@ def test_lease_failover_keeps_one_execute_span_per_attempt(monkeypatch):
                     by_task.setdefault(t, []).append(s)
                 elif s["k"] == "submit":
                     subs[t] = s
-            if not all(tid in by_task for tid in task_ids):
+            late = [tid[:12] for tid in task_ids
+                    if len(by_task.get(tid, [])) < runs[tid]]
+            if late:  # a worker has not flushed its ring yet
                 return None
             for tid in task_ids:
                 exes = by_task[tid]
-                assert len(exes) == 1, (
-                    f"task {tid[:12]} has {len(exes)} execute spans "
-                    f"(failover duplicated or lost the execution)")
+                assert len(exes) == runs[tid], (
+                    f"task {tid[:12]} ran {runs[tid]} time(s) and has "
+                    f"{len(exes)} execute spans (the failover duplicated "
+                    f"a span): {exes}")
                 sub = subs.get(tid)
                 assert sub is not None
-                assert exes[0]["t"] == sub["t"]
-                assert exes[0]["p"] == sub["s"]
+                for exe in exes:
+                    assert exe["t"] == sub["t"]
+                    assert exe["p"] == sub["s"]
             return True
 
         _wait(_one_exec_each, 40,
-              "exactly one execute span per task after failover")
+              "one execute span per execution after failover")
     finally:
         try:
             ray_tpu.shutdown()
