@@ -365,6 +365,17 @@ def serve_phase(serve, LLMConfig, build_openai_app, chips: int) -> dict:
         require(st["served"] >= 1, f"replica {st['pid']} served no request")
         require(st["active"] == 0,
                 f"replica {st['pid']} still holds {st['active']} slots")
+        say(f"replica pid={st['pid']} kv cache {st['cache_layout']}; "
+            f"{st['cache_boundary_copies']} whole-leaf copies in its chunk "
+            f"program; peak device memory "
+            f"{st['memory_peak_bytes'] / 2**30:.2f} GiB")
+        # The cache must cross a program's boundary in the layout the
+        # decode loop computes in: a copy of a whole leaf there is a
+        # conversion paid by every chunk, whatever its length.
+        require(st["cache_boundary_copies"] == 0,
+                f"replica {st['pid']}: the chunk program copies whole cache "
+                f"leaves {st['cache_boundary_copies']} times "
+                f"(kv cache {st['cache_layout']})")
     built = (sum(s["compile_count"] for s in end)
              - sum(s["compile_count"] for s in mid))
     say(f"requests: {len(wave)} at once answered in {t_wave:.1f}s; token "

@@ -2284,7 +2284,7 @@ class Controller:
             rep = await nconn.call(
                 "profile_worker", worker_id=wid, seconds=seconds,
                 mode=a.get("mode") or "cpu", hz=a.get("hz"),
-                _timeout=seconds + 40.0)
+                _timeout=seconds + 110.0)
         except Exception as e:
             # Agent death/sever/timeout mid-capture follows the same
             # attributed-error contract as every other failure branch

@@ -12,7 +12,10 @@ it only accounts lease resources and brokers worker acquisition.
 Failure model (owner-based, like the reference TaskManager): a dead leased
 worker fails its in-flight specs back into the class queue (attempt++ up to
 max_retries), a `lease_invalid` push from the controller does the same, and
-`need_resources` returns idle leases so other demand can place.
+`need_resources` returns idle leases so other demand can place. Specs that
+fail over to the controller path need the dead lease's resources to run
+there, so their class asks for no new lease until they have resolved
+(`_hold_for_failover`).
 """
 
 from __future__ import annotations
@@ -52,6 +55,15 @@ CAP_PROBE_S = 0.25
 # one worker while a second node sits idle). Once the class holds the
 # cluster's proven capacity, the full DEPTH applies.
 RAMP_DEPTH = 4
+# How long severed specs wait at the owner before they are submitted on the
+# controller path. A severed worker that is alive reports the spec it is
+# still running to its node agent (`ltask_running`), whose dedup parks the
+# failover re-dispatch of that id; nothing orders that report before the
+# re-dispatch, which takes two hops more. While the owner leased the
+# severed worker's resources again at once, the re-dispatch waited for them
+# long enough; now that it does not (_hold_for_failover) this is the margin
+# (one sever test in 40 ran a spec twice under load without it, none with).
+FAILOVER_GRACE_S = 0.25
 
 _metrics_mod = None
 
@@ -105,7 +117,7 @@ class _Lease:
 
 class _Class:
     __slots__ = ("key", "resources", "strategy", "queue", "leases", "requesting",
-                 "depth", "cap", "cap_ts", "proven_cap")
+                 "depth", "cap", "cap_ts", "proven_cap", "failover")
 
     def __init__(self, key: tuple, spec: TaskSpec):
         self.key = key
@@ -124,6 +136,10 @@ class _Class:
         # answered short re-proves it; only a grant that actually GROWS
         # the set clears it), so steady-state pipelining never dips.
         self.proven_cap: int | None = None
+        # Specs of this class failed over to the controller path and not
+        # yet resolved; while any are, the class requests no new lease
+        # (_hold_for_failover).
+        self.failover = 0
         # SPREAD must place per task across nodes (reference spread policy),
         # so no pipelining: each task forces its own lease while the queue
         # is non-empty.
@@ -215,7 +231,7 @@ class LeaseManager:
             if assigned and not lease.flushing:
                 lease.flushing = True
                 asyncio.ensure_future(self._a_flush(lease))
-        if cls.queue and not cls.requesting:
+        if cls.queue and not cls.requesting and not cls.failover:
             outstanding = len(cls.queue) + sum(len(l.inflight) for l in live)
             want = min(max(1, CONFIG.lease_batch), outstanding)
             if cls.cap is not None:
@@ -266,6 +282,11 @@ class LeaseManager:
                 # capacity is unknown again — ramp shallow until the next
                 # short answer re-proves the ceiling.
                 cls.proven_cap = None
+        if cls.failover and rep["leases"]:
+            # Asked before a lease was severed, granted after: the
+            # resources are the failed-over specs' (_hold_for_failover).
+            await self._a_return([g["lease_id"] for g in rep["leases"]])
+            return
         for g in rep["leases"]:
             lease = _Lease(cls, g["lease_id"], g["worker_id"], g["node_id"],
                            tuple(g["address"]), g.get("incarnation"))
@@ -538,9 +559,40 @@ class LeaseManager:
                 f"in-flight spec(s) fail over to the controller path",
                 entity=(lease.lease_id, lease.worker_id),
                 attrs={"path": "owner_sever", "specs": len(failover)})
-            self.w.submit_specs_via_controller(failover)
+            self._hold_for_failover(lease.cls, failover)
+            asyncio.ensure_future(self._a_submit_failover(failover))
         if lease.cls.queue:
             self._pump(lease.cls)
+
+    async def _a_submit_failover(self, specs: list):
+        await asyncio.sleep(FAILOVER_GRACE_S)
+        if not self._shutdown:
+            self.w.submit_specs_via_controller(specs)
+
+    def _hold_for_failover(self, cls: _Class, specs: list):
+        """Keep `cls` from leasing until `specs` have resolved on the
+        controller path. They can only run there on resources no lease
+        holds, and what the severed lease gave back is all a full cluster
+        has: an owner that leased it again would fill the new worker with
+        younger specs that wait, on the worker, for the failed-over specs'
+        results, while those wait at the controller for the worker's
+        resources - forever (a 2-CPU cluster, one of two leased workers
+        SIGKILLed mid-shuffle: one run in three). Live leases keep
+        draining the queue meanwhile."""
+
+        def resolved():
+            cls.failover -= 1
+            if not cls.failover and not self._shutdown:
+                self._pump(cls)
+
+        for spec in specs:
+            oids = spec.return_object_ids()  # none: nothing waits for it
+            res = self.w._resolutions.get(oids[0]) if oids else None
+            # Watchers run on the resolving thread; the count is the IO
+            # loop's. An unresolved resolution outlives its ref (_free).
+            if res is not None and res.add_watcher(
+                    lambda: self.w.io.loop.call_soon_threadsafe(resolved)):
+                cls.failover += 1
 
     def task_status(self, task_id: str) -> dict | None:
         """Best-effort status of a task this owner submitted on the direct

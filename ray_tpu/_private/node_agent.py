@@ -843,7 +843,7 @@ class NodeAgent:
         try:
             rep = await slot.conn.call(
                 "profile", mode=mode, seconds=seconds, hz=a.get("hz"),
-                _timeout=seconds + 30.0)
+                _timeout=seconds + 100.0)
         except Exception as e:
             return {"found": False,
                     "error": f"worker {wid[:12]} died or failed mid-capture "

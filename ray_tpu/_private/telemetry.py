@@ -333,8 +333,13 @@ _MAX_PROFILE_SAMPLES = 20_000
 def clamp_profile_seconds(seconds) -> float:
     """One capture-window clamp shared by every hop of the profile path
     (controller -> agent -> worker): 0.05s floor, 300s cap, 5s default.
-    The hops' RPC timeout margins (+40s controller, +30s agent) are tuned
-    against these constants — change them here, nowhere else."""
+    The hops' RPC timeout margins (+110s controller, +100s agent) are tuned
+    against these constants — change them here, nowhere else. The margins
+    are what a `jax` capture needs after its window: on the v5e a serving
+    replica takes 25-30 s to stop a one-second trace, zip it (27 MB) and
+    hand it on, and one capture in ten went over the 30 s it used to be
+    given (PERF.md section 6, PR 27). A worker that dies fails the call at
+    once through its closed connection, whatever the margin."""
     try:
         seconds = float(seconds)
     except (TypeError, ValueError):

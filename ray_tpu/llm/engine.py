@@ -11,6 +11,12 @@ engine workers via vllm_models.py:123-137). TPU-native design:
   slot) and leave independently — no lockstep. Fixed shapes mean every
   decode step is the same compiled XLA program; a TPU cannot afford
   vLLM's dynamic block tables, slots are the idiomatic equivalent.
+- **Cache layout**: the cache crosses every program boundary in the
+  on-device layout the decode loop computes in. The engine asks the
+  compiler for it once, and where rows as wide as their tiles make it the
+  default layout (head_dim 96 in 128 lanes on the v5e) it widens the rows
+  (`_probe_cache_row`); no chunk program then converts the cache on its
+  way in or out.
 - **Chunked decode**: between admission points the engine runs
   `decode_chunk` single-token steps under ONE lax.scan dispatch,
   amortizing host->device latency while bounding join latency to a few
@@ -35,9 +41,11 @@ engine workers via vllm_models.py:123-137). TPU-native design:
 from __future__ import annotations
 
 import collections
+import dataclasses
 import itertools
 import logging
 import queue
+import re
 import threading
 import time
 from dataclasses import dataclass
@@ -524,21 +532,68 @@ class ContinuousEngine:
         import jax
         import jax.numpy as jnp
 
-        model = self.model
+        from ray_tpu.models.transformer import Transformer
+
         sampler = self._sampler
 
+        def make_chunk(model):
+            def chunk(params, cache, toks, lengths, keys, temp, top_k, top_p,
+                      n: int, greedy: bool):
+                """n in-flight decode steps under one scan. toks/lengths
+                [B]; returns (cache, keys, tokens [B, n], lengths [B]).
+                greedy=True compiles an argmax-only variant: the sampler's
+                two full-vocab sorts per step are pure waste when no active
+                slot samples."""
+                def step(carry, _):
+                    cache, tok, lens, keys = carry
+                    logits, vars_out = model.apply(
+                        {"params": params, "cache": cache}, tok[:, None],
+                        positions=lens[:, None], decode=True,
+                        mutable=["cache"])
+                    if greedy:
+                        nxt = jnp.argmax(
+                            logits[:, -1], axis=-1).astype(jnp.int32)
+                    else:
+                        split = jax.vmap(jax.random.split)(keys)  # [B, 2, 2]
+                        keys = split[:, 0]
+                        nxt = sampler(logits[:, -1].astype(jnp.float32),
+                                      split[:, 1], temp, top_k, top_p)
+                    return (vars_out["cache"], nxt, lens + 1, keys), nxt
+
+                (cache, _tok, lens, keys), out = jax.lax.scan(
+                    step, (cache, toks, lengths, keys), None, length=n)
+                return cache, keys, jnp.moveaxis(out, 0, 1), lens
+
+            return chunk
+
+        # Before any program is traced through the model: the width of a
+        # cache row is the compiler's to choose (see "cache layout" below).
+        row = self._probe_cache_row(make_chunk)
+        if row:
+            self.model = Transformer(
+                dataclasses.replace(self.model.cfg, cache_row=row))
+        model = self.model
+        self._cache_spec = self._cache_shapes(model, self.params)
+
         def prefill(params, toks, plen):
-            """toks [1, Lb] -> (last-position logits [V], cache slice)."""
-            positions = jnp.arange(toks.shape[1])[None]
+            """toks [1, Lb] -> (last-position logits [V], the cache's first
+            Lb rows). The rows beyond the bucket were not written, and a
+            request parked in _ready holds what this returns: Lb rows a
+            leaf, not max_seq."""
+            lb = toks.shape[1]
+            positions = jnp.arange(lb)[None]
             logits, vars_out = model.apply(
                 {"params": params}, toks, positions=positions, decode=True,
                 mutable=["cache"])
             last = jax.lax.dynamic_index_in_dim(
                 logits[0].astype(jnp.float32), plen - 1, 0, keepdims=False)
-            return last, vars_out["cache"]
+            return last, jax.tree.map(lambda c: c[:, :lb], vars_out["cache"])
 
         def place(cache, slice_cache, slot):
-            """Copy a [1, ...] prefill cache slice into batch row `slot`."""
+            """Copy a [1, Lb, ...] prefill cache slice into the first rows
+            of batch row `slot`. The slot's later rows keep what an earlier
+            request left there: a row is written by the step that first
+            makes it visible (Attention._cached_attention)."""
             return jax.tree.map(
                 lambda big, small: jax.lax.dynamic_update_slice(
                     big, small.astype(big.dtype),
@@ -549,63 +604,143 @@ class ContinuousEngine:
             return sampler(logits[None], key[None], temp[None], top_k[None],
                            top_p[None])[0]
 
-        def chunk(params, cache, toks, lengths, keys, temp, top_k, top_p,
-                  n: int, greedy: bool):
-            """n in-flight decode steps under one scan. toks/lengths [B];
-            returns (cache, keys, tokens [B, n], lengths [B]). greedy=True
-            compiles an argmax-only variant: the sampler's two full-vocab
-            sorts per step are pure waste when no active slot samples."""
-            def step(carry, _):
-                cache, tok, lens, keys = carry
-                logits, vars_out = model.apply(
-                    {"params": params, "cache": cache}, tok[:, None],
-                    positions=lens[:, None], decode=True, mutable=["cache"])
-                if greedy:
-                    nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
-                else:
-                    split = jax.vmap(jax.random.split)(keys)  # [B, 2, 2]
-                    keys = split[:, 0]
-                    nxt = sampler(logits[:, -1].astype(jnp.float32),
-                                  split[:, 1], temp, top_k, top_p)
-                return (vars_out["cache"], nxt, lens + 1, keys), nxt
-
-            (cache, _tok, lens, keys), out = jax.lax.scan(
-                step, (cache, toks, lengths, keys), None, length=n)
-            return cache, keys, jnp.moveaxis(out, 0, 1), lens
-
         self._prefill = jax.jit(prefill)
         self._place = jax.jit(place, donate_argnums=(0,))
         self._sample1 = jax.jit(sample1)
-        self._chunk = jax.jit(chunk, static_argnums=(8, 9),
+        self._chunk = jax.jit(make_chunk(model), static_argnums=(8, 9),
                               donate_argnums=(1,))
+        self._count_boundary_copies()
 
-    def _init_cache(self):
-        """Zero cache for the full batch, built by tracing one dummy step
-        (gives the exact per-layer cache structure at [max_batch, ...])."""
+    # ------------------------------------------------------- cache layout
+    # The KV cache crosses every program boundary in the on-device layout
+    # the decode loop computes in (README "Serving hot loop"). A program's
+    # parameters and results are in their shapes' DEFAULT layouts; where
+    # that is not the loop's, every chunk program converts every cache leaf
+    # on its way in and back on its way out, whatever its length. So the
+    # engine asks the compiler which layout it wants, and gives the cache
+    # rows the width at which that layout is the default one.
+    def _cache_shapes(self, model, params):
+        """`model`'s per-layer cache structure at [max_batch, ...], each
+        leaf with the sharding `_init_cache` places it in."""
+        import jax
+        import jax.numpy as jnp
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        b = self.max_batch
+        shapes = jax.eval_shape(
+            lambda p, t, pos: model.apply(
+                {"params": p}, t, positions=pos, decode=True,
+                mutable=["cache"])[1]["cache"],
+            params, jnp.zeros((b, 1), jnp.int32),
+            jnp.zeros((b, 1), jnp.int32))
+        if self.mesh is None:
+            return shapes
+
+        def sharded(leaf):
+            # KV-head axis over tp, matching the attention head sharding.
+            spec = P(None, None, "tp", None) if leaf.ndim == 4 else P()
+            return jax.ShapeDtypeStruct(
+                leaf.shape, leaf.dtype,
+                sharding=NamedSharding(self.mesh, spec))
+
+        return jax.tree.map(sharded, shapes)
+
+    def _chunk_shapes(self, params, cache, greedy: bool) -> tuple:
+        """Arguments to lower a chunk program of decode_chunk steps from."""
         import jax
         import jax.numpy as jnp
 
         b = self.max_batch
-        toks = jnp.zeros((b, 1), jnp.int32)
-        positions = jnp.zeros((b, 1), jnp.int32)
-        shapes = jax.eval_shape(
-            lambda p, t, pos: self.model.apply(
-                {"params": p}, t, positions=pos, decode=True,
-                mutable=["cache"])[1]["cache"],
-            self.params, toks, positions)
-        cache = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), shapes)
-        if self.mesh is not None:
-            from jax.sharding import NamedSharding, PartitionSpec as P
+        return (params, cache,
+                jax.ShapeDtypeStruct((b,), jnp.int32),
+                jax.ShapeDtypeStruct((b,), jnp.int32),
+                jax.ShapeDtypeStruct((b, 2), jnp.uint32),
+                jax.ShapeDtypeStruct((b,), jnp.float32),
+                jax.ShapeDtypeStruct((b,), jnp.int32),
+                jax.ShapeDtypeStruct((b,), jnp.float32),
+                self.decode_chunk, greedy)
 
-            # KV-head axis over tp, matching the attention head sharding.
-            def _spec(leaf):
-                if leaf.ndim == 4:  # [B, S, KV, D]
-                    return NamedSharding(self.mesh, P(None, None, "tp", None))
-                return NamedSharding(self.mesh, P())
+    def _probe_cache_row(self, make_chunk) -> int:
+        """The row width the compiler wants for the cache, 0 for the one it
+        has. A chunk program is lowered once with the layout of its donated
+        cache input and of its cache output left to the compiler
+        (`Layout.AUTO`). If the answer is row-major in tiles that a row
+        does not fill (head_dim 96 in 128 lanes on the v5e), a row as wide
+        as its tiles has that layout by default; any other answer, the
+        default layout among them (head_dim 128; the CPU), leaves the
+        cache as it is. The program asked about is the greedy one of the
+        model's first layer alone: every layer uses its leaves alike and
+        the sampler never sees them, so the question is the same at a
+        fraction of the lowering and without the sampler's sorts."""
+        import jax
+        from jax.experimental.layout import Format, Layout
 
-            cache = jax.tree.map(
-                lambda leaf: jax.device_put(leaf, _spec(leaf)), cache)
-        return cache
+        from ray_tpu.models.transformer import Transformer
+
+        one = Transformer(dataclasses.replace(self.model.cfg, n_layers=1))
+        params = stage_param_slice(self.params, (0,), True, True)
+        cache = self._cache_shapes(one, params)
+        auto = jax.tree.map(
+            lambda leaf: Format(Layout.AUTO, leaf.sharding), cache)
+        probe = jax.jit(make_chunk(one), static_argnums=(8, 9),
+                        donate_argnums=(1,),
+                        in_shardings=(None, auto) + (None,) * 6,
+                        out_shardings=(auto, None, None, None))
+        wanted = probe.lower(
+            *self._chunk_shapes(params, cache, True)
+        ).compile().input_formats[0][1]
+        rows = set()
+        for leaf, fmt in zip(jax.tree.leaves(cache), jax.tree.leaves(wanted)):
+            lay, width = fmt.layout, leaf.shape[-1]
+            lanes = lay.tiling[0][-1] if lay.tiling else 1
+            row_major = lay.major_to_minor == tuple(range(leaf.ndim))
+            rows.add(-(-width // lanes) * lanes
+                     if row_major and width % lanes else 0)
+        return rows.pop() if len(rows) == 1 else 0
+
+    def _count_boundary_copies(self):
+        """Read from the compiled text of the longest sampled chunk program
+        what it does with the cache at its boundary. The same lowering
+        serves this variant's first call: it is compiled once either
+        way."""
+        import jax
+
+        compiled = self._chunk.lower(*self._chunk_shapes(
+            self.params, self._cache_spec, False)).compile()
+        text = compiled.as_text()
+        leaves = jax.tree.leaves(self._cache_spec)
+        formats = jax.tree.leaves(compiled.input_formats[0][1])
+        local = {f.sharding.shard_shape(leaf.shape)
+                 for leaf, f in zip(leaves, formats)}
+        self.cache_boundary_copies = sum(
+            len(re.findall(r"= \w+\[%s\]\S* copy\("
+                           % ",".join(map(str, dims)), text))
+            for dims in local)
+        self.cache_layout = "; ".join(sorted(
+            {f"{leaf.dtype}{list(leaf.shape)} {f.layout}"
+             for leaf, f in zip(leaves, formats)}))
+        logger.info("kv cache %s: %d whole-leaf copies in the %d-step chunk "
+                    "program", self.cache_layout, self.cache_boundary_copies,
+                    self.decode_chunk)
+
+    def cache_stats(self) -> dict:
+        """For /v1/stats: the cache's leaves and their on-device layout, and
+        how many copies of a whole leaf the longest sampled chunk program
+        makes (a conversion at its boundary; 0 wanted)."""
+        return {"cache_layout": self.cache_layout,
+                "cache_boundary_copies": self.cache_boundary_copies}
+
+    def _init_cache(self):
+        """Zero cache for the full batch."""
+        import jax
+        import jax.numpy as jnp
+
+        def zeros(leaf):
+            z = jnp.zeros(leaf.shape, leaf.dtype)
+            return (z if leaf.sharding is None
+                    else jax.device_put(z, leaf.sharding))
+
+        return jax.tree.map(zeros, self._cache_spec)
 
     # -------------------------------------------------------------- public
     def submit(self, prompt_tokens, sampling: Optional[SamplingParams] = None

@@ -51,8 +51,9 @@ def _sampling_from_body(body: dict, default_max: int) -> SamplingParams:
 def _device_report() -> dict:
     """This process's devices as JAX reports them, the chip device files it
     really holds open, the chips the node agent booked to it
-    (TPU_VISIBLE_CHIPS; None when it was granted none), and what it has
-    compiled since the server was built."""
+    (TPU_VISIBLE_CHIPS; None when it was granted none), what it has
+    compiled since the server was built, and the most device memory it has
+    held at once (None where the backend keeps no such count)."""
     import jax
 
     from ray_tpu._private import accelerators, telemetry
@@ -65,7 +66,9 @@ def _device_report() -> dict:
             "chip_files_open": accelerators.open_chip_files(),
             "tpu_visible_chips": os.environ.get("TPU_VISIBLE_CHIPS"),
             "compile_count": compiled["count"],
-            "compile_s": round(compiled["seconds"], 3)}
+            "compile_s": round(compiled["seconds"], 3),
+            "memory_peak_bytes": (devs[0].memory_stats() or {}).get(
+                "peak_bytes_in_use")}
 
 
 class OpenAIServer:
@@ -163,6 +166,8 @@ class OpenAIServer:
             if stages:
                 out["pipeline_stages"] = stages
                 out["stages"] = self.engine.stage_devices()
+            else:
+                out.update(self.engine.cache_stats())
             return out
         self._served += 1
         body = request.json() or {}

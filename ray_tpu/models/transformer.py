@@ -42,6 +42,12 @@ class TransformerConfig:
     #: >0 switches the MLP to a top-2 MoE with this many experts, sharded
     #: over the "ep" mesh axis.
     moe_experts: int = 0
+    #: Width of a KV-cache row: head_dim when 0, else head_dim followed by
+    #: zeros that are never read. The serving engine sets it to the width
+    #: the device's compiler lays a row out in (llm/engine.py
+    #: `_probe_cache_row`), so that the cache's default on-device layout
+    #: is the one the decode loop computes in.
+    cache_row: int = 0
 
     @property
     def head_dim(self) -> int:
@@ -109,15 +115,23 @@ class Attention(nn.Module):
         f32 einsum below over the whole cache."""
         cfg = self.cfg
         b, s = q.shape[0], q.shape[1]
+        d = cfg.head_dim
+        row = max(cfg.cache_row, d)
         ck = self.variable("cache", "k", lambda: jnp.zeros(
-            (b, cfg.max_seq, cfg.n_kv_heads, cfg.head_dim), cfg.dtype))
+            (b, cfg.max_seq, cfg.n_kv_heads, row), cfg.dtype))
         cv = self.variable("cache", "v", lambda: jnp.zeros(
-            (b, cfg.max_seq, cfg.n_kv_heads, cfg.head_dim), cfg.dtype))
+            (b, cfg.max_seq, cfg.n_kv_heads, row), cfg.dtype))
         pos = positions.astype(jnp.int32)
         bidx = jnp.arange(b)[:, None]
-        ck.value = ck.value.at[bidx, pos].set(k.astype(cfg.dtype))
-        cv.value = cv.value.at[bidx, pos].set(v.astype(cfg.dtype))
+        k, v = k.astype(cfg.dtype), v.astype(cfg.dtype)
+        if row > d:
+            tail = ((0, 0),) * 3 + ((0, row - d),)
+            k, v = jnp.pad(k, tail), jnp.pad(v, tail)
+        ck.value = ck.value.at[bidx, pos].set(k)
+        cv.value = cv.value.at[bidx, pos].set(v)
         keys, vals = ck.value, cv.value
+        if row > d:
+            keys, vals = keys[..., :d], vals[..., :d]
         if s == 1:
             from ray_tpu.ops.decode_attention import decode_attention
 

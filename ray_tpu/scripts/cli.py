@@ -599,7 +599,7 @@ def cmd_profile(args) -> int:
     Captures persist through the storage plane under <session>/profiles/
     and are listed by `/api/profiles` / `util.state.list_profiles()`."""
     address = _resolve_address(args)
-    rep = _rpc_call(address, "profile_worker", timeout=args.seconds + 60,
+    rep = _rpc_call(address, "profile_worker", timeout=args.seconds + 120,
                     worker_id=args.worker, seconds=args.seconds,
                     mode=args.mode)
     if not rep.get("found"):

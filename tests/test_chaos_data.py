@@ -19,10 +19,30 @@ from ray_tpu.data._internal import exchange as xch
 from ray_tpu.exceptions import DataSpillError
 
 
-def _shuffle_blocks(items, seed, n_blocks):
-    refs = rd.from_items(items, parallelism=n_blocks).random_shuffle(
-        seed=seed)._block_refs()
-    return [ray_tpu.get(r, timeout=600) for r in refs]
+def _shuffle_blocks(items, seed, n_blocks, limit_s=180.0):
+    """The shuffled blocks, within a time limit of this call's own (a
+    shuffle takes seconds under tier-1's load): the exchange's driver loop
+    waits without end for a shard that is never made, and that has to fail
+    the test here, not hold the whole run."""
+    box = {}
+
+    def run():
+        try:
+            refs = rd.from_items(items, parallelism=n_blocks).random_shuffle(
+                seed=seed)._block_refs()
+            box["blocks"] = ray_tpu.get(refs, timeout=limit_s)
+        except BaseException as e:  # noqa: BLE001 - re-raised by the caller
+            box["error"] = e
+
+    t = threading.Thread(target=run, daemon=True)
+    t.start()
+    t.join(limit_s)
+    if t.is_alive():
+        pytest.fail(f"shuffle still running after {limit_s:.0f}s; exchange "
+                    f"stats {xch.exchange_stats()}")
+    if "error" in box:
+        raise box["error"]
+    return box["blocks"]
 
 
 def _leased_pid():

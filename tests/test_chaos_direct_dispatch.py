@@ -146,6 +146,82 @@ def test_sever_mid_batch_fails_over_to_controller_no_duplicates(chaos_cleanup):
                        timeout=60) == [100, 101, 102, 103]
 
 
+def test_severed_specs_get_the_dead_leases_resources(chaos_cleanup):
+    """On a full cluster (one CPU, one lease) the owner loses its leased
+    worker with a producer executing, consumers pipelined behind it and
+    more consumers still queued at the owner. The sent specs fail over to
+    the controller path, which can run them only on the CPU the dead lease
+    gave back: the owner must not lease it again for the queued consumers,
+    which would wait on the new worker for the producer while the producer
+    waits at the controller for the worker's CPU."""
+    import signal
+
+    ray_tpu.init(num_cpus=1, _system_config={"fault_injection": True})
+
+    @ray_tpu.remote(num_cpus=1, max_retries=2)
+    def produce(gate):
+        import os as _os
+        import time as _t
+
+        while not _os.path.exists(gate):  # the first attempt is killed
+            _t.sleep(0.02)
+        return 7
+
+    @ray_tpu.remote(num_cpus=1, max_retries=2)
+    def consume(x, i):
+        return x * 100 + i
+
+    assert ray_tpu.get(consume.remote(0, 0), timeout=60) == 0  # warm lease
+    gate = os.path.join(tempfile.mkdtemp(prefix="rt_chaos_dd_"), "go")
+    src = produce.remote(gate)
+    # More consumers than a lease takes at its deepest (lease.DEPTH): the
+    # rest wait in the owner's class queue.
+    outs = [consume.remote(src, i) for i in range(24)]
+
+    def _executing_pid():
+        slots = [s for s in ray_tpu._head.agent.workers.values()
+                 if s.state == "leased" and s.proc.poll() is None]
+        lm = ray_tpu._private.worker.global_worker().lease_mgr
+        sent = sum(len(l.inflight) - len(l.buf)
+                   for l in list(lm._by_id.values()))
+        return slots[0].proc.pid if slots and sent >= 2 else None
+
+    _wait(_executing_pid, 30, "producer and a consumer on the leased worker")
+    # A SIGKILLed worker is mostly known by its closed connection first (a
+    # sever, as here) and now and then by the controller's lease_invalid (a
+    # retry on a new lease): the connection goes first, so that it is the
+    # sever every time.
+    pid = _executing_pid()
+    assert rpc.fault_injector().sever("lease") == 1
+    lm = ray_tpu._private.worker.global_worker().lease_mgr
+    (cls,) = lm.classes.values()
+    _wait(lambda: cls.failover, 30, "the sent specs to fail over")
+    asked = []
+    request = lm._a_request
+    lm._a_request = lambda c, n: asked.append(n) or request(c, n)
+    os.kill(pid, signal.SIGKILL)
+    # The producer goes to the controller (lease.FAILOVER_GRACE_S after
+    # the sever) and holds the CPU there, its gate shut. Until it resolves
+    # the owner asks for no lease, though consumers are queued and it
+    # holds none (a request from before the sever may still be answered:
+    # that lease goes straight back).
+    deadline = time.monotonic() + 1.0
+    while time.monotonic() < deadline:
+        assert cls.failover and cls.queue
+        assert not asked and not cls.leases
+        time.sleep(0.005)
+    open(gate, "w").close()
+
+    assert ray_tpu.get(outs, timeout=60) == [700 + i for i in range(24)]
+    from ray_tpu.util.metrics import task_dispatch_counts
+
+    assert task_dispatch_counts()["controller"] > 0
+    # The hold ends with the failed-over specs: the owner leases again.
+    assert ray_tpu.get([consume.remote(1, i) for i in range(6)],
+                       timeout=60) == [100 + i for i in range(6)]
+    assert cls.failover == 0 and task_dispatch_counts()["direct"] >= 32
+
+
 def test_lease_fencing_across_incarnation_bump(chaos_cleanup):
     """A lease reasserted against a node's previous incarnation is dead on
     arrival: rejected (counted + lease_invalid pushed to the owner), with

@@ -184,6 +184,12 @@ def test_serve_openai_http(ray_start_4cpu):
         assert st["chip_files_open"] == []
         assert st["served"] == 3 and st["active"] == 0
         assert st["compile_count"] > 0 and st["engine_init_s"] > 0
+        # ... and the layout its chunk program takes the KV cache in (on
+        # the CPU the default one, so nothing is converted); the CPU keeps
+        # no count of device memory.
+        assert "major_to_minor" in st["cache_layout"]
+        assert st["cache_boundary_copies"] == 0
+        assert st["memory_peak_bytes"] is None
     finally:
         serve.shutdown()
 
@@ -207,3 +213,81 @@ def test_serve_handle_streaming(ray_start_2cpu):
         assert vals == [0, 1, 2, 3, 4]
     finally:
         serve.shutdown()
+
+
+# ---------------------------------------------------------------------------
+# The KV cache crosses program boundaries in the layout the decode loop
+# computes in: the engine asks the compiler for it and, where a wider row
+# makes it the default layout, widens the cache's rows (README "Serving hot
+# loop"). On the CPU the compiler's answer is the default layout.
+
+
+def test_cache_stats_name_the_layout_and_count_boundary_copies(engine):
+    st = engine.cache_stats()
+    assert set(st) == {"cache_layout", "cache_boundary_copies"}
+    head_dim = CFG.d_model // CFG.n_heads
+    assert st["cache_layout"].startswith(
+        f"{CFG.dtype}[4, {CFG.max_seq}, {CFG.n_heads}, {head_dim}] Layout(")
+    # The CPU's own choice is the default layout: nothing to convert, and
+    # the rows stay as wide as a head.
+    assert st["cache_boundary_copies"] == 0
+    assert engine.model.cfg.cache_row == 0
+
+
+@pytest.mark.parametrize("row", [32, 128])
+def test_widened_cache_rows_decode_the_same_tokens(engine, monkeypatch, row):
+    """An engine whose compiler asks for wider cache rows (as the v5e's
+    does for heads of 96) serves the same greedy tokens, token for token,
+    through zero cache, splices and chunks of four lengths, and hands the
+    cache from program to program at that width."""
+    import jax
+
+    monkeypatch.setattr(ContinuousEngine, "_probe_cache_row",
+                        lambda self, make_chunk: row)
+    wide = ContinuousEngine(CFG, max_batch=4, decode_chunk=4)
+    try:
+        assert wide.model.cfg.cache_row == row
+        assert f", {row}] Layout(" in wide.cache_stats()["cache_layout"]
+        # One at a time: alone in the batch a request decodes through a
+        # fixed sequence of chunk programs (12 tokens = 1 + 4 + 4 + 2 + 1).
+        sp = SamplingParams(temperature=0.0, max_tokens=12)
+        for prompt in ([1, 2, 3], [7, 5, 3, 2, 1, 8, 9, 4, 6], [11]):
+            want = engine.submit(prompt, sp).tokens()
+            assert wide.submit(prompt, sp).tokens() == want
+        # ... and a request that joins a running batch (a splice beside
+        # live rows), sampled.
+        a = wide.submit([3, 1, 4, 1, 5], SamplingParams(temperature=0.0,
+                                                        max_tokens=9))
+        a.next(timeout=60)
+        b = wide.submit([9, 2, 6], SamplingParams(temperature=0.7, top_k=5,
+                                                  max_tokens=6))
+        assert len(b.tokens()) == 6 and len(a.tokens()) == 8
+        leaves = jax.tree.leaves(wide._cache)
+        assert len(leaves) == 2 * CFG.n_layers
+        for leaf in leaves:
+            assert leaf.shape == (4, CFG.max_seq, CFG.n_heads, row)
+    finally:
+        wide.shutdown()
+
+
+def test_rows_an_earlier_request_left_in_a_slot_are_never_read():
+    """A splice writes the rows of the prompt's bucket and no more; what a
+    longer request left further down the slot stays there and must stay
+    invisible: a short prompt decodes through those rows exactly as it does
+    in a slot that has only ever held zeros."""
+    sp = SamplingParams(temperature=0.0, max_tokens=40)
+    short = [[5, 6, 7], [9], [2, 4, 6, 8, 10]]  # bucket 8, decoded to 40+
+    fresh = ContinuousEngine(CFG, max_batch=4, decode_chunk=4)
+    try:
+        want = [fresh.submit(p, sp).tokens() for p in short]
+        # every slot held to position 100 by a long request (bucket 64)
+        long_sp = SamplingParams(temperature=0.9, top_k=8, max_tokens=40)
+        rng = np.random.default_rng(0)
+        for out in fresh.generate(
+                [rng.integers(1, CFG.vocab_size, 60) for _ in range(4)],
+                long_sp):
+            assert len(out) == 40
+        got = [fresh.submit(p, sp).tokens() for p in short]
+        assert got == want
+    finally:
+        fresh.shutdown()

@@ -1,0 +1,97 @@
+"""The serving programs compiled for a v5e chip that is described, not
+attached (no chip time, nothing runs): what the TPU's compiler makes of the
+KV cache at a program's boundary. The CPU's default layout is the decode
+loop's own, so tier-1's other tests cannot see a conversion there.
+
+Every test that loads the TPU's compiler lives in this one file, and the
+topology is described inside a fixture: only the worker that is handed this
+file loads the library (README "Serving hot loop"; the `on-chip-measurement`
+guide, section 2).
+"""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from ray_tpu.llm import LLMConfig
+from ray_tpu.llm.engine import ContinuousEngine, _make_sampler, model_config
+from ray_tpu.models.transformer import Transformer
+
+#: Phi-3-mini's heads (96 wide, which the chip pads to 128) at a size that
+#: compiles in seconds: 4 heads, 2 layers, 8 slots of 256 positions.
+CFG = LLMConfig(vocab_size=512, d_model=384, n_layers=2, n_heads=4,
+                max_seq=256, dtype="bfloat16")
+MAX_BATCH = 8
+
+
+@pytest.fixture(scope="module")
+def chip():
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no TPU compiler on this host
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return topo.devices[0]
+
+
+def build_compiled(chip, monkeypatch=None, row=None) -> ContinuousEngine:
+    """An engine's compiled programs for `chip`, from shapes: no parameter
+    is made, no thread started, nothing placed on a device. `row` stands
+    in for the compiler's answer."""
+    if row is not None:
+        monkeypatch.setattr(ContinuousEngine, "_probe_cache_row",
+                            lambda self, make_chunk: row)
+    eng = object.__new__(ContinuousEngine)
+    eng.cfg, eng.max_batch, eng.decode_chunk, eng.mesh = CFG, MAX_BATCH, 4, None
+    eng.model = Transformer(model_config(CFG))
+    eng._sampler = _make_sampler(CFG.vocab_size)
+    eng._jax, eng._jnp = jax, jnp
+    shapes = jax.eval_shape(lambda: eng.model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    eng.params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, jnp.bfloat16,
+                                       sharding=SingleDeviceSharding(chip)),
+        shapes)
+    eng._build_compiled()
+    return eng
+
+
+def test_v5e_chunk_program_takes_and_returns_the_cache_without_a_copy(chip):
+    """The compiler wants the cache row-major in tiles of 128 lanes, which
+    a head of 96 does not fill; with rows of 128 that is the default layout
+    and the chunk program converts nothing at its boundary."""
+    eng = build_compiled(chip)
+    assert eng.model.cfg.cache_row == 128
+    st = eng.cache_stats()
+    assert st["cache_boundary_copies"] == 0
+    assert st["cache_layout"].startswith(
+        f"bfloat16[{MAX_BATCH}, {CFG.max_seq}, {CFG.n_heads}, 128] "
+        f"Layout(major_to_minor=(0, 1, 2, 3), tiling=(")
+    # a prefill's slice (its bucket's rows) arrives at the same width, so
+    # placing it is a plain update of a slot's first rows
+    cache = eng._cache_spec
+    one = jax.eval_shape(eng._prefill, eng.params,
+                         jax.ShapeDtypeStruct((1, 64), jnp.int32), 5)[1]
+    assert {leaf.shape for leaf in jax.tree.leaves(one)} == {
+        (1, 64, CFG.n_heads, 128)}
+    placed = eng._place.lower(
+        cache, one, jax.ShapeDtypeStruct((), jnp.int32)).compile()
+    assert not re.findall(r"= \w+\[%d,%d,%d,\d+\]\S* copy\("
+                          % (MAX_BATCH, CFG.max_seq, CFG.n_heads),
+                          placed.as_text())
+
+
+def test_v5e_counter_sees_the_conversions_of_head_wide_rows(
+        chip, monkeypatch):
+    """What the engine did before it asked: rows as wide as a head, whose
+    default layout has the positions minor-most, are converted on the way
+    into the chunk program and back on the way out, once each per leaf."""
+    eng = build_compiled(chip, monkeypatch, row=0)
+    assert eng.model.cfg.cache_row == 0
+    st = eng.cache_stats()
+    assert st["cache_boundary_copies"] == 2 * 2 * CFG.n_layers
+    assert "96] Layout(major_to_minor=(0, 2, 3, 1)" in st["cache_layout"]
