@@ -33,6 +33,23 @@ class LLMConfig:
     dtype: str = "float32"
     #: optional pytree of trained params; random init otherwise
     params: Any = None
+    #: The architecture, as the model's published `config.json` names it
+    #: (`model_type`, `q_lora_rank`, `kv_lora_rank`, the three head dims,
+    #: `intermediate_size`, `moe_intermediate_size`, `n_routed_experts` as
+    #: PUBLISHED, `num_experts_per_tok`, `n_shared_experts`,
+    #: `first_k_dense_replace`, `routed_scaling_factor`, `scoring_func`,
+    #: `topk_method`, `norm_topk_prob`, `rope_theta`, `rope_scaling`,
+    #: `rms_norm_eps`, `tie_word_embeddings`, ...). None is the Llama-style
+    #: block the six sizes above describe alone (MHA, SwiGLU of 8/3 d, a tied
+    #: head). The sizes above stay what is RUN (a vocabulary slice, a cut in
+    #: depth); `engine.model_config` reads the rest from here and refuses a
+    #: key it does not know how to build.
+    arch: Optional[dict] = None
+    #: This device's share of the routed experts under expert parallelism:
+    #: `[first_expert, first_expert + experts_held)` of `n_routed_experts`;
+    #: 0 holds them all.
+    experts_held: int = 0
+    first_expert: int = 0
 
 
 class LLMEngine:
@@ -46,15 +63,11 @@ class LLMEngine:
         import jax
         import jax.numpy as jnp
 
-        from ray_tpu.models.transformer import Transformer, TransformerConfig
+        from ray_tpu.llm.engine import model_config
+        from ray_tpu.models.transformer import Transformer
 
         self.cfg = cfg
-        mcfg = TransformerConfig(
-            vocab_size=cfg.vocab_size, d_model=cfg.d_model,
-            n_layers=cfg.n_layers, n_heads=cfg.n_heads,
-            n_kv_heads=cfg.n_heads, d_ff=int(cfg.d_model * 8 / 3) // 8 * 8,
-            max_seq=cfg.max_seq, dtype=jnp.dtype(cfg.dtype))
-        self.model = Transformer(mcfg)
+        self.model = Transformer(model_config(cfg))
         if cfg.params is not None:
             self.params = cfg.params
         else:

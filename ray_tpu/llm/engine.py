@@ -328,16 +328,64 @@ def model_config(cfg):
     """LLMConfig -> TransformerConfig, the single place the serving model
     shape is derived (ContinuousEngine and the pipeline stages must agree
     bit-for-bit: a pipelined run is the SAME model cut at layer
-    boundaries, so matched-parameter A/B comparisons stay honest)."""
+    boundaries, so matched-parameter A/B comparisons stay honest).
+
+    Without `cfg.arch` the model is the Llama-style block of the six sizes.
+    With it, the published keys say the rest; every key that bears on the
+    arithmetic is either built or refused here."""
     import jax.numpy as jnp
 
-    from ray_tpu.models.transformer import TransformerConfig
+    from ray_tpu.models.transformer import TransformerConfig, YarnScaling
 
+    sizes = dict(vocab_size=cfg.vocab_size, d_model=cfg.d_model,
+                 n_layers=cfg.n_layers, n_heads=cfg.n_heads,
+                 max_seq=cfg.max_seq, dtype=jnp.dtype(cfg.dtype))
+    arch = cfg.arch
+    if arch is None:
+        if cfg.experts_held or cfg.first_expert:
+            raise ValueError("experts_held / first_expert need an `arch` "
+                             "with routed experts")
+        return TransformerConfig(
+            n_kv_heads=cfg.n_heads, d_ff=int(cfg.d_model * 8 / 3) // 8 * 8,
+            **sizes)
+    if arch.get("model_type") not in ("kimi_k2", "deepseek_v3"):
+        raise ValueError(f"no model is built for model_type "
+                         f"{arch.get('model_type')!r}")
+    want = {"hidden_act": "silu", "attention_bias": False, "n_group": 1,
+            "topk_group": 1, "topk_method": "noaux_tc", "moe_layer_freq": 1,
+            "num_nextn_predict_layers": 0}
+    odd = {k: arch[k] for k, v in want.items() if arch.get(k, v) != v}
+    if odd:
+        raise ValueError(f"not built: {odd} (built: {want})")
+    if arch.get("num_key_value_heads", cfg.n_heads) != cfg.n_heads:
+        raise ValueError("latent attention has one latent for all heads: "
+                         "num_key_value_heads must equal the heads")
+    published = int(arch["n_routed_experts"])
+    held = cfg.experts_held or published
+    if not 0 <= cfg.first_expert <= published - held:
+        raise ValueError(
+            f"experts [{cfg.first_expert}, {cfg.first_expert + held}) are "
+            f"not among the {published} published")
     return TransformerConfig(
-        vocab_size=cfg.vocab_size, d_model=cfg.d_model,
-        n_layers=cfg.n_layers, n_heads=cfg.n_heads,
-        n_kv_heads=cfg.n_heads, d_ff=int(cfg.d_model * 8 / 3) // 8 * 8,
-        max_seq=cfg.max_seq, dtype=jnp.dtype(cfg.dtype))
+        n_kv_heads=cfg.n_heads, d_ff=int(arch["intermediate_size"]),
+        rope_theta=float(arch["rope_theta"]),
+        norm_eps=float(arch["rms_norm_eps"]),
+        tie_embeddings=bool(arch["tie_word_embeddings"]),
+        attention="mla", q_lora_rank=int(arch["q_lora_rank"] or 0),
+        kv_lora_rank=int(arch["kv_lora_rank"]),
+        qk_nope_head_dim=int(arch["qk_nope_head_dim"]),
+        qk_rope_head_dim=int(arch["qk_rope_head_dim"]),
+        v_head_dim=int(arch["v_head_dim"]),
+        rope_yarn=YarnScaling.from_config(arch.get("rope_scaling")),
+        moe_experts=published, moe_top_k=int(arch["num_experts_per_tok"]),
+        moe_d_ff=int(arch["moe_intermediate_size"]),
+        moe_scoring=arch["scoring_func"],
+        moe_norm_topk=bool(arch["norm_topk_prob"]),
+        moe_routed_scale=float(arch["routed_scaling_factor"]),
+        moe_score_bias=True,  # noaux_tc's e_score_correction_bias
+        moe_shared_experts=int(arch["n_shared_experts"] or 0),
+        moe_first_layer=int(arch["first_k_dense_replace"]),
+        experts_held=held, first_expert=cfg.first_expert, **sizes)
 
 
 def stage_layer_split(n_layers: int, n_stages: int) -> list[tuple[int, ...]]:
@@ -361,51 +409,64 @@ def stage_param_slice(params: dict, layers: tuple, first: bool,
     """This stage's shard of a full Transformer param tree. Layer keys keep
     their GLOBAL names (`layer_{i}`) so a shard is a strict subtree of the
     full checkpoint; the embedding rides along on the first stage (embed)
-    and the last (tied output head)."""
+    and the last (tied output head), an untied `lm_head` on the last."""
     out = {}
-    if first or last:
+    tied = "lm_head" not in params
+    if first or (last and tied):
         out["tok_emb"] = params["tok_emb"]
     for i in layers:
         out[f"layer_{i}"] = params[f"layer_{i}"]
     if last:
         out["final_norm"] = params["final_norm"]
+        if not tied:
+            out["lm_head"] = params["lm_head"]
     return out
 
 
 def make_stage_net(mcfg, layers: tuple, first: bool, last: bool):
     """Flax module computing one pipeline stage's slice of the Transformer:
-    embed (first stage) -> layers[a:b] -> final_norm + tied head (last
-    stage). Per-layer module names match the full model's, so
+    embed (first stage) -> layers[a:b] -> final_norm + the output head
+    (last stage). Per-layer module names match the full model's, so
     stage_param_slice output applies directly and a 1-stage net is
     numerically the full Transformer."""
     import flax.linen as nn
-    import jax
-    import jax.numpy as jnp
 
-    from ray_tpu.models.transformer import Block, RMSNorm
+    from ray_tpu.models.transformer import Block, RMSNorm, output_head
 
     class _StageNet(nn.Module):
         @nn.compact
         def __call__(self, x, positions, decode: bool = True):
             emb = None
-            if first or last:
+            if first or (last and mcfg.tie_embeddings):
                 emb = self.param(
                     "tok_emb", nn.initializers.normal(0.02),
                     (mcfg.vocab_size, mcfg.d_model), mcfg.param_dtype)
             if first:
                 x = emb[x].astype(mcfg.dtype)
             for i in layers:
-                x = Block(mcfg, name=f"layer_{i}")(x, positions,
-                                                   decode=decode)
+                x = Block(mcfg, moe=mcfg.is_moe_layer(i),
+                          name=f"layer_{i}")(x, positions, decode=decode)
             if last:
-                x = RMSNorm(name="final_norm")(x)
-                with jax.named_scope("lm_head"):
-                    x = jnp.einsum(
-                        "bsd,vd->bsv", x,
-                        emb.astype(mcfg.dtype)).astype(jnp.float32)
+                x = RMSNorm(mcfg.norm_eps, name="final_norm")(x)
+                x = output_head(self, mcfg, x, emb)
             return x
 
     return _StageNet()
+
+
+def _rows_columns(rows, batch: int):
+    """Per-expert row counts [held] as whole columns of a [batch, n] token
+    block: ceil(held / batch) columns, filled column by column, zeros
+    after. `_rows_from_columns` is the inverse, on the host."""
+    import jax.numpy as jnp
+
+    cols = -(-rows.shape[0] // batch)
+    padded = jnp.pad(rows, (0, cols * batch - rows.shape[0]))
+    return padded.reshape(cols, batch).T
+
+
+def _rows_from_columns(columns: np.ndarray, held: int) -> np.ndarray:
+    return columns.T.reshape(-1)[:held]
 
 
 class ContinuousEngine:
@@ -427,18 +488,27 @@ class ContinuousEngine:
         self.mesh = mesh
         mcfg = model_config(cfg)
         self.model = Transformer(mcfg)
-        if cfg.params is not None:
-            params = cfg.params["params"] if "params" in cfg.params else cfg.params
-        else:
-            dummy = jnp.zeros((1, 8), jnp.int32)
-            params = self.model.init(jax.random.PRNGKey(cfg.seed), dummy)["params"]
-        if mcfg.dtype == jnp.bfloat16:
-            # Inference needs no f32 master weights: pre-cast once so every
+        def serving_dtype(params):
+            # Inference needs no f32 master weights: held in bf16, every
             # decode step reads half the bytes (flax would otherwise cast
             # f32->bf16 per call, paying f32 HBM reads each step).
-            params = jax.tree.map(
+            if mcfg.dtype != jnp.bfloat16:
+                return params
+            return jax.tree.map(
                 lambda x: x.astype(jnp.bfloat16)
                 if x.dtype == jnp.float32 else x, params)
+
+        if cfg.params is not None:
+            params = serving_dtype(
+                cfg.params["params"] if "params" in cfg.params else cfg.params)
+        else:
+            # One program makes each leaf and casts it: the float32 tree is
+            # never held whole beside its bf16 copy (6 bytes a parameter),
+            # only a leaf at a time. The values are those of an eager init
+            # followed by the cast.
+            self._make_params = jax.jit(lambda key: serving_dtype(
+                self.model.init(key, jnp.zeros((1, 8), jnp.int32))["params"]))
+            params = self._make_params(jax.random.PRNGKey(cfg.seed))
         if mesh is not None:
             params = self._shard_params(params, mesh)
         self.params = params
@@ -535,21 +605,35 @@ class ContinuousEngine:
         from ray_tpu.models.transformer import Transformer
 
         sampler = self._sampler
+        # Expert layers: the columns each chunk's token block carries beyond
+        # its tokens (`_rows_columns`), and the rows routed to held experts
+        # since start, over all expert layers and steps.
+        self._moe_held = self.model.cfg.held_experts
+        self._moe_cols = -(-self._moe_held // self.max_batch)
+        self.moe_rows_total = 0
 
         def make_chunk(model):
+            held = model.cfg.held_experts
+
             def chunk(params, cache, toks, lengths, keys, temp, top_k, top_p,
                       n: int, greedy: bool):
                 """n in-flight decode steps under one scan. toks/lengths
                 [B]; returns (cache, keys, tokens [B, n], lengths [B]).
                 greedy=True compiles an argmax-only variant: the sampler's
                 two full-vocab sorts per step are pure waste when no active
-                slot samples."""
+                slot samples. A model with expert layers appends to the
+                token block the columns of `_rows_columns`: the rows its
+                held experts were routed in these n steps ride to the host
+                in the read that brings the tokens."""
                 def step(carry, _):
-                    cache, tok, lens, keys = carry
+                    cache, tok, lens, keys, *rows = carry
                     logits, vars_out = model.apply(
                         {"params": params, "cache": cache}, tok[:, None],
                         positions=lens[:, None], decode=True,
-                        mutable=["cache"])
+                        mutable=["cache", "stats"] if held else ["cache"])
+                    if held:
+                        rows = [sum(jax.tree.leaves(vars_out.get("stats", {})),
+                                    rows[0])]
                     if greedy:
                         nxt = jnp.argmax(
                             logits[:, -1], axis=-1).astype(jnp.int32)
@@ -558,11 +642,18 @@ class ContinuousEngine:
                         keys = split[:, 0]
                         nxt = sampler(logits[:, -1].astype(jnp.float32),
                                       split[:, 1], temp, top_k, top_p)
-                    return (vars_out["cache"], nxt, lens + 1, keys), nxt
+                    return (vars_out["cache"], nxt, lens + 1, keys, *rows), nxt
 
-                (cache, _tok, lens, keys), out = jax.lax.scan(
-                    step, (cache, toks, lengths, keys), None, length=n)
-                return cache, keys, jnp.moveaxis(out, 0, 1), lens
+                rows0 = [jnp.zeros((held,), jnp.int32)] if held else []
+                (cache, _tok, lens, keys, *rows), out = jax.lax.scan(
+                    step, (cache, toks, lengths, keys, *rows0), None,
+                    length=n)
+                block = jnp.moveaxis(out, 0, 1)
+                if held:
+                    block = jnp.concatenate(
+                        [block, _rows_columns(rows[0], block.shape[0])],
+                        axis=1)
+                return cache, keys, block, lens
 
             return chunk
 
@@ -637,7 +728,9 @@ class ContinuousEngine:
             return shapes
 
         def sharded(leaf):
-            # KV-head axis over tp, matching the attention head sharding.
+            # K and V [slots, rows, heads, dim]: the head axis over tp, as
+            # the attention's heads are. A latent leaf [slots, rows, row]
+            # belongs to every head: each tp shard keeps all of it.
             spec = P(None, None, "tp", None) if leaf.ndim == 4 else P()
             return jax.ShapeDtypeStruct(
                 leaf.shape, leaf.dtype,
@@ -727,8 +820,45 @@ class ContinuousEngine:
         """For /v1/stats: the cache's leaves and their on-device layout, and
         how many copies of a whole leaf the longest sampled chunk program
         makes (a conversion at its boundary; 0 wanted)."""
-        return {"cache_layout": self.cache_layout,
-                "cache_boundary_copies": self.cache_boundary_copies}
+        import jax
+
+        mcfg = self.model.cfg
+        out = {"cache_layout": self.cache_layout,
+               "cache_boundary_copies": self.cache_boundary_copies,
+               "cache_kind": "latent" if mcfg.attention == "mla" else "kv",
+               "cache_bytes": sum(
+                   leaf.size * leaf.dtype.itemsize
+                   for leaf in jax.tree.leaves(self._cache_spec))}
+        if self._moe_held:
+            out.update(experts_held=self._moe_held,
+                       experts_published=mcfg.moe_experts,
+                       first_expert=mcfg.first_expert,
+                       moe_rows_total=self.moe_rows_total)
+        return out
+
+    def _count_moe(self, all_np: np.ndarray, q: list, off: int) -> dict:
+        """The expert layers' row counts of the chunks just read (they ride
+        behind each chunk's tokens): added to the totals, and returned as
+        the attributes `engine.host_sync` carries."""
+        rows = np.zeros(self._moe_held, np.int64)
+        steps = 0
+        for _toks, _active, pn, _tag in q:
+            off += pn
+            rows += _rows_from_columns(
+                all_np[:, off:off + self._moe_cols], self._moe_held)
+            off += self._moe_cols
+            steps += pn
+        total = int(rows.sum())
+        self.moe_rows_total += total
+        if total:
+            try:
+                from ray_tpu.util import metrics as _metrics
+
+                _metrics.LLM_MOE_ROWS.inc(total)
+            except Exception:
+                pass
+        return {"moe_rows": total, "moe_rows_busiest": int(rows.max()),
+                "moe_steps": steps}
 
     def _init_cache(self):
         """Zero cache for the full batch."""
@@ -1242,12 +1372,14 @@ class ContinuousEngine:
                 # sync_ms of the pass is engine.host_sync's own interval.
                 t_end = ph.begin("deliver") if ph is not None else None
                 iter_ctx = iter_ctx or sync_ctx
+                moe = (self._count_moe(all_np, q, 1 if firsts else 0)
+                       if self._moe_cols and q and all_np is not None else {})
                 if sync_ctx is not None and all_np is not None:
                     t_end = t_end or time.time()
                     _tracing.record_span_in(
                         sync_ctx, "engine.host_sync", "engine", t_sync,
                         t_end, {"chunks": len(q),
-                                "cols": int(all_np.shape[1])})
+                                "cols": int(all_np.shape[1]), **moe})
                     try:
                         from ray_tpu.util import metrics as _metrics
 
@@ -1276,7 +1408,7 @@ class ContinuousEngine:
                             if self._slots[i] is not None:
                                 self._next_tok[i] = int(
                                     all_np[i, off + pn - 1])
-                    off += pn
+                    off += pn + self._moe_cols
                     self._cooling = {s: t for s, t in self._cooling.items()
                                      if t is not tag}
             if ph is not None:

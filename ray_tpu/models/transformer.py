@@ -18,13 +18,21 @@ TPU-native design notes:
 from __future__ import annotations
 
 from dataclasses import dataclass
+from typing import Optional
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from ray_tpu.models.layers import RMSNorm, SwiGLU, YarnScaling, rope as _rope
+from ray_tpu.models.mla import MLA
+from ray_tpu.models.moe import MoE
 from ray_tpu.ops import dot_product_attention
+
+__all__ = ["Attention", "Block", "MLA", "MoE", "RMSNorm", "SwiGLU",
+           "Transformer", "TransformerConfig", "YarnScaling", "loss_fn",
+           "param_specs"]
 
 
 @dataclass(frozen=True)
@@ -39,12 +47,48 @@ class TransformerConfig:
     rope_theta: float = 10000.0
     dtype: jnp.dtype = jnp.bfloat16  # activation/compute dtype
     param_dtype: jnp.dtype = jnp.float32
-    #: >0 switches the MLP to a top-2 MoE with this many experts, sharded
-    #: over the "ep" mesh axis.
+    #: RMSNorm's epsilon, every norm of the model.
+    norm_eps: float = 1e-6
+    #: The logits are the final hidden state times the embedding (True) or
+    #: times a matrix of their own, `lm_head` (False).
+    tie_embeddings: bool = True
+    #: "mha": K and V per head (`Attention`; GQA when n_kv_heads < n_heads).
+    #: "mla": one latent per token (`models/mla.py`), sized by the five
+    #: numbers below under their published names; `rope_yarn` blends the
+    #: rotary frequencies of its `qk_rope_head_dim` dims.
+    attention: str = "mha"
+    q_lora_rank: int = 0  # 0: queries are projected straight from x
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_yarn: Optional[YarnScaling] = None
+    #: >0 makes the feed-forward of every layer from `moe_first_layer` on an
+    #: expert layer (`models/moe.py`) whose router scores this many experts:
+    #: the count a model publishes. The defaults are a top-2 softmax mixture
+    #: of experts `d_ff` wide, all held, their leading [E] axis sharded over
+    #: the "ep" mesh axis.
     moe_experts: int = 0
-    #: Width of a KV-cache row: head_dim when 0, else head_dim followed by
-    #: zeros that are never read. The serving engine sets it to the width
-    #: the device's compiler lays a row out in (llm/engine.py
+    moe_top_k: int = 2
+    moe_d_ff: int = 0  # an expert's width; 0: d_ff
+    moe_scoring: str = "softmax"  # or "sigmoid"
+    moe_norm_topk: bool = True  # weights sum to 1 over the selected
+    moe_routed_scale: float = 1.0
+    moe_score_bias: bool = False  # selection by score + a learnt bias
+    moe_shared_experts: int = 0  # SwiGLUs beside the routed ones, never routed
+    moe_first_layer: int = 0  # layers before it keep the dense SwiGLU
+    #: The experts held here, `[first_expert, first_expert + experts_held)`
+    #: of `moe_experts`; 0 holds them all. What the others would add to a
+    #: token is left out (one device's share under expert parallelism).
+    experts_held: int = 0
+    first_expert: int = 0
+    #: Rows of one expert in a tile of the expert layer's grouped path; the
+    #: serving prefill takes that path above two tiles' worth of rows.
+    moe_group_tile: int = 128
+    #: Width of a row of a cache leaf: the leaf's own width when 0 (head_dim
+    #: for K and V, kv_lora_rank + qk_rope_head_dim for a latent), else that
+    #: followed by zeros that are never read. The serving engine sets it to
+    #: the width the device's compiler lays such a row out in (llm/engine.py
     #: `_probe_cache_row`), so that the cache's default on-device layout
     #: is the one the decode loop computes in.
     cache_row: int = 0
@@ -53,28 +97,14 @@ class TransformerConfig:
     def head_dim(self) -> int:
         return self.d_model // self.n_heads
 
+    def is_moe_layer(self, i: int) -> bool:
+        return self.moe_experts > 0 and i >= self.moe_first_layer
 
-def _rope(x, positions, theta: float):
-    """Rotary position embeddings. x: [B, S, H, D], positions: [B, S]."""
-    d = x.shape[-1]
-    freqs = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    angles = positions[..., None].astype(jnp.float32) * freqs  # [B, S, D/2]
-    cos = jnp.cos(angles)[:, :, None, :]
-    sin = jnp.sin(angles)[:, :, None, :]
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
-    return out.astype(x.dtype)
-
-
-class RMSNorm(nn.Module):
-    eps: float = 1e-6
-
-    @nn.compact
-    def __call__(self, x):
-        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],), jnp.float32)
-        x32 = x.astype(jnp.float32)
-        norm = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + self.eps)
-        return (norm * scale).astype(x.dtype)
+    @property
+    def held_experts(self) -> int:
+        """Routed experts an expert layer holds here; 0 without expert
+        layers."""
+        return (self.experts_held or self.moe_experts) if self.moe_experts else 0
 
 
 class Attention(nn.Module):
@@ -156,70 +186,36 @@ class Attention(nn.Module):
             return out.astype(cfg.dtype)
 
 
-class SwiGLU(nn.Module):
-    cfg: TransformerConfig
-
-    @nn.compact
-    def __call__(self, x):
-        cfg = self.cfg
-        dense = lambda feats, name: nn.Dense(  # noqa: E731
-            feats, use_bias=False, name=name, dtype=cfg.dtype, param_dtype=cfg.param_dtype)
-        gate = nn.silu(dense(cfg.d_ff, "w_gate")(x))
-        up = dense(cfg.d_ff, "w_up")(x)
-        return dense(cfg.d_model, "w_down")(gate * up)
-
-
-class MoE(nn.Module):
-    """Top-2 mixture-of-experts SwiGLU, expert-parallel over "ep".
-
-    Expert weights carry a leading [E] axis sharded over the ep mesh axis;
-    each device computes its expert shard over all tokens and the combine
-    contraction reduces over ep (XLA inserts the collective). Dense
-    dispatch (no capacity/dropping) keeps the math exactly equal to the
-    single-device reference — the routing SEMANTICS and the ep sharding are
-    what the dryrun proves; capacity-based all_to_all dispatch is the
-    optimization seam."""
-
-    cfg: TransformerConfig
-
-    @nn.compact
-    def __call__(self, x):
-        cfg = self.cfg
-        e, dm, ff = cfg.moe_experts, cfg.d_model, cfg.d_ff
-        router = self.param("router", nn.initializers.normal(0.02),
-                            (dm, e), jnp.float32)
-        w_gate = self.param("w_gate", nn.initializers.lecun_normal(),
-                            (e, dm, ff), cfg.param_dtype)
-        w_up = self.param("w_up", nn.initializers.lecun_normal(),
-                          (e, dm, ff), cfg.param_dtype)
-        w_down = self.param("w_down", nn.initializers.lecun_normal(),
-                            (e, ff, dm), cfg.param_dtype)
-        logits = x.astype(jnp.float32) @ router  # [B, S, E]
-        probs = jax.nn.softmax(logits, axis=-1)
-        k = min(2, e)  # top-2 routing (top-1 when only one expert)
-        kth = jax.lax.top_k(probs, k)[0][..., -1:]  # k-th highest prob
-        gates = jnp.where(probs >= kth, probs, 0.0)
-        gates = gates / jnp.sum(gates, axis=-1, keepdims=True)  # renorm top-k
-        xc = x.astype(cfg.dtype)
-        gate_h = nn.silu(jnp.einsum("bsd,edf->ebsf", xc, w_gate.astype(cfg.dtype)))
-        up_h = jnp.einsum("bsd,edf->ebsf", xc, w_up.astype(cfg.dtype))
-        expert_out = jnp.einsum("ebsf,efd->ebsd", gate_h * up_h,
-                                w_down.astype(cfg.dtype))
-        return jnp.einsum("ebsd,bse->bsd", expert_out,
-                          gates.astype(cfg.dtype))
-
-
 class Block(nn.Module):
     cfg: TransformerConfig
+    #: this layer's feed-forward is the expert layer (cfg.is_moe_layer(i))
+    moe: bool = False
 
     @nn.compact
     def __call__(self, x, positions, decode: bool = False):
-        x = x + Attention(self.cfg, name="attn")(
-            RMSNorm(name="attn_norm")(x), positions, decode=decode)
-        mlp = (MoE(self.cfg, name="moe") if self.cfg.moe_experts
-               else SwiGLU(self.cfg, name="mlp"))
-        x = x + mlp(RMSNorm(name="mlp_norm")(x))
-        return x
+        cfg = self.cfg
+        attn = (MLA(cfg, name="attn") if cfg.attention == "mla"
+                else Attention(cfg, name="attn"))
+        x = x + attn(RMSNorm(cfg.norm_eps, name="attn_norm")(x), positions,
+                     decode=decode)
+        h = RMSNorm(cfg.norm_eps, name="mlp_norm")(x)
+        if self.moe:
+            return x + MoE(cfg, name="moe")(h, serving=decode)
+        return x + SwiGLU(cfg, name="mlp")(h)
+
+
+def output_head(module: nn.Module, cfg: TransformerConfig, x, emb):
+    """Logits [B, S, vocab] (f32) of final hidden states, by the embedding
+    when the head is tied to it, else by `lm_head` (a parameter of
+    `module`, made here). Vocab-sharded matmul over tp either way."""
+    with jax.named_scope("lm_head"):
+        if cfg.tie_embeddings:
+            return jnp.einsum("bsd,vd->bsv", x,
+                              emb.astype(cfg.dtype)).astype(jnp.float32)
+        head = module.param("lm_head", nn.initializers.normal(0.02),
+                            (cfg.d_model, cfg.vocab_size), cfg.param_dtype)
+        return jnp.einsum("bsd,dv->bsv", x,
+                          head.astype(cfg.dtype)).astype(jnp.float32)
 
 
 class Transformer(nn.Module):
@@ -229,8 +225,8 @@ class Transformer(nn.Module):
     def __call__(self, tokens, positions=None, decode: bool = False):
         """tokens: [B, S] int32 -> logits [B, S, vocab] (f32).
 
-        decode=True uses per-layer KV caches (flax "cache" collection):
-        pass `positions` (absolute) and apply with mutable=["cache"]."""
+        decode=True uses per-layer caches (flax "cache" collection): pass
+        `positions` (absolute) and apply with mutable=["cache"]."""
         cfg = self.cfg
         emb = self.param("tok_emb", nn.initializers.normal(0.02),
                          (cfg.vocab_size, cfg.d_model), cfg.param_dtype)
@@ -240,12 +236,10 @@ class Transformer(nn.Module):
         for i in range(cfg.n_layers):
             if not decode:
                 x = _seq_shard(x)
-            x = Block(cfg, name=f"layer_{i}")(x, positions, decode=decode)
-        x = RMSNorm(name="final_norm")(x)
-        # Tied output head (vocab-sharded matmul over tp).
-        with jax.named_scope("lm_head"):
-            return jnp.einsum("bsd,vd->bsv", x,
-                              emb.astype(cfg.dtype)).astype(jnp.float32)
+            x = Block(cfg, moe=cfg.is_moe_layer(i), name=f"layer_{i}")(
+                x, positions, decode=decode)
+        x = RMSNorm(cfg.norm_eps, name="final_norm")(x)
+        return output_head(self, cfg, x, emb)
 
 
 def _seq_shard(x):
@@ -284,6 +278,9 @@ def param_specs(params) -> dict:
 
     kernels are [in, out] (flax Dense); DenseGeneral qkv kernels are
     [d_model, heads, head_dim]; wo kernel is [heads, head_dim, d_model].
+    The expert layer's own leaves (`router`, `w_gate`/`w_up`/`w_down` with a
+    leading [E] axis) sit right under "moe"; its shared expert is a SwiGLU
+    under "moe/shared" and takes the dense rules.
     """
 
     def rule(path: tuple[str, ...], leaf):
@@ -292,8 +289,16 @@ def param_specs(params) -> dict:
         moe = "moe" in path
         if last == "tok_emb":
             return P("tp", "fsdp")  # vocab over tp, d_model over fsdp
+        if last == "lm_head":
+            return P("fsdp", "tp")
         if last == "router":
             return P("fsdp", None)
+        if last in ("wk_b", "wv_b"):
+            return P("tp", None, None)  # MLA: [heads, rank, dim]
+        if name == "wq_b":
+            return P(None, "tp", None)  # [q_lora_rank, heads, dim]
+        if name in ("wq_a", "wkv_a"):
+            return P("fsdp", None)  # into a latent every head reads
         if moe and last in ("w_gate", "w_up"):
             return P("ep", "fsdp", "tp")  # leading [E] axis over ep
         if moe and last == "w_down":

@@ -464,6 +464,14 @@ LLM_HOST_SYNC_SECONDS = Histogram(
     description="llm engine host-sync readback duration per decode drain",
     boundaries=[0.0005, 0.002, 0.01, 0.05, 0.2, 1.0, 5.0])
 
+#: Rows (token x selected expert) routed to the experts an engine holds,
+#: over all its expert layers: counted on the device inside the decode
+#: chunks and read with their tokens (llm/engine.py `_count_moe`).
+LLM_MOE_ROWS = Counter(
+    "rt_llm_moe_rows_total",
+    description="rows routed to the held experts in decode steps, summed "
+                "over expert layers")
+
 #: Pipeline-parallel serving (README "Pipeline-parallel serving"), drained
 #: each flush tick in processes hosting a PipelineStage: occupancy is the
 #: stage's busy fraction of the tick window, bubble its complement. A

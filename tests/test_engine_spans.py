@@ -276,6 +276,38 @@ def test_the_decode_steps_parts_carry_their_names_in_the_program(engine):
     assert {"prefill_attention", "mlp", "lm_head"} <= scopes_of(prefill)
 
 
+def test_the_latent_and_expert_parts_carry_their_names_in_the_program():
+    """The scopes a model with latent attention and expert layers adds to
+    the four above, in its decode step and in its prefill."""
+    import jax.numpy as jnp
+
+    arch = {"model_type": "kimi_k2", "intermediate_size": 96,
+            "q_lora_rank": 24, "kv_lora_rank": 16, "qk_nope_head_dim": 16,
+            "qk_rope_head_dim": 8, "v_head_dim": 16,
+            "moe_intermediate_size": 32, "n_routed_experts": 8,
+            "n_shared_experts": 1, "num_experts_per_tok": 2,
+            "first_k_dense_replace": 1, "norm_topk_prob": True,
+            "routed_scaling_factor": 2.5, "scoring_func": "sigmoid",
+            "rms_norm_eps": 1e-5, "rope_theta": 50000, "rope_scaling": None,
+            "tie_word_embeddings": False}
+    eng = ContinuousEngine(LLMConfig(**CFG, arch=arch, experts_held=4),
+                           max_batch=2, decode_chunk=4)
+    try:
+        eng._cache = eng._init_cache()
+        chunk = eng._chunk.lower(
+            eng.params, eng._cache, eng._toks_dev, eng._lens_dev, eng._keys,
+            eng._temps_dev, eng._topks_dev, eng._topps_dev, 2, False)
+        new = {"mla_attention", "moe_router", "moe_experts", "shared_expert"}
+        assert new | {"decode_attention", "mlp", "lm_head",
+                      "sampler"} <= scopes_of(chunk)
+        prefill = eng._prefill.lower(
+            eng.params, jnp.zeros((1, 8), jnp.int32), 3)
+        assert new | {"prefill_attention", "mlp",
+                      "lm_head"} <= scopes_of(prefill)
+    finally:
+        eng.shutdown()
+
+
 def test_a_capture_keeps_the_python_tracer_off_and_the_host_tracer_on(
         monkeypatch):
     import jax

@@ -224,7 +224,11 @@ def test_serve_handle_streaming(ray_start_2cpu):
 
 def test_cache_stats_name_the_layout_and_count_boundary_copies(engine):
     st = engine.cache_stats()
-    assert set(st) == {"cache_layout", "cache_boundary_copies"}
+    assert set(st) == {"cache_layout", "cache_boundary_copies",
+                       "cache_kind", "cache_bytes"}
+    assert st["cache_kind"] == "kv"
+    assert st["cache_bytes"] == (CFG.n_layers * 2 * 4 * CFG.max_seq
+                                 * CFG.d_model * 4)
     head_dim = CFG.d_model // CFG.n_heads
     assert st["cache_layout"].startswith(
         f"{CFG.dtype}[4, {CFG.max_seq}, {CFG.n_heads}, {head_dim}] Layout(")

@@ -38,10 +38,12 @@ def chip():
     return topo.devices[0]
 
 
-def build_compiled(chip, monkeypatch=None, row=None) -> ContinuousEngine:
+def build_compiled(chip, monkeypatch=None, row=None,
+                   cfg=None) -> ContinuousEngine:
     """An engine's compiled programs for `chip`, from shapes: no parameter
     is made, no thread started, nothing placed on a device. `row` stands
     in for the compiler's answer."""
+    CFG = cfg or globals()["CFG"]
     if row is not None:
         monkeypatch.setattr(ContinuousEngine, "_probe_cache_row",
                             lambda self, make_chunk: row)
@@ -95,3 +97,48 @@ def test_v5e_counter_sees_the_conversions_of_head_wide_rows(
     st = eng.cache_stats()
     assert st["cache_boundary_copies"] == 2 * 2 * CFG.n_layers
     assert "96] Layout(major_to_minor=(0, 2, 3, 1)" in st["cache_layout"]
+
+
+#: Kimi K2's latent row (kv_lora_rank 512 + qk_rope_head_dim 64 = 576
+#: values) and head dims at a hidden size and depth that compile in seconds.
+LATENT = LLMConfig(
+    vocab_size=512, d_model=256, n_layers=2, n_heads=2, max_seq=256,
+    dtype="bfloat16", experts_held=4,
+    arch={"model_type": "kimi_k2", "intermediate_size": 512,
+          "q_lora_rank": 128, "kv_lora_rank": 512, "qk_nope_head_dim": 128,
+          "qk_rope_head_dim": 64, "v_head_dim": 128,
+          "moe_intermediate_size": 128, "n_routed_experts": 16,
+          "n_shared_experts": 1, "num_experts_per_tok": 4,
+          "first_k_dense_replace": 1, "norm_topk_prob": True,
+          "routed_scaling_factor": 2.827, "scoring_func": "sigmoid",
+          "rms_norm_eps": 1e-5, "rope_theta": 50000,
+          "rope_scaling": {"type": "yarn", "factor": 64, "beta_fast": 32,
+                           "beta_slow": 1, "mscale": 1, "mscale_all_dim": 1,
+                           "original_max_position_embeddings": 4096},
+          "tie_word_embeddings": False})
+
+
+def test_v5e_latent_cache_rows_are_widened_to_their_tiles(chip):
+    """The question `_probe_cache_row` puts for K and V it puts for a latent
+    leaf too: 576 values come back as rows of 640 (five tiles of 128
+    lanes), and the chunk program of the latent-attention, expert-layer
+    model then takes and returns its cache without a copy; a prefill hands
+    on its bucket's rows at that width."""
+    eng = build_compiled(chip, cfg=LATENT)
+    assert eng.model.cfg.cache_row == 640
+    st = eng.cache_stats()
+    assert st["cache_kind"] == "latent"
+    assert st["cache_boundary_copies"] == 0
+    assert st["cache_layout"].startswith(
+        f"bfloat16[{MAX_BATCH}, {LATENT.max_seq}, 640] "
+        f"Layout(major_to_minor=(0, 1, 2), tiling=(")
+    assert st["cache_bytes"] == 2 * MAX_BATCH * LATENT.max_seq * 640 * 2
+    assert (st["experts_held"], st["experts_published"]) == (4, 16)
+    one = jax.eval_shape(eng._prefill, eng.params,
+                         jax.ShapeDtypeStruct((1, 64), jnp.int32), 5)[1]
+    assert {leaf.shape for leaf in jax.tree.leaves(one)} == {(1, 64, 640)}
+    # the chunk's token block carries the held experts' row counts: 4
+    # counts in one more column of 8 slots
+    block = jax.eval_shape(
+        eng._chunk, *eng._chunk_shapes(eng.params, eng._cache_spec, False))[2]
+    assert block.shape == (MAX_BATCH, 4 + 1)
