@@ -368,7 +368,9 @@ def serve_phase(serve, LLMConfig, build_openai_app, chips: int) -> dict:
         say(f"replica pid={st['pid']} kv cache {st['cache_layout']}; "
             f"{st['cache_boundary_copies']} whole-leaf copies in its chunk "
             f"program; peak device memory "
-            f"{st['memory_peak_bytes'] / 2**30:.2f} GiB")
+            f"{st['memory_peak_bytes'] / 2**30:.2f} GiB; decode steps "
+            f"walked {st['kv_walk_share']:.3f} of max_seq "
+            f"(live rows {st['kv_live_share']:.3f})")
         # The cache must cross a program's boundary in the layout the
         # decode loop computes in: a copy of a whole leaf there is a
         # conversion paid by every chunk, whatever its length.

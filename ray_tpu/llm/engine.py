@@ -20,8 +20,10 @@ engine workers via vllm_models.py:123-137). TPU-native design:
 - **Chunked decode**: between admission points the engine runs
   `decode_chunk` single-token steps under ONE lax.scan dispatch,
   amortizing host->device latency while bounding join latency to a few
-  tokens. Single-token attention runs the Pallas decode kernel
-  (ops/decode_attention.py) against the slot cache.
+  tokens. Single-token attention (ops/decode_attention.py) reads a
+  static prefix of the slot cache, chosen inside the program from the
+  one number the scheduler hands each chunk: `kv_bound`, the rows its
+  longest LIVE slot will have (`_run_scheduler`).
 - **In-graph sampling**: temperature / top-k / top-p / greedy are
   vectorized per-slot inside the compiled step (each slot carries its own
   sampling params and PRNG key), so mixed request settings share a batch.
@@ -611,14 +613,26 @@ class ContinuousEngine:
         self._moe_held = self.model.cfg.held_experts
         self._moe_cols = -(-self._moe_held // self.max_batch)
         self.moe_rows_total = 0
+        # The decode steps dispatched since start, the cache rows they
+        # walked a slot, and the rows a live slot had on average
+        # (`cache_stats`: kv_walk_share, kv_live_share).
+        self._kv_steps = 0
+        self._kv_walked = 0
+        self._kv_live = 0.0
 
         def make_chunk(model):
             held = model.cfg.held_experts
 
             def chunk(params, cache, toks, lengths, keys, temp, top_k, top_p,
-                      n: int, greedy: bool):
+                      n: int, greedy: bool, kv_bound=None):
                 """n in-flight decode steps under one scan. toks/lengths
                 [B]; returns (cache, keys, tokens [B, n], lengths [B]).
+                `kv_bound` (int32 scalar, traced: one program whatever its
+                value) is the most cache rows any LIVE slot has after
+                these n steps; attention stops at the shortest static
+                prefix that holds them (ops/decode_attention.py
+                `over_kv_prefix`), the same in every step of the chunk.
+                Without it every step walks all max_seq rows.
                 greedy=True compiles an argmax-only variant: the sampler's
                 two full-vocab sorts per step are pure waste when no active
                 slot samples. A model with expert layers appends to the
@@ -630,6 +644,7 @@ class ContinuousEngine:
                     logits, vars_out = model.apply(
                         {"params": params, "cache": cache}, tok[:, None],
                         positions=lens[:, None], decode=True,
+                        kv_bound=kv_bound,
                         mutable=["cache", "stats"] if held else ["cache"])
                     if held:
                         rows = [sum(jax.tree.leaves(vars_out.get("stats", {})),
@@ -751,7 +766,8 @@ class ContinuousEngine:
                 jax.ShapeDtypeStruct((b,), jnp.float32),
                 jax.ShapeDtypeStruct((b,), jnp.int32),
                 jax.ShapeDtypeStruct((b,), jnp.float32),
-                self.decode_chunk, greedy)
+                self.decode_chunk, greedy,
+                jax.ShapeDtypeStruct((), jnp.int32))
 
     def _probe_cache_row(self, make_chunk) -> int:
         """The row width the compiler wants for the cache, 0 for the one it
@@ -777,7 +793,7 @@ class ContinuousEngine:
             lambda leaf: Format(Layout.AUTO, leaf.sharding), cache)
         probe = jax.jit(make_chunk(one), static_argnums=(8, 9),
                         donate_argnums=(1,),
-                        in_shardings=(None, auto) + (None,) * 6,
+                        in_shardings=(None, auto) + (None,) * 7,
                         out_shardings=(auto, None, None, None))
         wanted = probe.lower(
             *self._chunk_shapes(params, cache, True)
@@ -817,18 +833,26 @@ class ContinuousEngine:
                     self.decode_chunk)
 
     def cache_stats(self) -> dict:
-        """For /v1/stats: the cache's leaves and their on-device layout, and
-        how many copies of a whole leaf the longest sampled chunk program
-        makes (a conversion at its boundary; 0 wanted)."""
+        """For /v1/stats: the cache's leaves and their on-device layout, how
+        many copies of a whole leaf the longest sampled chunk program makes
+        (a conversion at its boundary; 0 wanted), and, over the decode
+        steps dispatched since start, the share of `max_seq` rows a step's
+        attention walked (`kv_walk_share`: the prefix its chunk's
+        `kv_bound` chose) beside the share its live slots had written on
+        average (`kv_live_share`: what a walk that stopped at each slot's
+        own length would read)."""
         import jax
 
         mcfg = self.model.cfg
+        rows = max(1, self._kv_steps) * mcfg.max_seq
         out = {"cache_layout": self.cache_layout,
                "cache_boundary_copies": self.cache_boundary_copies,
                "cache_kind": "latent" if mcfg.attention == "mla" else "kv",
                "cache_bytes": sum(
                    leaf.size * leaf.dtype.itemsize
-                   for leaf in jax.tree.leaves(self._cache_spec))}
+                   for leaf in jax.tree.leaves(self._cache_spec)),
+               "kv_walk_share": self._kv_walked / rows,
+               "kv_live_share": self._kv_live / rows}
         if self._moe_held:
             out.update(experts_held=self._moe_held,
                        experts_published=mcfg.moe_experts,
@@ -1176,6 +1200,9 @@ class ContinuousEngine:
         chunk still steps (the _cooling set)."""
         import jax.numpy as jnp
 
+        from ray_tpu.ops.decode_attention import kv_prefix_rows
+
+        max_seq = self.cfg.max_seq
         phases = _Phases(self._jax.profiler)
         while self._running:
             # Tracing on: the pass's phases on the host's and the
@@ -1261,11 +1288,11 @@ class ContinuousEngine:
                           if s is not None]
                 if not active:
                     break
+                live = [int(self._lengths[i]) for i in active]
                 budget = int(min(
                     min(self._slots[i].remaining - self._pending_toks[i]
                         for i in active),
-                    min(self.cfg.max_seq - int(self._lengths[i])
-                        for i in active)))
+                    max_seq - max(live)))
                 if budget < 1:
                     break  # every active slot's fate is already in flight
                 # Power-of-2 chunk sizes only: each distinct scan length
@@ -1283,6 +1310,14 @@ class ContinuousEngine:
                 tctx = next((self._slots[i].stream.trace for i in active
                              if self._slots[i].stream.trace is not None),
                             None)
+                # The rows the longest LIVE slot has after these n steps:
+                # where the chunk's attention may stop. Only the host can
+                # say it, from integers it holds: an idle or cooling slot's
+                # device-side length is stale and keeps growing, and what
+                # such a slot decodes is discarded (_deliver).
+                kv_bound = max(live) + n
+                assert kv_bound <= max_seq and all(
+                    length + n <= kv_bound for length in live), (live, n)
                 try:
                     t_disp = time.time()
                     self._cache, self._keys, toks_out, lens_out = \
@@ -1290,7 +1325,8 @@ class ContinuousEngine:
                             self.params, self._cache,
                             self._toks_dev, self._lens_dev,
                             self._keys, self._temps_dev,
-                            self._topks_dev, self._topps_dev, n, greedy)
+                            self._topks_dev, self._topps_dev, n, greedy,
+                            np.int32(kv_bound))
                     # Start the device→host copy of this chunk's tokens
                     # NOW: by the time the drain reads it (D iterations
                     # later), the transfer has overlapped the younger
@@ -1299,9 +1335,16 @@ class ContinuousEngine:
                         toks_out.copy_to_host_async()
                     except Exception:
                         pass  # backend without async copy: read pays it
+                    kv_rows = kv_prefix_rows(kv_bound, max_seq)
                     _tracing.record_span_in(
                         tctx, "engine.dispatch_chunk", "engine", t_disp,
-                        time.time(), {"tokens": n, "active": len(active)})
+                        time.time(), {"tokens": n, "active": len(active),
+                                      "kv_bound": kv_bound,
+                                      "kv_rows": kv_rows})
+                    self._kv_steps += n
+                    self._kv_walked += n * kv_rows
+                    # step j of the chunk sees length + j + 1 rows of a slot
+                    self._kv_live += n * (sum(live) / len(live) + (n + 1) / 2)
                     # Chain on device; mirror lengths on host (every slot
                     # steps n times — deterministic, no read needed).
                     self._toks_dev = toks_out[:, n - 1]

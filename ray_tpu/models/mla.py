@@ -20,12 +20,15 @@ Two paths compute the same attention:
 
 from __future__ import annotations
 
+import functools
+
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.layers import (RMSNorm, rope_cos_sin_scale, rope_interleaved,
                                    rope_inv_freq, yarn_mscale)
+from ray_tpu.ops.decode_attention import over_kv_prefix
 
 #: Most bytes of float32 scores one tile of queries may hold in the expanded
 #: path (heads x tile x keys x 4).
@@ -46,7 +49,7 @@ class MLA(nn.Module):
     cfg: "TransformerConfig"  # noqa: F821 - models/transformer.py
 
     @nn.compact
-    def __call__(self, x, positions, decode: bool = False):
+    def __call__(self, x, positions, decode: bool = False, kv_bound=None):
         cfg = self.cfg
         heads, rank = cfg.n_heads, cfg.kv_lora_rank
         nope, rot, vdim = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
@@ -81,7 +84,7 @@ class MLA(nn.Module):
             scale = softmax_scale(cfg)
             if decode:
                 out = self._cached(q_nope, q_rope, c_kv, k_rope, wk_b, wv_b,
-                                   positions, scale)
+                                   positions, scale, kv_bound)
             else:
                 out = _expanded(q_nope, q_rope, c_kv, k_rope, wk_b, wv_b,
                                 positions, scale)
@@ -90,12 +93,15 @@ class MLA(nn.Module):
                                param_dtype=cfg.param_dtype)(out)
 
     def _cached(self, q_nope, q_rope, c_kv, k_rope, wk_b, wv_b, positions,
-                scale):
+                scale, kv_bound=None):
         """Serving: one cache leaf `[slots, max_seq, row]` a layer, each
         sequence's rows written at its own absolute positions (as
         `Attention._cached_attention` writes K and V). A single-token step
         attends in the latent space over the sequence's rows up to its
-        position; a multi-token step is a prefill from position 0 and
+        position, or, given `kv_bound`, over the shortest static prefix of
+        them that holds that many (`ops/decode_attention.py`
+        `over_kv_prefix`: a latent row is the one-KV-head case); a
+        multi-token step is a prefill from position 0 and
         attends over its own rows, expanded, and only writes the latents.
         `row` is `kv_lora_rank + qk_rope_head_dim`, or wider when the
         engine found that the device lays such rows out in wider tiles
@@ -117,20 +123,50 @@ class MLA(nn.Module):
                 return _expanded(q_nope, q_rope, c_kv, k_rope, wk_b, wv_b,
                                  positions, scale)
         with jax.named_scope("decode_attention"):
-            latents = cache.value
             q_lat = jnp.einsum("bhn,hcn->bhc", q_nope[:, 0], wk_b)
-            scores = (
-                jnp.einsum("bhc,btc->bht", q_lat, latents[..., :rank],
-                           preferred_element_type=jnp.float32)
-                + jnp.einsum("bhr,btr->bht", q_rope[:, 0],
-                             latents[..., rank:width],
-                             preferred_element_type=jnp.float32)) * scale
-            visible = jnp.arange(cfg.max_seq)[None, None, :] <= pos[:, :, None]
-            probs = jax.nn.softmax(jnp.where(visible, scores, -jnp.inf), -1)
-            o_lat = jnp.einsum("bht,btc->bhc", probs.astype(cfg.dtype),
-                               latents[..., :rank])
+            if kv_bound is None:
+                o_lat = _latent_attention(q_lat, q_rope, cache.value, pos,
+                                          rank, width, scale)
+            else:
+                o_lat = _latent_walk(q_lat, q_rope, cache.value, pos,
+                                     kv_bound, rank, width, scale)
             out = jnp.einsum("bhc,hcv->bhv", o_lat, wv_b)
             return out[:, None].astype(cfg.dtype)
+
+
+def _latent_attention(q_lat, q_rope, latents, pos, rank, width, scale):
+    """One query a head (`q_lat` [slots, heads, rank] in the latent space,
+    `q_rope` [slots, 1, heads, rot]) against the rows `[slots, rows, row]`
+    it is given: f32 scores, the weighted sum of the `c_kv` part in the
+    cache's dtype."""
+    scores = jnp.einsum("bhc,btc->bht", q_lat, latents[..., :rank],
+                        preferred_element_type=jnp.float32)
+    q_rope = q_rope[:, 0]
+    # The rotary key is read to the END of the row, against a query that is
+    # zeros there as the row is: cut at `width` inside a row of whole lane
+    # tiles, the v5e's compiler copies the WHOLE leaf into another layout
+    # in every branch of a bounded walk (PERF.md section 6, PR 29).
+    if latents.shape[-1] > width:
+        q_rope = jnp.pad(
+            q_rope, ((0, 0), (0, 0), (0, latents.shape[-1] - width)))
+    scores = (scores + jnp.einsum("bhr,btr->bht", q_rope, latents[..., rank:],
+                                  preferred_element_type=jnp.float32)) * scale
+    visible = jnp.arange(latents.shape[1])[None, None, :] <= pos[:, :, None]
+    probs = jax.nn.softmax(jnp.where(visible, scores, -jnp.inf), -1)
+    return jnp.einsum("bht,btc->bhc", probs.astype(latents.dtype),
+                      latents[..., :rank])
+
+
+@functools.partial(jax.jit, static_argnums=(5, 6, 7))
+def _latent_walk(q_lat, q_rope, latents, pos, kv_bound, rank, width, scale):
+    """`_latent_attention` over the prefix of the rows that `kv_bound`
+    picks (`over_kv_prefix`: a latent row is its one-KV-head case).
+    Jitted, so that the layers of a model share one trace and one function
+    of the lowered module."""
+    return over_kv_prefix(
+        lambda rows: _latent_attention(q_lat, q_rope, rows, pos, rank, width,
+                                       scale),
+        (latents,), kv_bound)
 
 
 def _expanded(q_nope, q_rope, c_kv, k_rope, wk_b, wv_b, positions, scale):

@@ -111,7 +111,7 @@ class Attention(nn.Module):
     cfg: TransformerConfig
 
     @nn.compact
-    def __call__(self, x, positions, decode: bool = False):
+    def __call__(self, x, positions, decode: bool = False, kv_bound=None):
         cfg = self.cfg
         hd = cfg.head_dim
         dense = lambda feats, name: nn.DenseGeneral(  # noqa: E731
@@ -123,13 +123,13 @@ class Attention(nn.Module):
         q = _rope(q, positions, cfg.rope_theta)
         k = _rope(k, positions, cfg.rope_theta)
         if decode:
-            out = self._cached_attention(q, k, v, positions)
+            out = self._cached_attention(q, k, v, positions, kv_bound)
         else:
             out = dot_product_attention(q, k, v, causal=True)
         return nn.DenseGeneral(cfg.d_model, axis=(-2, -1), use_bias=False, name="wo",
                                dtype=cfg.dtype, param_dtype=cfg.param_dtype)(out)
 
-    def _cached_attention(self, q, k, v, positions):
+    def _cached_attention(self, q, k, v, positions, kv_bound=None):
         """Autoregressive KV-cache attention with PER-SEQUENCE positions
         (reference role: vLLM's paged KV cache; here slot-per-sequence):
         new k/v rows scatter into fixed [B, max_seq, KV, D] buffers at each
@@ -140,8 +140,14 @@ class Attention(nn.Module):
         position are never visible, so stale pad/previous-request garbage
         in the slot can never leak into attention. Single-token steps
         (S==1, the serving hot loop) go through the decode-attention
-        dispatcher (ops/decode_attention.py: fused XLA or the Pallas
-        kernel, by cache size); multi-token steps (prefill) run the dense
+        dispatcher (ops/decode_attention.py: the fused XLA path in every
+        configuration served so far). Given `kv_bound` (a traced int32
+        scalar from the engine's scheduler: the longest LIVE sequence's
+        length after this chunk, which only the host knows, because a
+        retired slot's device-side position keeps growing), such a step
+        reads the shortest static prefix of the cache that holds that many
+        rows instead of all `max_seq`; without it, the whole cache, by the
+        program it always was. Multi-token steps (prefill) run the dense
         f32 einsum below over the whole cache."""
         cfg = self.cfg
         b, s = q.shape[0], q.shape[1]
@@ -160,12 +166,15 @@ class Attention(nn.Module):
         ck.value = ck.value.at[bidx, pos].set(k)
         cv.value = cv.value.at[bidx, pos].set(v)
         keys, vals = ck.value, cv.value
-        if row > d:
+        if row > d and (s > 1 or kv_bound is None):
+            # (a bounded step hands the leaves over whole: its branch cuts
+            # rows and head together, where the cache lies)
             keys, vals = keys[..., :d], vals[..., :d]
         if s == 1:
             from ray_tpu.ops.decode_attention import decode_attention
 
-            out = decode_attention(q[:, 0], keys, vals, pos[:, 0] + 1)
+            out = decode_attention(q[:, 0], keys, vals, pos[:, 0] + 1,
+                                   kv_bound=kv_bound)
             return out[:, None].astype(cfg.dtype)
         with jax.named_scope("prefill_attention"):
             if cfg.n_kv_heads < cfg.n_heads:  # GQA: broadcast kv heads
@@ -192,12 +201,12 @@ class Block(nn.Module):
     moe: bool = False
 
     @nn.compact
-    def __call__(self, x, positions, decode: bool = False):
+    def __call__(self, x, positions, decode: bool = False, kv_bound=None):
         cfg = self.cfg
         attn = (MLA(cfg, name="attn") if cfg.attention == "mla"
                 else Attention(cfg, name="attn"))
         x = x + attn(RMSNorm(cfg.norm_eps, name="attn_norm")(x), positions,
-                     decode=decode)
+                     decode=decode, kv_bound=kv_bound)
         h = RMSNorm(cfg.norm_eps, name="mlp_norm")(x)
         if self.moe:
             return x + MoE(cfg, name="moe")(h, serving=decode)
@@ -222,11 +231,15 @@ class Transformer(nn.Module):
     cfg: TransformerConfig
 
     @nn.compact
-    def __call__(self, tokens, positions=None, decode: bool = False):
+    def __call__(self, tokens, positions=None, decode: bool = False,
+                 kv_bound=None):
         """tokens: [B, S] int32 -> logits [B, S, vocab] (f32).
 
         decode=True uses per-layer caches (flax "cache" collection): pass
-        `positions` (absolute) and apply with mutable=["cache"]."""
+        `positions` (absolute) and apply with mutable=["cache"]. A
+        single-token decode step may also be told `kv_bound`, how many
+        cache rows its longest sequence of interest has
+        (`Attention._cached_attention`)."""
         cfg = self.cfg
         emb = self.param("tok_emb", nn.initializers.normal(0.02),
                          (cfg.vocab_size, cfg.d_model), cfg.param_dtype)
@@ -237,7 +250,7 @@ class Transformer(nn.Module):
             if not decode:
                 x = _seq_shard(x)
             x = Block(cfg, moe=cfg.is_moe_layer(i), name=f"layer_{i}")(
-                x, positions, decode=decode)
+                x, positions, decode=decode, kv_bound=kv_bound)
         x = RMSNorm(cfg.norm_eps, name="final_norm")(x)
         return output_head(self, cfg, x, emb)
 

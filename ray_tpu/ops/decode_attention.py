@@ -161,6 +161,77 @@ def _xla_decode_attention(q, k_cache, v_cache, lengths):
     return out.astype(q.dtype)
 
 
+#: A bounded decode step walks one of this many static prefixes of the
+#: cache: quarters of `max_seq`, the whole cache the last of them.
+KV_PREFIXES = 4
+
+
+def kv_prefixes(max_seq: int) -> tuple[int, ...]:
+    """The row counts a bounded step can stop at, ascending; the last is
+    `max_seq`."""
+    width = -(-max_seq // KV_PREFIXES)
+    return tuple(range(width, max_seq, width)) + (max_seq,)
+
+
+def kv_prefix_rows(kv_bound: int, max_seq: int) -> int:
+    """The rows a step with this bound walks: the shortest prefix that
+    holds `kv_bound` rows (host integers; `over_kv_prefix` picks the same
+    one inside the program)."""
+    return next(t for t in kv_prefixes(max_seq) if t >= min(kv_bound, max_seq))
+
+
+def over_kv_prefix(attend, leaves, kv_bound):
+    """`attend(*leaves)` over the shortest of `kv_prefixes` that holds
+    `kv_bound` rows of the cache leaves `[B, max_seq, ...]`. `kv_bound` is a
+    traced int32 scalar: no row at or beyond it is visible to a sequence
+    whose output is used. The rows left out are rows whose softmax weight
+    `attend`'s own mask makes exactly zero, so the result is the whole
+    cache's. The prefix is static in each branch of one `lax.switch`, which
+    takes the leaves as operands: a branch reads a slice of the cache where
+    it lies, and the step has no loop in it."""
+    ends = kv_prefixes(leaves[0].shape[1])
+    index = jnp.clip((kv_bound - 1) // ends[0], 0, len(ends) - 1)
+    return jax.lax.switch(
+        index,
+        [lambda *ls, t=t: attend(*(leaf[:, :t] for leaf in ls)) for t in ends],
+        *leaves)
+
+
+@jax.jit
+def _xla_decode_walk(q, k_cache, v_cache, lengths, kv_bound):
+    """`_xla_decode_attention` over the prefix `kv_bound` picks, taking the
+    cache leaves whole: a row wider than q's head
+    (`TransformerConfig.cache_row`) is cut with the prefix, inside the
+    branch. Jitted, so that the layers of a model share one trace and one
+    function of the lowered module. Two things differ from the whole walk's
+    text, neither in what is computed. The scores' product is written out
+    as what it is, one query row a head times the keys, multiplied and
+    summed in f32: outside a `conditional` the TPU's compiler makes
+    exactly that of the einsum (the `multiply_reduce_fusion` of a chunk's
+    trace) and reads the cache where it lies, inside one it keeps the
+    einsum a convolution and feeds it a transposed copy of the prefix
+    (PERF.md section 6, PR 29). And what no branch needs its own copy of,
+    the query in f32 and the mask, is made once outside them."""
+    _, hq, d = q.shape
+    q32 = q.astype(jnp.float32)
+    visible = jnp.arange(k_cache.shape[1])[None, :] < lengths[:, None]
+
+    def attend(k, v):
+        k, v = k[..., :d], v[..., :d]
+        if k.shape[2] < hq:
+            k = jnp.repeat(k, hq // k.shape[2], axis=2)
+            v = jnp.repeat(v, hq // v.shape[2], axis=2)
+        scores = jnp.swapaxes(
+            jnp.sum(q32[:, None] * k.astype(jnp.float32), axis=-1), 1, 2)
+        scores = jnp.where(visible[:, None, :k.shape[1]],
+                           scores / (d ** 0.5), NEG_INF)
+        probs = jax.nn.softmax(scores, axis=-1)
+        out = jnp.einsum("bht,bthd->bhd", probs, v.astype(jnp.float32))
+        return out.astype(q.dtype)
+
+    return over_kv_prefix(attend, (k_cache, v_cache), kv_bound)
+
+
 #: Cache bytes above which the Pallas kernel dispatches by default. At
 #: serving-typical sizes (B=8, KV=16, D=64, S=1024: ~2x16MB bf16) the
 #: fused XLA einsum was the faster of the two when last compared (1.44 vs
@@ -198,21 +269,34 @@ def choose_impl(q_shape, cache_shape, cache_itemsize: int, *,
     return "pallas", f"k+v cache of {cache_bytes} bytes"
 
 
-def decode_attention(q, k_cache, v_cache, lengths, *, interpret: bool = False):
-    """Dispatcher: `choose_impl` picks between the fused XLA path and the
-    Pallas streaming kernel up front and the choice is stated once at INFO;
-    nothing is caught, so a forced kernel on a shape it rejects, or a
-    kernel that fails to compile, raises. `interpret=True` runs the kernel
-    in the Pallas interpreter on any backend (tests).
-    q: [B, H, D]; caches [B, S, KV, D]; lengths [B] -> [B, H, D]."""
+def decode_attention(q, k_cache, v_cache, lengths, *, kv_bound=None,
+                     interpret: bool = False):
+    """One new token a sequence against its cache rows `[0, lengths[b])`.
+    q: [B, H, D]; caches [B, S, KV, D]; lengths [B] -> [B, H, D].
+
+    `choose_impl` picks the implementation up front and the choice is
+    stated once at INFO; nothing is caught, so a forced kernel on a shape
+    it rejects, or a kernel that fails to compile, raises. Every serving
+    configuration measured so far takes the fused XLA path (the Pallas
+    streaming kernel only above `PALLAS_MIN_CACHE_BYTES` of cache, or when
+    forced). On the XLA path a `kv_bound` (`over_kv_prefix`: the longest
+    live sequence's rows, from whoever knows which sequences are live)
+    stops the walk at a static prefix of the cache instead of S, and the
+    caches may then come with rows wider than D (zeros beyond it), which
+    are cut with the prefix; `lengths` still masks each sequence inside
+    it. Without one the whole cache is walked, by the program this always
+    built. The Pallas kernel takes no bound: it skips the arithmetic of
+    blocks beyond a sequence's length itself. `interpret=True` runs the
+    kernel in the Pallas interpreter on any backend (tests)."""
     from ray_tpu._private.rtconfig import CONFIG
     from ray_tpu.ops.attention import _state_once
 
+    d = q.shape[-1]
     if interpret:
         impl, why = "pallas", "interpret mode"
     else:
         impl, why = choose_impl(
-            q.shape, k_cache.shape, k_cache.dtype.itemsize,
+            q.shape, k_cache.shape[:-1] + (d,), k_cache.dtype.itemsize,
             backend=jax.default_backend(),
             force=str(CONFIG.decode_kernel).lower())
     _state_once(f"decode attention: {impl} ({why}; q {tuple(q.shape)}, "
@@ -220,6 +304,10 @@ def decode_attention(q, k_cache, v_cache, lengths, *, interpret: bool = False):
     # One name for both paths in a device trace (operation metadata only).
     with jax.named_scope("decode_attention"):
         if impl == "pallas":
+            if k_cache.shape[-1] > d:
+                k_cache, v_cache = k_cache[..., :d], v_cache[..., :d]
             return decode_attention_pallas(
                 q, k_cache, v_cache, lengths, interpret=interpret)
-        return _xla_decode_attention(q, k_cache, v_cache, lengths)
+        if kv_bound is None:
+            return _xla_decode_attention(q, k_cache, v_cache, lengths)
+        return _xla_decode_walk(q, k_cache, v_cache, lengths, kv_bound)

@@ -252,11 +252,60 @@ def test_a_profile_of_a_traced_engine_holds_its_phases_in_a_host_plane(
     assert ev.duration_ns > 0
 
 
+def op_names(lowered) -> list:
+    return re.findall(r'op_name="([^"]+)"', lowered.compile().as_text())
+
+
 def scopes_of(lowered) -> set:
     """Every component of every operation's name in a compiled program."""
-    text = lowered.compile().as_text()
-    return {part for name in re.findall(r'op_name="([^"]+)"', text)
-            for part in name.split("/")}
+    return {part for name in op_names(lowered) for part in name.split("/")}
+
+
+def in_branches(lowered, *scopes) -> bool:
+    """Some operation inside a branch of a `lax.switch` carries these
+    scopes, in this order, before the branch's own name: the walk's
+    branches (ops/decode_attention.py `over_kv_prefix`) sit inside them."""
+    pattern = "/.*".join(scopes) + r"/.*branch_\d+_fun/"
+    return any(re.search(pattern, name) for name in op_names(lowered))
+
+
+def test_a_chunks_kv_bound_follows_the_live_slots_only(engine, spans):
+    """A long request retires while a short one decodes on: the bound on
+    the `engine.dispatch_chunk` spans falls to the short one's rows, though
+    the retired slot's device-side length is stale and keeps growing; the
+    rows walked are the bound's prefix; and both requests' greedy tokens
+    are the whole-cache engine's."""
+    import numpy as np
+
+    from ray_tpu.llm import LLMEngine
+    from ray_tpu.ops.decode_attention import kv_prefix_rows
+
+    long_prompt, short_prompt = list(range(1, 45)), [5, 6, 7]
+    greedy = dict(temperature=0.0)
+    tracing._ctx.set(("a" * 32, "b" * 16))
+    long = engine.submit(long_prompt, SamplingParams(max_tokens=4, **greedy))
+    short = engine.submit(short_prompt,
+                          SamplingParams(max_tokens=24, **greedy))
+    tracing._ctx.set(None)
+    got_long, got_short = long.tokens(), short.tokens()
+    chunks = sorted((s for s in spans if s["n"] == "engine.dispatch_chunk"),
+                    key=lambda s: s["a"])
+    bounds = [s["at"]["kv_bound"] for s in chunks]
+    max_seq = CFG["max_seq"]
+    assert all(s["at"]["kv_rows"] == kv_prefix_rows(s["at"]["kv_bound"],
+                                                    max_seq) for s in chunks)
+    # While the long request lives, its rows set the bound: 44 and at most
+    # 4 steps (its first token comes from the prefill, so 3 would do; the
+    # first chunk is sized before that token is delivered) ...
+    assert len(long_prompt) < max(bounds) <= len(long_prompt) + 4 <= max_seq
+    # ... and once it has retired the short one's 3 + 23 rows do, in a
+    # prefix the long one's rows would not fit
+    assert bounds[-1] == len(short_prompt) + 23
+    assert chunks[-1]["at"]["kv_rows"] < len(long_prompt)
+    ref = LLMEngine(LLMConfig(**CFG, params={"params": engine.params}))
+    for prompt, got in ((long_prompt, got_long), (short_prompt, got_short)):
+        want = ref.generate(np.asarray([prompt]), len(got))[0, len(prompt):]
+        assert got == want.tolist()
 
 
 def test_the_decode_steps_parts_carry_their_names_in_the_program(engine):
@@ -268,9 +317,10 @@ def test_the_decode_steps_parts_carry_their_names_in_the_program(engine):
     chunk = engine._chunk.lower(
         engine.params, engine._cache, engine._toks_dev, engine._lens_dev,
         engine._keys, engine._temps_dev, engine._topks_dev,
-        engine._topps_dev, 2, False)
+        engine._topps_dev, 2, False, jnp.int32(9))
     assert {"decode_attention", "mlp", "lm_head",
             "sampler"} <= scopes_of(chunk)
+    assert in_branches(chunk, "decode_attention")
     prefill = engine._prefill.lower(
         engine.params, jnp.zeros((1, 8), jnp.int32), 3)
     assert {"prefill_attention", "mlp", "lm_head"} <= scopes_of(prefill)
@@ -296,10 +346,12 @@ def test_the_latent_and_expert_parts_carry_their_names_in_the_program():
         eng._cache = eng._init_cache()
         chunk = eng._chunk.lower(
             eng.params, eng._cache, eng._toks_dev, eng._lens_dev, eng._keys,
-            eng._temps_dev, eng._topks_dev, eng._topps_dev, 2, False)
+            eng._temps_dev, eng._topks_dev, eng._topps_dev, 2, False,
+            jnp.int32(9))
         new = {"mla_attention", "moe_router", "moe_experts", "shared_expert"}
         assert new | {"decode_attention", "mlp", "lm_head",
                       "sampler"} <= scopes_of(chunk)
+        assert in_branches(chunk, "mla_attention", "decode_attention")
         prefill = eng._prefill.lower(
             eng.params, jnp.zeros((1, 8), jnp.int32), 3)
         assert new | {"prefill_attention", "mlp",

@@ -225,7 +225,9 @@ def test_serve_handle_streaming(ray_start_2cpu):
 def test_cache_stats_name_the_layout_and_count_boundary_copies(engine):
     st = engine.cache_stats()
     assert set(st) == {"cache_layout", "cache_boundary_copies",
-                       "cache_kind", "cache_bytes"}
+                       "cache_kind", "cache_bytes", "kv_walk_share",
+                       "kv_live_share"}
+    assert 0.0 <= st["kv_live_share"] <= st["kv_walk_share"] <= 1.0
     assert st["cache_kind"] == "kv"
     assert st["cache_bytes"] == (CFG.n_layers * 2 * 4 * CFG.max_seq
                                  * CFG.d_model * 4)

@@ -142,3 +142,33 @@ def test_v5e_latent_cache_rows_are_widened_to_their_tiles(chip):
     block = jax.eval_shape(
         eng._chunk, *eng._chunk_shapes(eng.params, eng._cache_spec, False))[2]
     assert block.shape == (MAX_BATCH, 4 + 1)
+
+
+@pytest.mark.parametrize("name", ["kv", "latent"])
+def test_v5e_bounded_chunk_program_branches_once_a_layer_and_copies_no_rows(
+        chip, name):
+    """The chunk program the scheduler dispatches (with a `kv_bound`): one
+    `conditional` a layer, whose branches read their prefix of the cache
+    where it lies. Written as an einsum over a slice, the v5e's compiler
+    fed each branch a transposed COPY of the K prefix, and cutting the
+    latent's rotary columns inside a tile made it copy the whole leaf in
+    every branch (PERF.md section 6, PR 29): no copy of a leaf, whole or
+    any prefix of its rows, may be in the text. Without the bound the
+    program has no `conditional`, as before there was one."""
+    from ray_tpu.ops.decode_attention import kv_prefixes
+
+    cfg = {"kv": CFG, "latent": LATENT}[name]
+    eng = build_compiled(chip, cfg=cfg)
+    assert eng.cache_boundary_copies == 0  # whole leaves, branches included
+    shapes = eng._chunk_shapes(eng.params, eng._cache_spec, False)
+    assert shapes[-1].shape == () and shapes[-1].dtype == jnp.int32
+    text = eng._chunk.lower(*shapes).compile().as_text()
+    assert len(re.findall(r" conditional\(", text)) == cfg.n_layers
+    for leaf in jax.tree.leaves(eng._cache_spec):
+        slots, _rows, *rest = leaf.shape
+        for rows in kv_prefixes(cfg.max_seq):
+            dims = ",".join([str(slots), str(rows)] + [r"\d+"] * len(rest))
+            assert not re.findall(r"= \w+\[%s\]\S* copy\(" % dims, text), (
+                leaf.shape, rows)
+    unbounded = eng._chunk.lower(*shapes[:-1]).compile().as_text()
+    assert " conditional(" not in unbounded
