@@ -7,8 +7,8 @@ the entry points a user calls, at the full width of the widest model this
 repository has run (1024d x 8L, bf16, random weights from a seed), with one
 one-chip replica per chip of the host. Then, in an actor that holds a chip,
 it checks what was served against a cache-free forward pass of the same
-model, compiles both Pallas kernels with Mosaic and compares them with their
-XLA references.
+model, compiles the Pallas flash-attention kernel with Mosaic and compares
+it with its XLA reference.
 
 This parent process never imports JAX: a chip belongs to one process, and a
 worker sees one only by holding the `TPU` resource. It exits non-zero, and
@@ -205,10 +205,12 @@ class ChipProbe:
                          "argmax_matches": int((gaps == 0).sum())})
         return rows
 
-    def kernels(self, model: dict, max_batch: int) -> dict:
-        """Compiles both Pallas kernels with Mosaic (interpret=False) and
-        compares each with its XLA reference. An exception from a kernel is
-        reported in its row, and the smoke fails on it."""
+    def kernels(self, model: dict) -> dict:
+        """Compiles the Pallas flash-attention kernel (training and long
+        prefill; decode attention has no kernel of its own) with Mosaic
+        (interpret=False) and compares it with its XLA reference. An
+        exception from the kernel is reported in its row, and the smoke
+        fails on it."""
         import time
         import traceback
 
@@ -216,10 +218,7 @@ class ChipProbe:
         import jax.numpy as jnp
         import numpy as np
 
-        from ray_tpu._private.rtconfig import CONFIG
         from ray_tpu.ops.attention import _xla_attention
-        from ray_tpu.ops.decode_attention import (
-            _xla_decode_attention, choose_impl, decode_attention_pallas)
         from ray_tpu.ops.flash_attention import (
             derive_blocks, flash_attention, unsupported_reason)
 
@@ -260,25 +259,8 @@ class ChipProbe:
                                             interpret=False),
                     lambda: _xla_attention(q, k, v, causal=True))
 
-        # Decode shapes: this model's cache, then the bench shape.
-        for b, s, h, d in [(max_batch, model["max_seq"], heads, hd),
-                           (4, 2048, 8, 128)]:
-            q, k, v = qkv(b, s, h, d, s + 1)
-            q1 = q[:, 0]
-            lens = jnp.asarray(
-                np.linspace(1, s, b).astype(np.int32))  # 1 .. S, ragged
-            compare(f"decode_attention_pallas b{b} s{s} h{h} d{d}",
-                    lambda: decode_attention_pallas(
-                        q1, k, v, lens, interpret=False),
-                    lambda: _xla_decode_attention(q1, k, v, lens))
-
-        served = choose_impl(
-            (max_batch, heads, hd), (max_batch, model["max_seq"], heads, hd),
-            2, backend=jax.default_backend(),
-            force=str(CONFIG.decode_kernel).lower())
         return {"platform": dev.platform, "device_kind": dev.device_kind,
                 "rows": rows,
-                "served_decode_impl": list(served),
                 "prefill_flash_unsupported_reason": unsupported_reason(
                     (1, 128, heads, hd), (1, 128, heads, hd))}
 
@@ -395,7 +377,7 @@ def chip_phase(ray_tpu, greedy: list) -> None:
     try:
         ref = (ray_tpu.get(probe.reference.remote(MODEL, greedy),
                            timeout=left(300)) if greedy else [])
-        rep = ray_tpu.get(probe.kernels.remote(MODEL, MAX_BATCH),
+        rep = ray_tpu.get(probe.kernels.remote(MODEL),
                           timeout=left(300))
     finally:
         ray_tpu.kill(probe)
@@ -411,8 +393,6 @@ def chip_phase(ray_tpu, greedy: list) -> None:
                 f"({row['compile_and_run_s']}s)")
         else:
             say(f"  FAIL {row['kernel']}: {row.get('error') or row}")
-    impl, why = rep["served_decode_impl"]
-    say(f"served decode shape: dispatcher chooses {impl} ({why})")
     say("served prefill: the cached dense einsum of "
         "Attention._cached_attention (decode=True); "
         "dot_product_attention is not on the serving path. At the same "

@@ -257,11 +257,6 @@ _flag("token_ring", bool, True)
 # parks the producer instead of buffering unboundedly; a record may be at
 # most half this).
 _flag("token_ring_bytes", int, 1 << 20)
-# Continuous-engine prefill lane: admissions (bucketed prefill + first-
-# token sample) dispatch on a dedicated thread and splice into the
-# running batch at chunk boundaries, so a new request's prefill compile/
-# dispatch never stalls the decode loop. False restores inline admission.
-_flag("llm_prefill_lane", bool, True)
 # --- serve admission control (README "Overload & admission control") --------
 # Master switch for the serve admission/degradation plane: per-deployment
 # concurrency budgets, bounded router queues with deadlines (sheds raise
@@ -377,10 +372,6 @@ _flag("data_mem_cap_bytes", int, 0)
 # mem://, sim://); "" = local://<session_dir>/data_spill. Spilled shards
 # are restored transparently when the reduce consumes them.
 _flag("data_spill_uri", str, "")
-# Decode-attention kernel selection: "pallas" / "xla" force a path, ""
-# keeps the size-based dispatch (ops/decode_attention.py
-# PALLAS_MIN_CACHE_BYTES).
-_flag("decode_kernel", str, "")
 # Non-empty: worker processes run under cProfile and write
 # <dir>/worker_<pid>.pstats at exit (dev profiling; costs ~2x on hot paths).
 _flag("profile_worker", str, "")

@@ -56,7 +56,6 @@ from typing import Any, Optional
 import numpy as np
 
 from ray_tpu._private import tracing as _tracing
-from ray_tpu._private.rtconfig import CONFIG
 
 logger = logging.getLogger(__name__)
 
@@ -471,12 +470,17 @@ def _rows_from_columns(columns: np.ndarray, held: int) -> np.ndarray:
     return columns.T.reshape(-1)[:held]
 
 
+#: Decode chunks kept in flight (`ContinuousEngine._run_scheduler`): the
+#: oldest is read back while the younger ones execute, so the device never
+#: waits for a read. No caller ever asked for another depth.
+PIPELINE_DEPTH = 4
+
+
 class ContinuousEngine:
     """In-flight-batching engine over the flagship Transformer."""
 
     def __init__(self, cfg, *, max_batch: int = 8, decode_chunk: int = 8,
-                 pipeline_depth: int = 4, mesh=None,
-                 prefill_buckets: tuple = ()):
+                 mesh=None):
         import jax
         import jax.numpy as jnp
 
@@ -486,7 +490,6 @@ class ContinuousEngine:
         self.cfg = cfg
         self.max_batch = max_batch
         self.decode_chunk = decode_chunk
-        self.pipeline_depth = max(1, pipeline_depth)
         self.mesh = mesh
         mcfg = model_config(cfg)
         self.model = Transformer(mcfg)
@@ -553,21 +556,16 @@ class ContinuousEngine:
         self._running = True
         # Prefill lane (README "Serving hot loop"): admissions dispatch on
         # their own thread and splice at chunk boundaries via _ready, so a
-        # prefill compile/dispatch never blocks the decode loop. Off =
-        # inline admission in the scheduler loop (the classic path).
-        self._prefill_lane = bool(CONFIG.llm_prefill_lane)
+        # prefill compile/dispatch never blocks the decode loop.
         self._ready: collections.deque = collections.deque()
         self._prefill_inflight = 0
-        self._threads = []
-        if self._prefill_lane:
-            t = threading.Thread(target=self._prefill_loop, daemon=True,
-                                 name="rt-llm-prefill")
+        self._threads = [
+            threading.Thread(target=self._prefill_loop, daemon=True,
+                             name="rt-llm-prefill"),
+            threading.Thread(target=self._loop, daemon=True,
+                             name="rt-llm-engine")]
+        for t in self._threads:
             t.start()
-            self._threads.append(t)
-        self._thread = threading.Thread(target=self._loop, daemon=True,
-                                        name="rt-llm-engine")
-        self._thread.start()
-        self._threads.append(self._thread)
 
     # ------------------------------------------------------------ sharding
     def _shard_params(self, params, mesh):
@@ -1083,7 +1081,6 @@ class ContinuousEngine:
         the cache slice, set the device mirrors, book the slot."""
         if stream.trace is not None:
             now = time.time()
-            # (inline admission leaves no stage open here: no ready_wait)
             _stage_end(stream, "engine.ready_wait", now,
                        active=self._n_active)
             _stage_begin(stream, now, slot=slot,
@@ -1105,16 +1102,6 @@ class ContinuousEngine:
         # Merge into the device mirrors without a sync.
         self._toks_dev = self._toks_dev.at[slot].set(first)
         self._lens_dev = self._lens_dev.at[slot].set(int(plen))
-
-    def _admit_async(self, slot: int, prompt, sampling, stream):
-        """Inline admission (prefill lane off): dispatch prefill + first-
-        token sample + cache place for one slot WITHOUT reading the result
-        back (first tokens join the next drain's readback — each read is a
-        blocking host sync)."""
-        first, cache_slice, key = self._prefill_dispatch(
-            prompt, sampling, stream)
-        self._splice(slot, len(prompt), sampling, stream, first,
-                     cache_slice, key)
 
     def _free_slot(self, taken=()) -> Optional[int]:
         return next((i for i, s in enumerate(self._slots)
@@ -1188,7 +1175,7 @@ class ContinuousEngine:
     def _run_scheduler(self):
         """Scheduler with depth-D software pipelining. Host syncs are the
         scarce resource (a blocking read stalls dispatch until the device
-        catches up): up to `pipeline_depth` decode chunks stay in flight with
+        catches up): up to `PIPELINE_DEPTH` decode chunks stay in flight with
         their inputs chained ENTIRELY on device (next-token/length mirrors
         ride chunk outputs, so steady-state dispatch transfers nothing).
         Each chunk's token block starts its device→host copy AT DISPATCH
@@ -1197,66 +1184,18 @@ class ContinuousEngine:
         of chunks N+1..N+D-1, so the XLA stream never drains. Correctness
         leans on device program order (place/chunk chain through the cache
         handle); the host only avoids re-admitting a slot an in-flight
-        chunk still steps (the _cooling set)."""
-        import jax.numpy as jnp
+        chunk still steps (the _cooling set).
 
-        from ray_tpu.ops.decode_attention import kv_prefix_rows
-
-        max_seq = self.cfg.max_seq
+        One pass: `_admit` what the prefill lane has ready, `_fill_pipeline`
+        with decode chunks, `_drain` the oldest of them."""
         phases = _Phases(self._jax.profiler)
         while self._running:
             # Tracing on: the pass's phases on the host's and the
             # profiler's clock (_Phases). Off: this one read, nothing else.
             ph = phases if _tracing.enabled() else None
-            iter_ctx = None  # the traced request the pass's span is bound to
-            spliced = dispatched = 0
             if ph is not None:
                 ph.start_pass()
-            # ---- 1. admissions: splice prefilled requests at the chunk
-            # boundary (prefill lane), or run the classic inline admission
-            # (lane off). Either way nothing here reads from device.
-            if self._prefill_lane:
-                while self._n_active < self.max_batch:
-                    free = self._free_slot()
-                    if free is None:
-                        break
-                    with self._lock:
-                        if not self._ready:
-                            break
-                        entry = self._ready.popleft()
-                    plen, sampling, stream, first, cache_slice, key = entry
-                    if stream.closed:
-                        stream.finish_reason = "cancelled"
-                        self._finish_stream(stream)
-                        continue
-                    try:
-                        self._splice(free, plen, sampling, stream, first,
-                                     cache_slice, key)
-                        spliced += 1
-                    except Exception as e:
-                        self._finish_stream(stream, e)
-            else:
-                while self._n_active < self.max_batch:
-                    free = self._free_slot()
-                    if free is None:
-                        break
-                    try:
-                        item = self._pending.get_nowait()
-                    except queue.Empty:
-                        break
-                    if item is None:
-                        continue
-                    prompt, sampling, stream = item
-                    if stream.trace is not None:
-                        _stage_end(stream, "engine.queue", time.time())
-                    try:
-                        self._admit_async(free, prompt, sampling, stream)
-                        spliced += 1
-                    except Exception as e:  # bad request or engine failure
-                        self._finish_stream(stream, e)
-            # First tokens are NOT read at admission: they join the next
-            # drain's readback (an admission-wave readback would cost its
-            # own blocking host sync).
+            spliced = self._admit()
             if (self._n_active == 0 and not self._q_chunks
                     and not self._pending_firsts):
                 if ph is not None:
@@ -1269,192 +1208,237 @@ class ContinuousEngine:
                 if ph is not None:
                     ph.end()  # no span: the next pass carries its idle_ms
                 continue
-            # ---- 2. fill the pipeline: dispatch up to pipeline_depth
-            # chunks back to back (dispatches are asynchronous and nearly
-            # free; only the readback costs a round trip)
+            dispatched, iter_ctx = self._fill_pipeline(ph)
+            sync_ctx = self._drain(ph)
             if ph is not None:
-                # wall_ns ties the spans' wall clock to the trace's own.
-                ph.begin("dispatch", wall_ns=time.time_ns())
-            while len(self._q_chunks) < self.pipeline_depth:
-                if (self._prefill_lane and self._ready
-                        and self._n_active < self.max_batch
-                        and self._free_slot() is not None):
-                    # A prefilled request is waiting and a slot is open:
-                    # stop filling the pipeline with the OLD batch and
-                    # splice at this chunk boundary (next iteration's
-                    # admission step) — join latency stays a few tokens.
-                    break
-                active = [i for i, s in enumerate(self._slots)
-                          if s is not None]
-                if not active:
-                    break
-                live = [int(self._lengths[i]) for i in active]
-                budget = int(min(
-                    min(self._slots[i].remaining - self._pending_toks[i]
-                        for i in active),
-                    max_seq - max(live)))
-                if budget < 1:
-                    break  # every active slot's fate is already in flight
-                # Power-of-2 chunk sizes only: each distinct scan length
-                # is its own compiled program, and an arbitrary shrinking
-                # budget would recompile on nearly every call.
-                n = max(1, min(self.decode_chunk,
-                               1 << (budget.bit_length() - 1)))
-                greedy = all(
-                    self._slots[i].sampling.temperature <= 0.0
-                    for i in active)
-                # Per-iteration tracing (README "Tracing & timeline"): bind
-                # the decode loop's spans to the oldest active TRACED
-                # request — in the one-request case every dispatch and
-                # host sync lands in its timeline.
-                tctx = next((self._slots[i].stream.trace for i in active
-                             if self._slots[i].stream.trace is not None),
-                            None)
-                # The rows the longest LIVE slot has after these n steps:
-                # where the chunk's attention may stop. Only the host can
-                # say it, from integers it holds: an idle or cooling slot's
-                # device-side length is stale and keeps growing, and what
-                # such a slot decodes is discarded (_deliver).
-                kv_bound = max(live) + n
-                assert kv_bound <= max_seq and all(
-                    length + n <= kv_bound for length in live), (live, n)
-                try:
-                    t_disp = time.time()
-                    self._cache, self._keys, toks_out, lens_out = \
-                        self._chunk(
-                            self.params, self._cache,
-                            self._toks_dev, self._lens_dev,
-                            self._keys, self._temps_dev,
-                            self._topks_dev, self._topps_dev, n, greedy,
-                            np.int32(kv_bound))
-                    # Start the device→host copy of this chunk's tokens
-                    # NOW: by the time the drain reads it (D iterations
-                    # later), the transfer has overlapped the younger
-                    # chunks' execution instead of serializing after it.
-                    try:
-                        toks_out.copy_to_host_async()
-                    except Exception:
-                        pass  # backend without async copy: read pays it
-                    kv_rows = kv_prefix_rows(kv_bound, max_seq)
-                    _tracing.record_span_in(
-                        tctx, "engine.dispatch_chunk", "engine", t_disp,
-                        time.time(), {"tokens": n, "active": len(active),
-                                      "kv_bound": kv_bound,
-                                      "kv_rows": kv_rows})
-                    self._kv_steps += n
-                    self._kv_walked += n * kv_rows
-                    # step j of the chunk sees length + j + 1 rows of a slot
-                    self._kv_live += n * (sum(live) / len(live) + (n + 1) / 2)
-                    # Chain on device; mirror lengths on host (every slot
-                    # steps n times — deterministic, no read needed).
-                    self._toks_dev = toks_out[:, n - 1]
-                    self._lens_dev = lens_out
-                    self._lengths = self._lengths + n
-                    for i in active:
-                        self._pending_toks[i] += n
-                    self._q_chunks.append((toks_out, active, n, object()))
-                    dispatched += 1
-                    iter_ctx = iter_ctx or tctx
-                except Exception as e:
-                    logger.exception("llm engine decode chunk failed")
-                    for i in active:
-                        self._slots[i].stream._q.put(e)
-                        self._retire(i)
-                    break
-            # ---- 3. drain: read the OLDEST in-flight chunk (plus any
-            # admission wave's first tokens) in one device sync, leaving
-            # the younger chunks executing — the double buffer. One
-            # host_sync per chunk: a request's span count is bounded by
-            # its CHUNK count, never its token count.
-            if self._q_chunks or self._pending_firsts:
-                q = self._q_chunks[:1]
-                del self._q_chunks[:1]
-                firsts, self._pending_firsts = self._pending_firsts, []
-                parts = []
-                if firsts:
-                    col = jnp.zeros((self.max_batch, 1), jnp.int32)
-                    for slot, fdev in firsts:
-                        col = col.at[slot, 0].set(fdev)
-                    parts.append(col)
-                parts.extend(c[0] for c in q)
-                # The host-sync readback: THE per-iteration host-link round
-                # trip the decode loop pays (once one per TOKEN; now one per
-                # chunk, overlapped). Span it against the oldest traced
-                # in-flight request + the decode-step histogram.
-                sync_ctx = None
-                if _tracing.enabled():
-                    sync_ctx = next(
-                        (self._slots[i].stream.trace
-                         for _t, p_active, _n, _tag in q for i in p_active
-                         if self._slots[i] is not None
-                         and self._slots[i].stream.trace is not None),
-                        None)
-                    if sync_ctx is None:
-                        sync_ctx = next(
-                            (self._slots[s].stream.trace
-                             for s, _f in firsts
-                             if self._slots[s] is not None
-                             and self._slots[s].stream.trace is not None),
-                            None)
-                t_sync = ph.begin("sync") if ph is not None else time.time()
-                try:
-                    all_np = np.asarray(
-                        parts[0] if len(parts) == 1
-                        else jnp.concatenate(parts, axis=1))
-                except Exception as e:
-                    for slot, _f in firsts:
-                        if self._slots[slot] is not None:
-                            self._slots[slot].stream._q.put(e)
-                            self._retire(slot)
-                    for _t, p_active, _n, _tag in q:
-                        for i in p_active:
-                            if self._slots[i] is not None:
-                                self._slots[i].stream._q.put(e)
-                                self._retire(i)
-                    all_np = None
-                # sync_ms of the pass is engine.host_sync's own interval.
-                t_end = ph.begin("deliver") if ph is not None else None
-                iter_ctx = iter_ctx or sync_ctx
-                moe = (self._count_moe(all_np, q, 1 if firsts else 0)
-                       if self._moe_cols and q and all_np is not None else {})
-                if sync_ctx is not None and all_np is not None:
-                    t_end = t_end or time.time()
-                    _tracing.record_span_in(
-                        sync_ctx, "engine.host_sync", "engine", t_sync,
-                        t_end, {"chunks": len(q),
-                                "cols": int(all_np.shape[1]), **moe})
-                    try:
-                        from ray_tpu.util import metrics as _metrics
-
-                        _metrics.LLM_HOST_SYNC_SECONDS.observe(t_end - t_sync)
-                    except Exception:
-                        pass
-                off = 0
-                if firsts and all_np is not None:
-                    for slot, _f in firsts:
-                        if self._slots[slot] is None:
-                            continue  # retired by a failed-dispatch path
-                        self._next_tok[slot] = int(all_np[slot, 0])
-                        self._deliver(slot, [int(all_np[slot, 0])])
-                if firsts:
-                    off = 1
-                for _toks_dev, p_active, pn, tag in q:
-                    if all_np is not None:
-                        for i in p_active:
-                            self._pending_toks[i] = max(
-                                0, self._pending_toks[i] - pn)
-                            if self._slots[i] is None:
-                                continue  # retired; tail is garbage
-                            toks = [int(all_np[i, j])
-                                    for j in range(off, off + pn)]
-                            self._deliver(i, toks)
-                            if self._slots[i] is not None:
-                                self._next_tok[i] = int(
-                                    all_np[i, off + pn - 1])
-                    off += pn + self._moe_cols
-                    self._cooling = {s: t for s, t in self._cooling.items()
-                                     if t is not tag}
-            if ph is not None:
-                ph.end_pass(iter_ctx, spliced=spliced, chunks=dispatched,
+                # the traced request the pass's span is bound to
+                ph.end_pass(iter_ctx or sync_ctx, spliced=spliced,
+                            chunks=dispatched,
                             in_flight=len(self._q_chunks),
                             active=self._n_active)
+
+    def _admit(self) -> int:
+        """Phase `admit`: splice the requests the prefill lane has parked in
+        `_ready` into free slots, at this chunk boundary. Nothing here reads
+        from the device: first tokens are NOT read at admission, they join
+        the next drain's readback (an admission-wave readback would cost
+        its own blocking host sync). Returns the requests spliced."""
+        spliced = 0
+        while self._n_active < self.max_batch:
+            free = self._free_slot()
+            if free is None:
+                break
+            with self._lock:
+                if not self._ready:
+                    break
+                entry = self._ready.popleft()
+            plen, sampling, stream, first, cache_slice, key = entry
+            if stream.closed:
+                stream.finish_reason = "cancelled"
+                self._finish_stream(stream)
+                continue
+            try:
+                self._splice(free, plen, sampling, stream, first,
+                             cache_slice, key)
+                spliced += 1
+            except Exception as e:
+                self._finish_stream(stream, e)
+        return spliced
+
+    def _fill_pipeline(self, ph) -> tuple:
+        """Phase `dispatch`: dispatch up to PIPELINE_DEPTH chunks back to
+        back (dispatches are asynchronous and nearly free; only the
+        readback costs a round trip). Returns the chunks dispatched and
+        the first traced request a chunk's span was bound to, if any."""
+        from ray_tpu.ops.decode_attention import kv_prefix_rows
+
+        max_seq = self.cfg.max_seq
+        iter_ctx = None
+        dispatched = 0
+        if ph is not None:
+            # wall_ns ties the spans' wall clock to the trace's own.
+            ph.begin("dispatch", wall_ns=time.time_ns())
+        while len(self._q_chunks) < PIPELINE_DEPTH:
+            if (self._ready and self._n_active < self.max_batch
+                    and self._free_slot() is not None):
+                # A prefilled request is waiting and a slot is open:
+                # stop filling the pipeline with the OLD batch and
+                # splice at this chunk boundary (next iteration's
+                # admission step) — join latency stays a few tokens.
+                break
+            active = [i for i, s in enumerate(self._slots)
+                      if s is not None]
+            if not active:
+                break
+            live = [int(self._lengths[i]) for i in active]
+            budget = int(min(
+                min(self._slots[i].remaining - self._pending_toks[i]
+                    for i in active),
+                max_seq - max(live)))
+            if budget < 1:
+                break  # every active slot's fate is already in flight
+            # Power-of-2 chunk sizes only: each distinct scan length
+            # is its own compiled program, and an arbitrary shrinking
+            # budget would recompile on nearly every call.
+            n = max(1, min(self.decode_chunk,
+                           1 << (budget.bit_length() - 1)))
+            greedy = all(
+                self._slots[i].sampling.temperature <= 0.0
+                for i in active)
+            # Per-iteration tracing (README "Tracing & timeline"): bind
+            # the decode loop's spans to the oldest active TRACED
+            # request — in the one-request case every dispatch and
+            # host sync lands in its timeline.
+            tctx = next((self._slots[i].stream.trace for i in active
+                         if self._slots[i].stream.trace is not None),
+                        None)
+            # The rows the longest LIVE slot has after these n steps:
+            # where the chunk's attention may stop. Only the host can
+            # say it, from integers it holds: an idle or cooling slot's
+            # device-side length is stale and keeps growing, and what
+            # such a slot decodes is discarded (_deliver).
+            kv_bound = max(live) + n
+            assert kv_bound <= max_seq and all(
+                length + n <= kv_bound for length in live), (live, n)
+            try:
+                t_disp = time.time()
+                self._cache, self._keys, toks_out, lens_out = \
+                    self._chunk(
+                        self.params, self._cache,
+                        self._toks_dev, self._lens_dev,
+                        self._keys, self._temps_dev,
+                        self._topks_dev, self._topps_dev, n, greedy,
+                        np.int32(kv_bound))
+                # Start the device→host copy of this chunk's tokens
+                # NOW: by the time the drain reads it (D iterations
+                # later), the transfer has overlapped the younger
+                # chunks' execution instead of serializing after it.
+                try:
+                    toks_out.copy_to_host_async()
+                except Exception:
+                    pass  # backend without async copy: read pays it
+                kv_rows = kv_prefix_rows(kv_bound, max_seq)
+                _tracing.record_span_in(
+                    tctx, "engine.dispatch_chunk", "engine", t_disp,
+                    time.time(), {"tokens": n, "active": len(active),
+                                  "kv_bound": kv_bound,
+                                  "kv_rows": kv_rows})
+                self._kv_steps += n
+                self._kv_walked += n * kv_rows
+                # step j of the chunk sees length + j + 1 rows of a slot
+                self._kv_live += n * (sum(live) / len(live) + (n + 1) / 2)
+                # Chain on device; mirror lengths on host (every slot
+                # steps n times — deterministic, no read needed).
+                self._toks_dev = toks_out[:, n - 1]
+                self._lens_dev = lens_out
+                self._lengths = self._lengths + n
+                for i in active:
+                    self._pending_toks[i] += n
+                self._q_chunks.append((toks_out, active, n, object()))
+                dispatched += 1
+                iter_ctx = iter_ctx or tctx
+            except Exception as e:
+                logger.exception("llm engine decode chunk failed")
+                for i in active:
+                    self._slots[i].stream._q.put(e)
+                    self._retire(i)
+                break
+        return dispatched, iter_ctx
+
+    def _drain(self, ph):
+        """Phases `sync` and `deliver`: read the OLDEST in-flight chunk
+        (plus any admission wave's first tokens) in one device sync, leaving
+        the younger chunks executing — the double buffer — and hand the
+        tokens to their streams. One host_sync per chunk: a request's span
+        count is bounded by its CHUNK count, never its token count.
+        Returns the traced request the sync's span is bound to, if any."""
+        if not (self._q_chunks or self._pending_firsts):
+            return None
+        jnp = self._jnp
+        q = self._q_chunks[:1]
+        del self._q_chunks[:1]
+        firsts, self._pending_firsts = self._pending_firsts, []
+        parts = []
+        if firsts:
+            col = jnp.zeros((self.max_batch, 1), jnp.int32)
+            for slot, fdev in firsts:
+                col = col.at[slot, 0].set(fdev)
+            parts.append(col)
+        parts.extend(c[0] for c in q)
+        # The host-sync readback: THE per-iteration host-link round
+        # trip the decode loop pays (once one per TOKEN; now one per
+        # chunk, overlapped). Span it against the oldest traced
+        # in-flight request + the decode-step histogram.
+        sync_ctx = None
+        if _tracing.enabled():
+            sync_ctx = next(
+                (self._slots[i].stream.trace
+                 for _t, p_active, _n, _tag in q for i in p_active
+                 if self._slots[i] is not None
+                 and self._slots[i].stream.trace is not None),
+                None)
+            if sync_ctx is None:
+                sync_ctx = next(
+                    (self._slots[s].stream.trace
+                     for s, _f in firsts
+                     if self._slots[s] is not None
+                     and self._slots[s].stream.trace is not None),
+                    None)
+        t_sync = ph.begin("sync") if ph is not None else time.time()
+        try:
+            all_np = np.asarray(
+                parts[0] if len(parts) == 1
+                else jnp.concatenate(parts, axis=1))
+        except Exception as e:
+            for slot, _f in firsts:
+                if self._slots[slot] is not None:
+                    self._slots[slot].stream._q.put(e)
+                    self._retire(slot)
+            for _t, p_active, _n, _tag in q:
+                for i in p_active:
+                    if self._slots[i] is not None:
+                        self._slots[i].stream._q.put(e)
+                        self._retire(i)
+            all_np = None
+        # sync_ms of the pass is engine.host_sync's own interval.
+        t_end = ph.begin("deliver") if ph is not None else None
+        moe = (self._count_moe(all_np, q, 1 if firsts else 0)
+               if self._moe_cols and q and all_np is not None else {})
+        if sync_ctx is not None and all_np is not None:
+            t_end = t_end or time.time()
+            _tracing.record_span_in(
+                sync_ctx, "engine.host_sync", "engine", t_sync,
+                t_end, {"chunks": len(q),
+                        "cols": int(all_np.shape[1]), **moe})
+            try:
+                from ray_tpu.util import metrics as _metrics
+
+                _metrics.LLM_HOST_SYNC_SECONDS.observe(t_end - t_sync)
+            except Exception:
+                pass
+        off = 0
+        if firsts and all_np is not None:
+            for slot, _f in firsts:
+                if self._slots[slot] is None:
+                    continue  # retired by a failed-dispatch path
+                self._next_tok[slot] = int(all_np[slot, 0])
+                self._deliver(slot, [int(all_np[slot, 0])])
+        if firsts:
+            off = 1
+        for _toks_dev, p_active, pn, tag in q:
+            if all_np is not None:
+                for i in p_active:
+                    self._pending_toks[i] = max(
+                        0, self._pending_toks[i] - pn)
+                    if self._slots[i] is None:
+                        continue  # retired; tail is garbage
+                    toks = [int(all_np[i, j])
+                            for j in range(off, off + pn)]
+                    self._deliver(i, toks)
+                    if self._slots[i] is not None:
+                        self._next_tok[i] = int(
+                            all_np[i, off + pn - 1])
+            off += pn + self._moe_cols
+            self._cooling = {s: t for s, t in self._cooling.items()
+                             if t is not tag}
+        return sync_ctx

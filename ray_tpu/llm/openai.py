@@ -10,6 +10,7 @@ prompts are strings (byte-level tokenizer) or raw token lists.
 
 from __future__ import annotations
 
+import codecs
 import json
 import os
 import time
@@ -35,6 +36,16 @@ class ByteTokenizer:
     def decode(self, tokens) -> str:
         data = bytes(t for t in tokens if 0 <= t < 256)
         return data.decode("utf-8", "replace")
+
+    def stream_decoder(self):
+        """`decode(tokens, final=False)` for ONE stream, fed a batch at a
+        time: a character whose bytes straddle two batches comes out whole
+        with the second of them, so the text a stream adds up to is
+        `decode` of all its tokens wherever its batches were cut (they are
+        cut by timing: what the engine had ready at each wakeup)."""
+        utf8 = codecs.getincrementaldecoder("utf-8")("replace")
+        return lambda tokens, final=False: utf8.decode(
+            bytes(t for t in tokens if 0 <= t < 256), final)
 
 
 def _sampling_from_body(body: dict, default_max: int) -> SamplingParams:
@@ -188,6 +199,7 @@ class OpenAIServer:
         a chunk of decode output is one dict, one downstream flush — not
         one wakeup and one SSE event per token)."""
         def gen():
+            decode = self.tok.stream_decoder()
             try:
                 while True:
                     try:
@@ -195,10 +207,12 @@ class OpenAIServer:
                     except StopIteration:
                         break
                     yield self._completion_body(
-                        req_id, self.tok.decode(toks), toks, None, chat,
+                        req_id, decode(toks), toks, None, chat,
                         stream_delta=True)
+                # (the text of bytes that never became a whole character)
                 yield self._completion_body(
-                    req_id, "", [], stream.finish_reason or "length", chat,
+                    req_id, decode([], True), [],
+                    stream.finish_reason or "length", chat,
                     stream_delta=True)
             finally:
                 # Consumer gone (client disconnect propagates as

@@ -10,14 +10,13 @@ Consecutive-failure accounting resets on any completed invocation, so a
 single chaos kill never eats into the rebuild budget of a later one."""
 
 import os
-import queue
 import signal
 import time
 
 import pytest
 
 import ray_tpu
-from ray_tpu.exceptions import DagStageError
+from ray_tpu.exceptions import DagStageError, GetTimeoutError
 from ray_tpu.llm import LLMConfig
 from ray_tpu.llm.engine import SamplingParams
 
@@ -39,13 +38,17 @@ def _wait(pred, timeout=30.0, what="condition"):
 
 def _drain_bounded(stream, budget_s=60.0) -> list:
     """Drain a GenStream with a hard wall — a hang is a test FAILURE with
-    a named deadline, not a pytest timeout. Engine errors propagate."""
+    a named deadline, not a pytest timeout. Engine errors propagate. The
+    wall is `budget_s` for the whole stream: a token that takes longer than
+    one 5 s poll (the first one after a rebuild, on a loaded host) is waited
+    for again. `GenStream.next` reports a poll that ran out as
+    GetTimeoutError, not as the queue's Empty."""
     toks = []
     deadline = time.monotonic() + budget_s
     while time.monotonic() < deadline:
         try:
             toks.append(stream.next(timeout=5))
-        except queue.Empty:
+        except GetTimeoutError:
             continue
         except StopIteration:
             return toks

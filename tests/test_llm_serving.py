@@ -297,3 +297,139 @@ def test_rows_an_earlier_request_left_in_a_slot_are_never_read():
         assert got == want
     finally:
         fresh.shutdown()
+
+
+# ---------------------------------------------------------------------------
+# The prefill lane's edges: what ends a request that never reaches a slot.
+# No pytest-timeout is installed: every wait below has a deadline of its own.
+
+WAIT_S = 60.0
+
+
+def until(cond, what: str):
+    deadline = time.monotonic() + WAIT_S
+    while not cond():
+        assert time.monotonic() < deadline, f"timed out waiting for {what}"
+        time.sleep(0.005)
+
+
+def drain(stream) -> list:
+    """The stream's tokens, each awaited at most WAIT_S."""
+    out = []
+    while True:
+        try:
+            out.append(stream.next(timeout=WAIT_S))
+        except StopIteration:
+            return out
+
+
+def test_a_request_cancelled_while_parked_in_ready_takes_no_slot(
+        engine, monkeypatch):
+    """Its consumer goes away while its prefill waits in `_ready` for a
+    slot: the next admission finishes it as cancelled, splices nothing,
+    and the slot goes to the request behind it."""
+    sp = SamplingParams(temperature=0.0, max_tokens=6)
+    spliced = []
+    splice = engine._splice
+
+    def spy(slot, plen, sampling, stream, *rest):
+        spliced.append(stream)
+        return splice(slot, plen, sampling, stream, *rest)
+
+    monkeypatch.setattr(engine, "_splice", spy)
+    with monkeypatch.context() as full:
+        full.setattr(engine, "_free_slot", lambda taken=(): None)
+        parked = engine.submit([1, 2, 3], sp)
+        behind = engine.submit([1, 2, 3], sp)
+        until(lambda: len(engine._ready) == 2, "both prefills to park")
+        parked.close()
+    assert len(drain(behind)) == 6
+    assert drain(parked) == [] and parked.finish_reason == "cancelled"
+    assert spliced == [behind]
+    until(lambda: engine.num_active == 0, "the slot to be given back")
+    assert not engine._ready and parked not in engine._streams
+
+
+def test_a_prefill_that_raises_ends_its_own_stream_and_no_other(
+        engine, monkeypatch):
+    """The lane hands the error to the request it belongs to, counts it
+    out of `_prefill_inflight`, and goes on with the next request; the
+    scheduler never sees the failed one."""
+    sp = SamplingParams(temperature=0.0, max_tokens=6)
+    want = drain(engine.submit([4, 5, 6], sp))
+    prefill, failed = engine._prefill, []
+
+    def once(*args):
+        if not failed:
+            failed.append(args)
+            raise RuntimeError("prefill failed on the device")
+        return prefill(*args)
+
+    monkeypatch.setattr(engine, "_prefill", once)
+    bad = engine.submit([4, 5, 6], sp)
+    good = engine.submit([4, 5, 6], sp)
+    with pytest.raises(RuntimeError, match="prefill failed on the device"):
+        bad.next(timeout=WAIT_S)
+    assert drain(bad) == []  # the error, then the end of the stream
+    assert drain(good) == want
+    assert len(failed) == 1 and engine._prefill_inflight == 0
+    assert bad not in engine._streams
+    assert all(t.is_alive() for t in engine._threads)
+
+
+def test_shutdown_ends_requests_parked_in_ready_and_in_pending():
+    """shutdown() while two requests wait in `_ready` for a slot, one is
+    inside its prefill and two more are queued behind it in `_pending`:
+    every stream ends, both threads are joined, all within a deadline."""
+    import threading
+
+    eng = ContinuousEngine(CFG, max_batch=2, decode_chunk=4)
+    gate, inside = threading.Event(), threading.Event()
+    prefill, calls = eng._prefill, []
+
+    def third_call_waits(*args):
+        calls.append(args)
+        if len(calls) == 3:
+            inside.set()
+            assert gate.wait(timeout=WAIT_S)
+        return prefill(*args)
+
+    eng._prefill = third_call_waits
+    eng._free_slot = lambda taken=(): None  # no slot comes free
+    sp = SamplingParams(temperature=0.0, max_tokens=6)
+    try:
+        streams = [eng.submit([1, 2, 3], sp) for _ in range(2)]
+        until(lambda: len(eng._ready) == 2, "two prefills to park")
+        streams += [eng.submit([1, 2, 3], sp) for _ in range(3)]
+        assert inside.wait(timeout=WAIT_S)
+        until(lambda: eng._pending.qsize() == 2, "two requests to queue")
+        stopper = threading.Thread(target=eng.shutdown, daemon=True)
+        t0 = time.monotonic()
+        stopper.start()
+        until(lambda: not eng._running, "shutdown to begin")
+    finally:
+        gate.set()
+    stopper.join(timeout=WAIT_S)
+    assert not stopper.is_alive()
+    assert not any(t.is_alive() for t in eng._threads)
+    assert [drain(s) for s in streams] == [[]] * 5
+    assert time.monotonic() - t0 < WAIT_S
+    assert eng._pending.empty() and not eng._streams
+    with pytest.raises(RuntimeError, match="shut down"):
+        eng.submit([1, 2, 3], sp)
+
+
+def test_streamed_text_does_not_depend_on_where_its_batches_were_cut():
+    """A stream's batches are cut by timing. Its text must not be: bytes of
+    one character that straddle two batches come out whole with the second
+    (tests/test_stream_fallback.py compares two runs' streamed text)."""
+    from ray_tpu.llm.openai import ByteTokenizer
+
+    tok = ByteTokenizer()
+    toks = list("aƺ€".encode()) + [0xE2, 0x82, 65, 257, 0xF0, 0x9F]
+    want = tok.decode(toks)
+    assert want == "aƺ€�A�"
+    for cut in range(len(toks) + 1):
+        decode = tok.stream_decoder()
+        got = decode(toks[:cut]) + decode(toks[cut:]) + decode([], True)
+        assert got == want, cut

@@ -190,60 +190,58 @@ def test_attention_gradient_takes_xla_path_by_rule(caplog):
     assert len(said) == 1
 
 
-def test_decode_attention_pallas_matches_xla():
-    """The decode kernel in the interpreter against the dense reference:
-    ragged lengths, GQA (q group padded to the sublane tile) and MHA."""
-    from ray_tpu.ops.decode_attention import (
-        _xla_decode_attention, decode_attention, decode_attention_pallas)
-
-    rng = np.random.RandomState(6)
-    for hq, hkv in [(4, 4), (8, 2)]:
-        b, s, d = 3, 256, 32
-        q = jnp.asarray(rng.randn(b, hq, d), jnp.float32)
-        k = jnp.asarray(rng.randn(b, s, hkv, d), jnp.float32)
-        v = jnp.asarray(rng.randn(b, s, hkv, d), jnp.float32)
-        lens = jnp.asarray([1, 130, 256], jnp.int32)
-        ref = _xla_decode_attention(q, k, v, lens)
-        out = decode_attention_pallas(q, k, v, lens, block_k=128,
-                                      interpret=True)
-        assert float(jnp.max(jnp.abs(out - ref))) < 2e-5
-        via = decode_attention(q, k, v, lens, interpret=True)
-        assert float(jnp.max(jnp.abs(via - ref))) < 2e-5
+def plain_decode_attention(q, k, v, lengths):
+    """One sequence and one head at a time, in float64: softmax over the
+    sequence's own rows of q . K / sqrt(d), times V. Rows of the cache
+    wider than q's head are cut to it."""
+    q, k, v = (np.asarray(x, np.float64) for x in (q, k, v))
+    b, hq, d = q.shape
+    group = hq // k.shape[2]
+    out = np.zeros((b, hq, d))
+    for i, n in enumerate(lengths):
+        for h in range(hq):
+            scores = k[i, :n, h // group, :d] @ q[i, h] / np.sqrt(d)
+            weights = np.exp(scores - scores.max())
+            out[i, h] = weights / weights.sum() @ v[i, :n, h // group, :d]
+    return out
 
 
-def test_decode_dispatch_rule():
-    """choose_impl: forced choice, then backend, cache size and shape."""
-    from ray_tpu.ops.decode_attention import (
-        PALLAS_MIN_CACHE_BYTES, choose_impl)
-
-    served = ((8, 16, 64), (8, 1024, 16, 64), 2)  # 32 MiB of k+v
-    big = ((64, 16, 64), (64, 2048, 16, 64), 2)  # 512 MiB
-    assert 2 * 64 * 2048 * 16 * 64 * 2 >= PALLAS_MIN_CACHE_BYTES
-    assert choose_impl(*served, backend="tpu")[0] == "xla"
-    assert choose_impl(*big, backend="tpu")[0] == "pallas"
-    assert choose_impl(*big, backend="cpu") == ("xla", "backend is cpu")
-    # a big cache the kernel cannot tile stays on XLA, and says why
-    odd = ((64, 16, 64), (64, 2100, 16, 64), 2)
-    impl, why = choose_impl(*odd, backend="tpu")
-    assert impl == "xla" and "lane-aligned" in why
-    # forced choices win on any backend
-    assert choose_impl(*served, backend="cpu", force="pallas")[0] == "pallas"
-    assert choose_impl(*big, backend="tpu", force="xla")[0] == "xla"
-    with pytest.raises(ValueError, match="RT_DECODE_KERNEL"):
-        choose_impl(*served, backend="tpu", force="mosaic")
+def decode_case(hq, hkv, d, row, s=256, seed=6):
+    rng = np.random.RandomState(seed)
+    q = jnp.asarray(rng.randn(3, hq, d), jnp.float32)
+    k = jnp.asarray(rng.randn(3, s, hkv, row), jnp.float32)
+    v = jnp.asarray(rng.randn(3, s, hkv, row), jnp.float32)
+    return q, k, v
 
 
-def test_decode_forced_kernel_raises_on_a_shape_it_rejects(monkeypatch):
-    """RT_DECODE_KERNEL=pallas on a cache length the kernel cannot tile
-    raises the kernel's reason; it does not run XLA instead."""
+@pytest.mark.parametrize("hq,hkv", [(4, 4), (8, 2)], ids=["mha", "gqa"])
+def test_decode_attention_matches_a_plain_softmax(hq, hkv):
+    """The whole-cache walk (no bound: what `LLMEngine` and the pipeline's
+    stages build) at ragged lengths: one row, mid-cache, every row."""
     from ray_tpu.ops.decode_attention import decode_attention
 
-    q = jnp.ones((2, 4, 32), jnp.float32)
-    cache = jnp.ones((2, 100, 4, 32), jnp.float32)
-    lens = jnp.asarray([5, 100], jnp.int32)
-    monkeypatch.setenv("RT_DECODE_KERNEL", "pallas")
-    with pytest.raises(ValueError, match="lane-aligned"):
-        decode_attention(q, cache, cache, lens)
+    q, k, v = decode_case(hq, hkv, d=32, row=32)
+    lens = [1, 130, 256]
+    out = decode_attention(q, k, v, jnp.asarray(lens, jnp.int32))
+    np.testing.assert_allclose(
+        np.asarray(out), plain_decode_attention(q, k, v, lens), atol=2e-5)
+
+
+def test_decode_attention_bounded_over_wide_rows_matches_a_plain_softmax():
+    """The serving walk as the engine calls it on the chip: heads of 96 in
+    rows of 128 (`cache_row`), stopped by a traced bound at a prefix of the
+    cache (192 of 256 rows here). What lies beyond the head in a row, and
+    beyond the bound in the cache, is never read into the result."""
+    from ray_tpu.ops.decode_attention import decode_attention, kv_prefix_rows
+
+    q, k, v = decode_case(4, 4, d=96, row=128)
+    lens = [1, 100, 130]
+    assert kv_prefix_rows(max(lens), 256) == 192
+    walk = jax.jit(lambda kb: decode_attention(
+        q, k, v, jnp.asarray(lens, jnp.int32), kv_bound=kb))
+    np.testing.assert_allclose(
+        np.asarray(walk(jnp.int32(max(lens)))),
+        plain_decode_attention(q, k, v, lens), atol=2e-5)
 
 
 @pytest.mark.parametrize("causal", [True, False])

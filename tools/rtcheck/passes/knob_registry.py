@@ -3,9 +3,8 @@
 The typed `rtconfig` registry is the single source of truth for runtime
 knobs: flags are env-overridable (`RT_<NAME>`), overridable per-cluster via
 `init(_system_config=...)`, and the resolved table propagates cluster-wide.
-An ad-hoc `os.environ.get("RT_*")` read bypasses all three — the stray
-`RT_DECODE_KERNEL` knob was invisible to `_system_config`, undocumented,
-and unpropagated.
+An ad-hoc `os.environ.get("RT_*")` read bypasses all three: a knob read
+that way is invisible to `_system_config`, undocumented, and unpropagated.
 
 Checks across ray_tpu/ (rtconfig.py itself is exempt — it IS the registry):
 
