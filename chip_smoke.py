@@ -352,7 +352,10 @@ def serve_phase(serve, LLMConfig, build_openai_app, chips: int) -> dict:
             f"program; peak device memory "
             f"{st['memory_peak_bytes'] / 2**30:.2f} GiB; decode steps "
             f"walked {st['kv_walk_share']:.3f} of max_seq "
-            f"(live rows {st['kv_live_share']:.3f})")
+            f"(live rows {st['kv_live_share']:.3f}); {st['splices']} "
+            f"hand-overs of a batch row, {st['splices_in_flight']} behind "
+            f"a chunk in flight, {st['pipeline_dry']} passes began with "
+            f"nothing in flight")
         # The cache must cross a program's boundary in the layout the
         # decode loop computes in: a copy of a whole leaf there is a
         # conversion paid by every chunk, whatever its length.

@@ -36,8 +36,10 @@ engine workers via vllm_models.py:123-137). TPU-native design:
   iteration while every younger chunk keeps executing — the XLA stream
   never drains on a readback. Prefill dispatches on its own lane thread
   and splices into the batch at chunk boundaries, so admissions never
-  stall steady-state decode. Tokens are DELIVERED in per-chunk batches
-  (one consumer wakeup per chunk, not per token).
+  stall steady-state decode. A batch row changes hands through ONE device
+  program, and the scheduler's thread issues no eager program at a
+  hand-over or a drain (`_run_scheduler`). Tokens are DELIVERED in
+  per-chunk batches (one consumer wakeup per chunk, not per token).
 """
 
 from __future__ import annotations
@@ -51,7 +53,7 @@ import re
 import threading
 import time
 from dataclasses import dataclass
-from typing import Any, Optional
+from typing import Optional
 
 import numpy as np
 
@@ -237,13 +239,23 @@ def _make_sampler(vocab: int):
 
 
 class _Slot:
-    __slots__ = ("stream", "sampling", "remaining", "emitted")
+    """One request's occupancy of batch row `slot`, from its hand-over
+    (`_splice`) to the end of its stream. Every chunk dispatched for it
+    records IT, not the row's index, so the row can pass to the next
+    request while chunks that stepped this one are still in flight."""
 
-    def __init__(self, stream: GenStream, sampling: SamplingParams):
+    __slots__ = ("slot", "stream", "sampling", "remaining", "emitted",
+                 "in_flight", "done")
+
+    def __init__(self, slot: int, stream: GenStream,
+                 sampling: SamplingParams):
+        self.slot = slot
         self.stream = stream
         self.sampling = sampling
-        self.remaining = sampling.max_tokens
+        self.remaining = sampling.max_tokens  # tokens its stream is owed
         self.emitted = 0
+        self.in_flight = 0  # decode steps dispatched for it and not read
+        self.done = False   # its stream has ended (`_retire`)
 
 
 # ------------------------------------------------------ engine tracing
@@ -470,6 +482,13 @@ def _rows_from_columns(columns: np.ndarray, held: int) -> np.ndarray:
     return columns.T.reshape(-1)[:held]
 
 
+def _start_host_copy(arr) -> None:
+    try:
+        arr.copy_to_host_async()
+    except Exception:
+        pass  # backend without async copy: the read pays it
+
+
 #: Decode chunks kept in flight (`ContinuousEngine._run_scheduler`): the
 #: oldest is read back while the younger ones execute, so the device never
 #: waits for a read. No caller ever asked for another depth.
@@ -527,8 +546,7 @@ class ContinuousEngine:
         self._pending: "queue.Queue" = queue.Queue()
         self._slots: list[Optional[_Slot]] = [None] * max_batch
         self._lengths = np.zeros(max_batch, np.int32)  # next write position
-        self._next_tok = np.zeros(max_batch, np.int32)
-        # Sampling params live ON DEVICE (updated by .at[].set at admit):
+        # Sampling params live ON DEVICE (set by the hand-over program):
         # steady-state chunk dispatch must transfer nothing host->device.
         self._temps_dev = jnp.zeros(max_batch, jnp.float32)
         self._topks_dev = jnp.zeros(max_batch, jnp.int32)
@@ -538,15 +556,12 @@ class ContinuousEngine:
         self._cache = None  # created lazily at first admit
         self._req_counter = itertools.count()
         self._n_active = 0
-        # Pipelining state: FIFO of dispatched-but-unread chunks, per-slot
-        # counts of dispatched-but-unemitted tokens, slots that must not be
-        # re-admitted until every in-flight chunk stepping them lands, and
-        # device-resident next-token/length mirrors so steady-state chunk
-        # dispatch needs NO host->device transfer.
-        self._q_chunks: list = []  # [(tokens_device, active, n, tag), ...]
-        self._pending_firsts: list = []  # [(slot, first_token_device), ...]
-        self._pending_toks = np.zeros(max_batch, np.int64)
-        self._cooling: dict[int, Any] = {}
+        # Pipelining state: FIFO of dispatched-but-unread chunks, each with
+        # the occupants it stepped; first tokens dispatched by the prefill
+        # lane and not read; and device-resident next-token/length mirrors
+        # so steady-state chunk dispatch needs NO host->device transfer.
+        self._q_chunks: list = []  # [(tokens_device, occupants, n), ...]
+        self._pending_firsts: list = []  # [(occupant, first_token_device)]
         self._toks_dev = jnp.zeros(max_batch, jnp.int32)
         self._lens_dev = jnp.zeros(max_batch, jnp.int32)
         # Every GenStream not yet _DONE, independent of slot state: the
@@ -617,6 +632,13 @@ class ContinuousEngine:
         self._kv_steps = 0
         self._kv_walked = 0
         self._kv_live = 0.0
+        # Hand-overs of a batch row since start, those dispatched behind at
+        # least one decode chunk still in flight, and the scheduler's
+        # passes that began with occupants seated and no chunk in flight:
+        # the pipeline drained to a retirement (`cache_stats`).
+        self.splices = 0
+        self.splices_in_flight = 0
+        self.pipeline_dry = 0
 
         def make_chunk(model):
             held = model.cfg.held_experts
@@ -693,16 +715,25 @@ class ContinuousEngine:
                 logits[0].astype(jnp.float32), plen - 1, 0, keepdims=False)
             return last, jax.tree.map(lambda c: c[:, :lb], vars_out["cache"])
 
-        def place(cache, slice_cache, slot):
-            """Copy a [1, Lb, ...] prefill cache slice into the first rows
-            of batch row `slot`. The slot's later rows keep what an earlier
+        def place(cache, slice_cache, mirrors, first, key, ints, floats):
+            """The hand-over of batch row `slot` to a prefilled request, as
+            ONE program whatever its values (one per prefill bucket: the
+            slice's shape). `ints` is [slot, prompt length, top_k], `floats`
+            [temperature, top_p], `mirrors` the per-row (next token,
+            length, key, temperature, top_k, top_p) the chunk programs
+            chain through. The [1, Lb, ...] prefill cache slice goes into
+            the row's first rows; its later rows keep what an earlier
             request left there: a row is written by the step that first
             makes it visible (Attention._cached_attention)."""
-            return jax.tree.map(
+            slot = ints[0]
+            cache = jax.tree.map(
                 lambda big, small: jax.lax.dynamic_update_slice(
                     big, small.astype(big.dtype),
                     (slot,) + (0,) * (small.ndim - 1)),
                 cache, slice_cache)
+            values = (first, ints[1], key, floats[0], ints[2], floats[1])
+            return cache, tuple(
+                m.at[slot].set(v) for m, v in zip(mirrors, values))
 
         def sample1(logits, key, temp, top_k, top_p):
             return sampler(logits[None], key[None], temp[None], top_k[None],
@@ -838,7 +869,11 @@ class ContinuousEngine:
         attention walked (`kv_walk_share`: the prefix its chunk's
         `kv_bound` chose) beside the share its live slots had written on
         average (`kv_live_share`: what a walk that stopped at each slot's
-        own length would read)."""
+        own length would read); and how batch rows changed hands: the
+        hand-overs (`splices`), those whose program was dispatched behind
+        at least one decode chunk in flight (`splices_in_flight`), and
+        the scheduler's passes that began with occupants seated and no
+        chunk in flight (`pipeline_dry`)."""
         import jax
 
         mcfg = self.model.cfg
@@ -850,7 +885,10 @@ class ContinuousEngine:
                    leaf.size * leaf.dtype.itemsize
                    for leaf in jax.tree.leaves(self._cache_spec)),
                "kv_walk_share": self._kv_walked / rows,
-               "kv_live_share": self._kv_live / rows}
+               "kv_live_share": self._kv_live / rows,
+               "splices": self.splices,
+               "splices_in_flight": self.splices_in_flight,
+               "pipeline_dry": self.pipeline_dry}
         if self._moe_held:
             out.update(experts_held=self._moe_held,
                        experts_published=mcfg.moe_experts,
@@ -858,18 +896,12 @@ class ContinuousEngine:
                        moe_rows_total=self.moe_rows_total)
         return out
 
-    def _count_moe(self, all_np: np.ndarray, q: list, off: int) -> dict:
-        """The expert layers' row counts of the chunks just read (they ride
-        behind each chunk's tokens): added to the totals, and returned as
-        the attributes `engine.host_sync` carries."""
-        rows = np.zeros(self._moe_held, np.int64)
-        steps = 0
-        for _toks, _active, pn, _tag in q:
-            off += pn
-            rows += _rows_from_columns(
-                all_np[:, off:off + self._moe_cols], self._moe_held)
-            off += self._moe_cols
-            steps += pn
+    def _count_moe(self, block: np.ndarray, n: int) -> dict:
+        """The expert layers' row counts of the n-step chunk just read
+        (they ride behind its tokens): added to the totals, and returned
+        as the attributes `engine.host_sync` carries."""
+        rows = _rows_from_columns(block[:, n:n + self._moe_cols],
+                                  self._moe_held)
         total = int(rows.sum())
         self.moe_rows_total += total
         if total:
@@ -880,7 +912,7 @@ class ContinuousEngine:
             except Exception:
                 pass
         return {"moe_rows": total, "moe_rows_busiest": int(rows.max()),
-                "moe_steps": steps}
+                "moe_steps": n}
 
     def _init_cache(self):
         """Zero cache for the full batch."""
@@ -1011,6 +1043,9 @@ class ContinuousEngine:
                 last_logits, key,
                 jnp.float32(sampling.temperature),
                 jnp.int32(sampling.top_k), jnp.float32(sampling.top_p))
+            # The scheduler reads it at the drain after the hand-over:
+            # the copy is under way by then, and nothing is built there.
+            _start_host_copy(first)
         finally:
             if ann is not None:
                 ann.__exit__(None, None, None)
@@ -1076,9 +1111,11 @@ class ContinuousEngine:
 
     def _splice(self, slot: int, plen: int, sampling, stream, first,
                 cache_slice, key):
-        """Install one prefilled request into batch row `slot` (scheduler
-        thread only — this is the chunk-boundary splice point): scatter
-        the cache slice, set the device mirrors, book the slot."""
+        """Hand free batch row `slot` to one prefilled request (scheduler
+        thread only): ONE device program scatters the cache slice and sets
+        the row's device mirrors (`place`), then the occupant is booked.
+        The program queues behind whatever chunks are in flight, and
+        every chunk dispatched from here on records the new occupant."""
         if stream.trace is not None:
             now = time.time()
             _stage_end(stream, "engine.ready_wait", now,
@@ -1087,37 +1124,36 @@ class ContinuousEngine:
                          chunks_in_flight=len(self._q_chunks))
         if self._cache is None:
             self._cache = self._init_cache()
-        self._cache = self._place(self._cache, cache_slice,
-                                  self._jnp.int32(slot))
-        st = _Slot(stream, sampling)
+        mirrors = (self._toks_dev, self._lens_dev, self._keys,
+                   self._temps_dev, self._topks_dev, self._topps_dev)
+        self._cache, mirrors = self._place(
+            self._cache, cache_slice, mirrors, first, key,
+            np.array([slot, plen, sampling.top_k], np.int32),
+            np.array([sampling.temperature, sampling.top_p], np.float32))
+        (self._toks_dev, self._lens_dev, self._keys, self._temps_dev,
+         self._topks_dev, self._topps_dev) = mirrors
+        st = _Slot(slot, stream, sampling)
         self._slots[slot] = st
         self._n_active += 1
         self._lengths[slot] = plen
-        self._pending_toks[slot] = 0
-        self._temps_dev = self._temps_dev.at[slot].set(sampling.temperature)
-        self._topks_dev = self._topks_dev.at[slot].set(sampling.top_k)
-        self._topps_dev = self._topps_dev.at[slot].set(sampling.top_p)
-        self._keys = self._keys.at[slot].set(key)
-        self._pending_firsts.append((slot, first))
-        # Merge into the device mirrors without a sync.
-        self._toks_dev = self._toks_dev.at[slot].set(first)
-        self._lens_dev = self._lens_dev.at[slot].set(int(plen))
+        self._pending_firsts.append((st, first))
+        self.splices += 1
+        if self._q_chunks:
+            self.splices_in_flight += 1
 
-    def _free_slot(self, taken=()) -> Optional[int]:
-        return next((i for i, s in enumerate(self._slots)
-                     if s is None and i not in self._cooling
-                     and i not in taken), None)
+    def _free_slot(self) -> Optional[int]:
+        return next((i for i, s in enumerate(self._slots) if s is None),
+                    None)
 
-    def _deliver(self, slot: int, toks: list):
-        """Hand one chunk's tokens for `slot` to its stream as ONE queue
+    def _deliver(self, st: _Slot, toks: list):
+        """Hand one chunk's tokens to the occupant's stream as ONE queue
         put (a blocked reader wakes once per chunk, not once per token),
         applying stop-token / length truncation host-side."""
-        st = self._slots[slot]
-        if st is None:
-            return
+        if st.done:
+            return  # stopped or cancelled earlier; the tail is garbage
         if st.stream.closed:
             st.stream.finish_reason = "cancelled"
-            self._retire(slot)
+            self._retire(st)
             return
         out = toks[:max(0, st.remaining)]
         finish = None
@@ -1138,23 +1174,29 @@ class ContinuousEngine:
             _count_tokens(len(out))
         if finish is not None:
             st.stream.finish_reason = finish
-            self._retire(slot)
+            self._retire(st)
 
-    def _retire(self, slot: int):
-        st = self._slots[slot]
+    def _retire(self, st: _Slot):
+        """End the occupant's stream; its row is free for the next request
+        at once. Chunks in flight may still step the row (the occupant
+        stopped early or its consumer went away): they run before the next
+        hand-over's program on the device, and what they decode belongs to
+        the occupant they recorded, which takes no more."""
         self._finish_stream(st.stream)
-        self._slots[slot] = None
+        st.done = True
+        self._slots[st.slot] = None
         self._n_active -= 1
-        self._lengths[slot] = 0
-        self._next_tok[slot] = 0
-        # (device-side sampling mirrors keep stale values for retired
-        # slots; the slot decodes garbage that deliver discards)
-        if self._q_chunks and slot in self._q_chunks[-1][1]:
-            # Already-dispatched chunks still step this slot; it must not
-            # be re-admitted until the NEWEST of them is emitted (device
-            # program order makes the cache safe — this guards only the
-            # host-side slot bookkeeping).
-            self._cooling[slot] = self._q_chunks[-1][3]
+        self._lengths[st.slot] = 0
+        # (the row's device mirrors keep stale values until the next
+        # hand-over; it decodes garbage that nobody is handed)
+
+    def _fail(self, occupants, error: Exception):
+        """A dispatch or a read failed: the error, then the end, on every
+        stream among `occupants` that is still open."""
+        for st in occupants:
+            if not st.done:
+                st.stream._q.put(error)
+                self._retire(st)
 
     def _loop(self):
         """Scheduler wrapper: an unexpected scheduler death must surface
@@ -1181,13 +1223,25 @@ class ContinuousEngine:
         Each chunk's token block starts its device→host copy AT DISPATCH
         (copy_to_host_async) and is read back one chunk per iteration —
         double-buffered extraction: reading chunk N overlaps the execution
-        of chunks N+1..N+D-1, so the XLA stream never drains. Correctness
-        leans on device program order (place/chunk chain through the cache
-        handle); the host only avoids re-admitting a slot an in-flight
-        chunk still steps (the _cooling set).
+        of chunks N+1..N+D-1, so the XLA stream never drains.
 
-        One pass: `_admit` what the prefill lane has ready, `_fill_pipeline`
-        with decode chunks, `_drain` the oldest of them."""
+        How a batch row changes hands: through ONE device program
+        (`_splice`), where its occupant's last token is READ. A chunk is cut
+        to the fewest steps any seated occupant still needs, so once
+        somebody's last step is in flight nothing more is dispatched until
+        it is read; the newcomer then joins a pipeline that has drained to
+        that point, and its first token and its first chunk's tokens reach
+        the client one drain apart. (Handing the row on at DISPATCH time,
+        with the pipeline kept at its depth, was measured and left out:
+        the newcomer's first step then queues behind up to three chunks,
+        PERF.md section 6, PR 31.) A chunk records its occupants
+        (`_Slot`), not their rows, so a row given up early (a stop token,
+        a consumer gone) is the next request's at once, whatever chunks
+        still step it: device program order alone protects the cache
+        (place/chunk chain through the cache handle and the mirrors).
+
+        One pass: `_fill_pipeline` with hand-overs and decode chunks,
+        `_drain` the oldest chunk."""
         phases = _Phases(self._jax.profiler)
         while self._running:
             # Tracing on: the pass's phases on the host's and the
@@ -1195,9 +1249,11 @@ class ContinuousEngine:
             ph = phases if _tracing.enabled() else None
             if ph is not None:
                 ph.start_pass()
-            spliced = self._admit()
-            if (self._n_active == 0 and not self._q_chunks
-                    and not self._pending_firsts):
+            if self._n_active and not self._q_chunks:
+                self.pipeline_dry += 1
+            spliced, dispatched, iter_ctx = self._fill_pipeline(ph)
+            if not (self._q_chunks or self._pending_firsts):
+                # Nothing in flight, so nobody is seated: wait for work.
                 if ph is not None:
                     ph.begin("idle_wait")
                 with self._lock:
@@ -1208,7 +1264,6 @@ class ContinuousEngine:
                 if ph is not None:
                     ph.end()  # no span: the next pass carries its idle_ms
                 continue
-            dispatched, iter_ctx = self._fill_pipeline(ph)
             sync_ctx = self._drain(ph)
             if ph is not None:
                 # the traced request the pass's span is bound to
@@ -1218,13 +1273,13 @@ class ContinuousEngine:
                             active=self._n_active)
 
     def _admit(self) -> int:
-        """Phase `admit`: splice the requests the prefill lane has parked in
-        `_ready` into free slots, at this chunk boundary. Nothing here reads
-        from the device: first tokens are NOT read at admission, they join
-        the next drain's readback (an admission-wave readback would cost
-        its own blocking host sync). Returns the requests spliced."""
+        """Phase `admit`: hand the free rows to the requests the prefill
+        lane has parked in `_ready`. Nothing here reads from the device:
+        first tokens are NOT read at admission, they join the next drain's
+        readback (an admission-wave readback would cost its own blocking
+        host sync). Returns the requests spliced."""
         spliced = 0
-        while self._n_active < self.max_batch:
+        while self._ready:
             free = self._free_slot()
             if free is None:
                 break
@@ -1246,60 +1301,54 @@ class ContinuousEngine:
         return spliced
 
     def _fill_pipeline(self, ph) -> tuple:
-        """Phase `dispatch`: dispatch up to PIPELINE_DEPTH chunks back to
-        back (dispatches are asynchronous and nearly free; only the
-        readback costs a round trip). Returns the chunks dispatched and
-        the first traced request a chunk's span was bound to, if any."""
+        """Phases `admit` and `dispatch`, as often as they alternate: hand
+        every free row to a waiting request, then dispatch a chunk for the
+        occupants seated, until PIPELINE_DEPTH chunks are in flight
+        (dispatches are asynchronous and nearly free; only the readback
+        costs a round trip). A request that is parked while the loop runs
+        joins before its next chunk. Returns the requests spliced, the chunks
+        dispatched and the first traced request a chunk's span was bound
+        to, if any. Entered in phase `admit`."""
         from ray_tpu.ops.decode_attention import kv_prefix_rows
 
         max_seq = self.cfg.max_seq
         iter_ctx = None
-        dispatched = 0
-        if ph is not None:
-            # wall_ns ties the spans' wall clock to the trace's own.
-            ph.begin("dispatch", wall_ns=time.time_ns())
-        while len(self._q_chunks) < PIPELINE_DEPTH:
-            if (self._ready and self._n_active < self.max_batch
-                    and self._free_slot() is not None):
-                # A prefilled request is waiting and a slot is open:
-                # stop filling the pipeline with the OLD batch and
-                # splice at this chunk boundary (next iteration's
-                # admission step) — join latency stays a few tokens.
+        spliced = dispatched = 0
+        while True:
+            spliced += self._admit()
+            active = [s for s in self._slots if s is not None]
+            if not active or len(self._q_chunks) >= PIPELINE_DEPTH:
                 break
-            active = [i for i, s in enumerate(self._slots)
-                      if s is not None]
-            if not active:
-                break
-            live = [int(self._lengths[i]) for i in active]
-            budget = int(min(
-                min(self._slots[i].remaining - self._pending_toks[i]
-                    for i in active),
-                max_seq - max(live)))
+            live = [int(self._lengths[s.slot]) for s in active]
+            budget = int(min(min(s.remaining - s.in_flight for s in active),
+                             max_seq - max(live)))
             if budget < 1:
-                break  # every active slot's fate is already in flight
+                # Somebody's last step is in flight: its row changes hands
+                # where that is read, and nobody is stepped past it.
+                break
             # Power-of-2 chunk sizes only: each distinct scan length
             # is its own compiled program, and an arbitrary shrinking
             # budget would recompile on nearly every call.
             n = max(1, min(self.decode_chunk,
                            1 << (budget.bit_length() - 1)))
-            greedy = all(
-                self._slots[i].sampling.temperature <= 0.0
-                for i in active)
+            greedy = all(s.sampling.temperature <= 0.0 for s in active)
             # Per-iteration tracing (README "Tracing & timeline"): bind
             # the decode loop's spans to the oldest active TRACED
             # request — in the one-request case every dispatch and
             # host sync lands in its timeline.
-            tctx = next((self._slots[i].stream.trace for i in active
-                         if self._slots[i].stream.trace is not None),
-                        None)
-            # The rows the longest LIVE slot has after these n steps:
+            tctx = next((s.stream.trace for s in active
+                         if s.stream.trace is not None), None)
+            # The rows the longest LIVE occupant has after these n steps:
             # where the chunk's attention may stop. Only the host can
-            # say it, from integers it holds: an idle or cooling slot's
-            # device-side length is stale and keeps growing, and what
-            # such a slot decodes is discarded (_deliver).
+            # say it, from integers it holds: a free row's device-side
+            # length is stale and keeps growing, and what such a row
+            # decodes is handed to nobody.
             kv_bound = max(live) + n
             assert kv_bound <= max_seq and all(
                 length + n <= kv_bound for length in live), (live, n)
+            if ph is not None:
+                # wall_ns ties the spans' wall clock to the trace's own.
+                ph.begin("dispatch", wall_ns=time.time_ns())
             try:
                 t_disp = time.time()
                 self._cache, self._keys, toks_out, lens_out = \
@@ -1313,10 +1362,7 @@ class ContinuousEngine:
                 # NOW: by the time the drain reads it (D iterations
                 # later), the transfer has overlapped the younger
                 # chunks' execution instead of serializing after it.
-                try:
-                    toks_out.copy_to_host_async()
-                except Exception:
-                    pass  # backend without async copy: read pays it
+                _start_host_copy(toks_out)
                 kv_rows = kv_prefix_rows(kv_bound, max_seq)
                 _tracing.record_span_in(
                     tctx, "engine.dispatch_chunk", "engine", t_disp,
@@ -1327,44 +1373,36 @@ class ContinuousEngine:
                 self._kv_walked += n * kv_rows
                 # step j of the chunk sees length + j + 1 rows of a slot
                 self._kv_live += n * (sum(live) / len(live) + (n + 1) / 2)
-                # Chain on device; mirror lengths on host (every slot
+                # Chain on device; mirror lengths on host (every row
                 # steps n times — deterministic, no read needed).
                 self._toks_dev = toks_out[:, n - 1]
                 self._lens_dev = lens_out
                 self._lengths = self._lengths + n
-                for i in active:
-                    self._pending_toks[i] += n
-                self._q_chunks.append((toks_out, active, n, object()))
+                self._q_chunks.append((toks_out, active, n))
                 dispatched += 1
                 iter_ctx = iter_ctx or tctx
+                for s in active:
+                    s.in_flight += n
             except Exception as e:
                 logger.exception("llm engine decode chunk failed")
-                for i in active:
-                    self._slots[i].stream._q.put(e)
-                    self._retire(i)
+                self._fail(active, e)
                 break
-        return dispatched, iter_ctx
+            if ph is not None:
+                ph.begin("admit")
+        return spliced, dispatched, iter_ctx
 
     def _drain(self, ph):
         """Phases `sync` and `deliver`: read the OLDEST in-flight chunk
-        (plus any admission wave's first tokens) in one device sync, leaving
-        the younger chunks executing — the double buffer — and hand the
-        tokens to their streams. One host_sync per chunk: a request's span
-        count is bounded by its CHUNK count, never its token count.
+        (plus the first tokens of the hand-overs since the last drain, whose
+        host copies the prefill lane started), leaving the younger chunks
+        executing — the double buffer — and hand the tokens to the
+        occupants the chunk recorded. One host_sync per chunk: a request's
+        span count is bounded by its CHUNK count, never its token count.
         Returns the traced request the sync's span is bound to, if any."""
-        if not (self._q_chunks or self._pending_firsts):
-            return None
-        jnp = self._jnp
-        q = self._q_chunks[:1]
-        del self._q_chunks[:1]
+        toks_dev, occupants, n = (self._q_chunks.pop(0) if self._q_chunks
+                                  else (None, [], 0))
         firsts, self._pending_firsts = self._pending_firsts, []
-        parts = []
-        if firsts:
-            col = jnp.zeros((self.max_batch, 1), jnp.int32)
-            for slot, fdev in firsts:
-                col = col.at[slot, 0].set(fdev)
-            parts.append(col)
-        parts.extend(c[0] for c in q)
+        owed = occupants + [st for st, _f in firsts]
         # The host-sync readback: THE per-iteration host-link round
         # trip the decode loop pays (once one per TOKEN; now one per
         # chunk, overlapped). Span it against the oldest traced
@@ -1372,73 +1410,34 @@ class ContinuousEngine:
         sync_ctx = None
         if _tracing.enabled():
             sync_ctx = next(
-                (self._slots[i].stream.trace
-                 for _t, p_active, _n, _tag in q for i in p_active
-                 if self._slots[i] is not None
-                 and self._slots[i].stream.trace is not None),
-                None)
-            if sync_ctx is None:
-                sync_ctx = next(
-                    (self._slots[s].stream.trace
-                     for s, _f in firsts
-                     if self._slots[s] is not None
-                     and self._slots[s].stream.trace is not None),
-                    None)
+                (st.stream.trace for st in owed
+                 if not st.done and st.stream.trace is not None), None)
         t_sync = ph.begin("sync") if ph is not None else time.time()
         try:
-            all_np = np.asarray(
-                parts[0] if len(parts) == 1
-                else jnp.concatenate(parts, axis=1))
+            first_toks = [int(f) for _st, f in firsts]
+            block = None if toks_dev is None else np.asarray(toks_dev)
         except Exception as e:
-            for slot, _f in firsts:
-                if self._slots[slot] is not None:
-                    self._slots[slot].stream._q.put(e)
-                    self._retire(slot)
-            for _t, p_active, _n, _tag in q:
-                for i in p_active:
-                    if self._slots[i] is not None:
-                        self._slots[i].stream._q.put(e)
-                        self._retire(i)
-            all_np = None
+            self._fail(owed, e)
+            return sync_ctx
         # sync_ms of the pass is engine.host_sync's own interval.
         t_end = ph.begin("deliver") if ph is not None else None
-        moe = (self._count_moe(all_np, q, 1 if firsts else 0)
-               if self._moe_cols and q and all_np is not None else {})
-        if sync_ctx is not None and all_np is not None:
+        moe = (self._count_moe(block, n)
+               if self._moe_cols and block is not None else {})
+        if sync_ctx is not None:
             t_end = t_end or time.time()
             _tracing.record_span_in(
-                sync_ctx, "engine.host_sync", "engine", t_sync,
-                t_end, {"chunks": len(q),
-                        "cols": int(all_np.shape[1]), **moe})
+                sync_ctx, "engine.host_sync", "engine", t_sync, t_end,
+                {"chunks": int(block is not None),
+                 "cols": 0 if block is None else block.shape[1], **moe})
             try:
                 from ray_tpu.util import metrics as _metrics
 
                 _metrics.LLM_HOST_SYNC_SECONDS.observe(t_end - t_sync)
             except Exception:
                 pass
-        off = 0
-        if firsts and all_np is not None:
-            for slot, _f in firsts:
-                if self._slots[slot] is None:
-                    continue  # retired by a failed-dispatch path
-                self._next_tok[slot] = int(all_np[slot, 0])
-                self._deliver(slot, [int(all_np[slot, 0])])
-        if firsts:
-            off = 1
-        for _toks_dev, p_active, pn, tag in q:
-            if all_np is not None:
-                for i in p_active:
-                    self._pending_toks[i] = max(
-                        0, self._pending_toks[i] - pn)
-                    if self._slots[i] is None:
-                        continue  # retired; tail is garbage
-                    toks = [int(all_np[i, j])
-                            for j in range(off, off + pn)]
-                    self._deliver(i, toks)
-                    if self._slots[i] is not None:
-                        self._next_tok[i] = int(
-                            all_np[i, off + pn - 1])
-            off += pn + self._moe_cols
-            self._cooling = {s: t for s, t in self._cooling.items()
-                             if t is not tag}
+        for (st, _f), tok in zip(firsts, first_toks):
+            self._deliver(st, [tok])
+        for st in occupants:
+            st.in_flight -= n
+            self._deliver(st, block[st.slot, :n].tolist())
         return sync_ctx

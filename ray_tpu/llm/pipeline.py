@@ -721,7 +721,7 @@ class PipelinedEngine:
             stream.finish_reason = "cancelled"
             self._finish_stream(stream)
             return
-        st = _Slot(stream, sampling)
+        st = _Slot(slot, stream, sampling)
         self._slots[slot] = st
         self._n_active += 1
         mb, r = divmod(slot, self.mb_size)

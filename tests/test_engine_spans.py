@@ -154,6 +154,33 @@ def test_an_iteration_spans_phases_add_up(engine, spans):
             (sync["b"] - sync["a"]) * 1e3, abs=0.002)
 
 
+def test_the_stats_count_how_rows_changed_hands(engine, spans):
+    """`splices`, `splices_in_flight`, `pipeline_dry` of `cache_stats` (what
+    `/v1/stats` reports) against what the spans saw: the traced stage
+    `engine.first_token` carries the chunks in flight at its hand-over, and
+    a pass that began dry dispatched into an empty pipeline."""
+    keys = ("splices", "splices_in_flight", "pipeline_dry")
+    assert [engine.cache_stats()[k] for k in keys] == [0, 0, 0]
+    serve(engine, 5)
+    # a request that joins a neighbour in mid-flight: behind its chunks
+    tracing._ctx.set(("c" * 32, "d" * 16))
+    long = engine.submit([1], SamplingParams(max_tokens=40, temperature=0.0))
+    long.next(timeout=WAIT_S)
+    joiner = engine.submit([2, 3], SamplingParams(max_tokens=6,
+                                                  temperature=0.0))
+    tracing._ctx.set(None)
+    assert len(joiner.tokens()) == 6 and len(long.tokens()) == 39
+    st = engine.cache_stats()
+    firsts = [s for s in spans if s["n"] == "engine.first_token"]
+    assert st["splices"] == len(firsts) == 7
+    assert st["splices_in_flight"] == sum(
+        1 for s in firsts if s["at"]["chunks_in_flight"] > 0) >= 1
+    # A row changes hands where its occupant's last token is read: the
+    # pipeline has drained to there, and where a neighbour is seated the
+    # next pass begins with nothing in flight.
+    assert 1 <= st["pipeline_dry"] <= st["splices"]
+
+
 def test_idle_time_is_carried_by_the_next_recorded_pass(engine, spans):
     serve(engine, 1)
     time.sleep(0.35)  # the scheduler waits with nothing to do

@@ -226,7 +226,8 @@ def test_cache_stats_name_the_layout_and_count_boundary_copies(engine):
     st = engine.cache_stats()
     assert set(st) == {"cache_layout", "cache_boundary_copies",
                        "cache_kind", "cache_bytes", "kv_walk_share",
-                       "kv_live_share"}
+                       "kv_live_share", "splices", "splices_in_flight",
+                       "pipeline_dry"}
     assert 0.0 <= st["kv_live_share"] <= st["kv_walk_share"] <= 1.0
     assert st["cache_kind"] == "kv"
     assert st["cache_bytes"] == (CFG.n_layers * 2 * 4 * CFG.max_seq
@@ -338,7 +339,7 @@ def test_a_request_cancelled_while_parked_in_ready_takes_no_slot(
 
     monkeypatch.setattr(engine, "_splice", spy)
     with monkeypatch.context() as full:
-        full.setattr(engine, "_free_slot", lambda taken=(): None)
+        full.setattr(engine, "_free_slot", lambda: None)
         parked = engine.submit([1, 2, 3], sp)
         behind = engine.submit([1, 2, 3], sp)
         until(lambda: len(engine._ready) == 2, "both prefills to park")
@@ -395,7 +396,7 @@ def test_shutdown_ends_requests_parked_in_ready_and_in_pending():
         return prefill(*args)
 
     eng._prefill = third_call_waits
-    eng._free_slot = lambda taken=(): None  # no slot comes free
+    eng._free_slot = lambda: None  # no slot comes free
     sp = SamplingParams(temperature=0.0, max_tokens=6)
     try:
         streams = [eng.submit([1, 2, 3], sp) for _ in range(2)]
@@ -417,6 +418,214 @@ def test_shutdown_ends_requests_parked_in_ready_and_in_pending():
     assert eng._pending.empty() and not eng._streams
     with pytest.raises(RuntimeError, match="shut down"):
         eng.submit([1, 2, 3], sp)
+
+
+# ---------------------------------------------------------------------------
+# How a batch row changes hands (README "Serving hot loop"): through one device
+# program, where its occupant's last token is read; a chunk records its
+# occupants, so a row given up early is the next request's at once. Two rows,
+# chunks of four steps; every request is parked in `_ready` before a row is
+# given out, so that the order of the hand-overs is the order of the submits.
+
+GREEDY = dict(temperature=0.0)
+
+
+@pytest.fixture(scope="module")
+def pair():
+    eng = ContinuousEngine(CFG, max_batch=2, decode_chunk=4)
+    yield eng
+    eng.shutdown()
+
+
+def alone(eng, prompt, **sampling) -> list:
+    return drain(eng.submit(prompt, SamplingParams(**GREEDY, **sampling)))
+
+
+def parked(eng, monkeypatch, requests) -> list:
+    """Submit `requests` [(prompt, SamplingParams)] while no row is given
+    out, wait until every prefill is parked in `_ready`, then let go."""
+    with monkeypatch.context() as full:
+        full.setattr(eng, "_free_slot", lambda: None)
+        streams = [eng.submit(p, sp) for p, sp in requests]
+        until(lambda: len(eng._ready) == len(requests),
+              "every prefill to park")
+    return streams
+
+
+def hand_overs(eng, monkeypatch) -> dict:
+    """Every `_splice` as stream -> (row, chunks in flight behind which its
+    program was dispatched)."""
+    seen, splice = {}, eng._splice
+
+    def spy(slot, plen, sampling, stream, *rest):
+        seen[stream] = (slot, len(eng._q_chunks))
+        return splice(slot, plen, sampling, stream, *rest)
+
+    monkeypatch.setattr(eng, "_splice", spy)
+    return seen
+
+
+def counted(eng, before: dict) -> dict:
+    now = eng.cache_stats()
+    return {k: now[k] - before[k]
+            for k in ("splices", "splices_in_flight", "pipeline_dry")}
+
+
+def test_six_requests_through_two_rows_each_get_the_tokens_they_get_alone(
+        pair, monkeypatch):
+    """Requests of different lengths, so that rows change hands at six
+    different chunk boundaries beside a live neighbour: each stream is the
+    one its request gets alone, every row was handed on where its
+    occupant's last token was read (the pipeline had drained to there, and
+    the next pass began with the neighbour seated and nothing or one step
+    in flight), and the counters say so."""
+    lengths = [5, 9, 14, 3, 7, 11]
+    prompts = [[1 + i, 2, 3 + i] for i in range(len(lengths))]
+    want = [alone(pair, p, max_tokens=n) for p, n in zip(prompts, lengths)]
+    assert [len(w) for w in want] == lengths
+    before = pair.cache_stats()
+    seen = hand_overs(pair, monkeypatch)
+    streams = parked(pair, monkeypatch, [
+        (p, SamplingParams(**GREEDY, max_tokens=n))
+        for p, n in zip(prompts, lengths)])
+    assert [drain(s) for s in streams] == want
+    assert {s.finish_reason for s in streams} == {"length"}
+    until(lambda: pair.num_active == 0, "the rows to be given back")
+    # (a request's steps are counted before its first token is: the one
+    # step too many may still be in flight when its last token is read)
+    behind = [seen[s][1] for s in streams]
+    assert behind[:2] == [0, 0] and max(behind) <= 1
+    assert {seen[s][0] for s in streams} == {0, 1}
+    got = counted(pair, before)
+    assert got["splices"] == 6
+    assert got["splices_in_flight"] == sum(behind)
+    assert 1 <= got["pipeline_dry"] <= 6
+    assert not pair._q_chunks and not pair._pending_firsts
+
+
+def test_a_row_given_up_at_a_stop_token_is_the_next_requests_at_once(
+        pair, monkeypatch):
+    """The stop is read with chunks in flight that still step the row.
+    They were recorded for the occupant that stopped: the next one takes
+    that very row behind them, with no wait for them to land, and gets its
+    own tokens and none of theirs."""
+    base = alone(pair, [4, 5], max_tokens=40)
+    stop = base[5]
+    cut = base.index(stop) + 1
+    long_want = alone(pair, [7, 7, 7], max_tokens=90)
+    next_want = alone(pair, [9, 8], max_tokens=13)
+    before = pair.cache_stats()
+    seen = hand_overs(pair, monkeypatch)
+    long_s, stopper, nxt = parked(pair, monkeypatch, [
+        ([7, 7, 7], SamplingParams(**GREEDY, max_tokens=90)),
+        ([4, 5], SamplingParams(**GREEDY, max_tokens=40, stop_token=stop)),
+        ([9, 8], SamplingParams(**GREEDY, max_tokens=13))])
+    assert drain(stopper) == base[:cut] and stopper.finish_reason == "stop"
+    assert drain(nxt) == next_want and nxt.finish_reason == "length"
+    assert drain(long_s) == long_want
+    assert seen[nxt][0] == seen[stopper][0] != seen[long_s][0]
+    assert seen[nxt][1] >= 1, "the hand-over waited for the chunks in flight"
+    assert counted(pair, before)["splices_in_flight"] == 1
+
+
+def test_a_stream_closed_mid_decode_ends_cancelled_and_frees_its_row(
+        pair, monkeypatch):
+    """The consumer goes away with chunks of its request in flight: the
+    stream ends `cancelled` where the next of them is read, the request
+    parked behind it takes the row at once, and that one's tokens and the
+    neighbour's are whole."""
+    gone_want = alone(pair, [3, 1, 4], max_tokens=60)
+    next_want = alone(pair, [2, 7, 1, 8], max_tokens=10)
+    other_want = alone(pair, [6], max_tokens=70)
+    seen = hand_overs(pair, monkeypatch)
+    other, gone, nxt = parked(pair, monkeypatch, [
+        ([6], SamplingParams(**GREEDY, max_tokens=70)),
+        ([3, 1, 4], SamplingParams(**GREEDY, max_tokens=60)),
+        ([2, 7, 1, 8], SamplingParams(**GREEDY, max_tokens=10))])
+    got = [gone.next(timeout=WAIT_S)]
+    gone.close()
+    got += drain(gone)
+    assert gone.finish_reason == "cancelled"
+    assert len(got) < 60 and got == gone_want[:len(got)]
+    assert drain(nxt) == next_want and nxt.finish_reason == "length"
+    assert drain(other) == other_want
+    assert seen[nxt][0] == seen[gone][0] and seen[nxt][1] >= 1
+    until(lambda: pair.num_active == 0, "the rows to be given back")
+    assert gone not in pair._streams and not any(pair._slots)
+
+
+@pytest.mark.parametrize("how", ["shutdown", "scheduler_error"])
+def test_a_stream_with_chunks_in_flight_is_ended(how):
+    """The engine stops, or its scheduler dies, between the dispatch of a
+    request's chunks and their read: the stream ends all the same, with
+    the error where there is one."""
+    import threading
+
+    eng = ContinuousEngine(CFG, max_batch=2, decode_chunk=4)
+    reached, drain_, there = threading.Event(), eng._drain, []
+
+    def stops_there(ph):
+        if len(eng._q_chunks) < 2:
+            return drain_(ph)
+        there.append(([s and s.stream for s in eng._slots],
+                      set(eng._streams)))
+        reached.set()
+        if how == "scheduler_error":
+            raise RuntimeError("the read broke")
+        until(lambda: not eng._running, "shutdown to begin")
+        return None
+
+    eng._drain = stops_there
+    try:
+        stream = eng.submit([1, 2, 3],
+                            SamplingParams(**GREEDY, max_tokens=12))
+        assert reached.wait(timeout=WAIT_S)
+        assert there[0] == ([stream, None], {stream})
+        if how == "shutdown":
+            eng.shutdown()
+            assert len(drain(stream)) < 12
+        else:
+            with pytest.raises(RuntimeError, match="scheduler died.*broke"):
+                drain(stream)
+            assert drain(stream) == []  # the error, then the end
+        assert not eng._streams
+    finally:
+        eng.shutdown()
+    assert not any(t.is_alive() for t in eng._threads)
+
+
+def test_twenty_hand_overs_with_other_values_build_no_program(
+        engine, monkeypatch):
+    """One hand-over program whatever the row, the prompt's length within
+    a bucket, `temperature`, `top_k`, `top_p` and seed: after one request
+    of each kind has been through, twenty more build nothing, eager or
+    jitted."""
+    from ray_tpu._private import telemetry
+
+    telemetry.ensure_compile_listener()
+    # Alone in the batch a request of 7 tokens is dispatched whole in its
+    # first pass, as chunks of 4, 2 and 1: every length, greedy and sampled.
+    for temperature in (0.0, 0.8):
+        assert len(drain(engine.submit([1, 2, 3], SamplingParams(
+            temperature=temperature, top_k=4, top_p=0.9, max_tokens=7)))) == 7
+    rows, splice = [], engine._splice
+
+    def spy(slot, *rest):
+        rows.append(slot)
+        return splice(slot, *rest)
+
+    monkeypatch.setattr(engine, "_splice", spy)
+    built = telemetry.compile_stats()["count"]
+    streams = [engine.submit(
+        list(range(1, 2 + i % 7)),
+        SamplingParams(temperature=(0.0, 0.3, 0.9, 1.7)[i % 4],
+                       top_k=(0, 1, 5, 50)[i % 3 if i % 3 else 3],
+                       top_p=(1.0, 0.95, 0.5)[i % 3], seed=1000 + i,
+                       max_tokens=7))
+        for i in range(20)]
+    assert [len(drain(s)) for s in streams] == [7] * 20
+    assert len(rows) == 20 and set(rows) == set(range(engine.max_batch))
+    assert telemetry.compile_stats()["count"] == built
 
 
 def test_streamed_text_does_not_depend_on_where_its_batches_were_cut():

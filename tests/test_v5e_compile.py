@@ -80,8 +80,15 @@ def test_v5e_chunk_program_takes_and_returns_the_cache_without_a_copy(chip):
                          jax.ShapeDtypeStruct((1, 64), jnp.int32), 5)[1]
     assert {leaf.shape for leaf in jax.tree.leaves(one)} == {
         (1, 64, CFG.n_heads, 128)}
+    row = lambda dtype, *dims: jax.ShapeDtypeStruct(  # noqa: E731
+        (MAX_BATCH, *dims), dtype)
+    mirrors = (row(jnp.int32), row(jnp.int32), row(jnp.uint32, 2),
+               row(jnp.float32), row(jnp.int32), row(jnp.float32))
     placed = eng._place.lower(
-        cache, one, jax.ShapeDtypeStruct((), jnp.int32)).compile()
+        cache, one, mirrors, jax.ShapeDtypeStruct((), jnp.int32),
+        jax.ShapeDtypeStruct((2,), jnp.uint32),
+        jax.ShapeDtypeStruct((3,), jnp.int32),
+        jax.ShapeDtypeStruct((2,), jnp.float32)).compile()
     assert not re.findall(r"= \w+\[%d,%d,%d,\d+\]\S* copy\("
                           % (MAX_BATCH, CFG.max_seq, CFG.n_heads),
                           placed.as_text())
