@@ -361,9 +361,11 @@ def model_config(cfg):
         return TransformerConfig(
             n_kv_heads=cfg.n_heads, d_ff=int(cfg.d_model * 8 / 3) // 8 * 8,
             **sizes)
-    if arch.get("model_type") not in ("kimi_k2", "deepseek_v3"):
-        raise ValueError(f"no model is built for model_type "
-                         f"{arch.get('model_type')!r}")
+    kind = arch.get("model_type")
+    if kind == "afmoe":
+        return TransformerConfig(**_afmoe(cfg, arch), **sizes)
+    if kind not in ("kimi_k2", "deepseek_v3"):
+        raise ValueError(f"no model is built for model_type {kind!r}")
     want = {"hidden_act": "silu", "attention_bias": False, "n_group": 1,
             "topk_group": 1, "topk_method": "noaux_tc", "moe_layer_freq": 1,
             "num_nextn_predict_layers": 0}
@@ -374,11 +376,7 @@ def model_config(cfg):
         raise ValueError("latent attention has one latent for all heads: "
                          "num_key_value_heads must equal the heads")
     published = int(arch["n_routed_experts"])
-    held = cfg.experts_held or published
-    if not 0 <= cfg.first_expert <= published - held:
-        raise ValueError(
-            f"experts [{cfg.first_expert}, {cfg.first_expert + held}) are "
-            f"not among the {published} published")
+    held = _experts_held(cfg, published)
     return TransformerConfig(
         n_kv_heads=cfg.n_heads, d_ff=int(arch["intermediate_size"]),
         rope_theta=float(arch["rope_theta"]),
@@ -399,6 +397,65 @@ def model_config(cfg):
         moe_shared_experts=int(arch["n_shared_experts"] or 0),
         moe_first_layer=int(arch["first_k_dense_replace"]),
         experts_held=held, first_expert=cfg.first_expert, **sizes)
+
+
+def _experts_held(cfg, published: int) -> int:
+    """How many of the `published` routed experts this device holds."""
+    held = cfg.experts_held or published
+    if not 0 <= cfg.first_expert <= published - held:
+        raise ValueError(
+            f"experts [{cfg.first_expert}, {cfg.first_expert + held}) are "
+            f"not among the {published} published")
+    return held
+
+
+def _afmoe(cfg, arch: dict) -> dict:
+    """The fields of a `model_type: afmoe` decoder (Arcee's Trinity family)
+    beyond the six sizes: grouped-query attention with a published head
+    size, window and full layers by `layer_types`, query/key norms, a
+    sigmoid gate on the attention's output, rotary embedding on the window
+    layers only, four norms a layer, the embedding scaled by sqrt(d) under
+    `mup_enabled`, leading dense layers, then sigmoid-routed experts beside
+    shared ones. What no key of `config.json` states is the published
+    modelling code's (ISSUE 32 lists each under `assumed`)."""
+    want = {"hidden_act": "silu", "n_group": 1, "topk_group": 1,
+            "num_expert_groups": 1, "num_limited_groups": 1,
+            "rope_scaling": None, "attention_bias": False}
+    odd = {k: arch[k] for k, v in want.items() if arch.get(k, v) != v}
+    if odd:
+        raise ValueError(f"not built: {odd} (built: {want})")
+    kinds = list(arch["layer_types"])[:cfg.n_layers]
+    if len(kinds) < cfg.n_layers or set(kinds) - {"sliding_attention",
+                                                  "full_attention"}:
+        raise ValueError(f"layer_types must name {cfg.n_layers} layers as "
+                         f"sliding_attention or full_attention: {kinds}")
+    kv_heads = int(arch["num_key_value_heads"])
+    if cfg.n_heads % kv_heads:
+        raise ValueError(f"{cfg.n_heads} heads do not share {kv_heads} "
+                         f"key/value heads evenly")
+    published = int(arch["num_experts"])
+    return dict(
+        n_kv_heads=kv_heads, head_size=int(arch["head_dim"]),
+        d_ff=int(arch["intermediate_size"]),
+        rope_theta=float(arch["rope_theta"]),
+        norm_eps=float(arch["rms_norm_eps"]),
+        tie_embeddings=bool(arch["tie_word_embeddings"]),
+        sliding_window=int(arch["sliding_window"]),
+        window_layers=tuple(k == "sliding_attention" for k in kinds),
+        rope_window_only=True, qk_norm=True, attn_gate=True,
+        sandwich_norm=True,
+        emb_scale=(float(cfg.d_model) ** 0.5 if arch.get("mup_enabled")
+                   else 1.0),
+        moe_experts=published, moe_top_k=int(arch["num_experts_per_tok"]),
+        moe_d_ff=int(arch["moe_intermediate_size"]),
+        moe_scoring=arch["score_func"],
+        moe_norm_topk=bool(arch["route_norm"]),
+        moe_routed_scale=float(arch["route_scale"]),
+        moe_score_bias=True,  # the router's `expert_bias`: selection only
+        moe_shared_experts=int(arch["num_shared_experts"] or 0),
+        moe_first_layer=int(arch["num_dense_layers"]),
+        experts_held=_experts_held(cfg, published),
+        first_expert=cfg.first_expert)
 
 
 def stage_layer_split(n_layers: int, n_stages: int) -> list[tuple[int, ...]]:
@@ -445,6 +502,13 @@ def make_stage_net(mcfg, layers: tuple, first: bool, last: bool):
     import flax.linen as nn
 
     from ray_tpu.models.transformer import Block, RMSNorm, output_head
+
+    if any(mcfg.window_of(i) for i in layers):
+        raise NotImplementedError(
+            "pipeline stages keep one kind of cache leaf, max_seq rows a "
+            "slot (llm/pipeline.py `place`, `_init_cache`, and no "
+            "`prompt_len` for a ring's hand-over): a model with window "
+            "layers is served by ContinuousEngine only")
 
     class _StageNet(nn.Module):
         @nn.compact
@@ -493,6 +557,14 @@ def _start_host_copy(arr) -> None:
 #: oldest is read back while the younger ones execute, so the device never
 #: waits for a read. No caller ever asked for another depth.
 PIPELINE_DEPTH = 4
+
+#: Prefill buckets are powers of two, each its own compiled program. Above
+#: this many rows a bucket comes at three quarters of one as well: the rows a
+#: prompt is padded by are device time that every request decoding beside
+#: it waits for (tenths of a second at these lengths: PERF.md section 6,
+#: PR 32); below it a step in between is one more program to build for
+#: milliseconds.
+HALF_STEP_ABOVE = 4096
 
 
 class ContinuousEngine:
@@ -574,6 +646,7 @@ class ContinuousEngine:
         # prefill compile/dispatch never blocks the decode loop.
         self._ready: collections.deque = collections.deque()
         self._prefill_inflight = 0
+        self._parked_bytes = 0  # of the cache slices in `_ready` or on their way
         self._threads = [
             threading.Thread(target=self._prefill_loop, daemon=True,
                              name="rt-llm-prefill"),
@@ -630,8 +703,8 @@ class ContinuousEngine:
         # walked a slot, and the rows a live slot had on average
         # (`cache_stats`: kv_walk_share, kv_live_share).
         self._kv_steps = 0
-        self._kv_walked = 0
-        self._kv_live = 0.0
+        self._kv_walked = {"full": 0, "window": 0}
+        self._kv_live = {"full": 0.0, "window": 0.0}
         # Hand-overs of a batch row since start, those dispatched behind at
         # least one decode chunk still in flight, and the scheduler's
         # passes that began with occupants seated and no chunk in flight:
@@ -700,20 +773,45 @@ class ContinuousEngine:
                 dataclasses.replace(self.model.cfg, cache_row=row))
         model = self.model
         self._cache_spec = self._cache_shapes(model, self.params)
+        mcfg = model.cfg
+        # Two kinds of leaf in one manager: a full layer's `max_seq` rows a
+        # slot and a window layer's ring, both `[slots, rows, ...]`.
+        self._window = max((mcfg.window_of(i) for i in range(mcfg.n_layers)),
+                           default=0)
+        kinds = ["window" if mcfg.window_of(i) else "full"
+                 for i in range(mcfg.n_layers)]
+        self._cache_kinds = {
+            kind: {"layers": kinds.count(kind),
+                   "rows": self._window if kind == "window" else mcfg.max_seq,
+                   "bytes": sum(
+                       leaf.size * leaf.dtype.itemsize
+                       for i, k in enumerate(kinds) if k == kind
+                       for leaf in jax.tree.leaves(
+                           self._cache_spec[f"layer_{i}"]))}
+            for kind in ("full", "window") if kind in kinds}
+        # A request parked in `_ready` holds its prefill's cache slices on
+        # the device. The lane runs ahead of the scheduler only while what
+        # is parked stays under a quarter of the cache's own bytes.
+        self._park_budget = sum(
+            k["bytes"] for k in self._cache_kinds.values()) // 4
+        self._slice_bytes_of: dict = {}
 
         def prefill(params, toks, plen):
-            """toks [1, Lb] -> (last-position logits [V], the cache's first
-            Lb rows). The rows beyond the bucket were not written, and a
-            request parked in _ready holds what this returns: Lb rows a
-            leaf, not max_seq."""
+            """toks [1, Lb] -> (last-position logits [V], each cache leaf's
+            first min(Lb, its rows) rows). A full leaf's rows beyond the
+            bucket were not written; a ring shorter than the bucket comes
+            whole, holding the last positions before `plen` at their ring
+            places (`Attention._cached_attention`). A request parked in
+            _ready holds what this returns, not max_seq rows a leaf."""
             lb = toks.shape[1]
             positions = jnp.arange(lb)[None]
             logits, vars_out = model.apply(
                 {"params": params}, toks, positions=positions, decode=True,
-                mutable=["cache"])
+                prompt_len=jnp.reshape(plen, (1,)), mutable=["cache"])
             last = jax.lax.dynamic_index_in_dim(
                 logits[0].astype(jnp.float32), plen - 1, 0, keepdims=False)
-            return last, jax.tree.map(lambda c: c[:, :lb], vars_out["cache"])
+            return last, jax.tree.map(
+                lambda c: c[:, :min(lb, c.shape[1])], vars_out["cache"])
 
         def place(cache, slice_cache, mirrors, first, key, ints, floats):
             """The hand-over of batch row `slot` to a prefilled request, as
@@ -722,9 +820,10 @@ class ContinuousEngine:
             [temperature, top_p], `mirrors` the per-row (next token,
             length, key, temperature, top_k, top_p) the chunk programs
             chain through. The [1, Lb, ...] prefill cache slice goes into
-            the row's first rows; its later rows keep what an earlier
-            request left there: a row is written by the step that first
-            makes it visible (Attention._cached_attention)."""
+            the row's first rows (of a ring: all of them, once the bucket
+            is as long); its later rows keep what an earlier request left
+            there: a row is written by the step that first makes it
+            visible (Attention._cached_attention)."""
             slot = ints[0]
             cache = jax.tree.map(
                 lambda big, small: jax.lax.dynamic_update_slice(
@@ -869,23 +968,32 @@ class ContinuousEngine:
         attention walked (`kv_walk_share`: the prefix its chunk's
         `kv_bound` chose) beside the share its live slots had written on
         average (`kv_live_share`: what a walk that stopped at each slot's
-        own length would read); and how batch rows changed hands: the
+        own length would read); `cache_kinds`, the same two shares and the
+        layers, rows a slot and bytes of each kind of leaf, `full`
+        (`max_seq` rows) and `window` (a ring); `kv_heads`, the key/value
+        heads a row holds; and how batch rows changed hands: the
         hand-overs (`splices`), those whose program was dispatched behind
         at least one decode chunk in flight (`splices_in_flight`), and
         the scheduler's passes that began with occupants seated and no
         chunk in flight (`pipeline_dry`)."""
-        import jax
-
         mcfg = self.model.cfg
-        rows = max(1, self._kv_steps) * mcfg.max_seq
+        steps = max(1, self._kv_steps)
+        kinds = {kind: {**k, "walk_share": self._kv_walked[kind]
+                        / (steps * k["rows"]),
+                        "live_share": self._kv_live[kind]
+                        / (steps * k["rows"])}
+                 for kind, k in self._cache_kinds.items()}
+        # (the two shares at the top are the full leaves', as they were
+        # before a leaf could be a ring; `cache_kinds` has both kinds')
+        top = kinds.get("full") or kinds["window"]
         out = {"cache_layout": self.cache_layout,
                "cache_boundary_copies": self.cache_boundary_copies,
                "cache_kind": "latent" if mcfg.attention == "mla" else "kv",
-               "cache_bytes": sum(
-                   leaf.size * leaf.dtype.itemsize
-                   for leaf in jax.tree.leaves(self._cache_spec)),
-               "kv_walk_share": self._kv_walked / rows,
-               "kv_live_share": self._kv_live / rows,
+               "cache_bytes": sum(k["bytes"] for k in kinds.values()),
+               "cache_kinds": kinds,
+               "kv_heads": 1 if mcfg.attention == "mla" else mcfg.n_kv_heads,
+               "kv_walk_share": top["walk_share"],
+               "kv_live_share": top["live_share"],
                "splices": self.splices,
                "splices_in_flight": self.splices_in_flight,
                "pipeline_dry": self.pipeline_dry}
@@ -1012,10 +1120,26 @@ class ContinuousEngine:
 
     # ----------------------------------------------------------- scheduler
     def _bucket(self, plen: int) -> int:
+        """Rows a prompt's prefill is padded to: the next power of two and,
+        above HALF_STEP_ABOVE, three quarters of it where the prompt fits
+        (..., 2048, 4096, 6144, 8192, 12288, ...)."""
         b = 8
         while b < plen:
             b *= 2
+        if b > HALF_STEP_ABOVE and plen <= b // 4 * 3:
+            b = b // 4 * 3
         return min(b, self.cfg.max_seq)
+
+    def _slice_bytes(self, bucket: int) -> int:
+        """Bytes of the cache slices a prefill of this bucket hands on."""
+        import jax
+
+        if bucket not in self._slice_bytes_of:
+            self._slice_bytes_of[bucket] = sum(
+                leaf.size // (self.max_batch * leaf.shape[1])
+                * min(bucket, leaf.shape[1]) * leaf.dtype.itemsize
+                for leaf in jax.tree.leaves(self._cache_spec))
+        return self._slice_bytes_of[bucket]
 
     def _prefill_dispatch(self, prompt, sampling, stream):
         """Dispatch bucketed prefill + first-token sample WITHOUT reading
@@ -1092,7 +1216,15 @@ class ContinuousEngine:
             # whose prefill is still dispatching must keep the loop from
             # concluding "nothing pending" (it would only cost the 0.1s
             # wait timeout, but the first token is latency-critical).
+            nbytes = self._slice_bytes(self._bucket(len(prompt)))
             with self._lock:
+                # Parked slices live on the device: run ahead of the
+                # scheduler only within `_park_budget` (one request may
+                # always be parked, whatever its size).
+                while (self._running and self._parked_bytes
+                       and self._parked_bytes + nbytes > self._park_budget):
+                    self._lock.wait(timeout=0.25)
+                self._parked_bytes += nbytes
                 self._prefill_inflight += 1
             try:
                 entry = (len(prompt), sampling, stream,
@@ -1100,6 +1232,7 @@ class ContinuousEngine:
             except Exception as e:  # bad request or device failure
                 with self._lock:
                     self._prefill_inflight -= 1
+                    self._parked_bytes -= nbytes
                 self._finish_stream(stream, e)
                 continue
             with self._lock:
@@ -1287,6 +1420,9 @@ class ContinuousEngine:
                 if not self._ready:
                     break
                 entry = self._ready.popleft()
+                self._parked_bytes -= self._slice_bytes(
+                    self._bucket(entry[0]))
+                self._lock.notify_all()  # the lane may run ahead again
             plen, sampling, stream, first, cache_slice, key = entry
             if stream.closed:
                 stream.finish_reason = "cancelled"
@@ -1363,16 +1499,28 @@ class ContinuousEngine:
                 # later), the transfer has overlapped the younger
                 # chunks' execution instead of serializing after it.
                 _start_host_copy(toks_out)
-                kv_rows = kv_prefix_rows(kv_bound, max_seq)
+                # Rows a slot's attention walks in each step of the chunk,
+                # and rows a live slot has to show (step j sees length +
+                # j + 1 of them, a ring at most its own length): by kind
+                # of leaf, a step's mean.
+                seen = np.add.outer(live, np.arange(1, n + 1))
+                rows = {"full": (kv_prefix_rows(kv_bound, max_seq),
+                                 float(seen.mean()))}
+                if self._window:
+                    rows["window"] = (
+                        kv_prefix_rows(kv_bound, self._window),
+                        float(np.minimum(seen, self._window).mean()))
+                attrs = {"tokens": n, "active": len(active),
+                         "kv_bound": kv_bound, "kv_rows": rows["full"][0]}
+                for kind, (walked, visible) in rows.items():
+                    attrs["kv_rows_" + kind] = walked
+                    attrs["kv_live_" + kind] = round(visible, 2)
+                    self._kv_walked[kind] += n * walked
+                    self._kv_live[kind] += n * visible
                 _tracing.record_span_in(
                     tctx, "engine.dispatch_chunk", "engine", t_disp,
-                    time.time(), {"tokens": n, "active": len(active),
-                                  "kv_bound": kv_bound,
-                                  "kv_rows": kv_rows})
+                    time.time(), attrs)
                 self._kv_steps += n
-                self._kv_walked += n * kv_rows
-                # step j of the chunk sees length + j + 1 rows of a slot
-                self._kv_live += n * (sum(live) / len(live) + (n + 1) / 2)
                 # Chain on device; mirror lengths on host (every row
                 # steps n times — deterministic, no read needed).
                 self._toks_dev = toks_out[:, n - 1]

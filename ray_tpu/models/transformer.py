@@ -26,13 +26,13 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ray_tpu.models.layers import RMSNorm, SwiGLU, YarnScaling, rope as _rope
-from ray_tpu.models.mla import MLA
+from ray_tpu.models.mla import MLA, SCORE_TILE_BYTES
 from ray_tpu.models.moe import MoE
 from ray_tpu.ops import dot_product_attention
 
 __all__ = ["Attention", "Block", "MLA", "MoE", "RMSNorm", "SwiGLU",
            "Transformer", "TransformerConfig", "YarnScaling", "loss_fn",
-           "param_specs"]
+           "param_specs", "prefill_attention"]
 
 
 @dataclass(frozen=True)
@@ -49,6 +49,29 @@ class TransformerConfig:
     param_dtype: jnp.dtype = jnp.float32
     #: RMSNorm's epsilon, every norm of the model.
     norm_eps: float = 1e-6
+    #: A head's size where the model publishes one (`head_dim`); 0 is
+    #: d_model // n_heads.
+    head_size: int = 0
+    #: Layers whose attention sees the last `sliding_window` positions only
+    #: (key j is visible to query i iff 0 <= i - j < sliding_window): the
+    #: model's `layer_types`, True for a window layer; () is none. A window
+    #: layer's cache is a ring of `sliding_window` rows, position p in row
+    #: p mod sliding_window (`Attention._cached_attention`).
+    sliding_window: int = 0
+    window_layers: tuple = ()
+    #: Rotary embedding on the window layers only; a full layer's queries
+    #: and keys then carry no position.
+    rope_window_only: bool = False
+    #: RMSNorm of each head's query and key over the head's dims.
+    qk_norm: bool = False
+    #: The attention's output times sigmoid(x W_g), a gate a head dim, before
+    #: the output projection.
+    attn_gate: bool = False
+    #: Two further norms a layer: each sublayer's output is normed before it
+    #: joins the residual stream.
+    sandwich_norm: bool = False
+    #: What the embedding's rows are multiplied by.
+    emb_scale: float = 1.0
     #: The logits are the final hidden state times the embedding (True) or
     #: times a matrix of their own, `lm_head` (False).
     tie_embeddings: bool = True
@@ -95,7 +118,14 @@ class TransformerConfig:
 
     @property
     def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+        return self.head_size or self.d_model // self.n_heads
+
+    def window_of(self, i: int) -> int:
+        """Rows of layer i's window, cut to `max_seq`; 0 for a full layer."""
+        if self.sliding_window and i < len(self.window_layers) \
+                and self.window_layers[i]:
+            return min(self.sliding_window, self.max_seq)
+        return 0
 
     def is_moe_layer(self, i: int) -> bool:
         return self.moe_experts > 0 and i >= self.moe_first_layer
@@ -109,9 +139,12 @@ class TransformerConfig:
 
 class Attention(nn.Module):
     cfg: TransformerConfig
+    #: Rows of this layer's window (`cfg.window_of(i)`); 0: full attention.
+    window: int = 0
 
     @nn.compact
-    def __call__(self, x, positions, decode: bool = False, kv_bound=None):
+    def __call__(self, x, positions, decode: bool = False, kv_bound=None,
+                 prompt_len=None):
         cfg = self.cfg
         hd = cfg.head_dim
         dense = lambda feats, name: nn.DenseGeneral(  # noqa: E731
@@ -120,97 +153,200 @@ class Attention(nn.Module):
         q = dense((cfg.n_heads, hd), "wq")(x)
         k = dense((cfg.n_kv_heads, hd), "wk")(x)
         v = dense((cfg.n_kv_heads, hd), "wv")(x)
-        q = _rope(q, positions, cfg.rope_theta)
-        k = _rope(k, positions, cfg.rope_theta)
-        if decode:
-            out = self._cached_attention(q, k, v, positions, kv_bound)
-        else:
-            out = dot_product_attention(q, k, v, causal=True)
+        if cfg.qk_norm:
+            with jax.named_scope("qk_norm"):
+                q = RMSNorm(cfg.norm_eps, name="q_norm")(q)
+                k = RMSNorm(cfg.norm_eps, name="k_norm")(k)
+        if self.window or not cfg.rope_window_only:
+            # (keys are rotated by their absolute position BEFORE they are
+            # cached, so the order of a ring's rows means nothing)
+            q = _rope(q, positions, cfg.rope_theta)
+            k = _rope(k, positions, cfg.rope_theta)
+        with jax.named_scope("window_attention" if self.window
+                             else "full_attention"):
+            if decode:
+                out = self._cached_attention(q, k, v, positions, kv_bound,
+                                             prompt_len)
+            elif self.window:
+                out = prefill_attention(q, k, v, self.window)
+            else:
+                out = dot_product_attention(q, k, v, causal=True)
+        if cfg.attn_gate:
+            with jax.named_scope("attn_gate"):
+                out = out * jax.nn.sigmoid(dense((cfg.n_heads, hd), "wg")(x))
         return nn.DenseGeneral(cfg.d_model, axis=(-2, -1), use_bias=False, name="wo",
                                dtype=cfg.dtype, param_dtype=cfg.param_dtype)(out)
 
-    def _cached_attention(self, q, k, v, positions, kv_bound=None):
+    def _cached_attention(self, q, k, v, positions, kv_bound=None,
+                          prompt_len=None):
         """Autoregressive KV-cache attention with PER-SEQUENCE positions
         (reference role: vLLM's paged KV cache; here slot-per-sequence):
-        new k/v rows scatter into fixed [B, max_seq, KV, D] buffers at each
-        sequence's own absolute positions, so one compiled step can serve a
-        continuous batch whose members are at different depths (the
-        requirement of in-flight batching). Visibility for query i of
-        sequence b is t <= positions[b, i]; rows above a sequence's current
-        position are never visible, so stale pad/previous-request garbage
-        in the slot can never leak into attention. Single-token steps
-        (S==1, the serving hot loop) go through the decode-attention
-        dispatcher (ops/decode_attention.py: the fused XLA path in every
-        configuration served so far). Given `kv_bound` (a traced int32
-        scalar from the engine's scheduler: the longest LIVE sequence's
-        length after this chunk, which only the host knows, because a
-        retired slot's device-side position keeps growing), such a step
-        reads the shortest static prefix of the cache that holds that many
-        rows instead of all `max_seq`; without it, the whole cache, by the
-        program it always was. Multi-token steps (prefill) run the dense
-        f32 einsum below over the whole cache."""
+        new k/v rows go into fixed per-slot buffers at each sequence's own
+        absolute positions, so one compiled step can serve a continuous
+        batch whose members are at different depths (the requirement of
+        in-flight batching).
+
+        Two kinds of leaf. A full layer keeps `max_seq` rows a slot,
+        position p in row p. A window layer keeps a RING of `window` rows,
+        position p in row p mod window: the rows of the last `window`
+        positions are exactly the rows a query may see. Either way the rows
+        visible to a single-token step at position p are `[0, min(p + 1,
+        rows))`: rows above a sequence's current position are never
+        visible, so stale pad/previous-request garbage in the slot can
+        never leak into attention, and in a ring that has wrapped every row
+        is live. A leaf is `[slots, rows, kv heads, row]`; `row` is the
+        head's size or wider (`cfg.cache_row`).
+
+        Single-token steps (S==1, the serving hot loop) go through
+        ops/decode_attention.py. Given `kv_bound` (a traced int32 scalar
+        from the engine's scheduler: the longest LIVE sequence's length
+        after this chunk, which only the host knows, because a retired
+        slot's device-side position keeps growing), such a step reads the
+        shortest static prefix of the leaf that holds that many rows, of
+        `max_seq` or of the ring; without it, the whole leaf, by the
+        program it always was.
+
+        A multi-token step is a prefill from position 0: it attends over
+        its own rows in query tiles (`prefill_attention`) and only writes
+        the cache. Where the call is longer than a ring, the ring gets the
+        rows of the last `window` positions before `prompt_len` ([B],
+        traced; the call's length when None) at their ring places: a padded
+        position past the prompt would otherwise land on the row of a live
+        one."""
         cfg = self.cfg
         b, s = q.shape[0], q.shape[1]
         d = cfg.head_dim
         row = max(cfg.cache_row, d)
-        ck = self.variable("cache", "k", lambda: jnp.zeros(
-            (b, cfg.max_seq, cfg.n_kv_heads, row), cfg.dtype))
-        cv = self.variable("cache", "v", lambda: jnp.zeros(
-            (b, cfg.max_seq, cfg.n_kv_heads, row), cfg.dtype))
+        rows = self.window or cfg.max_seq
+        shape = (b, rows, cfg.n_kv_heads, row)
+        ck = self.variable("cache", "k", lambda: jnp.zeros(shape, cfg.dtype))
+        cv = self.variable("cache", "v", lambda: jnp.zeros(shape, cfg.dtype))
         pos = positions.astype(jnp.int32)
         bidx = jnp.arange(b)[:, None]
         k, v = k.astype(cfg.dtype), v.astype(cfg.dtype)
+        kc, vc = k, v  # as the cache holds them: rows as wide as `row`
         if row > d:
             tail = ((0, 0),) * 3 + ((0, row - d),)
-            k, v = jnp.pad(k, tail), jnp.pad(v, tail)
-        ck.value = ck.value.at[bidx, pos].set(k)
-        cv.value = cv.value.at[bidx, pos].set(v)
+            kc, vc = jnp.pad(k, tail), jnp.pad(v, tail)
+        if s > 1:
+            with jax.named_scope("prefill_attention"):
+                out = prefill_attention(q, k, v, self.window)
+            if s > rows:  # a ring shorter than the call
+                plen = (jnp.full((b,), s, jnp.int32) if prompt_len is None
+                        else prompt_len.astype(jnp.int32))
+                at = jnp.arange(rows)[None, :]
+                # row r holds the last position before plen that is r mod rows
+                src = jnp.clip(at + rows * ((plen[:, None] - 1 - at) // rows),
+                               0, s - 1)
+                kc, vc = kc[bidx, src], vc[bidx, src]
+            ck.value = jax.lax.dynamic_update_slice(ck.value, kc, (0, 0, 0, 0))
+            cv.value = jax.lax.dynamic_update_slice(cv.value, vc, (0, 0, 0, 0))
+            return out.astype(cfg.dtype)
+        at = pos % rows if self.window else pos
+        ck.value = ck.value.at[bidx, at].set(kc)
+        cv.value = cv.value.at[bidx, at].set(vc)
         keys, vals = ck.value, cv.value
-        if row > d and (s > 1 or kv_bound is None):
+        if row > d and kv_bound is None:
             # (a bounded step hands the leaves over whole: its branch cuts
             # rows and head together, where the cache lies)
             keys, vals = keys[..., :d], vals[..., :d]
-        if s == 1:
-            from ray_tpu.ops.decode_attention import decode_attention
+        from ray_tpu.ops.decode_attention import decode_attention
 
-            out = decode_attention(q[:, 0], keys, vals, pos[:, 0] + 1,
-                                   kv_bound=kv_bound)
-            return out[:, None].astype(cfg.dtype)
-        with jax.named_scope("prefill_attention"):
-            if cfg.n_kv_heads < cfg.n_heads:  # GQA: broadcast kv heads
-                rep = cfg.n_heads // cfg.n_kv_heads
-                keys = jnp.repeat(keys, rep, axis=2)
-                vals = jnp.repeat(vals, rep, axis=2)
-            scores = jnp.einsum(
-                "bshd,bthd->bhst", q.astype(jnp.float32),
-                keys.astype(jnp.float32)) / (cfg.head_dim ** 0.5)
-            # cache row t is visible to query i of sequence b iff
-            # t <= pos[b, i]
-            t_pos = jnp.arange(cfg.max_seq)[None, None, None, :]
-            q_pos = pos[:, None, :, None]
-            scores = jnp.where(t_pos <= q_pos, scores, -jnp.inf)
-            probs = jax.nn.softmax(scores, axis=-1)
-            out = jnp.einsum("bhst,bthd->bshd", probs,
-                             vals.astype(jnp.float32))
-            return out.astype(cfg.dtype)
+        out = decode_attention(q[:, 0], keys, vals, pos[:, 0] + 1,
+                               kv_bound=kv_bound)
+        return out[:, None].astype(cfg.dtype)
+
+
+def prefill_attention(q, k, v, window: int = 0):
+    """Causal attention of a call over ITS OWN rows, positions 0..S-1 in
+    order (a prefill, or a training batch of a model with window layers):
+    q [B, S, H, D], k and v [B, S, KV, D] -> [B, S, H, D]. Queries go in
+    tiles so that a tile's float32 scores stay small. The query heads that
+    share a key/value head are one matrix product against it: K and V are
+    never copied per query head.
+
+    Several tiles are ONE loop over a body of one shape (a loop in a
+    prefill is fine; a decode step has none): each tile reads the `band`
+    rows before it and its own, of a K and V padded in front by `band`
+    rows. In a window layer (key j visible to query i iff 0 <= i - j <
+    window) the band is the window, so a long bucket costs its band, not
+    its square. In a full layer the band is the whole call: half of what a
+    tile reads is masked, twice the products a tile that stopped at its own
+    end would make. Written out tile by tile at their own lengths, a prefill
+    of 8192 rows was an executable of 2,700 fusions, 55 MB in the compile
+    cache, whose serialisation held the interpreter's lock long enough for
+    serve's 5 s health check to lose the replica (PERF.md section 6, PR 32)."""
+    b, s, h, d = q.shape
+    kv = k.shape[2]
+    dtype = q.dtype
+    qg = q.reshape(b, s, kv, h // kv, d)
+    band = min(window, s) if window else s
+    fits = lambda t: (b * h * t * 4 * min(s, band + t)  # noqa: E731
+                      <= SCORE_TILE_BYTES)
+    # a call too long for one tile goes in tiles of a power of two that
+    # divide it (a bucket of 6144 in tiles of 512 or 256, like its neighbours)
+    tile = s if fits(s) else s & -s
+    while tile > 8 and not fits(tile):
+        tile //= 2
+
+    def attend(qt, kt, vt, i, j):
+        """One tile: queries at positions i [T] against keys at j [K]."""
+        scores = jnp.einsum("bsngd,btnd->bngst", qt, kt,
+                            preferred_element_type=jnp.float32) / (d ** 0.5)
+        back = i[:, None] - j[None, :]
+        visible = (back >= 0) & (j[None, :] >= 0)
+        if window:
+            visible = visible & (back < window)
+        probs = jax.nn.softmax(jnp.where(visible, scores, -jnp.inf), axis=-1)
+        return jnp.einsum("bngst,btnd->bsngd", probs.astype(dtype), vt)
+
+    if s == tile or s % tile:  # one tile, or a ragged call (no bucket)
+        outs = [attend(qg[:, at:at + tile], k[:, :at + tile], v[:, :at + tile],
+                       jnp.arange(at, min(at + tile, s)),
+                       jnp.arange(min(at + tile, s)))
+                for at in range(0, s, tile)]
+        out = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
+        return out.reshape(b, s, h, d).astype(dtype)
+    # keys [start - band, start + tile) of the padded K and V; the padding's
+    # positions are negative and never visible
+    front = ((0, 0), (band, 0), (0, 0), (0, 0))
+    kp, vp = jnp.pad(k, front), jnp.pad(v, front)
+
+    def one_tile(_, start):
+        cut = lambda t, n: jax.lax.dynamic_slice_in_dim(  # noqa: E731
+            t, start, n, axis=1)
+        return None, attend(cut(qg, tile), cut(kp, band + tile),
+                            cut(vp, band + tile), start + jnp.arange(tile),
+                            start - band + jnp.arange(band + tile))
+
+    _, outs = jax.lax.scan(one_tile, None, jnp.arange(0, s, tile))
+    return jnp.moveaxis(outs, 0, 1).reshape(b, s, h, d).astype(dtype)
 
 
 class Block(nn.Module):
     cfg: TransformerConfig
     #: this layer's feed-forward is the expert layer (cfg.is_moe_layer(i))
     moe: bool = False
+    #: rows of this layer's attention window (cfg.window_of(i)); 0: full
+    window: int = 0
 
     @nn.compact
-    def __call__(self, x, positions, decode: bool = False, kv_bound=None):
+    def __call__(self, x, positions, decode: bool = False, kv_bound=None,
+                 prompt_len=None):
         cfg = self.cfg
-        attn = (MLA(cfg, name="attn") if cfg.attention == "mla"
-                else Attention(cfg, name="attn"))
-        x = x + attn(RMSNorm(cfg.norm_eps, name="attn_norm")(x), positions,
-                     decode=decode, kv_bound=kv_bound)
-        h = RMSNorm(cfg.norm_eps, name="mlp_norm")(x)
-        if self.moe:
-            return x + MoE(cfg, name="moe")(h, serving=decode)
-        return x + SwiGLU(cfg, name="mlp")(h)
+        norm = lambda name: RMSNorm(cfg.norm_eps, name=name)  # noqa: E731
+        if cfg.attention == "mla":
+            a = MLA(cfg, name="attn")(norm("attn_norm")(x), positions,
+                                      decode=decode, kv_bound=kv_bound)
+        else:
+            a = Attention(cfg, window=self.window, name="attn")(
+                norm("attn_norm")(x), positions, decode=decode,
+                kv_bound=kv_bound, prompt_len=prompt_len)
+        x = x + (norm("post_attn_norm")(a) if cfg.sandwich_norm else a)
+        h = norm("mlp_norm")(x)
+        f = (MoE(cfg, name="moe")(h, serving=decode) if self.moe
+             else SwiGLU(cfg, name="mlp")(h))
+        return x + (norm("post_mlp_norm")(f) if cfg.sandwich_norm else f)
 
 
 def output_head(module: nn.Module, cfg: TransformerConfig, x, emb):
@@ -232,25 +368,30 @@ class Transformer(nn.Module):
 
     @nn.compact
     def __call__(self, tokens, positions=None, decode: bool = False,
-                 kv_bound=None):
+                 kv_bound=None, prompt_len=None):
         """tokens: [B, S] int32 -> logits [B, S, vocab] (f32).
 
         decode=True uses per-layer caches (flax "cache" collection): pass
         `positions` (absolute) and apply with mutable=["cache"]. A
         single-token decode step may also be told `kv_bound`, how many
-        cache rows its longest sequence of interest has
+        cache rows its longest sequence of interest has, and a prefill
+        padded to a bucket `prompt_len` [B], where its prompts end
         (`Attention._cached_attention`)."""
         cfg = self.cfg
         emb = self.param("tok_emb", nn.initializers.normal(0.02),
                          (cfg.vocab_size, cfg.d_model), cfg.param_dtype)
         x = emb[tokens].astype(cfg.dtype)
+        if cfg.emb_scale != 1.0:
+            x = x * jnp.asarray(cfg.emb_scale, cfg.dtype)
         if positions is None:
             positions = jnp.broadcast_to(jnp.arange(tokens.shape[1]), tokens.shape)
         for i in range(cfg.n_layers):
             if not decode:
                 x = _seq_shard(x)
-            x = Block(cfg, moe=cfg.is_moe_layer(i), name=f"layer_{i}")(
-                x, positions, decode=decode, kv_bound=kv_bound)
+            x = Block(cfg, moe=cfg.is_moe_layer(i), window=cfg.window_of(i),
+                      name=f"layer_{i}")(
+                x, positions, decode=decode, kv_bound=kv_bound,
+                prompt_len=prompt_len)
         x = RMSNorm(cfg.norm_eps, name="final_norm")(x)
         return output_head(self, cfg, x, emb)
 
@@ -316,8 +457,8 @@ def param_specs(params) -> dict:
             return P("ep", "fsdp", "tp")  # leading [E] axis over ep
         if moe and last == "w_down":
             return P("ep", "tp", "fsdp")
-        if name in ("wq", "wk", "wv"):
-            return P("fsdp", "tp", None)  # heads over tp
+        if name in ("wq", "wk", "wv", "wg"):
+            return P("fsdp", "tp", None)  # heads over tp (wg: the gate's)
         if name == "wo":
             return P("tp", None, "fsdp")
         if name in ("w_gate", "w_up"):

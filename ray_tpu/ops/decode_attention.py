@@ -62,13 +62,15 @@ def kv_prefix_rows(kv_bound: int, max_seq: int) -> int:
 
 def over_kv_prefix(attend, leaves, kv_bound):
     """`attend(*leaves)` over the shortest of `kv_prefixes` that holds
-    `kv_bound` rows of the cache leaves `[B, max_seq, ...]`. `kv_bound` is a
-    traced int32 scalar: no row at or beyond it is visible to a sequence
-    whose output is used. The rows left out are rows whose softmax weight
-    `attend`'s own mask makes exactly zero, so the result is the whole
-    cache's. The prefix is static in each branch of one `lax.switch`, which
-    takes the leaves as operands: a branch reads a slice of the cache where
-    it lies, and the step has no loop in it."""
+    `kv_bound` rows of the cache leaves `[B, rows, ...]`: `max_seq` rows
+    of a full layer, or the ring of a window layer, whose last prefix, the
+    whole ring, holds any bound beyond it. `kv_bound` is a traced int32
+    scalar: no row at or beyond it is visible to a sequence whose output is
+    used. The rows left out are rows whose softmax weight `attend`'s own
+    mask makes exactly zero, so the result is the whole leaf's. The prefix
+    is static in each branch of one `lax.switch`, which takes the leaves as
+    operands: a branch reads a slice of the cache where it lies, and the
+    step has no loop in it."""
     ends = kv_prefixes(leaves[0].shape[1])
     index = jnp.clip((kv_bound - 1) // ends[0], 0, len(ends) - 1)
     return jax.lax.switch(
@@ -77,30 +79,72 @@ def over_kv_prefix(attend, leaves, kv_bound):
         *leaves)
 
 
+def _grouped_attention(q, k, v, visible):
+    """Query heads that share key/value heads: q [B, H, D]; k, v
+    [B, T, KV, D] as they lie in the cache; visible [B, T]. The rows of all
+    KV heads are ONE matrix `[T x KV, D]` a slot (merging two adjacent axes
+    of a leaf moves nothing), every query head is multiplied against all of
+    it, and the mask keeps, for each query head, the rows of its own
+    key/value head: KV times the products a grouped form would make, on a
+    unit that has them to spare in a decode step, against a copy of the
+    prefix into a heads-major layout in every step, which is what the
+    v5e's compiler makes of `bngd,btnd->bngt` inside a `conditional`
+    (PERF.md section 6, PR 32). K and V are read once, where they lie; no
+    `jnp.repeat`. bf16 operands, f32 sums."""
+    b, hq, d = q.shape
+    t, kv = k.shape[1], k.shape[2]
+    k, v = k.reshape(b, t * kv, d), v.reshape(b, t * kv, d)
+    scores = jnp.einsum("bhd,bsd->bhs", q, k,
+                        preferred_element_type=jnp.float32) / (d ** 0.5)
+    # row s of the merged matrix is position s // KV of key/value head
+    # s % KV; query head h reads key/value head h // (H / KV)
+    own = (jnp.arange(t * kv)[None, :] % kv
+           == jnp.arange(hq)[:, None] // (hq // kv))
+    seen = jnp.repeat(visible, kv, axis=1)[:, None, :] & own[None]
+    probs = jax.nn.softmax(jnp.where(seen, scores, NEG_INF), axis=-1)
+    out = jnp.einsum("bhs,bsd->bhd", probs.astype(v.dtype), v,
+                     preferred_element_type=jnp.float32)
+    return out.astype(q.dtype)
+
+
+@jax.jit
+def grouped_walk(q, k_cache, v_cache, lengths, kv_bound):
+    """The bounded walk of heads that share key/value heads (fewer of those
+    than query heads). Jitted, so that the layers of a kind share one trace
+    and one function of the lowered module (a model with window layers has
+    two: its full leaves and its rings). No loop."""
+    d = q.shape[-1]
+    visible = jnp.arange(k_cache.shape[1])[None, :] < lengths[:, None]
+    return over_kv_prefix(
+        lambda k, v: _grouped_attention(q, k[..., :d], v[..., :d],
+                                        visible[:, :k.shape[1]]),
+        (k_cache, v_cache), kv_bound)
+
+
 @jax.jit
 def _xla_decode_walk(q, k_cache, v_cache, lengths, kv_bound):
     """`_xla_decode_attention` over the prefix `kv_bound` picks, taking the
     cache leaves whole: a row wider than q's head
     (`TransformerConfig.cache_row`) is cut with the prefix, inside the
-    branch. Jitted, so that the layers of a model share one trace and one
-    function of the lowered module. Two things differ from the whole walk's
-    text, neither in what is computed. The scores' product is written out
-    as what it is, one query row a head times the keys, multiplied and
-    summed in f32: outside a `conditional` the TPU's compiler makes
-    exactly that of the einsum (the `multiply_reduce_fusion` of a chunk's
-    trace) and reads the cache where it lies, inside one it keeps the
-    einsum a convolution and feeds it a transposed copy of the prefix
-    (PERF.md section 6, PR 29). And what no branch needs its own copy of,
-    the query in f32 and the mask, is made once outside them."""
+    branch. Jitted, so that the layers of a model that share a leaf's shape
+    share one trace and one function of the lowered module (a model with
+    window layers has two: its full leaves and its rings). Two things
+    differ from the whole walk's text, neither in what is computed. The
+    scores' product of a head with keys of its own is written out as what
+    it is, one query row a head times the keys, multiplied and summed in
+    f32: outside a `conditional` the TPU's compiler makes exactly that of
+    the einsum (the `multiply_reduce_fusion` of a chunk's trace) and reads
+    the cache where it lies, inside one it keeps the einsum a convolution
+    and feeds it a transposed copy of the prefix (PERF.md section 6, PR
+    29). And what no branch needs its own copy of, the query in f32 and the
+    mask, is made once outside them. (Heads that share key/value heads
+    take `grouped_walk`.)"""
     _, hq, d = q.shape
     q32 = q.astype(jnp.float32)
     visible = jnp.arange(k_cache.shape[1])[None, :] < lengths[:, None]
 
     def attend(k, v):
         k, v = k[..., :d], v[..., :d]
-        if k.shape[2] < hq:
-            k = jnp.repeat(k, hq // k.shape[2], axis=2)
-            v = jnp.repeat(v, hq // v.shape[2], axis=2)
         scores = jnp.swapaxes(
             jnp.sum(q32[:, None] * k.astype(jnp.float32), axis=-1), 1, 2)
         scores = jnp.where(visible[:, None, :k.shape[1]],
@@ -113,7 +157,8 @@ def _xla_decode_walk(q, k_cache, v_cache, lengths, kv_bound):
 
 
 def decode_attention(q, k_cache, v_cache, lengths, *, kv_bound=None):
-    """One new token a sequence against its cache rows `[0, lengths[b])`.
+    """One new token a sequence against its cache rows `[0, lengths[b])`
+    (all of a ring's rows once `lengths[b]` has passed its length).
     q: [B, H, D]; caches [B, S, KV, D]; lengths [B] -> [B, H, D].
 
     A `kv_bound` (`over_kv_prefix`: the longest live sequence's rows, from
@@ -126,4 +171,6 @@ def decode_attention(q, k_cache, v_cache, lengths, *, kv_bound=None):
     with jax.named_scope("decode_attention"):
         if kv_bound is None:
             return _xla_decode_attention(q, k_cache, v_cache, lengths)
+        if k_cache.shape[2] < q.shape[1]:
+            return grouped_walk(q, k_cache, v_cache, lengths, kv_bound)
         return _xla_decode_walk(q, k_cache, v_cache, lengths, kv_bound)

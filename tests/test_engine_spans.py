@@ -382,6 +382,42 @@ def test_the_latent_and_expert_parts_carry_their_names_in_the_program():
         eng.shutdown()
 
 
+def test_the_window_gate_and_norm_parts_carry_their_names_in_the_program():
+    """The scopes a model with window and full layers, query/key norms and
+    a gated attention adds (`model_type` `afmoe`), in its decode step and in
+    its prefill; the walk's branches sit inside each kind of layer's."""
+    import jax.numpy as jnp
+
+    arch = {"model_type": "afmoe", "num_key_value_heads": 2, "head_dim": 16,
+            "layer_types": ["sliding_attention", "full_attention"],
+            "sliding_window": 16, "num_dense_layers": 1, "num_experts": 8,
+            "num_experts_per_tok": 2, "num_shared_experts": 1,
+            "moe_intermediate_size": 32, "intermediate_size": 96,
+            "score_func": "sigmoid", "route_norm": True, "route_scale": 2.5,
+            "mup_enabled": True, "rope_theta": 10000, "rms_norm_eps": 1e-5,
+            "rope_scaling": None, "tie_word_embeddings": False}
+    eng = ContinuousEngine(LLMConfig(**CFG, arch=arch, experts_held=4),
+                           max_batch=2, decode_chunk=4)
+    try:
+        eng._cache = eng._init_cache()
+        chunk = eng._chunk.lower(
+            eng.params, eng._cache, eng._toks_dev, eng._lens_dev, eng._keys,
+            eng._temps_dev, eng._topks_dev, eng._topps_dev, 2, False,
+            jnp.int32(9))
+        new = {"full_attention", "window_attention", "attn_gate", "qk_norm"}
+        assert new | {"decode_attention", "moe_router", "moe_experts",
+                      "shared_expert", "mlp", "lm_head",
+                      "sampler"} <= scopes_of(chunk)
+        assert in_branches(chunk, "window_attention", "decode_attention")
+        assert in_branches(chunk, "full_attention", "decode_attention")
+        prefill = eng._prefill.lower(
+            eng.params, jnp.zeros((1, 32), jnp.int32), 20)
+        assert new | {"prefill_attention", "mlp",
+                      "lm_head"} <= scopes_of(prefill)
+    finally:
+        eng.shutdown()
+
+
 def test_a_capture_keeps_the_python_tracer_off_and_the_host_tracer_on(
         monkeypatch):
     import jax

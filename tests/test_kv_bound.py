@@ -158,7 +158,8 @@ def test_without_a_bound_the_step_lowers_to_the_program_it_always_was(name):
     # and with one, the layers' attention is ONE function of the module,
     # a switch over the prefixes, called once a layer
     bounded = step_text(cfg, bounded=True)
-    walk = "_latent_walk" if cfg.attention == "mla" else "_xla_decode_walk"
+    walk = ("_latent_walk" if cfg.attention == "mla" else "grouped_walk"
+            if cfg.n_kv_heads < cfg.n_heads else "_xla_decode_walk")
     assert bounded.count("stablehlo.case") == 1
     assert bounded.count(f"call @{walk}(") == cfg.n_layers
 

@@ -227,8 +227,13 @@ def test_cache_stats_name_the_layout_and_count_boundary_copies(engine):
     assert set(st) == {"cache_layout", "cache_boundary_copies",
                        "cache_kind", "cache_bytes", "kv_walk_share",
                        "kv_live_share", "splices", "splices_in_flight",
-                       "pipeline_dry"}
+                       "pipeline_dry", "cache_kinds", "kv_heads"}
     assert 0.0 <= st["kv_live_share"] <= st["kv_walk_share"] <= 1.0
+    # one kind of leaf: every layer keeps max_seq rows a slot
+    assert st["kv_heads"] == CFG.n_heads and st["cache_kinds"] == {"full": {
+        "layers": CFG.n_layers, "rows": CFG.max_seq,
+        "bytes": st["cache_bytes"], "walk_share": st["kv_walk_share"],
+        "live_share": st["kv_live_share"]}}
     assert st["cache_kind"] == "kv"
     assert st["cache_bytes"] == (CFG.n_layers * 2 * 4 * CFG.max_seq
                                  * CFG.d_model * 4)
@@ -443,7 +448,14 @@ def alone(eng, prompt, **sampling) -> list:
 
 def parked(eng, monkeypatch, requests) -> list:
     """Submit `requests` [(prompt, SamplingParams)] while no row is given
-    out, wait until every prefill is parked in `_ready`, then let go."""
+    out, wait until every prefill is parked in `_ready`, then let go. The
+    engine is at rest first: an earlier request's stream ends where its
+    last token is read, one pass before the scheduler has read the step
+    dispatched beyond it, and a hand-over behind THAT chunk is none the
+    test arranged (on a loaded host the scheduler's thread can stand still
+    that long)."""
+    until(lambda: not (eng._q_chunks or eng._pending_firsts
+                       or eng.num_active), "the engine to come to rest")
     with monkeypatch.context() as full:
         full.setattr(eng, "_free_slot", lambda: None)
         streams = [eng.submit(p, sp) for p, sp in requests]

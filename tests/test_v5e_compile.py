@@ -151,7 +151,113 @@ def test_v5e_latent_cache_rows_are_widened_to_their_tiles(chip):
     assert block.shape == (MAX_BATCH, 4 + 1)
 
 
-@pytest.mark.parametrize("name", ["kv", "latent"])
+#: Trinity-Mini's attention and expert layers at the PUBLISHED widths (hidden
+#: 2048, 32 query heads on 4 key/value heads of 128, window 2048, 16 of 128
+#: experts of 1024 held, dense layers of 6144, 8192 positions a slot) and one
+#: period of its depth: layers 0-3, two dense and two expert, three window
+#: layers and a full one. (All 32 layers compile in 38 s and a minute for the
+#: 8192-row prefill: PERF.md section 4.)
+SWA = LLMConfig(
+    vocab_size=25024, d_model=2048, n_layers=4, n_heads=32, max_seq=8192,
+    dtype="bfloat16", experts_held=16,
+    arch={"model_type": "afmoe", "num_key_value_heads": 4, "head_dim": 128,
+          "layer_types": ["sliding_attention"] * 3 + ["full_attention"],
+          "sliding_window": 2048, "num_dense_layers": 2, "num_experts": 128,
+          "num_experts_per_tok": 8, "num_shared_experts": 1,
+          "moe_intermediate_size": 1024, "intermediate_size": 6144,
+          "score_func": "sigmoid", "route_norm": True, "route_scale": 2.826,
+          "mup_enabled": True, "rope_theta": 10000, "rms_norm_eps": 1e-5,
+          "rope_scaling": None, "tie_word_embeddings": False})
+
+
+def test_v5e_window_and_full_leaves_of_four_heads_cross_without_a_copy(chip):
+    """A leaf of FOUR key/value heads of 128: its default layout on the
+    v5e is row-major in tiles of (4, 128), no padding (tiles of 16 heads
+    would hold four times the bytes), and it is the decode loop's own: the
+    chunk program converts nothing at its boundary, for the rings and the
+    full leaves alike. A prefill of 8192 rows hands on 8192 rows of a full
+    leaf and the whole ring of a window leaf; placing them copies no leaf;
+    each program fits the chip beside what is resident."""
+    eng = build_compiled(chip, cfg=SWA)
+    assert eng.model.cfg.cache_row == 0  # a head of 128 fills its lanes
+    st = eng.cache_stats()
+    assert st["cache_boundary_copies"] == 0
+    row = 4 * 128 * 2  # one position's K or V of a layer, bf16
+    assert st["cache_kinds"]["full"]["bytes"] == 2 * MAX_BATCH * 8192 * row
+    assert st["cache_kinds"]["window"]["bytes"] == (3 * 2 * MAX_BATCH * 2048
+                                                    * row)
+    layouts = st["cache_layout"].split("; ")
+    assert [lay.split(" Layout(")[0] for lay in layouts] == [
+        f"bfloat16[{MAX_BATCH}, 2048, 4, 128]",
+        f"bfloat16[{MAX_BATCH}, 8192, 4, 128]"]
+    assert all("major_to_minor=(0, 1, 2, 3), tiling=((4, 128), (2, 1))" in lay
+               for lay in layouts)
+    on_chip = lambda *dims: jax.ShapeDtypeStruct(  # noqa: E731
+        dims, jnp.int32, sharding=SingleDeviceSharding(chip))
+    prefill = eng._prefill.lower(eng.params, on_chip(1, 8192),
+                                 on_chip()).compile()
+    mem = prefill.memory_analysis()
+    resident = mem.argument_size_in_bytes  # the parameters
+    assert mem.temp_size_in_bytes + mem.output_size_in_bytes < 2.5e9
+    one = jax.eval_shape(eng._prefill, eng.params,
+                         jax.ShapeDtypeStruct((1, 8192), jnp.int32), 5)[1]
+    assert sorted({leaf.shape for leaf in jax.tree.leaves(one)}) == [
+        (1, 2048, 4, 128), (1, 8192, 4, 128)]
+    row_of = lambda dtype, *dims: jax.ShapeDtypeStruct(  # noqa: E731
+        (MAX_BATCH, *dims), dtype)
+    mirrors = (row_of(jnp.int32), row_of(jnp.int32), row_of(jnp.uint32, 2),
+               row_of(jnp.float32), row_of(jnp.int32), row_of(jnp.float32))
+    placed = eng._place.lower(
+        eng._cache_spec, one, mirrors, jax.ShapeDtypeStruct((), jnp.int32),
+        jax.ShapeDtypeStruct((2,), jnp.uint32),
+        jax.ShapeDtypeStruct((3,), jnp.int32),
+        jax.ShapeDtypeStruct((2,), jnp.float32)).compile()
+    assert not re.findall(r"= \w+\[%d,\d+,4,128\]\S* copy\(" % MAX_BATCH,
+                          placed.as_text())
+    assert placed.memory_analysis().temp_size_in_bytes < 1e6
+    chunk = eng._chunk.lower(*eng._chunk_shapes(
+        eng.params, eng._cache_spec, False)).compile().memory_analysis()
+    assert abs(chunk.argument_size_in_bytes
+               - (resident + st["cache_bytes"])) < 1e6
+    assert chunk.temp_size_in_bytes < 1e9
+
+
+def test_v5e_prefill_of_6144_rows_goes_in_its_neighbours_tiles(chip):
+    """The bucket between 4096 and 8192 (a prompt of 5,000 is padded to it):
+    its window layers go in the tiles of 512 queries against 2,560 keys that
+    both neighbours use, its full layers in tiles of 256; it fits beside
+    what is resident, hands on 6144 rows of a full leaf and the whole ring,
+    and placing them copies no leaf."""
+    eng = build_compiled(chip, cfg=SWA)
+    assert [eng._bucket(n) for n in (4096, 5000, 6144, 6145)] == [
+        4096, 6144, 6144, 8192]
+    on_chip = lambda *dims: jax.ShapeDtypeStruct(  # noqa: E731
+        dims, jnp.int32, sharding=SingleDeviceSharding(chip))
+    prefill = eng._prefill.lower(eng.params, on_chip(1, 6144),
+                                 on_chip()).compile()
+    text = prefill.as_text()
+    assert "f32[1,4,8,512,2560]" in text and "f32[1,4,8,256,6400]" in text
+    mem = prefill.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.output_size_in_bytes < 2.5e9
+    one = jax.eval_shape(eng._prefill, eng.params,
+                         jax.ShapeDtypeStruct((1, 6144), jnp.int32), 5)[1]
+    assert sorted({leaf.shape for leaf in jax.tree.leaves(one)}) == [
+        (1, 2048, 4, 128), (1, 6144, 4, 128)]
+    row_of = lambda dtype, *dims: jax.ShapeDtypeStruct(  # noqa: E731
+        (MAX_BATCH, *dims), dtype)
+    mirrors = (row_of(jnp.int32), row_of(jnp.int32), row_of(jnp.uint32, 2),
+               row_of(jnp.float32), row_of(jnp.int32), row_of(jnp.float32))
+    placed = eng._place.lower(
+        eng._cache_spec, one, mirrors, jax.ShapeDtypeStruct((), jnp.int32),
+        jax.ShapeDtypeStruct((2,), jnp.uint32),
+        jax.ShapeDtypeStruct((3,), jnp.int32),
+        jax.ShapeDtypeStruct((2,), jnp.float32)).compile()
+    assert not re.findall(r"= \w+\[%d,\d+,4,128\]\S* copy\(" % MAX_BATCH,
+                          placed.as_text())
+    assert placed.memory_analysis().temp_size_in_bytes < 1e6
+
+
+@pytest.mark.parametrize("name", ["kv", "latent", "swa"])
 def test_v5e_bounded_chunk_program_branches_once_a_layer_and_copies_no_rows(
         chip, name):
     """The chunk program the scheduler dispatches (with a `kv_bound`): one
@@ -164,7 +270,7 @@ def test_v5e_bounded_chunk_program_branches_once_a_layer_and_copies_no_rows(
     program has no `conditional`, as before there was one."""
     from ray_tpu.ops.decode_attention import kv_prefixes
 
-    cfg = {"kv": CFG, "latent": LATENT}[name]
+    cfg = {"kv": CFG, "latent": LATENT, "swa": SWA}[name]
     eng = build_compiled(chip, cfg=cfg)
     assert eng.cache_boundary_copies == 0  # whole leaves, branches included
     shapes = eng._chunk_shapes(eng.params, eng._cache_spec, False)
@@ -172,8 +278,8 @@ def test_v5e_bounded_chunk_program_branches_once_a_layer_and_copies_no_rows(
     text = eng._chunk.lower(*shapes).compile().as_text()
     assert len(re.findall(r" conditional\(", text)) == cfg.n_layers
     for leaf in jax.tree.leaves(eng._cache_spec):
-        slots, _rows, *rest = leaf.shape
-        for rows in kv_prefixes(cfg.max_seq):
+        slots, leaf_rows, *rest = leaf.shape  # max_seq, or a ring's
+        for rows in kv_prefixes(leaf_rows):
             dims = ",".join([str(slots), str(rows)] + [r"\d+"] * len(rest))
             assert not re.findall(r"= \w+\[%s\]\S* copy\(" % dims, text), (
                 leaf.shape, rows)
