@@ -36,7 +36,9 @@ T_START = time.monotonic()
 #: The whole run, compilation included, has to end well inside 1200 s.
 DEADLINE = T_START + 1080.0
 
-MODEL = dict(vocab_size=32000, d_model=1024, n_layers=8, n_heads=16,
+#: Heads of 128: a head the flash kernel takes (a whole lane tile), so the
+#: served prefill goes through it on the chip (ops/attention.py).
+MODEL = dict(vocab_size=32000, d_model=1024, n_layers=8, n_heads=8,
              max_seq=1024, dtype="bfloat16")
 MAX_BATCH = 8
 DECODE_CHUNK = 16
@@ -206,11 +208,14 @@ class ChipProbe:
         return rows
 
     def kernels(self, model: dict) -> dict:
-        """Compiles the Pallas flash-attention kernel (training and long
-        prefill; decode attention has no kernel of its own) with Mosaic
-        (interpret=False) and compares it with its XLA reference. An
-        exception from the kernel is reported in its row, and the smoke
-        fails on it."""
+        """Compiles the Pallas flash-attention kernel (the prefill's
+        attention and a forward pass without a gradient; decode attention
+        has no kernel of its own) with Mosaic (interpret=False) and
+        compares it with its XLA form, `prefill_attention`: at this model's
+        prefill buckets, at the bench shape, and at Trinity-Mini's (32
+        heads on 4, a window of 2048 and none, a prompt that ends inside
+        its bucket). An exception from the kernel is reported in its row,
+        and the smoke fails on it."""
         import time
         import traceback
 
@@ -218,25 +223,24 @@ class ChipProbe:
         import jax.numpy as jnp
         import numpy as np
 
-        from ray_tpu.ops.attention import _xla_attention
-        from ray_tpu.ops.flash_attention import (
-            derive_blocks, flash_attention, unsupported_reason)
+        from ray_tpu.ops.attention import kernel_refusal, prefill_attention
+        from ray_tpu.ops.flash_attention import flash_attention
 
         dev = jax.devices()[0]
         heads = model["n_heads"]
         hd = model["d_model"] // heads
         rows = []
 
-        def compare(name, kernel, ref):
+        def compare(name, kernel, ref, upto):
             row = {"kernel": name}
             try:
                 t0 = time.monotonic()
                 got = np.asarray(jax.block_until_ready(kernel()), np.float32)
                 row["compile_and_run_s"] = round(time.monotonic() - t0, 2)
                 want = np.asarray(ref(), np.float32)
-                err = np.abs(got - want)
+                err = np.abs(got - want)[:, :upto]
                 # bf16 keeps 8 bits: an output near 4 is rounded by ~0.016.
-                tol = 2e-2 + 2e-2 * np.abs(want)
+                tol = 2e-2 + 2e-2 * np.abs(want)[:, :upto]
                 row.update(max_abs_err=float(err.max()),
                            ok=bool(np.isfinite(got).all()
                                    and (err <= tol).all()))
@@ -244,24 +248,27 @@ class ChipProbe:
                 row.update(ok=False, error=traceback.format_exc(limit=6))
             rows.append(row)
 
-        def qkv(b, s, h, d, seed):
-            ks = jax.random.split(jax.random.PRNGKey(seed), 3)
-            return [jax.random.normal(k, (b, s, h, d), jnp.bfloat16)
-                    for k in ks]
-
-        # Prefill shapes of this model, then the bench shape.
-        for b, s, h, d in [(1, 128, heads, hd), (1, 256, heads, hd),
-                           (1, 1024, heads, hd), (4, 2048, 8, 128)]:
-            q, k, v = qkv(b, s, h, d, s)
-            compare(f"flash_attention b{b} s{s} h{h} d{d} "
-                    f"blocks{derive_blocks(s, s)}",
+        # Prefill buckets of this model, the bench shape, Trinity-Mini's.
+        for b, s, h, kv, d, window, plen in [
+                (1, 128, heads, heads, hd, 0, 100),
+                (1, 256, heads, heads, hd, 0, 200),
+                (1, 1024, heads, heads, hd, 0, 1024),
+                (4, 2048, 8, 8, 128, 0, 2048),
+                (1, 4096, 32, 4, 128, 2048, 3282),
+                (1, 4096, 32, 4, 128, 0, 3282)]:
+            ks = jax.random.split(jax.random.PRNGKey(s), 3)
+            q, k, v = (jax.random.normal(key, (b, s, n, d), jnp.bfloat16)
+                       for key, n in zip(ks, (h, kv, kv)))
+            q_len = jnp.full((b,), plen, jnp.int32)
+            compare(f"flash_attention b{b} s{s} h{h} on {kv} d{d} "
+                    f"window {window} prompt {plen}",
                     lambda: flash_attention(q, k, v, causal=True,
-                                            interpret=False),
-                    lambda: _xla_attention(q, k, v, causal=True))
+                                            window=window, q_len=q_len),
+                    lambda: prefill_attention(q, k, v, window), plen)
 
         return {"platform": dev.platform, "device_kind": dev.device_kind,
                 "rows": rows,
-                "prefill_flash_unsupported_reason": unsupported_reason(
+                "prefill_kernel_refusal": kernel_refusal(
                     (1, 128, heads, hd), (1, 128, heads, hd))}
 
 
@@ -355,7 +362,15 @@ def serve_phase(serve, LLMConfig, build_openai_app, chips: int) -> dict:
             f"(live rows {st['kv_live_share']:.3f}); {st['splices']} "
             f"hand-overs of a batch row, {st['splices_in_flight']} behind "
             f"a chunk in flight, {st['pipeline_dry']} passes began with "
-            f"nothing in flight")
+            f"nothing in flight; {st['prefill_rows']} rows of prefill "
+            f"buckets, {st['prefill_rows_kernel']} of them through the "
+            f"flash kernel")
+        # Heads of 128 and buckets of at least one lane tile: on the chip
+        # the dispatcher's rule gives every prefill of MODEL the kernel.
+        require(st["prefill_rows_kernel"] == st["prefill_rows"] > 0,
+                f"replica {st['pid']}: {st['prefill_rows_kernel']} of "
+                f"{st['prefill_rows']} prefill rows went through the flash "
+                f"kernel")
         # The cache must cross a program's boundary in the layout the
         # decode loop computes in: a copy of a whole leaf there is a
         # conversion paid by every chunk, whatever its length.
@@ -396,13 +411,13 @@ def chip_phase(ray_tpu, greedy: list) -> None:
                 f"({row['compile_and_run_s']}s)")
         else:
             say(f"  FAIL {row['kernel']}: {row.get('error') or row}")
-    say("served prefill: the cached dense einsum of "
-        "Attention._cached_attention (decode=True); "
-        "dot_product_attention is not on the serving path. At the same "
-        "shape without a cache it chooses "
+    say("attention by phase: a decode step reads the cache through "
+        "ops/decode_attention.py (plain JAX); a prefill goes through "
+        "dot_product_attention, which for this model's buckets chooses "
         + ("the Pallas flash kernel"
-           if rep["prefill_flash_unsupported_reason"] is None
-           else f"XLA ({rep['prefill_flash_unsupported_reason']})"))
+           if rep["prefill_kernel_refusal"] is None
+           else f"the XLA form ({rep['prefill_kernel_refusal']})")
+        + "; each replica's line above counts the rows either form served")
     require(rep["platform"] == "tpu", "chip actor did not run on a TPU")
     require(bool(ref), "no served greedy continuation to check")
     off = [r for r in ref

@@ -45,6 +45,7 @@ engine workers via vllm_models.py:123-137). TPU-native design:
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import itertools
 import logging
@@ -712,6 +713,12 @@ class ContinuousEngine:
         self.splices = 0
         self.splices_in_flight = 0
         self.pipeline_dry = 0
+        # Rows of the prefill buckets dispatched since start, and those of
+        # buckets whose program's attention is the flash kernel
+        # (`_prefill_form`).
+        self.prefill_rows = 0
+        self.prefill_rows_kernel = 0
+        self._prefill_form_of: dict = {}
 
         def make_chunk(model):
             held = model.cfg.held_experts
@@ -805,9 +812,11 @@ class ContinuousEngine:
             _ready holds what this returns, not max_seq rows a leaf."""
             lb = toks.shape[1]
             positions = jnp.arange(lb)[None]
-            logits, vars_out = model.apply(
-                {"params": params}, toks, positions=positions, decode=True,
-                prompt_len=jnp.reshape(plen, (1,)), mutable=["cache"])
+            with self._mesh_scope():
+                logits, vars_out = model.apply(
+                    {"params": params}, toks, positions=positions,
+                    decode=True, prompt_len=jnp.reshape(plen, (1,)),
+                    mutable=["cache"])
             last = jax.lax.dynamic_index_in_dim(
                 logits[0].astype(jnp.float32), plen - 1, 0, keepdims=False)
             return last, jax.tree.map(
@@ -975,7 +984,9 @@ class ContinuousEngine:
         hand-overs (`splices`), those whose program was dispatched behind
         at least one decode chunk in flight (`splices_in_flight`), and
         the scheduler's passes that began with occupants seated and no
-        chunk in flight (`pipeline_dry`)."""
+        chunk in flight (`pipeline_dry`); and the rows of the prefill
+        buckets dispatched (`prefill_rows`) beside those whose program's
+        attention is the flash kernel (`prefill_rows_kernel`)."""
         mcfg = self.model.cfg
         steps = max(1, self._kv_steps)
         kinds = {kind: {**k, "walk_share": self._kv_walked[kind]
@@ -996,7 +1007,9 @@ class ContinuousEngine:
                "kv_live_share": top["live_share"],
                "splices": self.splices,
                "splices_in_flight": self.splices_in_flight,
-               "pipeline_dry": self.pipeline_dry}
+               "pipeline_dry": self.pipeline_dry,
+               "prefill_rows": self.prefill_rows,
+               "prefill_rows_kernel": self.prefill_rows_kernel}
         if self._moe_held:
             out.update(experts_held=self._moe_held,
                        experts_published=mcfg.moe_experts,
@@ -1130,6 +1143,33 @@ class ContinuousEngine:
             b = b // 4 * 3
         return min(b, self.cfg.max_seq)
 
+    def _prefill_form(self, bucket: int) -> str:
+        """`kernel` where every layer's attention of this bucket's prefill
+        program is the flash kernel, else `xla`: the dispatcher's own rule
+        (`ops/attention.py` `kernel_refusal`) put to the shapes the program
+        was traced with. Nothing is read back from the device. Latent
+        attention has a prefill of its own (`models/mla.py`)."""
+        from ray_tpu.ops.attention import kernel_refusal
+
+        if bucket not in self._prefill_form_of:
+            mcfg = self.model.cfg
+            with self._mesh_scope():
+                kernel = mcfg.attention == "mha" and all(
+                    kernel_refusal(
+                        (1, bucket, mcfg.n_heads, mcfg.head_dim),
+                        (1, bucket, mcfg.n_kv_heads, mcfg.head_dim),
+                        window=window) is None
+                    for window in {mcfg.window_of(i)
+                                   for i in range(mcfg.n_layers)})
+            self._prefill_form_of[bucket] = "kernel" if kernel else "xla"
+        return self._prefill_form_of[bucket]
+
+    def _mesh_scope(self):
+        """The engine's mesh as the mesh in context, for what is traced
+        inside: the attention's dispatcher keeps its unpartitioned kernel
+        out of a program that GSPMD shards over `tp`."""
+        return contextlib.nullcontext() if self.mesh is None else self.mesh
+
     def _slice_bytes(self, bucket: int) -> int:
         """Bytes of the cache slices a prefill of this bucket hands on."""
         import jax
@@ -1177,9 +1217,14 @@ class ContinuousEngine:
         # device may run them later). The device's prefill time is
         # `jit_prefill` in a device trace. The benchmark's `admit_wait_ms`
         # reads this span's start.
+        form = self._prefill_form(lb)
+        self.prefill_rows += lb
+        if form == "kernel":
+            self.prefill_rows_kernel += lb
         _tracing.record_span_in(
             stream.trace, "engine.prefill", "engine", t_adm, time.time(),
-            {"prompt_len": plen, "bucket": lb, "what": "dispatch"})
+            {"prompt_len": plen, "bucket": lb, "what": "dispatch",
+             "attention": form})
         return first, cache_slice, self._jax.random.fold_in(key, 1)
 
     def _prefill_loop(self):

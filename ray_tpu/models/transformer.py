@@ -6,10 +6,11 @@ TPU-native design notes:
   MLP matmuls split over "tp", parameters additionally over "fsdp"
   (ZeRO-3 analogue), activations between blocks sequence-sharded over "sp";
   XLA/GSPMD inserts the all-gathers/reduce-scatters over ICI.
-- Attention goes through ray_tpu.ops.dot_product_attention: the Pallas flash
-  kernel on a TPU when no gradient is taken (the kernel has no VJP, so a
-  training step takes the XLA path), the XLA reference elsewhere. Serving
-  runs with decode=True and takes neither: `_cached_attention` below.
+- Attention over a call's own rows (a training batch, a serving prefill, of
+  full and of window layers) goes through ray_tpu.ops.dot_product_attention:
+  the Pallas flash kernel on a TPU when no gradient is taken (the kernel has
+  no VJP, so a training step takes the XLA form), the XLA form elsewhere. A
+  decode step reads the cache through ops/decode_attention.py.
 - The reference framework has no model zoo of its own — this fills the role
   its vLLM/torch delegation played (llm/_internal/serve/.../vllm_models.py
   TP/PP passthrough), natively.
@@ -26,9 +27,11 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ray_tpu.models.layers import RMSNorm, SwiGLU, YarnScaling, rope as _rope
-from ray_tpu.models.mla import MLA, SCORE_TILE_BYTES
+from ray_tpu.models.mla import MLA
 from ray_tpu.models.moe import MoE
 from ray_tpu.ops import dot_product_attention
+from ray_tpu.ops.attention import prefill_attention
+from ray_tpu.parallel.mesh import context_mesh_shape, spec_tree_like
 
 __all__ = ["Attention", "Block", "MLA", "MoE", "RMSNorm", "SwiGLU",
            "Transformer", "TransformerConfig", "YarnScaling", "loss_fn",
@@ -167,10 +170,9 @@ class Attention(nn.Module):
             if decode:
                 out = self._cached_attention(q, k, v, positions, kv_bound,
                                              prompt_len)
-            elif self.window:
-                out = prefill_attention(q, k, v, self.window)
             else:
-                out = dot_product_attention(q, k, v, causal=True)
+                out = dot_product_attention(q, k, v, causal=True,
+                                            window=self.window)
         if cfg.attn_gate:
             with jax.named_scope("attn_gate"):
                 out = out * jax.nn.sigmoid(dense((cfg.n_heads, hd), "wg")(x))
@@ -207,12 +209,13 @@ class Attention(nn.Module):
         program it always was.
 
         A multi-token step is a prefill from position 0: it attends over
-        its own rows in query tiles (`prefill_attention`) and only writes
-        the cache. Where the call is longer than a ring, the ring gets the
-        rows of the last `window` positions before `prompt_len` ([B],
-        traced; the call's length when None) at their ring places: a padded
-        position past the prompt would otherwise land on the row of a live
-        one."""
+        its own rows (`dot_product_attention`: the flash kernel on the chip,
+        which skips the query blocks past `prompt_len`, else query tiles in
+        XLA) and only writes the cache. Where the call is longer than a
+        ring, the ring gets the rows of the last `window` positions before
+        `prompt_len` ([B], traced; the call's length when None) at their
+        ring places: a padded position past the prompt would otherwise land
+        on the row of a live one."""
         cfg = self.cfg
         b, s = q.shape[0], q.shape[1]
         d = cfg.head_dim
@@ -230,7 +233,9 @@ class Attention(nn.Module):
             kc, vc = jnp.pad(k, tail), jnp.pad(v, tail)
         if s > 1:
             with jax.named_scope("prefill_attention"):
-                out = prefill_attention(q, k, v, self.window)
+                out = dot_product_attention(q, k, v, causal=True,
+                                            window=self.window,
+                                            q_len=prompt_len)
             if s > rows:  # a ring shorter than the call
                 plen = (jnp.full((b,), s, jnp.int32) if prompt_len is None
                         else prompt_len.astype(jnp.int32))
@@ -255,72 +260,6 @@ class Attention(nn.Module):
         out = decode_attention(q[:, 0], keys, vals, pos[:, 0] + 1,
                                kv_bound=kv_bound)
         return out[:, None].astype(cfg.dtype)
-
-
-def prefill_attention(q, k, v, window: int = 0):
-    """Causal attention of a call over ITS OWN rows, positions 0..S-1 in
-    order (a prefill, or a training batch of a model with window layers):
-    q [B, S, H, D], k and v [B, S, KV, D] -> [B, S, H, D]. Queries go in
-    tiles so that a tile's float32 scores stay small. The query heads that
-    share a key/value head are one matrix product against it: K and V are
-    never copied per query head.
-
-    Several tiles are ONE loop over a body of one shape (a loop in a
-    prefill is fine; a decode step has none): each tile reads the `band`
-    rows before it and its own, of a K and V padded in front by `band`
-    rows. In a window layer (key j visible to query i iff 0 <= i - j <
-    window) the band is the window, so a long bucket costs its band, not
-    its square. In a full layer the band is the whole call: half of what a
-    tile reads is masked, twice the products a tile that stopped at its own
-    end would make. Written out tile by tile at their own lengths, a prefill
-    of 8192 rows was an executable of 2,700 fusions, 55 MB in the compile
-    cache, whose serialisation held the interpreter's lock long enough for
-    serve's 5 s health check to lose the replica (PERF.md section 6, PR 32)."""
-    b, s, h, d = q.shape
-    kv = k.shape[2]
-    dtype = q.dtype
-    qg = q.reshape(b, s, kv, h // kv, d)
-    band = min(window, s) if window else s
-    fits = lambda t: (b * h * t * 4 * min(s, band + t)  # noqa: E731
-                      <= SCORE_TILE_BYTES)
-    # a call too long for one tile goes in tiles of a power of two that
-    # divide it (a bucket of 6144 in tiles of 512 or 256, like its neighbours)
-    tile = s if fits(s) else s & -s
-    while tile > 8 and not fits(tile):
-        tile //= 2
-
-    def attend(qt, kt, vt, i, j):
-        """One tile: queries at positions i [T] against keys at j [K]."""
-        scores = jnp.einsum("bsngd,btnd->bngst", qt, kt,
-                            preferred_element_type=jnp.float32) / (d ** 0.5)
-        back = i[:, None] - j[None, :]
-        visible = (back >= 0) & (j[None, :] >= 0)
-        if window:
-            visible = visible & (back < window)
-        probs = jax.nn.softmax(jnp.where(visible, scores, -jnp.inf), axis=-1)
-        return jnp.einsum("bngst,btnd->bsngd", probs.astype(dtype), vt)
-
-    if s == tile or s % tile:  # one tile, or a ragged call (no bucket)
-        outs = [attend(qg[:, at:at + tile], k[:, :at + tile], v[:, :at + tile],
-                       jnp.arange(at, min(at + tile, s)),
-                       jnp.arange(min(at + tile, s)))
-                for at in range(0, s, tile)]
-        out = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
-        return out.reshape(b, s, h, d).astype(dtype)
-    # keys [start - band, start + tile) of the padded K and V; the padding's
-    # positions are negative and never visible
-    front = ((0, 0), (band, 0), (0, 0), (0, 0))
-    kp, vp = jnp.pad(k, front), jnp.pad(v, front)
-
-    def one_tile(_, start):
-        cut = lambda t, n: jax.lax.dynamic_slice_in_dim(  # noqa: E731
-            t, start, n, axis=1)
-        return None, attend(cut(qg, tile), cut(kp, band + tile),
-                            cut(vp, band + tile), start + jnp.arange(tile),
-                            start - band + jnp.arange(band + tile))
-
-    _, outs = jax.lax.scan(one_tile, None, jnp.arange(0, s, tile))
-    return jnp.moveaxis(outs, 0, 1).reshape(b, s, h, d).astype(dtype)
 
 
 class Block(nn.Module):
@@ -405,26 +344,9 @@ def _seq_shard(x):
     Applied when the mesh in context has all three axes; with no mesh
     (single device) or a mesh without them (a tp-only serving mesh) there is
     nothing to constrain."""
-    if not {"dp", "fsdp", "sp"} <= set(_context_mesh_axes()):
+    if not {"dp", "fsdp", "sp"} <= set(context_mesh_shape()):
         return x
     return jax.lax.with_sharding_constraint(x, P(("dp", "fsdp"), "sp", None))
-
-
-def _context_mesh_axes() -> tuple[str, ...]:
-    """Axis names of the mesh in context, whichever way it was entered.
-
-    JAX 0.9.0 keeps two contexts that do not see each other:
-    `jax.set_mesh(mesh)` sets the abstract mesh, the older `with mesh:` sets
-    only the thread-local physical mesh, which has no public reader.
-    `with_sharding_constraint` honours a bare PartitionSpec under either, so
-    both are read here: a caller under `with mesh:` must not lose sequence
-    parallelism without an error."""
-    axes = jax.sharding.get_abstract_mesh().axis_names
-    if axes:
-        return axes
-    from jax._src.mesh import thread_resources
-
-    return thread_resources.env.physical_mesh.axis_names
 
 
 def param_specs(params) -> dict:
@@ -466,8 +388,6 @@ def param_specs(params) -> dict:
         if name == "w_down":
             return P("tp", "fsdp")
         return P()  # norms etc: replicated
-
-    from ray_tpu.parallel.mesh import spec_tree_like
 
     return spec_tree_like(params, rule)
 

@@ -81,6 +81,24 @@ def build_mesh(config: MeshConfig | None = None, devices=None) -> Mesh:
     return Mesh(dev_array, AXIS_ORDER)
 
 
+def context_mesh_shape() -> dict[str, int]:
+    """Axis sizes of the mesh in context, whichever way it was entered; {}
+    when there is none.
+
+    JAX 0.9.0 keeps two contexts that do not see each other:
+    `jax.set_mesh(mesh)` sets the abstract mesh, the older `with mesh:` sets
+    only the thread-local physical mesh, which has no public reader.
+    `with_sharding_constraint` honours a bare PartitionSpec under either, so
+    both are read here: a caller under `with mesh:` must not lose sequence
+    parallelism without an error."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.axis_names:
+        from jax._src.mesh import thread_resources
+
+        mesh = thread_resources.env.physical_mesh
+    return dict(mesh.shape)
+
+
 def local_mesh(n: int | None = None, axis: str = "dp") -> Mesh:
     """1-axis mesh over the first n local devices (tests, single-host)."""
     devices = jax.devices()[: n or len(jax.devices())]

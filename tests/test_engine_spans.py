@@ -95,8 +95,10 @@ def test_a_traced_request_has_each_stage_once_in_order(engine, spans):
         assert mine[-1]["b"] <= t_first
         assert stream._stage is None  # every stage was closed
         by_name = {s["n"]: s for s in mine}
+        # (on the CPU the prefill's attention is the XLA form)
         assert by_name["engine.prefill"]["at"] == {
-            "prompt_len": 4, "bucket": 8, "what": "dispatch"}
+            "prompt_len": 4, "bucket": 8, "what": "dispatch",
+            "attention": "xla"}
         assert set(by_name["engine.queue"]["at"]) == {"pending"}
         assert set(by_name["engine.first_token"]["at"]) == {
             "slot", "chunks_in_flight"}
@@ -179,6 +181,41 @@ def test_the_stats_count_how_rows_changed_hands(engine, spans):
     # pipeline has drained to there, and where a neighbour is seated the
     # next pass begins with nothing in flight.
     assert 1 <= st["pipeline_dry"] <= st["splices"]
+
+
+def test_the_stats_count_prefill_rows_and_which_attention_served_them(
+        engine, spans, monkeypatch):
+    """`prefill_rows` and `prefill_rows_kernel` of `cache_stats` (what
+    `/v1/stats` reports) beside the attribute `attention` of the span
+    `engine.prefill`: the rows of the buckets dispatched, and those whose
+    program's attention is the flash kernel by the dispatcher's own rule
+    for the bucket's shape. On the CPU that is none of them; where the rule
+    says the kernel (asked of it here, nothing is run), a bucket dispatched
+    from then on counts under it."""
+    from ray_tpu.ops import attention
+
+    keys = ("prefill_rows", "prefill_rows_kernel")
+    assert [engine.cache_stats()[k] for k in keys] == [0, 0]
+    serve(engine, 3)
+    prefills = [s for s in spans if s["n"] == "engine.prefill"]
+    assert [s["at"]["attention"] for s in prefills] == ["xla"] * 3
+    assert [engine.cache_stats()[k] for k in keys] == [
+        sum(s["at"]["bucket"] for s in prefills), 0] == [24, 0]
+    # the engine asks the rule once a bucket: a bucket it has not seen
+    asked = []
+    with monkeypatch.context() as patch:
+        patch.setattr(
+            attention, "kernel_refusal",
+            lambda q, k, **kw: asked.append((q, k, kw)))  # None: the kernel
+        assert engine._prefill_form(8) == "xla"  # remembered
+        assert engine._prefill_form(16) == "kernel"
+    assert asked == [((1, 16, 4, 16), (1, 16, 4, 16), {"window": 0})]
+    tracing._ctx.set(("e" * 32, "f" * 16))
+    engine.submit(list(range(1, 12)), SamplingParams(max_tokens=2)).tokens()
+    tracing._ctx.set(None)
+    last = [s for s in spans if s["n"] == "engine.prefill"][-1]["at"]
+    assert (last["bucket"], last["attention"]) == (16, "kernel")
+    assert [engine.cache_stats()[k] for k in keys] == [40, 16]
 
 
 def test_idle_time_is_carried_by_the_next_recorded_pass(engine, spans):

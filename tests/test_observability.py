@@ -103,14 +103,20 @@ def test_live_worker_stack_dump(ray_start_2cpu):
 
     a = Busy.remote()
     ref = a.spin.remote(8.0)
-    _t.sleep(1.0)  # ensure the call is executing
     w = ray_tpu._private.worker.global_worker()
     # resolve the actor's worker id via the controller
     info = w.io.run(w.controller.call(
         "get_actor_info", actor_id=a._actor_id, wait=True))
-    rep = w.io.run(w.controller.call(
-        "worker_stacks", worker_id=info["worker_id"], node_id=None),
-        timeout=15)
-    assert rep["found"], rep
+    # the call executes for 8 s; on a loaded host its worker may take more
+    # than a second to start it, so ask until the frame shows
+    give_up = _t.time() + 6.0
+    while True:
+        rep = w.io.run(w.controller.call(
+            "worker_stacks", worker_id=info["worker_id"], node_id=None),
+            timeout=15)
+        assert rep["found"], rep
+        if "spin" in rep["stacks"] or _t.time() > give_up:
+            break
+        _t.sleep(0.25)
     assert "spin" in rep["stacks"], rep["stacks"][:500]
     assert ray_tpu.get(ref, timeout=60) == "done"

@@ -117,6 +117,119 @@ def test_flash_attention_auto_blocks():
     assert float(jnp.max(jnp.abs(out - ref))) < 2e-5
 
 
+def _dense_float64(q, k, v, causal, window):
+    """Masked dense softmax attention in float64: query row i stands at key
+    position i + (Sk - Sq) and sees key j iff 0 <= that - j (< window)."""
+    q, k, v = (np.asarray(x, np.float64) for x in (q, k, v))
+    (_, sq, hq, d), (_, sk, hkv, _) = q.shape, k.shape
+    k, v = (np.repeat(x, hq // hkv, axis=2) for x in (k, v))
+    scores = np.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(d)
+    back = (np.arange(sq)[:, None] + sk - sq) - np.arange(sk)[None, :]
+    seen = np.ones_like(back, bool)
+    if causal:
+        seen = back >= 0
+    if window:
+        seen &= back < window
+    scores = np.where(seen, scores, -np.inf)
+    probs = np.exp(scores - scores.max(-1, keepdims=True))
+    probs /= probs.sum(-1, keepdims=True)
+    return np.einsum("bhqk,bkhd->bqhd", probs, v)
+
+
+@pytest.mark.parametrize(
+    "rows, heads, kv_heads, d, window, blocks, q_len", [
+        (256, 32, 4, 128, 0, (64, 128), None),  # Trinity's heads, full
+        (256, 32, 4, 128, 16, (64, 128), None),  # a window inside a block
+        (384, 8, 2, 128, 200, (64, 128), None),  # rows no multiple of it
+        (256, 8, 2, 128, 1000, (64, 128), None),  # longer than the call
+        (256, 8, 8, 128, 0, (128, 128), None),  # one head a key/value head
+        (256, 8, 8, 128, 48, (64, 128), None),
+        (256, 4, 4, 96, 0, (128, 128), None),  # heads of 96, interpreted
+        (512, 8, 1, 128, 130, (64, 128), [300, 70]),  # prompts end inside
+        (512, 16, 4, 128, 0, (128, 256), [512, 1]),  # a block, or at once
+        (640, 8, 2, 128, 256, (None, None), [401]),  # blocks of 320 x 640
+    ])
+def test_flash_kernel_is_the_masked_dense_softmax(rows, heads, kv_heads, d,
+                                                   window, blocks, q_len):
+    """The one kernel, interpreted on the CPU, against a dense float64
+    softmax under the mask `prefill_attention` applies: full causal and
+    windowed layers, query heads grouped on fewer key/value heads (never
+    repeated), windows inside a block, across blocks and longer than the
+    call, and prompts that end inside a block: rows before `q_len` are
+    right, query blocks wholly past it come back as zeros. (On the chip a
+    head of 96 is refused by `unsupported_reason`; interpreted, the kernel
+    takes it.)"""
+    rng = np.random.RandomState(rows + heads + window)
+    b = 1 if q_len is None else len(q_len)
+    q = jnp.asarray(rng.randn(b, rows, heads, d), jnp.float32)
+    k = jnp.asarray(rng.randn(b, rows, kv_heads, d), jnp.float32)
+    v = jnp.asarray(rng.randn(b, rows, kv_heads, d), jnp.float32)
+    got = np.array(flash_attention(
+        q, k, v, causal=True, window=window, block_q=blocks[0],
+        block_k=blocks[1], interpret=True,
+        q_len=None if q_len is None else jnp.asarray(q_len, jnp.int32)))
+    want = _dense_float64(q, k, v, True, window)
+    block_q = blocks[0] or 320
+    for i, n in enumerate(q_len or []):
+        assert not got[i, -(-n // block_q) * block_q:].any()
+        got[i, n:] = want[i, n:] = 0.0
+    assert np.abs(got - want).max() < 5e-6
+
+
+@pytest.mark.parametrize("q_shape, k_shape, kw, reason", [
+    # Trinity's prefill buckets, window and full layers: the kernel
+    ((1, 4096, 32, 128), (1, 4096, 4, 128), {"window": 2048}, None),
+    ((1, 6144, 32, 128), (1, 6144, 4, 128), {}, None),
+    ((1, 128, 32, 128), (1, 128, 4, 128), {"window": 2048}, None),
+    # Phi-3's heads of 96 are no whole lane tile; a bucket under one tile
+    ((1, 512, 32, 96), (1, 512, 32, 96), {}, "head size 96"),
+    ((1, 64, 32, 128), (1, 64, 4, 128), {}, "Sk=64 has no divisor"),
+    ((1, 100, 32, 128), (1, 256, 4, 128), {}, "Sq=100 has no divisor"),
+    ((1, 256, 6, 128), (1, 256, 4, 128), {}, "Hq=6 not a multiple"),
+    ((1, 256, 8, 128), (1, 128, 8, 128), {}, "Sq=256 > Sk=128"),
+    ((1, 256, 8, 128), (1, 256, 8, 128),
+     {"causal": False, "window": 8}, "window without causal"),
+])
+def test_the_dispatchers_rule_for_a_shape(q_shape, k_shape, kw, reason):
+    """`kernel_refusal` is the one rule: here, on the CPU, nobody asks for
+    the kernel; asked for it, the answer is the kernel's own
+    `unsupported_reason`, and `dot_product_attention` follows it to the XLA
+    form without touching the kernel (which could not be lowered here)."""
+    from ray_tpu.ops.attention import (NOT_ASKED, dot_product_attention,
+                                       kernel_refusal)
+
+    assert kernel_refusal(q_shape, k_shape, **kw) == NOT_ASKED
+    got = kernel_refusal(q_shape, k_shape, use_pallas=True, **kw)
+    assert (got is None) if reason is None else (reason in got), got
+    if reason and ("has no divisor" in reason or "head size" in reason):
+        small = lambda shape: jnp.ones(  # noqa: E731 - 2 heads' worth
+            (1, shape[1], shape[2], shape[3]), jnp.float32)
+        out = dot_product_attention(small(q_shape), small(k_shape),
+                                    small(k_shape), use_pallas=True, **kw)
+        assert out.shape == q_shape
+
+
+@pytest.mark.parametrize("window", [0, 24])
+def test_on_the_cpu_the_door_leads_to_the_xla_form(window):
+    """Causal attention of a call over its own rows, with a window or
+    without: off the chip `dot_product_attention` IS `prefill_attention`,
+    whatever `q_len` says."""
+    from ray_tpu.ops.attention import dot_product_attention, prefill_attention
+
+    rng = np.random.RandomState(window)
+    q = jnp.asarray(rng.randn(2, 128, 8, 128), jnp.float32)
+    k, v = (jnp.asarray(rng.randn(2, 128, 2, 128), jnp.float32)
+            for _ in range(2))
+    got = dot_product_attention(q, k, v, causal=True, window=window,
+                                q_len=jnp.asarray([100, 7], jnp.int32))
+    want = prefill_attention(q, k, v, window)
+    assert float(jnp.max(jnp.abs(got - want))) == 0.0
+    np.testing.assert_allclose(
+        np.asarray(want), _dense_float64(q, k, v, True, window), atol=5e-6)
+    with pytest.raises(ValueError, match="own rows"):
+        dot_product_attention(q, k[:, :64], v[:, :64], window=8)
+
+
 def test_attention_dispatch_states_its_rule_once(caplog):
     """The choice is made up front from the shapes (the kernel's own block
     derivation) and stated once per distinct reason at INFO; a shape the
@@ -127,8 +240,8 @@ def test_attention_dispatch_states_its_rule_once(caplog):
     from ray_tpu.ops.attention import dot_product_attention
 
     attn_mod._stated.clear()
-    q_bad_sq = jnp.ones((1, 100, 2, 64), jnp.float32)  # Sq not 8-alignable
-    q_small = jnp.ones((1, 64, 2, 64), jnp.float32)  # Sk < one lane tile
+    q_bad_sq = jnp.ones((1, 100, 2, 128), jnp.float32)  # Sq not 8-alignable
+    q_small = jnp.ones((1, 64, 2, 128), jnp.float32)  # Sk < one lane tile
 
     def stated():
         return [r.message for r in caplog.records if "XLA path" in r.message]
@@ -152,15 +265,16 @@ def test_attention_kernel_error_propagates():
     the caller's error, not a quiet switch to XLA."""
     from ray_tpu.ops.attention import dot_product_attention
 
-    q = jnp.ones((1, 256, 2, 64), jnp.float32)
+    q = jnp.ones((1, 256, 2, 128), jnp.float32)
     with pytest.raises(Exception, match="[Ii]nterpret mode"):
         dot_product_attention(q, q, q, use_pallas=True)
 
 
-def test_attention_gradient_takes_xla_path_by_rule(caplog):
+@pytest.mark.parametrize("window", [0, 40])
+def test_attention_gradient_takes_xla_path_by_rule(caplog, window):
     """The flash kernel has no VJP. Under differentiation the dispatcher
     takes the XLA path for forward and backward — by a rule it states, in
-    either order of jit and grad. On the CPU the kernel cannot be lowered
+    either order of jit and grad, for a window layer as for a full one. On the CPU the kernel cannot be lowered
     at all (test_attention_kernel_error_propagates), so a gradient that
     comes out right here never ran it."""
     import logging
@@ -170,16 +284,23 @@ def test_attention_gradient_takes_xla_path_by_rule(caplog):
 
     attn_mod._stated.clear()
     rng = np.random.RandomState(4)
-    q, k, v = (jnp.asarray(rng.randn(1, 128, 2, 32), jnp.float32)
+    q, k, v = (jnp.asarray(rng.randn(1, 128, 2, 128), jnp.float32)
                for _ in range(3))
 
     def loss(fn):
         return lambda q, k, v: jnp.sum(fn(q, k, v) ** 2)
 
     dispatched = loss(lambda q, k, v: dot_product_attention(
-        q, k, v, causal=True, use_pallas=True))
-    want = jax.grad(loss(lambda q, k, v: _xla_attention(
-        q, k, v, causal=True)), argnums=(0, 1, 2))(q, k, v)
+        q, k, v, causal=True, window=window, use_pallas=True,
+        q_len=jnp.asarray([100], jnp.int32)))
+    from ray_tpu.ops.attention import prefill_attention
+    want = jax.grad(loss(lambda q, k, v: prefill_attention(
+        q, k, v, window)), argnums=(0, 1, 2))(q, k, v)
+    if not window:
+        for w, x in zip(want, jax.grad(loss(lambda q, k, v: _xla_attention(
+                q, k, v, causal=True)), argnums=(0, 1, 2))(q, k, v)):
+            np.testing.assert_allclose(np.asarray(w), np.asarray(x),
+                                       atol=1e-5)
     with caplog.at_level(logging.INFO, logger="ray_tpu.ops.attention"):
         got = jax.jit(jax.grad(dispatched, argnums=(0, 1, 2)))(q, k, v)
         got2 = jax.grad(jax.jit(dispatched), argnums=(0, 1, 2))(q, k, v)

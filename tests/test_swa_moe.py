@@ -258,9 +258,9 @@ def test_tiled_prefill_attention_is_the_masked_dense_one(window, rows,
     band for a window: against the full [S, S] mask, with tiles so small
     that a band starts inside the sequence. A call of three quarters of a
     power of two (the bucket of 6144) goes in tiles of a power of two."""
-    from ray_tpu.models import transformer
+    from ray_tpu.ops import attention
 
-    monkeypatch.setattr(transformer, "SCORE_TILE_BYTES", 4 * 8 * 64 * 4)
+    monkeypatch.setattr(attention, "SCORE_TILE_BYTES", 4 * 8 * 64 * 4)
     keys = jax.random.split(jax.random.PRNGKey(window), 3)
     q = jax.random.normal(keys[0], (2, rows, 4, 16), jnp.float32)
     k = jax.random.normal(keys[1], (2, rows, 2, 16), jnp.float32)

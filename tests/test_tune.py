@@ -289,7 +289,11 @@ def test_hyperband_scheduler(ray_start_4cpu):
         import time as _t
 
         for i in range(1, 28):
-            _t.sleep(0.04)
+            # slow enough that the two trials started once slots come
+            # free reach a milestone before the worst trial, started
+            # first, has run out: at 0.04 s a step a worker that took a
+            # second to start on a loaded host let it reach max_t
+            _t.sleep(0.12)
             tune.report({"loss": 100.0 / config["lr_id"] - i * 0.01,
                          "training_iteration": i})
 

@@ -14,11 +14,12 @@ import re
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import (NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
 
 from ray_tpu.llm import LLMConfig
 from ray_tpu.llm.engine import ContinuousEngine, _make_sampler, model_config
-from ray_tpu.models.transformer import Transformer
+from ray_tpu.models.transformer import Transformer, param_specs
 
 #: Phi-3-mini's heads (96 wide, which the chip pads to 128) at a size that
 #: compiles in seconds: 4 heads, 2 layers, 8 slots of 256 positions.
@@ -28,36 +29,45 @@ MAX_BATCH = 8
 
 
 @pytest.fixture(scope="module")
-def chip():
+def chips():
     from jax.experimental import topologies
     try:
         topo = topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:  # noqa: BLE001 - no TPU compiler on this host
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return topo.devices[0]
+    return topo.devices
 
 
-def build_compiled(chip, monkeypatch=None, row=None,
-                   cfg=None) -> ContinuousEngine:
+@pytest.fixture(scope="module")
+def chip(chips):
+    return chips[0]
+
+
+def build_compiled(chip, monkeypatch=None, row=None, cfg=None,
+                   mesh=None) -> ContinuousEngine:
     """An engine's compiled programs for `chip`, from shapes: no parameter
     is made, no thread started, nothing placed on a device. `row` stands
-    in for the compiler's answer."""
+    in for the compiler's answer. With a `mesh` (of one axis, `tp`) the
+    engine is given it and its parameters are sharded over it."""
     CFG = cfg or globals()["CFG"]
     if row is not None:
         monkeypatch.setattr(ContinuousEngine, "_probe_cache_row",
                             lambda self, make_chunk: row)
     eng = object.__new__(ContinuousEngine)
-    eng.cfg, eng.max_batch, eng.decode_chunk, eng.mesh = CFG, MAX_BATCH, 4, None
+    eng.cfg, eng.max_batch, eng.decode_chunk, eng.mesh = CFG, MAX_BATCH, 4, mesh
     eng.model = Transformer(model_config(CFG))
     eng._sampler = _make_sampler(CFG.vocab_size)
     eng._jax, eng._jnp = jax, jnp
     shapes = jax.eval_shape(lambda: eng.model.init(
         jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    where = lambda spec: (  # noqa: E731
+        SingleDeviceSharding(chip) if mesh is None else NamedSharding(
+            mesh, P(*[axis if axis == "tp" else None for axis in spec])))
     eng.params = jax.tree.map(
-        lambda s: jax.ShapeDtypeStruct(s.shape, jnp.bfloat16,
-                                       sharding=SingleDeviceSharding(chip)),
-        shapes)
+        lambda s, spec: jax.ShapeDtypeStruct(s.shape, jnp.bfloat16,
+                                             sharding=where(spec)),
+        shapes, param_specs({"params": shapes})["params"])
     eng._build_compiled()
     return eng
 
@@ -255,6 +265,69 @@ def test_v5e_prefill_of_6144_rows_goes_in_its_neighbours_tiles(chip):
     assert not re.findall(r"= \w+\[%d,\d+,4,128\]\S* copy\(" % MAX_BATCH,
                           placed.as_text())
     assert placed.memory_analysis().temp_size_in_bytes < 1e6
+
+
+def test_v5e_prefill_of_4096_rows_keeps_its_scores_in_the_kernel(
+        chip, monkeypatch):
+    """On the chip (the dispatcher's question about the backend answered as
+    the chip would: this process is held to the CPU) Trinity's prefill of
+    4096 rows is one Mosaic kernel a layer, window and full alike, with the
+    prompt's length prefetched; no float32 tile of scores `[1, 4 key/value
+    heads, 8 heads each, queries, keys]` is left in the program, which the
+    XLA form of the same bucket holds; and the temporaries shrink with
+    them."""
+    from ray_tpu.ops import attention
+
+    on_chip = lambda *dims: jax.ShapeDtypeStruct(  # noqa: E731
+        dims, jnp.int32, sharding=SingleDeviceSharding(chip))
+    scores = r"f32\[1,4,8,\d+,\d+\]"
+    xla = build_compiled(chip, cfg=SWA)
+    assert xla._prefill_form(4096) == "xla"
+    before = xla._prefill.lower(xla.params, on_chip(1, 4096),
+                                on_chip()).compile()
+    assert re.search(scores, before.as_text())
+    assert "tpu_custom_call" not in before.as_text()
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    eng = build_compiled(chip, cfg=SWA)
+    assert eng._prefill_form(4096) == "kernel"
+    after = eng._prefill.lower(eng.params, on_chip(1, 4096),
+                               on_chip()).compile()
+    text = after.as_text()
+    assert not re.search(scores, text)
+    assert len(re.findall(r" custom-call\(.*tpu_custom_call", text)) \
+        == SWA.n_layers
+    assert (after.memory_analysis().temp_size_in_bytes
+            < before.memory_analysis().temp_size_in_bytes - 150e6)
+    # heads of 96 are no whole lane tile: Phi-3's prefill stays the XLA form
+    assert build_compiled(chip)._prefill_form(128) == "xla"
+
+
+def test_v5e_prefill_sharded_over_tp_keeps_the_unpartitioned_kernel_out(
+        chips, monkeypatch):
+    """An engine given a `tp` mesh traces its prefill with that mesh in
+    context, and the dispatcher's rule then takes the XLA form: Mosaic
+    refuses to lower a kernel into a program that GSPMD partitions
+    ("cannot be automatically partitioned"), and nothing wraps this one in
+    a shard_map. Heads of 128 on four chips: the program compiles, holds no
+    kernel, and the engine counts its rows as the XLA form's."""
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from ray_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    cfg = LLMConfig(vocab_size=512, d_model=1024, n_layers=2, n_heads=8,
+                    max_seq=256, dtype="bfloat16")
+    mesh = Mesh(np.array(chips), ("tp",))
+    eng = build_compiled(chips[0], cfg=cfg, mesh=mesh)
+    assert eng._prefill_form(128) == "xla"
+    everywhere = lambda *dims: jax.ShapeDtypeStruct(  # noqa: E731
+        dims, jnp.int32, sharding=NamedSharding(mesh, P()))
+    text = eng._prefill.lower(eng.params, everywhere(1, 128),
+                              everywhere()).compile().as_text()
+    assert "tpu_custom_call" not in text
+    # the same engine on one chip takes the kernel
+    assert build_compiled(chips[0], cfg=cfg)._prefill_form(128) == "kernel"
 
 
 @pytest.mark.parametrize("name", ["kv", "latent", "swa"])
