@@ -11,6 +11,13 @@ engine workers via vllm_models.py:123-137). TPU-native design:
   slot) and leave independently — no lockstep. Fixed shapes mean every
   decode step is the same compiled XLA program; a TPU cannot afford
   vLLM's dynamic block tables, slots are the idiomatic equivalent.
+- **Kinds of leaf**: a layer keeps rows to `max_seq`, a ring of its
+  window's rows, or a STATE, a fixed block a slot that every token replaces
+  (a gated delta-rule layer's float32 `S` and its convolution's tail:
+  models/kda.py). The cache is the model's flax collection; the engine
+  keeps each layer's kind beside it (`_kind_of`) and goes by that kind
+  wherever rows and states differ: what a prefill hands on, what a parked
+  request holds, how a leaf is sharded, what the stats count.
 - **Cache layout**: the cache crosses every program boundary in the
   on-device layout the decode loop computes in. The engine asks the
   compiler for it once, and where rows as wide as their tiles make it the
@@ -365,6 +372,8 @@ def model_config(cfg):
     kind = arch.get("model_type")
     if kind == "afmoe":
         return TransformerConfig(**_afmoe(cfg, arch), **sizes)
+    if kind == "kimi_linear":
+        return TransformerConfig(**_kimi_linear(cfg, arch), **sizes)
     if kind not in ("kimi_k2", "deepseek_v3"):
         raise ValueError(f"no model is built for model_type {kind!r}")
     want = {"hidden_act": "silu", "attention_bias": False, "n_group": 1,
@@ -383,7 +392,8 @@ def model_config(cfg):
         rope_theta=float(arch["rope_theta"]),
         norm_eps=float(arch["rms_norm_eps"]),
         tie_embeddings=bool(arch["tie_word_embeddings"]),
-        attention="mla", q_lora_rank=int(arch["q_lora_rank"] or 0),
+        mixers=("mla",) * cfg.n_layers,
+        q_lora_rank=int(arch["q_lora_rank"] or 0),
         kv_lora_rank=int(arch["kv_lora_rank"]),
         qk_nope_head_dim=int(arch["qk_nope_head_dim"]),
         qk_rope_head_dim=int(arch["qk_rope_head_dim"]),
@@ -459,6 +469,64 @@ def _afmoe(cfg, arch: dict) -> dict:
         first_expert=cfg.first_expert)
 
 
+def _kimi_linear(cfg, arch: dict) -> dict:
+    """The fields of a `model_type: kimi_linear` decoder (Moonshot's Kimi
+    Linear) beyond the six sizes: gated delta-rule layers (`models/kda.py`)
+    and latent-attention layers without a position (`mla_use_nope`), each
+    named once by `linear_attn_config`'s two lists (counted from 1), leading
+    dense layers, then sigmoid-routed experts beside shared ones. What no
+    key of `config.json` states is the published modelling code's (ISSUE 34
+    lists each under `assumed`)."""
+    want = {"hidden_act": "silu", "num_expert_group": 1, "topk_group": 1,
+            "moe_layer_freq": 1, "num_nextn_predict_layers": 0,
+            "rope_scaling": None}
+    odd = {k: arch[k] for k, v in want.items() if arch.get(k, v) != v}
+    if odd:
+        raise ValueError(f"not built: {odd} (built: {want})")
+    if arch.get("num_key_value_heads", cfg.n_heads) != cfg.n_heads:
+        raise ValueError("latent attention has one latent for all heads: "
+                         "num_key_value_heads must equal the heads")
+    lin = arch["linear_attn_config"]
+    kda, full = list(lin["kda_layers"]), list(lin["full_attn_layers"])
+    named = collections.Counter(kda + full)
+    if any(named[i] != 1 for i in range(1, cfg.n_layers + 1)):
+        raise ValueError(
+            f"kda_layers and full_attn_layers must name each of the layers "
+            f"1..{cfg.n_layers} exactly once: {kda}, {full}")
+    published = int(arch["num_experts"])
+    return dict(
+        n_kv_heads=cfg.n_heads, d_ff=int(arch["intermediate_size"]),
+        rope_theta=float(arch.get("rope_theta", 10000.0)),
+        norm_eps=float(arch["rms_norm_eps"]),
+        tie_embeddings=bool(arch["tie_word_embeddings"]),
+        mixers=tuple("kda" if i in kda else "mla"
+                     for i in range(1, cfg.n_layers + 1)),
+        q_lora_rank=int(arch["q_lora_rank"] or 0),
+        kv_lora_rank=int(arch["kv_lora_rank"]),
+        qk_nope_head_dim=int(arch["qk_nope_head_dim"]),
+        qk_rope_head_dim=int(arch["qk_rope_head_dim"]),
+        v_head_dim=int(arch["v_head_dim"]),
+        mla_rope=not arch["mla_use_nope"],
+        kda_heads=int(lin["num_heads"]), kda_head_dim=int(lin["head_dim"]),
+        kda_conv=int(lin["short_conv_kernel_size"]),
+        moe_experts=published, moe_top_k=int(arch["num_experts_per_token"]),
+        moe_d_ff=int(arch["moe_intermediate_size"]),
+        moe_scoring=arch["moe_router_activation_func"],
+        moe_norm_topk=bool(arch["moe_renormalize"]),
+        moe_routed_scale=float(arch["routed_scaling_factor"]),
+        moe_score_bias=True,  # the router's e_score_correction_bias
+        moe_shared_experts=int(arch["num_shared_experts"] or 0),
+        moe_first_layer=int(arch["first_k_dense_replace"]),
+        experts_held=_experts_held(cfg, published),
+        first_expert=cfg.first_expert)
+
+
+def _layer_kinds(mcfg) -> dict:
+    """The kind of cache each layer keeps (`TransformerConfig.cache_kind_of`)
+    by the layer's name in the cache collection."""
+    return {f"layer_{i}": mcfg.cache_kind_of(i) for i in range(mcfg.n_layers)}
+
+
 def stage_layer_split(n_layers: int, n_stages: int) -> list[tuple[int, ...]]:
     """Contiguous, balanced layer ranges, one per pipeline stage (the
     remainder layers go to the EARLIEST stages: the last stage already
@@ -511,6 +579,13 @@ def make_stage_net(mcfg, layers: tuple, first: bool, last: bool):
             "`prompt_len` for a ring's hand-over): a model with window "
             "layers is served by ContinuousEngine only")
 
+    if any(mcfg.cache_kind_of(i) == "state" for i in layers):
+        raise NotImplementedError(
+            "pipeline stages keep rows per position only (llm/pipeline.py "
+            "`place`, `_init_cache`, and no `prompt_len` for where a padded "
+            "prefill's state ends): a model with state layers (recurrent "
+            "state a slot) is served by ContinuousEngine only")
+
     class _StageNet(nn.Module):
         @nn.compact
         def __call__(self, x, positions, decode: bool = True):
@@ -523,6 +598,7 @@ def make_stage_net(mcfg, layers: tuple, first: bool, last: bool):
                 x = emb[x].astype(mcfg.dtype)
             for i in layers:
                 x = Block(mcfg, moe=mcfg.is_moe_layer(i),
+                          mixer=mcfg.mixer_of(i),
                           name=f"layer_{i}")(x, positions, decode=decode)
             if last:
                 x = RMSNorm(mcfg.norm_eps, name="final_norm")(x)
@@ -781,21 +857,33 @@ class ContinuousEngine:
         model = self.model
         self._cache_spec = self._cache_shapes(model, self.params)
         mcfg = model.cfg
-        # Two kinds of leaf in one manager: a full layer's `max_seq` rows a
-        # slot and a window layer's ring, both `[slots, rows, ...]`.
+        # Three kinds of leaf in one manager, a kind a layer
+        # (`TransformerConfig.cache_kind_of`): a full layer's `max_seq` rows
+        # a slot and a window layer's ring, both `[slots, rows, ...]` and
+        # appended to; and a state, a fixed block a slot that every token
+        # replaces. Whatever goes by the kind of a leaf asks `_kind_of`,
+        # never the leaf's rank or its second axis.
+        self._kind_of = _layer_kinds(mcfg)
         self._window = max((mcfg.window_of(i) for i in range(mcfg.n_layers)),
                            default=0)
-        kinds = ["window" if mcfg.window_of(i) else "full"
-                 for i in range(mcfg.n_layers)]
-        self._cache_kinds = {
-            kind: {"layers": kinds.count(kind),
-                   "rows": self._window if kind == "window" else mcfg.max_seq,
-                   "bytes": sum(
-                       leaf.size * leaf.dtype.itemsize
-                       for i, k in enumerate(kinds) if k == kind
-                       for leaf in jax.tree.leaves(
-                           self._cache_spec[f"layer_{i}"]))}
-            for kind in ("full", "window") if kind in kinds}
+        kinds = list(self._kind_of.values())
+        self._cache_kinds = {}
+        for kind in ("full", "window", "state"):
+            if kind not in kinds:
+                continue
+            nbytes = sum(leaf.size * leaf.dtype.itemsize
+                         for name, k in self._kind_of.items() if k == kind
+                         for leaf in jax.tree.leaves(self._cache_spec[name]))
+            self._cache_kinds[kind] = {
+                "layers": kinds.count(kind),
+                **({"bytes_per_slot": nbytes // self.max_batch}
+                   if kind == "state" else
+                   {"rows": self._window if kind == "window"
+                    else mcfg.max_seq}),
+                "bytes": nbytes}
+        # What one decode step reads and writes of state, all slots.
+        self._state_rw_bytes = 2 * self._cache_kinds.get(
+            "state", {"bytes": 0})["bytes"]
         # A request parked in `_ready` holds its prefill's cache slices on
         # the device. The lane runs ahead of the scheduler only while what
         # is parked stays under a quarter of the cache's own bytes.
@@ -805,11 +893,13 @@ class ContinuousEngine:
 
         def prefill(params, toks, plen):
             """toks [1, Lb] -> (last-position logits [V], each cache leaf's
-            first min(Lb, its rows) rows). A full leaf's rows beyond the
-            bucket were not written; a ring shorter than the bucket comes
-            whole, holding the last positions before `plen` at their ring
-            places (`Attention._cached_attention`). A request parked in
-            _ready holds what this returns, not max_seq rows a leaf."""
+            first min(Lb, its rows) rows, a state leaf whole). A full leaf's
+            rows beyond the bucket were not written; a ring shorter than
+            the bucket comes whole, holding the last positions before `plen`
+            at their ring places (`Attention._cached_attention`); a state is
+            the one after position `plen - 1`, not after the bucket's last
+            row (`models/kda.py`). A request parked in _ready holds what
+            this returns, not max_seq rows a leaf."""
             lb = toks.shape[1]
             positions = jnp.arange(lb)[None]
             with self._mesh_scope():
@@ -819,8 +909,9 @@ class ContinuousEngine:
                     mutable=["cache"])
             last = jax.lax.dynamic_index_in_dim(
                 logits[0].astype(jnp.float32), plen - 1, 0, keepdims=False)
-            return last, jax.tree.map(
-                lambda c: c[:, :min(lb, c.shape[1])], vars_out["cache"])
+            return last, self._by_kind(
+                lambda kind, c: c if kind == "state"
+                else c[:, :min(lb, c.shape[1])], vars_out["cache"])
 
         def place(cache, slice_cache, mirrors, first, key, ints, floats):
             """The hand-over of batch row `slot` to a prefilled request, as
@@ -828,11 +919,14 @@ class ContinuousEngine:
             slice's shape). `ints` is [slot, prompt length, top_k], `floats`
             [temperature, top_p], `mirrors` the per-row (next token,
             length, key, temperature, top_k, top_p) the chunk programs
-            chain through. The [1, Lb, ...] prefill cache slice goes into
-            the row's first rows (of a ring: all of them, once the bucket
-            is as long); its later rows keep what an earlier request left
-            there: a row is written by the step that first makes it
-            visible (Attention._cached_attention)."""
+            chain through. Every slice lands at the row's origin. The [1,
+            Lb, ...] slice of a rows leaf goes into the row's first rows (of
+            a ring: all of them, once the bucket is as long); its later rows
+            keep what an earlier request left there: a row is written by the
+            step that first makes it visible (Attention._cached_attention).
+            A state leaf's slice is the slot's whole block: nothing of the
+            last occupant's state, or of what chunks in flight made of it
+            since, is left."""
             slot = ints[0]
             cache = jax.tree.map(
                 lambda big, small: jax.lax.dynamic_update_slice(
@@ -879,16 +973,33 @@ class ContinuousEngine:
         if self.mesh is None:
             return shapes
 
-        def sharded(leaf):
+        def sharded(kind, leaf):
             # K and V [slots, rows, heads, dim]: the head axis over tp, as
             # the attention's heads are. A latent leaf [slots, rows, row]
             # belongs to every head: each tp shard keeps all of it.
+            if kind == "state":
+                raise NotImplementedError(
+                    "a state leaf under a `tp` mesh: its heads would be "
+                    "sharded as the layer's are, and no cell or test serves "
+                    "that; a model with state layers takes no mesh")
             spec = P(None, None, "tp", None) if leaf.ndim == 4 else P()
             return jax.ShapeDtypeStruct(
                 leaf.shape, leaf.dtype,
                 sharding=NamedSharding(self.mesh, spec))
 
-        return jax.tree.map(sharded, shapes)
+        kind_of = _layer_kinds(model.cfg)
+        return {name: jax.tree.map(
+            lambda leaf, kind=kind_of[name]: sharded(kind, leaf), sub)
+            for name, sub in shapes.items()}
+
+    def _by_kind(self, fn, cache, *rest):
+        """`fn(kind, leaf, ...)` over a cache tree, the kind its layer's
+        (`_kind_of`); one map over the whole tree, in its own order."""
+        import jax
+
+        return jax.tree_util.tree_map_with_path(
+            lambda path, *leaves: fn(self._kind_of[path[0].key], *leaves),
+            cache, *rest)
 
     def _chunk_shapes(self, params, cache, greedy: bool) -> tuple:
         """Arguments to lower a chunk program of decode_chunk steps from."""
@@ -914,17 +1025,28 @@ class ContinuousEngine:
         does not fill (head_dim 96 in 128 lanes on the v5e), a row as wide
         as its tiles has that layout by default; any other answer, the
         default layout among them (head_dim 128; the CPU), leaves the
-        cache as it is. The program asked about is the greedy one of the
-        model's first layer alone: every layer uses its leaves alike and
-        the sampler never sees them, so the question is the same at a
-        fraction of the lowering and without the sampler's sorts."""
+        cache as it is. The program asked about is the greedy one of one
+        layer alone, the model's first that keeps rows (a state leaf has no
+        row to widen: its layout is put to the compiler by
+        `_count_boundary_copies`): every layer uses its rows alike and the
+        sampler never sees them, so the question is the same at a fraction
+        of the lowering and without the sampler's sorts."""
         import jax
         from jax.experimental.layout import Format, Layout
 
         from ray_tpu.models.transformer import Transformer
 
-        one = Transformer(dataclasses.replace(self.model.cfg, n_layers=1))
-        params = stage_param_slice(self.params, (0,), True, True)
+        mcfg = self.model.cfg
+        at = next((i for i in range(mcfg.n_layers)
+                   if mcfg.cache_kind_of(i) != "state"), None)
+        if at is None:
+            return 0
+        one = mcfg if at == 0 else dataclasses.replace(
+            mcfg, mixers=mcfg.mixers[at:], window_layers=mcfg.window_layers[at:],
+            moe_first_layer=max(0, mcfg.moe_first_layer - at))
+        one = Transformer(dataclasses.replace(one, n_layers=1))
+        params = stage_param_slice(self.params, (at,), True, True)
+        params["layer_0"] = params.pop(f"layer_{at}")
         cache = self._cache_shapes(one, params)
         auto = jax.tree.map(
             lambda leaf: Format(Layout.AUTO, leaf.sharding), cache)
@@ -978,8 +1100,10 @@ class ContinuousEngine:
         `kv_bound` chose) beside the share its live slots had written on
         average (`kv_live_share`: what a walk that stopped at each slot's
         own length would read); `cache_kinds`, the same two shares and the
-        layers, rows a slot and bytes of each kind of leaf, `full`
-        (`max_seq` rows) and `window` (a ring); `kv_heads`, the key/value
+        layers, rows a slot and bytes of each kind of rows leaf, `full`
+        (`max_seq` rows; the latent leaves are of this kind) and `window`
+        (a ring), and of the `state` leaves their layers, `bytes_per_slot`
+        and bytes (`state_bytes` at the top); `kv_heads`, the key/value
         heads a row holds; and how batch rows changed hands: the
         hand-overs (`splices`), those whose program was dispatched behind
         at least one decode chunk in flight (`splices_in_flight`), and
@@ -989,27 +1113,30 @@ class ContinuousEngine:
         attention is the flash kernel (`prefill_rows_kernel`)."""
         mcfg = self.model.cfg
         steps = max(1, self._kv_steps)
-        kinds = {kind: {**k, "walk_share": self._kv_walked[kind]
-                        / (steps * k["rows"]),
-                        "live_share": self._kv_live[kind]
-                        / (steps * k["rows"])}
+        kinds = {kind: k if kind == "state" else {
+                     **k, "walk_share": self._kv_walked[kind]
+                     / (steps * k["rows"]),
+                     "live_share": self._kv_live[kind] / (steps * k["rows"])}
                  for kind, k in self._cache_kinds.items()}
         # (the two shares at the top are the full leaves', as they were
         # before a leaf could be a ring; `cache_kinds` has both kinds')
-        top = kinds.get("full") or kinds["window"]
+        top = kinds.get("full") or kinds.get("window") or {}
+        latent = "mla" in mcfg.mixers
         out = {"cache_layout": self.cache_layout,
                "cache_boundary_copies": self.cache_boundary_copies,
-               "cache_kind": "latent" if mcfg.attention == "mla" else "kv",
+               "cache_kind": "latent" if latent else "kv",
                "cache_bytes": sum(k["bytes"] for k in kinds.values()),
                "cache_kinds": kinds,
-               "kv_heads": 1 if mcfg.attention == "mla" else mcfg.n_kv_heads,
-               "kv_walk_share": top["walk_share"],
-               "kv_live_share": top["live_share"],
+               "kv_heads": 1 if latent else mcfg.n_kv_heads,
+               "kv_walk_share": top.get("walk_share", 0.0),
+               "kv_live_share": top.get("live_share", 0.0),
                "splices": self.splices,
                "splices_in_flight": self.splices_in_flight,
                "pipeline_dry": self.pipeline_dry,
                "prefill_rows": self.prefill_rows,
                "prefill_rows_kernel": self.prefill_rows_kernel}
+        if "state" in kinds:
+            out["state_bytes"] = kinds["state"]["bytes"]
         if self._moe_held:
             out.update(experts_held=self._moe_held,
                        experts_published=mcfg.moe_experts,
@@ -1154,7 +1281,7 @@ class ContinuousEngine:
         if bucket not in self._prefill_form_of:
             mcfg = self.model.cfg
             with self._mesh_scope():
-                kernel = mcfg.attention == "mha" and all(
+                kernel = not mcfg.mixers and all(
                     kernel_refusal(
                         (1, bucket, mcfg.n_heads, mcfg.head_dim),
                         (1, bucket, mcfg.n_kv_heads, mcfg.head_dim),
@@ -1175,10 +1302,13 @@ class ContinuousEngine:
         import jax
 
         if bucket not in self._slice_bytes_of:
-            self._slice_bytes_of[bucket] = sum(
-                leaf.size // (self.max_batch * leaf.shape[1])
-                * min(bucket, leaf.shape[1]) * leaf.dtype.itemsize
-                for leaf in jax.tree.leaves(self._cache_spec))
+            # a state leaf whole, a rows leaf up to the bucket
+            self._slice_bytes_of[bucket] = sum(jax.tree.leaves(self._by_kind(
+                lambda kind, leaf: leaf.size // self.max_batch
+                * leaf.dtype.itemsize if kind == "state"
+                else leaf.size // (self.max_batch * leaf.shape[1])
+                * min(bucket, leaf.shape[1]) * leaf.dtype.itemsize,
+                self._cache_spec)))
         return self._slice_bytes_of[bucket]
 
     def _prefill_dispatch(self, prompt, sampling, stream):
@@ -1221,10 +1351,15 @@ class ContinuousEngine:
         self.prefill_rows += lb
         if form == "kernel":
             self.prefill_rows_kernel += lb
-        _tracing.record_span_in(
-            stream.trace, "engine.prefill", "engine", t_adm, time.time(),
-            {"prompt_len": plen, "bucket": lb, "what": "dispatch",
-             "attention": form})
+        attrs = {"prompt_len": plen, "bucket": lb, "what": "dispatch",
+                 "attention": form}
+        if self._state_rw_bytes:
+            # a state layer's prefill is a scan over chunks of the bucket
+            mcfg = self.model.cfg
+            attrs.update(scan_chunks=-(-lb // mcfg.kda_chunk), mixers=",".join(
+                f"{m}:{mcfg.mixers.count(m)}" for m in sorted(set(mcfg.mixers))))
+        _tracing.record_span_in(stream.trace, "engine.prefill", "engine",
+                                t_adm, time.time(), attrs)
         return first, cache_slice, self._jax.random.fold_in(key, 1)
 
     def _prefill_loop(self):
@@ -1557,6 +1692,8 @@ class ContinuousEngine:
                         float(np.minimum(seen, self._window).mean()))
                 attrs = {"tokens": n, "active": len(active),
                          "kv_bound": kv_bound, "kv_rows": rows["full"][0]}
+                if self._state_rw_bytes:
+                    attrs["state_rw_bytes"] = self._state_rw_bytes
                 for kind, (walked, visible) in rows.items():
                     attrs["kv_rows_" + kind] = walked
                     attrs["kv_live_" + kind] = round(visible, 2)

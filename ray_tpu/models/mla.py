@@ -5,6 +5,9 @@ Keys and values of all heads are linear maps of ONE latent per token: `c_kv`
 to each head's `k_nope` / `v`, and one rotary key `k_r` (`qk_rope_head_dim`
 wide) is shared by all heads. So the cache holds `[c_kv | k_r]`, 576 values a
 token a layer at the published sizes, where K and V per head would be 16,384.
+A model whose latent attention carries no position (`cfg.mla_rope` False: Kimi
+Linear's `mla_use_nope`) leaves `k_r` and the query dims facing it unrotated;
+nothing else differs.
 
 Two paths compute the same attention:
 
@@ -78,9 +81,13 @@ class MLA(nn.Module):
             inv_freq = rope_inv_freq(rot, cfg.rope_theta, cfg.rope_yarn)
             cs = rope_cos_sin_scale(cfg.rope_yarn)
             q_nope = q[..., :nope]
-            q_rope = rope_interleaved(q[..., nope:], positions, inv_freq, cs)
-            k_rope = rope_interleaved(kv[..., None, rank:], positions,
-                                      inv_freq, cs)[:, :, 0]  # [B, S, rot]
+            if cfg.mla_rope:
+                q_rope = rope_interleaved(q[..., nope:], positions, inv_freq,
+                                          cs)
+                k_rope = rope_interleaved(kv[..., None, rank:], positions,
+                                          inv_freq, cs)[:, :, 0]  # [B, S, rot]
+            else:  # no position: the shared key dims go as they are
+                q_rope, k_rope = q[..., nope:], kv[..., rank:]
             scale = softmax_scale(cfg)
             if decode:
                 out = self._cached(q_nope, q_rope, c_kv, k_rope, wk_b, wv_b,

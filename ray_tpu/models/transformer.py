@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from ray_tpu.models.kda import KDA
 from ray_tpu.models.layers import RMSNorm, SwiGLU, YarnScaling, rope as _rope
 from ray_tpu.models.mla import MLA
 from ray_tpu.models.moe import MoE
@@ -33,7 +34,7 @@ from ray_tpu.ops import dot_product_attention
 from ray_tpu.ops.attention import prefill_attention
 from ray_tpu.parallel.mesh import context_mesh_shape, spec_tree_like
 
-__all__ = ["Attention", "Block", "MLA", "MoE", "RMSNorm", "SwiGLU",
+__all__ = ["Attention", "Block", "KDA", "MLA", "MoE", "RMSNorm", "SwiGLU",
            "Transformer", "TransformerConfig", "YarnScaling", "loss_fn",
            "param_specs", "prefill_attention"]
 
@@ -78,17 +79,30 @@ class TransformerConfig:
     #: The logits are the final hidden state times the embedding (True) or
     #: times a matrix of their own, `lm_head` (False).
     tie_embeddings: bool = True
-    #: "mha": K and V per head (`Attention`; GQA when n_kv_heads < n_heads).
-    #: "mla": one latent per token (`models/mla.py`), sized by the five
-    #: numbers below under their published names; `rope_yarn` blends the
-    #: rotary frequencies of its `qk_rope_head_dim` dims.
-    attention: str = "mha"
+    #: The kind of each layer's mixer, a name a layer; () is "mha" in every
+    #: layer. "mha": K and V per head (`Attention`; GQA when n_kv_heads <
+    #: n_heads). "mla": one latent per token (`models/mla.py`), sized by the
+    #: five numbers below under their published names; `rope_yarn` blends
+    #: the rotary frequencies of its `qk_rope_head_dim` dims, and
+    #: `mla_rope` False leaves those dims unrotated (the model's
+    #: `mla_use_nope`: its latent attention carries no position). "kda": a
+    #: gated delta-rule linear attention (`models/kda.py`) of `kda_heads`
+    #: heads of `kda_head_dim`, a depthwise convolution of `kda_conv`
+    #: positions on q, k and v, its prefill a scan over chunks of
+    #: `kda_chunk` positions. Its cache is no rows per position but a state
+    #: a slot (`cache_kind_of`).
+    mixers: tuple = ()
     q_lora_rank: int = 0  # 0: queries are projected straight from x
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
     rope_yarn: Optional[YarnScaling] = None
+    mla_rope: bool = True
+    kda_heads: int = 0
+    kda_head_dim: int = 0
+    kda_conv: int = 4
+    kda_chunk: int = 64
     #: >0 makes the feed-forward of every layer from `moe_first_layer` on an
     #: expert layer (`models/moe.py`) whose router scores this many experts:
     #: the count a model publishes. The defaults are a top-2 softmax mixture
@@ -129,6 +143,17 @@ class TransformerConfig:
                 and self.window_layers[i]:
             return min(self.sliding_window, self.max_seq)
         return 0
+
+    def mixer_of(self, i: int) -> str:
+        return self.mixers[i] if self.mixers else "mha"
+
+    def cache_kind_of(self, i: int) -> str:
+        """What layer i keeps for a sequence: `full`, rows to `max_seq`, one
+        a position; `window`, a ring of the window's rows; `state`, a fixed
+        block that every token replaces."""
+        if self.mixer_of(i) == "kda":
+            return "state"
+        return "window" if self.window_of(i) else "full"
 
     def is_moe_layer(self, i: int) -> bool:
         return self.moe_experts > 0 and i >= self.moe_first_layer
@@ -268,15 +293,21 @@ class Block(nn.Module):
     moe: bool = False
     #: rows of this layer's attention window (cfg.window_of(i)); 0: full
     window: int = 0
+    #: this layer's mixer (cfg.mixer_of(i))
+    mixer: str = "mha"
 
     @nn.compact
     def __call__(self, x, positions, decode: bool = False, kv_bound=None,
                  prompt_len=None):
         cfg = self.cfg
         norm = lambda name: RMSNorm(cfg.norm_eps, name=name)  # noqa: E731
-        if cfg.attention == "mla":
+        if self.mixer == "mla":
             a = MLA(cfg, name="attn")(norm("attn_norm")(x), positions,
                                       decode=decode, kv_bound=kv_bound)
+        elif self.mixer == "kda":
+            # (a state is read and written whole: no notice of `kv_bound`)
+            a = KDA(cfg, name="attn")(norm("attn_norm")(x), decode=decode,
+                                      prompt_len=prompt_len)
         else:
             a = Attention(cfg, window=self.window, name="attn")(
                 norm("attn_norm")(x), positions, decode=decode,
@@ -315,7 +346,7 @@ class Transformer(nn.Module):
         single-token decode step may also be told `kv_bound`, how many
         cache rows its longest sequence of interest has, and a prefill
         padded to a bucket `prompt_len` [B], where its prompts end
-        (`Attention._cached_attention`)."""
+        (`Attention._cached_attention`, `models/kda.py`)."""
         cfg = self.cfg
         emb = self.param("tok_emb", nn.initializers.normal(0.02),
                          (cfg.vocab_size, cfg.d_model), cfg.param_dtype)
@@ -328,7 +359,7 @@ class Transformer(nn.Module):
             if not decode:
                 x = _seq_shard(x)
             x = Block(cfg, moe=cfg.is_moe_layer(i), window=cfg.window_of(i),
-                      name=f"layer_{i}")(
+                      mixer=cfg.mixer_of(i), name=f"layer_{i}")(
                 x, positions, decode=decode, kv_bound=kv_bound,
                 prompt_len=prompt_len)
         x = RMSNorm(cfg.norm_eps, name="final_norm")(x)
@@ -373,8 +404,14 @@ def param_specs(params) -> dict:
             return P("tp", None, None)  # MLA: [heads, rank, dim]
         if name == "wq_b":
             return P(None, "tp", None)  # [q_lora_rank, heads, dim]
-        if name in ("wq_a", "wkv_a"):
+        if name in ("wq_a", "wkv_a", "f_a", "g_a"):
             return P("fsdp", None)  # into a latent every head reads
+        if name in ("f_b", "g_b") or last in ("conv_q", "conv_k", "conv_v"):
+            return P(None, "tp", None)  # KDA: [rank or taps, heads, dim]
+        if name == "w_beta":
+            return P("fsdp", "tp")  # KDA: one beta a head
+        if last in ("A_log", "dt_bias"):
+            return P("tp", *(None,) * (leaf.ndim - 1))  # KDA: per head
         if moe and last in ("w_gate", "w_up"):
             return P("ep", "fsdp", "tp")  # leading [E] axis over ep
         if moe and last == "w_down":

@@ -455,6 +455,48 @@ def test_the_window_gate_and_norm_parts_carry_their_names_in_the_program():
         eng.shutdown()
 
 
+def test_the_state_layers_parts_carry_their_names_in_the_program():
+    """The scopes a model with gated delta-rule layers beside latent
+    attention adds (`model_type` `kimi_linear`): the recurrence in its
+    decode step, the chunk scan in its prefill; the latent walk's branches
+    still sit inside the latent layers'."""
+    import jax.numpy as jnp
+
+    arch = {"model_type": "kimi_linear",
+            "linear_attn_config": {
+                "kda_layers": [1], "full_attn_layers": [2], "num_heads": 4,
+                "head_dim": 16, "short_conv_kernel_size": 4},
+            "kv_lora_rank": 16, "q_lora_rank": None, "qk_nope_head_dim": 16,
+            "qk_rope_head_dim": 8, "v_head_dim": 16, "mla_use_nope": True,
+            "num_experts": 8, "num_experts_per_token": 2,
+            "num_shared_experts": 1, "moe_intermediate_size": 32,
+            "intermediate_size": 96, "first_k_dense_replace": 1,
+            "moe_router_activation_func": "sigmoid", "moe_renormalize": True,
+            "routed_scaling_factor": 2.5, "rms_norm_eps": 1e-5,
+            "rope_scaling": None, "tie_word_embeddings": False}
+    eng = ContinuousEngine(LLMConfig(**CFG, arch=arch, experts_held=4),
+                           max_batch=2, decode_chunk=4)
+    try:
+        eng._cache = eng._init_cache()
+        chunk = eng._chunk.lower(
+            eng.params, eng._cache, eng._toks_dev, eng._lens_dev, eng._keys,
+            eng._temps_dev, eng._topks_dev, eng._topps_dev, 2, False,
+            jnp.int32(9))
+        new = {"kda_attention", "kda_conv", "kda_gate", "kda_out_gate"}
+        assert new | {"kda_recurrence", "mla_attention", "decode_attention",
+                      "moe_router", "moe_experts", "shared_expert", "mlp",
+                      "lm_head", "sampler"} <= scopes_of(chunk)
+        assert "kda_chunk_scan" not in scopes_of(chunk)
+        assert in_branches(chunk, "mla_attention", "decode_attention")
+        prefill = eng._prefill.lower(
+            eng.params, jnp.zeros((1, 32), jnp.int32), 20)
+        assert new | {"kda_chunk_scan", "prefill_attention", "mlp",
+                      "lm_head"} <= scopes_of(prefill)
+        assert "kda_recurrence" not in scopes_of(prefill)
+    finally:
+        eng.shutdown()
+
+
 def test_a_capture_keeps_the_python_tracer_off_and_the_host_tracer_on(
         monkeypatch):
     import jax

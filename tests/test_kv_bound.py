@@ -39,7 +39,7 @@ CASES = {
     "stale_idle_slot_beyond_the_bound": ([5, 60, 9, 12], 14, [0, 2, 3]),
 }
 
-MLA = dict(attention="mla", q_lora_rank=24, kv_lora_rank=16,
+MLA = dict(mixers=("mla", "mla"), q_lora_rank=24, kv_lora_rank=16,
            qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16)
 BASE = dict(vocab_size=64, d_model=48, n_layers=2, n_heads=4, n_kv_heads=4,
             d_ff=64, max_seq=MAX_SEQ, dtype=jnp.float32)
@@ -158,7 +158,7 @@ def test_without_a_bound_the_step_lowers_to_the_program_it_always_was(name):
     # and with one, the layers' attention is ONE function of the module,
     # a switch over the prefixes, called once a layer
     bounded = step_text(cfg, bounded=True)
-    walk = ("_latent_walk" if cfg.attention == "mla" else "grouped_walk"
+    walk = ("_latent_walk" if cfg.mixer_of(0) == "mla" else "grouped_walk"
             if cfg.n_kv_heads < cfg.n_heads else "_xla_decode_walk")
     assert bounded.count("stablehlo.case") == 1
     assert bounded.count(f"call @{walk}(") == cfg.n_layers

@@ -349,7 +349,7 @@ def test_the_older_arms_build_what_they_built_field_for_field(name):
     want = {"phi3": dict(n_kv_heads=32, d_ff=8192),
             "kimi": dict(
                 n_kv_heads=64, d_ff=18432, rope_theta=50000.0, norm_eps=1e-5,
-                tie_embeddings=False, attention="mla", q_lora_rank=1536,
+                tie_embeddings=False, mixers=("mla",) * 6, q_lora_rank=1536,
                 kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
                 v_head_dim=128, rope_yarn=YarnScaling(
                     factor=64, original_max_position_embeddings=4096,

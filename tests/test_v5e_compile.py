@@ -45,7 +45,8 @@ def chip(chips):
 
 
 def build_compiled(chip, monkeypatch=None, row=None, cfg=None,
-                   mesh=None) -> ContinuousEngine:
+                   mesh=None, max_batch=None,
+                   decode_chunk=4) -> ContinuousEngine:
     """An engine's compiled programs for `chip`, from shapes: no parameter
     is made, no thread started, nothing placed on a device. `row` stands
     in for the compiler's answer. With a `mesh` (of one axis, `tp`) the
@@ -55,7 +56,8 @@ def build_compiled(chip, monkeypatch=None, row=None, cfg=None,
         monkeypatch.setattr(ContinuousEngine, "_probe_cache_row",
                             lambda self, make_chunk: row)
     eng = object.__new__(ContinuousEngine)
-    eng.cfg, eng.max_batch, eng.decode_chunk, eng.mesh = CFG, MAX_BATCH, 4, mesh
+    eng.cfg, eng.max_batch, eng.decode_chunk, eng.mesh = (
+        CFG, max_batch or MAX_BATCH, decode_chunk, mesh)
     eng.model = Transformer(model_config(CFG))
     eng._sampler = _make_sampler(CFG.vocab_size)
     eng._jax, eng._jnp = jax, jnp
@@ -230,6 +232,112 @@ def test_v5e_window_and_full_leaves_of_four_heads_cross_without_a_copy(chip):
     assert abs(chunk.argument_size_in_bytes
                - (resident + st["cache_bytes"])) < 1e6
     assert chunk.temp_size_in_bytes < 1e9
+
+
+#: Kimi Linear's mixers and expert layers at the PUBLISHED widths (hidden
+#: 2304; KDA 32 heads of 128 with a filter of 4; MLA 32 heads of 128 + 64
+#: over a latent of 512; 16 of 256 experts of 1024 held, the dense layer of
+#: 9216; 4096 positions a slot) and one period of its depth: layers 0-3,
+#: three KDA layers (the first dense) and a latent one. (All 16 layers: the
+#: chunk program compiles in 30 s, the 2048-row prefill in 17 s, 8.13 GB of
+#: arguments and 0.11 GB of temporaries: PERF.md section 4.)
+KDA = LLMConfig(
+    vocab_size=20480, d_model=2304, n_layers=4, n_heads=32, max_seq=4096,
+    dtype="bfloat16", experts_held=16,
+    arch={"model_type": "kimi_linear",
+          "linear_attn_config": {
+              "kda_layers": [1, 2, 3], "full_attn_layers": [4],
+              "num_heads": 32, "head_dim": 128, "short_conv_kernel_size": 4},
+          "kv_lora_rank": 512, "q_lora_rank": None, "qk_nope_head_dim": 128,
+          "qk_rope_head_dim": 64, "v_head_dim": 128, "mla_use_nope": True,
+          "num_experts": 256, "num_experts_per_token": 8,
+          "num_shared_experts": 1, "moe_intermediate_size": 1024,
+          "intermediate_size": 9216, "first_k_dense_replace": 1,
+          "moe_router_activation_func": "sigmoid", "moe_renormalize": True,
+          "routed_scaling_factor": 2.446, "rms_norm_eps": 1e-5,
+          "rope_scaling": None, "tie_word_embeddings": False})
+KDA_SLOTS = 64
+
+
+@pytest.fixture(scope="module")
+def kda_engine(chip):
+    return build_compiled(chip, cfg=KDA, max_batch=KDA_SLOTS,
+                          decode_chunk=16)
+
+
+def test_v5e_chunk_program_updates_the_state_of_64_slots_in_place(kda_engine):
+    """The 16-step chunk program at 64 slots: the float32 state `[64, 32,
+    128, 128]` crosses its boundary in its default layout (row-major, tiles
+    of (8, 128): the loop's own), no leaf of any kind is copied whole, the
+    donated cache is the result's buffer (no second copy of the state), the
+    latent row found through the model's first layer that keeps rows is
+    widened to 640, and the only loop is the scan over the steps: none
+    inside a decode step (`trace_reduce.loop_steps` counts a step by its most
+    often started operation)."""
+    eng = kda_engine
+    assert eng.model.cfg.cache_row == 640
+    st = eng.cache_stats()
+    assert st["cache_boundary_copies"] == 0
+    state = 32 * 128 * 128 * 4 + 3 * 32 * 128 * 4 + 3 * 12288 * 2
+    assert st["cache_kinds"]["state"] == {
+        "layers": 3, "bytes_per_slot": 3 * state,
+        "bytes": KDA_SLOTS * 3 * state}
+    assert st["cache_kinds"]["full"]["bytes"] == KDA_SLOTS * 4096 * 640 * 2
+    layouts = st["cache_layout"].split("; ")
+    assert [lay.split(" Layout(")[0] for lay in layouts] == [
+        "bfloat16[64, 3, 12288]", "bfloat16[64, 4096, 640]",
+        "float32[64, 3, 4096]", "float32[64, 32, 128, 128]"]
+    assert "major_to_minor=(0, 1, 2, 3), tiling=((8, 128),)" in layouts[3]
+    compiled = eng._chunk.lower(*eng._chunk_shapes(
+        eng.params, eng._cache_spec, False)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= st["cache_bytes"]
+    assert mem.temp_size_in_bytes < 0.5e9
+    text = compiled.as_text()
+    assert len(re.findall(r" while\(", text)) == 1
+    assert len(re.findall(r" conditional\(", text)) == 1  # the latent walk
+    assert not re.findall(r"= f32\[%d,32,128,128\]\S* copy\(" % KDA_SLOTS,
+                          text)
+    # S is read ONCE a layer a step: the pass that applies the last token's
+    # pending correction and writes S also takes this token's two sums
+    fused = re.findall(r"= \(f32\[64,32,128\]\S*, f32\[64,32,128\]\S*, "
+                       r"f32\[64,32,128,128\]\S*\) fusion\(", text)
+    assert len(fused) == 3
+
+
+def test_v5e_prefill_of_2048_rows_hands_on_a_state_and_place_replaces_it(
+        chip, kda_engine):
+    """The longest bucket of the cell: the chunk scan and the expanded
+    latent attention fit beside what is resident; the program hands on a
+    state and a tail whole and 2048 latent rows at the cache's width;
+    placing them copies no leaf and needs no room of its own."""
+    eng = kda_engine
+    on_chip = lambda *dims: jax.ShapeDtypeStruct(  # noqa: E731
+        dims, jnp.int32, sharding=SingleDeviceSharding(chip))
+    prefill = eng._prefill.lower(eng.params, on_chip(1, 2048),
+                                 on_chip()).compile()
+    mem = prefill.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.output_size_in_bytes < 1e9
+    assert len(re.findall(r" while\(", prefill.as_text())) >= 3  # the scans
+    one = jax.eval_shape(eng._prefill, eng.params,
+                         jax.ShapeDtypeStruct((1, 2048), jnp.int32), 5)[1]
+    assert sorted({leaf.shape for leaf in jax.tree.leaves(one)}) == [
+        (1, 3, 4096), (1, 3, 12288), (1, 32, 128, 128), (1, 2048, 640)]
+    row_of = lambda dtype, *dims: jax.ShapeDtypeStruct(  # noqa: E731
+        (KDA_SLOTS, *dims), dtype)
+    mirrors = (row_of(jnp.int32), row_of(jnp.int32), row_of(jnp.uint32, 2),
+               row_of(jnp.float32), row_of(jnp.int32), row_of(jnp.float32))
+    placed = eng._place.lower(
+        eng._cache_spec, one, mirrors, jax.ShapeDtypeStruct((), jnp.int32),
+        jax.ShapeDtypeStruct((2,), jnp.uint32),
+        jax.ShapeDtypeStruct((3,), jnp.int32),
+        jax.ShapeDtypeStruct((2,), jnp.float32)).compile()
+    assert not re.findall(
+        r"= \w+\[%d,(32,128,128|3,4096|3,12288|4096,640)\]\S* copy\(" % KDA_SLOTS,
+        placed.as_text())
+    assert placed.memory_analysis().temp_size_in_bytes < 1e6
+    assert placed.memory_analysis().alias_size_in_bytes >= eng.cache_stats()[
+        "cache_bytes"]
 
 
 def test_v5e_prefill_of_6144_rows_goes_in_its_neighbours_tiles(chip):
