@@ -364,7 +364,13 @@ def serve_phase(serve, LLMConfig, build_openai_app, chips: int) -> dict:
             f"a chunk in flight, {st['pipeline_dry']} passes began with "
             f"nothing in flight; {st['prefill_rows']} rows of prefill "
             f"buckets, {st['prefill_rows_kernel']} of them through the "
-            f"flash kernel")
+            f"flash kernel; {st['sampler_steps']} decode steps sampled, "
+            f"{st['sampler_steps_select']} of them by selection")
+        # Every sampled request here asks for a top_k of 50: no step of the
+        # wave sorts the vocabulary.
+        require(st["sampler_steps_select"] == st["sampler_steps"] > 0,
+                f"replica {st['pid']}: {st['sampler_steps_select']} of "
+                f"{st['sampler_steps']} sampled decode steps selected")
         # Heads of 128 and buckets of at least one lane tile: on the chip
         # the dispatcher's rule gives every prefill of MODEL the kernel.
         require(st["prefill_rows_kernel"] == st["prefill_rows"] > 0,

@@ -34,6 +34,9 @@ engine workers via vllm_models.py:123-137). TPU-native design:
 - **In-graph sampling**: temperature / top-k / top-p / greedy are
   vectorized per-slot inside the compiled step (each slot carries its own
   sampling params and PRNG key), so mixed request settings share a batch.
+  The k-th largest logit is selected, not sorted for (`_kth_largest`); a
+  step sorts the vocabulary, once, only where a live row has a nucleus
+  (`_make_sampler`).
 - **TP over a mesh**: pass `mesh` (axis "tp") and params/caches shard via
   the model's Megatron PartitionSpecs; XLA inserts the ICI collectives.
 - **Zero-sync hot loop** (README "Serving hot loop"): decode chunks stay
@@ -216,32 +219,114 @@ class GenStream:
         return list(self)
 
 
-def _make_sampler(vocab: int):
+def _sampler_path(samplings) -> str:
+    """The sampler's path for a chunk whose occupants ask for `samplings`,
+    by the rule the device applies to its live rows: `greedy` (the
+    argmax-only program), `sort` (an occupant that samples has a nucleus,
+    `top_p` < 1: one sort of the vocabulary a step) or `select`."""
+    sampled = [s for s in samplings if s.temperature > 0.0]
+    if not sampled:
+        return "greedy"
+    return "sort" if any(s.top_p < 1.0 for s in sampled) else "select"
+
+
+def _kth_largest(x, k):
+    """The k-th largest value of each row of x [B, V] float32, k [B] in
+    1..V: exact, ties and all, without an order. The floats' bits, read as
+    ordered integers, are searched from the top bit down: 32 passes that
+    each count a row's values at or above a candidate (on the v5e 0.03-0.08
+    ms together at the serving shapes, where `lax.top_k` of 64 or 128
+    candidates is a `TopK` call that costs 0.87 of a full sort: PERF.md
+    section 6, PR 35). Written out, not a loop: the decode step keeps no
+    loop of its own (PERF.md section 7 (l))."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    bits = lax.bitcast_convert_type(x, jnp.int32)
+    # a negative float's bits fall as it rises: flip them; lift the others
+    # above them by the sign bit
+    image = lax.bitcast_convert_type(
+        jnp.where(bits < 0, ~bits, bits | jnp.int32(-2**31)), jnp.uint32)
+    found = jnp.zeros((x.shape[0], 1), jnp.uint32)
+    for bit in range(31, -1, -1):
+        candidate = found | jnp.uint32(1 << bit)
+        at_or_above = jnp.sum(image >= candidate, axis=-1, keepdims=True)
+        found = jnp.where(at_or_above >= k[:, None], candidate, found)
+    found = lax.bitcast_convert_type(found, jnp.int32)
+    return lax.bitcast_convert_type(
+        jnp.where(found < 0, found & jnp.int32(2**31 - 1), ~found),
+        jnp.float32)
+
+
+def _kept_logits(logits, temp, top_k, top_p, live=None):
+    """The scaled logits [B, V] a row draws from, -inf where a token is not
+    kept: a row keeps every token whose scaled logit is >= its k-th largest
+    (ties included), and of those every token whose probability is >= the
+    smallest of the shortest prefix whose mass reaches top_p.
+
+    Which path a step takes is read from its own inputs. No order is taken
+    that no live sampling row asks for: without a nucleus in any of them the
+    k-th values are SELECTED (`_kth_largest`), or nothing is masked at all
+    where no row has a top_k either; a nucleus needs the kept values in
+    order, and the step sorts the vocabulary ONCE (the sorted probabilities
+    are the softmax of the sorted logits: softmax is monotone)."""
     import jax
     import jax.numpy as jnp
+    from jax import lax
 
-    @jax.named_scope("sampler")  # its name in a device trace
-    def sample(logits, keys, temp, top_k, top_p):
-        """logits [B, V] f32; keys [B, 2] uint32; temp/top_k/top_p [B].
-        temp <= 0 -> greedy. top_k <= 0 -> disabled. top_p >= 1 -> disabled
-        (the formula below then keeps every token)."""
-        greedy = jnp.argmax(logits, axis=-1)
-        lt = logits / jnp.maximum(temp, 1e-6)[:, None]
-        sorted_lt = jnp.sort(lt, axis=-1)[:, ::-1]
-        k_eff = jnp.clip(jnp.where(top_k > 0, top_k, vocab), 1, vocab)
-        kth = jnp.take_along_axis(sorted_lt, (k_eff - 1)[:, None], axis=-1)
-        lt = jnp.where(lt < kth, -jnp.inf, lt)
-        probs = jax.nn.softmax(lt, axis=-1)
-        sp = jnp.sort(probs, axis=-1)[:, ::-1]
+    vocab = logits.shape[-1]
+    lt = logits / jnp.maximum(temp, 1e-6)[:, None]
+    sampled = temp > 0.0 if live is None else (temp > 0.0) & live
+    k_eff = jnp.clip(jnp.where(top_k > 0, top_k, vocab), 1, vocab)
+    by_k = sampled & (k_eff < vocab)  # rows whose top-k masks anything
+    by_p = sampled & (top_p < 1.0)
+
+    def below(kth):
+        return jnp.where(by_k[:, None] & (lt < kth), -jnp.inf, lt)
+
+    @jax.named_scope("select")
+    def select():
+        return lax.cond(jnp.any(by_k),
+                        lambda: below(_kth_largest(lt, k_eff)), lambda: lt)
+
+    @jax.named_scope("nucleus")
+    def nucleus():
+        ordered = jnp.sort(lt, axis=-1)[:, ::-1]
+        kth = jnp.take_along_axis(ordered, (k_eff - 1)[:, None], axis=-1)
+        lt_k = below(kth)
+        top = jnp.max(lt_k, axis=-1, keepdims=True)
+        e = jnp.exp(lt_k - top)
+        mass = jnp.sum(e, axis=-1, keepdims=True)
+        # the sorted probabilities, without sorting them
+        sp = jnp.where(by_k[:, None] & (ordered < kth), 0.0,
+                       jnp.exp(ordered - top) / mass)
         csum = jnp.cumsum(sp, axis=-1)
         # smallest prefix whose mass reaches top_p (always keeps the top
         # token: csum - sp is 0 for it)
         keep = (csum - sp) < top_p[:, None]
         min_keep = jnp.min(jnp.where(keep, sp, jnp.inf), axis=-1,
                            keepdims=True)
-        lt = jnp.where(probs < min_keep, -jnp.inf, lt)
-        sampled = jax.vmap(jax.random.categorical)(keys, lt)
-        return jnp.where(temp <= 0.0, greedy, sampled).astype(jnp.int32)
+        return jnp.where(by_p[:, None] & (e / mass < min_keep), -jnp.inf,
+                         lt_k)
+
+    return lax.cond(jnp.any(by_p), nucleus, select)
+
+
+def _make_sampler(vocab: int):
+    import jax
+    import jax.numpy as jnp
+
+    @jax.named_scope("sampler")  # its name in a device trace
+    def sample(logits, keys, temp, top_k, top_p, live=None):
+        """logits [B, V] f32; keys [B, 2] uint32; temp/top_k/top_p [B];
+        live [B] bool, the rows somebody reads (all of them without it).
+        temp <= 0 -> greedy. top_k <= 0 -> disabled. top_p >= 1 -> disabled.
+        The draw is `categorical` over `_kept_logits`."""
+        assert logits.shape[-1] == vocab
+        greedy = jnp.argmax(logits, axis=-1)
+        drawn = jax.vmap(jax.random.categorical)(
+            keys, _kept_logits(logits, temp, top_k, top_p, live))
+        return jnp.where(temp <= 0.0, greedy, drawn).astype(jnp.int32)
 
     return sample
 
@@ -794,13 +879,18 @@ class ContinuousEngine:
         # (`_prefill_form`).
         self.prefill_rows = 0
         self.prefill_rows_kernel = 0
+        # Decode steps dispatched in the sampled program since start, and
+        # those in which no occupant's nucleus made the sampler sort
+        # (`_sampler_path`).
+        self.sampler_steps = 0
+        self.sampler_steps_select = 0
         self._prefill_form_of: dict = {}
 
         def make_chunk(model):
             held = model.cfg.held_experts
 
             def chunk(params, cache, toks, lengths, keys, temp, top_k, top_p,
-                      n: int, greedy: bool, kv_bound=None):
+                      n: int, greedy: bool, kv_bound=None, live=None):
                 """n in-flight decode steps under one scan. toks/lengths
                 [B]; returns (cache, keys, tokens [B, n], lengths [B]).
                 `kv_bound` (int32 scalar, traced: one program whatever its
@@ -808,13 +898,16 @@ class ContinuousEngine:
                 these n steps; attention stops at the shortest static
                 prefix that holds them (ops/decode_attention.py
                 `over_kv_prefix`), the same in every step of the chunk.
-                Without it every step walks all max_seq rows.
-                greedy=True compiles an argmax-only variant: the sampler's
-                two full-vocab sorts per step are pure waste when no active
-                slot samples. A model with expert layers appends to the
-                token block the columns of `_rows_columns`: the rows its
-                held experts were routed in these n steps ride to the host
-                in the read that brings the tokens."""
+                Without it every step walks all max_seq rows. `live` [B]
+                bool marks the rows with an occupant: a row whose occupant
+                has left keeps its sampling mirrors, and the sampler takes
+                no order on a stale row's account (without it every row
+                counts). greedy=True compiles an argmax-only variant: the
+                sampler is pure waste when no active slot samples. A model
+                with expert layers appends to the token block the columns
+                of `_rows_columns`: the rows its held experts were routed
+                in these n steps ride to the host in the read that brings
+                the tokens."""
                 def step(carry, _):
                     cache, tok, lens, keys, *rows = carry
                     logits, vars_out = model.apply(
@@ -832,7 +925,7 @@ class ContinuousEngine:
                         split = jax.vmap(jax.random.split)(keys)  # [B, 2, 2]
                         keys = split[:, 0]
                         nxt = sampler(logits[:, -1].astype(jnp.float32),
-                                      split[:, 1], temp, top_k, top_p)
+                                      split[:, 1], temp, top_k, top_p, live)
                     return (vars_out["cache"], nxt, lens + 1, keys, *rows), nxt
 
                 rows0 = [jnp.zeros((held,), jnp.int32)] if held else []
@@ -1015,7 +1108,8 @@ class ContinuousEngine:
                 jax.ShapeDtypeStruct((b,), jnp.int32),
                 jax.ShapeDtypeStruct((b,), jnp.float32),
                 self.decode_chunk, greedy,
-                jax.ShapeDtypeStruct((), jnp.int32))
+                jax.ShapeDtypeStruct((), jnp.int32),
+                jax.ShapeDtypeStruct((b,), jnp.bool_))
 
     def _probe_cache_row(self, make_chunk) -> int:
         """The row width the compiler wants for the cache, 0 for the one it
@@ -1030,7 +1124,7 @@ class ContinuousEngine:
         row to widen: its layout is put to the compiler by
         `_count_boundary_copies`): every layer uses its rows alike and the
         sampler never sees them, so the question is the same at a fraction
-        of the lowering and without the sampler's sorts."""
+        of the lowering and without the sampler."""
         import jax
         from jax.experimental.layout import Format, Layout
 
@@ -1052,7 +1146,7 @@ class ContinuousEngine:
             lambda leaf: Format(Layout.AUTO, leaf.sharding), cache)
         probe = jax.jit(make_chunk(one), static_argnums=(8, 9),
                         donate_argnums=(1,),
-                        in_shardings=(None, auto) + (None,) * 7,
+                        in_shardings=(None, auto) + (None,) * 8,
                         out_shardings=(auto, None, None, None))
         wanted = probe.lower(
             *self._chunk_shapes(params, cache, True)
@@ -1110,7 +1204,10 @@ class ContinuousEngine:
         the scheduler's passes that began with occupants seated and no
         chunk in flight (`pipeline_dry`); and the rows of the prefill
         buckets dispatched (`prefill_rows`) beside those whose program's
-        attention is the flash kernel (`prefill_rows_kernel`)."""
+        attention is the flash kernel (`prefill_rows_kernel`); and the
+        decode steps dispatched in the sampled program (`sampler_steps`)
+        beside those in which the sampler selected and sorted nothing
+        (`sampler_steps_select`)."""
         mcfg = self.model.cfg
         steps = max(1, self._kv_steps)
         kinds = {kind: k if kind == "state" else {
@@ -1134,7 +1231,9 @@ class ContinuousEngine:
                "splices_in_flight": self.splices_in_flight,
                "pipeline_dry": self.pipeline_dry,
                "prefill_rows": self.prefill_rows,
-               "prefill_rows_kernel": self.prefill_rows_kernel}
+               "prefill_rows_kernel": self.prefill_rows_kernel,
+               "sampler_steps": self.sampler_steps,
+               "sampler_steps_select": self.sampler_steps_select}
         if "state" in kinds:
             out["state_bytes"] = kinds["state"]["bytes"]
         if self._moe_held:
@@ -1647,7 +1746,9 @@ class ContinuousEngine:
             # budget would recompile on nearly every call.
             n = max(1, min(self.decode_chunk,
                            1 << (budget.bit_length() - 1)))
-            greedy = all(s.sampling.temperature <= 0.0 for s in active)
+            path = _sampler_path(s.sampling for s in active)
+            live_rows = np.zeros(self.max_batch, bool)
+            live_rows[[s.slot for s in active]] = True
             # Per-iteration tracing (README "Tracing & timeline"): bind
             # the decode loop's spans to the oldest active TRACED
             # request — in the one-request case every dispatch and
@@ -1672,8 +1773,8 @@ class ContinuousEngine:
                         self.params, self._cache,
                         self._toks_dev, self._lens_dev,
                         self._keys, self._temps_dev,
-                        self._topks_dev, self._topps_dev, n, greedy,
-                        np.int32(kv_bound))
+                        self._topks_dev, self._topps_dev, n,
+                        path == "greedy", np.int32(kv_bound), live_rows)
                 # Start the device→host copy of this chunk's tokens
                 # NOW: by the time the drain reads it (D iterations
                 # later), the transfer has overlapped the younger
@@ -1691,6 +1792,7 @@ class ContinuousEngine:
                         kv_prefix_rows(kv_bound, self._window),
                         float(np.minimum(seen, self._window).mean()))
                 attrs = {"tokens": n, "active": len(active),
+                         "sampler": path,
                          "kv_bound": kv_bound, "kv_rows": rows["full"][0]}
                 if self._state_rw_bytes:
                     attrs["state_rw_bytes"] = self._state_rw_bytes
@@ -1703,6 +1805,8 @@ class ContinuousEngine:
                     tctx, "engine.dispatch_chunk", "engine", t_disp,
                     time.time(), attrs)
                 self._kv_steps += n
+                self.sampler_steps += n * (path != "greedy")
+                self.sampler_steps_select += n * (path == "select")
                 # Chain on device; mirror lengths on host (every row
                 # steps n times — deterministic, no read needed).
                 self._toks_dev = toks_out[:, n - 1]

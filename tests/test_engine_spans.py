@@ -183,6 +183,44 @@ def test_the_stats_count_how_rows_changed_hands(engine, spans):
     assert 1 <= st["pipeline_dry"] <= st["splices"]
 
 
+def test_the_stats_and_the_chunk_spans_name_the_samplers_path(engine, spans):
+    """`sampler` on `engine.dispatch_chunk` is the sampler's path for the
+    chunk's occupants (`greedy`, `select`, `sort`: `llm/engine.py`
+    `_sampler_path`), and `sampler_steps`, `sampler_steps_select` of
+    `cache_stats` (what `/v1/stats` reports) count the steps of the sampled
+    program and those in which it sorted nothing."""
+    keys = ("sampler_steps", "sampler_steps_select")
+    assert [engine.cache_stats()[k] for k in keys] == [0, 0]
+
+    def chunks_of(**sampling):
+        del spans[:]
+        tracing._ctx.set(("a" * 32, "b" * 16))
+        stream = engine.submit([1, 2, 3], SamplingParams(
+            max_tokens=9, **sampling))
+        tracing._ctx.set(None)
+        assert len(stream.tokens()) == 9
+        return [(s["at"]["sampler"], s["at"]["tokens"]) for s in spans
+                if s["n"] == "engine.dispatch_chunk"]
+
+    greedy = chunks_of(temperature=0.0, top_p=0.5)
+    assert {path for path, _n in greedy} == {"greedy"}
+    assert [engine.cache_stats()[k] for k in keys] == [0, 0]
+    # the benchmark's traffic, a top_k of any size, and no order asked for
+    select = (chunks_of(temperature=0.7, top_k=50)
+              + chunks_of(temperature=0.7, top_k=100)
+              + chunks_of(temperature=1.0))
+    assert {path for path, _n in select} == {"select"}
+    steps = sum(n for _path, n in select)
+    assert steps >= 3 * 8
+    assert [engine.cache_stats()[k] for k in keys] == [steps, steps]
+    # a nucleus, bare or under a top_k: one sort a step
+    sort = (chunks_of(temperature=0.7, top_p=0.9)
+            + chunks_of(temperature=0.7, top_k=50, top_p=0.9))
+    assert {path for path, _n in sort} == {"sort"}
+    assert [engine.cache_stats()[k] for k in keys] == [
+        steps + sum(n for _path, n in sort), steps]
+
+
 def test_the_stats_count_prefill_rows_and_which_attention_served_them(
         engine, spans, monkeypatch):
     """`prefill_rows` and `prefill_rows_kernel` of `cache_stats` (what

@@ -344,8 +344,23 @@ def test_a_request_spliced_behind_a_chunk_in_flight_gets_its_own_tokens(
     its tokens are the reference's."""
     eng = engine
     gate, drain = threading.Event(), eng._drain
-    monkeypatch.setattr(
-        eng, "_drain", lambda ph: (gate.wait(WAIT_S), drain(ph))[1])
+
+    def held_drain(ph):
+        """Nothing is read while the gate is shut, but a request parked
+        beside a free row is seated first. One request's slice is over the
+        lane's whole parking budget here, so the stopper is prefilled only
+        once the long request has left `_ready`; a scheduler that went on to
+        block in the drain with the long request seated ALONE (its four
+        chunks take less time to dispatch than a prefill on a loaded host)
+        never seated the stopper, and the wait below ran out."""
+        deadline = time.monotonic() + WAIT_S
+        while not gate.is_set() and time.monotonic() < deadline:
+            if eng._ready and eng._free_slot() is not None:
+                return None  # go round: `_admit` seats it, nothing is read
+            time.sleep(0.005)
+        return drain(ph)
+
+    monkeypatch.setattr(eng, "_drain", held_drain)
     gate.set()
     try:
         stopper = prompt_of(38, seed=5)

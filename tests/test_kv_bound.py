@@ -182,12 +182,13 @@ def test_every_chunk_is_bounded_by_its_live_slots_in_a_run_of_mixed_lengths():
     chunk = eng._chunk
 
     def spy(params, cache, toks, lens, keys, temp, top_k, top_p, n, greedy,
-            kv_bound):
+            kv_bound, live_rows):
         live = [int(eng._lengths[i]) for i, s in enumerate(eng._slots)
                 if s is not None]
+        assert live_rows.tolist() == [s is not None for s in eng._slots]
         seen.append((live, n, int(kv_bound)))
         return chunk(params, cache, toks, lens, keys, temp, top_k, top_p, n,
-                     greedy, kv_bound)
+                     greedy, kv_bound, live_rows)
 
     eng._chunk = spy
     try:

@@ -190,6 +190,9 @@ def test_serve_openai_http(ray_start_4cpu):
         assert "major_to_minor" in st["cache_layout"]
         assert st["cache_boundary_copies"] == 0
         assert st["memory_peak_bytes"] is None
+        # ... and how its decode steps sampled: every request here is
+        # greedy, so none went through the sampled program.
+        assert st["sampler_steps"] == st["sampler_steps_select"] == 0
     finally:
         serve.shutdown()
 
@@ -228,7 +231,8 @@ def test_cache_stats_name_the_layout_and_count_boundary_copies(engine):
                        "cache_kind", "cache_bytes", "kv_walk_share",
                        "kv_live_share", "splices", "splices_in_flight",
                        "pipeline_dry", "cache_kinds", "kv_heads",
-                       "prefill_rows", "prefill_rows_kernel"}
+                       "prefill_rows", "prefill_rows_kernel",
+                       "sampler_steps", "sampler_steps_select"}
     assert 0.0 <= st["kv_live_share"] <= st["kv_walk_share"] <= 1.0
     # one kind of leaf: every layer keeps max_seq rows a slot
     assert st["kv_heads"] == CFG.n_heads and st["cache_kinds"] == {"full": {

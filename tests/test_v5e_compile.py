@@ -295,7 +295,11 @@ def test_v5e_chunk_program_updates_the_state_of_64_slots_in_place(kda_engine):
     assert mem.temp_size_in_bytes < 0.5e9
     text = compiled.as_text()
     assert len(re.findall(r" while\(", text)) == 1
-    assert len(re.findall(r" conditional\(", text)) == 1  # the latent walk
+    # the latent walk, and the sampler's two (a nucleus? a top_k?)
+    assert len(re.findall(r" conditional\(", text)) == 3
+    # one sort of the vocabulary, the nucleus's own (the routers sort 256)
+    assert len(re.findall(r"= \(f32\[64,20480\]\S*, s32\[64,20480\]\S*\) "
+                          r"sort\(", text)) == 1
     assert not re.findall(r"= f32\[%d,32,128,128\]\S* copy\(" % KDA_SLOTS,
                           text)
     # S is read ONCE a layer a step: the pass that applies the last token's
@@ -454,8 +458,9 @@ def test_v5e_bounded_chunk_program_branches_once_a_layer_and_copies_no_rows(
     cfg = {"kv": CFG, "latent": LATENT, "swa": SWA}[name]
     eng = build_compiled(chip, cfg=cfg)
     assert eng.cache_boundary_copies == 0  # whole leaves, branches included
-    shapes = eng._chunk_shapes(eng.params, eng._cache_spec, False)
-    assert shapes[-1].shape == () and shapes[-1].dtype == jnp.int32
+    # (the greedy program: the sampled one's sampler has branches of its own)
+    shapes = eng._chunk_shapes(eng.params, eng._cache_spec, True)
+    assert shapes[-2].shape == () and shapes[-2].dtype == jnp.int32
     text = eng._chunk.lower(*shapes).compile().as_text()
     assert len(re.findall(r" conditional\(", text)) == cfg.n_layers
     for leaf in jax.tree.leaves(eng._cache_spec):
@@ -464,5 +469,5 @@ def test_v5e_bounded_chunk_program_branches_once_a_layer_and_copies_no_rows(
             dims = ",".join([str(slots), str(rows)] + [r"\d+"] * len(rest))
             assert not re.findall(r"= \w+\[%s\]\S* copy\(" % dims, text), (
                 leaf.shape, rows)
-    unbounded = eng._chunk.lower(*shapes[:-1]).compile().as_text()
+    unbounded = eng._chunk.lower(*shapes[:-2]).compile().as_text()
     assert " conditional(" not in unbounded
