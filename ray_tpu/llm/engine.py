@@ -794,8 +794,19 @@ class ContinuousEngine:
         # the occupants it stepped; first tokens dispatched by the prefill
         # lane and not read; and device-resident next-token/length mirrors
         # so steady-state chunk dispatch needs NO host->device transfer.
-        self._q_chunks: list = []  # [(tokens_device, occupants, n), ...]
+        self._q_chunks: list = []  # [(tokens_device, occupants, n, seq)]
         self._pending_firsts: list = []  # [(occupant, first_token_device)]
+        # The device's account (RT_TRACING=1 only; README "Tracing &
+        # timeline"): the chunks whose dispatch has begun and ended, the
+        # buckets of the prefills the lane has enqueued since the last
+        # chunk, whether the lane is inside a prefill program's call just
+        # now, and `splices` as it stood at that chunk. With tracing off
+        # none of it is touched.
+        self._chunks_begun = 0
+        self._chunks_done = 0
+        self._prefills_since: collections.deque = collections.deque()
+        self._prefill_in_call = False
+        self._splices_at_chunk = 0
         self._toks_dev = jnp.zeros(max_batch, jnp.int32)
         self._lens_dev = jnp.zeros(max_batch, jnp.int32)
         # Every GenStream not yet _DONE, independent of slot state: the
@@ -1426,10 +1437,27 @@ class ContinuousEngine:
             ann = self._jax.profiler.TraceAnnotation(
                 "engine.prefill_dispatch")
             ann.__enter__()
+        account = _tracing.enabled()
         t_adm = time.time()
         try:
-            last_logits, cache_slice = self._prefill(
-                self.params, jnp.asarray(toks), plen)
+            toks_dev = jnp.asarray(toks)
+            # The account: the chunks whose dispatch had ended when the
+            # prefill program's call began, and the prefill's bucket for
+            # the next chunk the scheduler dispatches to count ahead of
+            # itself. A chunk whose dispatch ends while the lane is inside
+            # the call, or finds the bucket already there, says so
+            # (`prefills_beside`): the two may lie either way round.
+            if account:
+                done0 = self._chunks_done
+                self._prefill_in_call = True
+            try:
+                last_logits, cache_slice = self._prefill(
+                    self.params, toks_dev, plen)
+                if account:
+                    self._prefills_since.append(lb)
+            finally:
+                if account:
+                    self._prefill_in_call = False
             key = self._jax.random.fold_in(
                 self._jax.random.PRNGKey(sampling.seed), stream.request_id)
             first = self._sample1(
@@ -1450,15 +1478,18 @@ class ContinuousEngine:
         self.prefill_rows += lb
         if form == "kernel":
             self.prefill_rows_kernel += lb
-        attrs = {"prompt_len": plen, "bucket": lb, "what": "dispatch",
-                 "attention": form}
+        attrs = {"prompt_len": plen, "bucket": lb, "attention": form}
+        t_end = time.time()
+        if account:
+            # the last chunk surely enqueued before the prefill
+            attrs["after_seq"] = done0 - 1
         if self._state_rw_bytes:
             # a state layer's prefill is a scan over chunks of the bucket
             mcfg = self.model.cfg
             attrs.update(scan_chunks=-(-lb // mcfg.kda_chunk), mixers=",".join(
                 f"{m}:{mcfg.mixers.count(m)}" for m in sorted(set(mcfg.mixers))))
         _tracing.record_span_in(stream.trace, "engine.prefill", "engine",
-                                t_adm, time.time(), attrs)
+                                t_adm, t_end, attrs)
         return first, cache_slice, self._jax.random.fold_in(key, 1)
 
     def _prefill_loop(self):
@@ -1715,6 +1746,20 @@ class ContinuousEngine:
                 self._finish_stream(stream, e)
         return spliced
 
+    def _account_ahead(self) -> dict:
+        """The admission programs enqueued since the last chunk's dispatch
+        (scheduler thread, tracing on): the prefills the lane has counted
+        and the `place`s of this thread's own hand-overs."""
+        buckets: dict = {}
+        while self._prefills_since:
+            lb = self._prefills_since.popleft()
+            buckets[lb] = buckets.get(lb, 0) + 1
+        places, self._splices_at_chunk = (
+            self.splices - self._splices_at_chunk, self.splices)
+        return {"prefill_buckets_ahead": ",".join(
+            f"{lb}:{k}" for lb, k in sorted(buckets.items())),
+            "places_ahead": places}
+
     def _fill_pipeline(self, ph) -> tuple:
         """Phases `admit` and `dispatch`, as often as they alternate: hand
         every free row to a waiting request, then dispatch a chunk for the
@@ -1763,9 +1808,15 @@ class ContinuousEngine:
             kv_bound = max(live) + n
             assert kv_bound <= max_seq and all(
                 length + n <= kv_bound for length in live), (live, n)
+            seq = None
             if ph is not None:
                 # wall_ns ties the spans' wall clock to the trace's own.
                 ph.begin("dispatch", wall_ns=time.time_ns())
+                # The account: this chunk's ordinal, and what the device
+                # was handed since the chunk before it.
+                seq = self._chunks_begun
+                self._chunks_begun = seq + 1
+                ahead = self._account_ahead()
             try:
                 t_disp = time.time()
                 self._cache, self._keys, toks_out, lens_out = \
@@ -1796,6 +1847,16 @@ class ContinuousEngine:
                          "kv_bound": kv_bound, "kv_rows": rows["full"][0]}
                 if self._state_rw_bytes:
                     attrs["state_rw_bytes"] = self._state_rw_bytes
+                if seq is not None:
+                    # in_flight 0: the device had no chunk of ours queued
+                    attrs.update(ahead, seq=seq,
+                                 in_flight=len(self._q_chunks))
+                    if self._prefills_since or self._prefill_in_call:
+                        # a prefill program's call ran beside this
+                        # dispatch: on the device it may lie before this
+                        # chunk, and the next chunk counts it ahead of
+                        # itself. Said, not guessed.
+                        attrs["prefills_beside"] = True
                 for kind, (walked, visible) in rows.items():
                     attrs["kv_rows_" + kind] = walked
                     attrs["kv_live_" + kind] = round(visible, 2)
@@ -1812,7 +1873,7 @@ class ContinuousEngine:
                 self._toks_dev = toks_out[:, n - 1]
                 self._lens_dev = lens_out
                 self._lengths = self._lengths + n
-                self._q_chunks.append((toks_out, active, n))
+                self._q_chunks.append((toks_out, active, n, seq))
                 dispatched += 1
                 iter_ctx = iter_ctx or tctx
                 for s in active:
@@ -1820,8 +1881,10 @@ class ContinuousEngine:
             except Exception as e:
                 logger.exception("llm engine decode chunk failed")
                 self._fail(active, e)
+                self._chunks_done = self._chunks_begun
                 break
             if ph is not None:
+                self._chunks_done = self._chunks_begun
                 ph.begin("admit")
         return spliced, dispatched, iter_ctx
 
@@ -1832,9 +1895,11 @@ class ContinuousEngine:
         executing — the double buffer — and hand the tokens to the
         occupants the chunk recorded. One host_sync per chunk: a request's
         span count is bounded by its CHUNK count, never its token count.
+        The chunk's block is read first and the first tokens after it, so
+        that a traced sync can stamp the block's arrival alone.
         Returns the traced request the sync's span is bound to, if any."""
-        toks_dev, occupants, n = (self._q_chunks.pop(0) if self._q_chunks
-                                  else (None, [], 0))
+        toks_dev, occupants, n, seq = (
+            self._q_chunks.pop(0) if self._q_chunks else (None, [], 0, None))
         firsts, self._pending_firsts = self._pending_firsts, []
         owed = occupants + [st for st, _f in firsts]
         # The host-sync readback: THE per-iteration host-link round
@@ -1847,9 +1912,27 @@ class ContinuousEngine:
                 (st.stream.trace for st in owed
                  if not st.done and st.stream.trace is not None), None)
         t_sync = ph.begin("sync") if ph is not None else time.time()
+        # The account (a traced sync only): the instant each of the two
+        # reads returned, and whether it had to wait. A read that waited
+        # returns when the device finished what it read, so its stamp is
+        # the device's clock on the host's; one that did not says only
+        # that the host came late.
+        acct = None if sync_ctx is None else {}
         try:
-            first_toks = [int(f) for _st, f in firsts]
-            block = None if toks_dev is None else np.asarray(toks_dev)
+            block, first_toks = None, []
+            if toks_dev is not None:
+                if acct is not None:
+                    acct["block_waited"] = not toks_dev.is_ready()
+                block = np.asarray(toks_dev)
+                if acct is not None:
+                    acct["block_ready"] = time.time()
+            if firsts:
+                if acct is not None:
+                    acct["firsts_waited"] = not all(
+                        f.is_ready() for _st, f in firsts)
+                first_toks = [int(f) for _st, f in firsts]
+                if acct is not None:
+                    acct["firsts_ready"] = time.time()
         except Exception as e:
             self._fail(owed, e)
             return sync_ctx
@@ -1861,8 +1944,13 @@ class ContinuousEngine:
             t_end = t_end or time.time()
             _tracing.record_span_in(
                 sync_ctx, "engine.host_sync", "engine", t_sync, t_end,
-                {"chunks": int(block is not None),
-                 "cols": 0 if block is None else block.shape[1], **moe})
+                {**moe, **acct,
+                 **({} if seq is None else {"seq": seq, "tokens": n})})
+            if seq is not None:
+                # ties a first token to the chunk it was read beside
+                for st, _f in firsts:
+                    if st.stream._stage is not None:
+                        st.stream._stage[1]["sync_seq"] = seq
             try:
                 from ray_tpu.util import metrics as _metrics
 

@@ -96,11 +96,11 @@ def test_a_traced_request_has_each_stage_once_in_order(engine, spans):
         assert stream._stage is None  # every stage was closed
         by_name = {s["n"]: s for s in mine}
         # (on the CPU the prefill's attention is the XLA form)
-        assert by_name["engine.prefill"]["at"] == {
-            "prompt_len": 4, "bucket": 8, "what": "dispatch",
-            "attention": "xla"}
+        prefill = dict(by_name["engine.prefill"]["at"])
+        assert prefill.pop("after_seq") >= -1
+        assert prefill == {"prompt_len": 4, "bucket": 8, "attention": "xla"}
         assert set(by_name["engine.queue"]["at"]) == {"pending"}
-        assert set(by_name["engine.first_token"]["at"]) == {
+        assert set(by_name["engine.first_token"]["at"]) - {"sync_seq"} == {
             "slot", "chunks_in_flight"}
         assert set(by_name["engine.ready_wait"]["at"]) == {
             "ready", "active"}
@@ -283,6 +283,40 @@ class Counting:
         return False
 
 
+def watch_blocks(eng, monkeypatch):
+    """Every chunk's token block goes through a stand-in that counts the
+    `is_ready` calls made on it; returns the list they are counted in."""
+    import numpy as np
+
+    asked = []
+
+    class Watched:
+        def __init__(self, arr):
+            self._arr = arr
+
+        def is_ready(self):
+            asked.append(1)
+            return self._arr.is_ready()
+
+        def __array__(self, *a, **kw):
+            return np.asarray(self._arr)
+
+        def __getitem__(self, i):
+            return self._arr[i]
+
+        def __getattr__(self, name):
+            return getattr(self._arr, name)
+
+    chunk = eng._chunk
+
+    def watched(*a, **kw):
+        cache, keys, toks_out, lens_out = chunk(*a, **kw)
+        return cache, keys, Watched(toks_out), lens_out
+
+    monkeypatch.setattr(eng, "_chunk", watched)
+    return asked
+
+
 def test_with_tracing_off_the_engine_records_and_stamps_nothing(
         engine, monkeypatch):
     import jax
@@ -293,10 +327,125 @@ def test_with_tracing_off_the_engine_records_and_stamps_nothing(
                         lambda *a, **kw: recorded.append(a))
     monkeypatch.setattr(jax.profiler, "TraceAnnotation", Counting)
     Counting.entered = 0
+    asked = watch_blocks(engine, monkeypatch)
+    queued = []
+    monkeypatch.setattr(engine, "_q_chunks", type("Q", (list,), {
+        "append": lambda self, e: (queued.append(e), list.append(self, e))
+    })())
     for ctx, stream, _t in serve(engine, 3, traced=False):
         assert stream.trace is None and stream._stage is None
     assert recorded == []
     assert Counting.entered == 0
+    # nothing of the device's account either: no chunk got an ordinal, no
+    # block was asked whether it was ready, no counter was kept or read
+    assert queued and all(seq is None for *_rest, seq in queued)
+    assert asked == []
+    assert (engine._chunks_begun, engine._chunks_done,
+            engine._splices_at_chunk) == (0, 0, 0)
+    assert not engine._prefills_since and not engine._prefill_in_call
+    assert engine.splices == 3
+
+
+def account(spans):
+    """(chunks by ordinal, reads that carry an ordinal, the other reads,
+    prefills) of the spans caught."""
+    chunks = sorted((s for s in spans if s["n"] == "engine.dispatch_chunk"),
+                    key=lambda s: s["at"]["seq"])
+    syncs = [s for s in spans if s["n"] == "engine.host_sync"]
+    return (chunks, [s for s in syncs if "seq" in s["at"]],
+            [s for s in syncs if "seq" not in s["at"]],
+            [s for s in spans if s["n"] == "engine.prefill"])
+
+
+def settle(eng):
+    deadline = time.monotonic() + WAIT_S
+    while time.monotonic() < deadline and (
+            eng.num_active or eng._q_chunks or eng._pending_firsts):
+        time.sleep(0.01)
+    time.sleep(0.05)  # the pass that read the last block records its spans
+
+
+def test_every_chunk_has_an_ordinal_and_its_read_names_it(engine, spans,
+                                                          monkeypatch):
+    """`seq` on `engine.dispatch_chunk` counts the engine's chunks from 0
+    without a hole, and the `engine.host_sync` that read a chunk's block
+    names it (`seq`, `tokens`) and stamps the block's arrival alone
+    (`block_ready`, `block_waited`), before the hand-overs' first tokens
+    (`firsts_ready`, `firsts_waited`), inside its own interval."""
+    asked = watch_blocks(engine, monkeypatch)
+    serve(engine, 5, max_tokens=20)
+    settle(engine)
+    chunks, reads, others, _prefills = account(spans)
+    assert [c["at"]["seq"] for c in chunks] == list(range(len(chunks)))
+    assert len(chunks) >= 10 and len(asked) == len(reads) == len(chunks)
+    by_seq = {c["at"]["seq"]: c for c in chunks}
+    assert chunks[0]["at"]["in_flight"] == 0
+    for c in chunks:
+        assert 0 <= c["at"]["in_flight"] < 4  # PIPELINE_DEPTH
+    for r in reads:
+        at = r["at"]
+        assert at["tokens"] == by_seq[at["seq"]]["at"]["tokens"]
+        assert r["a"] <= at["block_ready"] <= r["b"]
+        assert at["block_waited"] in (True, False)
+        assert by_seq[at["seq"]]["b"] <= at["block_ready"]
+        if "firsts_ready" in at:
+            assert at["block_ready"] <= at["firsts_ready"] <= r["b"]
+            assert at["firsts_waited"] in (True, False)
+    assert sorted(r["at"]["seq"] for r in reads) == list(range(len(chunks)))
+    # a read of first tokens only names no chunk and stamps no block
+    for r in others:
+        assert not {"tokens", "block_ready", "block_waited"} & set(r["at"])
+        assert r["a"] <= r["at"]["firsts_ready"] <= r["b"]
+    # a first token read beside a chunk's block says which
+    firsts = [s for s in spans if s["n"] == "engine.first_token"]
+    assert len(firsts) == 5
+    beside = {r["at"]["seq"] for r in reads if "firsts_ready" in r["at"]}
+    assert {s["at"]["sync_seq"] for s in firsts
+            if "sync_seq" in s["at"]} == beside
+
+
+def test_a_chunk_counts_the_admission_programs_enqueued_ahead_of_it(
+        engine, spans):
+    """`prefill_buckets_ahead` (`"8:2,16:1"`) and `places_ahead` on
+    `engine.dispatch_chunk` are what the device was handed since the chunk
+    before: every hand-over's `place` and every prefill is ahead of exactly
+    one chunk, and a prefill's `after_seq` says which: the next one, but
+    where the prefill program's call ran beside that chunk's dispatch,
+    which the chunk then says (`prefills_beside`) and nobody guesses."""
+    streams = []
+    for plen in (3, 11, 20, 5, 40, 9):
+        tracing._ctx.set((f"{plen:032x}", f"{plen:016x}"))
+        streams.append(engine.submit(
+            list(range(1, plen + 1)), SamplingParams(
+                max_tokens=14, temperature=0.7, top_k=8, seed=plen)))
+        time.sleep(0.02)
+    tracing._ctx.set(None)
+    assert all(len(s.tokens()) == 14 for s in streams)
+    settle(engine)
+    chunks, _reads, _others, prefills = account(spans)
+    assert len(prefills) == 6 == engine.cache_stats()["splices"]
+    assert sum(c["at"]["places_ahead"] for c in chunks) == 6
+    ahead = [{int(b): int(k) for b, k in (
+        part.split(":") for part in c["at"]["prefill_buckets_ahead"].split(",")
+        if part)} for c in chunks]
+    assert sum(sum(a.values()) for a in ahead) == 6
+    assert sum(b * k for a in ahead for b, k in a.items()) == sum(
+        p["at"]["bucket"] for p in prefills) == 8 + 16 + 32 + 8 + 64 + 16
+    assert not engine._prefills_since and not engine._prefill_in_call
+    unsure = {c["at"]["seq"] for c in chunks if "prefills_beside" in c["at"]}
+    assert all(c["at"].get("prefills_beside", True) is True for c in chunks)
+    # chunk k has ahead of it the prefills enqueued after chunk k - 1: a
+    # prefill is counted by the chunk after its `after_seq`, or later if
+    # that chunk's dispatch ran beside its call
+    counted = 0
+    for c, a in zip(chunks, ahead):
+        k = c["at"]["seq"]
+        counted += sum(a.values())
+        before = [p["at"] for p in prefills if p["at"]["after_seq"] < k]
+        sure = [at for at in before if at["after_seq"] + 1 not in unsure]
+        assert len(sure) <= counted <= len(before), (k, counted, prefills)
+    assert all(-1 <= p["at"]["after_seq"] < len(chunks) for p in prefills)
+    assert prefills[0]["at"]["after_seq"] == -1
 
 
 def test_with_tracing_on_every_phase_is_an_annotation(engine, spans,

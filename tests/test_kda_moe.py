@@ -571,9 +571,9 @@ def test_the_spans_carry_the_states_traffic_and_the_scans_chunks(engine,
         assert {"kv_rows_full", "kv_live_full", "kv_bound"} <= set(at)
         assert "kv_rows_window" not in at
     prefill = [s["at"] for s in spans if s["n"] == "engine.prefill"]
-    assert prefill == [{"prompt_len": 21, "bucket": 32, "what": "dispatch",
-                        "attention": "xla", "scan_chunks": 2,
-                        "mixers": "kda:6,mla:2"}]
+    assert prefill == [{"prompt_len": 21, "bucket": 32, "attention": "xla",
+                        "scan_chunks": 2, "mixers": "kda:6,mla:2",
+                        "after_seq": -1}]
     counted = [s["at"] for s in spans if s["n"] == "engine.host_sync"
                and s["at"].get("moe_steps")]
     assert sum(a["moe_steps"] for a in counted) in (13, 14)
