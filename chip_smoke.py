@@ -208,14 +208,17 @@ class ChipProbe:
         return rows
 
     def kernels(self, model: dict) -> dict:
-        """Compiles the Pallas flash-attention kernel (the prefill's
-        attention and a forward pass without a gradient; decode attention
-        has no kernel of its own) with Mosaic (interpret=False) and
-        compares it with its XLA form, `prefill_attention`: at this model's
-        prefill buckets, at the bench shape, and at Trinity-Mini's (32
-        heads on 4, a window of 2048 and none, a prompt that ends inside
-        its bucket). An exception from the kernel is reported in its row,
-        and the smoke fails on it."""
+        """Compiles the two Pallas kernels with Mosaic (interpret=False)
+        and compares each with its XLA form. The flash-attention kernel
+        (the prefill's attention and a forward pass without a gradient)
+        against `prefill_attention`: at this model's prefill buckets, at
+        the bench shape, and at Trinity-Mini's (32 heads on 4, a window of
+        2048 and none, a prompt that ends inside its bucket). The ragged
+        decode kernel (a bounded decode step's attention) against the
+        whole-cache walk `_xla_decode_attention`: at Phi-3's leaf (heads of
+        96 in rows of 128) and at Trinity-Mini's full leaf and ring, with
+        ragged lengths, a wrapped ring and free rows. An exception from a
+        kernel is reported in its row, and the smoke fails on it."""
         import time
         import traceback
 
@@ -224,6 +227,9 @@ class ChipProbe:
         import numpy as np
 
         from ray_tpu.ops.attention import kernel_refusal, prefill_attention
+        from ray_tpu.ops.decode_attention import (_xla_decode_attention,
+                                                  ragged_decode_attention,
+                                                  walk_refusal)
         from ray_tpu.ops.flash_attention import flash_attention
 
         dev = jax.devices()[0]
@@ -266,10 +272,35 @@ class ChipProbe:
                                             window=window, q_len=q_len),
                     lambda: prefill_attention(q, k, v, window), plen)
 
+        # Decode: Phi-3's leaf, Trinity-Mini's full leaf and its ring.
+        for b, rows_, h, kv, d in [(8, 2048, 32, 32, 96),
+                                   (16, 8192, 32, 4, 128),
+                                   (16, 2048, 32, 4, 128)]:
+            ks = jax.random.split(jax.random.PRNGKey(rows_ + kv), 4)
+            q = jax.random.normal(ks[0], (b, h, d), jnp.bfloat16)
+            wide = ((0, 0),) * 3 + ((0, 128 - d),)
+            k, v = (jnp.pad(jax.random.normal(key, (b, rows_, kv, d),
+                                              jnp.bfloat16), wide)
+                    for key in ks[1:3])
+            lens = jax.random.randint(ks[3], (b,), 1, 3 * rows_ // 2)
+            live = jnp.arange(b) % 4 != 1
+            compare(f"ragged_decode_attention b{b} rows{rows_} h{h} on {kv} "
+                    f"d{d}",
+                    lambda: jnp.where(
+                        live[:, None], ragged_decode_attention(
+                            q, k, v, lens, live).reshape(b, -1), 0),
+                    lambda: jnp.where(
+                        live[:, None], _xla_decode_attention(
+                            q, k[..., :d], v[..., :d],
+                            jnp.minimum(lens, rows_)).reshape(b, -1), 0),
+                    h * d)
+
         return {"platform": dev.platform, "device_kind": dev.device_kind,
                 "rows": rows,
                 "prefill_kernel_refusal": kernel_refusal(
-                    (1, 128, heads, hd), (1, 128, heads, hd))}
+                    (1, 128, heads, hd), (1, 128, heads, hd)),
+                "decode_kernel_refusal": walk_refusal(
+                    (8, heads, hd), (8, model["max_seq"], heads, hd))}
 
 
 # -------------------------------------------------------------------- phases
@@ -364,7 +395,9 @@ def serve_phase(serve, LLMConfig, build_openai_app, chips: int) -> dict:
             f"a chunk in flight, {st['pipeline_dry']} passes began with "
             f"nothing in flight; {st['prefill_rows']} rows of prefill "
             f"buckets, {st['prefill_rows_kernel']} of them through the "
-            f"flash kernel; {st['sampler_steps']} decode steps sampled, "
+            f"flash kernel; {st['decode_steps']} decode steps, "
+            f"{st['decode_steps_kernel']} of them through the ragged "
+            f"kernel; {st['sampler_steps']} sampled, "
             f"{st['sampler_steps_select']} of them by selection")
         # Every sampled request here asks for a top_k of 50: no step of the
         # wave sorts the vocabulary.
@@ -376,6 +409,12 @@ def serve_phase(serve, LLMConfig, build_openai_app, chips: int) -> dict:
         require(st["prefill_rows_kernel"] == st["prefill_rows"] > 0,
                 f"replica {st['pid']}: {st['prefill_rows_kernel']} of "
                 f"{st['prefill_rows']} prefill rows went through the flash "
+                f"kernel")
+        # Rows of whole lane tiles: on the chip the dispatcher's rule gives
+        # every bounded decode step of MODEL the ragged kernel.
+        require(st["decode_steps_kernel"] == st["decode_steps"] > 0,
+                f"replica {st['pid']}: {st['decode_steps_kernel']} of "
+                f"{st['decode_steps']} decode steps went through the ragged "
                 f"kernel")
         # The cache must cross a program's boundary in the layout the
         # decode loop computes in: a copy of a whole leaf there is a
@@ -418,12 +457,17 @@ def chip_phase(ray_tpu, greedy: list) -> None:
         else:
             say(f"  FAIL {row['kernel']}: {row.get('error') or row}")
     say("attention by phase: a decode step reads the cache through "
-        "ops/decode_attention.py (plain JAX); a prefill goes through "
-        "dot_product_attention, which for this model's buckets chooses "
+        "ops/decode_attention.py, which for this model's leaves chooses "
+        + ("the ragged Pallas kernel"
+           if rep["decode_kernel_refusal"] is None
+           else f"the XLA walk ({rep['decode_kernel_refusal']})")
+        + "; a prefill goes through dot_product_attention, which for this "
+        "model's buckets chooses "
         + ("the Pallas flash kernel"
            if rep["prefill_kernel_refusal"] is None
            else f"the XLA form ({rep['prefill_kernel_refusal']})")
-        + "; each replica's line above counts the rows either form served")
+        + "; each replica's line above counts the steps and rows either "
+        "form served")
     require(rep["platform"] == "tpu", "chip actor did not run on a TPU")
     require(bool(ref), "no served greedy continuation to check")
     off = [r for r in ref

@@ -27,10 +27,13 @@ engine workers via vllm_models.py:123-137). TPU-native design:
 - **Chunked decode**: between admission points the engine runs
   `decode_chunk` single-token steps under ONE lax.scan dispatch,
   amortizing host->device latency while bounding join latency to a few
-  tokens. Single-token attention (ops/decode_attention.py) reads a
-  static prefix of the slot cache, chosen inside the program from the
-  one number the scheduler hands each chunk: `kv_bound`, the rows its
-  longest LIVE slot will have (`_run_scheduler`).
+  tokens. Single-token attention (ops/decode_attention.py) goes by two
+  things only the scheduler knows and hands each chunk: `kv_bound`, the
+  rows its longest LIVE slot will have, and `live`, which rows of the
+  batch have an occupant (`_run_scheduler`). On a TPU a ragged kernel
+  reads each live slot's own K and V rows and nothing of a free one;
+  elsewhere, and for latent rows, the step reads a static prefix of the
+  slot cache chosen inside the program from `kv_bound`.
 - **In-graph sampling**: temperature / top-k / top-p / greedy are
   vectorized per-slot inside the compiled step (each slot carries its own
   sampling params and PRNG key), so mixed request settings share a batch.
@@ -872,10 +875,12 @@ class ContinuousEngine:
         self._moe_held = self.model.cfg.held_experts
         self._moe_cols = -(-self._moe_held // self.max_batch)
         self.moe_rows_total = 0
-        # The decode steps dispatched since start, the cache rows they
-        # walked a slot, and the rows a live slot had on average
-        # (`cache_stats`: kv_walk_share, kv_live_share).
-        self._kv_steps = 0
+        # The decode steps dispatched since start, those whose attention
+        # over K and V rows is the ragged kernel (`_decode_blocks`), the
+        # cache rows they walked a slot, and the rows a live slot had on
+        # average (`cache_stats`: kv_walk_share, kv_live_share).
+        self.decode_steps = 0
+        self.decode_steps_kernel = 0
         self._kv_walked = {"full": 0, "window": 0}
         self._kv_live = {"full": 0.0, "window": 0.0}
         # Hand-overs of a batch row since start, those dispatched behind at
@@ -908,12 +913,15 @@ class ContinuousEngine:
                 value) is the most cache rows any LIVE slot has after
                 these n steps; attention stops at the shortest static
                 prefix that holds them (ops/decode_attention.py
-                `over_kv_prefix`), the same in every step of the chunk.
-                Without it every step walks all max_seq rows. `live` [B]
-                bool marks the rows with an occupant: a row whose occupant
-                has left keeps its sampling mirrors, and the sampler takes
-                no order on a stale row's account (without it every row
-                counts). greedy=True compiles an argmax-only variant: the
+                `over_kv_prefix`), the same in every step of the chunk,
+                or, where the ragged kernel serves (`_decode_blocks`), at
+                each slot's own length. Without it every step walks all
+                max_seq rows. `live` [B] bool marks the rows with an
+                occupant: a row whose occupant has left keeps its sampling
+                mirrors and its growing length, and the sampler takes no
+                order on a stale row's account, nor does the kernel read a
+                row of its cache (without it every row counts).
+                greedy=True compiles an argmax-only variant: the
                 sampler is pure waste when no active slot samples. A model
                 with expert layers appends to the token block the columns
                 of `_rows_columns`: the rows its held experts were routed
@@ -921,11 +929,15 @@ class ContinuousEngine:
                 the tokens."""
                 def step(carry, _):
                     cache, tok, lens, keys, *rows = carry
-                    logits, vars_out = model.apply(
-                        {"params": params, "cache": cache}, tok[:, None],
-                        positions=lens[:, None], decode=True,
-                        kv_bound=kv_bound,
-                        mutable=["cache", "stats"] if held else ["cache"])
+                    # (the mesh in context, as the prefill's: what
+                    # `_decode_blocks` asks the rule, the trace asks too)
+                    with self._mesh_scope():
+                        logits, vars_out = model.apply(
+                            {"params": params, "cache": cache}, tok[:, None],
+                            positions=lens[:, None], decode=True,
+                            kv_bound=kv_bound, live=live,
+                            mutable=(["cache", "stats"] if held
+                                     else ["cache"]))
                     if held:
                         rows = [sum(jax.tree.leaves(vars_out.get("stats", {})),
                                     rows[0])]
@@ -988,6 +1000,11 @@ class ContinuousEngine:
         # What one decode step reads and writes of state, all slots.
         self._state_rw_bytes = 2 * self._cache_kinds.get(
             "state", {"bytes": 0})["bytes"]
+        # Where the ragged kernel serves the decode step's attention, its
+        # row block by kind of leaf ({} where the XLA walk does), and what
+        # a chunk's span calls that: `kernel`, `xla`, or `mixed` where the
+        # dispatcher takes one kind of leaf and refuses the other.
+        self._kernel_blocks, self._decode_form = self._decode_blocks()
         # A request parked in `_ready` holds its prefill's cache slices on
         # the device. The lane runs ahead of the scheduler only while what
         # is parked stays under a quarter of the cache's own bytes.
@@ -1218,9 +1235,12 @@ class ContinuousEngine:
         attention is the flash kernel (`prefill_rows_kernel`); and the
         decode steps dispatched in the sampled program (`sampler_steps`)
         beside those in which the sampler selected and sorted nothing
-        (`sampler_steps_select`)."""
+        (`sampler_steps_select`); and all decode steps dispatched
+        (`decode_steps`) beside those whose attention is the ragged kernel
+        (`decode_steps_kernel`), for which the walked share is the live
+        slots' own rows rounded up to the kernel's row block."""
         mcfg = self.model.cfg
-        steps = max(1, self._kv_steps)
+        steps = max(1, self.decode_steps)
         kinds = {kind: k if kind == "state" else {
                      **k, "walk_share": self._kv_walked[kind]
                      / (steps * k["rows"]),
@@ -1244,7 +1264,9 @@ class ContinuousEngine:
                "prefill_rows": self.prefill_rows,
                "prefill_rows_kernel": self.prefill_rows_kernel,
                "sampler_steps": self.sampler_steps,
-               "sampler_steps_select": self.sampler_steps_select}
+               "sampler_steps_select": self.sampler_steps_select,
+               "decode_steps": self.decode_steps,
+               "decode_steps_kernel": self.decode_steps_kernel}
         if "state" in kinds:
             out["state_bytes"] = kinds["state"]["bytes"]
         if self._moe_held:
@@ -1400,6 +1422,32 @@ class ContinuousEngine:
                                    for i in range(mcfg.n_layers)})
             self._prefill_form_of[bucket] = "kernel" if kernel else "xla"
         return self._prefill_form_of[bucket]
+
+    def _decode_blocks(self) -> tuple[dict, str]:
+        """The ragged kernel's row block by kind of rows leaf (`full`,
+        `window`) whose `mha` layers' bounded decode step takes the kernel
+        (a kind the dispatcher refuses is left out, and {} is the XLA walk
+        throughout), and the name of that: `kernel`, `xla`, `mixed`. The
+        dispatcher's own rule (`ops/decode_attention.py`
+        `walk_refusal`, which decides leaf by leaf) put to the leaves the
+        chunk program is traced with, under the mesh it is traced under.
+        Nothing is read back from the device. A latent layer walks its rows
+        itself (`models/mla.py`)."""
+        import jax
+
+        from ray_tpu.ops.decode_attention import row_block, walk_refusal
+
+        mcfg = self.model.cfg
+        leaves = {self._kind_of[f"layer_{i}"]:
+                  jax.tree.leaves(self._cache_spec[f"layer_{i}"])[0]
+                  for i in range(mcfg.n_layers) if mcfg.mixer_of(i) == "mha"}
+        q = (self.max_batch, mcfg.n_heads, mcfg.head_dim)
+        with self._mesh_scope():
+            blocks = {kind: row_block(leaf.shape, leaf.dtype)
+                      for kind, leaf in leaves.items()
+                      if walk_refusal(q, leaf.shape, leaf.dtype) is None}
+        return blocks, ("xla" if not blocks else "kernel"
+                        if len(blocks) == len(leaves) else "mixed")
 
     def _mesh_scope(self):
         """The engine's mesh as the mesh in context, for what is traced
@@ -1760,6 +1808,26 @@ class ContinuousEngine:
             f"{lb}:{k}" for lb, k in sorted(buckets.items())),
             "places_ahead": places}
 
+    def _rows_walked(self, kind: str, seen, kv_bound: int) -> tuple:
+        """Rows of a leaf of this kind a slot's attention reads in a step of
+        a chunk whose live slots show `seen` [slots, steps] rows of it: (as
+        the chunk's span says it, as read). The XLA walk reads the static
+        prefix `kv_bound` picks, every slot alike. The ragged kernel reads
+        each live slot's own rows rounded up to its row block: `/v1/stats`
+        sums their mean as it is, and the span carries it as a whole
+        multiple of the block (ISSUE 37 asked for that form: a reader that
+        groups chunks by the rows walked, `benchmark/device_account.py`'s
+        classes, needs few distinct values until it buckets them itself,
+        ROADMAP M0 (j))."""
+        from ray_tpu.ops.decode_attention import kv_prefix_rows
+
+        block = self._kernel_blocks.get(kind)
+        if block is None:
+            rows = kv_prefix_rows(kv_bound, self._cache_kinds[kind]["rows"])
+            return rows, rows
+        blocks = float(np.ceil(seen / block).mean())
+        return int(round(blocks)) * block, blocks * block
+
     def _fill_pipeline(self, ph) -> tuple:
         """Phases `admit` and `dispatch`, as often as they alternate: hand
         every free row to a waiting request, then dispatch a chunk for the
@@ -1769,8 +1837,6 @@ class ContinuousEngine:
         joins before its next chunk. Returns the requests spliced, the chunks
         dispatched and the first traced request a chunk's span was bound
         to, if any. Entered in phase `admit`."""
-        from ray_tpu.ops.decode_attention import kv_prefix_rows
-
         max_seq = self.cfg.max_seq
         iter_ctx = None
         spliced = dispatched = 0
@@ -1836,14 +1902,16 @@ class ContinuousEngine:
                 # j + 1 of them, a ring at most its own length): by kind
                 # of leaf, a step's mean.
                 seen = np.add.outer(live, np.arange(1, n + 1))
-                rows = {"full": (kv_prefix_rows(kv_bound, max_seq),
+                rows = {"full": (*self._rows_walked("full", seen, kv_bound),
                                  float(seen.mean()))}
                 if self._window:
+                    ring = np.minimum(seen, self._window)
                     rows["window"] = (
-                        kv_prefix_rows(kv_bound, self._window),
-                        float(np.minimum(seen, self._window).mean()))
+                        *self._rows_walked("window", ring, kv_bound),
+                        float(ring.mean()))
+                form = self._decode_form
                 attrs = {"tokens": n, "active": len(active),
-                         "sampler": path,
+                         "sampler": path, "attention": form,
                          "kv_bound": kv_bound, "kv_rows": rows["full"][0]}
                 if self._state_rw_bytes:
                     attrs["state_rw_bytes"] = self._state_rw_bytes
@@ -1857,15 +1925,16 @@ class ContinuousEngine:
                         # chunk, and the next chunk counts it ahead of
                         # itself. Said, not guessed.
                         attrs["prefills_beside"] = True
-                for kind, (walked, visible) in rows.items():
+                for kind, (walked, read, visible) in rows.items():
                     attrs["kv_rows_" + kind] = walked
                     attrs["kv_live_" + kind] = round(visible, 2)
-                    self._kv_walked[kind] += n * walked
+                    self._kv_walked[kind] += n * read
                     self._kv_live[kind] += n * visible
                 _tracing.record_span_in(
                     tctx, "engine.dispatch_chunk", "engine", t_disp,
                     time.time(), attrs)
-                self._kv_steps += n
+                self.decode_steps += n
+                self.decode_steps_kernel += n * (form == "kernel")
                 self.sampler_steps += n * (path != "greedy")
                 self.sampler_steps_select += n * (path == "select")
                 # Chain on device; mirror lengths on host (every row

@@ -172,7 +172,7 @@ class Attention(nn.Module):
 
     @nn.compact
     def __call__(self, x, positions, decode: bool = False, kv_bound=None,
-                 prompt_len=None):
+                 prompt_len=None, live=None):
         cfg = self.cfg
         hd = cfg.head_dim
         dense = lambda feats, name: nn.DenseGeneral(  # noqa: E731
@@ -194,7 +194,7 @@ class Attention(nn.Module):
                              else "full_attention"):
             if decode:
                 out = self._cached_attention(q, k, v, positions, kv_bound,
-                                             prompt_len)
+                                             prompt_len, live)
             else:
                 out = dot_product_attention(q, k, v, causal=True,
                                             window=self.window)
@@ -205,7 +205,7 @@ class Attention(nn.Module):
                                dtype=cfg.dtype, param_dtype=cfg.param_dtype)(out)
 
     def _cached_attention(self, q, k, v, positions, kv_bound=None,
-                          prompt_len=None):
+                          prompt_len=None, live=None):
         """Autoregressive KV-cache attention with PER-SEQUENCE positions
         (reference role: vLLM's paged KV cache; here slot-per-sequence):
         new k/v rows go into fixed per-slot buffers at each sequence's own
@@ -228,10 +228,11 @@ class Attention(nn.Module):
         ops/decode_attention.py. Given `kv_bound` (a traced int32 scalar
         from the engine's scheduler: the longest LIVE sequence's length
         after this chunk, which only the host knows, because a retired
-        slot's device-side position keeps growing), such a step reads the
-        shortest static prefix of the leaf that holds that many rows, of
-        `max_seq` or of the ring; without it, the whole leaf, by the
-        program it always was.
+        slot's device-side position keeps growing), such a step reads, on
+        a TPU, each slot's own rows and none of a slot that `live` ([B]
+        bool, the host's too) marks free, and elsewhere the shortest static
+        prefix of the leaf that holds `kv_bound` rows, of `max_seq` or of
+        the ring; without it, the whole leaf, by the program it always was.
 
         A multi-token step is a prefill from position 0: it attends over
         its own rows (`dot_product_attention`: the flash kernel on the chip,
@@ -283,7 +284,7 @@ class Attention(nn.Module):
         from ray_tpu.ops.decode_attention import decode_attention
 
         out = decode_attention(q[:, 0], keys, vals, pos[:, 0] + 1,
-                               kv_bound=kv_bound)
+                               kv_bound=kv_bound, live=live)
         return out[:, None].astype(cfg.dtype)
 
 
@@ -298,7 +299,7 @@ class Block(nn.Module):
 
     @nn.compact
     def __call__(self, x, positions, decode: bool = False, kv_bound=None,
-                 prompt_len=None):
+                 prompt_len=None, live=None):
         cfg = self.cfg
         norm = lambda name: RMSNorm(cfg.norm_eps, name=name)  # noqa: E731
         if self.mixer == "mla":
@@ -311,7 +312,7 @@ class Block(nn.Module):
         else:
             a = Attention(cfg, window=self.window, name="attn")(
                 norm("attn_norm")(x), positions, decode=decode,
-                kv_bound=kv_bound, prompt_len=prompt_len)
+                kv_bound=kv_bound, prompt_len=prompt_len, live=live)
         x = x + (norm("post_attn_norm")(a) if cfg.sandwich_norm else a)
         h = norm("mlp_norm")(x)
         f = (MoE(cfg, name="moe")(h, serving=decode) if self.moe
@@ -338,15 +339,17 @@ class Transformer(nn.Module):
 
     @nn.compact
     def __call__(self, tokens, positions=None, decode: bool = False,
-                 kv_bound=None, prompt_len=None):
+                 kv_bound=None, prompt_len=None, live=None):
         """tokens: [B, S] int32 -> logits [B, S, vocab] (f32).
 
         decode=True uses per-layer caches (flax "cache" collection): pass
         `positions` (absolute) and apply with mutable=["cache"]. A
         single-token decode step may also be told `kv_bound`, how many
-        cache rows its longest sequence of interest has, and a prefill
-        padded to a bucket `prompt_len` [B], where its prompts end
-        (`Attention._cached_attention`, `models/kda.py`)."""
+        cache rows its longest sequence of interest has, and `live` [B]
+        bool, which rows of the batch have an occupant (a free row's cache
+        need not be read), and a prefill padded to a bucket `prompt_len`
+        [B], where its prompts end (`Attention._cached_attention`,
+        `models/kda.py`)."""
         cfg = self.cfg
         emb = self.param("tok_emb", nn.initializers.normal(0.02),
                          (cfg.vocab_size, cfg.d_model), cfg.param_dtype)
@@ -361,7 +364,7 @@ class Transformer(nn.Module):
             x = Block(cfg, moe=cfg.is_moe_layer(i), window=cfg.window_of(i),
                       mixer=cfg.mixer_of(i), name=f"layer_{i}")(
                 x, positions, decode=decode, kv_bound=kv_bound,
-                prompt_len=prompt_len)
+                prompt_len=prompt_len, live=live)
         x = RMSNorm(cfg.norm_eps, name="final_norm")(x)
         return output_head(self, cfg, x, emb)
 

@@ -36,13 +36,13 @@ SCORE_TILE_BYTES = 256 << 20
 NOT_ASKED = "not on a TPU"
 
 
-def _state_once(msg: str) -> None:
+def state_once(msg: str) -> None:
     if msg not in _stated:
         _stated.add(msg)
         logger.info(msg)
 
 
-def _on_tpu() -> bool:
+def on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
@@ -53,15 +53,20 @@ def kernel_refusal(q_shape, k_shape, *, causal: bool = True, window: int = 0,
     takes the kernel: the dispatcher's own rule, for whoever wants to know
     the choice without making the call (llm/engine.py counts the prefill
     rows either way)."""
-    if not (_on_tpu() if use_pallas is None else use_pallas):
+    if not (on_tpu() if use_pallas is None else use_pallas):
         return NOT_ASKED
+    return mesh_refusal() or unsupported_reason(
+        q_shape, k_shape, causal=causal, window=window)
+
+
+def mesh_refusal() -> str | None:
+    """Mosaic refuses to lower a kernel into a program that GSPMD
+    partitions, and nothing here wraps one in a shard_map."""
     devices = math.prod(context_mesh_shape().values())
     if devices > 1:
-        # Mosaic refuses to lower a kernel into a program that GSPMD
-        # partitions; nothing here wraps it in a shard_map
         return (f"a mesh of {devices} devices is in context: the kernel is "
                 f"not partitioned")
-    return unsupported_reason(q_shape, k_shape, causal=causal, window=window)
+    return None
 
 
 def dot_product_attention(q, k, v, *, causal: bool = True, window: int = 0,
@@ -94,7 +99,7 @@ def dot_product_attention(q, k, v, *, causal: bool = True, window: int = 0,
     if reason is None:
         return _kernel_or_xla_grad(q, k, v, q_len, causal, window)
     if reason != NOT_ASKED:
-        _state_once(f"attention: XLA path, O(Sq*Sk) memory ({reason})")
+        state_once(f"attention: XLA path, O(Sq*Sk) memory ({reason})")
     return _xla_form(q, k, v, causal, window)
 
 
@@ -106,13 +111,13 @@ def _xla_form(q, k, v, causal: bool, window: int):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
 def _kernel_or_xla_grad(q, k, v, q_len, causal, window):
-    _state_once("attention: Pallas flash kernel")
+    state_once("attention: Pallas flash kernel")
     return flash_attention(q, k, v, causal=causal, window=window, q_len=q_len)
 
 
 def _kernel_or_xla_grad_fwd(q, k, v, q_len, causal, window):
-    _state_once("attention: XLA path under differentiation (the flash "
-                "kernel has no VJP)")
+    state_once("attention: XLA path under differentiation (the flash "
+               "kernel has no VJP)")
     return jax.vjp(functools.partial(_xla_form, causal=causal, window=window),
                    q, k, v)
 

@@ -1,16 +1,27 @@
 """Decode attention: one new token a sequence against its KV cache.
 
 The serving hot loop is q [B, H, D] attending over a fixed [B, S, KV, D]
-cache, each sequence up to its own length. One implementation, in plain
-JAX, in two forms. `_xla_decode_attention` walks all S rows under a
-per-sequence mask: the program of every caller that knows no bound
-(`LLMEngine.generate`, the pipeline's stages, the tests' references).
-`_xla_decode_walk` stops at a static prefix of the cache that holds the
-longest LIVE sequence (`over_kv_prefix`, from the `kv_bound` the serving
-scheduler hands down) and takes the cache in the engine's own layout, rows
-as wide as the device's tiles. How the two are written decides what the
-TPU's compiler makes of them (PERF.md section 6, PR 29): change either
-only with a chip run beside it.
+cache, each sequence up to its own length. Which form serves where
+(`decode_attention` picks from what it can observe and says so once):
+
+- On a TPU, given a `kv_bound` (the serving scheduler's chunks), with cache
+  rows of whole lane tiles and no mesh of several devices in context:
+  `ragged_decode_attention`, one Pallas kernel for a head of its own and
+  for grouped heads, full leaves and rings. Every slot stops at its OWN
+  length rounded up to a row block, a free slot reads nothing, the leaf is
+  read in the shape and layout it has, and the scores never leave VMEM
+  (PERF.md section 6, PR 37).
+- Given a `kv_bound` anywhere else (the CPU and the tests, a `tp` mesh, a
+  row that is no whole lane tile): the bounded walk in plain JAX,
+  `_xla_decode_walk` and `grouped_walk`, which stops every slot at a static
+  prefix of the cache that holds the longest LIVE sequence
+  (`over_kv_prefix`) and takes the cache in the engine's own layout. How
+  it is written decides what the TPU's compiler makes of it (PERF.md
+  section 6, PR 29): change it only with a chip run beside it.
+  `models/mla.py`'s latent walk calls `over_kv_prefix` itself.
+- Without a bound (`LLMEngine.generate`, the pipeline's stages, the tests'
+  references): `_xla_decode_attention` walks all S rows under a
+  per-sequence mask.
 
 Reference role: vLLM's paged-attention decode kernel (the engine seat
 python/ray/llm delegates; no TPU equivalent exists in the reference).
@@ -18,8 +29,15 @@ python/ray/llm delegates; no TPU equivalent exists in the reference).
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ray_tpu.ops import attention as rule
+from ray_tpu.ops.flash_attention import LANES, auto_block
 
 NEG_INF = float("-inf")
 
@@ -156,21 +174,231 @@ def _xla_decode_walk(q, k_cache, v_cache, lengths, kv_bound):
     return over_kv_prefix(attend, (k_cache, v_cache), kv_bound)
 
 
-def decode_attention(q, k_cache, v_cache, lengths, *, kv_bound=None):
+#: Bytes of K (and as many of V) one grid step of the ragged kernel holds:
+#: the row block is the largest divisor of the leaf's rows at or under this
+#: many bytes of rows (Phi-3's rows of 32 heads of 128: 128 rows; Trinity's
+#: of 4: 1024). A slot's walk is rounded up to it.
+BLOCK_BYTES = 1 << 20
+
+
+def row_block(leaf_shape, dtype) -> int | None:
+    """Rows of one block of the ragged kernel for a leaf `[slots, rows, KV,
+    row]`: derived from the row's bytes, a divisor of `rows` in whole
+    sublane tiles (or all of a short leaf). None: no such divisor."""
+    _, rows, kv, row = leaf_shape
+    most = max(8, BLOCK_BYTES // (kv * row * jnp.dtype(dtype).itemsize))
+    return rows if rows <= most else auto_block(rows, most, 8)
+
+
+def walk_refusal(q_shape, leaf_shape, dtype=jnp.bfloat16) -> str | None:
+    """Why a bounded step of q [B, H, D] over a leaf [B, rows, KV, row]
+    takes the XLA walk, or None when it takes the ragged kernel: the
+    dispatcher's own rule (`ops/attention.py` `kernel_refusal` states the
+    same for the prefill), for whoever wants to know the choice without
+    making the call (llm/engine.py counts the decode steps either way)."""
+    if not rule.on_tpu():
+        return rule.NOT_ASKED
+    (_, hq, d), (_, rows, kv, row) = q_shape, leaf_shape
+    if (reason := rule.mesh_refusal()) is not None:
+        return reason
+    if hq % kv:
+        return f"Hq={hq} not a multiple of Hkv={kv}"
+    if row % LANES or d > row:
+        return f"a cache row of {row} is not whole lane tiles ({LANES})"
+    if row_block(leaf_shape, dtype) is None:
+        return f"{rows} rows have no block in whole sublane tiles"
+    return None
+
+
+def _merged(ref):
+    """The block `[1, T, KV, row]` a ref holds, as ONE matrix `[T x KV,
+    row]`, row t * KV + h of it position t of key/value head h. The two
+    images are the same bytes in VMEM, but a leaf of few heads of a packed
+    dtype lies in tiles smaller than a register's (four bf16 heads: (4,
+    128)), and merged as a value Mosaic shuffles every tile (Trinity's
+    leaves: 520 GB/s against 617, and 1.8 s of compile against 0.3; my chip
+    run, PR 37). Read as 32-bit words, which `KV / 2` rows of words a
+    position are whatever the tile, and unpacked in registers, nothing
+    moves."""
+    _, t, kv, row = ref.shape
+    pack = 4 // ref.dtype.itemsize
+    if pack > 1 and kv % pack == 0:
+        words = ref.bitcast(jnp.uint32).reshape(t * kv // pack, row)[...]
+        return pltpu.bitcast(words, ref.dtype)
+    return ref[0].reshape(t * kv, row)
+
+
+def _ragged_kernel(stop_ref, _slot_ref, _last_ref, q_ref, k_ref, v_ref, o_ref,
+                   m_ref, l_ref, acc_ref, *, scale: float, block: int,
+                   kv: int, group: int, exact: bool):
+    """One (slot, row block) of the grid. q_ref/o_ref [H, row]; k_ref/v_ref
+    [1, block, KV, row] as the leaf holds them; m/l [H, 128] (every lane the
+    same), acc [H, row]. The block's rows of all KV heads are ONE matrix
+    `[block x KV, row]` (merging the two leading axes of the block moves
+    nothing in VMEM), every query head is multiplied against all of it on
+    the MXU, and the mask keeps, for each query head, the visible rows of
+    its own key/value head."""
+    b, j = pl.program_id(0), pl.program_id(1)
+    stop = stop_ref[b]
+    hq = q_ref.shape[0]
+    cols = block * kv
+
+    @pl.when(j == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(j * block < stop)
+    def _block():
+        k, v = _merged(k_ref), _merged(v_ref)
+        s = jax.lax.dot_general(
+            q_ref[...], k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        # column c of the merged matrix is position j * block + c // KV of
+        # key/value head c % KV; query head h reads key/value head
+        # h // group, up to the slot's stop
+        col = jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1)
+        head = jnp.where(col // kv < stop - j * block, col % kv, -1)
+        own = jax.lax.broadcasted_iota(jnp.int32, (hq, 1), 0) // group
+        s = jnp.where(head == own, s, NEG_INF)
+        # (the block holds a visible row, so every head's max is finite)
+        m_prev, l_prev = m_ref[:, 0:1], l_ref[:, 0:1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+        weigh = lambda w: jax.lax.dot_general(  # noqa: E731
+            w, v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        hi = p.astype(v.dtype)
+        pv = weigh(hi)
+        if exact and hi.dtype != p.dtype:
+            # float32 probabilities against V's own dtype, as two products
+            pv = pv + weigh((p - hi.astype(jnp.float32)).astype(v.dtype))
+        acc_ref[...] = acc_ref[...] * alpha + pv
+        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _finish():
+        l = l_ref[:, 0:1]
+        l = jnp.where(l == 0.0, 1.0, l)  # a free slot: zeros
+        o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
+
+
+def ragged_decode_attention(q, k_cache, v_cache, lengths, live=None, *,
+                            interpret: bool = False):
+    """q [B, H, D] against cache leaves [B, rows, KV, row] (row >= D, zeros
+    beyond D), slot b up to `min(lengths[b], rows)` rows (a ring that has
+    wrapped shows all its rows, in whatever order: a softmax does not
+    care), and NO row of a slot `live` [B] bool marks free, whose output is
+    zeros (its device-side length is stale and keeps growing). -> [B, H, D].
+
+    Grid (slot, row block), as many row blocks as the longest live slot
+    shows (the grid's extent is a traced scalar: one program whatever it
+    is), online softmax in float32 VMEM scratch. A slot's stop is
+    prefetched as a scalar. A block at or past it does no work and fetches
+    nothing: its index map names the block the pipeline already holds, the
+    slot's last visible one or, for a free slot, the last live slot's. The
+    leaf is read in the shape and layout it has. bf16 operands, float32
+    sums; the probabilities go to the weighted sum in V's dtype where heads
+    share key/value heads, and as float32 (two products) where each has its
+    own, as the XLA walk of each family does."""
+    (_, hq, d), (_, _, kv, row) = q.shape, k_cache.shape
+    block = row_block(k_cache.shape, k_cache.dtype)
+    if not block or hq % kv or row < d:
+        raise ValueError(f"q {q.shape} against a leaf {k_cache.shape} in "
+                         f"blocks of {block} rows (`walk_refusal` says so "
+                         f"beforehand)")
+    return _ragged(q, k_cache, v_cache, lengths, live, block=block,
+                   interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("block", "interpret"))
+def _ragged(q, k_cache, v_cache, lengths, live, *, block: int,
+            interpret: bool):
+    """`ragged_decode_attention` in row blocks of `block` (`row_block`'s,
+    static: the layers of a model that share a leaf's shape share one
+    trace)."""
+    b, hq, d = q.shape
+    _, rows, kv, row = k_cache.shape
+    n_blocks = rows // block
+    stop = jnp.minimum(lengths.astype(jnp.int32), rows)
+    if live is not None:
+        stop = jnp.where(live, stop, 0)
+    # what the pipeline holds while a free slot's steps pass: the last
+    # block of the nearest live slot before it (slot 0's first, if none)
+    held = jnp.maximum(jax.lax.cummax(jnp.where(
+        stop > 0, jnp.arange(b, dtype=jnp.int32) * n_blocks
+        + (stop - 1) // block, -1)), 0)
+    slot, last = held // n_blocks, held % n_blocks
+    if row > d:
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, row - d)))
+
+    # (left to itself the compiler may stage a whole leaf that fits its
+    # fast memory there ahead of the call, Trinity's rings at 16 slots:
+    # every row of every slot, live or not)
+    in_hbm = (lambda leaf: leaf) if interpret else functools.partial(
+        pltpu.with_memory_space_constraint, memory_space=pltpu.HBM)
+
+    def kv_index(bi, j, stop, slot, last):
+        return (slot[bi],
+                jnp.where(stop[bi] > 0, jnp.minimum(j, last[bi]), last[bi]),
+                0, 0)
+
+    kernel = functools.partial(
+        _ragged_kernel, scale=d ** -0.5, block=block, kv=kv, group=hq // kv,
+        exact=hq == kv)
+    leaf = pl.BlockSpec((1, block, kv, row), kv_index)
+    head = pl.BlockSpec((None, hq, row), lambda bi, j, *_: (bi, 0, 0))
+    out = pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((b, hq, row), q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(b, jnp.maximum(-(-jnp.max(stop) // block), 1)),
+            in_specs=[head, leaf, leaf],
+            out_specs=head,
+            scratch_shapes=[
+                pltpu.VMEM((hq, LANES), jnp.float32),  # max
+                pltpu.VMEM((hq, LANES), jnp.float32),  # denominator
+                pltpu.VMEM((hq, row), jnp.float32),  # accumulator
+            ]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=64 << 20),
+        interpret=interpret,
+    )(stop, slot, last, q, in_hbm(k_cache), in_hbm(v_cache))
+    return out[..., :d]
+
+
+def decode_attention(q, k_cache, v_cache, lengths, *, kv_bound=None,
+                     live=None):
     """One new token a sequence against its cache rows `[0, lengths[b])`
     (all of a ring's rows once `lengths[b]` has passed its length).
     q: [B, H, D]; caches [B, S, KV, D]; lengths [B] -> [B, H, D].
 
-    A `kv_bound` (`over_kv_prefix`: the longest live sequence's rows, from
-    whoever knows which sequences are live) stops the walk at a static
-    prefix of the cache instead of S, and the caches may then come with
-    rows wider than D (zeros beyond it), which are cut with the prefix;
-    `lengths` still masks each sequence inside it. Without one the whole
+    A `kv_bound` (the longest live sequence's rows, from whoever knows
+    which sequences are live) bounds the walk, and the caches may then come
+    with rows wider than D (zeros beyond it). On a TPU the ragged kernel
+    takes such a step (`walk_refusal` is the rule; each choice is stated
+    once at INFO): every slot stops at its own length and a slot that
+    `live` [B] bool marks free reads nothing and returns zeros. Elsewhere
+    the walk stops at a static prefix of the cache (`over_kv_prefix`) and
+    `lengths` masks each sequence inside it. Without a bound the whole
     cache is walked, by the program this always built."""
-    # One name for both forms in a device trace (operation metadata only).
+    # One name for every form in a device trace (operation metadata only).
     with jax.named_scope("decode_attention"):
         if kv_bound is None:
             return _xla_decode_attention(q, k_cache, v_cache, lengths)
+        reason = walk_refusal(q.shape, k_cache.shape, k_cache.dtype)
+        if reason is None:
+            rule.state_once("decode attention: ragged Pallas kernel")
+            return ragged_decode_attention(q, k_cache, v_cache, lengths, live)
+        if reason != rule.NOT_ASKED:
+            rule.state_once(f"decode attention: XLA walk to a quarter "
+                            f"prefix ({reason})")
         if k_cache.shape[2] < q.shape[1]:
             return grouped_walk(q, k_cache, v_cache, lengths, kv_bound)
         return _xla_decode_walk(q, k_cache, v_cache, lengths, kv_bound)

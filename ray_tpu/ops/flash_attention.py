@@ -60,7 +60,7 @@ LANES = 128
 NEG_INF = float("-inf")
 
 
-def _auto_block(dim: int, preferred: int, align: int) -> int | None:
+def auto_block(dim: int, preferred: int, align: int) -> int | None:
     """Largest divisor of `dim` that is a multiple of `align` (TPU sublane/
     lane tiling) and <= `preferred`. None when no aligned divisor exists
     (the dispatcher then takes the XLA path). Auto-deriving from the
@@ -80,12 +80,12 @@ def _block_reasons(sq: int, sk: int, block_q: int | None,
                    block_k: int | None):
     """((block_q, block_k), None) for a [Sq, Sk] problem the kernel can
     tile, else (None, reason)."""
-    bq = _auto_block(sq, block_q or DEFAULT_BLOCK_Q, 8)
+    bq = auto_block(sq, block_q or DEFAULT_BLOCK_Q, 8)
     if bq is None:
         return None, (
             f"Sq={sq} has no divisor aligned to the TPU sublane tile (8)"
             + (f" at or under block_q={block_q}" if block_q else ""))
-    bk = _auto_block(sk, block_k or DEFAULT_BLOCK_K, 128)
+    bk = auto_block(sk, block_k or DEFAULT_BLOCK_K, 128)
     if bk is None:
         # block_k spans the LANE axis of the [block_q, block_k] score
         # tile, so it needs 128-alignment (block_q only needs sublane 8).

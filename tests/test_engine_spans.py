@@ -256,6 +256,66 @@ def test_the_stats_count_prefill_rows_and_which_attention_served_them(
     assert [engine.cache_stats()[k] for k in keys] == [40, 16]
 
 
+@pytest.mark.parametrize("form,name", [("xla", "mha"), ("kernel", "mha"),
+                                       ("xla", "swa"), ("kernel", "swa"),
+                                       ("mixed", "swa")])
+def test_the_chunk_spans_and_the_stats_say_which_attention_served(
+        form, name, spans, monkeypatch):
+    """`attention` on `engine.dispatch_chunk` (`kernel` or `xla`, as
+    `engine.prefill` carries it) and `decode_steps`, `decode_steps_kernel`
+    of `cache_stats` (what `/v1/stats` reports): the decode steps
+    dispatched, and those whose attention is the ragged kernel by the
+    dispatcher's own rule for the model's leaves. With the kernel (forced
+    here, in interpret mode, in blocks of 8 rows) `kv_rows_full` and
+    `kv_rows_window` are what it reads, the live slots' own rows rounded
+    up to the block, as a whole multiple of the block
+    (`benchmark/device_account.py` keys a step's class on them); the XLA
+    walk's are its quarter prefixes, as they were. The rule decides leaf
+    by leaf: a ring of 20 rows has no block in whole sublane tiles and
+    keeps the XLA walk beside full leaves that take the kernel, which the
+    span calls `mixed` and the kernel's counter leaves out."""
+    import dataclasses
+
+    from ray_tpu.ops.decode_attention import kv_prefix_rows
+    from tests.test_ragged_decode import MHA, SWA, on_the_chip  # heads of 128
+
+    block, ring = 8, 20 if form == "mixed" else 16
+    if form != "xla":
+        on_the_chip(monkeypatch, block * 2 * 128 * 4)
+    cfg = {"mha": MHA, "swa": dataclasses.replace(
+        SWA, arch={**SWA.arch, "sliding_window": ring})}[name]
+    eng = ContinuousEngine(cfg, max_batch=2, decode_chunk=4)
+    try:
+        assert eng.cache_stats()["decode_steps"] == 0
+        serve(eng, 3, max_tokens=30)  # (asserts every answer's length)
+        st = eng.cache_stats()
+    finally:
+        eng.shutdown()
+    chunks = [s["at"] for s in spans if s["n"] == "engine.dispatch_chunk"]
+    assert chunks and {at["attention"] for at in chunks} == {form}
+    steps = sum(at["tokens"] for at in chunks)
+    assert st["decode_steps"] == steps
+    assert st["decode_steps_kernel"] == (steps if form == "kernel" else 0)
+    kinds = {"full": 64, **({"window": ring} if name == "swa" else {})}
+    for at in chunks:
+        assert at["kv_rows"] == at["kv_rows_full"]
+        for kind, leaf_rows in kinds.items():
+            walked, live = at["kv_rows_" + kind], at["kv_live_" + kind]
+            if form == "xla" or (form, kind) == ("mixed", "window"):
+                assert walked == kv_prefix_rows(at["kv_bound"], leaf_rows)
+            else:
+                assert walked % block == 0 and 0 < walked <= leaf_rows
+                # a slot's rows rounded up to a block, their mean to a
+                # whole block
+                assert live - block / 2 <= walked <= live + 1.5 * block
+    if form != "xla":
+        # the slots' own lengths, not the longest one's quarter; the
+        # stats' share is the mean as it is: under a block over the live
+        assert len({at["kv_rows_full"] for at in chunks}) > 2
+        assert 0 < st["kv_live_share"] <= st["kv_walk_share"] < (
+            st["kv_live_share"] + block / 64)
+
+
 def test_idle_time_is_carried_by_the_next_recorded_pass(engine, spans):
     serve(engine, 1)
     time.sleep(0.35)  # the scheduler waits with nothing to do

@@ -232,7 +232,8 @@ def test_cache_stats_name_the_layout_and_count_boundary_copies(engine):
                        "kv_live_share", "splices", "splices_in_flight",
                        "pipeline_dry", "cache_kinds", "kv_heads",
                        "prefill_rows", "prefill_rows_kernel",
-                       "sampler_steps", "sampler_steps_select"}
+                       "sampler_steps", "sampler_steps_select",
+                       "decode_steps", "decode_steps_kernel"}
     assert 0.0 <= st["kv_live_share"] <= st["kv_walk_share"] <= 1.0
     # one kind of leaf: every layer keeps max_seq rows a slot
     assert st["kv_heads"] == CFG.n_heads and st["cache_kinds"] == {"full": {

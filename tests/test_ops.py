@@ -100,15 +100,15 @@ def test_flash_attention_explicit_blocks_clamped_numerics():
 def test_flash_attention_auto_blocks():
     """Auto-derived blocks: lane-aligned divisors of Sq/Sk, numerics still
     matching the XLA reference; shapes with no aligned divisor raise."""
-    from ray_tpu.ops.flash_attention import _auto_block
+    from ray_tpu.ops.flash_attention import auto_block
 
-    assert _auto_block(2048, 512, 8) == 512
-    assert _auto_block(2048, 1024, 128) == 1024
-    assert _auto_block(640, 512, 8) == 320
-    assert _auto_block(640, 1024, 128) == 640
-    assert _auto_block(16, 512, 8) == 16
-    assert _auto_block(64, 1024, 128) is None  # < one lane tile
-    assert _auto_block(100, 512, 8) is None  # not sublane-alignable
+    assert auto_block(2048, 512, 8) == 512
+    assert auto_block(2048, 1024, 128) == 1024
+    assert auto_block(640, 512, 8) == 320
+    assert auto_block(640, 1024, 128) == 640
+    assert auto_block(16, 512, 8) == 16
+    assert auto_block(64, 1024, 128) is None  # < one lane tile
+    assert auto_block(100, 512, 8) is None  # not sublane-alignable
     rng = np.random.RandomState(3)
     b, s, h, d = 1, 256, 2, 64
     q = jnp.asarray(rng.randn(b, s, h, d), jnp.float32)

@@ -1,0 +1,260 @@
+"""The ragged decode attention (README "Serving hot loop";
+`ops/decode_attention.py` `ragged_decode_attention`): one Pallas kernel
+that stops every slot at its own length, reads nothing of a free slot, and
+takes the cache leaf `[slots, rows, KV heads, row]` as it lies.
+
+Here, on the CPU in interpret mode (tests/test_ops.py's way): the kernel
+against the dense masked attention over the whole cache for a head of its
+own and for grouped heads, full leaves and rings, the lengths at which a
+block begins and ends, and free rows; the dispatcher's rule; and the engine
+serving the same tokens through the kernel as through the XLA walk. What
+the TPU's compiler makes of it is tests/test_v5e_compile.py's."""
+
+import functools
+import importlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.llm import LLMConfig
+from ray_tpu.llm.engine import ContinuousEngine, SamplingParams
+from ray_tpu.ops import attention
+
+# (the module: `ray_tpu.ops.decode_attention` the attribute may be the
+# function, which the package pins over it on first use)
+da = importlib.import_module("ray_tpu.ops.decode_attention")
+
+ROWS, BLOCK = 64, 16
+
+#: name -> (query heads, key/value heads, head size, cache row)
+FAMILIES = {
+    # Phi-3: a head of its own, 96 wide in rows of 128 (zeros beyond 96)
+    "heads_of_96_in_rows_of_128": (32, 32, 96, 128),
+    # Trinity-Mini: eight query heads a key/value head of 128
+    "32_heads_on_4_of_128": (32, 4, 128, 128),
+}
+
+#: name -> (each slot's length as the device holds it, its `live` mark).
+#: A leaf is a ring where a length can pass its rows: the rows of the last
+#: `rows` positions are all of it, in whatever order.
+CASES = {
+    "full_leaf_one_row_a_blocks_edge_one_past_it_all_rows": (
+        [1, BLOCK, BLOCK + 1, ROWS], [True] * 4),
+    "ring_not_yet_wrapped": ([ROWS - 1, 3 * BLOCK, 2, ROWS - BLOCK + 1],
+                             [True] * 4),
+    "ring_exactly_full": ([ROWS] * 4, [True] * 4),
+    "ring_wrapped_twice": ([2 * ROWS + 5, 2 * ROWS, 3 * ROWS - 1, ROWS + 1],
+                           [True] * 4),
+    "free_row_whose_stale_length_exceeds_the_rows": (
+        [7, 10 * ROWS, BLOCK + 1, ROWS], [True, False, True, True]),
+    "free_rows_first_and_last": ([9 * ROWS, 2 * BLOCK, 5, 4 * ROWS],
+                                 [False, True, True, False]),
+    "all_rows_free": ([5, 10 * ROWS, BLOCK, ROWS], [False] * 4),
+}
+
+
+def on_the_chip(monkeypatch, block_bytes: int) -> None:
+    """The dispatcher's questions answered as the chip would, for an engine
+    here on the CPU: the backend is a TPU (one patch for both dispatchers),
+    the ragged kernel runs in interpret mode in row blocks of `block_bytes`
+    of K (`BLOCK_BYTES`, the one place a block comes from), and the
+    prefill keeps its XLA form (the flash kernel has tests of its own)."""
+    monkeypatch.setattr(attention, "on_tpu", lambda: True)
+    monkeypatch.setattr(attention, "kernel_refusal",
+                        lambda *shapes, **kw: attention.NOT_ASKED)
+    monkeypatch.setattr(da, "ragged_decode_attention", functools.partial(
+        da.ragged_decode_attention, interpret=True))
+    monkeypatch.setattr(da, "BLOCK_BYTES", block_bytes)
+
+
+def leaves(family: str, dtype, poison=()):
+    hq, kv, d, row = FAMILIES[family]
+    keys = jax.random.split(jax.random.PRNGKey(len(family)), 3)
+    q = jax.random.normal(keys[0], (4, hq, d), dtype)
+    wide = ((0, 0),) * 3 + ((0, row - d),)
+    k, v = (jnp.pad(jax.random.normal(key, (4, ROWS, kv, d), dtype), wide)
+            for key in keys[1:])
+    for slot in poison:  # what a free row's stale steps may have left
+        k, v = k.at[slot].set(jnp.nan), v.at[slot].set(jnp.nan)
+    return q, k, v, d
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("case", list(CASES))
+@pytest.mark.parametrize("family", list(FAMILIES))
+def test_the_kernel_reads_each_slots_own_rows_and_none_of_a_free_one(
+        family, case, dtype, monkeypatch):
+    """Against `_xla_decode_attention` over the rows a slot shows
+    (`min(length, rows)`). A free row's cache is never read: filled with
+    NaN here, it leaves its neighbours' outputs as they were, and its own
+    output, which nobody reads, is zeros."""
+    lens, live = CASES[case]
+    free = [i for i, seated in enumerate(live) if not seated]
+    q, k, v, d = leaves(family, dtype, poison=free)
+    # (the block is derived from the row's bytes: BLOCK rows of this leaf)
+    monkeypatch.setattr(da, "BLOCK_BYTES",
+                        BLOCK * k.shape[2] * k.shape[3] * k.dtype.itemsize)
+    assert da.row_block(k.shape, k.dtype) == BLOCK
+    got = da.ragged_decode_attention(
+        q, k, v, jnp.asarray(lens, jnp.int32), jnp.asarray(live),
+        interpret=True)
+    assert got.shape == q.shape and got.dtype == q.dtype
+    got = np.asarray(got, np.float32)
+    assert np.all(got[free] == 0.0)
+    seated = [i for i, s in enumerate(live) if s]
+    if not seated:
+        return
+    shown = jnp.minimum(jnp.asarray(lens, jnp.int32), ROWS)
+    clean = lambda leaf: jnp.nan_to_num(leaf[..., :d])  # noqa: E731
+    want = np.asarray(_xla_f32(q, clean(k), clean(v), shown))
+    # bf16: the output's own rounding, and where heads share key/value
+    # heads the probabilities' too (V's dtype, as the grouped XLA walk)
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(got[seated], want[seated], atol=tol, rtol=0)
+
+
+def _xla_f32(q, k, v, lengths):
+    return da._xla_decode_attention(
+        q.astype(jnp.float32), k.astype(jnp.float32), v.astype(jnp.float32),
+        lengths)
+
+
+def test_without_a_live_mask_every_row_counts_and_the_block_is_derived():
+    """`live` left out (a caller that knows no free rows): every slot
+    shows its own rows. The row block comes from the leaf's row bytes: a
+    short leaf is one block, Phi-3's rows of 32 heads of 128 go 128 a
+    block and Trinity-Mini's of 4 heads 1024, both a MiB of K."""
+    q, k, v, d = leaves("32_heads_on_4_of_128", jnp.float32)
+    lens = jnp.asarray([1, 17, 40, 64], jnp.int32)
+    got = da.ragged_decode_attention(q, k, v, lens, interpret=True)
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(_xla_f32(q, k, v, lens)), atol=2e-5)
+    assert da.row_block(k.shape, k.dtype) == ROWS
+    assert da.row_block((8, 2048, 32, 128), jnp.bfloat16) == 128
+    assert da.row_block((16, 8192, 4, 128), jnp.bfloat16) == 1024
+    assert da.row_block((16, 2048, 4, 128), jnp.bfloat16) == 1024
+    assert da.row_block((2, 8 * 1031, 32, 128), jnp.bfloat16) == 8
+    assert da.row_block((2, 2 * 1031, 32, 128), jnp.bfloat16) is None
+
+
+@pytest.mark.parametrize("name,q,leaf,mesh,why", [
+    ("phi3", (8, 32, 96), (8, 2048, 32, 128), None, None),
+    ("trinity_ring", (16, 32, 128), (16, 2048, 4, 128), None, None),
+    ("rows_of_96", (8, 32, 96), (8, 2048, 32, 96), None, "lane tiles"),
+    ("heads_do_not_group", (8, 6, 128), (8, 256, 4, 128), None, "multiple"),
+    ("no_block", (2, 32, 128), (2, 2 * 1031, 32, 128), None, "no block"),
+    ("tp_mesh", (8, 32, 96), (8, 2048, 32, 128), 2, "not partitioned"),
+])
+def test_the_dispatchers_rule(name, q, leaf, mesh, why, monkeypatch):
+    """`walk_refusal`: off a TPU nothing is asked; on one (the question
+    about the backend answered as the chip would) the kernel takes a leaf
+    of whole lane tiles whose heads group and whose rows have a block,
+    with no mesh of several devices in context."""
+    from jax.sharding import Mesh
+
+    assert da.walk_refusal(q, leaf) == attention.NOT_ASKED
+    monkeypatch.setattr(attention, "on_tpu", lambda: True)
+    if mesh:
+        with Mesh(np.array(jax.devices()[:mesh]), ("tp",)):
+            reason = da.walk_refusal(q, leaf)
+    else:
+        reason = da.walk_refusal(q, leaf)
+    assert (reason is None) if why is None else (why in reason), reason
+
+
+# ---------------------------------------------------------------------------
+# The engine through the kernel.
+
+#: Heads of 128 (a cache row of whole lane tiles without a chip to ask).
+MHA = LLMConfig(vocab_size=128, d_model=256, n_layers=2, n_heads=2,
+                max_seq=64, dtype="float32")
+#: Trinity-Mini's block at a toy size: a window layer (a ring of 16 rows)
+#: and a full one, 4 heads on 2 key/value heads of 128.
+SWA = LLMConfig(
+    vocab_size=128, d_model=64, n_layers=2, n_heads=4, max_seq=64,
+    dtype="float32", experts_held=4,
+    arch={"model_type": "afmoe", "head_dim": 128, "num_key_value_heads": 2,
+          "sliding_window": 16,
+          "layer_types": ["sliding_attention", "full_attention"],
+          "intermediate_size": 96, "moe_intermediate_size": 32,
+          "num_experts": 8, "num_experts_per_tok": 2,
+          "num_shared_experts": 1, "num_dense_layers": 1,
+          "route_scale": 1.5, "route_norm": True, "score_func": "sigmoid",
+          "rms_norm_eps": 1e-5, "rope_theta": 10000.0,
+          "mup_enabled": True, "tie_word_embeddings": False})
+
+
+def run(cfg, prompts, budgets, mesh=None):
+    eng = ContinuousEngine(cfg, max_batch=3, decode_chunk=4, mesh=mesh)
+    try:
+        streams = [eng.submit(p, SamplingParams(max_tokens=m,
+                                                temperature=0.0))
+                   for p, m in zip(prompts, budgets)]
+        return ([s.tokens() for s in streams],
+                {**eng.cache_stats(), "attention": eng._decode_form})
+    finally:
+        eng.shutdown()
+
+
+@pytest.mark.parametrize("name", ["mha", "swa"])
+def test_the_engine_serves_the_same_tokens_through_the_kernel(
+        name, monkeypatch):
+    """Ten greedy requests of mixed lengths on three slots (rows change
+    hands, slots stand free, a ring wraps): the tokens with the kernel
+    forced, in interpret mode, are the XLA walk's, every decode step is
+    counted under the kernel, and the walked share is the live slots' own
+    rows rounded up to the row block."""
+    cfg = {"mha": MHA, "swa": SWA}[name]
+    rng = np.random.default_rng(1)
+    prompts = [rng.integers(1, 128, size=n).tolist()
+               for n in (5, 40, 17, 30, 9, 3, 33, 12, 21, 7)]
+    budgets = [12, 6, 9, 20, 5, 16, 8, 3, 11, 14]
+    want, xla = run(cfg, prompts, budgets)
+    assert xla["decode_steps"] > 0 and xla["decode_steps_kernel"] == 0
+    # blocks of 16 rows of 2 float32 heads of 128, so that a full leaf of
+    # 64 rows is four of them (the ring of 16 rows is one)
+    on_the_chip(monkeypatch, 16 * 2 * 128 * 4)
+    got, kernel = run(cfg, prompts, budgets)
+    assert got == want
+    # (which chunks a run dispatches depends on when its requests arrive:
+    # the two runs' counters are not compared with each other)
+    assert kernel["decode_steps_kernel"] == kernel["decode_steps"] > 0
+    for k in kernel["cache_kinds"].values():
+        # a slot's rows rounded up to a block: under a block more
+        assert k["live_share"] <= k["walk_share"] < (
+            k["live_share"] + 16 / k["rows"])
+    assert kernel["kv_walk_share"] < 1.0
+
+
+def test_an_engine_given_a_tp_mesh_keeps_the_walk_in_what_it_traces(
+        monkeypatch):
+    """Where the chip would take the kernel (heads of 128, the backend
+    question answered as the chip would), an engine given a `tp` mesh
+    traces its decode step with that mesh in context: the rule its counters
+    asked is the rule the trace asks, both keep the XLA walk (Mosaic
+    refuses a kernel in a program GSPMD partitions), the kernel is never
+    called, and the tokens are the unsharded engine's. Without a mesh the
+    same engine takes the kernel."""
+    from jax.sharding import Mesh
+
+    prompts = [list(range(1, n)) for n in (6, 41, 18)]
+    budgets = [9, 6, 12]
+    want, _ = run(MHA, prompts, budgets)
+    on_the_chip(monkeypatch, 16 * 2 * 128 * 4)
+    _, alone = run(MHA, prompts[:1], budgets[:1])
+    assert alone["attention"] == "kernel"
+    assert alone["decode_steps_kernel"] == alone["decode_steps"] > 0
+
+    def never(*args, **kwargs):
+        raise AssertionError("the ragged kernel under a `tp` mesh")
+
+    monkeypatch.setattr(da, "ragged_decode_attention", never)
+    got, sharded = run(MHA, prompts, budgets,
+                       mesh=Mesh(np.array(jax.devices()[:2]), ("tp",)))
+    assert got == want
+    assert sharded["attention"] == "xla"
+    assert sharded["decode_steps"] > 0 == sharded["decode_steps_kernel"]
+

@@ -399,7 +399,7 @@ def test_v5e_prefill_of_4096_rows_keeps_its_scores_in_the_kernel(
                                 on_chip()).compile()
     assert re.search(scores, before.as_text())
     assert "tpu_custom_call" not in before.as_text()
-    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    monkeypatch.setattr(attention, "on_tpu", lambda: True)
     eng = build_compiled(chip, cfg=SWA)
     assert eng._prefill_form(4096) == "kernel"
     after = eng._prefill.lower(eng.params, on_chip(1, 4096),
@@ -427,7 +427,7 @@ def test_v5e_prefill_sharded_over_tp_keeps_the_unpartitioned_kernel_out(
 
     from ray_tpu.ops import attention
 
-    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    monkeypatch.setattr(attention, "on_tpu", lambda: True)
     cfg = LLMConfig(vocab_size=512, d_model=1024, n_layers=2, n_heads=8,
                     max_seq=256, dtype="bfloat16")
     mesh = Mesh(np.array(chips), ("tp",))
@@ -442,32 +442,142 @@ def test_v5e_prefill_sharded_over_tp_keeps_the_unpartitioned_kernel_out(
     assert build_compiled(chips[0], cfg=cfg)._prefill_form(128) == "kernel"
 
 
+def test_v5e_chunk_sharded_over_tp_keeps_the_unpartitioned_kernel_out(
+        chips, monkeypatch):
+    """The chunk program's twin of the prefill's test above: an engine
+    given a `tp` mesh traces its decode step with that mesh in context, so
+    the rule the trace asks (`walk_refusal`) is the rule `_decode_blocks`
+    asked, and both keep the XLA walk: the chunk compiles for four chips
+    over K and V leaves sharded on the head axis, holds no Mosaic call and
+    one `conditional` a layer, and the engine names its steps `xla`. (Traced
+    without the mesh, the ragged kernel went into a program that GSPMD
+    partitions while the counters said `xla`.) The same engine on one chip
+    takes the kernel."""
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from ray_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "on_tpu", lambda: True)
+    cfg = LLMConfig(vocab_size=512, d_model=1024, n_layers=2, n_heads=8,
+                    max_seq=256, dtype="bfloat16")
+    mesh = Mesh(np.array(chips), ("tp",))
+    eng = build_compiled(chips[0], cfg=cfg, mesh=mesh)
+    assert (eng._kernel_blocks, eng._decode_form) == ({}, "xla")
+    everywhere = lambda s: s if getattr(  # noqa: E731
+        s, "sharding", None) is not None or not hasattr(s, "shape") else (
+        jax.ShapeDtypeStruct(s.shape, s.dtype,
+                             sharding=NamedSharding(mesh, P())))
+    shapes = eng._chunk_shapes(eng.params, eng._cache_spec, True)
+    text = eng._chunk.lower(*(jax.tree.map(everywhere, a)
+                              for a in shapes)).compile().as_text()
+    assert "tpu_custom_call" not in text
+    assert len(re.findall(r" conditional\(", text)) == cfg.n_layers
+    one = build_compiled(chips[0], cfg=cfg)
+    assert (one._kernel_blocks, one._decode_form) == ({"full": 256}, "kernel")
+
+
+def moved_rows(eng, text: str, ops: str = "copy|slice") -> list:
+    """The operations of the compiled `text`, outside any fusion, whose
+    RESULT has the dimensions of one of the engine's cache leaves or of
+    any prefix of its rows: `[slots, n, ...rest]`, n <= rows. Such an
+    operation has a buffer of its own: the rows are moved."""
+    found = {}  # by place in the text: a prefix of two kinds of leaf once
+    for shape in {leaf.shape for leaf in jax.tree.leaves(eng._cache_spec)}:
+        slots, leaf_rows, *rest = shape
+        dims = ",".join([str(slots), r"(\d+)"] + [str(d) for d in rest])
+        for m in re.finditer(r"= \w+\[%s\]\S* (%s)\(" % (dims, ops), text):
+            if int(m.group(1)) <= leaf_rows:
+                found[m.start()] = (m.group(2),
+                                    (slots, int(m.group(1)), *rest))
+    return list(found.values())
+
+
 @pytest.mark.parametrize("name", ["kv", "latent", "swa"])
 def test_v5e_bounded_chunk_program_branches_once_a_layer_and_copies_no_rows(
         chip, name):
-    """The chunk program the scheduler dispatches (with a `kv_bound`): one
-    `conditional` a layer, whose branches read their prefix of the cache
-    where it lies. Written as an einsum over a slice, the v5e's compiler
-    fed each branch a transposed COPY of the K prefix, and cutting the
-    latent's rotary columns inside a tile made it copy the whole leaf in
-    every branch (PERF.md section 6, PR 29): no copy of a leaf, whole or
-    any prefix of its rows, may be in the text. Without the bound the
-    program has no `conditional`, as before there was one."""
+    """The chunk program the scheduler dispatches (with a `kv_bound`) in
+    its XLA form (this process is held to the CPU, so the dispatcher asks
+    for no kernel: `latent` has none, `kv` and `swa` take theirs on the
+    chip, below): one `conditional` a layer, whose branches read their
+    prefix of the cache where it lies. Written as an einsum over a slice,
+    the v5e's compiler fed each branch a transposed COPY of the K prefix,
+    and cutting the latent's rotary columns inside a tile made it copy the
+    whole leaf in every branch (PERF.md section 6, PR 29): no copy of a
+    leaf, whole or any prefix of its rows, may be in the text. Nor a
+    `slice` given a buffer of its own, which is how the ledger's traces
+    saw the grouped walk move its prefixes (`slice bf16[16,6144,4,128]`,
+    PERF.md section 6, PRs 33-34) where a search for `copy(` saw nothing:
+    the grouped XLA walk still has them, K and V of every prefix in every
+    layer, and that is what the ragged kernel took off the chip's path (PR
+    37).
+    Without the bound the program has no `conditional`, as before there
+    was one."""
     from ray_tpu.ops.decode_attention import kv_prefixes
 
     cfg = {"kv": CFG, "latent": LATENT, "swa": SWA}[name]
     eng = build_compiled(chip, cfg=cfg)
     assert eng.cache_boundary_copies == 0  # whole leaves, branches included
+    assert eng._kernel_blocks == {}
     # (the greedy program: the sampled one's sampler has branches of its own)
     shapes = eng._chunk_shapes(eng.params, eng._cache_spec, True)
     assert shapes[-2].shape == () and shapes[-2].dtype == jnp.int32
     text = eng._chunk.lower(*shapes).compile().as_text()
     assert len(re.findall(r" conditional\(", text)) == cfg.n_layers
-    for leaf in jax.tree.leaves(eng._cache_spec):
-        slots, leaf_rows, *rest = leaf.shape  # max_seq, or a ring's
-        for rows in kv_prefixes(leaf_rows):
-            dims = ",".join([str(slots), str(rows)] + [r"\d+"] * len(rest))
-            assert not re.findall(r"= \w+\[%s\]\S* copy\(" % dims, text), (
-                leaf.shape, rows)
+    assert "tpu_custom_call" not in text
+    moved = moved_rows(eng, text)
+    assert not [m for m in moved if m[0] == "copy"], moved
+    if name == "swa":
+        # K and V of every prefix short of the whole, in each of the
+        # three window layers (rings of 2048 rows) and in the full one
+        assert sorted(moved) == sorted(
+            ("slice", (MAX_BATCH, rows, 4, 128))
+            for leaf_rows, layers in ((2048, 3), (8192, 1))
+            for rows in kv_prefixes(leaf_rows) if rows < leaf_rows
+            for _leaf in range(2 * layers)), moved
+    else:
+        assert not moved, moved
     unbounded = eng._chunk.lower(*shapes[:-2]).compile().as_text()
     assert " conditional(" not in unbounded
+
+
+@pytest.mark.parametrize("name", ["kv", "swa"])
+def test_v5e_chunk_program_with_the_ragged_kernel_moves_no_rows(
+        chip, name, monkeypatch):
+    """On the chip (the dispatcher's question about the backend answered
+    as the chip would) the bounded step of the `mha` family is the ragged
+    kernel: ONE Mosaic call a layer in the step's body, handed the leaf as
+    it lies; no `conditional` for attention; no loop inside the step (the
+    chunk's own `while` is the only one: `trace_reduce.loop_steps` counts
+    an operation NAME's starts inside a `jit_chunk` execution, and a Pallas
+    call is one `custom-call` a layer a step whose grid lives inside
+    Mosaic); and outside the fusions no `copy`, `slice`, `transpose` or
+    `bitcast-convert` whose result has a leaf's dimensions or any prefix of
+    its rows. The engine counts its decode steps under the kernel."""
+    from ray_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "on_tpu", lambda: True)
+    cfg = {"kv": CFG, "swa": SWA}[name]
+    eng = build_compiled(chip, cfg=cfg)
+    assert eng.cache_boundary_copies == 0
+    assert eng._kernel_blocks == {
+        "kv": {"full": 256},  # a short leaf of 4 heads: one block
+        "swa": {"full": 1024, "window": 1024}}[name]
+    text = eng._chunk.lower(*eng._chunk_shapes(
+        eng.params, eng._cache_spec, True)).compile().as_text()
+    assert len(re.findall(r" custom-call\(.*tpu_custom_call", text)) \
+        == cfg.n_layers
+    assert " conditional(" not in text
+    assert len(re.findall(r" while\(", text)) == 1
+    assert not moved_rows(eng, text,
+                          "copy|slice|transpose|bitcast-convert"), name
+    # K and V go into the call as the step's own update of the leaf left
+    # them, in HBM: the compiler stages no leaf in its fast memory ahead of
+    # the call (left to itself it copied Trinity's rings whole, free slots
+    # and all: `copy-start` / `copy-done` of `[slots, 2048, 4, 128]`)
+    for line in re.findall(r" custom-call\((.*?)\), custom_call_target="
+                           r"\"tpu_custom_call\"", text):
+        # (the grid's extent, stop, slot, last, q, K, V)
+        *_, k, v = line.split(", ")
+        assert "copy" not in k and "copy" not in v, line
+    assert not moved_rows(eng, text, "copy-start|copy-done"), name
