@@ -42,6 +42,26 @@ MODEL = dict(vocab_size=32000, d_model=1024, n_layers=8, n_heads=8,
              max_seq=1024, dtype="bfloat16")
 MAX_BATCH = 8
 DECODE_CHUNK = 16
+#: Kimi K2's latent attention at its published latent and head widths (a
+#: row of 512 + 64 values a token, which the engine widens to 640), 16
+#: heads, two layers, the second with experts: the model whose bounded
+#: decode steps read the cache through the latent kernel
+#: (ops/decode_attention.py `ragged_latent_attention`).
+LATENT_MODEL = dict(
+    vocab_size=32000, d_model=1024, n_layers=2, n_heads=16, max_seq=2048,
+    dtype="bfloat16", experts_held=4,
+    arch={"model_type": "kimi_k2", "intermediate_size": 2048,
+          "q_lora_rank": 512, "kv_lora_rank": 512, "qk_nope_head_dim": 128,
+          "qk_rope_head_dim": 64, "v_head_dim": 128,
+          "moe_intermediate_size": 512, "n_routed_experts": 16,
+          "n_shared_experts": 1, "num_experts_per_tok": 4,
+          "first_k_dense_replace": 1, "norm_topk_prob": True,
+          "routed_scaling_factor": 2.827, "scoring_func": "sigmoid",
+          "rms_norm_eps": 1e-5, "rope_theta": 50000,
+          "rope_scaling": {"type": "yarn", "factor": 64, "beta_fast": 32,
+                           "beta_slow": 1, "mscale": 1, "mscale_all_dim": 1,
+                           "original_max_position_embeddings": 4096},
+          "tie_word_embeddings": False})
 #: Programs of the serving path, as their compile-cache entries are named.
 SERVING_PROGRAMS = ("jit_prefill", "jit_chunk", "jit_place", "jit_sample1")
 #: A served greedy token may differ from the argmax of the cache-free
@@ -73,6 +93,12 @@ def require(cond: bool, msg: str) -> None:
 
 
 # ------------------------------------------------------------------ requests
+def free_port() -> int:
+    with socket.socket() as s:
+        s.bind(("127.0.0.1", 0))
+        return s.getsockname()[1]
+
+
 def _prompts() -> tuple[list[int], list[int]]:
     """A 100-token and a 200-token prompt: prefill buckets 128 and 256."""
     rng = random.Random(0)
@@ -217,8 +243,12 @@ class ChipProbe:
         decode kernel (a bounded decode step's attention) against the
         whole-cache walk `_xla_decode_attention`: at Phi-3's leaf (heads of
         96 in rows of 128) and at Trinity-Mini's full leaf and ring, with
-        ragged lengths, a wrapped ring and free rows. An exception from a
-        kernel is reported in its row, and the smoke fails on it."""
+        ragged lengths, a wrapped ring and free rows. The latent kernel
+        against `models/mla.py` `_latent_attention` over the whole leaf, at
+        Kimi K2's and Kimi Linear's cells' shapes (32 slots of 64 heads, 64
+        of 32, on 4096 rows of 640), ragged lengths and free rows. An
+        exception from a kernel is reported in its row, and the smoke fails
+        on it."""
         import time
         import traceback
 
@@ -226,9 +256,12 @@ class ChipProbe:
         import jax.numpy as jnp
         import numpy as np
 
+        from ray_tpu.models.mla import _latent_attention
         from ray_tpu.ops.attention import kernel_refusal, prefill_attention
         from ray_tpu.ops.decode_attention import (_xla_decode_attention,
+                                                  latent_refusal,
                                                   ragged_decode_attention,
+                                                  ragged_latent_attention,
                                                   walk_refusal)
         from ray_tpu.ops.flash_attention import flash_attention
 
@@ -295,8 +328,32 @@ class ChipProbe:
                             jnp.minimum(lens, rows_)).reshape(b, -1), 0),
                     h * d)
 
+        # Latent rows: Kimi K2's cell and Kimi Linear's.
+        rank, width, row, scale = 512, 576, 640, 0.1147
+        for b, h in [(32, 64), (64, 32)]:
+            ks = jax.random.split(jax.random.PRNGKey(b + h), 3)
+            q = jax.random.normal(ks[0], (b, h, width), jnp.bfloat16)
+            leaf = jnp.pad(
+                jax.random.normal(ks[1], (b, 4096, width), jnp.bfloat16),
+                ((0, 0), (0, 0), (0, row - width)))
+            lens = jax.random.randint(ks[2], (b,), 1, 4097)
+            live = jnp.arange(b) % 4 != 1
+            compare(f"ragged_latent_attention b{b} rows4096 h{h} on a row "
+                    f"of {row}",
+                    lambda: ragged_latent_attention(
+                        q, leaf, lens, live, rank=rank,
+                        scale=scale).reshape(b, -1),
+                    lambda: jnp.where(
+                        live[:, None], _latent_attention(
+                            q[..., :rank], q[:, None, :, rank:], leaf,
+                            lens[:, None] - 1, rank, width,
+                            scale).reshape(b, -1), 0),
+                    h * rank)
+
         return {"platform": dev.platform, "device_kind": dev.device_kind,
                 "rows": rows,
+                "latent_kernel_refusal": latent_refusal(
+                    (32, 4096, row), rank),
                 "prefill_kernel_refusal": kernel_refusal(
                     (1, 128, heads, hd), (1, 128, heads, hd)),
                 "decode_kernel_refusal": walk_refusal(
@@ -322,9 +379,7 @@ def preflight() -> int:
 def serve_phase(serve, LLMConfig, build_openai_app, chips: int) -> dict:
     """Deploy, warm up, answer a wave of requests. Returns the replicas'
     last /v1/stats and the served greedy (prompt, tokens) pairs."""
-    with socket.socket() as s:
-        s.bind(("127.0.0.1", 0))
-        port = s.getsockname()[1]
+    port = free_port()
     base = f"http://127.0.0.1:{port}"
     wave = wave_requests(chips)
     app = build_openai_app(
@@ -435,6 +490,50 @@ def serve_phase(serve, LLMConfig, build_openai_app, chips: int) -> dict:
             "greedy": [(list(p), list(t)) for p, t in sorted(greedy)]}
 
 
+def latent_phase(serve, LLMConfig, build_openai_app) -> None:
+    """One replica of LATENT_MODEL: greedy requests of mixed lengths, more
+    than it has slots, so that slots of different lengths decode together
+    and stand free in between; the line `/v1/stats` gives of it."""
+    port = free_port()
+    base = f"http://127.0.0.1:{port}"
+    rng = random.Random(1)
+    vocab = LATENT_MODEL["vocab_size"]
+    reqs = [_request([rng.randrange(vocab) for _ in range(n)], m, 0.0,
+                     i % 2 == 0)
+            for i, (n, m) in enumerate([(100, 33), (900, 17), (200, 48),
+                                        (1500, 24), (40, 40), (600, 9),
+                                        (300, 31), (1100, 20), (70, 16),
+                                        (450, 28)])]
+    serve.run(build_openai_app(
+        LLMConfig(**LATENT_MODEL), num_replicas=1, max_batch=MAX_BATCH,
+        decode_chunk=DECODE_CHUNK, ray_actor_options={"num_tpus": 1},
+        max_ongoing_requests=len(reqs)), port=port, timeout_s=left(300))
+    with concurrent.futures.ThreadPoolExecutor(len(reqs)) as pool:
+        futs = [pool.submit(complete, base, r, left(300)) for r in reqs]
+        check_outputs(reqs, [f.result(timeout=left(310)) for f in futs])
+    (st,) = replica_stats(base, 1, left(60))
+    block = 512 / LATENT_MODEL["max_seq"]  # (a MiB of rows of 640)
+    say(f"latent replica pid={st['pid']} {st['cache_kind']} cache "
+        f"{st['cache_layout']}; {st['cache_boundary_copies']} whole-leaf "
+        f"copies in its chunk program; {st['decode_steps']} decode steps, "
+        f"{st['decode_steps_kernel']} of them through the latent kernel; "
+        f"they walked {st['kv_walk_share']:.3f} of max_seq (live rows "
+        f"{st['kv_live_share']:.3f})")
+    require(st["cache_kind"] == "latent" and ", 640]" in st["cache_layout"],
+            f"not a latent cache in rows of 640: {st['cache_layout']}")
+    require(st["decode_steps_kernel"] == st["decode_steps"] > 0,
+            f"latent replica: {st['decode_steps_kernel']} of "
+            f"{st['decode_steps']} decode steps went through the latent "
+            f"kernel")
+    require(st["kv_live_share"] <= st["kv_walk_share"]
+            < st["kv_live_share"] + block,
+            f"latent replica: walked {st['kv_walk_share']:.3f} of max_seq, "
+            f"over a block beyond the live rows' {st['kv_live_share']:.3f}")
+    require(st["cache_boundary_copies"] == 0,
+            f"latent replica: the chunk program copies whole cache leaves "
+            f"{st['cache_boundary_copies']} times")
+
+
 def chip_phase(ray_tpu, greedy: list) -> None:
     probe = ray_tpu.remote(num_cpus=0, num_tpus=1)(ChipProbe).remote()
     try:
@@ -461,6 +560,10 @@ def chip_phase(ray_tpu, greedy: list) -> None:
         + ("the ragged Pallas kernel"
            if rep["decode_kernel_refusal"] is None
            else f"the XLA walk ({rep['decode_kernel_refusal']})")
+        + (", and a latent model's through the latent kernel"
+           if rep["latent_kernel_refusal"] is None
+           else f", and a latent model's through its own XLA walk "
+                f"({rep['latent_kernel_refusal']})")
         + "; a prefill goes through dot_product_attention, which for this "
         "model's buckets chooses "
         + ("the Pallas flash kernel"
@@ -534,6 +637,9 @@ def main() -> int:
         served = phase("serve", lambda: serve_phase(
             serve, LLMConfig, build_openai_app, chips))
         phase("serve shutdown", serve.shutdown)
+        phase("serve a latent model", lambda: latent_phase(
+            serve, LLMConfig, build_openai_app))
+        phase("latent shutdown", serve.shutdown)
         dead = [n["NodeID"][:8] for n in ray_tpu.nodes() if not n["Alive"]]
         if dead:
             failed.append("nodes")

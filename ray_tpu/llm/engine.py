@@ -31,9 +31,9 @@ engine workers via vllm_models.py:123-137). TPU-native design:
   things only the scheduler knows and hands each chunk: `kv_bound`, the
   rows its longest LIVE slot will have, and `live`, which rows of the
   batch have an occupant (`_run_scheduler`). On a TPU a ragged kernel
-  reads each live slot's own K and V rows and nothing of a free one;
-  elsewhere, and for latent rows, the step reads a static prefix of the
-  slot cache chosen inside the program from `kv_bound`.
+  reads each live slot's own rows (K and V, or latent rows) and nothing of
+  a free one; elsewhere the step reads a static prefix of the slot cache
+  chosen inside the program from `kv_bound`.
 - **In-graph sampling**: temperature / top-k / top-p / greedy are
   vectorized per-slot inside the compiled step (each slot carries its own
   sampling params and PRNG key), so mixed request settings share a batch.
@@ -876,9 +876,9 @@ class ContinuousEngine:
         self._moe_cols = -(-self._moe_held // self.max_batch)
         self.moe_rows_total = 0
         # The decode steps dispatched since start, those whose attention
-        # over K and V rows is the ragged kernel (`_decode_blocks`), the
-        # cache rows they walked a slot, and the rows a live slot had on
-        # average (`cache_stats`: kv_walk_share, kv_live_share).
+        # over K and V or latent rows is a ragged kernel (`_decode_blocks`),
+        # the cache rows they walked a slot, and the rows a live slot had
+        # on average (`cache_stats`: kv_walk_share, kv_live_share).
         self.decode_steps = 0
         self.decode_steps_kernel = 0
         self._kv_walked = {"full": 0, "window": 0}
@@ -1424,28 +1424,43 @@ class ContinuousEngine:
         return self._prefill_form_of[bucket]
 
     def _decode_blocks(self) -> tuple[dict, str]:
-        """The ragged kernel's row block by kind of rows leaf (`full`,
-        `window`) whose `mha` layers' bounded decode step takes the kernel
-        (a kind the dispatcher refuses is left out, and {} is the XLA walk
+        """The ragged kernels' row block by kind of rows leaf (`full`,
+        `window`) whose layers' bounded decode step takes a kernel (a kind
+        the dispatcher refuses is left out, and {} is the XLA walk
         throughout), and the name of that: `kernel`, `xla`, `mixed`. The
-        dispatcher's own rule (`ops/decode_attention.py`
-        `walk_refusal`, which decides leaf by leaf) put to the leaves the
-        chunk program is traced with, under the mesh it is traced under.
-        Nothing is read back from the device. A latent layer walks its rows
-        itself (`models/mla.py`)."""
+        dispatchers' own rules (`ops/decode_attention.py`: `walk_refusal`
+        for the K and V leaves of `mha` layers, `latent_refusal` for the
+        latent leaf of `mla` layers, each deciding leaf by leaf) put to the
+        leaves the chunk program is traced with, under the mesh it is
+        traced under. Nothing is read back from the device. (No model has
+        rows leaves of both families.)"""
         import jax
 
-        from ray_tpu.ops.decode_attention import row_block, walk_refusal
+        from ray_tpu.ops.decode_attention import (latent_block,
+                                                  latent_refusal, row_block,
+                                                  walk_refusal)
 
         mcfg = self.model.cfg
-        leaves = {self._kind_of[f"layer_{i}"]:
-                  jax.tree.leaves(self._cache_spec[f"layer_{i}"])[0]
-                  for i in range(mcfg.n_layers) if mcfg.mixer_of(i) == "mha"}
         q = (self.max_batch, mcfg.n_heads, mcfg.head_dim)
+
+        def block_of(mixer, leaf):
+            """The leaf's row block, None where its rule refuses it."""
+            if mixer == "mla":
+                refused = latent_refusal(leaf.shape, mcfg.kv_lora_rank,
+                                         leaf.dtype)
+                return None if refused else latent_block(leaf.shape,
+                                                         leaf.dtype)
+            refused = walk_refusal(q, leaf.shape, leaf.dtype)
+            return None if refused else row_block(leaf.shape, leaf.dtype)
+
+        leaves = {self._kind_of[f"layer_{i}"]: (
+                      mcfg.mixer_of(i),
+                      jax.tree.leaves(self._cache_spec[f"layer_{i}"])[0])
+                  for i in range(mcfg.n_layers)
+                  if mcfg.mixer_of(i) in ("mha", "mla")}
         with self._mesh_scope():
-            blocks = {kind: row_block(leaf.shape, leaf.dtype)
-                      for kind, leaf in leaves.items()
-                      if walk_refusal(q, leaf.shape, leaf.dtype) is None}
+            blocks = {kind: block_of(*leaf) for kind, leaf in leaves.items()}
+        blocks = {kind: block for kind, block in blocks.items() if block}
         return blocks, ("xla" if not blocks else "kernel"
                         if len(blocks) == len(leaves) else "mixed")
 

@@ -31,7 +31,9 @@ import jax.numpy as jnp
 
 from ray_tpu.models.layers import (RMSNorm, rope_cos_sin_scale, rope_interleaved,
                                    rope_inv_freq, yarn_mscale)
-from ray_tpu.ops.decode_attention import over_kv_prefix
+from ray_tpu.ops import attention as rule
+from ray_tpu.ops.decode_attention import (latent_refusal, over_kv_prefix,
+                                          ragged_latent_attention)
 
 #: Most bytes of float32 scores one tile of queries may hold in the expanded
 #: path (heads x tile x keys x 4).
@@ -52,7 +54,8 @@ class MLA(nn.Module):
     cfg: "TransformerConfig"  # noqa: F821 - models/transformer.py
 
     @nn.compact
-    def __call__(self, x, positions, decode: bool = False, kv_bound=None):
+    def __call__(self, x, positions, decode: bool = False, kv_bound=None,
+                 live=None):
         cfg = self.cfg
         heads, rank = cfg.n_heads, cfg.kv_lora_rank
         nope, rot, vdim = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
@@ -91,7 +94,7 @@ class MLA(nn.Module):
             scale = softmax_scale(cfg)
             if decode:
                 out = self._cached(q_nope, q_rope, c_kv, k_rope, wk_b, wv_b,
-                                   positions, scale, kv_bound)
+                                   positions, scale, kv_bound, live)
             else:
                 out = _expanded(q_nope, q_rope, c_kv, k_rope, wk_b, wv_b,
                                 positions, scale)
@@ -100,14 +103,19 @@ class MLA(nn.Module):
                                param_dtype=cfg.param_dtype)(out)
 
     def _cached(self, q_nope, q_rope, c_kv, k_rope, wk_b, wv_b, positions,
-                scale, kv_bound=None):
+                scale, kv_bound=None, live=None):
         """Serving: one cache leaf `[slots, max_seq, row]` a layer, each
         sequence's rows written at its own absolute positions (as
         `Attention._cached_attention` writes K and V). A single-token step
         attends in the latent space over the sequence's rows up to its
-        position, or, given `kv_bound`, over the shortest static prefix of
-        them that holds that many (`ops/decode_attention.py`
-        `over_kv_prefix`: a latent row is the one-KV-head case); a
+        position; given `kv_bound`, on a TPU through the ragged kernel,
+        which reads the rows of each slot that `live` ([B] bool; None:
+        all) marks occupied up to its own position and nothing else
+        (`ops/decode_attention.py` `ragged_latent_attention`;
+        `latent_refusal` is the rule, and each choice is stated once at
+        INFO), and elsewhere over the shortest static prefix of the rows
+        that holds `kv_bound` of them (`over_kv_prefix`: a latent row is
+        the one-KV-head case); a
         multi-token step is a prefill from position 0 and
         attends over its own rows, expanded, and only writes the latents.
         `row` is `kv_lora_rank + qk_rope_head_dim`, or wider when the
@@ -134,7 +142,17 @@ class MLA(nn.Module):
             if kv_bound is None:
                 o_lat = _latent_attention(q_lat, q_rope, cache.value, pos,
                                           rank, width, scale)
+            elif (reason := latent_refusal(cache.value.shape, rank,
+                                           cache.value.dtype)) is None:
+                rule.state_once("latent decode attention: ragged Pallas "
+                                "kernel")
+                o_lat = ragged_latent_attention(
+                    jnp.concatenate([q_lat, q_rope[:, 0]], axis=-1),
+                    cache.value, pos[:, 0] + 1, live, rank=rank, scale=scale)
             else:
+                if reason != rule.NOT_ASKED:
+                    rule.state_once(f"latent decode attention: XLA walk to "
+                                    f"a quarter prefix ({reason})")
                 o_lat = _latent_walk(q_lat, q_rope, cache.value, pos,
                                      kv_bound, rank, width, scale)
             out = jnp.einsum("bhc,hcv->bhv", o_lat, wv_b)

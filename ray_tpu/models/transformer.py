@@ -303,8 +303,8 @@ class Block(nn.Module):
         cfg = self.cfg
         norm = lambda name: RMSNorm(cfg.norm_eps, name=name)  # noqa: E731
         if self.mixer == "mla":
-            a = MLA(cfg, name="attn")(norm("attn_norm")(x), positions,
-                                      decode=decode, kv_bound=kv_bound)
+            a = MLA(cfg, name="attn")(norm("attn_norm")(x), positions, decode,
+                                      kv_bound=kv_bound, live=live)
         elif self.mixer == "kda":
             # (a state is read and written whole: no notice of `kv_bound`)
             a = KDA(cfg, name="attn")(norm("attn_norm")(x), decode=decode,

@@ -18,7 +18,7 @@ cache, each sequence up to its own length. Which form serves where
   (`over_kv_prefix`) and takes the cache in the engine's own layout. How
   it is written decides what the TPU's compiler makes of it (PERF.md
   section 6, PR 29): change it only with a chip run beside it.
-  `models/mla.py`'s latent walk calls `over_kv_prefix` itself.
+  Latent rows (`models/mla.py`) have a kernel and a walk of their own.
 - Without a bound (`LLMEngine.generate`, the pipeline's stages, the tests'
   references): `_xla_decode_attention` walks all S rows under a
   per-sequence mask.
@@ -402,3 +402,170 @@ def decode_attention(q, k_cache, v_cache, lengths, *, kv_bound=None,
         if k_cache.shape[2] < q.shape[1]:
             return grouped_walk(q, k_cache, v_cache, lengths, kv_bound)
         return _xla_decode_walk(q, k_cache, v_cache, lengths, kv_bound)
+
+
+# ---------------------------------------------------------------------------
+# Latent rows (models/mla.py): one "head" of `[c_kv | k_r | 0]` a position,
+# key and value both. A path of its own beside the `mha` family's above,
+# with which it shares the block's size (`row_block`) and `rule` and nothing
+# whose text would have to bend: K and V as ONE operand against two, one
+# head against grouped heads merged into one matrix, a row of 640 against
+# 128 (PERF.md section 6, PR 41).
+
+
+def latent_block(leaf_shape, dtype) -> int | None:
+    """Rows of one block of the latent kernel for a leaf `[slots, rows,
+    row]`: `row_block`'s, a latent row being its one-head case (Kimi's 4096
+    rows of 640 bf16 values: 512 rows). None: no such divisor."""
+    slots, rows, row = leaf_shape
+    return row_block((slots, rows, 1, row), dtype)
+
+
+def latent_refusal(leaf_shape, rank: int, dtype=jnp.bfloat16) -> str | None:
+    """Why a bounded step over a latent leaf `[slots, rows, row]` whose
+    first `rank` values are `c_kv` takes the XLA walk (`models/mla.py`
+    `_latent_walk`), or None when it takes `ragged_latent_attention`: the
+    rule `models/mla.py` asks, for whoever wants to know the choice without
+    making the call (llm/engine.py counts the decode steps either way). A
+    row as the model has it (576 values) is refused: the engine asks the
+    compiler first and widens it (`_probe_cache_row`: 640)."""
+    if not rule.on_tpu():
+        return rule.NOT_ASKED
+    if (reason := rule.mesh_refusal()) is not None:
+        return reason
+    _, rows, row = leaf_shape
+    if row % LANES or rank % LANES:
+        return (f"a latent row of {row} with {rank} of c_kv is not whole "
+                f"lane tiles ({LANES})")
+    if latent_block(leaf_shape, dtype) is None:
+        return f"{rows} rows have no block in whole sublane tiles"
+    return None
+
+
+def _latent_kernel(stop_ref, slot_ref, at_ref, _held_ref, q_ref, c_ref,
+                   o_ref, m_ref, l_ref, acc_ref, *, scale: float, block: int,
+                   rank: int):
+    """One entry of the grid: row block `at_ref[i]` of slot `slot_ref[i]`.
+    q_ref [H, row], the query in the latent space beside its rotary part,
+    zeros beyond; c_ref [1, block, row], fetched ONCE and read as keys (the
+    whole row) and as values (its first `rank` lanes); o_ref [H, rank];
+    m/l [H, 128] (every lane the same), acc [H, rank]. The heads are the
+    rows of both products."""
+    i = pl.program_id(0)
+    j = at_ref[i]
+    stop = stop_ref[slot_ref[i]]
+
+    @pl.when(j == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(j * block < stop)
+    def _block():
+        s = jax.lax.dot_general(
+            q_ref[...], c_ref[0], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        col = jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
+        s = jnp.where(col < stop - j * block, s, NEG_INF)
+        # (the block holds a visible row, so every head's max is finite)
+        m_prev, l_prev = m_ref[:, 0:1], l_ref[:, 0:1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+        # the probabilities in the cache's dtype, as `_latent_attention`
+        pv = jax.lax.dot_general(
+            p.astype(c_ref.dtype), c_ref[0, :, :rank],
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        acc_ref[...] = acc_ref[...] * alpha + pv
+        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+
+    @pl.when((j + 1) * block >= stop)  # the slot's last entry
+    def _finish():
+        l = l_ref[:, 0:1]
+        l = jnp.where(l == 0.0, 1.0, l)  # a free slot: zeros
+        o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
+
+
+def ragged_latent_attention(q, latents, lengths, live=None, *, rank: int,
+                            scale: float, interpret: bool = False):
+    """q [B, H, width] (`[q_lat | q_rope]`, `width` <= row) against a
+    latent leaf [B, rows, row] (zeros beyond `width`), slot b up to
+    `lengths[b]` rows and NO row of a slot `live` [B] bool marks free,
+    whose output is zeros -> the weighted sums of the rows' first `rank`
+    values, [B, H, rank]: `models/mla.py` `_latent_attention`'s result.
+
+    The grid is ONE list of the row blocks that hold a visible row, slot
+    after slot, its length a traced scalar (one program whatever it is):
+    no entry passes without work but a free slot's one, which fetches
+    nothing (it names the block the pipeline already holds) and writes the
+    zeros. (In a grid of (slot, row block) as long as the longest slot's,
+    `ragged_decode_attention`'s, three entries in five of these cells'
+    lengths are past their slot's stop, at a third of a microsecond each:
+    my chip run, PR 41.) Online softmax in float32 VMEM scratch; the leaf
+    is read where it lies, each block once. bf16 operands, float32 sums;
+    the scores never leave VMEM."""
+    block = latent_block(latents.shape, latents.dtype)
+    if not block or latents.shape[2] < q.shape[2] or rank > q.shape[2]:
+        raise ValueError(f"q {q.shape} against a latent leaf "
+                         f"{latents.shape} in blocks of {block} rows "
+                         f"(`latent_refusal` says so beforehand)")
+    return _ragged_latent(q, latents, lengths, live, rank=rank, scale=scale,
+                          block=block, interpret=interpret)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("rank", "scale", "block", "interpret"))
+def _ragged_latent(q, latents, lengths, live, *, rank: int, scale: float,
+                   block: int, interpret: bool):
+    """`ragged_latent_attention` in row blocks of `block` (`latent_block`'s,
+    static: the latent layers of a model share one trace)."""
+    b, hq, width = q.shape
+    _, rows, row = latents.shape
+    n_blocks = rows // block
+    stop = jnp.minimum(lengths.astype(jnp.int32), rows)
+    if live is not None:
+        stop = jnp.where(live, stop, 0)
+    # The grid's entries, as three tables a scalar prefetch carries: whose
+    # entry it is, which of its row blocks, and the block to hold while it
+    # passes, `slot * n_blocks + block`: its own, or for a free slot's
+    # entry the one before it (the first of all, if none). Entries past
+    # the grid's length are never looked at.
+    need = jnp.maximum(-(-stop // block), 1)
+    ends = jnp.cumsum(need)
+    entry = jnp.arange(b * n_blocks, dtype=jnp.int32)
+    slot = jnp.minimum(jnp.sum(entry[:, None] >= ends[None, :], axis=1,
+                               dtype=jnp.int32), b - 1)
+    at = entry - (ends - need)[slot]
+    held = jnp.maximum(jax.lax.cummax(jnp.where(
+        stop[slot] > 0, slot * n_blocks + at, -1)), 0)
+    if row > width:
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, row - width)))
+
+    per_slot = lambda i, stop, slot, *_: (slot[i], 0, 0)  # noqa: E731
+    leaf = latents if interpret else pltpu.with_memory_space_constraint(
+        latents, memory_space=pltpu.HBM)
+    return pl.pallas_call(
+        functools.partial(_latent_kernel, scale=scale, block=block,
+                          rank=rank),
+        out_shape=jax.ShapeDtypeStruct((b, hq, rank), q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(ends[-1],),
+            in_specs=[pl.BlockSpec((None, hq, row), per_slot),
+                      pl.BlockSpec(
+                          (1, block, row), lambda i, stop, slot, at, held: (
+                              held[i] // n_blocks, held[i] % n_blocks, 0))],
+            out_specs=pl.BlockSpec((None, hq, rank), per_slot),
+            scratch_shapes=[
+                pltpu.VMEM((hq, LANES), jnp.float32),  # max
+                pltpu.VMEM((hq, LANES), jnp.float32),  # denominator
+                pltpu.VMEM((hq, rank), jnp.float32),  # accumulator
+            ]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=64 << 20),
+        interpret=interpret,
+    )(stop, slot, at, held, q, leaf)

@@ -258,7 +258,8 @@ def test_the_stats_count_prefill_rows_and_which_attention_served_them(
 
 @pytest.mark.parametrize("form,name", [("xla", "mha"), ("kernel", "mha"),
                                        ("xla", "swa"), ("kernel", "swa"),
-                                       ("mixed", "swa")])
+                                       ("mixed", "swa"),
+                                       ("xla", "mla"), ("kernel", "mla")])
 def test_the_chunk_spans_and_the_stats_say_which_attention_served(
         form, name, spans, monkeypatch):
     """`attention` on `engine.dispatch_chunk` (`kernel` or `xla`, as
@@ -273,16 +274,23 @@ def test_the_chunk_spans_and_the_stats_say_which_attention_served(
     walk's are its quarter prefixes, as they were. The rule decides leaf
     by leaf: a ring of 20 rows has no block in whole sublane tiles and
     keeps the XLA walk beside full leaves that take the kernel, which the
-    span calls `mixed` and the kernel's counter leaves out."""
+    span calls `mixed` and the kernel's counter leaves out. A model with
+    latent layers (`mla`) says the same of its latent leaf, which the
+    kernel of its own reads (`ops/decode_attention.py`
+    `ragged_latent_attention`, PR 41): before, its steps were `xla`
+    wherever they ran."""
     import dataclasses
 
     from ray_tpu.ops.decode_attention import kv_prefix_rows
-    from tests.test_ragged_decode import MHA, SWA, on_the_chip  # heads of 128
+    from tests.test_ragged_decode import (MHA, MLA, SWA, latent_on_the_chip,
+                                          on_the_chip)  # heads of 128
 
     block, ring = 8, 20 if form == "mixed" else 16
-    if form != "xla":
+    if form != "xla" and name == "mla":
+        latent_on_the_chip(monkeypatch, block)
+    elif form != "xla":
         on_the_chip(monkeypatch, block * 2 * 128 * 4)
-    cfg = {"mha": MHA, "swa": dataclasses.replace(
+    cfg = {"mha": MHA, "mla": MLA, "swa": dataclasses.replace(
         SWA, arch={**SWA.arch, "sliding_window": ring})}[name]
     eng = ContinuousEngine(cfg, max_batch=2, decode_chunk=4)
     try:

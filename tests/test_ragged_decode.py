@@ -20,6 +20,7 @@ import pytest
 
 from ray_tpu.llm import LLMConfig
 from ray_tpu.llm.engine import ContinuousEngine, SamplingParams
+from ray_tpu.models import mla
 from ray_tpu.ops import attention
 
 # (the module: `ray_tpu.ops.decode_attention` the attribute may be the
@@ -258,3 +259,220 @@ def test_an_engine_given_a_tp_mesh_keeps_the_walk_in_what_it_traces(
     assert sharded["attention"] == "xla"
     assert sharded["decode_steps"] > 0 == sharded["decode_steps_kernel"]
 
+
+
+# ---------------------------------------------------------------------------
+# Latent rows (`ragged_latent_attention`): a kernel and a rule of their own.
+
+#: Kimi's latent row: `c_kv` of 512 beside a rotary key of 64, in the row
+#: of 640 the chip's compiler asks for (`_probe_cache_row`).
+RANK, ROT, ROW = 512, 64, 640
+WIDTH = RANK + ROT
+
+#: name -> (each slot's visible rows, its `live` mark); a latent leaf is
+#: never a ring, so a live slot's length stays within the rows.
+LATENT_CASES = {
+    "one_row_a_blocks_edge_one_past_it_all_rows": (
+        [1, BLOCK, BLOCK + 1, ROWS], [True] * 4),
+    "each_blocks_last_row_and_the_row_before": (
+        [2 * BLOCK, 2 * BLOCK - 1, 3 * BLOCK, ROWS - 1], [True] * 4),
+    "free_row_whose_stale_length_exceeds_the_rows": (
+        [7, 10 * ROWS, BLOCK + 1, ROWS], [True, False, True, True]),
+    "free_rows_first_and_last": ([9 * ROWS, 2 * BLOCK, 5, 4 * ROWS],
+                                 [False, True, True, False]),
+    "free_rows_between_live_ones": ([ROWS, 3, 9 * ROWS, 2 * BLOCK + 3],
+                                    [True, False, False, True]),
+    "all_rows_free": ([5, 10 * ROWS, BLOCK, ROWS], [False] * 4),
+}
+
+
+def latent_leaf(heads: int, dtype, poison=()):
+    keys = jax.random.split(jax.random.PRNGKey(heads), 2)
+    q = jax.random.normal(keys[0], (4, heads, WIDTH), dtype)
+    rows = jnp.pad(jax.random.normal(keys[1], (4, ROWS, WIDTH), dtype),
+                   ((0, 0), (0, 0), (0, ROW - WIDTH)))
+    for slot in poison:  # what a free row's stale steps may have left
+        rows = rows.at[slot].set(jnp.nan)
+    return q, rows
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("case", list(LATENT_CASES))
+@pytest.mark.parametrize("heads", [64, 32], ids=["kimi_k2_64_heads",
+                                                 "kimi_linear_32_heads"])
+def test_the_latent_kernel_reads_each_slots_own_rows_and_none_of_a_free_one(
+        heads, case, dtype, monkeypatch):
+    """Against `models/mla.py` `_latent_attention` over the whole leaf, 64
+    and 32 heads on one row of 640 (rank 512, rotary 64, 64 of zeros). A
+    free row's leaf is never read: filled with NaN here, it leaves its
+    neighbours' outputs as they were, and its own output is zeros."""
+    lens, live = LATENT_CASES[case]
+    free = [i for i, seated in enumerate(live) if not seated]
+    seated = [i for i, s in enumerate(live) if s]
+    q, rows = latent_leaf(heads, dtype, poison=free)
+    monkeypatch.setattr(da, "BLOCK_BYTES",
+                        BLOCK * ROW * jnp.dtype(dtype).itemsize)
+    assert da.latent_block(rows.shape, rows.dtype) == BLOCK
+    scale = 0.1147
+    got = da.ragged_latent_attention(
+        q, rows, jnp.asarray(lens, jnp.int32), jnp.asarray(live), rank=RANK,
+        scale=scale, interpret=True)
+    assert got.shape == (4, heads, RANK) and got.dtype == q.dtype
+    got = np.asarray(got, np.float32)
+    assert np.all(got[free] == 0.0)
+    if not seated:
+        return
+    pos = jnp.asarray(lens, jnp.int32)[:, None] - 1
+    want = np.asarray(mla._latent_attention(
+        q[..., :RANK], q[:, None, :, RANK:], jnp.nan_to_num(rows), pos, RANK,
+        WIDTH, scale), np.float32)
+    # bf16: the output's own rounding and the probabilities' (the cache's
+    # dtype in both forms, rounded after sums taken in another order)
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(got[seated], want[seated], atol=tol, rtol=0)
+
+
+def test_the_latent_block_is_derived_from_the_rows_bytes():
+    """`latent_block`: `row_block`'s rule for a row of one head. Both
+    cells' leaves (4096 rows of 640 bf16 values) go 512 rows a block; a
+    short leaf is one block; and `live` left out counts every slot."""
+    assert da.latent_block((32, 4096, 640), jnp.bfloat16) == 512
+    assert da.latent_block((64, 4096, 640), jnp.bfloat16) == 512
+    assert da.latent_block((64, 4096, 640), jnp.float32) == 256
+    assert da.latent_block((8, 256, 640), jnp.bfloat16) == 256
+    assert da.latent_block((2, 8 * 1031, 640), jnp.bfloat16) == 8
+    assert da.latent_block((2, 2 * 1031, 640), jnp.bfloat16) is None
+    q, rows = latent_leaf(8, jnp.float32)
+    lens = jnp.asarray([1, 17, 40, 64], jnp.int32)
+    got = da.ragged_latent_attention(q, rows, lens, rank=RANK, scale=0.1,
+                                     interpret=True)
+    want = mla._latent_attention(q[..., :RANK], q[:, None, :, RANK:], rows,
+                                 lens[:, None] - 1, RANK, WIDTH, 0.1)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+    with pytest.raises(ValueError, match="latent_refusal"):
+        da.ragged_latent_attention(q, rows[:, :, :WIDTH - 8], lens, rank=RANK,
+                                   scale=0.1, interpret=True)
+
+
+@pytest.mark.parametrize("name,leaf,rank,mesh,why", [
+    ("kimi_k2", (32, 4096, 640), 512, None, None),
+    ("kimi_linear", (64, 4096, 640), 512, None, None),
+    ("a_row_of_576", (32, 4096, 576), 512, None, "lane tiles"),
+    ("c_kv_of_96", (8, 256, 128), 96, None, "lane tiles"),
+    ("no_block", (2, 2 * 1031, 640), 512, None, "no block"),
+    ("tp_mesh", (32, 4096, 640), 512, 2, "not partitioned"),
+])
+def test_the_latent_rule(name, leaf, rank, mesh, why, monkeypatch):
+    """`latent_refusal`: off a TPU nothing is asked; on one (the question
+    about the backend answered as the chip would) the kernel takes a row
+    of whole lane tiles, which the row as the model has it (576) is not
+    until the engine has widened it, whose rows have a block, with no mesh
+    of several devices in context."""
+    from jax.sharding import Mesh
+
+    assert da.latent_refusal(leaf, rank) == attention.NOT_ASKED
+    monkeypatch.setattr(attention, "on_tpu", lambda: True)
+    if mesh:
+        with Mesh(np.array(jax.devices()[:mesh]), ("tp",)):
+            reason = da.latent_refusal(leaf, rank)
+    else:
+        reason = da.latent_refusal(leaf, rank)
+    assert (reason is None) if why is None else (why in reason), reason
+
+
+#: Kimi K2's block at a toy size with the latent row at its PUBLISHED
+#: width (512 + 64): two latent layers, the second with experts.
+MLA = LLMConfig(
+    vocab_size=128, d_model=64, n_layers=2, n_heads=4, max_seq=64,
+    dtype="float32", experts_held=4,
+    arch={"model_type": "kimi_k2", "intermediate_size": 96,
+          "q_lora_rank": 24, "kv_lora_rank": RANK, "qk_nope_head_dim": 16,
+          "qk_rope_head_dim": ROT, "v_head_dim": 16,
+          "moe_intermediate_size": 32, "n_routed_experts": 8,
+          "n_shared_experts": 1, "num_experts_per_tok": 2,
+          "first_k_dense_replace": 1, "norm_topk_prob": True,
+          "routed_scaling_factor": 2.5, "scoring_func": "sigmoid",
+          "rms_norm_eps": 1e-5, "rope_theta": 50000, "rope_scaling": None,
+          "tie_word_embeddings": False})
+#: Kimi Linear's: state layers beside a latent one without a position.
+KDA_MLA = LLMConfig(
+    vocab_size=128, d_model=64, n_layers=4, n_heads=4, max_seq=64,
+    dtype="float32", experts_held=4,
+    arch={"model_type": "kimi_linear",
+          "linear_attn_config": {
+              "kda_layers": [1, 2, 3], "full_attn_layers": [4],
+              "num_heads": 4, "head_dim": 16, "short_conv_kernel_size": 4},
+          "kv_lora_rank": RANK, "q_lora_rank": None, "qk_nope_head_dim": 16,
+          "qk_rope_head_dim": ROT, "v_head_dim": 16, "mla_use_nope": True,
+          "num_experts": 8, "num_experts_per_token": 2,
+          "num_shared_experts": 1, "moe_intermediate_size": 32,
+          "intermediate_size": 128, "first_k_dense_replace": 1,
+          "moe_router_activation_func": "sigmoid", "moe_renormalize": True,
+          "routed_scaling_factor": 2.446, "rms_norm_eps": 1e-5,
+          "tie_word_embeddings": False, "hidden_act": "silu",
+          "num_expert_group": 1, "topk_group": 1, "moe_layer_freq": 1,
+          "num_nextn_predict_layers": 0, "rope_scaling": None})
+
+
+def latent_on_the_chip(monkeypatch, block_rows: int) -> None:
+    """`on_the_chip` for an engine with latent layers: the compiler's
+    answer about the row stands in (`_probe_cache_row`: 576 values go in
+    rows of 640), and the latent kernel runs in interpret mode in blocks of
+    `block_rows` float32 rows."""
+    on_the_chip(monkeypatch, block_rows * ROW * 4)
+    monkeypatch.setattr(ContinuousEngine, "_probe_cache_row",
+                        lambda self, make_chunk: ROW)
+    monkeypatch.setattr(mla, "ragged_latent_attention", functools.partial(
+        da.ragged_latent_attention, interpret=True))
+
+
+@pytest.mark.parametrize("name", ["mla", "kda_mla"])
+def test_the_engine_serves_the_same_tokens_through_the_latent_kernel(
+        name, monkeypatch):
+    """The `mha` engines' test above for a model with latent layers (and
+    for one with state layers beside them): ten greedy requests of mixed
+    lengths on three slots, the tokens through the latent kernel, forced in
+    interpret mode, are the walk's; every decode step is counted under the
+    kernel and the walked share is the live slots' own rows rounded up to
+    the block, where the walk's is its quarter prefixes'."""
+    cfg = {"mla": MLA, "kda_mla": KDA_MLA}[name]
+    rng = np.random.default_rng(2)
+    prompts = [rng.integers(1, 128, size=n).tolist()
+               for n in (5, 40, 17, 30, 9, 3, 33, 12, 21, 7)]
+    budgets = [12, 6, 9, 20, 5, 16, 8, 3, 11, 14]
+    want, xla = run(cfg, prompts, budgets)
+    assert xla["attention"] == "xla" and xla["cache_kind"] == "latent"
+    assert xla["decode_steps"] > 0 and xla["decode_steps_kernel"] == 0
+    latent_on_the_chip(monkeypatch, 16)
+    got, kernel = run(cfg, prompts, budgets)
+    assert got == want
+    assert kernel["attention"] == "kernel"
+    assert kernel["decode_steps_kernel"] == kernel["decode_steps"] > 0
+    assert f"[3, 64, {ROW}]" in kernel["cache_layout"]
+    full = kernel["cache_kinds"]["full"]
+    assert full["live_share"] <= full["walk_share"] < (
+        full["live_share"] + 16 / 64)
+    assert kernel["kv_walk_share"] == full["walk_share"] < 1.0
+
+
+def test_a_latent_engine_given_a_tp_mesh_keeps_the_walk(monkeypatch):
+    """The latent twin of the `mha` engine's test above: with a `tp` mesh
+    in context the rule the counters asked is the rule the trace asks,
+    both keep `_latent_walk`, and the kernel is never called."""
+    from jax.sharding import Mesh
+
+    prompts = [list(range(1, n)) for n in (6, 41, 18)]
+    budgets = [9, 6, 12]
+    want, _ = run(MLA, prompts, budgets)
+    latent_on_the_chip(monkeypatch, 16)
+
+    def never(*args, **kwargs):
+        raise AssertionError("the latent kernel under a `tp` mesh")
+
+    monkeypatch.setattr(mla, "ragged_latent_attention", never)
+    got, sharded = run(MLA, prompts, budgets,
+                       mesh=Mesh(np.array(jax.devices()[:2]), ("tp",)))
+    assert got == want
+    assert sharded["attention"] == "xla"
+    assert sharded["decode_steps"] > 0 == sharded["decode_steps_kernel"]

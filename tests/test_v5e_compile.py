@@ -497,9 +497,9 @@ def moved_rows(eng, text: str, ops: str = "copy|slice") -> list:
 def test_v5e_bounded_chunk_program_branches_once_a_layer_and_copies_no_rows(
         chip, name):
     """The chunk program the scheduler dispatches (with a `kv_bound`) in
-    its XLA form (this process is held to the CPU, so the dispatcher asks
-    for no kernel: `latent` has none, `kv` and `swa` take theirs on the
-    chip, below): one `conditional` a layer, whose branches read their
+    its XLA form (this process is held to the CPU, so the dispatchers ask
+    for no kernel: each family takes its own on the chip, in the tests
+    below): one `conditional` a layer, whose branches read their
     prefix of the cache where it lies. Written as an einsum over a slice,
     the v5e's compiler fed each branch a transposed COPY of the K prefix,
     and cutting the latent's rotary columns inside a tile made it copy the
@@ -581,3 +581,51 @@ def test_v5e_chunk_program_with_the_ragged_kernel_moves_no_rows(
         *_, k, v = line.split(", ")
         assert "copy" not in k and "copy" not in v, line
     assert not moved_rows(eng, text, "copy-start|copy-done"), name
+
+
+@pytest.mark.parametrize("name", ["latent", "kda"])
+def test_v5e_latent_chunk_program_with_the_ragged_kernel_moves_no_rows(
+        chip, name, monkeypatch):
+    """The twin of the test above for the `mla` family, at Kimi K2's
+    latent widths (two latent layers of a short leaf) and at Kimi Linear's
+    published widths (64 slots of 4096 rows, one latent layer in four): on
+    the chip the bounded step of a latent layer is `ragged_latent_attention`,
+    ONE Mosaic call a latent layer in the step's body, handed the leaf
+    `[slots, rows, 640]` as the step's own update left it, in HBM; no
+    `conditional` (the walk's `lax.switch` over quarter prefixes is off the
+    path), no loop but the chunk's own, and outside the fusions no `copy`,
+    `slice`, `transpose` or `bitcast-convert` whose result has a leaf's
+    dimensions or any prefix of its rows. The row as the model has it (576)
+    is refused, so the engine's question to the compiler is still put
+    through the walk and still answered 640. The engine counts its decode
+    steps under the kernel."""
+    import types
+
+    from ray_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "on_tpu", lambda: True)
+    cfg, slots, layers, block = {"latent": (LATENT, MAX_BATCH, 2, 256),
+                                 "kda": (KDA, KDA_SLOTS, 1, 512)}[name]
+    eng = build_compiled(chip, cfg=cfg, max_batch=slots)
+    assert eng.model.cfg.cache_row == 640
+    assert eng.cache_boundary_copies == 0
+    assert (eng._kernel_blocks, eng._decode_form) == ({"full": block},
+                                                      "kernel")
+    text = eng._chunk.lower(*eng._chunk_shapes(
+        eng.params, eng._cache_spec, True)).compile().as_text()
+    calls = re.findall(r" custom-call\((.*?)\), custom_call_target="
+                       r"\"tpu_custom_call\"", text)
+    assert len(calls) == layers
+    assert " conditional(" not in text
+    assert len(re.findall(r" while\(", text)) == 1
+    latent = [leaf for leaf in jax.tree.leaves(eng._cache_spec)
+              if leaf.shape[1:] == (cfg.max_seq, 640)]
+    assert len(latent) == layers
+    # (the latent leaves only: a state leaf's filter tail is sliced by its
+    # own layer, which is no business of this kernel's)
+    assert not moved_rows(
+        types.SimpleNamespace(_cache_spec=latent), text,
+        "copy|slice|transpose|bitcast-convert|copy-start|copy-done"), name
+    for line in calls:
+        # (the grid's length, stop, slot, at, held, q, the leaf)
+        assert "copy" not in line.split(", ")[-1], line
