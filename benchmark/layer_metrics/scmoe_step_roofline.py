@@ -1,0 +1,59 @@
+"""scmoe_step_roofline — layer: kernels (the decode step of a layer with two
+latent attentions, two dense feed-forwards and an expert layer on a
+shortcut: the latent walk is PR 41's Pallas kernel, the rest XLA).
+
+The least time the chip could take for a decode step of this model over the
+time it took (`decode_step_ms`), in %. The least time is the larger of bytes
+over bandwidth and operations over the bf16 peak, from
+`benchmark/shapes_scmoe.py` and `benchmark/peaks.py`: every held weight
+outside the experts and the embedding table once; one expert's weights for
+each held expert a step TOUCHED (`moe_touched` of the chunks dispatched
+while the profiler ran: an expert that got no row need not be read, and
+with 8 rows a step over 16 held experts most get none; the three older
+expert rooflines count every held expert whole); every latent row visible to
+a live slot once a LEAF, two leaves a layer, 1152 bytes at the published
+sizes (`kv_live_full` x `active` on the traced chunks); the expert
+operations for the rows the engine counted (`moe_rows`). A program that
+counts no `moe_touched` gives nothing."""
+
+from benchmark import (engine_spans as es, peaks, scmoe_spans, shapes_scmoe,
+                       spans as sp)
+
+
+@es.never_raises
+def read(run: dict):
+    llm = run["config"]["llm_config"]
+    got = sp.decode_steps(run)
+    chunks = [c["at"] for c in sp.traced_chunks(run)
+              if "kv_live_full" in (c.get("at") or {})]
+    counted = scmoe_spans.totals(run, scmoe_spans.traced(run))
+    if (not shapes_scmoe.is_scmoe(llm) or got is None or not chunks
+            or counted is None):
+        return None
+    steps, secs = got
+    batch = run["config"]["app_kwargs"]["max_batch"]
+    tokens = sum(c["tokens"] for c in chunks)
+    rows = sum(c["kv_live_full"] * c["active"] * c["tokens"]
+               for c in chunks) / tokens
+    active = sum(c["active"] * c["tokens"] for c in chunks) / tokens
+    least = shapes_scmoe.decode_step_min_seconds(
+        llm, batch, rows, peaks.peaks(run["device"]["kind"]),
+        expert_rows=counted["rows"] / counted["steps"],
+        touched=counted["touched"] / counted["steps"])
+    all_held = shapes_scmoe.decode_step_min_seconds(
+        llm, batch, rows, peaks.peaks(run["device"]["kind"]),
+        expert_rows=counted["rows"] / counted["steps"])
+    parts = ", ".join(f"{k} {v / 1e9:.3f}" for k, v in sorted(
+        least["parts"].items(), key=lambda kv: -kv[1]))
+    step = secs / steps
+    print(f"scmoe_step_roofline: least step {least['seconds'] * 1e3:.3f} ms "
+          f"({least['bytes'] / 1e9:.3f} GB, {least['flops'] / 1e12:.3f} "
+          f"TFLOP, bound by {least['bound']}); GB by part: {parts}; "
+          f"{least['touched']:.2f} of {least['held']} held experts touched "
+          f"a step (over {counted['steps']} counted steps); counted on all "
+          f"held it would be {all_held['seconds'] * 1e3:.3f} ms, "
+          f"{100.0 * all_held['seconds'] / step:.1f}%; {active:.2f} slots "
+          f"active, {rows / max(active, 1e-9):.0f} latent rows visible a "
+          f"slot a leaf, {shapes_scmoe.latent_leaves(llm)} leaves",
+          flush=True)
+    return 100.0 * least["seconds"] / step

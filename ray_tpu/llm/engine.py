@@ -458,10 +458,10 @@ def model_config(cfg):
             n_kv_heads=cfg.n_heads, d_ff=int(cfg.d_model * 8 / 3) // 8 * 8,
             **sizes)
     kind = arch.get("model_type")
-    if kind == "afmoe":
-        return TransformerConfig(**_afmoe(cfg, arch), **sizes)
-    if kind == "kimi_linear":
-        return TransformerConfig(**_kimi_linear(cfg, arch), **sizes)
+    arms = {"afmoe": _afmoe, "kimi_linear": _kimi_linear,
+            "longcat_flash": _longcat_flash}  # each: the fields past the sizes
+    if kind in arms:
+        return TransformerConfig(**arms[kind](cfg, arch), **sizes)
     if kind not in ("kimi_k2", "deepseek_v3"):
         raise ValueError(f"no model is built for model_type {kind!r}")
     want = {"hidden_act": "silu", "attention_bias": False, "n_group": 1,
@@ -610,8 +610,8 @@ def _kimi_linear(cfg, arch: dict) -> dict:
 
 
 def _layer_kinds(mcfg) -> dict:
-    """The kind of cache each layer keeps (`TransformerConfig.cache_kind_of`)
-    by the layer's name in the cache collection."""
+    """The kind of cache LEAVES each layer keeps (`cache_kind_of`; a layer
+    may keep several) by the layer's name in the cache collection."""
     return {f"layer_{i}": mcfg.cache_kind_of(i) for i in range(mcfg.n_layers)}
 
 
@@ -870,11 +870,11 @@ class ContinuousEngine:
 
         sampler = self._sampler
         # Expert layers: the columns each chunk's token block carries beyond
-        # its tokens (`_rows_columns`), and the rows routed to held experts
-        # since start, over all expert layers and steps.
+        # its tokens (`_rows_columns`); the totals since start (`_count_moe`).
         self._moe_held = self.model.cfg.held_experts
-        self._moe_cols = -(-self._moe_held // self.max_batch)
+        self._moe_cols = -(-_moe_counters(self.model.cfg) // self.max_batch)
         self.moe_rows_total = 0
+        self.moe_picks_total = self.moe_zero_picks_total = 0
         # The decode steps dispatched since start, those whose attention
         # over K and V or latent rows is a ragged kernel (`_decode_blocks`),
         # the cache rows they walked a slot, and the rows a live slot had
@@ -903,7 +903,7 @@ class ContinuousEngine:
         self._prefill_form_of: dict = {}
 
         def make_chunk(model):
-            held = model.cfg.held_experts
+            held = _moe_counters(model.cfg)  # counters a step carries on
 
             def chunk(params, cache, toks, lengths, keys, temp, top_k, top_p,
                       n: int, greedy: bool, kv_bound=None, live=None):
@@ -925,8 +925,8 @@ class ContinuousEngine:
                 sampler is pure waste when no active slot samples. A model
                 with expert layers appends to the token block the columns
                 of `_rows_columns`: the rows its held experts were routed
-                in these n steps ride to the host in the read that brings
-                the tokens."""
+                in these n steps (and `_moe_counters`' others) ride to the
+                host in the read that brings the tokens."""
                 def step(carry, _):
                     cache, tok, lens, keys, *rows = carry
                     # (the mesh in context, as the prefill's: what
@@ -939,8 +939,8 @@ class ContinuousEngine:
                             mutable=(["cache", "stats"] if held
                                      else ["cache"]))
                     if held:
-                        rows = [sum(jax.tree.leaves(vars_out.get("stats", {})),
-                                    rows[0])]
+                        rows = [_counted(vars_out.get("stats", {}),
+                                         rows[0])]
                     if greedy:
                         nxt = jnp.argmax(
                             logits[:, -1], axis=-1).astype(jnp.int32)
@@ -973,12 +973,12 @@ class ContinuousEngine:
         model = self.model
         self._cache_spec = self._cache_shapes(model, self.params)
         mcfg = model.cfg
-        # Three kinds of leaf in one manager, a kind a layer
-        # (`TransformerConfig.cache_kind_of`): a full layer's `max_seq` rows
-        # a slot and a window layer's ring, both `[slots, rows, ...]` and
-        # appended to; and a state, a fixed block a slot that every token
-        # replaces. Whatever goes by the kind of a leaf asks `_kind_of`,
-        # never the leaf's rank or its second axis.
+        # Three kinds of leaf in one manager, a kind a layer (`cache_kind_of`;
+        # a layer keeps one leaf or several: K and V, a state's parts, the two
+        # latents under `moe_shortcut`): a full layer's `max_seq` rows a slot
+        # and a window layer's ring, both `[slots, rows, ...]` and appended
+        # to; and a state, a fixed block a slot that every token replaces.
+        # Whatever goes by a leaf's kind asks `_kind_of`, never its rank.
         self._kind_of = _layer_kinds(mcfg)
         self._window = max((mcfg.window_of(i) for i in range(mcfg.n_layers)),
                            default=0)
@@ -987,11 +987,11 @@ class ContinuousEngine:
         for kind in ("full", "window", "state"):
             if kind not in kinds:
                 continue
-            nbytes = sum(leaf.size * leaf.dtype.itemsize
-                         for name, k in self._kind_of.items() if k == kind
-                         for leaf in jax.tree.leaves(self._cache_spec[name]))
+            leaves = [leaf for name, k in self._kind_of.items() if k == kind
+                      for leaf in jax.tree.leaves(self._cache_spec[name])]
+            nbytes = sum(leaf.size * leaf.dtype.itemsize for leaf in leaves)
             self._cache_kinds[kind] = {
-                "layers": kinds.count(kind),
+                "layers": kinds.count(kind), "leaves": len(leaves),
                 **({"bytes_per_slot": nbytes // self.max_batch}
                    if kind == "state" else
                    {"rows": self._window if kind == "window"
@@ -1222,10 +1222,13 @@ class ContinuousEngine:
         `kv_bound` chose) beside the share its live slots had written on
         average (`kv_live_share`: what a walk that stopped at each slot's
         own length would read); `cache_kinds`, the same two shares and the
-        layers, rows a slot and bytes of each kind of rows leaf, `full`
-        (`max_seq` rows; the latent leaves are of this kind) and `window`
-        (a ring), and of the `state` leaves their layers, `bytes_per_slot`
-        and bytes (`state_bytes` at the top); `kv_heads`, the key/value
+        layers, LEAVES (a layer keeps K and V, a state's parts, or, under
+        `moe_shortcut`, two latents), rows a slot and bytes of each kind of
+        rows leaf, `full` (`max_seq` rows; the latent leaves are of this
+        kind) and `window` (a ring), and of the `state` leaves their layers,
+        leaves, `bytes_per_slot` and bytes (`state_bytes` at the top); for
+        a model with expert layers what `_moe_stats` counts (the identity
+        experts' selections among it); `kv_heads`, the key/value
         heads a row holds; and how batch rows changed hands: the
         hand-overs (`splices`), those whose program was dispatched behind
         at least one decode chunk in flight (`splices_in_flight`), and
@@ -1270,29 +1273,26 @@ class ContinuousEngine:
         if "state" in kinds:
             out["state_bytes"] = kinds["state"]["bytes"]
         if self._moe_held:
-            out.update(experts_held=self._moe_held,
-                       experts_published=mcfg.moe_experts,
-                       first_expert=mcfg.first_expert,
-                       moe_rows_total=self.moe_rows_total)
+            out.update(self._moe_stats())
         return out
 
     def _count_moe(self, block: np.ndarray, n: int) -> dict:
-        """The expert layers' row counts of the n-step chunk just read
-        (they ride behind its tokens): added to the totals, and returned
-        as the attributes `engine.host_sync` carries."""
-        rows = _rows_from_columns(block[:, n:n + self._moe_cols],
-                                  self._moe_held)
+        """The expert layers' counts of the n-step chunk just read (they
+        ride behind its tokens): added to the totals, and returned as the
+        attributes `engine.host_sync` carries."""
+        counts = _rows_from_columns(block[:, n:n + self._moe_cols],
+                                    _moe_counters(self.model.cfg))
+        rows = counts[:self._moe_held]
         total = int(rows.sum())
         self.moe_rows_total += total
-        if total:
-            try:
-                from ray_tpu.util import metrics as _metrics
-
-                _metrics.LLM_MOE_ROWS.inc(total)
-            except Exception:
-                pass
-        return {"moe_rows": total, "moe_rows_busiest": int(rows.max()),
-                "moe_steps": n}
+        _count_metric("LLM_MOE_ROWS", total)
+        attrs = {"moe_rows": total, "moe_rows_busiest": int(rows.max()),
+                 "moe_steps": n}
+        if len(counts) > len(rows):
+            # a router with identity experts: its selections, those that
+            # fell on an identity expert, held experts that got a row
+            attrs.update(self._count_picks(*counts[len(rows):]))
+        return attrs
 
     def _init_cache(self):
         """Zero cache for the full batch."""
@@ -1443,23 +1443,23 @@ class ContinuousEngine:
         mcfg = self.model.cfg
         q = (self.max_batch, mcfg.n_heads, mcfg.head_dim)
 
-        def block_of(mixer, leaf):
-            """The leaf's row block, None where its rule refuses it."""
+        def block_of(mixer, shape, dtype):
+            """A leaf's row block, None where its rule refuses it."""
             if mixer == "mla":
-                refused = latent_refusal(leaf.shape, mcfg.kv_lora_rank,
-                                         leaf.dtype)
-                return None if refused else latent_block(leaf.shape,
-                                                         leaf.dtype)
-            refused = walk_refusal(q, leaf.shape, leaf.dtype)
-            return None if refused else row_block(leaf.shape, leaf.dtype)
+                refused = latent_refusal(shape, mcfg.kv_lora_rank, dtype)
+                return None if refused else latent_block(shape, dtype)
+            refused = walk_refusal(q, shape, dtype)
+            return None if refused else row_block(shape, dtype)
 
-        leaves = {self._kind_of[f"layer_{i}"]: (
-                      mcfg.mixer_of(i),
-                      jax.tree.leaves(self._cache_spec[f"layer_{i}"])[0])
-                  for i in range(mcfg.n_layers)
-                  if mcfg.mixer_of(i) in ("mha", "mla")}
-        with self._mesh_scope():
-            blocks = {kind: block_of(*leaf) for kind, leaf in leaves.items()}
+        leaves: dict = {}  # kind -> EVERY leaf of its layers, as the rules ask
+        for i in range(mcfg.n_layers):
+            if mcfg.mixer_of(i) in ("mha", "mla"):
+                leaves.setdefault(self._kind_of[f"layer_{i}"], set()).update(
+                    (mcfg.mixer_of(i), leaf.shape, leaf.dtype) for leaf in
+                    jax.tree.leaves(self._cache_spec[f"layer_{i}"]))
+        with self._mesh_scope():  # a kind's block: the one ALL its leaves get
+            blocks = {kind: _agreed({block_of(*leaf) for leaf in asked})
+                      for kind, asked in leaves.items()}
         blocks = {kind: block for kind, block in blocks.items() if block}
         return blocks, ("xla" if not blocks else "kernel"
                         if len(blocks) == len(leaves) else "mixed")
@@ -2047,3 +2047,126 @@ class ContinuousEngine:
             st.in_flight -= n
             self._deliver(st, block[st.slot, :n].tolist())
         return sync_ctx
+
+    # ------------------------------------------------ expert layers' counts
+    def _moe_stats(self) -> dict:
+        """What /v1/stats says of the expert layers: the share held, the rows
+        routed to it since start and, for a router with identity experts,
+        how many it has, the router's width, and the selections made since
+        start beside those that fell on an identity expert."""
+        mcfg = self.model.cfg
+        out = dict(experts_held=self._moe_held,
+                   experts_published=mcfg.moe_experts,
+                   first_expert=mcfg.first_expert,
+                   moe_rows_total=self.moe_rows_total)
+        if mcfg.moe_zero_experts:
+            out.update(
+                zero_experts=mcfg.moe_zero_experts,
+                router_outputs=mcfg.moe_experts + mcfg.moe_zero_experts,
+                moe_picks_total=self.moe_picks_total,
+                moe_zero_picks_total=self.moe_zero_picks_total)
+        return out
+
+    def _count_picks(self, picks, zero_picks, touched) -> dict:
+        """A chunk's three counters of a router with identity experts
+        (`models/moe.py` `zero_counts`, summed over expert layers and
+        steps): into the totals, and as `engine.host_sync`'s attributes."""
+        picks, zero_picks = int(picks), int(zero_picks)
+        self.moe_picks_total += picks
+        self.moe_zero_picks_total += zero_picks
+        _count_metric("LLM_MOE_PICKS", picks)
+        _count_metric("LLM_MOE_ZERO_PICKS", zero_picks)
+        return {"moe_picks": picks, "moe_zero_picks": zero_picks,
+                "moe_touched": int(touched)}
+
+
+# Helpers of what stands above, kept down here: the serving programs that
+# hold a Pallas kernel carry the line numbers of their callers in this file
+# (`model_config` .. `_fill_pipeline`) inside the kernel's payload, and with
+# them in their compile-cache key (PERF.md section 6, PRs 41 and 42).
+def _longcat_flash(cfg, arch: dict) -> dict:
+    """The fields of a `model_type: longcat_flash` decoder (Meituan's
+    LongCat-Flash) beyond the six sizes: every layer two latent attentions
+    and two dense SwiGLUs with ONE expert layer on a shortcut across the
+    second half (`models/scmoe.py`); a softmax router over the routed experts
+    AND `zero_expert_num` identity experts, selection by score + correction
+    bias, weights the scores times `routed_scaling_factor`, not renormalised;
+    the two low-rank scale corrections of its latent attention; plain rotary
+    frequencies. What no key of `config.json` states is the published
+    modelling code's (ISSUE 42 lists each under `assumed`)."""
+    want = {"hidden_act": "silu", "attention_bias": False,
+            "attention_method": "MLA", "zero_expert_type": "identity",
+            "rope_scaling": None, "norm_topk_prob": False,
+            "router_bias": False}
+    odd = {k: arch[k] for k, v in want.items() if arch.get(k, v) != v}
+    if odd:
+        raise ValueError(f"not built: {odd} (built: {want})")
+    if arch.get("num_key_value_heads", cfg.n_heads) != cfg.n_heads:
+        raise ValueError("latent attention has one latent for all heads: "
+                         "num_key_value_heads must equal the heads")
+    published = int(arch["n_routed_experts"])
+    q_rank, kv_rank = int(arch["q_lora_rank"] or 0), int(arch["kv_lora_rank"])
+
+    def lora_scale(on, rank) -> float:
+        """(hidden / rank)^0.5 where the model says so (and has the rank)."""
+        return (cfg.d_model / rank) ** 0.5 if on and rank else 1.0
+
+    return dict(
+        n_kv_heads=cfg.n_heads, d_ff=int(arch["ffn_hidden_size"]),
+        rope_theta=float(arch["rope_theta"]),
+        norm_eps=float(arch["rms_norm_eps"]),
+        tie_embeddings=bool(arch.get("tie_word_embeddings", False)),
+        mixers=("mla",) * cfg.n_layers,
+        q_lora_rank=q_rank, kv_lora_rank=kv_rank,
+        qk_nope_head_dim=int(arch["qk_nope_head_dim"]),
+        qk_rope_head_dim=int(arch["qk_rope_head_dim"]),
+        v_head_dim=int(arch["v_head_dim"]),
+        mla_q_scale=lora_scale(arch.get("mla_scale_q_lora"), q_rank),
+        mla_kv_scale=lora_scale(arch.get("mla_scale_kv_lora"), kv_rank),
+        moe_experts=published, moe_top_k=int(arch["moe_topk"]),
+        moe_d_ff=int(arch["expert_ffn_hidden_size"]),
+        moe_scoring="softmax", moe_norm_topk=False,
+        moe_routed_scale=float(arch["routed_scaling_factor"]),
+        moe_score_bias=True,  # the router's e_score_correction_bias
+        moe_zero_experts=int(arch["zero_expert_num"]), moe_shortcut=True,
+        experts_held=_experts_held(cfg, published),
+        first_expert=cfg.first_expert)
+
+
+def _moe_counters(mcfg) -> int:
+    """Counters a decode step of this model carries to the host behind its
+    tokens: the rows of each held expert and, for a router with identity
+    experts, `models/moe.py` `zero_counts`' three; 0 without expert layers."""
+    held = mcfg.held_experts
+    return held + 3 if held and mcfg.moe_zero_experts else held
+
+
+def _counted(stats, so_far):
+    """`so_far` plus what one step's expert layers sowed into `stats`: the
+    rows of each held expert, then (a router with identity experts) the
+    three of `zero_counts`. Without those it is the sum it always was."""
+    import jax
+    import jax.numpy as jnp
+
+    flat = jax.tree_util.tree_flatten_with_path(stats)[0]
+    picks = [leaf for path, leaf in flat if path[-1].key == "picks"]
+    if not picks:
+        return sum(jax.tree.leaves(stats), so_far)
+    rows = [leaf for path, leaf in flat if path[-1].key == "expert_rows"]
+    return so_far + jnp.concatenate([sum(rows), sum(picks)])
+
+
+def _agreed(values: set):
+    """The one value of a set whose members all agree, else None."""
+    return values.pop() if len(values) == 1 else None
+
+
+def _count_metric(name: str, n: int) -> None:
+    """`ray_tpu.util.metrics.<name>` up by n; never the caller's problem."""
+    if n:
+        try:
+            from ray_tpu.util import metrics as _metrics
+
+            getattr(_metrics, name).inc(n)
+        except Exception:
+            pass

@@ -18,12 +18,17 @@ import jax.numpy as jnp
 
 class RMSNorm(nn.Module):
     eps: float = 1e-6
+    #: A constant the normed values are multiplied by, in float32 before the
+    #: cast (`models/mla.py`: a latent's `mla_kv_scale`); 1.0: nothing.
+    gain: float = 1.0
 
     @nn.compact
     def __call__(self, x):
         scale = self.param("scale", nn.initializers.ones, (x.shape[-1],), jnp.float32)
         x32 = x.astype(jnp.float32)
         norm = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + self.eps)
+        if self.gain != 1.0:
+            scale = scale * self.gain
         return (norm * scale).astype(x.dtype)
 
 

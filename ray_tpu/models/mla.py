@@ -5,9 +5,9 @@ Keys and values of all heads are linear maps of ONE latent per token: `c_kv`
 to each head's `k_nope` / `v`, and one rotary key `k_r` (`qk_rope_head_dim`
 wide) is shared by all heads. So the cache holds `[c_kv | k_r]`, 576 values a
 token a layer at the published sizes, where K and V per head would be 16,384.
-A model whose latent attention carries no position (`cfg.mla_rope` False: Kimi
+A model without a position in its latent attention (`cfg.mla_rope` False: Kimi
 Linear's `mla_use_nope`) leaves `k_r` and the query dims facing it unrotated;
-nothing else differs.
+`cfg.mla_q_scale`, `mla_kv_scale` (LongCat-Flash) scale q and the normed c_kv.
 
 Two paths compute the same attention:
 
@@ -41,13 +41,13 @@ SCORE_TILE_BYTES = 256 << 20
 
 
 def softmax_scale(cfg) -> float:
-    """(qk_nope + qk_rope)^-0.5, times YaRN's mscale(factor,
-    mscale_all_dim) squared where the model scales its context with YaRN."""
+    """(qk_nope + qk_rope)^-0.5, times YaRN's mscale(factor, mscale_all_dim)
+    squared under YaRN, times `mla_q_scale`: q meets nothing but the scores."""
     scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
     if cfg.rope_yarn is not None:
         m = yarn_mscale(cfg.rope_yarn.factor, cfg.rope_yarn.mscale_all_dim)
         scale *= m * m
-    return scale
+    return scale * cfg.mla_q_scale
 
 
 class MLA(nn.Module):
@@ -58,16 +58,15 @@ class MLA(nn.Module):
                  live=None):
         cfg = self.cfg
         heads, rank = cfg.n_heads, cfg.kv_lora_rank
-        nope, rot, vdim = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
-                           cfg.v_head_dim)
-        dense = lambda feats, name: nn.DenseGeneral(  # noqa: E731
+        nope, rot = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+        vdim = cfg.v_head_dim
+        dense = lambda feats, name, **kw: nn.DenseGeneral(  # noqa: E731
             feats, axis=-1, use_bias=False, name=name,
-            dtype=cfg.dtype, param_dtype=cfg.param_dtype)
-        per_head = nn.initializers.lecun_normal(batch_axis=(0,))
-        # The published `kv_b_proj` is one matrix [rank, heads x (nope + v)];
-        # its two halves are held apart and head-major, [heads, rank, dim]:
-        # the latent path multiplies by each on its own, a batch of small
-        # matrices over the heads, every decode step.
+            dtype=cfg.dtype, param_dtype=cfg.param_dtype, **kw)
+        per_head = _up_init(cfg.mla_kv_scale, batch_axis=(0,))
+        # The published `kv_b_proj` [rank, heads x (nope + v)] is held as two
+        # head-major halves [heads, rank, dim]: the latent path uses each alone.
+        q_up = _up_init(cfg.mla_q_scale)
         wk_b = self.param("wk_b", per_head, (heads, rank, nope),
                           cfg.param_dtype).astype(cfg.dtype)
         wv_b = self.param("wv_b", per_head, (heads, rank, vdim),
@@ -76,11 +75,12 @@ class MLA(nn.Module):
             if cfg.q_lora_rank:
                 c_q = RMSNorm(cfg.norm_eps, name="q_norm")(
                     dense(cfg.q_lora_rank, "wq_a")(x))
-                q = dense((heads, nope + rot), "wq_b")(c_q)
+                q = dense((heads, nope + rot), "wq_b", kernel_init=q_up)(c_q)
             else:
                 q = dense((heads, nope + rot), "wq")(x)
             kv = dense(rank + rot, "wkv_a")(x)  # [B, S, rank + rot]
-            c_kv = RMSNorm(cfg.norm_eps, name="kv_norm")(kv[..., :rank])
+            c_kv = RMSNorm(cfg.norm_eps, cfg.mla_kv_scale, name="kv_norm")(
+                kv[..., :rank])
             inv_freq = rope_inv_freq(rot, cfg.rope_theta, cfg.rope_yarn)
             cs = rope_cos_sin_scale(cfg.rope_yarn)
             q_nope = q[..., :nope]
@@ -224,3 +224,18 @@ def _expanded(q_nope, q_rope, c_kv, k_rope, wk_b, wv_b, positions, scale):
                                v[:, :end]))
     return (outs[0] if len(outs) == 1
             else jnp.concatenate(outs, axis=1)).astype(dtype)
+
+
+def _up_init(scale: float, **kw):
+    """The initialiser of a low-rank path's up-projection (`wq_b`; `wk_b`,
+    `wv_b`): variance 1 / fan-in, which is flax's own `lecun_normal`, over the
+    square of the path's scale correction. `mla_scale_q_lora` and
+    `mla_scale_kv_lora` multiply by (hidden / rank)^0.5 because, under one
+    sigma for all matrices, what comes up from a rank r has r / hidden of the
+    variance of what comes from the hidden size; a random tree has to be one
+    the corrections are right for, or q and k_nope come out at 4 and 12 times
+    unit variance and the scores at a standard deviation of 5.7 (PERF.md
+    section 6, PR 42: served bf16 tokens then lay 1.87 below the float32
+    reference's best logit). At scale 1 the values are `lecun_normal`'s."""
+    return nn.initializers.variance_scaling(
+        scale ** -2, "fan_in", "truncated_normal", **kw)

@@ -1,7 +1,7 @@
 """The expert layer: a router over all the experts a model publishes, and
 the SwiGLU experts this device holds.
 
-One layer serves the two uses the repo has:
+One layer serves the uses the repo has:
 
 - the training dry-run's top-2 softmax mixture, every expert held and the
   leading `[E]` axis of the expert weights sharded over the `ep` mesh axis
@@ -13,6 +13,14 @@ One layer serves the two uses the repo has:
   this device, `[first_expert, first_expert + experts_held)`. The router
   keeps its published width; what experts held elsewhere would add is left
   out, and that partial result goes on. No row is dropped for any routing.
+  Four configurations are of this kind (Kimi K2, Trinity-Mini, Kimi Linear);
+- the fifth, a router with identity experts (LongCat-Flash: `moe_zero_experts`
+  outputs after the `moe_experts` routed ones, softmax over all of them,
+  weights not renormalised): a selection at or above `moe_experts` adds its
+  weight times the layer's input and computes nothing, so the number of real
+  experts a token uses varies from token to token. Every device computes the
+  identity part for its own tokens, like a shared expert; `experts_held` and
+  `first_expert` index the routed experts only.
 
 Two ways to the same sum, chosen by shape alone:
 
@@ -36,7 +44,8 @@ from ray_tpu.models.layers import SwiGLU
 
 def route(cfg, x, router, bias):
     """Which experts each row selects and with what weight: `(idx, w)`,
-    both `[..., k]`, over all `cfg.moe_experts` experts. float32 all
+    both `[..., k]`, over all the router's outputs (`cfg.moe_experts` routed
+    experts, then `cfg.moe_zero_experts` identity ones). float32 all
     through: a near-tie decided in bf16 is another model."""
     logits = jnp.einsum("...d,de->...e", x.astype(jnp.float32),
                         router.astype(jnp.float32),
@@ -49,7 +58,7 @@ def route(cfg, x, router, bias):
         raise ValueError(f"unknown moe_scoring {cfg.moe_scoring!r}")
     # noaux_tc: the bias moves the selection only, never the weights.
     choose = scores if bias is None else scores + bias.astype(jnp.float32)
-    k = min(cfg.moe_top_k, cfg.moe_experts)
+    k = min(cfg.moe_top_k, cfg.moe_experts + cfg.moe_zero_experts)
     _, idx = jax.lax.top_k(choose, k)
     w = jnp.take_along_axis(scores, idx, axis=-1)
     if cfg.moe_norm_topk and k > 1:
@@ -65,14 +74,19 @@ class MoE(nn.Module):
         """x: [B, S, D]. `serving` says no gradient is wanted and nothing is
         sharded over `ep`, so many rows may take the grouped path."""
         cfg = self.cfg
-        e_all, dm = cfg.moe_experts, cfg.d_model
+        e_all, dm = cfg.moe_experts + cfg.moe_zero_experts, cfg.d_model
         held = cfg.held_experts
         ff = cfg.moe_d_ff or cfg.d_ff
         per_expert = nn.initializers.lecun_normal(batch_axis=(0,))
         router = self.param("router", nn.initializers.normal(0.02),
                             (dm, e_all), jnp.float32)
-        bias = (self.param("router_bias", nn.initializers.normal(0.01),
-                           (e_all,), jnp.float32)
+        # The correction bias, random here as every weight: a few percent of
+        # the scores' spread, as the trained one that balances the load is. A
+        # sigmoid's scores are O(1): 0.01; a softmax's average 1 / outputs, and
+        # at 0.01 beside them the largest biases would be every token's choice.
+        bias = (self.param("router_bias", nn.initializers.normal(
+                    0.01 if cfg.moe_scoring == "sigmoid" else 1.0 / e_all),
+                    (e_all,), jnp.float32)
                 if cfg.moe_score_bias else None)
         w_gate = self.param("w_gate", per_expert, (held, dm, ff),
                             cfg.param_dtype).astype(cfg.dtype)
@@ -87,10 +101,17 @@ class MoE(nn.Module):
             if not self.is_initializing():
                 # Rows routed to each held expert, for the engine's counters
                 # (collected only where "stats" is mutable).
-                self.sow("stats", "expert_rows",
-                         onehot.sum((0, 1, 2)).astype(jnp.int32),
+                rows_here = onehot.sum((0, 1, 2)).astype(jnp.int32)
+                self.sow("stats", "expert_rows", rows_here,
                          reduce_fn=lambda a, b: a + b,
                          init_fn=lambda: jnp.zeros((held,), jnp.int32))
+                if cfg.moe_zero_experts:
+                    # (only a router with identity experts sows these, so
+                    # that no other model's program changes)
+                    self.sow("stats", "picks",
+                             zero_counts(cfg, idx, rows_here),
+                             reduce_fn=lambda a, b: a + b,
+                             init_fn=lambda: jnp.zeros((3,), jnp.int32))
         xc = x.astype(cfg.dtype)
         rows = x.shape[0] * x.shape[1]
         with jax.named_scope("moe_experts"):
@@ -107,11 +128,28 @@ class MoE(nn.Module):
                                         w_down)
                 y = jnp.einsum("ebsd,bse->bsd", expert_out,
                                gates.astype(cfg.dtype))
+        if cfg.moe_zero_experts:
+            with jax.named_scope("moe_zero_experts"):
+                # ONE weighted x: the sum of the identity selections' weights
+                zero_w = jnp.sum(jnp.where(idx >= cfg.moe_experts, w, 0.0), -1)
+                y = (y.astype(jnp.float32) + zero_w[..., None]
+                     * x.astype(jnp.float32)).astype(cfg.dtype)
         if cfg.moe_shared_experts:
             with jax.named_scope("shared_expert"):
                 y = y + SwiGLU(cfg, d_ff=ff * cfg.moe_shared_experts,
                                name="shared")(x)
         return y
+
+
+def zero_counts(cfg, idx, rows_here):
+    """[selections made, those that fell on an identity expert, held experts
+    that got at least one row (`rows_here`: the rows of each)] of one call,
+    int32: the engine's counters `moe_picks`, `moe_zero_picks`,
+    `moe_touched`."""
+    return jnp.stack([
+        jnp.asarray(idx.size, jnp.int32),
+        jnp.sum(idx >= cfg.moe_experts, dtype=jnp.int32),
+        jnp.sum(rows_here > 0, dtype=jnp.int32)])
 
 
 def _grouped(x, local, w, w_gate, w_up, w_down, tile: int):

@@ -6,14 +6,13 @@ TPU-native design notes:
   MLP matmuls split over "tp", parameters additionally over "fsdp"
   (ZeRO-3 analogue), activations between blocks sequence-sharded over "sp";
   XLA/GSPMD inserts the all-gathers/reduce-scatters over ICI.
-- Attention over a call's own rows (a training batch, a serving prefill, of
-  full and of window layers) goes through ray_tpu.ops.dot_product_attention:
-  the Pallas flash kernel on a TPU when no gradient is taken (the kernel has
-  no VJP, so a training step takes the XLA form), the XLA form elsewhere. A
-  decode step reads the cache through ops/decode_attention.py.
-- The reference framework has no model zoo of its own — this fills the role
-  its vLLM/torch delegation played (llm/_internal/serve/.../vllm_models.py
-  TP/PP passthrough), natively.
+- Attention over a call's own rows (a training batch, a serving prefill; full
+  and window layers) goes through ray_tpu.ops.dot_product_attention: the
+  Pallas flash kernel on a TPU when no gradient is taken (it has no VJP), else
+  the XLA form. A decode step reads the cache through ops/decode_attention.py.
+- A layer is mixer -> feed-forward, or (`moe_shortcut`, models/scmoe.py) two
+  such halves with the expert layer on a shortcut across the second. Fills the
+  role of the reference's vLLM/torch delegation (.../vllm_models.py), natively.
 """
 
 from __future__ import annotations
@@ -30,6 +29,7 @@ from ray_tpu.models.kda import KDA
 from ray_tpu.models.layers import RMSNorm, SwiGLU, YarnScaling, rope as _rope
 from ray_tpu.models.mla import MLA
 from ray_tpu.models.moe import MoE
+from ray_tpu.models.scmoe import shortcut_layer
 from ray_tpu.ops import dot_product_attention
 from ray_tpu.ops.attention import prefill_attention
 from ray_tpu.parallel.mesh import context_mesh_shape, spec_tree_like
@@ -53,23 +53,19 @@ class TransformerConfig:
     param_dtype: jnp.dtype = jnp.float32
     #: RMSNorm's epsilon, every norm of the model.
     norm_eps: float = 1e-6
-    #: A head's size where the model publishes one (`head_dim`); 0 is
-    #: d_model // n_heads.
+    #: A published head size (`head_dim`); 0 is d_model // n_heads.
     head_size: int = 0
     #: Layers whose attention sees the last `sliding_window` positions only
-    #: (key j is visible to query i iff 0 <= i - j < sliding_window): the
-    #: model's `layer_types`, True for a window layer; () is none. A window
-    #: layer's cache is a ring of `sliding_window` rows, position p in row
-    #: p mod sliding_window (`Attention._cached_attention`).
+    #: (key j visible to query i iff 0 <= i - j < sliding_window): the model's
+    #: `layer_types`, True for a window layer; () is none. Such a layer's cache
+    #: is a ring, position p in row p mod sliding_window (`_cached_attention`).
     sliding_window: int = 0
     window_layers: tuple = ()
-    #: Rotary embedding on the window layers only; a full layer's queries
-    #: and keys then carry no position.
+    #: Rotary embedding on the window layers only: a full layer has none.
     rope_window_only: bool = False
     #: RMSNorm of each head's query and key over the head's dims.
     qk_norm: bool = False
-    #: The attention's output times sigmoid(x W_g), a gate a head dim, before
-    #: the output projection.
+    #: The attention's output times sigmoid(x W_g), a head dim each, before wo.
     attn_gate: bool = False
     #: Two further norms a layer: each sublayer's output is normed before it
     #: joins the residual stream.
@@ -79,18 +75,15 @@ class TransformerConfig:
     #: The logits are the final hidden state times the embedding (True) or
     #: times a matrix of their own, `lm_head` (False).
     tie_embeddings: bool = True
-    #: The kind of each layer's mixer, a name a layer; () is "mha" in every
-    #: layer. "mha": K and V per head (`Attention`; GQA when n_kv_heads <
-    #: n_heads). "mla": one latent per token (`models/mla.py`), sized by the
-    #: five numbers below under their published names; `rope_yarn` blends
-    #: the rotary frequencies of its `qk_rope_head_dim` dims, and
-    #: `mla_rope` False leaves those dims unrotated (the model's
-    #: `mla_use_nope`: its latent attention carries no position). "kda": a
-    #: gated delta-rule linear attention (`models/kda.py`) of `kda_heads`
-    #: heads of `kda_head_dim`, a depthwise convolution of `kda_conv`
-    #: positions on q, k and v, its prefill a scan over chunks of
-    #: `kda_chunk` positions. Its cache is no rows per position but a state
-    #: a slot (`cache_kind_of`).
+    #: Each layer's mixer by name; () is "mha" in every layer. "mha": K and V
+    #: per head (`Attention`; GQA when n_kv_heads < n_heads). "mla": one latent
+    #: per token (`models/mla.py`), sized by the five numbers below under their
+    #: published names; `rope_yarn` blends the rotary frequencies of its
+    #: `qk_rope_head_dim` dims, `mla_rope` False leaves them unrotated (the
+    #: model's `mla_use_nope`: no position). "kda": a gated delta-rule linear
+    #: attention (`models/kda.py`) of `kda_heads` heads of `kda_head_dim`, a
+    #: depthwise convolution of `kda_conv` positions on q, k and v, a prefill
+    #: that scans chunks of `kda_chunk`, a state a slot (`cache_kind_of`).
     mixers: tuple = ()
     q_lora_rank: int = 0  # 0: queries are projected straight from x
     kv_lora_rank: int = 0
@@ -99,15 +92,18 @@ class TransformerConfig:
     v_head_dim: int = 0
     rope_yarn: Optional[YarnScaling] = None
     mla_rope: bool = True
+    #: What latent attention multiplies its queries (after `wq_b`) and its
+    #: normed `c_kv` by (a model's `mla_scale_q_lora`, `mla_scale_kv_lora`).
+    mla_q_scale: float = 1.0
+    mla_kv_scale: float = 1.0
     kda_heads: int = 0
     kda_head_dim: int = 0
     kda_conv: int = 4
     kda_chunk: int = 64
     #: >0 makes the feed-forward of every layer from `moe_first_layer` on an
-    #: expert layer (`models/moe.py`) whose router scores this many experts:
-    #: the count a model publishes. The defaults are a top-2 softmax mixture
-    #: of experts `d_ff` wide, all held, their leading [E] axis sharded over
-    #: the "ep" mesh axis.
+    #: expert layer (`models/moe.py`) whose router scores this many experts,
+    #: the count a model publishes. Defaults: a top-2 softmax mixture, experts
+    #: `d_ff` wide, all held, their leading [E] axis sharded over "ep".
     moe_experts: int = 0
     moe_top_k: int = 2
     moe_d_ff: int = 0  # an expert's width; 0: d_ff
@@ -117,20 +113,24 @@ class TransformerConfig:
     moe_score_bias: bool = False  # selection by score + a learnt bias
     moe_shared_experts: int = 0  # SwiGLUs beside the routed ones, never routed
     moe_first_layer: int = 0  # layers before it keep the dense SwiGLU
-    #: The experts held here, `[first_expert, first_expert + experts_held)`
-    #: of `moe_experts`; 0 holds them all. What the others would add to a
-    #: token is left out (one device's share under expert parallelism).
+    #: Identity experts after the routed ones among the router's outputs: one
+    #: selected adds its weight times the layer's input and computes nothing.
+    moe_zero_experts: int = 0
+    #: A layer is TWO mixer + dense feed-forward halves, and its expert layer
+    #: rides a shortcut across the second (`models/scmoe.py` `shortcut_layer`).
+    moe_shortcut: bool = False
+    #: The experts held here, `[first_expert, first_expert + experts_held)` of
+    #: `moe_experts`; 0: all. What the others would add to a token is left out.
     experts_held: int = 0
     first_expert: int = 0
     #: Rows of one expert in a tile of the expert layer's grouped path; the
     #: serving prefill takes that path above two tiles' worth of rows.
     moe_group_tile: int = 128
-    #: Width of a row of a cache leaf: the leaf's own width when 0 (head_dim
-    #: for K and V, kv_lora_rank + qk_rope_head_dim for a latent), else that
-    #: followed by zeros that are never read. The serving engine sets it to
-    #: the width the device's compiler lays such a row out in (llm/engine.py
-    #: `_probe_cache_row`), so that the cache's default on-device layout
-    #: is the one the decode loop computes in.
+    #: Width of a row of a cache leaf: the leaf's own when 0 (head_dim for K
+    #: and V, kv_lora_rank + qk_rope_head_dim for a latent), else that and then
+    #: zeros that are never read. The serving engine sets it to the width the
+    #: device's compiler lays such a row out in (llm/engine.py
+    #: `_probe_cache_row`): the cache's default layout is then the loop's own.
     cache_row: int = 0
 
     @property
@@ -289,19 +289,19 @@ class Attention(nn.Module):
 
 
 class Block(nn.Module):
+    """One layer: mixer -> feed-forward, or `models/scmoe.py`'s two halves."""
     cfg: TransformerConfig
-    #: this layer's feed-forward is the expert layer (cfg.is_moe_layer(i))
-    moe: bool = False
-    #: rows of this layer's attention window (cfg.window_of(i)); 0: full
-    window: int = 0
-    #: this layer's mixer (cfg.mixer_of(i))
-    mixer: str = "mha"
+    moe: bool = False  # the feed-forward is the expert layer (is_moe_layer)
+    window: int = 0  # rows of the attention's window (window_of); 0: full
+    mixer: str = "mha"  # this layer's mixer (mixer_of)
 
     @nn.compact
     def __call__(self, x, positions, decode: bool = False, kv_bound=None,
                  prompt_len=None, live=None):
         cfg = self.cfg
         norm = lambda name: RMSNorm(cfg.norm_eps, name=name)  # noqa: E731
+        if cfg.moe_shortcut:
+            return shortcut_layer(cfg, x, positions, decode, kv_bound, live)
         if self.mixer == "mla":
             a = MLA(cfg, name="attn")(norm("attn_norm")(x), positions, decode,
                                       kv_bound=kv_bound, live=live)

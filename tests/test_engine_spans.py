@@ -710,6 +710,68 @@ def test_the_window_gate_and_norm_parts_carry_their_names_in_the_program():
         eng.shutdown()
 
 
+def test_the_shortcut_and_identity_parts_carry_names_and_count_in_the_spans(
+        spans):
+    """The scopes a layer with its expert layer on a shortcut adds
+    (`model_type` `longcat_flash`): `moe_shortcut` around the branch from
+    the first half's feed-forward input to the join, `moe_zero_experts`
+    around the identity experts' part, the two dense halves `mlp_0` and
+    `mlp_1` beside the scopes the latent and expert layers had; and its
+    `engine.host_sync` spans carry the identity experts' three counters
+    beside the rows."""
+    import jax.numpy as jnp
+
+    arch = {"model_type": "longcat_flash", "attention_method": "MLA",
+            "q_lora_rank": 24, "kv_lora_rank": 16, "qk_nope_head_dim": 16,
+            "qk_rope_head_dim": 8, "v_head_dim": 16,
+            "mla_scale_q_lora": True, "mla_scale_kv_lora": True,
+            "ffn_hidden_size": 96, "expert_ffn_hidden_size": 32,
+            "n_routed_experts": 8, "zero_expert_num": 4,
+            "zero_expert_type": "identity", "moe_topk": 3,
+            "routed_scaling_factor": 6, "rope_theta": 1e7,
+            "rms_norm_eps": 1e-5}
+    eng = ContinuousEngine(LLMConfig(**CFG, arch=arch, experts_held=4),
+                           max_batch=2, decode_chunk=4)
+    try:
+        eng._cache = eng._init_cache()
+        chunk = eng._chunk.lower(
+            eng.params, eng._cache, eng._toks_dev, eng._lens_dev, eng._keys,
+            eng._temps_dev, eng._topks_dev, eng._topps_dev, 2, False,
+            jnp.int32(9))
+        new = {"moe_shortcut", "moe_zero_experts", "mlp_0", "mlp_1"}
+        assert new | {"mla_attention", "decode_attention", "moe_router",
+                      "moe_experts", "lm_head",
+                      "sampler"} <= scopes_of(chunk)
+        assert in_branches(chunk, "mla_attention", "decode_attention")
+        # the expert layer sits inside the shortcut's scope, the dense
+        # halves do not
+        names = op_names(chunk)
+        assert any("moe_shortcut/moe/moe_router" in n for n in names)
+        assert not any("moe_shortcut/mlp_" in n for n in names)
+        prefill = eng._prefill.lower(
+            eng.params, jnp.zeros((1, 8), jnp.int32), 3)
+        assert new | {"prefill_attention", "moe_router", "moe_experts",
+                      "lm_head"} <= scopes_of(prefill)
+        tracing._ctx.set(("5" * 32, "6" * 16))
+        stream = eng.submit([3, 4, 5, 6], SamplingParams(temperature=0.0,
+                                                         max_tokens=7))
+        tracing._ctx.set(None)
+        assert len(stream.tokens()) == 7
+        counted = [s["at"] for s in spans if s["n"] == "engine.host_sync"
+                   and s["at"].get("moe_steps")]
+        assert counted
+        for at in counted:
+            assert {"moe_rows", "moe_rows_busiest", "moe_picks",
+                    "moe_zero_picks", "moe_touched"} <= set(at)
+            # every slot of the batch x selections x expert layers x steps
+            assert at["moe_picks"] == at["moe_steps"] * 2 * 3 * CFG["n_layers"]
+            assert 0 <= at["moe_zero_picks"] <= at["moe_picks"]
+            assert at["moe_touched"] <= min(
+                at["moe_rows"], at["moe_steps"] * 4 * CFG["n_layers"])
+    finally:
+        eng.shutdown()
+
+
 def test_the_state_layers_parts_carry_their_names_in_the_program():
     """The scopes a model with gated delta-rule layers beside latent
     attention adds (`model_type` `kimi_linear`): the recurrence in its

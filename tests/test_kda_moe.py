@@ -523,10 +523,13 @@ def test_the_stats_name_the_state_beside_the_latent_rows(engine):
     st = engine.cache_stats()
     assert st["kv_heads"] == 1 and st["cache_kind"] == "latent"
     kinds = st["cache_kinds"]
-    assert kinds["state"] == {"layers": 6, "bytes_per_slot": 6 * STATE_SLOT,
+    # (a KDA layer keeps three leaves: S, the filters' tail, the correction)
+    assert kinds["state"] == {"layers": 6, "leaves": 18,
+                              "bytes_per_slot": 6 * STATE_SLOT,
                               "bytes": 2 * 6 * STATE_SLOT}
-    assert (kinds["full"]["layers"], kinds["full"]["rows"],
-            kinds["full"]["bytes"]) == (2, MAX_SEQ, 2 * 2 * MAX_SEQ * 40 * 4)
+    assert (kinds["full"]["layers"], kinds["full"]["leaves"],
+            kinds["full"]["rows"], kinds["full"]["bytes"]) == (
+        2, 2, MAX_SEQ, 2 * 2 * MAX_SEQ * 40 * 4)
     assert st["state_bytes"] == kinds["state"]["bytes"]
     assert st["cache_bytes"] == sum(v["bytes"] for v in kinds.values())
     assert 0 < kinds["full"]["live_share"] <= kinds["full"]["walk_share"] <= 1

@@ -237,7 +237,8 @@ def test_cache_stats_name_the_layout_and_count_boundary_copies(engine):
     assert 0.0 <= st["kv_live_share"] <= st["kv_walk_share"] <= 1.0
     # one kind of leaf: every layer keeps max_seq rows a slot
     assert st["kv_heads"] == CFG.n_heads and st["cache_kinds"] == {"full": {
-        "layers": CFG.n_layers, "rows": CFG.max_seq,
+        "layers": CFG.n_layers, "leaves": 2 * CFG.n_layers,  # K and V
+        "rows": CFG.max_seq,
         "bytes": st["cache_bytes"], "walk_share": st["kv_walk_share"],
         "live_share": st["kv_live_share"]}}
     assert st["cache_kind"] == "kv"
@@ -250,6 +251,45 @@ def test_cache_stats_name_the_layout_and_count_boundary_copies(engine):
     # the rows stay as wide as a head.
     assert st["cache_boundary_copies"] == 0
     assert engine.model.cfg.cache_row == 0
+
+
+def test_cache_stats_of_a_layer_with_two_latents_count_leaves_beside_layers():
+    """What `/v1/stats` reports for a model whose layer holds two latent
+    attentions and an expert layer with identity experts (`model_type`
+    `longcat_flash`, four layers as the benchmark's cut): `leaves` 8 beside
+    `layers` 4 with `bytes` over all 8, the identity experts, the router's
+    width, and the selections counted since start."""
+    arch = {"model_type": "longcat_flash", "attention_method": "MLA",
+            "q_lora_rank": 24, "kv_lora_rank": 16, "qk_nope_head_dim": 16,
+            "qk_rope_head_dim": 8, "v_head_dim": 16,
+            "mla_scale_q_lora": True, "mla_scale_kv_lora": True,
+            "ffn_hidden_size": 96, "expert_ffn_hidden_size": 32,
+            "n_routed_experts": 8, "zero_expert_num": 4,
+            "zero_expert_type": "identity", "moe_topk": 3,
+            "routed_scaling_factor": 6, "rope_theta": 1e7,
+            "rms_norm_eps": 1e-5}
+    eng = ContinuousEngine(LLMConfig(
+        vocab_size=64, d_model=32, n_layers=4, n_heads=2, max_seq=32,
+        dtype="float32", arch=arch, experts_held=2), max_batch=2,
+        decode_chunk=4)
+    try:
+        out = eng.submit([5, 6, 7], SamplingParams(temperature=0.0,
+                                                   max_tokens=6)).tokens()
+        assert len(out) == 6
+        st = eng.cache_stats()
+        full = st["cache_kinds"]["full"]
+        assert (full["layers"], full["leaves"], full["rows"]) == (4, 8, 32)
+        assert full["bytes"] == st["cache_bytes"] == 8 * 2 * 32 * 24 * 4
+        assert (st["zero_experts"], st["router_outputs"],
+                st["experts_held"], st["experts_published"]) == (4, 12, 2, 8)
+        steps = st["decode_steps"]
+        # every slot of the batch x 3 selections x 4 expert layers a step
+        assert st["moe_picks_total"] == steps * 2 * 3 * 4
+        assert 0 <= st["moe_zero_picks_total"] <= st["moe_picks_total"]
+        assert st["moe_rows_total"] <= (st["moe_picks_total"]
+                                        - st["moe_zero_picks_total"])
+    finally:
+        eng.shutdown()
 
 
 @pytest.mark.parametrize("row", [32, 128])

@@ -280,7 +280,7 @@ def test_v5e_chunk_program_updates_the_state_of_64_slots_in_place(kda_engine):
     assert st["cache_boundary_copies"] == 0
     state = 32 * 128 * 128 * 4 + 3 * 32 * 128 * 4 + 3 * 12288 * 2
     assert st["cache_kinds"]["state"] == {
-        "layers": 3, "bytes_per_slot": 3 * state,
+        "layers": 3, "leaves": 9, "bytes_per_slot": 3 * state,
         "bytes": KDA_SLOTS * 3 * state}
     assert st["cache_kinds"]["full"]["bytes"] == KDA_SLOTS * 4096 * 640 * 2
     layouts = st["cache_layout"].split("; ")
@@ -583,12 +583,30 @@ def test_v5e_chunk_program_with_the_ragged_kernel_moves_no_rows(
     assert not moved_rows(eng, text, "copy-start|copy-done"), name
 
 
-@pytest.mark.parametrize("name", ["latent", "kda"])
+#: A layer of two latent attentions with its expert layer on a shortcut and
+#: identity experts among the router's outputs (`model_type` `longcat_flash`)
+#: at Kimi K2's latent widths: ONE layer, two latent leaves.
+SCMOE = LLMConfig(
+    vocab_size=512, d_model=256, n_layers=1, n_heads=2, max_seq=256,
+    dtype="bfloat16", experts_held=4,
+    arch={"model_type": "longcat_flash", "attention_method": "MLA",
+          "q_lora_rank": 128, "kv_lora_rank": 512, "qk_nope_head_dim": 128,
+          "qk_rope_head_dim": 64, "v_head_dim": 128,
+          "mla_scale_q_lora": True, "mla_scale_kv_lora": True,
+          "ffn_hidden_size": 512, "expert_ffn_hidden_size": 128,
+          "n_routed_experts": 16, "zero_expert_num": 8,
+          "zero_expert_type": "identity", "moe_topk": 4,
+          "routed_scaling_factor": 6, "rope_theta": 1e7,
+          "rms_norm_eps": 1e-5})
+
+
+@pytest.mark.parametrize("name", ["latent", "kda", "scmoe"])
 def test_v5e_latent_chunk_program_with_the_ragged_kernel_moves_no_rows(
         chip, name, monkeypatch):
     """The twin of the test above for the `mla` family, at Kimi K2's
     latent widths (two latent layers of a short leaf) and at Kimi Linear's
-    published widths (64 slots of 4096 rows, one latent layer in four): on
+    published widths (64 slots of 4096 rows, one latent layer in four), and
+    for a layer that holds TWO latent attentions (PR 42: a call a LEAF): on
     the chip the bounded step of a latent layer is `ragged_latent_attention`,
     ONE Mosaic call a latent layer in the step's body, handed the leaf
     `[slots, rows, 640]` as the step's own update left it, in HBM; no
@@ -605,7 +623,8 @@ def test_v5e_latent_chunk_program_with_the_ragged_kernel_moves_no_rows(
 
     monkeypatch.setattr(attention, "on_tpu", lambda: True)
     cfg, slots, layers, block = {"latent": (LATENT, MAX_BATCH, 2, 256),
-                                 "kda": (KDA, KDA_SLOTS, 1, 512)}[name]
+                                 "kda": (KDA, KDA_SLOTS, 1, 512),
+                                 "scmoe": (SCMOE, MAX_BATCH, 2, 256)}[name]
     eng = build_compiled(chip, cfg=cfg, max_batch=slots)
     assert eng.model.cfg.cache_row == 640
     assert eng.cache_boundary_copies == 0
