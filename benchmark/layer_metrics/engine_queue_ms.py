@@ -2,10 +2,10 @@
 (llm/engine.py `_pending`).
 
 Median over the window's requests of their `engine.queue` span, in ms: from
-`submit()` putting the request into `_pending` to the prefill lane (or, with
-inline admission, the scheduler) taking it out. It is the engine's own part
-of `admit_wait_ms`, which runs from the proxy's root span to the start of
-the prefill's dispatch and so contains it."""
+`submit()` putting the request into `_pending` to the prefill lane taking it
+out. It is the engine's own part of `admit_wait_ms`, which runs from the
+proxy's root span to the start of the prefill's dispatch and so contains
+it."""
 
 from benchmark import engine_spans as es
 

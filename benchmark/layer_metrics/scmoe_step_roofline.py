@@ -8,16 +8,18 @@ over bandwidth and operations over the bf16 peak, from
 `benchmark/shapes_scmoe.py` and `benchmark/peaks.py`: every held weight
 outside the experts and the embedding table once; one expert's weights for
 each held expert a step TOUCHED (`moe_touched` of the chunks dispatched
-while the profiler ran: an expert that got no row need not be read, and
-with 8 rows a step over 16 held experts most get none; the three older
-expert rooflines count every held expert whole); every latent row visible to
+while the profiler ran, held against `moe_rows` and `moe_steps`:
+`benchmark/moe_spans.py` `touched_per_step`, which the three older expert
+rooflines read too; an expert that got no row need not be read, and with 8
+rows a step over 16 held experts most get none); every latent row visible to
 a live slot once a LEAF, two leaves a layer, 1152 bytes at the published
 sizes (`kv_live_full` x `active` on the traced chunks); the expert
-operations for the rows the engine counted (`moe_rows`). A program that
-counts no `moe_touched` gives nothing."""
+operations for the rows the engine counted (`moe_rows`). The share counted
+on all held experts is printed beside it. A program that counts no
+`moe_picks` gives nothing."""
 
-from benchmark import (engine_spans as es, peaks, scmoe_spans, shapes_scmoe,
-                       spans as sp)
+from benchmark import (engine_spans as es, moe_spans, peaks, scmoe_spans,
+                       shapes_scmoe, spans as sp)
 
 
 @es.never_raises
@@ -26,7 +28,7 @@ def read(run: dict):
     got = sp.decode_steps(run)
     chunks = [c["at"] for c in sp.traced_chunks(run)
               if "kv_live_full" in (c.get("at") or {})]
-    counted = scmoe_spans.totals(run, scmoe_spans.traced(run))
+    counted = scmoe_spans.totals(run, moe_spans.traced(run, "moe_picks"))
     if (not shapes_scmoe.is_scmoe(llm) or got is None or not chunks
             or counted is None):
         return None
@@ -36,24 +38,18 @@ def read(run: dict):
     rows = sum(c["kv_live_full"] * c["active"] * c["tokens"]
                for c in chunks) / tokens
     active = sum(c["active"] * c["tokens"] for c in chunks) / tokens
-    least = shapes_scmoe.decode_step_min_seconds(
-        llm, batch, rows, peaks.peaks(run["device"]["kind"]),
-        expert_rows=counted["rows"] / counted["steps"],
-        touched=counted["touched"] / counted["steps"])
-    all_held = shapes_scmoe.decode_step_min_seconds(
-        llm, batch, rows, peaks.peaks(run["device"]["kind"]),
-        expert_rows=counted["rows"] / counted["steps"])
-    parts = ", ".join(f"{k} {v / 1e9:.3f}" for k, v in sorted(
-        least["parts"].items(), key=lambda kv: -kv[1]))
+    peak = peaks.peaks(run["device"]["kind"])
+    found = moe_spans.least_step(
+        run, batch, lambda touched: shapes_scmoe.decode_step_min_seconds(
+            llm, batch, rows, peak,
+            expert_rows=counted["rows"] / counted["steps"], touched=touched))
+    if found is None:
+        return None
+    least, all_held, said = found
     step = secs / steps
-    print(f"scmoe_step_roofline: least step {least['seconds'] * 1e3:.3f} ms "
-          f"({least['bytes'] / 1e9:.3f} GB, {least['flops'] / 1e12:.3f} "
-          f"TFLOP, bound by {least['bound']}); GB by part: {parts}; "
-          f"{least['touched']:.2f} of {least['held']} held experts touched "
-          f"a step (over {counted['steps']} counted steps); counted on all "
-          f"held it would be {all_held['seconds'] * 1e3:.3f} ms, "
-          f"{100.0 * all_held['seconds'] / step:.1f}%; {active:.2f} slots "
-          f"active, {rows / max(active, 1e-9):.0f} latent rows visible a "
-          f"slot a leaf, {shapes_scmoe.latent_leaves(llm)} leaves",
-          flush=True)
+    print(f"scmoe_step_roofline: "
+          f"{moe_spans.step_said(least, all_held, said, step)}; "
+          f"{active:.2f} slots active, {rows / max(active, 1e-9):.0f} latent "
+          f"rows visible a slot a leaf, {shapes_scmoe.latent_leaves(llm)} "
+          f"leaves", flush=True)
     return 100.0 * least["seconds"] / step

@@ -5,13 +5,18 @@ kernel).
 The least time the chip could take for a decode step of this model over the
 time it took (`decode_step_ms`), in %. The least time is the larger of bytes
 over bandwidth and operations over the bf16 peak, from
-`benchmark/shapes_swa_moe.py` and `benchmark/peaks.py`: every held weight but
-the embedding table once; every cache row visible to a live slot once, its
-context in a full layer and at most the window in a window layer, 2048 bytes
-a row a layer at the published sizes; the expert operations for the rows the
-engine counted (`moe_rows`). Visible rows are the engine's own count on the
-chunks dispatched while the profiler ran (`kv_live_full`, `kv_live_window`
-x `active`)."""
+`benchmark/shapes_swa_moe.py` and `benchmark/peaks.py`: every held weight
+outside the routed experts and the embedding table once; one expert's
+weights for each held expert a step TOUCHED, where the program says how many
+(`moe_touched` of the chunks dispatched while the profiler ran, held against
+`moe_rows` and `moe_steps`: `benchmark/moe_spans.py` `touched_per_step`),
+and for every held expert where it does not; every cache row visible to a
+live slot once, its context in a full layer and at most the window in a
+window layer, 2048 bytes a row a layer at the published sizes; the expert
+operations for the rows the engine counted (`moe_rows`). Visible rows are
+the engine's own count on the chunks dispatched while the profiler ran
+(`kv_live_full`, `kv_live_window` x `active`). The share counted on all held
+experts is printed beside it: the scale of the ledger's lines up to PR 43."""
 
 from benchmark import (engine_spans as es, moe_spans, peaks, shapes_swa_moe,
                        spans as sp, swa_spans)
@@ -33,14 +38,16 @@ def read(run: dict):
     active = sum(c["active"] * c["tokens"] for c in chunks) / tokens
     counted = moe_spans.totals(run)
     expert_rows = counted[0] / counted[2] if counted else None
-    least = shapes_swa_moe.decode_step_min_seconds(
-        llm, batch, rows_full, rows_window,
-        peaks.peaks(run["device"]["kind"]), expert_rows)
-    parts = ", ".join(f"{k} {v / 1e9:.3f}" for k, v in sorted(
-        least["parts"].items(), key=lambda kv: -kv[1]))
-    print(f"swa_moe_step_roofline: least step {least['seconds'] * 1e3:.3f} "
-          f"ms ({least['bytes'] / 1e9:.3f} GB, {least['flops'] / 1e12:.3f} "
-          f"TFLOP, bound by {least['bound']}); GB by part: {parts}; "
+    peak = peaks.peaks(run["device"]["kind"])
+    found = moe_spans.least_step(
+        run, batch, lambda touched: shapes_swa_moe.decode_step_min_seconds(
+            llm, batch, rows_full, rows_window, peak, expert_rows,
+            touched=touched))
+    if found is None:
+        return None
+    least, all_held, said = found
+    print(f"swa_moe_step_roofline: "
+          f"{moe_spans.step_said(least, all_held, said, secs / steps)}; "
           f"{active:.2f} slots active, {rows_full / active:.0f} rows visible "
           f"a slot in a full layer and {rows_window / active:.0f} in a "
           f"window layer", flush=True)

@@ -5,13 +5,14 @@ and inside each decode chunk, beside the rows of each held expert
 (every slot of the batch x selections a token x expert layers x steps),
 `moe_zero_picks`, those that fell on an identity expert, and `moe_touched`,
 the held experts that got at least one row, summed over expert layers and
-steps. They come out as attributes of the `engine.host_sync` span that read
+steps (any router may count it: `moe_spans.py` has its contract and its
+reader). They come out as attributes of the `engine.host_sync` span that read
 the chunk. A program without such a router, or from before they were
 counted, writes none: the readers then return None."""
 
 from __future__ import annotations
 
-from benchmark import moe_spans, spans as sp
+from benchmark import moe_spans
 
 
 def chunks(run: dict) -> list[dict]:
@@ -22,7 +23,8 @@ def chunks(run: dict) -> list[dict]:
 
 def totals(run: dict, only: list[dict] | None = None):
     """{picks, zero_picks, touched, rows, steps} over the window (or over
-    `only`, a subset of `chunks`), or None."""
+    `only`, a subset of `chunks`: `moe_spans.traced(run, "moe_picks")` gives
+    those dispatched while the profiler ran), or None."""
     got = chunks(run) if only is None else only
     if not got:
         return None
@@ -32,11 +34,3 @@ def totals(run: dict, only: list[dict] | None = None):
             "rows": sum(c["moe_rows"] for c in got),
             "steps": sum(c["moe_steps"] for c in got)}
 
-
-def traced(run: dict) -> list[dict]:
-    """The counted chunks whose dispatch lay in the profiler's window (by
-    the chunk's ordinal `seq`, which its dispatch and its read both carry);
-    the whole window's where none can be matched."""
-    seqs = {c["at"].get("seq") for c in sp.traced_chunks(run)} - {None}
-    got = [c for c in chunks(run) if c.get("seq") in seqs]
-    return got or chunks(run)

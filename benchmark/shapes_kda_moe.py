@@ -14,9 +14,12 @@ own device and is not counted); and read every latent row that is visible to a l
 once in each MLA layer, `kv_lora_rank + qk_rope_head_dim` values. A second
 read of S inside a step, the latent walk beyond a slot's own rows and the
 zeros that widen a latent row to its tiles are what the roofline share
-exposes, so none of it is counted. The held experts are counted whole: a
-step of 64 rows leaves few of the 16 without a row, a deployment's step
-(1024 rows from 16 chips) none.
+exposes, so none of it is counted. Of the held experts, each one a step
+TOUCHED is read once (an expert no row was routed to need not be read: a
+step of 64 rows leaves about two of the 16 without one, a deployment's
+step, 1024 rows from 16 chips, none); every held expert where the program
+does not say how many its steps touched (`benchmark/moe_spans.py`
+`touched_per_step`).
 """
 
 from __future__ import annotations
@@ -116,12 +119,17 @@ def param_count(llm: dict) -> dict:
     return total
 
 
-def decode_step_weight_bytes(llm: dict) -> dict:
-    """Weight bytes one decode step reads, by part: every held weight once,
-    the embedding table left out."""
+def decode_step_weight_bytes(llm: dict, touched: float | None = None) -> dict:
+    """Weight bytes one decode step has to read, by part: every held weight
+    outside the routed experts once, the embedding table left out, and one
+    expert's weights for each held expert a step touched (`touched`, summed
+    over the expert layers; every held expert where None)."""
     size = _BYTES[llm["dtype"]]
-    return {k: v * size for k, v in param_count(llm).items()
-            if k != "embedding"}
+    parts = {k: v * size for k, v in param_count(llm).items()
+             if k != "embedding"}
+    if touched is not None:
+        parts["routed_experts"] = touched * expert_params(llm) * size
+    return parts
 
 
 def state_slot_bytes(llm: dict) -> int:
@@ -195,14 +203,15 @@ def expected_expert_rows(llm: dict, batch: int) -> float:
 
 
 def decode_step_min_seconds(llm: dict, batch: int, latent_rows: float,
-                            peak: dict,
-                            expert_rows: float | None = None) -> dict:
+                            peak: dict, expert_rows: float | None = None,
+                            touched: float | None = None) -> dict:
     """The least time the chip could take for one decode step, which of its
-    two limits sets it, and the bytes by part. `latent_rows` is the rows
-    visible to the live slots, summed over them."""
+    two limits sets it, the bytes by part, and the experts counted as read
+    beside those held. `latent_rows` is the rows visible to the live slots,
+    summed over them."""
     if expert_rows is None:
         expert_rows = expected_expert_rows(llm, batch)
-    weights = decode_step_weight_bytes(llm)
+    weights = decode_step_weight_bytes(llm, touched)
     parts = {"experts": weights["routed_experts"],
              "state": decode_step_state_bytes(llm, batch),
              "kda_matrices": weights["kda"],
@@ -215,6 +224,8 @@ def decode_step_min_seconds(llm: dict, batch: int, latent_rows: float,
     flops = decode_step_flops(llm, batch, latent_rows, expert_rows)
     t_bw = nbytes / peak["hbm_bytes_per_s"]
     t_fl = flops / peak["bf16_flops_per_s"]
+    held = expert_layers(llm) * experts_held(llm)
     return {"seconds": max(t_bw, t_fl), "bytes": nbytes, "flops": flops,
             "bound": "bandwidth" if t_bw >= t_fl else "compute",
-            "parts": parts}
+            "parts": parts, "held": held,
+            "touched": held if touched is None else touched}

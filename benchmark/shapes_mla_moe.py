@@ -9,9 +9,11 @@ embedding table (a lookup of `batch` rows), and the valid rows of the latent
 cache once, `kv_lora_rank + qk_rope_head_dim` values a row a layer. The walk
 to `max_seq`, a second read of the cache for the weighted sum and the padding
 of a row to its tile are what the roofline share exposes, so none of it is
-counted. The held experts are counted whole: a step of 32 rows may leave
-some of the 12 without a row, a deployment's step (256 rows from 32 chips)
-would not.
+counted. Of the held experts, each one a step TOUCHED is read once (an expert
+no row was routed to need not be read: a step of 32 rows leaves half of the
+12 without one, where a deployment's step, 256 rows from 32 chips, would
+leave few); every held expert where the program does not say how many its
+steps touched (`benchmark/moe_spans.py` `touched_per_step`).
 """
 
 from __future__ import annotations
@@ -74,12 +76,17 @@ def param_count(llm: dict) -> dict:
     return total
 
 
-def decode_step_weight_bytes(llm: dict) -> dict:
-    """Weight bytes one decode step reads, by part: every held weight once,
-    the embedding table left out."""
+def decode_step_weight_bytes(llm: dict, touched: float | None = None) -> dict:
+    """Weight bytes one decode step has to read, by part: every held weight
+    outside the routed experts once, the embedding table left out, and one
+    expert's weights for each held expert a step touched (`touched`, summed
+    over the expert layers; every held expert where None)."""
     size = _BYTES[llm["dtype"]]
-    return {k: v * size for k, v in param_count(llm).items()
-            if k != "embedding"}
+    parts = {k: v * size for k, v in param_count(llm).items()
+             if k != "embedding"}
+    if touched is not None:
+        parts["routed_experts"] = touched * expert_params(llm) * size
+    return parts
 
 
 def cache_row_values(llm: dict) -> int:
@@ -121,18 +128,21 @@ def expected_expert_rows(llm: dict, batch: int) -> float:
 
 
 def decode_step_min_seconds(llm: dict, batch: int, valid_rows: float,
-                            peak: dict, expert_rows: float | None = None
-                            ) -> dict:
+                            peak: dict, expert_rows: float | None = None,
+                            touched: float | None = None) -> dict:
     """The least time the chip could take for one decode step, which of its
-    two limits sets it, and the bytes by part."""
+    two limits sets it, the bytes by part, and the experts counted as read
+    beside those held."""
     if expert_rows is None:
         expert_rows = expected_expert_rows(llm, batch)
-    parts = dict(decode_step_weight_bytes(llm))
+    parts = dict(decode_step_weight_bytes(llm, touched))
     parts["latent_cache"] = decode_step_cache_bytes(llm, valid_rows)
     nbytes = sum(parts.values())
     flops = decode_step_flops(llm, batch, valid_rows, expert_rows)
     t_bw = nbytes / peak["hbm_bytes_per_s"]
     t_fl = flops / peak["bf16_flops_per_s"]
+    held = expert_layers(llm) * experts_held(llm)
     return {"seconds": max(t_bw, t_fl), "bytes": nbytes, "flops": flops,
             "bound": "bandwidth" if t_bw >= t_fl else "compute",
-            "parts": parts}
+            "parts": parts, "held": held,
+            "touched": held if touched is None else touched}

@@ -13,8 +13,8 @@ The least a step can do: read every weight outside the experts once, except
 the embedding table (a lookup of `batch` rows); each held expert a step
 TOUCHED once (an expert no row was routed to need not be read: with 32 rows
 of 12 selections over 768 outputs, 16 held experts get 8 rows a step and 60%
-of them none, where the three older configurations' rooflines count every
-held expert whole); and the rows of each latent leaf visible to a live slot
+of them none; the three older configurations' shapes count the same way
+since PR 45); and the rows of each latent leaf visible to a live slot
 once, `kv_lora_rank + qk_rope_head_dim` values a row (576, not the 640 its
 tiles pad it to on the chip).
 """

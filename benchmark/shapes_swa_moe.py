@@ -11,9 +11,12 @@ is visible to a live slot once: its context in a full layer, at most the
 window in a window layer, K and V of the key/value heads at the published
 head size. The walk beyond a slot's own rows, and the KV-fold products the
 program makes against rows of other key/value heads, are what the roofline
-share exposes, so none of it is counted. The held experts are counted whole:
-a step of 16 rows may leave some of the 16 without a row, a deployment's
-step (128 rows from 8 chips) would not.
+share exposes, so none of it is counted. Of the held experts, each one a step
+TOUCHED is read once (an expert no row was routed to need not be read: a
+step of 16 rows leaves a third of the 16 without one, where a deployment's
+step, 128 rows from 8 chips, would leave few); every held expert where the
+program does not say how many its steps touched (`benchmark/moe_spans.py`
+`touched_per_step`).
 """
 
 from __future__ import annotations
@@ -91,12 +94,17 @@ def param_count(llm: dict) -> dict:
     return total
 
 
-def decode_step_weight_bytes(llm: dict) -> dict:
-    """Weight bytes one decode step reads, by part: every held weight once,
-    the embedding table left out."""
+def decode_step_weight_bytes(llm: dict, touched: float | None = None) -> dict:
+    """Weight bytes one decode step has to read, by part: every held weight
+    outside the routed experts once, the embedding table left out, and one
+    expert's weights for each held expert a step touched (`touched`, summed
+    over the expert layers; every held expert where None)."""
     size = _BYTES[llm["dtype"]]
-    return {k: v * size for k, v in param_count(llm).items()
-            if k != "embedding"}
+    parts = {k: v * size for k, v in param_count(llm).items()
+             if k != "embedding"}
+    if touched is not None:
+        parts["routed_experts"] = touched * expert_params(llm) * size
+    return parts
 
 
 def cache_row_bytes(llm: dict) -> int:
@@ -159,17 +167,21 @@ def expected_expert_rows(llm: dict, batch: int) -> float:
 
 def decode_step_min_seconds(llm: dict, batch: int, rows_full: float,
                             rows_window: float, peak: dict,
-                            expert_rows: float | None = None) -> dict:
+                            expert_rows: float | None = None,
+                            touched: float | None = None) -> dict:
     """The least time the chip could take for one decode step, which of its
-    two limits sets it, and the bytes by part."""
+    two limits sets it, the bytes by part, and the experts counted as read
+    beside those held."""
     if expert_rows is None:
         expert_rows = expected_expert_rows(llm, batch)
-    parts = dict(decode_step_weight_bytes(llm))
+    parts = dict(decode_step_weight_bytes(llm, touched))
     parts.update(decode_step_cache_bytes(llm, rows_full, rows_window))
     nbytes = sum(parts.values())
     flops = decode_step_flops(llm, batch, rows_full, rows_window, expert_rows)
     t_bw = nbytes / peak["hbm_bytes_per_s"]
     t_fl = flops / peak["bf16_flops_per_s"]
+    held = expert_layers(llm) * experts_held(llm)
     return {"seconds": max(t_bw, t_fl), "bytes": nbytes, "flops": flops,
             "bound": "bandwidth" if t_bw >= t_fl else "compute",
-            "parts": parts}
+            "parts": parts, "held": held,
+            "touched": held if touched is None else touched}

@@ -18,6 +18,12 @@ TRACED = os.path.join(ROOT, "benchmark/tests/BENCHMARK.tiny-traced.json")
 
 OPEN_LOOP = {"loadgen_late_ms", "admit_wait_ms", "ttft_p50_ms", "ttft_p95_ms",
              "engine_queue_ms", "ready_wait_ms", "first_token_read_ms"}
+# The device's account (PR 36) needs no device plane, only chunks that the
+# device, not the scheduler's loop, paces: this toy may or may not give its
+# readers enough of them (`test_end_to_end_account_cpu.py` has a toy that
+# does).
+ACCOUNT = {"decode_step_window_ms", "admit_dev_share_window", "admit_dev_ms",
+           "handover_gap_share_window"}
 
 
 def traced_run(workload, seed):
@@ -41,8 +47,9 @@ def test_a_traced_run_reports_the_engines_metrics(workload, replicas, want):
     assert line["correct"] is True and line["failed"] == 0
     assert line["attempted"] > 5
     assert line["device"]["count"] == replicas
-    # a CPU trace has no device plane: the device's readers return nothing
-    assert set(line["metrics"]) == want
+    # a CPU trace has no device plane: the device's readers return nothing,
+    # and this toy has no expert layer for the experts' readers to count
+    assert want <= set(line["metrics"]) <= want | ACCOUNT
     assert all(m["value"] >= 0 for m in line["metrics"].values())
     assert f"of {replicas} replica(s) in the window" in out
     assert "the reader failed" not in out

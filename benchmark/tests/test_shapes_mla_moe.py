@@ -52,3 +52,24 @@ def test_a_decode_step_is_bound_by_its_weights():
     assert round(share["dense_ffn"], 2) == 0.10
     assert 9.9e-3 < least["seconds"] < 10.3e-3  # ~8.25 GB at 819 GB/s
     assert sh.expected_expert_rows(LLM, 32) == 5 * 8.0
+
+
+def test_a_step_counts_the_held_experts_it_touched():
+    """ISSUE 45: at the cell's 35,200 latent rows, every held expert read is
+    8,296,056,576 bytes and 10.13 ms; 6 of 12 a layer, 5.65 GB and 6.9 ms."""
+    pk = peaks.peaks("TPU v5e")
+    held = sh.decode_step_min_seconds(LLM, 32, 35200, pk, 40.5)
+    assert (held["bytes"], held["held"], held["touched"]) == (
+        8_296_056_576, 60, 60)
+    assert round(held["seconds"] * 1e3, 2) == 10.13
+    half = sh.decode_step_min_seconds(LLM, 32, 35200, pk, 40.5, touched=30)
+    assert half["bytes"] == 8_296_056_576 - 30 * 2 * 44_040_192
+    assert round(half["seconds"] * 1e3, 2) == 6.90
+    assert (half["held"], half["touched"]) == (60, 30)
+    # 5.8 to 6.1 of 12 a layer, the uniform draw's and a little more
+    low, high = (sh.decode_step_min_seconds(LLM, 32, 35200, pk, 40.5,
+                                            touched=5 * t)["seconds"]
+                 for t in (5.8, 6.1))
+    assert 6.79e-3 < low < 6.80e-3 and 6.95e-3 < high < 6.96e-3
+    assert sh.decode_step_weight_bytes(LLM) == sh.decode_step_weight_bytes(
+        LLM, touched=60)
