@@ -247,14 +247,16 @@ class ChipProbe:
         against `models/mla.py` `_latent_attention` over the whole leaf, at
         Kimi K2's and Kimi Linear's cells' shapes (32 slots of 64 heads, 64
         of 32, on 4096 rows of 640), ragged lengths and free rows. The
-        occupied-experts kernel (`ops/expert_decode.py`, an expert layer's
-        decode step for a router with identity experts) against the dense
-        arm of the same layer, at LongCat-Flash's shapes (32 rows of 6144,
-        16 held experts of 2048) under DENSE routing, every held expert
-        several rows: the arithmetic that cell's own `correct` cannot see
-        (one selection in 48 of its checked prompts reaches a held
-        expert). An exception from a kernel is reported in its row, and
-        the smoke fails on it."""
+        occupied-experts kernel (`ops/expert_decode.py`, every expert
+        layer's decode step on the chip) against the dense arm of the same
+        layer, at the four expert cells' shapes (LongCat-Flash's 32 rows
+        of 6144 on 16 held experts of 2048 with identity experts, Kimi K2's
+        32 of 7168 on 12 of 2048, Trinity-Mini's 16 of 2048 on 16 of 1024,
+        Kimi Linear's 64 of 2304 on 16 of 1024), under dense routing, every
+        held expert several rows, and sparse: the arithmetic the cells' own
+        `correct` sees little of (a few selections of their checked prompts
+        reach a held expert), held to the dense arm's bits. An exception
+        from a kernel is reported in its row, and the smoke fails on it."""
         import time
         import traceback
 
@@ -356,40 +358,69 @@ class ChipProbe:
                             scale).reshape(b, -1), 0),
                     h * rank)
 
-        # The expert decode step: LongCat-Flash's layer, a router narrow
-        # enough (16 + 8 outputs, 6 a row) that every held expert gets rows.
-        # `serving` picks the arm: a decode step takes the kernel on the
-        # chip, the same rows outside serving the dense arm.
+        # The expert decode step at the four expert cells' layers: a router
+        # narrow enough that every held expert gets rows (dense routing),
+        # and one sixteen times as wide, of which this share holds a
+        # sixteenth (sparse: some held experts get none, and are not read). `serving`
+        # picks the arm: a decode step takes the kernel on the chip, the
+        # same rows outside serving the dense arm. The kernel makes the
+        # dense arm's roundings and float32 sums in its order, so the two
+        # results are held to the same BITS (a share of 1e-3 is allowed for
+        # what the compiler makes of the shared expert beside either arm).
         from ray_tpu.models.moe import MoE
         from ray_tpu.models.transformer import TransformerConfig
         from ray_tpu.ops.expert_decode import occupied_refusal
 
-        layer = MoE(TransformerConfig(
-            vocab_size=8, d_model=6144, n_layers=1, n_heads=1, d_ff=2048,
-            max_seq=8, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
-            moe_experts=16, moe_zero_experts=8, moe_top_k=6, moe_d_ff=2048,
-            moe_norm_topk=False, moe_routed_scale=6.0, moe_score_bias=True))
-        x = jax.random.normal(jax.random.PRNGKey(43), (32, 1, 6144),
-                              jnp.bfloat16)
-        weights = jax.jit(layer.init)(jax.random.PRNGKey(44), x)
-        arms = {serving: jax.jit(lambda w, x, serving=serving: layer.apply(
-            w, x, serving=serving, mutable=["stats"])) for serving in
-            (True, False)}
-        picks = {serving: np.asarray(arm(weights, x)[1]["stats"]["picks"])
-                 for serving, arm in arms.items()}
-        compare("occupied_experts b32 d6144 on 16 held experts of 2048, "
-                f"touched {picks[True][2]}, read {picks[True][3]} "
-                f"(the dense arm {picks[False][3]})",
-                lambda: arms[True](weights, x)[0].reshape(32, -1),
-                lambda: arms[False](weights, x)[0].reshape(32, -1), 6144)
-        rows[-1]["ok"] = bool(rows[-1]["ok"] and picks[True][2] >= 8
-                              and picks[True][3] == picks[True][2]
-                              and picks[False][3] == 16)
+        sigmoid = dict(moe_scoring="sigmoid", moe_norm_topk=True,
+                       moe_routed_scale=2.5, moe_shared_experts=1)
+        expert_cells = {  # slots, hidden, held experts, expert width, router
+            "b32 d6144 x 2048": (32, 6144, 16, 2048, dict(
+                moe_zero_experts=8, moe_norm_topk=False,
+                moe_routed_scale=6.0)),
+            "b32 d7168 x 2048": (32, 7168, 12, 2048, sigmoid),
+            "b16 d2048 x 1024": (16, 2048, 16, 1024, sigmoid),
+            "b64 d2304 x 1024": (64, 2304, 16, 1024, sigmoid)}
+        refusals = {}
+        for cell, (b, d, held, ff, router) in expert_cells.items():
+            refusals[cell] = occupied_refusal((b, 1, d), ff, serving=True)
+            for routing, published in (("dense", held), ("sparse", 16 * held)):
+                layer = MoE(TransformerConfig(
+                    vocab_size=8, d_model=d, n_layers=1, n_heads=1, d_ff=ff,
+                    max_seq=8, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
+                    moe_experts=published, experts_held=held, moe_top_k=6,
+                    moe_d_ff=ff, moe_score_bias=True, **router))
+                x = jax.random.normal(jax.random.PRNGKey(43), (b, 1, d),
+                                      jnp.bfloat16)
+                weights = jax.jit(layer.init)(jax.random.PRNGKey(44), x)
+                arms = {serving: jax.jit(
+                    lambda w, x, serving=serving: layer.apply(
+                        w, x, serving=serving, mutable=["stats"]))
+                    for serving in (True, False)}
+                out = {serving: arm(weights, x)
+                       for serving, arm in arms.items()}
+                picks = {serving: np.asarray(o[1]["stats"]["picks"])
+                         for serving, o in out.items()}
+                compare(f"occupied_experts {cell.split(' x ')[0]} on {held} "
+                        f"held experts of {ff}, {routing} routing, touched "
+                        f"{picks[True][2]}, read {picks[True][3]} (the "
+                        f"dense arm {picks[False][3]})",
+                        lambda: out[True][0].reshape(b, -1),
+                        lambda: out[False][0].reshape(b, -1), d)
+                other_bits = float(np.mean(
+                    np.asarray(out[True][0], np.float32)
+                    != np.asarray(out[False][0], np.float32)))
+                rows[-1]["other_bits_share"] = other_bits
+                rows[-1]["ok"] = bool(
+                    rows[-1]["ok"] and other_bits <= 1e-3
+                    and picks[True][3] == picks[True][2]
+                    and picks[False][3] == held
+                    and (picks[True][2] == held if routing == "dense"
+                         else 0 < picks[True][2] < held))
+                del weights, out
 
         return {"platform": dev.platform, "device_kind": dev.device_kind,
                 "rows": rows,
-                "expert_kernel_refusal": occupied_refusal(
-                    x.shape, 2048, serving=True, counts_touched=True),
+                "expert_kernel_refusal": refusals,
                 "latent_kernel_refusal": latent_refusal(
                     (32, 4096, row), rank),
                 "prefill_kernel_refusal": kernel_refusal(
@@ -602,9 +633,9 @@ def chip_phase(ray_tpu, greedy: list) -> None:
            if rep["latent_kernel_refusal"] is None
            else f", and a latent model's through its own XLA walk "
                 f"({rep['latent_kernel_refusal']})")
-        + (", and a decode step of a router with identity experts reads "
-           "the held experts that got a row"
-           if rep["expert_kernel_refusal"] is None
+        + (", and an expert layer's decode step reads the held experts that "
+           "got a row"
+           if not any(rep["expert_kernel_refusal"].values())
            else f", and an expert layer's through every held expert "
                 f"({rep['expert_kernel_refusal']})")
         + "; a prefill goes through dot_product_attention, which for this "

@@ -1288,10 +1288,10 @@ class ContinuousEngine:
         _count_metric("LLM_MOE_ROWS", total)
         attrs = {"moe_rows": total, "moe_rows_busiest": int(rows.max()),
                  "moe_steps": n}
-        if len(counts) > len(rows):
-            # a router with identity experts: its selections, those on an
-            # identity expert, held experts that got a row, those read
-            attrs.update(self._count_picks(*counts[len(rows):]))
+        # every expert layer's four (`_PICK_COUNTERS`): the selections made,
+        # those on an identity expert, the held experts that got a row, and
+        # those whose weights the steps' arm read
+        attrs.update(self._count_picks(*counts[len(rows):]))
         return attrs
 
     def _init_cache(self):
@@ -2051,27 +2051,27 @@ class ContinuousEngine:
     # ------------------------------------------------ expert layers' counts
     def _moe_stats(self) -> dict:
         """What /v1/stats says of the expert layers: the share held, the rows
-        routed to it since start and, for a router with identity experts,
-        how many it has, the router's width, and the selections made since
-        start beside those that fell on an identity expert, the held experts
-        that got a row and those whose weights the steps read."""
+        routed to it since start, the selections made since start beside
+        those that fell on an identity expert, the held experts that got a
+        row and those whose weights the steps read and, for a router with
+        identity experts, how many it has and the router's width."""
         mcfg = self.model.cfg
         out = dict(experts_held=self._moe_held,
                    experts_published=mcfg.moe_experts,
                    first_expert=mcfg.first_expert,
-                   moe_rows_total=self.moe_rows_total)
+                   moe_rows_total=self.moe_rows_total,
+                   **{f"{name}_total": getattr(self, f"{name}_total")
+                      for name in _PICK_COUNTERS})
         if mcfg.moe_zero_experts:
             out.update(
                 zero_experts=mcfg.moe_zero_experts,
-                router_outputs=mcfg.moe_experts + mcfg.moe_zero_experts,
-                **{f"{name}_total": getattr(self, f"{name}_total")
-                   for name in _PICK_COUNTERS})
+                router_outputs=mcfg.moe_experts + mcfg.moe_zero_experts)
         return out
 
     def _count_picks(self, *counts) -> dict:
-        """A chunk's four counters of a router with identity experts
-        (`models/moe.py` `zero_counts`, summed over expert layers and
-        steps; `_PICK_COUNTERS` has their names): into the totals and the
+        """A chunk's four counters of its expert layers (`models/moe.py`
+        `zero_counts`, summed over expert layers and steps;
+        `_PICK_COUNTERS` has their names): into the totals and the
         process's metrics, and as `engine.host_sync`'s attributes."""
         got = dict(zip(_PICK_COUNTERS, map(int, counts), strict=True))
         for name, n in got.items():
@@ -2135,24 +2135,25 @@ def _longcat_flash(cfg, arch: dict) -> dict:
 
 def _moe_counters(mcfg) -> int:
     """Counters a decode step of this model carries to the host behind its
-    tokens: the rows of each held expert and, for a router with identity
-    experts, `models/moe.py` `zero_counts`' four; 0 without expert layers."""
+    tokens: the rows of each held expert and `models/moe.py` `zero_counts`'
+    four; 0 without expert layers."""
     held = mcfg.held_experts
-    return held + len(_PICK_COUNTERS) * bool(held and mcfg.moe_zero_experts)
+    return held + len(_PICK_COUNTERS) * bool(held)
 
 
 def _counted(stats, so_far):
     """`so_far` plus what one step's expert layers sowed into `stats`: the
-    rows of each held expert, then (a router with identity experts) the
-    four of `zero_counts`. Without those it is the sum it always was."""
+    rows of each held expert, then the four of `zero_counts`. `so_far` as
+    it is where no layer of the program is an expert layer (the probe's
+    one layer of a model whose first is dense)."""
     import jax
     import jax.numpy as jnp
 
     flat = jax.tree_util.tree_flatten_with_path(stats)[0]
-    picks = [leaf for path, leaf in flat if path[-1].key == "picks"]
-    if not picks:
-        return sum(jax.tree.leaves(stats), so_far)
-    rows = [leaf for path, leaf in flat if path[-1].key == "expert_rows"]
+    if not flat:
+        return so_far
+    rows, picks = ([leaf for path, leaf in flat if path[-1].key == key]
+                   for key in ("expert_rows", "picks"))
     return so_far + jnp.concatenate([sum(rows), sum(picks)])
 
 

@@ -122,15 +122,16 @@ class MoE(nn.Module):
                 y = y.reshape(x.shape).astype(cfg.dtype)
             elif occupied_refusal(
                     x.shape, ff, dtype=cfg.dtype,
-                    serving=serving and not self.is_initializing(),
-                    counts_touched=cfg.moe_zero_experts > 0) is None:
+                    serving=serving and not self.is_initializing()) is None:
                 rule.state_once("expert decode step: occupied-experts "
                                 "Pallas kernel")
                 gates = jnp.einsum("bsk,bske->bse", w, onehot)
+                # (y comes in cfg.dtype: the kernel makes the dense arm's
+                # roundings itself, where no compiler can take one away)
                 y, fetched = occupied_experts(
                     xc.reshape(rows, dm), gates.reshape(rows, held),
                     rows_here, w_gate, w_up, w_down)
-                y = y.reshape(x.shape).astype(cfg.dtype)
+                y = y.reshape(x.shape)
             else:
                 gates = jnp.einsum("bsk,bske->bse", w, onehot)
                 gate_h = nn.silu(jnp.einsum("bsd,edf->ebsf", xc, w_gate))
@@ -139,9 +140,9 @@ class MoE(nn.Module):
                                         w_down)
                 y = jnp.einsum("ebsd,bse->bsd", expert_out,
                                gates.astype(cfg.dtype))
-        if cfg.moe_zero_experts and not self.is_initializing():
-            # (only a router with identity experts sows these, so that no
-            # other model's program changes)
+        if not self.is_initializing():
+            # `moe_touched` and the kernel's order are made from the ONE
+            # `rows_here`, so what a step says it touched is what it read
             self.sow("stats", "picks",
                      zero_counts(cfg, idx, rows_here, fetched),
                      reduce_fn=lambda a, b: a + b,

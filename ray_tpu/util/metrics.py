@@ -471,13 +471,13 @@ LLM_MOE_ROWS = Counter(
     "rt_llm_moe_rows_total",
     description="rows routed to the held experts in decode steps, summed "
                 "over expert layers")
-#: A router with identity experts: every selection its decode steps' rows
-#: made, and those that fell on an identity expert (`_count_picks`, as the
-#: two after them).
+#: Every selection the decode steps' rows made, and those that fell on an
+#: identity expert, which only some routers have (`_count_picks`, as the two
+#: after them).
 LLM_MOE_PICKS = Counter(
     "rt_llm_moe_picks_total",
     description="expert selections made in decode steps, summed over expert "
-                "layers (a router with identity experts only)")
+                "layers")
 LLM_MOE_ZERO_PICKS = Counter(
     "rt_llm_moe_zero_picks_total",
     description="expert selections that fell on an identity (zero-"

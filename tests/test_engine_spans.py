@@ -717,8 +717,8 @@ def test_the_shortcut_and_identity_parts_carry_names_and_count_in_the_spans(
     the first half's feed-forward input to the join, `moe_zero_experts`
     around the identity experts' part, the two dense halves `mlp_0` and
     `mlp_1` beside the scopes the latent and expert layers had; and its
-    `engine.host_sync` spans carry the identity experts' three counters
-    beside the rows."""
+    `engine.host_sync` spans carry the expert layers' four counters beside
+    the rows (as every expert layer's do since PR 47)."""
     import jax.numpy as jnp
 
     arch = {"model_type": "longcat_flash", "attention_method": "MLA",

@@ -411,15 +411,32 @@ def test_the_expert_rows_ride_with_the_tokens_into_the_host_sync_span(spans):
         syncs = [s["at"] for s in spans if s["n"] == "engine.host_sync"]
         counted = [a for a in syncs if a.get("moe_steps")]
         assert counted and all(
-            {"moe_rows", "moe_rows_busiest", "moe_steps"} <= set(a)
+            {"moe_rows", "moe_rows_busiest", "moe_steps", "moe_picks",
+             "moe_zero_picks", "moe_touched", "moe_fetched"} <= set(a)
             for a in counted)
         # 12 tokens: the first from the prefill, then chunks of 4 steps
         assert sum(a["moe_steps"] for a in counted) == 12
         assert sum(a["moe_rows"] for a in counted) == eng.moe_rows_total
+        held = eng.cache_stats()["experts_held"]
         for a in counted:
             # 2 slots x 4 selections x 2 expert layers a step, at most
             assert (0 <= a["moe_rows_busiest"] <= a["moe_rows"]
-                    <= a["moe_steps"] * 16)
+                    <= a["moe_steps"] * 16 == a["moe_picks"])
+            # a sigmoid router's layers count what they touched (PR 47):
+            # at most a row's worth, at least the rows over the batch; the
+            # CPU's arm is the dense one, which reads every held expert
+            assert a["moe_zero_picks"] == 0
+            assert a["moe_rows"] / 2 <= a["moe_touched"] <= min(
+                a["moe_rows"], a["moe_fetched"])
+            assert a["moe_fetched"] == a["moe_steps"] * 2 * held
+        st = eng.cache_stats()
+        assert st["moe_touched_total"] == sum(
+            a["moe_touched"] for a in counted) > 0
+        assert st["moe_fetched_total"] == sum(
+            a["moe_fetched"] for a in counted)
+        assert (st["moe_picks_total"], st["moe_zero_picks_total"]) == (
+            12 * 16, 0)
+        assert "zero_experts" not in st  # (no identity experts here)
     finally:
         eng.shutdown()
 
@@ -441,7 +458,8 @@ def test_a_model_without_expert_layers_carries_no_extra_columns():
 def test_more_held_experts_than_slots_take_more_columns():
     eng = ContinuousEngine(LLMConfig(**WHOLE), max_batch=3, decode_chunk=2)
     try:
-        assert (eng._moe_held, eng._moe_cols) == (16, 6)
+        # 16 rows' counts and the layers' four (PR 47) on 3 slots: 7 columns
+        assert (eng._moe_held, eng._moe_cols) == (16, 7)
         toks = eng.generate([[1, 2, 3], [4, 5]], SamplingParams(
             temperature=0.0, max_tokens=5))
         assert [len(t) for t in toks] == [5, 5]

@@ -279,7 +279,7 @@ def test_the_four_older_arms_build_their_configs_as_before(name):
         llm = json.load(f)["llm_config"]
     cfg = model_config(LLMConfig(**llm))
     assert {k: getattr(cfg, k) for k in NEW_FIELDS} == NEW_FIELDS
-    assert _moe_counters(cfg) == cfg.held_experts
+    assert _moe_counters(cfg) == cfg.held_experts + 4 * bool(cfg.held_experts)
     fields = {f.name for f in dataclasses.fields(cfg)}
     assert fields - set(NEW_FIELDS) == {
         "vocab_size", "d_model", "n_layers", "n_heads", "n_kv_heads", "d_ff",
@@ -334,9 +334,10 @@ OLDER_EXPERT_ARMS = {
 
 @pytest.mark.parametrize("arm", list(OLDER_EXPERT_ARMS))
 def test_an_older_arms_expert_layer_sows_what_it_sowed_and_nothing_new(arm):
-    """`expert_rows` and no other statistic: the chunk program of a model
-    without identity experts carries the counters it carried (the cause
-    behind "the older cells' programs lower to the parent's text")."""
+    """`expert_rows` and, since PR 47, the four of `picks` that every
+    expert layer sows (their rooflines count the experts a step touched by
+    them): no selection on an identity expert, `moe_touched` from the rows,
+    and on the CPU every held expert read."""
     cfg = model_config(LLMConfig(**dict(SIZES, arch=OLDER_EXPERT_ARMS[arm],
                                         experts_held=4)))
     net = Transformer(cfg)
@@ -345,7 +346,11 @@ def test_an_older_arms_expert_layer_sows_what_it_sowed_and_nothing_new(arm):
     _, out = net.apply({"params": params}, toks, mutable=["stats"])
     sown = {jax.tree_util.keystr(path[-1:]): leaf.shape for path, leaf in
             jax.tree_util.tree_flatten_with_path(out["stats"])[0]}
-    assert sown == {"['expert_rows']": (4,)}
+    assert sown == {"['expert_rows']": (4,), "['picks']": (4,)}
+    moe = out["stats"]["layer_1"]["moe"]
+    picks, zero, touched, fetched = np.asarray(moe["picks"])
+    assert (picks, zero, fetched) == (8 * 2, 0, 4)
+    assert touched == (np.asarray(moe["expert_rows"]) > 0).sum() > 0
     assert "router_bias" in params["layer_1"]["moe"]
     # the bias of a sigmoid router keeps its scale: the seeds' values stand
     bias = np.asarray(params["layer_1"]["moe"]["router_bias"])

@@ -440,3 +440,17 @@ def test_a_chunks_span_carries_the_rows_walked_and_visible_by_kind(
     # (13 steps follow the first token; the scheduler may step once more)
     assert sum(a["moe_steps"] for a in counted) in (13, 14)
     assert all({"moe_rows", "moe_rows_busiest"} <= set(a) for a in counted)
+    # a sigmoid router's layers count what they touched and read (PR 47)
+    mcfg = engine.model.cfg
+    layers = mcfg.n_layers - mcfg.moe_first_layer
+    for a in counted:
+        assert a["moe_picks"] == a["moe_steps"] * layers * 2 * 2  # slots x k
+        assert a["moe_zero_picks"] == 0
+        assert a["moe_rows"] / 2 <= a["moe_touched"] <= min(
+            a["moe_rows"], a["moe_fetched"])
+        # the CPU's arm is the dense one: every held expert is read
+        assert a["moe_fetched"] == a["moe_steps"] * layers * 4
+    st = engine.cache_stats()
+    assert st["moe_fetched_total"] >= sum(a["moe_fetched"] for a in counted)
+    assert 0 < st["moe_touched_total"] <= st["moe_fetched_total"]
+    assert st["moe_zero_picks_total"] == 0 < st["moe_picks_total"]

@@ -296,6 +296,18 @@ def test_sse_ring_concurrent_clients_fifo_no_loss(shutdown_only):
         serve.shutdown()
 
 
+def _own_rings() -> list:
+    """The SSE stream rings in /dev/shm that a process of THIS test's
+    cluster made (a ring's name embeds its creator's pid, and the cluster's
+    processes descend from this one): under `-n 6` the neighbours' tests
+    stream through rings of their own beside it."""
+    import psutil
+
+    mine = {str(p.pid) for p in psutil.Process().children(recursive=True)}
+    return [path for path in glob.glob("/dev/shm/rtring_sse_*")
+            if os.path.basename(path).split("_")[2] in mine]
+
+
 def test_sse_token_ring_off_byte_identical_fallback(monkeypatch,
                                                     shutdown_only):
     """RT_TOKEN_RING=0: the classic per-item streaming-generator reply
@@ -313,7 +325,7 @@ def test_sse_token_ring_off_byte_identical_fallback(monkeypatch,
         with urllib.request.urlopen(_sse_request(base, 12),
                                     timeout=180) as r:
             for line in r:
-                rings_seen.extend(glob.glob("/dev/shm/rtring_sse_*"))
+                rings_seen.extend(_own_rings())
                 line = line.decode().strip()
                 if not line.startswith("data: "):
                     continue
