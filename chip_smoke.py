@@ -207,7 +207,7 @@ class ChipProbe:
         import numpy as np
 
         from ray_tpu.llm import LLMConfig
-        from ray_tpu.llm.engine import model_config
+        from ray_tpu.models.published import model_config
         from ray_tpu.models.transformer import Transformer
 
         cfg = LLMConfig(**model)

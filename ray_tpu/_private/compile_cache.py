@@ -1,4 +1,4 @@
-"""The one place JAX's persistent compilation cache is pointed somewhere.
+"""The one place for a compiled program's identity and for where JAX keeps it.
 
 Every process that compiles shares one directory, so that a replica, a
 pipeline stage or a second run of a program finds what an earlier process
@@ -17,7 +17,7 @@ compiled. The rule, in the order it is applied:
    its cache again — never one built from a temporary directory, a pid, a
    session id or the time.
 
-Worker processes get it through their spawn environment (node_agent.py
+Worker processes get both through their spawn environment (node_agent.py
 `_spawn_worker`); programs that compile in their own process
 (`__graft_entry__.py`, `bench.py`) call `apply()` before they import JAX.
 """
@@ -35,6 +35,21 @@ _CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
 
+def program_identity(env: MutableMapping[str, str] | None = None) -> None:
+    """A program's text names no caller: a Mosaic kernel's payload holds its
+    body's locations, ten frames of traceback each by JAX's default (a line
+    shifted above a Pallas call was another cache key), with ONE its own
+    frame in `ops/*.py` only (tests/test_v5e_compile.py). SET, as what a
+    program is, in `env` and on an imported JAX's config. (No tracebacks at
+    all would also strip the scopes a device trace is read by.)"""
+    env = os.environ if env is None else env
+    env["JAX_TRACEBACK_IN_LOCATIONS_LIMIT"] = "1"
+    if env is os.environ and "jax" in sys.modules:
+        import jax
+
+        jax.config.update("jax_traceback_in_locations_limit", 1)
+
+
 def apply(env: MutableMapping[str, str] | None = None) -> str:
     """Apply the module's rule to `env` (default: this process's
     environment). Returns the directory, "" where there is none.
@@ -44,6 +59,7 @@ def apply(env: MutableMapping[str, str] | None = None) -> str:
     a second is written by some runs and not by others, and a warm start
     never settles."""
     env = os.environ if env is None else env
+    program_identity(env)  # wherever the cache is, and where there is none
     path = env.get(DIR_ENV)
     if path:
         return path

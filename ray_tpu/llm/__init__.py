@@ -33,17 +33,13 @@ class LLMConfig:
     dtype: str = "float32"
     #: optional pytree of trained params; random init otherwise
     params: Any = None
-    #: The architecture, as the model's published `config.json` names it
-    #: (`model_type`, `q_lora_rank`, `kv_lora_rank`, the three head dims,
-    #: `intermediate_size`, `moe_intermediate_size`, `n_routed_experts` as
-    #: PUBLISHED, `num_experts_per_tok`, `n_shared_experts`,
-    #: `first_k_dense_replace`, `routed_scaling_factor`, `scoring_func`,
-    #: `topk_method`, `norm_topk_prob`, `rope_theta`, `rope_scaling`,
-    #: `rms_norm_eps`, `tie_word_embeddings`, ...). None is the Llama-style
+    #: The architecture, in the keys of the model's published `config.json`
+    #: (`model_type` first, and the experts as PUBLISHED): `models/published.py`
+    #: `model_config` has an arm a `model_type`, reads the rest from here and
+    #: refuses a key it does not know how to build. None is the Llama-style
     #: block the six sizes above describe alone (MHA, SwiGLU of 8/3 d, a tied
     #: head). The sizes above stay what is RUN (a vocabulary slice, a cut in
-    #: depth); `engine.model_config` reads the rest from here and refuses a
-    #: key it does not know how to build.
+    #: depth).
     arch: Optional[dict] = None
     #: This device's share of the routed experts under expert parallelism:
     #: `[first_expert, first_expert + experts_held)` of `n_routed_experts`;
@@ -63,7 +59,7 @@ class LLMEngine:
         import jax
         import jax.numpy as jnp
 
-        from ray_tpu.llm.engine import model_config
+        from ray_tpu.models.published import model_config
         from ray_tpu.models.transformer import Transformer
 
         self.cfg = cfg

@@ -34,12 +34,8 @@ engine workers via vllm_models.py:123-137). TPU-native design:
   reads each live slot's own rows (K and V, or latent rows) and nothing of
   a free one; elsewhere the step reads a static prefix of the slot cache
   chosen inside the program from `kv_bound`.
-- **In-graph sampling**: temperature / top-k / top-p / greedy are
-  vectorized per-slot inside the compiled step (each slot carries its own
-  sampling params and PRNG key), so mixed request settings share a batch.
-  The k-th largest logit is selected, not sorted for (`_kth_largest`); a
-  step sorts the vocabulary, once, only where a live row has a nucleus
-  (`_make_sampler`).
+- **In-graph sampling** (`llm/sampler.py`): per slot, inside the compiled
+  step; a step sorts the vocabulary only where a live row has a nucleus.
 - **TP over a mesh**: pass `mesh` (axis "tp") and params/caches shard via
   the model's Megatron PartitionSpecs; XLA inserts the ICI collectives.
 - **Zero-sync hot loop** (README "Serving hot loop"): decode chunks stay
@@ -71,7 +67,9 @@ from typing import Optional
 
 import numpy as np
 
-from ray_tpu._private import tracing as _tracing
+from ray_tpu._private import compile_cache, tracing as _tracing
+from ray_tpu.llm.sampler import _make_sampler, _sampler_path
+from ray_tpu.models.published import model_config
 
 logger = logging.getLogger(__name__)
 
@@ -222,118 +220,6 @@ class GenStream:
         return list(self)
 
 
-def _sampler_path(samplings) -> str:
-    """The sampler's path for a chunk whose occupants ask for `samplings`,
-    by the rule the device applies to its live rows: `greedy` (the
-    argmax-only program), `sort` (an occupant that samples has a nucleus,
-    `top_p` < 1: one sort of the vocabulary a step) or `select`."""
-    sampled = [s for s in samplings if s.temperature > 0.0]
-    if not sampled:
-        return "greedy"
-    return "sort" if any(s.top_p < 1.0 for s in sampled) else "select"
-
-
-def _kth_largest(x, k):
-    """The k-th largest value of each row of x [B, V] float32, k [B] in
-    1..V: exact, ties and all, without an order. The floats' bits, read as
-    ordered integers, are searched from the top bit down: 32 passes that
-    each count a row's values at or above a candidate (on the v5e 0.03-0.08
-    ms together at the serving shapes, where `lax.top_k` of 64 or 128
-    candidates is a `TopK` call that costs 0.87 of a full sort: PERF.md
-    section 6, PR 35). Written out, not a loop: the decode step keeps no
-    loop of its own (PERF.md section 7 (l))."""
-    import jax.numpy as jnp
-    from jax import lax
-
-    bits = lax.bitcast_convert_type(x, jnp.int32)
-    # a negative float's bits fall as it rises: flip them; lift the others
-    # above them by the sign bit
-    image = lax.bitcast_convert_type(
-        jnp.where(bits < 0, ~bits, bits | jnp.int32(-2**31)), jnp.uint32)
-    found = jnp.zeros((x.shape[0], 1), jnp.uint32)
-    for bit in range(31, -1, -1):
-        candidate = found | jnp.uint32(1 << bit)
-        at_or_above = jnp.sum(image >= candidate, axis=-1, keepdims=True)
-        found = jnp.where(at_or_above >= k[:, None], candidate, found)
-    found = lax.bitcast_convert_type(found, jnp.int32)
-    return lax.bitcast_convert_type(
-        jnp.where(found < 0, found & jnp.int32(2**31 - 1), ~found),
-        jnp.float32)
-
-
-def _kept_logits(logits, temp, top_k, top_p, live=None):
-    """The scaled logits [B, V] a row draws from, -inf where a token is not
-    kept: a row keeps every token whose scaled logit is >= its k-th largest
-    (ties included), and of those every token whose probability is >= the
-    smallest of the shortest prefix whose mass reaches top_p.
-
-    Which path a step takes is read from its own inputs. No order is taken
-    that no live sampling row asks for: without a nucleus in any of them the
-    k-th values are SELECTED (`_kth_largest`), or nothing is masked at all
-    where no row has a top_k either; a nucleus needs the kept values in
-    order, and the step sorts the vocabulary ONCE (the sorted probabilities
-    are the softmax of the sorted logits: softmax is monotone)."""
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
-
-    vocab = logits.shape[-1]
-    lt = logits / jnp.maximum(temp, 1e-6)[:, None]
-    sampled = temp > 0.0 if live is None else (temp > 0.0) & live
-    k_eff = jnp.clip(jnp.where(top_k > 0, top_k, vocab), 1, vocab)
-    by_k = sampled & (k_eff < vocab)  # rows whose top-k masks anything
-    by_p = sampled & (top_p < 1.0)
-
-    def below(kth):
-        return jnp.where(by_k[:, None] & (lt < kth), -jnp.inf, lt)
-
-    @jax.named_scope("select")
-    def select():
-        return lax.cond(jnp.any(by_k),
-                        lambda: below(_kth_largest(lt, k_eff)), lambda: lt)
-
-    @jax.named_scope("nucleus")
-    def nucleus():
-        ordered = jnp.sort(lt, axis=-1)[:, ::-1]
-        kth = jnp.take_along_axis(ordered, (k_eff - 1)[:, None], axis=-1)
-        lt_k = below(kth)
-        top = jnp.max(lt_k, axis=-1, keepdims=True)
-        e = jnp.exp(lt_k - top)
-        mass = jnp.sum(e, axis=-1, keepdims=True)
-        # the sorted probabilities, without sorting them
-        sp = jnp.where(by_k[:, None] & (ordered < kth), 0.0,
-                       jnp.exp(ordered - top) / mass)
-        csum = jnp.cumsum(sp, axis=-1)
-        # smallest prefix whose mass reaches top_p (always keeps the top
-        # token: csum - sp is 0 for it)
-        keep = (csum - sp) < top_p[:, None]
-        min_keep = jnp.min(jnp.where(keep, sp, jnp.inf), axis=-1,
-                           keepdims=True)
-        return jnp.where(by_p[:, None] & (e / mass < min_keep), -jnp.inf,
-                         lt_k)
-
-    return lax.cond(jnp.any(by_p), nucleus, select)
-
-
-def _make_sampler(vocab: int):
-    import jax
-    import jax.numpy as jnp
-
-    @jax.named_scope("sampler")  # its name in a device trace
-    def sample(logits, keys, temp, top_k, top_p, live=None):
-        """logits [B, V] f32; keys [B, 2] uint32; temp/top_k/top_p [B];
-        live [B] bool, the rows somebody reads (all of them without it).
-        temp <= 0 -> greedy. top_k <= 0 -> disabled. top_p >= 1 -> disabled.
-        The draw is `categorical` over `_kept_logits`."""
-        assert logits.shape[-1] == vocab
-        greedy = jnp.argmax(logits, axis=-1)
-        drawn = jax.vmap(jax.random.categorical)(
-            keys, _kept_logits(logits, temp, top_k, top_p, live))
-        return jnp.where(temp <= 0.0, greedy, drawn).astype(jnp.int32)
-
-    return sample
-
-
 class _Slot:
     """One request's occupancy of batch row `slot`, from its hand-over
     (`_splice`) to the end of its stream. Every chunk dispatched for it
@@ -432,268 +318,42 @@ class _Phases:
         self.end(record)
 
 
-# ------------------------------------------------------- stage slicing
-def model_config(cfg):
-    """LLMConfig -> TransformerConfig, the single place the serving model
-    shape is derived (ContinuousEngine and the pipeline stages must agree
-    bit-for-bit: a pipelined run is the SAME model cut at layer
-    boundaries, so matched-parameter A/B comparisons stay honest).
-
-    Without `cfg.arch` the model is the Llama-style block of the six sizes.
-    With it, the published keys say the rest; every key that bears on the
-    arithmetic is either built or refused here."""
-    import jax.numpy as jnp
-
-    from ray_tpu.models.transformer import TransformerConfig, YarnScaling
-
-    sizes = dict(vocab_size=cfg.vocab_size, d_model=cfg.d_model,
-                 n_layers=cfg.n_layers, n_heads=cfg.n_heads,
-                 max_seq=cfg.max_seq, dtype=jnp.dtype(cfg.dtype))
-    arch = cfg.arch
-    if arch is None:
-        if cfg.experts_held or cfg.first_expert:
-            raise ValueError("experts_held / first_expert need an `arch` "
-                             "with routed experts")
-        return TransformerConfig(
-            n_kv_heads=cfg.n_heads, d_ff=int(cfg.d_model * 8 / 3) // 8 * 8,
-            **sizes)
-    kind = arch.get("model_type")
-    arms = {"afmoe": _afmoe, "kimi_linear": _kimi_linear,
-            "longcat_flash": _longcat_flash}  # each: the fields past the sizes
-    if kind in arms:
-        return TransformerConfig(**arms[kind](cfg, arch), **sizes)
-    if kind not in ("kimi_k2", "deepseek_v3"):
-        raise ValueError(f"no model is built for model_type {kind!r}")
-    want = {"hidden_act": "silu", "attention_bias": False, "n_group": 1,
-            "topk_group": 1, "topk_method": "noaux_tc", "moe_layer_freq": 1,
-            "num_nextn_predict_layers": 0}
-    odd = {k: arch[k] for k, v in want.items() if arch.get(k, v) != v}
-    if odd:
-        raise ValueError(f"not built: {odd} (built: {want})")
-    if arch.get("num_key_value_heads", cfg.n_heads) != cfg.n_heads:
-        raise ValueError("latent attention has one latent for all heads: "
-                         "num_key_value_heads must equal the heads")
-    published = int(arch["n_routed_experts"])
-    held = _experts_held(cfg, published)
-    return TransformerConfig(
-        n_kv_heads=cfg.n_heads, d_ff=int(arch["intermediate_size"]),
-        rope_theta=float(arch["rope_theta"]),
-        norm_eps=float(arch["rms_norm_eps"]),
-        tie_embeddings=bool(arch["tie_word_embeddings"]),
-        mixers=("mla",) * cfg.n_layers,
-        q_lora_rank=int(arch["q_lora_rank"] or 0),
-        kv_lora_rank=int(arch["kv_lora_rank"]),
-        qk_nope_head_dim=int(arch["qk_nope_head_dim"]),
-        qk_rope_head_dim=int(arch["qk_rope_head_dim"]),
-        v_head_dim=int(arch["v_head_dim"]),
-        rope_yarn=YarnScaling.from_config(arch.get("rope_scaling")),
-        moe_experts=published, moe_top_k=int(arch["num_experts_per_tok"]),
-        moe_d_ff=int(arch["moe_intermediate_size"]),
-        moe_scoring=arch["scoring_func"],
-        moe_norm_topk=bool(arch["norm_topk_prob"]),
-        moe_routed_scale=float(arch["routed_scaling_factor"]),
-        moe_score_bias=True,  # noaux_tc's e_score_correction_bias
-        moe_shared_experts=int(arch["n_shared_experts"] or 0),
-        moe_first_layer=int(arch["first_k_dense_replace"]),
-        experts_held=held, first_expert=cfg.first_expert, **sizes)
-
-
-def _experts_held(cfg, published: int) -> int:
-    """How many of the `published` routed experts this device holds."""
-    held = cfg.experts_held or published
-    if not 0 <= cfg.first_expert <= published - held:
-        raise ValueError(
-            f"experts [{cfg.first_expert}, {cfg.first_expert + held}) are "
-            f"not among the {published} published")
-    return held
-
-
-def _afmoe(cfg, arch: dict) -> dict:
-    """The fields of a `model_type: afmoe` decoder (Arcee's Trinity family)
-    beyond the six sizes: grouped-query attention with a published head
-    size, window and full layers by `layer_types`, query/key norms, a
-    sigmoid gate on the attention's output, rotary embedding on the window
-    layers only, four norms a layer, the embedding scaled by sqrt(d) under
-    `mup_enabled`, leading dense layers, then sigmoid-routed experts beside
-    shared ones. What no key of `config.json` states is the published
-    modelling code's (ISSUE 32 lists each under `assumed`)."""
-    want = {"hidden_act": "silu", "n_group": 1, "topk_group": 1,
-            "num_expert_groups": 1, "num_limited_groups": 1,
-            "rope_scaling": None, "attention_bias": False}
-    odd = {k: arch[k] for k, v in want.items() if arch.get(k, v) != v}
-    if odd:
-        raise ValueError(f"not built: {odd} (built: {want})")
-    kinds = list(arch["layer_types"])[:cfg.n_layers]
-    if len(kinds) < cfg.n_layers or set(kinds) - {"sliding_attention",
-                                                  "full_attention"}:
-        raise ValueError(f"layer_types must name {cfg.n_layers} layers as "
-                         f"sliding_attention or full_attention: {kinds}")
-    kv_heads = int(arch["num_key_value_heads"])
-    if cfg.n_heads % kv_heads:
-        raise ValueError(f"{cfg.n_heads} heads do not share {kv_heads} "
-                         f"key/value heads evenly")
-    published = int(arch["num_experts"])
-    return dict(
-        n_kv_heads=kv_heads, head_size=int(arch["head_dim"]),
-        d_ff=int(arch["intermediate_size"]),
-        rope_theta=float(arch["rope_theta"]),
-        norm_eps=float(arch["rms_norm_eps"]),
-        tie_embeddings=bool(arch["tie_word_embeddings"]),
-        sliding_window=int(arch["sliding_window"]),
-        window_layers=tuple(k == "sliding_attention" for k in kinds),
-        rope_window_only=True, qk_norm=True, attn_gate=True,
-        sandwich_norm=True,
-        emb_scale=(float(cfg.d_model) ** 0.5 if arch.get("mup_enabled")
-                   else 1.0),
-        moe_experts=published, moe_top_k=int(arch["num_experts_per_tok"]),
-        moe_d_ff=int(arch["moe_intermediate_size"]),
-        moe_scoring=arch["score_func"],
-        moe_norm_topk=bool(arch["route_norm"]),
-        moe_routed_scale=float(arch["route_scale"]),
-        moe_score_bias=True,  # the router's `expert_bias`: selection only
-        moe_shared_experts=int(arch["num_shared_experts"] or 0),
-        moe_first_layer=int(arch["num_dense_layers"]),
-        experts_held=_experts_held(cfg, published),
-        first_expert=cfg.first_expert)
-
-
-def _kimi_linear(cfg, arch: dict) -> dict:
-    """The fields of a `model_type: kimi_linear` decoder (Moonshot's Kimi
-    Linear) beyond the six sizes: gated delta-rule layers (`models/kda.py`)
-    and latent-attention layers without a position (`mla_use_nope`), each
-    named once by `linear_attn_config`'s two lists (counted from 1), leading
-    dense layers, then sigmoid-routed experts beside shared ones. What no
-    key of `config.json` states is the published modelling code's (ISSUE 34
-    lists each under `assumed`)."""
-    want = {"hidden_act": "silu", "num_expert_group": 1, "topk_group": 1,
-            "moe_layer_freq": 1, "num_nextn_predict_layers": 0,
-            "rope_scaling": None}
-    odd = {k: arch[k] for k, v in want.items() if arch.get(k, v) != v}
-    if odd:
-        raise ValueError(f"not built: {odd} (built: {want})")
-    if arch.get("num_key_value_heads", cfg.n_heads) != cfg.n_heads:
-        raise ValueError("latent attention has one latent for all heads: "
-                         "num_key_value_heads must equal the heads")
-    lin = arch["linear_attn_config"]
-    kda, full = list(lin["kda_layers"]), list(lin["full_attn_layers"])
-    named = collections.Counter(kda + full)
-    if any(named[i] != 1 for i in range(1, cfg.n_layers + 1)):
-        raise ValueError(
-            f"kda_layers and full_attn_layers must name each of the layers "
-            f"1..{cfg.n_layers} exactly once: {kda}, {full}")
-    published = int(arch["num_experts"])
-    return dict(
-        n_kv_heads=cfg.n_heads, d_ff=int(arch["intermediate_size"]),
-        rope_theta=float(arch.get("rope_theta", 10000.0)),
-        norm_eps=float(arch["rms_norm_eps"]),
-        tie_embeddings=bool(arch["tie_word_embeddings"]),
-        mixers=tuple("kda" if i in kda else "mla"
-                     for i in range(1, cfg.n_layers + 1)),
-        q_lora_rank=int(arch["q_lora_rank"] or 0),
-        kv_lora_rank=int(arch["kv_lora_rank"]),
-        qk_nope_head_dim=int(arch["qk_nope_head_dim"]),
-        qk_rope_head_dim=int(arch["qk_rope_head_dim"]),
-        v_head_dim=int(arch["v_head_dim"]),
-        mla_rope=not arch["mla_use_nope"],
-        kda_heads=int(lin["num_heads"]), kda_head_dim=int(lin["head_dim"]),
-        kda_conv=int(lin["short_conv_kernel_size"]),
-        moe_experts=published, moe_top_k=int(arch["num_experts_per_token"]),
-        moe_d_ff=int(arch["moe_intermediate_size"]),
-        moe_scoring=arch["moe_router_activation_func"],
-        moe_norm_topk=bool(arch["moe_renormalize"]),
-        moe_routed_scale=float(arch["routed_scaling_factor"]),
-        moe_score_bias=True,  # the router's e_score_correction_bias
-        moe_shared_experts=int(arch["num_shared_experts"] or 0),
-        moe_first_layer=int(arch["first_k_dense_replace"]),
-        experts_held=_experts_held(cfg, published),
-        first_expert=cfg.first_expert)
-
-
 def _layer_kinds(mcfg) -> dict:
     """The kind of cache LEAVES each layer keeps (`cache_kind_of`; a layer
     may keep several) by the layer's name in the cache collection."""
     return {f"layer_{i}": mcfg.cache_kind_of(i) for i in range(mcfg.n_layers)}
 
 
-def stage_layer_split(n_layers: int, n_stages: int) -> list[tuple[int, ...]]:
-    """Contiguous, balanced layer ranges, one per pipeline stage (the
-    remainder layers go to the EARLIEST stages: the last stage already
-    carries final_norm + the tied head + the sampler)."""
-    if not (1 <= n_stages <= n_layers):
-        raise ValueError(
-            f"n_stages ({n_stages}) must be in [1, n_layers ({n_layers})]")
-    base, rem = divmod(n_layers, n_stages)
-    out, start = [], 0
-    for s in range(n_stages):
-        n = base + (1 if s < rem else 0)
-        out.append(tuple(range(start, start + n)))
-        start += n
-    return out
+#: `models/moe.py` `zero_counts`' four, by the names they go by from here
+#: on: `<name>` on `engine.host_sync`, `<name>_total` in `/v1/stats`,
+#: `LLM_<NAME>` (`rt_llm_<name>_total`) in `util/metrics.py`. One less
+#: `moe_fetched` over held experts x expert layers x steps is the share of
+#: the held experts a step did not read.
+_PICK_COUNTERS = ("moe_picks", "moe_zero_picks", "moe_touched", "moe_fetched")
 
 
-def stage_param_slice(params: dict, layers: tuple, first: bool,
-                      last: bool) -> dict:
-    """This stage's shard of a full Transformer param tree. Layer keys keep
-    their GLOBAL names (`layer_{i}`) so a shard is a strict subtree of the
-    full checkpoint; the embedding rides along on the first stage (embed)
-    and the last (tied output head), an untied `lm_head` on the last."""
-    out = {}
-    tied = "lm_head" not in params
-    if first or (last and tied):
-        out["tok_emb"] = params["tok_emb"]
-    for i in layers:
-        out[f"layer_{i}"] = params[f"layer_{i}"]
-    if last:
-        out["final_norm"] = params["final_norm"]
-        if not tied:
-            out["lm_head"] = params["lm_head"]
-    return out
+def _moe_counters(mcfg) -> int:
+    """Counters a decode step of this model carries to the host behind its
+    tokens: the rows of each held expert and `models/moe.py` `zero_counts`'
+    four; 0 without expert layers."""
+    held = mcfg.held_experts
+    return held + len(_PICK_COUNTERS) * bool(held)
 
 
-def make_stage_net(mcfg, layers: tuple, first: bool, last: bool):
-    """Flax module computing one pipeline stage's slice of the Transformer:
-    embed (first stage) -> layers[a:b] -> final_norm + the output head
-    (last stage). Per-layer module names match the full model's, so
-    stage_param_slice output applies directly and a 1-stage net is
-    numerically the full Transformer."""
-    import flax.linen as nn
+def _counted(stats, so_far):
+    """`so_far` plus what one step's expert layers sowed into `stats`: the
+    rows of each held expert, then the four of `zero_counts`. `so_far` as
+    it is where no layer of the program is an expert layer (the probe's
+    one layer of a model whose first is dense)."""
+    import jax
+    import jax.numpy as jnp
 
-    from ray_tpu.models.transformer import Block, RMSNorm, output_head
-
-    if any(mcfg.window_of(i) for i in layers):
-        raise NotImplementedError(
-            "pipeline stages keep one kind of cache leaf, max_seq rows a "
-            "slot (llm/pipeline.py `place`, `_init_cache`, and no "
-            "`prompt_len` for a ring's hand-over): a model with window "
-            "layers is served by ContinuousEngine only")
-
-    if any(mcfg.cache_kind_of(i) == "state" for i in layers):
-        raise NotImplementedError(
-            "pipeline stages keep rows per position only (llm/pipeline.py "
-            "`place`, `_init_cache`, and no `prompt_len` for where a padded "
-            "prefill's state ends): a model with state layers (recurrent "
-            "state a slot) is served by ContinuousEngine only")
-
-    class _StageNet(nn.Module):
-        @nn.compact
-        def __call__(self, x, positions, decode: bool = True):
-            emb = None
-            if first or (last and mcfg.tie_embeddings):
-                emb = self.param(
-                    "tok_emb", nn.initializers.normal(0.02),
-                    (mcfg.vocab_size, mcfg.d_model), mcfg.param_dtype)
-            if first:
-                x = emb[x].astype(mcfg.dtype)
-            for i in layers:
-                x = Block(mcfg, moe=mcfg.is_moe_layer(i),
-                          mixer=mcfg.mixer_of(i),
-                          name=f"layer_{i}")(x, positions, decode=decode)
-            if last:
-                x = RMSNorm(mcfg.norm_eps, name="final_norm")(x)
-                x = output_head(self, mcfg, x, emb)
-            return x
-
-    return _StageNet()
+    flat = jax.tree_util.tree_flatten_with_path(stats)[0]
+    if not flat:
+        return so_far
+    rows, picks = ([leaf for path, leaf in flat if path[-1].key == key]
+                   for key in ("expert_rows", "picks"))
+    return so_far + jnp.concatenate([sum(rows), sum(picks)])
 
 
 def _rows_columns(rows, batch: int):
@@ -709,6 +369,22 @@ def _rows_columns(rows, batch: int):
 
 def _rows_from_columns(columns: np.ndarray, held: int) -> np.ndarray:
     return columns.T.reshape(-1)[:held]
+
+
+def _count_metric(name: str, n: int) -> None:
+    """`ray_tpu.util.metrics.<name>` up by n; never the caller's problem."""
+    if n:
+        try:
+            from ray_tpu.util import metrics as _metrics
+
+            getattr(_metrics, name).inc(n)
+        except Exception:
+            pass
+
+
+def _agreed(values: set):
+    """The one value of a set whose members all agree, else None."""
+    return values.pop() if len(values) == 1 else None
 
 
 def _start_host_copy(arr) -> None:
@@ -740,7 +416,6 @@ class ContinuousEngine:
         import jax
         import jax.numpy as jnp
 
-        from ray_tpu.llm import LLMConfig  # noqa: F401 (type)
         from ray_tpu.models.transformer import Transformer
 
         self.cfg = cfg
@@ -861,20 +536,20 @@ class ContinuousEngine:
 
     # ------------------------------------------------------------ compiled
     def _build_compiled(self):
-        import functools
-
         import jax
         import jax.numpy as jnp
 
         from ray_tpu.models.transformer import Transformer
 
+        compile_cache.program_identity()  # whoever made this process
         sampler = self._sampler
-        # Expert layers: columns behind a chunk's tokens, totals since start.
-        self.moe_touched_total = self.moe_fetched_total = 0
+        # Expert layers: the columns behind a chunk's tokens, and since start
+        # the rows routed to the held experts and `_PICK_COUNTERS`' four.
         self._moe_held = self.model.cfg.held_experts
         self._moe_cols = -(-_moe_counters(self.model.cfg) // self.max_batch)
         self.moe_rows_total = 0
-        self.moe_picks_total = self.moe_zero_picks_total = 0
+        for name in _PICK_COUNTERS:
+            setattr(self, f"{name}_total", 0)
         # The decode steps dispatched since start, those whose attention
         # over K and V or latent rows is a ragged kernel (`_decode_blocks`),
         # the cache rows they walked a slot, and the rows a live slot had
@@ -1167,8 +842,9 @@ class ContinuousEngine:
             mcfg, mixers=mcfg.mixers[at:], window_layers=mcfg.window_layers[at:],
             moe_first_layer=max(0, mcfg.moe_first_layer - at))
         one = Transformer(dataclasses.replace(one, n_layers=1))
-        params = stage_param_slice(self.params, (at,), True, True)
-        params["layer_0"] = params.pop(f"layer_{at}")
+        params = {name: leaf for name, leaf in self.params.items()
+                  if not name.startswith("layer_")}
+        params["layer_0"] = self.params[f"layer_{at}"]
         cache = self._cache_shapes(one, params)
         auto = jax.tree.map(
             lambda leaf: Format(Layout.AUTO, leaf.sharding), cache)
@@ -2078,104 +1754,3 @@ class ContinuousEngine:
             setattr(self, f"{name}_total", getattr(self, f"{name}_total") + n)
             _count_metric(f"LLM_{name.upper()}", n)
         return got
-
-
-# Helpers of what stands above, kept down here: the serving programs that
-# hold a Pallas kernel carry the line numbers of their callers in this file
-# (`model_config` .. `_fill_pipeline`) inside the kernel's payload, and with
-# them in their compile-cache key (PERF.md section 6, PRs 41 and 42).
-def _longcat_flash(cfg, arch: dict) -> dict:
-    """The fields of a `model_type: longcat_flash` decoder (Meituan's
-    LongCat-Flash) beyond the six sizes: every layer two latent attentions
-    and two dense SwiGLUs with ONE expert layer on a shortcut across the
-    second half (`models/scmoe.py`); a softmax router over the routed experts
-    AND `zero_expert_num` identity experts, selection by score + correction
-    bias, weights the scores times `routed_scaling_factor`, not renormalised;
-    the two low-rank scale corrections of its latent attention; plain rotary
-    frequencies. What no key of `config.json` states is the published
-    modelling code's (ISSUE 42 lists each under `assumed`)."""
-    want = {"hidden_act": "silu", "attention_bias": False,
-            "attention_method": "MLA", "zero_expert_type": "identity",
-            "rope_scaling": None, "norm_topk_prob": False,
-            "router_bias": False}
-    odd = {k: arch[k] for k, v in want.items() if arch.get(k, v) != v}
-    if odd:
-        raise ValueError(f"not built: {odd} (built: {want})")
-    if arch.get("num_key_value_heads", cfg.n_heads) != cfg.n_heads:
-        raise ValueError("latent attention has one latent for all heads: "
-                         "num_key_value_heads must equal the heads")
-    published = int(arch["n_routed_experts"])
-    q_rank, kv_rank = int(arch["q_lora_rank"] or 0), int(arch["kv_lora_rank"])
-
-    def lora_scale(on, rank) -> float:
-        """(hidden / rank)^0.5 where the model says so (and has the rank)."""
-        return (cfg.d_model / rank) ** 0.5 if on and rank else 1.0
-
-    return dict(
-        n_kv_heads=cfg.n_heads, d_ff=int(arch["ffn_hidden_size"]),
-        rope_theta=float(arch["rope_theta"]),
-        norm_eps=float(arch["rms_norm_eps"]),
-        tie_embeddings=bool(arch.get("tie_word_embeddings", False)),
-        mixers=("mla",) * cfg.n_layers,
-        q_lora_rank=q_rank, kv_lora_rank=kv_rank,
-        qk_nope_head_dim=int(arch["qk_nope_head_dim"]),
-        qk_rope_head_dim=int(arch["qk_rope_head_dim"]),
-        v_head_dim=int(arch["v_head_dim"]),
-        mla_q_scale=lora_scale(arch.get("mla_scale_q_lora"), q_rank),
-        mla_kv_scale=lora_scale(arch.get("mla_scale_kv_lora"), kv_rank),
-        moe_experts=published, moe_top_k=int(arch["moe_topk"]),
-        moe_d_ff=int(arch["expert_ffn_hidden_size"]),
-        moe_scoring="softmax", moe_norm_topk=False,
-        moe_routed_scale=float(arch["routed_scaling_factor"]),
-        moe_score_bias=True,  # the router's e_score_correction_bias
-        moe_zero_experts=int(arch["zero_expert_num"]), moe_shortcut=True,
-        experts_held=_experts_held(cfg, published),
-        first_expert=cfg.first_expert)
-
-
-def _moe_counters(mcfg) -> int:
-    """Counters a decode step of this model carries to the host behind its
-    tokens: the rows of each held expert and `models/moe.py` `zero_counts`'
-    four; 0 without expert layers."""
-    held = mcfg.held_experts
-    return held + len(_PICK_COUNTERS) * bool(held)
-
-
-def _counted(stats, so_far):
-    """`so_far` plus what one step's expert layers sowed into `stats`: the
-    rows of each held expert, then the four of `zero_counts`. `so_far` as
-    it is where no layer of the program is an expert layer (the probe's
-    one layer of a model whose first is dense)."""
-    import jax
-    import jax.numpy as jnp
-
-    flat = jax.tree_util.tree_flatten_with_path(stats)[0]
-    if not flat:
-        return so_far
-    rows, picks = ([leaf for path, leaf in flat if path[-1].key == key]
-                   for key in ("expert_rows", "picks"))
-    return so_far + jnp.concatenate([sum(rows), sum(picks)])
-
-
-def _agreed(values: set):
-    """The one value of a set whose members all agree, else None."""
-    return values.pop() if len(values) == 1 else None
-
-
-def _count_metric(name: str, n: int) -> None:
-    """`ray_tpu.util.metrics.<name>` up by n; never the caller's problem."""
-    if n:
-        try:
-            from ray_tpu.util import metrics as _metrics
-
-            getattr(_metrics, name).inc(n)
-        except Exception:
-            pass
-
-
-#: `models/moe.py` `zero_counts`' four, by the names they go by from here
-#: on: `<name>` on `engine.host_sync`, `<name>_total` in `/v1/stats`,
-#: `LLM_<NAME>` (`rt_llm_<name>_total`) in `util/metrics.py`. One less
-#: `moe_fetched` over held experts x expert layers x steps is the share of
-#: the held experts a step did not read.
-_PICK_COUNTERS = ("moe_picks", "moe_zero_picks", "moe_touched", "moe_fetched")

@@ -6,8 +6,8 @@ boundaries into N pipeline stages — each a long-lived actor bound into
 one compiled DAG (`stage0.step -> stage1.step -> ...`) — and runs decode
 iterations as DAG invocations:
 
-- **Stage slicing**: engine.stage_layer_split / stage_param_slice /
-  make_stage_net keep per-layer module names GLOBAL (`layer_{i}`), so a
+- **Stage slicing**: `stage_layer_split` / `stage_param_slice` /
+  `make_stage_net` keep per-layer module names GLOBAL (`layer_{i}`), so a
   stage's params are a strict subtree of the full checkpoint and the
   pipelined model is bit-compatible with the single-process one.
 - **Microbatched occupancy**: the batch splits into `n_mb` microbatches;
@@ -47,17 +47,99 @@ import queue
 import threading
 import time
 import uuid
-from typing import Any, Optional
+from typing import Optional
 
 import numpy as np
 
 from ray_tpu._private.rtconfig import CONFIG
 from ray_tpu.llm.engine import (GenStream, SamplingParams, _count_tokens,
-                                _make_sampler, _Slot, make_stage_net,
-                                model_config, stage_layer_split,
-                                stage_param_slice)
+                                _Slot)
+from ray_tpu.llm.sampler import _make_sampler
+from ray_tpu.models.published import model_config
 
 logger = logging.getLogger(__name__)
+
+
+def stage_layer_split(n_layers: int, n_stages: int) -> list[tuple[int, ...]]:
+    """Contiguous, balanced layer ranges, one per pipeline stage (the
+    remainder layers go to the EARLIEST stages: the last stage already
+    carries final_norm + the tied head + the sampler)."""
+    if not (1 <= n_stages <= n_layers):
+        raise ValueError(
+            f"n_stages ({n_stages}) must be in [1, n_layers ({n_layers})]")
+    base, rem = divmod(n_layers, n_stages)
+    out, start = [], 0
+    for s in range(n_stages):
+        n = base + (1 if s < rem else 0)
+        out.append(tuple(range(start, start + n)))
+        start += n
+    return out
+
+
+def stage_param_slice(params: dict, layers: tuple, first: bool,
+                      last: bool) -> dict:
+    """This stage's shard of a full Transformer param tree. Layer keys keep
+    their GLOBAL names (`layer_{i}`) so a shard is a strict subtree of the
+    full checkpoint; the embedding rides along on the first stage (embed)
+    and the last (tied output head), an untied `lm_head` on the last."""
+    out = {}
+    tied = "lm_head" not in params
+    if first or (last and tied):
+        out["tok_emb"] = params["tok_emb"]
+    for i in layers:
+        out[f"layer_{i}"] = params[f"layer_{i}"]
+    if last:
+        out["final_norm"] = params["final_norm"]
+        if not tied:
+            out["lm_head"] = params["lm_head"]
+    return out
+
+
+def make_stage_net(mcfg, layers: tuple, first: bool, last: bool):
+    """Flax module computing one pipeline stage's slice of the Transformer:
+    embed (first stage) -> layers[a:b] -> final_norm + the output head
+    (last stage). Per-layer module names match the full model's, so
+    stage_param_slice output applies directly and a 1-stage net is
+    numerically the full Transformer."""
+    import flax.linen as nn
+
+    from ray_tpu.models.transformer import Block, RMSNorm, output_head
+
+    if any(mcfg.window_of(i) for i in layers):
+        raise NotImplementedError(
+            "pipeline stages keep one kind of cache leaf, max_seq rows a "
+            "slot (llm/pipeline.py `place`, `_init_cache`, and no "
+            "`prompt_len` for a ring's hand-over): a model with window "
+            "layers is served by ContinuousEngine only")
+
+    if any(mcfg.cache_kind_of(i) == "state" for i in layers):
+        raise NotImplementedError(
+            "pipeline stages keep rows per position only (llm/pipeline.py "
+            "`place`, `_init_cache`, and no `prompt_len` for where a padded "
+            "prefill's state ends): a model with state layers (recurrent "
+            "state a slot) is served by ContinuousEngine only")
+
+    class _StageNet(nn.Module):
+        @nn.compact
+        def __call__(self, x, positions, decode: bool = True):
+            emb = None
+            if first or (last and mcfg.tie_embeddings):
+                emb = self.param(
+                    "tok_emb", nn.initializers.normal(0.02),
+                    (mcfg.vocab_size, mcfg.d_model), mcfg.param_dtype)
+            if first:
+                x = emb[x].astype(mcfg.dtype)
+            for i in layers:
+                x = Block(mcfg, moe=mcfg.is_moe_layer(i),
+                          mixer=mcfg.mixer_of(i),
+                          name=f"layer_{i}")(x, positions, decode=decode)
+            if last:
+                x = RMSNorm(mcfg.norm_eps, name="final_norm")(x)
+                x = output_head(self, mcfg, x, emb)
+            return x
+
+    return _StageNet()
+
 
 # ---------------------------------------------------------------- occupancy
 #: Cumulative per-stage busy time in THIS process (stage actors record into

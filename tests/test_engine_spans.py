@@ -185,7 +185,7 @@ def test_the_stats_count_how_rows_changed_hands(engine, spans):
 
 def test_the_stats_and_the_chunk_spans_name_the_samplers_path(engine, spans):
     """`sampler` on `engine.dispatch_chunk` is the sampler's path for the
-    chunk's occupants (`greedy`, `select`, `sort`: `llm/engine.py`
+    chunk's occupants (`greedy`, `select`, `sort`: `llm/sampler.py`
     `_sampler_path`), and `sampler_steps`, `sampler_steps_select` of
     `cache_stats` (what `/v1/stats` reports) count the steps of the sampled
     program and those in which it sorted nothing."""

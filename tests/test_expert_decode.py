@@ -27,8 +27,9 @@ from jax.sharding import Mesh
 
 from ray_tpu._private import tracing
 from ray_tpu.llm import LLMConfig
-from ray_tpu.llm.engine import ContinuousEngine, SamplingParams, model_config
+from ray_tpu.llm.engine import ContinuousEngine, SamplingParams
 from ray_tpu.models import moe
+from ray_tpu.models.published import model_config
 from ray_tpu.models.transformer import TransformerConfig
 from ray_tpu.ops import attention
 from ray_tpu.ops import expert_decode as ed

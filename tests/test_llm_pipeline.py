@@ -21,8 +21,8 @@ import pytest
 
 import ray_tpu
 from ray_tpu.llm import LLMConfig
-from ray_tpu.llm.engine import (ContinuousEngine, SamplingParams,
-                                stage_layer_split, stage_param_slice)
+from ray_tpu.llm.engine import ContinuousEngine, SamplingParams
+from ray_tpu.llm.pipeline import stage_layer_split, stage_param_slice
 
 # Small enough for seconds-scale CPU tests; d_model=64 x microbatch=4 puts
 # the decode activation (4*1*64 f32 = 1KiB) exactly at the device-edge

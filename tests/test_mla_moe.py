@@ -23,9 +23,9 @@ if ROOT not in sys.path:
 from benchmark import manifest  # noqa: E402
 from ray_tpu._private import tracing  # noqa: E402
 from ray_tpu.llm import LLMConfig  # noqa: E402
-from ray_tpu.llm.engine import (ContinuousEngine, SamplingParams,  # noqa: E402
-                                model_config)
+from ray_tpu.llm.engine import ContinuousEngine, SamplingParams  # noqa: E402
 from ray_tpu.models import layers  # noqa: E402
+from ray_tpu.models.published import model_config  # noqa: E402
 from ray_tpu.models.mla import softmax_scale  # noqa: E402
 from ray_tpu.models.moe import MoE  # noqa: E402
 from ray_tpu.models.transformer import Transformer  # noqa: E402

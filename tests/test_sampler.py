@@ -1,4 +1,4 @@
-"""The sampler selects where it used to sort (`llm/engine.py`
+"""The sampler selects where it used to sort (`llm/sampler.py`
 `_make_sampler`, `_kth_largest`): the kept set of every row, ties included,
 is the one the sort-based sampler kept, the same key draws the same token,
 and the lowered decode program holds one full-width sort, on the path a
@@ -16,9 +16,9 @@ import numpy as np
 import pytest
 
 from ray_tpu.llm import LLMConfig
-from ray_tpu.llm.engine import (ContinuousEngine, SamplingParams,
-                                _kept_logits, _kth_largest, _make_sampler,
-                                _sampler_path)
+from ray_tpu.llm.engine import ContinuousEngine, SamplingParams
+from ray_tpu.llm.sampler import (_kept_logits, _kth_largest, _make_sampler,
+                                 _sampler_path)
 
 W = 128  # a lane tile: the widths a selection by candidates would turn on
 VOCAB = 1000

@@ -31,8 +31,9 @@ from benchmark import manifest  # noqa: E402
 from ray_tpu._private import tracing  # noqa: E402
 from ray_tpu.llm import LLMConfig  # noqa: E402
 from ray_tpu.llm.engine import (ContinuousEngine, SamplingParams,  # noqa: E402
-                                _moe_counters, make_stage_net, model_config,
-                                stage_param_slice)
+                                _moe_counters)
+from ray_tpu.llm.pipeline import make_stage_net, stage_param_slice  # noqa: E402
+from ray_tpu.models.published import model_config  # noqa: E402
 from ray_tpu.models.moe import MoE  # noqa: E402
 from ray_tpu.models.transformer import (Transformer,  # noqa: E402
                                         TransformerConfig, param_specs)

@@ -26,8 +26,9 @@ if ROOT not in sys.path:
 from benchmark import manifest  # noqa: E402
 from ray_tpu._private import tracing  # noqa: E402
 from ray_tpu.llm import LLMConfig, LLMEngine  # noqa: E402
-from ray_tpu.llm.engine import (ContinuousEngine, SamplingParams,  # noqa: E402
-                                make_stage_net, model_config)
+from ray_tpu.llm.engine import ContinuousEngine, SamplingParams  # noqa: E402
+from ray_tpu.llm.pipeline import make_stage_net  # noqa: E402
+from ray_tpu.models.published import model_config  # noqa: E402
 from ray_tpu.models.layers import SwiGLU  # noqa: E402
 from ray_tpu.models.moe import MoE  # noqa: E402
 from ray_tpu.models.transformer import (TransformerConfig,  # noqa: E402

@@ -125,25 +125,57 @@ def test_whole_host_grant_sets_no_process_bounds(shutdown_only):
 
 
 # ---------------------------------------------------------- the compile cache
+#: What `compile_cache.program_identity` sets, whatever rule places the cache.
+IDENTITY = {"JAX_TRACEBACK_IN_LOCATIONS_LIMIT": "1"}
+
+
 def test_compile_cache_rule():
-    # Set outside: used as it is, and nothing at all is set here.
+    # Set outside the program: used as it is; nothing else about the cache
+    # is set.
     env = {"JAX_COMPILATION_CACHE_DIR": "/some/dir"}
     assert compile_cache.apply(env) == "/some/dir"
-    assert env == {"JAX_COMPILATION_CACHE_DIR": "/some/dir"}
+    assert env == {"JAX_COMPILATION_CACHE_DIR": "/some/dir", **IDENTITY}
     # Set outside wins over the CPU rule too.
     env = {"JAX_COMPILATION_CACHE_DIR": "/some/dir", "JAX_PLATFORMS": "cpu"}
     assert compile_cache.apply(env) == "/some/dir"
-    assert len(env) == 2
+    assert len(env) == 3
     # Unset: <checkout>/.jax_cache — a fixed path, where everything is kept.
     env = {}
     assert compile_cache.apply(env) == os.path.join(REPO, ".jax_cache")
     assert env == {
         "JAX_COMPILATION_CACHE_DIR": os.path.join(REPO, ".jax_cache"),
-        "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0"}
+        "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0", **IDENTITY}
     # Unset and held to the CPU: no cache.
     env = {"JAX_PLATFORMS": "cpu"}
     assert compile_cache.apply(env) == ""
     assert "JAX_COMPILATION_CACHE_DIR" not in env
+
+
+@pytest.mark.parametrize("env", [
+    {"JAX_COMPILATION_CACHE_DIR": "/some/dir"}, {"JAX_PLATFORMS": "cpu"}, {}],
+    ids=["placed-outside", "held-to-the-cpu", "the-checkout's"])
+def test_a_program_names_no_caller_under_every_rule(env, monkeypatch):
+    """Under each of `apply`'s three rules the spawn environment says that a
+    location in a program's text is one frame, and says it over whatever it
+    said before: it is SET, being part of what a program is. A process that
+    has JAX imported is told on its live config as well, and `apply` on a
+    mapping that is not this process's environment leaves that alone."""
+    import jax
+
+    var, name = ("JAX_TRACEBACK_IN_LOCATIONS_LIMIT",
+                 "jax_traceback_in_locations_limit")
+    env[var] = "10"
+    monkeypatch.setenv(var, "10")
+    jax.config.update(name, 10)
+    try:
+        compile_cache.apply(env)
+        assert env[var] == "1"
+        assert getattr(jax.config, name) == 10  # `env` is not os.environ
+        compile_cache.program_identity()
+        assert getattr(jax.config, name) == 1
+        assert os.environ[var] == "1"
+    finally:
+        jax.config.update(name, 1)
 
 
 def test_compile_cache_has_one_setter():
@@ -154,6 +186,20 @@ def test_compile_cache_has_one_setter():
          "chip_smoke.py"],
         cwd=REPO, capture_output=True, text=True).stdout.split()
     assert hits == ["ray_tpu/_private/compile_cache.py"]
+
+
+def test_building_the_serving_app_imports_no_jax():
+    """A process that builds the OpenAI application and never runs it (the
+    benchmark's driver, which refuses its own run otherwise: a chip belongs
+    to one process) imports the engine, the pipeline and the derivation of
+    the model's shape without importing JAX."""
+    code = ("import sys; import ray_tpu.llm.openai, ray_tpu.llm.pipeline; "
+            "from ray_tpu.llm.engine import model_config; "
+            "assert model_config.__module__ == 'ray_tpu.models.published'; "
+            "sys.exit('jax' in sys.modules)")
+    r = subprocess.run([sys.executable, "-c", code], cwd=REPO,
+                       capture_output=True, text=True, timeout=60)
+    assert r.returncode == 0, r.stderr
 
 
 # ------------------------------------------------------------- chip_smoke.py

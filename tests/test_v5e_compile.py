@@ -9,17 +9,20 @@ file loads the library (README "Serving hot loop"; the `on-chip-measurement`
 guide, section 2).
 """
 
+import os
 import re
+import sys
 
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import (NamedSharding, PartitionSpec as P,
-                          SingleDeviceSharding)
+from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
-from ray_tpu.llm import LLMConfig
-from ray_tpu.llm.engine import ContinuousEngine, _make_sampler, model_config
-from ray_tpu.models.transformer import Transformer, param_specs
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+from ray_tpu.llm import LLMConfig  # noqa: E402
+from ray_tpu.llm.engine import ContinuousEngine  # noqa: E402
+from tools import lowered  # noqa: E402
 
 #: Phi-3-mini's heads (96 wide, which the chip pads to 128) at a size that
 #: compiles in seconds: 4 heads, 2 layers, 8 slots of 256 positions.
@@ -30,13 +33,10 @@ MAX_BATCH = 8
 
 @pytest.fixture(scope="module")
 def chips():
-    from jax.experimental import topologies
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
+        return lowered.described_chips()
     except Exception as e:  # noqa: BLE001 - no TPU compiler on this host
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return topo.devices
 
 
 @pytest.fixture(scope="module")
@@ -44,34 +44,12 @@ def chip(chips):
     return chips[0]
 
 
-def build_compiled(chip, monkeypatch=None, row=None, cfg=None,
-                   mesh=None, max_batch=None,
+def build_compiled(chip, cfg=None, mesh=None, max_batch=None,
                    decode_chunk=4) -> ContinuousEngine:
-    """An engine's compiled programs for `chip`, from shapes: no parameter
-    is made, no thread started, nothing placed on a device. `row` stands
-    in for the compiler's answer. With a `mesh` (of one axis, `tp`) the
-    engine is given it and its parameters are sharded over it."""
-    CFG = cfg or globals()["CFG"]
-    if row is not None:
-        monkeypatch.setattr(ContinuousEngine, "_probe_cache_row",
-                            lambda self, make_chunk: row)
-    eng = object.__new__(ContinuousEngine)
-    eng.cfg, eng.max_batch, eng.decode_chunk, eng.mesh = (
-        CFG, max_batch or MAX_BATCH, decode_chunk, mesh)
-    eng.model = Transformer(model_config(CFG))
-    eng._sampler = _make_sampler(CFG.vocab_size)
-    eng._jax, eng._jnp = jax, jnp
-    shapes = jax.eval_shape(lambda: eng.model.init(
-        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
-    where = lambda spec: (  # noqa: E731
-        SingleDeviceSharding(chip) if mesh is None else NamedSharding(
-            mesh, P(*[axis if axis == "tp" else None for axis in spec])))
-    eng.params = jax.tree.map(
-        lambda s, spec: jax.ShapeDtypeStruct(s.shape, jnp.bfloat16,
-                                             sharding=where(spec)),
-        shapes, param_specs({"params": shapes})["params"])
-    eng._build_compiled()
-    return eng
+    """`tools/lowered.py` `build_compiled` at this file's sizes."""
+    return lowered.build_compiled(
+        chip, cfg or CFG, max_batch=max_batch or MAX_BATCH,
+        decode_chunk=decode_chunk, mesh=mesh)
 
 
 def test_v5e_chunk_program_takes_and_returns_the_cache_without_a_copy(chip):
@@ -111,7 +89,9 @@ def test_v5e_counter_sees_the_conversions_of_head_wide_rows(
     """What the engine did before it asked: rows as wide as a head, whose
     default layout has the positions minor-most, are converted on the way
     into the chunk program and back on the way out, once each per leaf."""
-    eng = build_compiled(chip, monkeypatch, row=0)
+    monkeypatch.setattr(ContinuousEngine, "_probe_cache_row",
+                        lambda self, make_chunk: 0)  # the answer not asked
+    eng = build_compiled(chip)
     assert eng.model.cfg.cache_row == 0
     st = eng.cache_stats()
     assert st["cache_boundary_copies"] == 2 * 2 * CFG.n_layers
@@ -707,3 +687,73 @@ def test_v5e_longcat_chunk_program_reads_its_experts_through_the_kernel(
     memory = compiled.memory_analysis()
     assert abs(memory.argument_size_in_bytes - 11.69e9) < 0.01e9
     assert memory.temp_size_in_bytes < 22e6
+
+
+#: A small caller of each kernel's entry point, as source, and the (dtype,
+#: *dims) it is lowered with (whole tiles, so that Mosaic takes them).
+BF16, I32 = jnp.bfloat16, jnp.int32
+KERNEL_CALLERS = {
+    "flash_attention": (
+        "from ray_tpu.ops.flash_attention import flash_attention\n"
+        "def caller(q, k, v):\n"
+        "    return flash_attention(q, k, v, causal=True)\n",
+        [(BF16, 1, 256, 4, 128)] * 3),
+    "ragged_decode_attention": (
+        "from ray_tpu.ops.decode_attention import ragged_decode_attention\n"
+        "def caller(q, k, v, lengths):\n"
+        "    return ragged_decode_attention(q, k, v, lengths)\n",
+        [(BF16, 8, 8, 128), (BF16, 8, 512, 4, 128), (BF16, 8, 512, 4, 128),
+         (I32, 8)]),
+    "ragged_latent_attention": (
+        "from ray_tpu.ops.decode_attention import ragged_latent_attention\n"
+        "def caller(q, latents, lengths):\n"
+        "    return ragged_latent_attention(q, latents, lengths, rank=512,\n"
+        "                                   scale=0.1)\n",
+        [(BF16, 8, 16, 640), (BF16, 8, 512, 640), (I32, 8)]),
+    "occupied_experts": (
+        "from ray_tpu.ops.expert_decode import occupied_experts\n"
+        "def caller(x, gates, rows_here, w_gate, w_up, w_down):\n"
+        "    return occupied_experts(x, gates, rows_here, w_gate, w_up,\n"
+        "                            w_down)\n",
+        [(BF16, 16, 1024), (jnp.float32, 16, 4), (I32, 4),
+         (BF16, 4, 1024, 512), (BF16, 4, 1024, 512), (BF16, 4, 512, 1024)]),
+}
+
+
+def lowered_from_line(chip, kernel: str, line: int) -> str:
+    """The text of `kernel`'s caller lowered for `chip`, the caller compiled
+    from source that begins at `line`. (The kernels' own jits keep their
+    first trace, and with it the first caller's frames: cleared.)"""
+    source, shapes = KERNEL_CALLERS[kernel]
+    scope: dict = {}
+    exec(compile("\n" * line + source, f"<a caller of {kernel}>", "exec"),
+         scope)
+    jax.clear_caches()
+    text = jax.jit(scope["caller"]).lower(*(
+        jax.ShapeDtypeStruct(dims, dtype, sharding=SingleDeviceSharding(chip))
+        for dtype, *dims in shapes)).as_text()
+    assert "tpu_custom_call" in text
+    return text
+
+
+@pytest.mark.parametrize("kernel, frames", [
+    *((kernel, 1) for kernel in KERNEL_CALLERS), ("occupied_experts", 10)])
+def test_v5e_kernel_program_does_not_name_its_callers_lines(
+        chip, kernel, frames):
+    """A program that holds a Mosaic kernel is the same text, and so the
+    same compile-cache entry, wherever in its file the caller stands:
+    `compile_cache.program_identity` (the engine's build calls it, `apply`
+    writes it into every spawn environment) leaves a location one frame, the
+    kernel body's own in `ops/*.py`. With JAX's ten frames put back the
+    payload names the caller's line again, so this test can tell."""
+    from ray_tpu._private import compile_cache
+
+    compile_cache.program_identity()
+    assert jax.config.jax_traceback_in_locations_limit == 1
+    jax.config.update("jax_traceback_in_locations_limit", frames)
+    try:
+        same = (lowered_from_line(chip, kernel, 0)
+                == lowered_from_line(chip, kernel, 3))
+    finally:
+        compile_cache.program_identity()
+    assert same == (frames == 1)
