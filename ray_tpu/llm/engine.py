@@ -11,13 +11,16 @@ engine workers via vllm_models.py:123-137). TPU-native design:
   slot) and leave independently — no lockstep. Fixed shapes mean every
   decode step is the same compiled XLA program; a TPU cannot afford
   vLLM's dynamic block tables, slots are the idiomatic equivalent.
-- **Kinds of leaf**: a layer keeps rows to `max_seq`, a ring of its
-  window's rows, or a STATE, a fixed block a slot that every token replaces
-  (a gated delta-rule layer's float32 `S` and its convolution's tail:
-  models/kda.py). The cache is the model's flax collection; the engine
-  keeps each layer's kind beside it (`_kind_of`) and goes by that kind
-  wherever rows and states differ: what a prefill hands on, what a parked
-  request holds, how a leaf is sharded, what the stats count.
+- **Kinds of leaf**: a leaf keeps rows to `max_seq`, its window's rows
+  (position p in row p mod window: a ring, or under "eva" a block that
+  starts over), one row for every CHUNK of positions (`chunks`: an "eva"
+  layer's summaries, beside its window leaves: a layer may keep leaves of
+  more than one kind), or a STATE, a fixed block a slot that every token
+  replaces (a gated delta-rule layer's float32 `S` and its convolution's
+  tail: models/kda.py). The cache is the model's flax collection; the
+  engine asks each LEAF's kind (`_leaf_kind`) and goes by it wherever the
+  kinds differ: what a prefill hands on, what a parked request holds, how
+  a leaf is sharded, what the stats count.
 - **Cache layout**: the cache crosses every program boundary in the
   on-device layout the decode loop computes in. The engine asks the
   compiler for it once, and where rows as wide as their tiles make it the
@@ -318,10 +321,36 @@ class _Phases:
         self.end(record)
 
 
-def _layer_kinds(mcfg) -> dict:
-    """The kind of cache LEAVES each layer keeps (`cache_kind_of`; a layer
-    may keep several) by the layer's name in the cache collection."""
-    return {f"layer_{i}": mcfg.cache_kind_of(i) for i in range(mcfg.n_layers)}
+#: The kinds of cache leaf (`TransformerConfig.cache_kind_of`), in the order
+#: the stats list them.
+_KINDS = ("full", "window", "chunks", "state")
+
+
+def _layer_of(path) -> int:
+    """The layer of the cache leaf at `path` (`layer_<i>/.../<leaf>`)."""
+    return int(path[0].key.rpartition("_")[2])
+
+
+def _leaf_kind(mcfg, path) -> str:
+    """The kind of the cache leaf at `path` of the cache collection:
+    `cache_kind_of` of its layer and its name."""
+    return mcfg.cache_kind_of(_layer_of(path), path[-1].key)
+
+
+def _leaves_by_kind(mcfg, cache) -> dict:
+    """kind -> [(layer, leaf)] of a cache tree, in `_KINDS`' order."""
+    import jax
+
+    found: dict = {kind: [] for kind in _KINDS}
+    for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]:
+        found[_leaf_kind(mcfg, path)].append((_layer_of(path), leaf))
+    return {kind: leaves for kind, leaves in found.items() if leaves}
+
+
+#: What an "eva" model's decode steps count on the device beside their
+#: tokens: `<name>` on `engine.host_sync`, `<name>_total` in `/v1/stats`,
+#: `LLM_<NAME>` (`rt_llm_<name>_total`) in `util/metrics.py`.
+_EVA_COUNTERS = ("eva_summaries", "eva_restarts")
 
 
 #: `models/moe.py` `zero_counts`' four, by the names they go by from here
@@ -548,7 +577,10 @@ class ContinuousEngine:
         self._moe_held = self.model.cfg.held_experts
         self._moe_cols = -(-_moe_counters(self.model.cfg) // self.max_batch)
         self.moe_rows_total = 0
-        for name in _PICK_COUNTERS:
+        # "eva" layers: the columns behind those, `_EVA_COUNTERS`' two.
+        self._eva = "eva" in self.model.cfg.mixers
+        self._eva_cols = -(-len(_EVA_COUNTERS) // self.max_batch) * self._eva
+        for name in _PICK_COUNTERS + _EVA_COUNTERS:
             setattr(self, f"{name}_total", 0)
         # The decode steps dispatched since start, those whose attention
         # over K and V or latent rows is a ragged kernel (`_decode_blocks`),
@@ -556,8 +588,8 @@ class ContinuousEngine:
         # on average (`cache_stats`: kv_walk_share, kv_live_share).
         self.decode_steps = 0
         self.decode_steps_kernel = 0
-        self._kv_walked = {"full": 0, "window": 0}
-        self._kv_live = {"full": 0.0, "window": 0.0}
+        self._kv_walked = dict.fromkeys(_KINDS[:3], 0)
+        self._kv_live = dict.fromkeys(_KINDS[:3], 0.0)
         # Hand-overs of a batch row since start, those dispatched behind at
         # least one decode chunk still in flight, and the scheduler's
         # passes that began with occupants seated and no chunk in flight:
@@ -579,6 +611,8 @@ class ContinuousEngine:
 
         def make_chunk(model):
             held = _moe_counters(model.cfg)  # counters a step carries on
+            eva = (model.cfg.eva_window, model.cfg.eva_chunk) \
+                if "eva" in model.cfg.mixers else None
 
             def chunk(params, cache, toks, lengths, keys, temp, top_k, top_p,
                       n: int, greedy: bool, kv_bound=None, live=None):
@@ -601,7 +635,10 @@ class ContinuousEngine:
                 with expert layers appends to the token block the columns
                 of `_rows_columns`: the rows its held experts were routed
                 in these n steps (and `_moe_counters`' others) ride to the
-                host in the read that brings the tokens."""
+                host in the read that brings the tokens; a model with
+                "eva" layers, behind those, `_EVA_COUNTERS`' two: the live
+                slots' steps that ended a chunk (each eva layer wrote a
+                summary) and those that began a window after the first."""
                 def step(carry, _):
                     cache, tok, lens, keys, *rows = carry
                     # (the mesh in context, as the prefill's: what
@@ -631,10 +668,20 @@ class ContinuousEngine:
                     step, (cache, toks, lengths, keys, *rows0), None,
                     length=n)
                 block = jnp.moveaxis(out, 0, 1)
-                if held:
+                if eva:
+                    # the positions the live slots stepped, [B, n]
+                    window, piece = eva
+                    at = lengths[:, None] + jnp.arange(n)[None, :]
+                    if live is not None:
+                        at = jnp.where(live[:, None], at, 0)  # counts nowhere
+                    rows.append(jnp.stack([
+                        jnp.sum(at % piece == piece - 1),
+                        jnp.sum((at % window == 0) & (at > 0))]
+                    ).astype(jnp.int32))
+                if rows:
                     block = jnp.concatenate(
-                        [block, _rows_columns(rows[0], block.shape[0])],
-                        axis=1)
+                        [block, *(_rows_columns(r, block.shape[0])
+                                  for r in rows)], axis=1)
                 return cache, keys, block, lens
 
             return chunk
@@ -648,29 +695,25 @@ class ContinuousEngine:
         model = self.model
         self._cache_spec = self._cache_shapes(model, self.params)
         mcfg = model.cfg
-        # Three kinds of leaf in one manager, a kind a layer (`cache_kind_of`;
+        # Four kinds of leaf in one manager, a kind a LEAF (`cache_kind_of`;
         # a layer keeps one leaf or several: K and V, a state's parts, the two
-        # latents under `moe_shortcut`): a full layer's `max_seq` rows a slot
-        # and a window layer's ring, both `[slots, rows, ...]` and appended
-        # to; and a state, a fixed block a slot that every token replaces.
-        # Whatever goes by a leaf's kind asks `_kind_of`, never its rank.
-        self._kind_of = _layer_kinds(mcfg)
+        # latents under `moe_shortcut`, and under "eva" leaves of two kinds):
+        # a full leaf's `max_seq` rows a slot, a window leaf's ring or block,
+        # and a chunks leaf's one row for several positions, all `[slots,
+        # rows, ...]`; and a state, a fixed block a slot that every token
+        # replaces. Whatever goes by a leaf's kind asks `_leaf_kind`, never
+        # its rank or its layer alone.
         self._window = max((mcfg.window_of(i) for i in range(mcfg.n_layers)),
                            default=0)
-        kinds = list(self._kind_of.values())
         self._cache_kinds = {}
-        for kind in ("full", "window", "state"):
-            if kind not in kinds:
-                continue
-            leaves = [leaf for name, k in self._kind_of.items() if k == kind
-                      for leaf in jax.tree.leaves(self._cache_spec[name])]
+        for kind, found in _leaves_by_kind(mcfg, self._cache_spec).items():
+            leaves = [leaf for _i, leaf in found]
             nbytes = sum(leaf.size * leaf.dtype.itemsize for leaf in leaves)
             self._cache_kinds[kind] = {
-                "layers": kinds.count(kind), "leaves": len(leaves),
+                "layers": len({i for i, _leaf in found}),
+                "leaves": len(leaves),
                 **({"bytes_per_slot": nbytes // self.max_batch}
-                   if kind == "state" else
-                   {"rows": self._window if kind == "window"
-                    else mcfg.max_seq}),
+                   if kind == "state" else {"rows": leaves[0].shape[1]}),
                 "bytes": nbytes}
         # What one decode step reads and writes of state, all slots.
         self._state_rw_bytes = 2 * self._cache_kinds.get(
@@ -707,7 +750,8 @@ class ContinuousEngine:
                 logits[0].astype(jnp.float32), plen - 1, 0, keepdims=False)
             return last, self._by_kind(
                 lambda kind, c: c if kind == "state"
-                else c[:, :min(lb, c.shape[1])], vars_out["cache"])
+                else c[:, :self._slice_rows(kind, lb, c.shape[1])],
+                vars_out["cache"])
 
         def place(cache, slice_cache, mirrors, first, key, ints, floats):
             """The hand-over of batch row `slot` to a prefilled request, as
@@ -770,9 +814,10 @@ class ContinuousEngine:
             return shapes
 
         def sharded(kind, leaf):
-            # K and V [slots, rows, heads, dim]: the head axis over tp, as
-            # the attention's heads are. A latent leaf [slots, rows, row]
-            # belongs to every head: each tp shard keeps all of it.
+            # K and V [slots, rows, heads, dim], and an "eva" layer's
+            # summaries alike: the head axis over tp, as the attention's
+            # heads are. A latent leaf [slots, rows, row] belongs to every
+            # head: each tp shard keeps all of it.
             if kind == "state":
                 raise NotImplementedError(
                     "a state leaf under a `tp` mesh: its heads would be "
@@ -783,19 +828,27 @@ class ContinuousEngine:
                 leaf.shape, leaf.dtype,
                 sharding=NamedSharding(self.mesh, spec))
 
-        kind_of = _layer_kinds(model.cfg)
-        return {name: jax.tree.map(
-            lambda leaf, kind=kind_of[name]: sharded(kind, leaf), sub)
-            for name, sub in shapes.items()}
+        return jax.tree_util.tree_map_with_path(
+            lambda path, leaf: sharded(_leaf_kind(model.cfg, path), leaf),
+            shapes)
 
     def _by_kind(self, fn, cache, *rest):
-        """`fn(kind, leaf, ...)` over a cache tree, the kind its layer's
-        (`_kind_of`); one map over the whole tree, in its own order."""
+        """`fn(kind, leaf, ...)` over a cache tree, the kind the leaf's own
+        (`_leaf_kind`); one map over the whole tree, in its own order."""
         import jax
 
         return jax.tree_util.tree_map_with_path(
-            lambda path, *leaves: fn(self._kind_of[path[0].key], *leaves),
+            lambda path, *leaves: fn(_leaf_kind(self.model.cfg, path),
+                                     *leaves),
             cache, *rest)
+
+    def _slice_rows(self, kind: str, bucket: int, rows: int) -> int:
+        """Rows of a rows leaf of `rows` rows that a prefill of `bucket`
+        positions hands on: one a position up to the leaf's own, one a whole
+        chunk of a `chunks` leaf (and never none: a slice has a row)."""
+        if kind == "chunks":
+            bucket = max(1, bucket // self.model.cfg.eva_chunk)
+        return min(bucket, rows)
 
     def _chunk_shapes(self, params, cache, greedy: bool) -> tuple:
         """Arguments to lower a chunk program of decode_chunk steps from."""
@@ -901,7 +954,11 @@ class ContinuousEngine:
         layers, LEAVES (a layer keeps K and V, a state's parts, or, under
         `moe_shortcut`, two latents), rows a slot and bytes of each kind of
         rows leaf, `full` (`max_seq` rows; the latent leaves are of this
-        kind) and `window` (a ring), and of the `state` leaves their layers,
+        kind), `window` (a ring, or under "eva" a block that starts over)
+        and `chunks` (an "eva" layer's summaries, `max_seq` / chunk rows,
+        beside `eva_summaries_total` and `eva_restarts_total`, the live
+        slots' steps that wrote a summary a layer and that began a window
+        after the first), and of the `state` leaves their layers,
         leaves, `bytes_per_slot` and bytes (`state_bytes` at the top); for
         a model with expert layers what `_moe_stats` counts (the identity
         experts' selections among it); `kv_heads`, the key/value
@@ -948,6 +1005,9 @@ class ContinuousEngine:
                "decode_steps_kernel": self.decode_steps_kernel}
         if "state" in kinds:
             out["state_bytes"] = kinds["state"]["bytes"]
+        if self._eva:
+            out.update({f"{name}_total": getattr(self, f"{name}_total")
+                        for name in _EVA_COUNTERS})
         if self._moe_held:
             out.update(self._moe_stats())
         return out
@@ -969,6 +1029,17 @@ class ContinuousEngine:
         # those whose weights the steps' arm read
         attrs.update(self._count_picks(*counts[len(rows):]))
         return attrs
+
+    def _count_eva(self, block: np.ndarray, at: int) -> dict:
+        """`_EVA_COUNTERS`' two of the chunk just read (columns `at` on of
+        its block): added to the totals, and returned as the attributes
+        `engine.host_sync` carries."""
+        got = dict(zip(_EVA_COUNTERS, map(int, _rows_from_columns(
+            block[:, at:at + self._eva_cols], len(_EVA_COUNTERS)))))
+        for name, n in got.items():
+            setattr(self, f"{name}_total", getattr(self, f"{name}_total") + n)
+            _count_metric(f"LLM_{name.upper()}", n)
+        return got
 
     def _init_cache(self):
         """Zero cache for the full batch."""
@@ -1109,7 +1180,8 @@ class ContinuousEngine:
         latent leaf of `mla` layers, each deciding leaf by leaf) put to the
         leaves the chunk program is traced with, under the mesh it is
         traced under. Nothing is read back from the device. (No model has
-        rows leaves of both families.)"""
+        rows leaves of both families; an "eva" layer's two leaves under one
+        softmax have no kernel yet, `two_leaf_decode_attention`.)"""
         import jax
 
         from ray_tpu.ops.decode_attention import (latent_block,
@@ -1130,7 +1202,7 @@ class ContinuousEngine:
         leaves: dict = {}  # kind -> EVERY leaf of its layers, as the rules ask
         for i in range(mcfg.n_layers):
             if mcfg.mixer_of(i) in ("mha", "mla"):
-                leaves.setdefault(self._kind_of[f"layer_{i}"], set()).update(
+                leaves.setdefault(mcfg.cache_kind_of(i), set()).update(
                     (mcfg.mixer_of(i), leaf.shape, leaf.dtype) for leaf in
                     jax.tree.leaves(self._cache_spec[f"layer_{i}"]))
         with self._mesh_scope():  # a kind's block: the one ALL its leaves get
@@ -1156,7 +1228,8 @@ class ContinuousEngine:
                 lambda kind, leaf: leaf.size // self.max_batch
                 * leaf.dtype.itemsize if kind == "state"
                 else leaf.size // (self.max_batch * leaf.shape[1])
-                * min(bucket, leaf.shape[1]) * leaf.dtype.itemsize,
+                * self._slice_rows(kind, bucket, leaf.shape[1])
+                * leaf.dtype.itemsize,
                 self._cache_spec)))
         return self._slice_bytes_of[bucket]
 
@@ -1227,6 +1300,11 @@ class ContinuousEngine:
             mcfg = self.model.cfg
             attrs.update(scan_chunks=-(-lb // mcfg.kda_chunk), mixers=",".join(
                 f"{m}:{mcfg.mixers.count(m)}" for m in sorted(set(mcfg.mixers))))
+        if self._eva:
+            # what the prefill handed on: the windows it ran, and the
+            # summaries of the whole chunks before the prompt's end
+            attrs.update(windows=-(-lb // self._window),
+                         summaries=plen // self.model.cfg.eva_chunk)
         _tracing.record_span_in(stream.trace, "engine.prefill", "engine",
                                 t_adm, t_end, attrs)
         return first, cache_slice, self._jax.random.fold_in(key, 1)
@@ -1499,11 +1577,13 @@ class ContinuousEngine:
             f"{lb}:{k}" for lb, k in sorted(buckets.items())),
             "places_ahead": places}
 
-    def _rows_walked(self, kind: str, seen, kv_bound: int) -> tuple:
+    def _rows_walked(self, kind: str, seen, kv_bound) -> tuple:
         """Rows of a leaf of this kind a slot's attention reads in a step of
         a chunk whose live slots show `seen` [slots, steps] rows of it: (as
         the chunk's span says it, as read). The XLA walk reads the static
-        prefix `kv_bound` picks, every slot alike. The ragged kernel reads
+        prefix `kv_bound` picks, every slot alike (the chunk's one bound,
+        or under "eva" each step's own, [steps]: a step's mean then). The
+        ragged kernel reads
         each live slot's own rows rounded up to its row block: `/v1/stats`
         sums their mean as it is, and the span carries it as a whole
         multiple of the block (ISSUE 37 asked for that form: a reader that
@@ -1514,8 +1594,10 @@ class ContinuousEngine:
 
         block = self._kernel_blocks.get(kind)
         if block is None:
-            rows = kv_prefix_rows(kv_bound, self._cache_kinds[kind]["rows"])
-            return rows, rows
+            rows = float(np.mean([
+                kv_prefix_rows(int(bound), self._cache_kinds[kind]["rows"])
+                for bound in np.atleast_1d(kv_bound)]))
+            return (int(rows) if rows.is_integer() else round(rows, 2)), rows
         blocks = float(np.ceil(seen / block).mean())
         return int(round(blocks)) * block, blocks * block
 
@@ -1593,9 +1675,24 @@ class ContinuousEngine:
                 # j + 1 of them, a ring at most its own length): by kind
                 # of leaf, a step's mean.
                 seen = np.add.outer(live, np.arange(1, n + 1))
-                rows = {"full": (*self._rows_walked("full", seen, kv_bound),
-                                 float(seen.mean()))}
-                if self._window:
+                rows = {}
+                if "full" in self._cache_kinds:
+                    rows["full"] = (
+                        *self._rows_walked("full", seen, kv_bound),
+                        float(seen.mean()))
+                if self._eva:
+                    # a block that starts over, and the summaries of the
+                    # windows behind it; each step's walk is bounded by the
+                    # longest of its own live stops (`models/eva.py`)
+                    per = self._window // self.model.cfg.eva_chunk
+                    for kind, shown in (
+                            ("window", (seen - 1) % self._window + 1),
+                            ("chunks", (seen - 1) // self._window * per)):
+                        rows[kind] = (
+                            *self._rows_walked(kind, shown,
+                                               shown.max(axis=0)),
+                            float(shown.mean()))
+                elif self._window:
                     ring = np.minimum(seen, self._window)
                     rows["window"] = (
                         *self._rows_walked("window", ring, kv_bound),
@@ -1603,7 +1700,8 @@ class ContinuousEngine:
                 form = self._decode_form
                 attrs = {"tokens": n, "active": len(active),
                          "sampler": path, "attention": form,
-                         "kv_bound": kv_bound, "kv_rows": rows["full"][0]}
+                         "kv_bound": kv_bound,
+                         "kv_rows": next(iter(rows.values()))[0]}
                 if self._state_rw_bytes:
                     attrs["state_rw_bytes"] = self._state_rw_bytes
                 if seq is not None:
@@ -1700,6 +1798,8 @@ class ContinuousEngine:
         t_end = ph.begin("deliver") if ph is not None else None
         moe = (self._count_moe(block, n)
                if self._moe_cols and block is not None else {})
+        if self._eva_cols and block is not None:
+            moe.update(self._count_eva(block, n + self._moe_cols))
         if sync_ctx is not None:
             t_end = t_end or time.time()
             _tracing.record_span_in(

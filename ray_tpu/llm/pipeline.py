@@ -105,6 +105,13 @@ def make_stage_net(mcfg, layers: tuple, first: bool, last: bool):
 
     from ray_tpu.models.transformer import Block, RMSNorm, output_head
 
+    if any(mcfg.mixer_of(i) == "eva" for i in layers):
+        raise NotImplementedError(
+            "pipeline stages keep one kind of cache leaf, max_seq rows a "
+            "slot (llm/pipeline.py `place`, `_init_cache`): a model whose "
+            "layers keep a window's rows beside one row a chunk of "
+            "positions (`eva`) is served by ContinuousEngine only")
+
     if any(mcfg.window_of(i) for i in layers):
         raise NotImplementedError(
             "pipeline stages keep one kind of cache leaf, max_seq rows a "

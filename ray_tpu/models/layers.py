@@ -21,10 +21,17 @@ class RMSNorm(nn.Module):
     #: A constant the normed values are multiplied by, in float32 before the
     #: cast (`models/mla.py`: a latent's `mla_kv_scale`); 1.0: nothing.
     gain: float = 1.0
+    #: The weight is `1 + g` with g the parameter, zeros at the start (a
+    #: model's `norm_add_unit_offset`), where it is else the parameter itself.
+    unit_offset: bool = False
 
     @nn.compact
     def __call__(self, x):
-        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],), jnp.float32)
+        if self.unit_offset:
+            scale = 1.0 + self.param("scale", nn.initializers.zeros,
+                                     (x.shape[-1],), jnp.float32)
+        else:
+            scale = self.param("scale", nn.initializers.ones, (x.shape[-1],), jnp.float32)
         x32 = x.astype(jnp.float32)
         norm = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + self.eps)
         if self.gain != 1.0:

@@ -214,6 +214,41 @@ def _longcat_flash(cfg, arch: dict) -> dict:
                    moe_shortcut=True))
 
 
-_ARMS = {"kimi_k2": _deepseek_v3, "deepseek_v3": _deepseek_v3,
+def _evabyte(cfg, arch: dict) -> dict:
+    """The fields of a `model_type: evabyte` decoder (EvaByte, a byte-level
+    model) beyond the six sizes: EVA attention in every layer
+    (`models/eva.py`: aligned windows of `window_size` positions seen
+    exactly, one summary for every `chunk_size` positions behind them, one
+    softmax), no grouping, norms whose weight is 1 + g, a float32 residual
+    stream, an untied head of `num_pred_heads` x vocabulary columns of which
+    the first head is the next byte (ISSUE 49). `mixedp_attn`, `lazy_init`,
+    `init_fn`, `init_std` and `init_cutoff_factor` bear on training only and
+    are not read."""
+    _refuse_unbuilt(arch, {
+        "attention_class": "eva", "num_chunks": None, "rope_scaling": None,
+        "attention_bias": False, "hidden_act": "silu",
+        "norm_add_unit_offset": True, "fp32_skip_add": True,
+        "fp32_logits": True, "tie_word_embeddings": False})
+    window, chunk = int(arch["window_size"]), int(arch["chunk_size"])
+    if chunk < 1 or window % chunk:
+        raise ValueError(f"not built: chunk_size {chunk} does not divide "
+                         f"window_size {window}")
+    if cfg.max_seq > window and cfg.max_seq % window:
+        raise ValueError(f"not built: {cfg.max_seq} positions a slot are "
+                         f"not whole windows of {window}")
+    if arch.get("num_key_value_heads", cfg.n_heads) != cfg.n_heads:
+        raise ValueError("not built: EVA's pooling vectors are one pair a "
+                         "head: num_key_value_heads must equal the heads")
+    return dict(
+        n_kv_heads=cfg.n_heads, head_size=int(arch.get("head_dim") or 0),
+        d_ff=int(arch["intermediate_size"]),
+        rope_theta=float(arch["rope_theta"]), tie_embeddings=False,
+        mixers=("eva",) * cfg.n_layers, eva_window=window, eva_chunk=chunk,
+        eva_pool_std=float(arch.get("pool_init_std", 1.0)),
+        norm_unit_offset=True, residual_f32=True,
+        pred_heads=int(arch.get("num_pred_heads", 1)))
+
+
+_ARMS = {"evabyte": _evabyte, "kimi_k2": _deepseek_v3, "deepseek_v3": _deepseek_v3,
          "afmoe": _afmoe, "kimi_linear": _kimi_linear,
          "longcat_flash": _longcat_flash}

@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from ray_tpu.models.eva import EVA
 from ray_tpu.models.kda import KDA
 from ray_tpu.models.layers import RMSNorm, SwiGLU, YarnScaling, rope as _rope
 from ray_tpu.models.mla import MLA
@@ -34,7 +35,7 @@ from ray_tpu.ops import dot_product_attention
 from ray_tpu.ops.attention import prefill_attention
 from ray_tpu.parallel.mesh import context_mesh_shape, spec_tree_like
 
-__all__ = ["Attention", "Block", "KDA", "MLA", "MoE", "RMSNorm", "SwiGLU",
+__all__ = ["Attention", "Block", "EVA", "KDA", "MLA", "MoE", "RMSNorm", "SwiGLU",
            "Transformer", "TransformerConfig", "YarnScaling", "loss_fn",
            "param_specs", "prefill_attention"]
 
@@ -84,6 +85,10 @@ class TransformerConfig:
     #: attention (`models/kda.py`) of `kda_heads` heads of `kda_head_dim`, a
     #: depthwise convolution of `kda_conv` positions on q, k and v, a prefill
     #: that scans chunks of `kda_chunk`, a state a slot (`cache_kind_of`).
+    #: "eva": K and V per head, seen exactly inside the query's own aligned
+    #: block of `eva_window` positions and, of every block before it, as one
+    #: summary row for every `eva_chunk` positions, both under one softmax
+    #: (`models/eva.py`): two kinds of cache leaf in ONE layer.
     mixers: tuple = ()
     q_lora_rank: int = 0  # 0: queries are projected straight from x
     kv_lora_rank: int = 0
@@ -100,6 +105,19 @@ class TransformerConfig:
     kda_head_dim: int = 0
     kda_conv: int = 4
     kda_chunk: int = 64
+    eva_window: int = 0
+    eva_chunk: int = 0
+    #: Standard deviation of the two pooling vectors a head (`mu`, `phi`) at
+    #: their start, before the cut to [-1, 1] standard deviations.
+    eva_pool_std: float = 1.0
+    #: Every norm's weight is `1 + g` (a model's `norm_add_unit_offset`).
+    norm_unit_offset: bool = False
+    #: The residual stream is float32 whatever `dtype` (`fp32_skip_add`);
+    #: every matrix product still takes its operands in `dtype`.
+    residual_f32: bool = False
+    #: `lm_head` holds this many heads of `vocab_size` columns each, the
+    #: first the next token's; the logits are the first head's.
+    pred_heads: int = 1
     #: >0 makes the feed-forward of every layer from `moe_first_layer` on an
     #: expert layer (`models/moe.py`) whose router scores this many experts,
     #: the count a model publishes. Defaults: a top-2 softmax mixture, experts
@@ -138,7 +156,11 @@ class TransformerConfig:
         return self.head_size or self.d_model // self.n_heads
 
     def window_of(self, i: int) -> int:
-        """Rows of layer i's window, cut to `max_seq`; 0 for a full layer."""
+        """Rows of layer i's window, cut to `max_seq`; 0 for a full layer.
+        An "mha" layer's window slides and its leaf is a ring; an "eva"
+        layer's is an aligned block that starts over."""
+        if self.mixer_of(i) == "eva":
+            return min(self.eva_window, self.max_seq)
         if self.sliding_window and i < len(self.window_layers) \
                 and self.window_layers[i]:
             return min(self.sliding_window, self.max_seq)
@@ -147,12 +169,18 @@ class TransformerConfig:
     def mixer_of(self, i: int) -> str:
         return self.mixers[i] if self.mixers else "mha"
 
-    def cache_kind_of(self, i: int) -> str:
-        """What layer i keeps for a sequence: `full`, rows to `max_seq`, one
-        a position; `window`, a ring of the window's rows; `state`, a fixed
-        block that every token replaces."""
+    def cache_kind_of(self, i: int, leaf: str = "") -> str:
+        """What `leaf` of layer i keeps for a sequence: `full`, rows to
+        `max_seq`, one a position; `window`, the window's rows, position p
+        in row p mod window; `chunks`, one row for every `eva_chunk`
+        positions (an "eva" layer's `kbar` and `vbar`, beside its `window`
+        leaves: the one mixer whose leaves differ in kind, and the kind of
+        its K and V where no leaf is named); `state`, a fixed block that
+        every token replaces."""
         if self.mixer_of(i) == "kda":
             return "state"
+        if self.mixer_of(i) == "eva" and leaf in ("kbar", "vbar"):
+            return "chunks"
         return "window" if self.window_of(i) else "full"
 
     def is_moe_layer(self, i: int) -> bool:
@@ -299,7 +327,8 @@ class Block(nn.Module):
     def __call__(self, x, positions, decode: bool = False, kv_bound=None,
                  prompt_len=None, live=None):
         cfg = self.cfg
-        norm = lambda name: RMSNorm(cfg.norm_eps, name=name)  # noqa: E731
+        norm = lambda name: RMSNorm(  # noqa: E731
+            cfg.norm_eps, unit_offset=cfg.norm_unit_offset, name=name)
         if cfg.moe_shortcut:
             return shortcut_layer(cfg, x, positions, decode, kv_bound, live)
         if self.mixer == "mla":
@@ -309,6 +338,13 @@ class Block(nn.Module):
             # (a state is read and written whole: no notice of `kv_bound`)
             a = KDA(cfg, name="attn")(norm("attn_norm")(x), decode=decode,
                                       prompt_len=prompt_len)
+        elif self.mixer == "eva":
+            # (its two leaves' stops are the positions' own: `kv_bound` says
+            # only whether the step's walk is bounded)
+            a = EVA(cfg, name="attn")(
+                norm("attn_norm")(x), positions, decode=decode,
+                bounded=kv_bound is not None, prompt_len=prompt_len,
+                live=live)
         else:
             a = Attention(cfg, window=self.window, name="attn")(
                 norm("attn_norm")(x), positions, decode=decode,
@@ -329,7 +365,12 @@ def output_head(module: nn.Module, cfg: TransformerConfig, x, emb):
             return jnp.einsum("bsd,vd->bsv", x,
                               emb.astype(cfg.dtype)).astype(jnp.float32)
         head = module.param("lm_head", nn.initializers.normal(0.02),
-                            (cfg.d_model, cfg.vocab_size), cfg.param_dtype)
+                            (cfg.d_model, cfg.pred_heads * cfg.vocab_size),
+                            cfg.param_dtype)
+        if cfg.pred_heads > 1:  # held whole; the next token's head is read
+            head = head[:, :cfg.vocab_size]
+        if cfg.residual_f32:
+            x = x.astype(cfg.dtype)
         return jnp.einsum("bsd,dv->bsv", x,
                           head.astype(cfg.dtype)).astype(jnp.float32)
 
@@ -353,7 +394,8 @@ class Transformer(nn.Module):
         cfg = self.cfg
         emb = self.param("tok_emb", nn.initializers.normal(0.02),
                          (cfg.vocab_size, cfg.d_model), cfg.param_dtype)
-        x = emb[tokens].astype(cfg.dtype)
+        x = emb[tokens].astype(jnp.float32 if cfg.residual_f32
+                               else cfg.dtype)
         if cfg.emb_scale != 1.0:
             x = x * jnp.asarray(cfg.emb_scale, cfg.dtype)
         if positions is None:
@@ -365,7 +407,8 @@ class Transformer(nn.Module):
                       mixer=cfg.mixer_of(i), name=f"layer_{i}")(
                 x, positions, decode=decode, kv_bound=kv_bound,
                 prompt_len=prompt_len, live=live)
-        x = RMSNorm(cfg.norm_eps, name="final_norm")(x)
+        x = RMSNorm(cfg.norm_eps, unit_offset=cfg.norm_unit_offset,
+                    name="final_norm")(x)
         return output_head(self, cfg, x, emb)
 
 
@@ -413,8 +456,9 @@ def param_specs(params) -> dict:
             return P(None, "tp", None)  # KDA: [rank or taps, heads, dim]
         if name == "w_beta":
             return P("fsdp", "tp")  # KDA: one beta a head
-        if last in ("A_log", "dt_bias"):
-            return P("tp", *(None,) * (leaf.ndim - 1))  # KDA: per head
+        if last in ("A_log", "dt_bias", "mu", "phi"):
+            # KDA, and EVA's pooling vectors [heads, dim]: per head
+            return P("tp", *(None,) * (leaf.ndim - 1))
         if moe and last in ("w_gate", "w_up"):
             return P("ep", "fsdp", "tp")  # leading [E] axis over ep
         if moe and last == "w_down":

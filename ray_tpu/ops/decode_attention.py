@@ -569,3 +569,66 @@ def _ragged_latent(q, latents, lengths, live, *, rank: int, scale: float,
             vmem_limit_bytes=64 << 20),
         interpret=interpret,
     )(stop, slot, at, held, q, leaf)
+
+
+# ---------------------------------------------------------------------------
+# ONE softmax over leaves of two kinds (models/eva.py): a window leaf whose
+# rows `[0, stop_w)` are positions, and a chunks leaf whose rows `[0, stop_c)`
+# each stand for several. Each leaf is walked on its own to what a softmax
+# is made of (running maximum, sum, weighted sum), and the two are merged:
+# what an online softmax does between two blocks, between two leaves. The
+# `mha` family's programs above are left as they are; no kernel here yet
+# (PERF.md section 7): each walk stops at the static prefix that holds its
+# leaf's longest live stop.
+
+
+@jax.jit
+def partial_walk(q, k_cache, v_cache, stop, bound):
+    """q [B, H, D] against rows `[0, stop[b])` of a leaf `[B, rows, H, D]`,
+    walked to the shortest static prefix that holds `bound` rows (a traced
+    scalar, `over_kv_prefix`): the scores' maximum m [B, H] (-inf where no
+    row is visible), the sum l [B, H] of exp(score - m) and the weighted sum
+    acc [B, H, D] of the values under the same weights, all float32 and not
+    normalised, so that two leaves merge (`merge_partials`). Written as
+    `_xla_decode_walk` is, for what the TPU's compiler makes of it."""
+    d = q.shape[-1]
+    q32 = q.astype(jnp.float32)
+    visible = jnp.arange(k_cache.shape[1])[None, :] < stop[:, None]
+
+    def attend(k, v):
+        scores = jnp.swapaxes(
+            jnp.sum(q32[:, None] * k.astype(jnp.float32), axis=-1), 1, 2)
+        scores = jnp.where(visible[:, None, :k.shape[1]],
+                           scores / (d ** 0.5), NEG_INF)
+        m = jnp.max(scores, axis=-1)
+        p = jnp.exp(scores - jnp.where(jnp.isfinite(m), m, 0.0)[..., None])
+        acc = jnp.einsum("bht,bthd->bhd", p, v.astype(jnp.float32))
+        return m, jnp.sum(p, axis=-1), acc
+
+    return over_kv_prefix(attend, (k_cache, v_cache), bound)
+
+
+def merge_partials(*parts):
+    """The softmax over the rows of all `parts` (each `partial_walk`'s m, l,
+    acc) together: [B, H, D] float32; zeros where no part saw a row."""
+    m = functools.reduce(jnp.maximum, [p[0] for p in parts])
+    m = jnp.where(jnp.isfinite(m), m, 0.0)
+    weights = [jnp.exp(p[0] - m) for p in parts]  # 0 for a part without rows
+    total = sum(w * p[1] for w, p in zip(weights, parts))
+    acc = sum(w[..., None] * p[2] for w, p in zip(weights, parts))
+    return acc / jnp.where(total == 0.0, 1.0, total)[..., None]
+
+
+def two_leaf_decode_attention(q, window, chunks, stop_w, stop_c, *,
+                              bounded: bool):
+    """One new token a sequence against `window` = (K, V) rows `[0,
+    stop_w[b])` and `chunks` = (Kbar, Vbar) rows `[0, stop_c[b])`, under one
+    softmax. A free slot comes with both stops 0 and gets zeros. `bounded`:
+    each leaf is walked to the static prefix that holds its longest stop;
+    else whole."""
+    with jax.named_scope("decode_attention"):
+        parts = []
+        for (k, v), stop in ((window, stop_w), (chunks, stop_c)):
+            bound = jnp.max(stop) if bounded else jnp.int32(k.shape[1])
+            parts.append(partial_walk(q, k, v, stop, bound))
+        return merge_partials(*parts).astype(q.dtype)

@@ -494,6 +494,19 @@ LLM_MOE_FETCHED = Counter(
     description="held experts whose weights decode steps read, summed over "
                 "expert layers and steps")
 
+#: An "eva" model's decode steps (models/eva.py), counted on the device
+#: inside the chunks and read with their tokens (llm/engine.py `_count_eva`):
+#: a live slot's step that ended a chunk of positions (every eva layer then
+#: wrote one summary row), and one that began a window after the first.
+LLM_EVA_SUMMARIES = Counter(
+    "rt_llm_eva_summaries_total",
+    description="decode steps of live slots that ended a chunk: each eva "
+                "layer wrote one summary row")
+LLM_EVA_RESTARTS = Counter(
+    "rt_llm_eva_restarts_total",
+    description="decode steps of live slots at which an eva layer's window "
+                "started over")
+
 #: Pipeline-parallel serving (README "Pipeline-parallel serving"), drained
 #: each flush tick in processes hosting a PipelineStage: occupancy is the
 #: stage's busy fraction of the tick window, bubble its complement. A

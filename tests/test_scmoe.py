@@ -293,7 +293,12 @@ def test_the_four_older_arms_build_their_configs_as_before(name):
         "moe_experts", "moe_top_k", "moe_d_ff", "moe_scoring",
         "moe_norm_topk", "moe_routed_scale", "moe_score_bias",
         "moe_shared_experts", "moe_first_layer", "experts_held",
-        "first_expert", "moe_group_tile", "cache_row"}
+        "first_expert", "moe_group_tile", "cache_row",
+        # PR 49's, for the `evabyte` arm alone (tests/test_eva.py)
+        "eva_window", "eva_chunk", "eva_pool_std", "norm_unit_offset",
+        "residual_f32", "pred_heads"}
+    assert (cfg.eva_window, cfg.eva_chunk, cfg.norm_unit_offset,
+            cfg.residual_f32, cfg.pred_heads) == (0, 0, False, False, 1)
 
 
 OLDER_EXPERT_ARMS = {

@@ -757,3 +757,94 @@ def test_v5e_kernel_program_does_not_name_its_callers_lines(
     finally:
         compile_cache.program_identity()
     assert same == (frames == 1)
+
+
+# ---------------------------------------------------------------------------
+# EVA attention (`model_type` `evabyte`): leaves of two kinds in one layer.
+
+#: EvaByte's attention at its PUBLISHED widths (32 heads of 128, a window of
+#: 2048 rows that starts over, one summary row for every 16 positions, 16384
+#: positions a slot, SwiGLU 11008, a head of 8 x 320 columns) at two of its
+#: layers.
+EVA = LLMConfig(
+    vocab_size=320, d_model=4096, n_layers=2, n_heads=32, max_seq=16384,
+    dtype="bfloat16",
+    arch={"model_type": "evabyte", "attention_class": "eva", "chunk_size": 16,
+          "window_size": 2048, "num_chunks": None, "num_key_value_heads": 32,
+          "intermediate_size": 11008, "hidden_act": "silu",
+          "attention_bias": False, "rope_theta": 100000,
+          "rope_scaling": None, "rms_norm_eps": 1e-5,
+          "norm_add_unit_offset": True, "fp32_skip_add": True,
+          "fp32_logits": True, "num_pred_heads": 8,
+          "tie_word_embeddings": False})
+
+
+def test_v5e_eva_chunk_program_walks_two_kinds_of_leaf_and_copies_no_rows(
+        chip):
+    """Each layer keeps a window leaf and a summaries leaf, both crossing
+    the chunk program's boundary in their default layout; the bounded step
+    walks each to a static prefix of its own (two `conditional`s a layer)
+    where it lies, writes the position's row and, where a chunk ends, the
+    chunk's summary, and copies no leaf, whole or any prefix of its rows.
+    (Gathered with a vmapped `dynamic_slice`, the chunk's last 16 rows made
+    the v5e's compiler copy every window leaf into another layout in every
+    step: PERF.md section 6, PR 49.) No kernel serves two leaves yet."""
+    eng = build_compiled(chip, cfg=EVA, max_batch=16, decode_chunk=16)
+    assert eng.model.cfg.cache_row == 0
+    assert eng.cache_boundary_copies == 0
+    assert (eng._kernel_blocks, eng._decode_form) == ({}, "xla")
+    kinds = eng.cache_stats()["cache_kinds"]
+    assert {k: (v["layers"], v["leaves"], v["rows"]) for k, v in kinds.items()
+            } == {"window": (2, 4, 2048), "chunks": (2, 4, 1024)}
+    assert "bfloat16[16, 1024, 32, 128]" in eng.cache_layout
+    assert "bfloat16[16, 2048, 32, 128]" in eng.cache_layout
+    shapes = eng._chunk_shapes(eng.params, eng._cache_spec, True)
+    compiled = eng._chunk.lower(*shapes).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r" conditional\(", text)) == 2 * EVA.n_layers
+    assert "tpu_custom_call" not in text
+    assert not [m for m in moved_rows(eng, text) if m[0] == "copy"]
+    # the block the tokens ride in: 16 steps and one column of counters
+    assert jax.eval_shape(eng._chunk, *shapes)[2].shape == (16, 16 + 1)
+    # a prefill of 6144 rows hands on ONE window's rows and a summary row
+    # for every 16 positions of its bucket
+    one = jax.eval_shape(eng._prefill, eng.params,
+                         jax.ShapeDtypeStruct((1, 6144), jnp.int32), 5000)[1]
+    assert sorted({leaf.shape for leaf in jax.tree.leaves(one)}) == [
+        (1, 384, 32, 128), (1, 2048, 32, 128)]
+
+
+def test_v5e_evabyte_as_benchmarked_fits_the_chip(chip):
+    """`benchmark/configs/evabyte-pp4-8l.json` as the cell runs it, 8 layers
+    and 16 slots: the 16-step chunk program and the longest prefill the mix
+    reaches (12288 rows), each beside everything else the device holds,
+    within the chip's 16 GB. Compile-only: no parameter is made."""
+    import json
+
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmark", "configs",
+            "evabyte-pp4-8l.json")) as f:
+        config = json.load(f)
+    app = config["app_kwargs"]
+    eng = build_compiled(chip, cfg=LLMConfig(**config["llm_config"]),
+                         max_batch=app["max_batch"],
+                         decode_chunk=app["decode_chunk"])
+    assert eng.cache_boundary_copies == 0
+    cache = eng.cache_stats()["cache_bytes"]
+    assert round(cache / 1e9, 2) == 6.44
+
+    def held(compiled):
+        m = compiled.memory_analysis()
+        return (m.argument_size_in_bytes + m.output_size_in_bytes
+                - m.alias_size_in_bytes + m.temp_size_in_bytes)
+
+    chunk = eng._chunk.lower(*eng._chunk_shapes(
+        eng.params, eng._cache_spec, False)).compile()
+    assert 9.7e9 < held(chunk) < 11.5e9  # weights 3.26 + cache 6.44 + temps
+    on_chip = lambda *dims: jax.ShapeDtypeStruct(  # noqa: E731
+        dims, jnp.int32, sharding=SingleDeviceSharding(chip))
+    prefill = eng._prefill.lower(eng.params, on_chip(1, 12288),
+                                 on_chip()).compile()
+    # beside the cache, the chunk program's temporaries and a quarter of
+    # the cache in parked slices (`_park_budget`)
+    assert held(prefill) + held(chunk) - 3.26e9 + cache / 4 < 15e9
