@@ -247,6 +247,9 @@ class ChipProbe:
         against `models/mla.py` `_latent_attention` over the whole leaf, at
         Kimi K2's and Kimi Linear's cells' shapes (32 slots of 64 heads, 64
         of 32, on 4096 rows of 640), ragged lengths and free rows. The
+        two-leaf kernel (an "eva" layer's bounded step) against the two
+        walks merged, at EvaByte's cell's leaves (16 slots of 32 heads on a
+        window of 2048 rows and 1024 summaries). The
         occupied-experts kernel (`ops/expert_decode.py`, every expert
         layer's decode step on the chip) against the dense arm of the same
         layer, at the four expert cells' shapes (LongCat-Flash's 32 rows
@@ -268,8 +271,12 @@ class ChipProbe:
         from ray_tpu.ops.attention import kernel_refusal, prefill_attention
         from ray_tpu.ops.decode_attention import (_xla_decode_attention,
                                                   latent_refusal,
+                                                  merge_partials,
+                                                  partial_walk,
                                                   ragged_decode_attention,
                                                   ragged_latent_attention,
+                                                  ragged_two_leaf_attention,
+                                                  two_leaf_refusal,
                                                   walk_refusal)
         from ray_tpu.ops.flash_attention import flash_attention
 
@@ -358,6 +365,29 @@ class ChipProbe:
                             scale).reshape(b, -1), 0),
                     h * rank)
 
+        # An "eva" layer's two leaves under one softmax, at EvaByte's cell:
+        # slots at scattered positions, free rows between them.
+        b, h, d, window, per = 16, 32, 128, 2048, 128
+        ks = jax.random.split(jax.random.PRNGKey(50), 6)
+        q = jax.random.normal(ks[0], (b, h, d), jnp.bfloat16)
+        leaves = [jax.random.normal(key, (b, rows_, h, d), jnp.bfloat16)
+                  for key, rows_ in zip(ks[1:5], (window, window, 8 * per,
+                                                  8 * per))]
+        at = jax.random.randint(ks[5], (b,), 0, 8 * window)
+        live = jnp.arange(b) % 4 != 1
+        stop_w = jnp.where(live, at % window + 1, 0)
+        stop_c = jnp.where(live, at // window * per, 0)
+        compare(f"ragged_two_leaf_attention b{b} rows {window} + {8 * per} "
+                f"h{h} d{d}",
+                lambda: ragged_two_leaf_attention(
+                    q, leaves[:2], leaves[2:], stop_w, stop_c).reshape(b, -1),
+                lambda: merge_partials(
+                    partial_walk(q, *leaves[:2], stop_w, jnp.max(stop_w)),
+                    partial_walk(q, *leaves[2:], stop_c, jnp.max(stop_c))
+                ).reshape(b, -1), h * d)
+        two_leaf = two_leaf_refusal(q.shape, leaves[0].shape, leaves[2].shape)
+        del leaves
+
         # The expert decode step at the four expert cells' layers: a router
         # narrow enough that every held expert gets rows (dense routing),
         # and one sixteen times as wide, of which this share holds a
@@ -423,6 +453,7 @@ class ChipProbe:
                 "expert_kernel_refusal": refusals,
                 "latent_kernel_refusal": latent_refusal(
                     (32, 4096, row), rank),
+                "two_leaf_kernel_refusal": two_leaf,
                 "prefill_kernel_refusal": kernel_refusal(
                     (1, 128, heads, hd), (1, 128, heads, hd)),
                 "decode_kernel_refusal": walk_refusal(
@@ -633,6 +664,10 @@ def chip_phase(ray_tpu, greedy: list) -> None:
            if rep["latent_kernel_refusal"] is None
            else f", and a latent model's through its own XLA walk "
                 f"({rep['latent_kernel_refusal']})")
+        + (", and an \"eva\" layer's two leaves through the two-leaf kernel"
+           if rep["two_leaf_kernel_refusal"] is None
+           else f", and an \"eva\" layer's two leaves through two XLA walks "
+                f"({rep['two_leaf_kernel_refusal']})")
         + (", and an expert layer's decode step reads the held experts that "
            "got a row"
            if not any(rep["expert_kernel_refusal"].values())

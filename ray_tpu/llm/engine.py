@@ -583,7 +583,8 @@ class ContinuousEngine:
         for name in _PICK_COUNTERS + _EVA_COUNTERS:
             setattr(self, f"{name}_total", 0)
         # The decode steps dispatched since start, those whose attention
-        # over K and V or latent rows is a ragged kernel (`_decode_blocks`),
+        # over K and V, latent rows or an "eva" layer's two leaves is a
+        # ragged kernel (`_decode_blocks`),
         # the cache rows they walked a slot, and the rows a live slot had
         # on average (`cache_stats`: kv_walk_share, kv_live_share).
         self.decode_steps = 0
@@ -1172,20 +1173,23 @@ class ContinuousEngine:
 
     def _decode_blocks(self) -> tuple[dict, str]:
         """The ragged kernels' row block by kind of rows leaf (`full`,
-        `window`) whose layers' bounded decode step takes a kernel (a kind
-        the dispatcher refuses is left out, and {} is the XLA walk
+        `window`, `chunks`) whose layers' bounded decode step takes a kernel
+        (a kind the dispatcher refuses is left out, and {} is the XLA walk
         throughout), and the name of that: `kernel`, `xla`, `mixed`. The
         dispatchers' own rules (`ops/decode_attention.py`: `walk_refusal`
         for the K and V leaves of `mha` layers, `latent_refusal` for the
-        latent leaf of `mla` layers, each deciding leaf by leaf) put to the
-        leaves the chunk program is traced with, under the mesh it is
-        traced under. Nothing is read back from the device. (No model has
-        rows leaves of both families; an "eva" layer's two leaves under one
-        softmax have no kernel yet, `two_leaf_decode_attention`.)"""
+        latent leaf of `mla` layers, each deciding leaf by leaf, and
+        `two_leaf_refusal` for an "eva" layer's window and chunks leaves,
+        ONE answer and one block for both) put to the leaves the chunk
+        program is traced with, under the mesh it is traced under. Nothing
+        is read back from the device. (No model has rows leaves of two
+        families.)"""
         import jax
 
         from ray_tpu.ops.decode_attention import (latent_block,
                                                   latent_refusal, row_block,
+                                                  two_leaf_block,
+                                                  two_leaf_refusal,
                                                   walk_refusal)
 
         mcfg = self.model.cfg
@@ -1196,15 +1200,27 @@ class ContinuousEngine:
             if mixer == "mla":
                 refused = latent_refusal(shape, mcfg.kv_lora_rank, dtype)
                 return None if refused else latent_block(shape, dtype)
+            if mixer == "eva":  # (the window leaf's shape, the chunks')
+                refused = two_leaf_refusal(q, *shape, dtype)
+                return None if refused else two_leaf_block(*shape, dtype)
             refused = walk_refusal(q, shape, dtype)
             return None if refused else row_block(shape, dtype)
 
         leaves: dict = {}  # kind -> EVERY leaf of its layers, as the rules ask
         for i in range(mcfg.n_layers):
-            if mcfg.mixer_of(i) in ("mha", "mla"):
+            mixer, layer = mcfg.mixer_of(i), self._cache_spec[f"layer_{i}"]
+            if mixer in ("mha", "mla"):
                 leaves.setdefault(mcfg.cache_kind_of(i), set()).update(
-                    (mcfg.mixer_of(i), leaf.shape, leaf.dtype) for leaf in
-                    jax.tree.leaves(self._cache_spec[f"layer_{i}"]))
+                    (mixer, leaf.shape, leaf.dtype)
+                    for leaf in jax.tree.leaves(layer))
+            elif mixer == "eva":
+                # both kinds of leaf get the layer's one question
+                kinds = _leaves_by_kind(mcfg, {f"layer_{i}": layer})
+                asked = (mixer, tuple(kinds[kind][0][1].shape
+                                      for kind in ("window", "chunks")),
+                         kinds["window"][0][1].dtype)
+                for kind in ("window", "chunks"):
+                    leaves.setdefault(kind, set()).add(asked)
         with self._mesh_scope():  # a kind's block: the one ALL its leaves get
             blocks = {kind: _agreed({block_of(*leaf) for leaf in asked})
                       for kind, asked in leaves.items()}
@@ -1682,8 +1698,9 @@ class ContinuousEngine:
                         float(seen.mean()))
                 if self._eva:
                     # a block that starts over, and the summaries of the
-                    # windows behind it; each step's walk is bounded by the
-                    # longest of its own live stops (`models/eva.py`)
+                    # windows behind it; each step's XLA walk is bounded by
+                    # the longest of its own live stops (`models/eva.py`),
+                    # the kernel's by each slot's own
                     per = self._window // self.model.cfg.eva_chunk
                     for kind, shown in (
                             ("window", (seen - 1) % self._window + 1),

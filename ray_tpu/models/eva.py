@@ -187,7 +187,9 @@ class EVA(nn.Module):
     def _step(self, q, k, v, mu, phi, positions, bounded, live):
         """One token a slot at its own position p ([B, 1]): its row goes to
         p mod W, one softmax runs over the visible rows of both leaves
-        (`ops/decode_attention.py` `two_leaf_decode_attention`; a slot that
+        (`ops/decode_attention.py` `two_leaf_decode_attention`: on the chip
+        a `bounded` step is one ragged kernel that stops every slot at its
+        own row of each leaf, elsewhere two walks and a merge; a slot that
         `live` [B] bool marks free sees none), and where p ends a chunk the
         chunk's summary is made from the window's last C rows and written,
         for the live slots and no other."""

@@ -9,19 +9,19 @@ cache, each sequence up to its own length. Which form serves where
   `ragged_decode_attention`, one Pallas kernel for a head of its own and
   for grouped heads, full leaves and rings. Every slot stops at its OWN
   length rounded up to a row block, a free slot reads nothing, the leaf is
-  read in the shape and layout it has, and the scores never leave VMEM
-  (PERF.md section 6, PR 37).
+  read in the shape and layout it has, the scores never leave VMEM (PR 37).
 - Given a `kv_bound` anywhere else (the CPU and the tests, a `tp` mesh, a
   row that is no whole lane tile): the bounded walk in plain JAX,
-  `_xla_decode_walk` and `grouped_walk`, which stops every slot at a static
-  prefix of the cache that holds the longest LIVE sequence
-  (`over_kv_prefix`) and takes the cache in the engine's own layout. How
-  it is written decides what the TPU's compiler makes of it (PERF.md
-  section 6, PR 29): change it only with a chip run beside it.
-  Latent rows (`models/mla.py`) have a kernel and a walk of their own.
+  `_xla_decode_walk` and `grouped_walk`, to a static prefix of the cache
+  that holds the longest LIVE sequence (`over_kv_prefix`). How it is
+  written decides what the TPU's compiler makes of it (PERF.md section 6,
+  PR 29): change it only with a chip run beside it.
 - Without a bound (`LLMEngine.generate`, the pipeline's stages, the tests'
-  references): `_xla_decode_attention` walks all S rows under a
-  per-sequence mask.
+  references): `_xla_decode_attention`, all S rows under a mask.
+- Latent rows (`models/mla.py`, PR 41) and an "eva" layer's two leaves
+  under one softmax (`models/eva.py`, PR 50: `two_leaf_decode_attention`)
+  have a kernel, a rule and walks of their own, each in its own part of
+  this file (a kernel's program names its body's lines: add at the end).
 
 Reference role: vLLM's paged-attention decode kernel (the engine seat
 python/ray/llm delegates; no TPU equivalent exists in the reference).
@@ -574,12 +574,16 @@ def _ragged_latent(q, latents, lengths, live, *, rank: int, scale: float,
 # ---------------------------------------------------------------------------
 # ONE softmax over leaves of two kinds (models/eva.py): a window leaf whose
 # rows `[0, stop_w)` are positions, and a chunks leaf whose rows `[0, stop_c)`
-# each stand for several. Each leaf is walked on its own to what a softmax
-# is made of (running maximum, sum, weighted sum), and the two are merged:
-# what an online softmax does between two blocks, between two leaves. The
-# `mha` family's programs above are left as they are; no kernel here yet
-# (PERF.md section 7): each walk stops at the static prefix that holds its
-# leaf's longest live stop.
+# each stand for several. On a TPU a bounded step takes ONE ragged kernel
+# over both (`ragged_two_leaf_attention`, PERF.md section 6, PR 50): every
+# slot stops at its own row in BOTH leaves and one online softmax runs over
+# its window blocks and then its summary blocks. Elsewhere (the CPU and the
+# tests, a `tp` mesh, the unbounded step) each leaf is walked on its own in
+# XLA to what a softmax is made of (running maximum, sum, weighted sum), a
+# bounded walk to the static prefix that holds its leaf's longest live stop,
+# and the two are merged: what an online softmax does between two blocks,
+# between two leaves. The `mha` family's programs above are left as they
+# are, to the line.
 
 
 @jax.jit
@@ -619,14 +623,240 @@ def merge_partials(*parts):
     return acc / jnp.where(total == 0.0, 1.0, total)[..., None]
 
 
+def two_leaf_block(window_shape, chunks_shape, dtype) -> int | None:
+    """Rows of one block of the two-leaf kernel, the same in both leaves
+    `[slots, rows, H, row]` (four operands under BlockSpecs of one shape):
+    `row_block`'s of as many rows as divide both leaves (EvaByte's 2048 and
+    1024 rows of 32 heads of 128 bf16 values: 128 rows, and a window of
+    2048 holds 128 summaries, so a summaries' stop is whole blocks). None:
+    no such divisor."""
+    import math  # (here: no line above the older kernels may move)
+
+    slots, rows, kv, row = window_shape
+    shared = math.gcd(rows, chunks_shape[1])
+    block = row_block((slots, shared, kv, row), dtype)
+    # (`row_block` takes a short leaf whole; here only if it IS both leaves)
+    return block if block and (block % 8 == 0
+                               or shared == rows == chunks_shape[1]) else None
+
+
+def two_leaf_refusal(q_shape, window_shape, chunks_shape,
+                     dtype=jnp.bfloat16) -> str | None:
+    """Why a bounded step of q [B, H, D] over a window leaf and a chunks
+    leaf `[B, rows, H, D]` takes the two XLA walks and the merge, or None
+    when it takes `ragged_two_leaf_attention`: `two_leaf_decode_attention`'s
+    own rule, for whoever wants to know the choice without making the call
+    (llm/engine.py counts the decode steps either way)."""
+    if not rule.on_tpu():
+        return rule.NOT_ASKED
+    if (reason := rule.mesh_refusal()) is not None:
+        return reason
+    (_, hq, d), (_, rows, kv, row) = q_shape, window_shape
+    if (kv, row) != (hq, d) or chunks_shape[2:] != (kv, row):
+        return (f"leaves {window_shape} and {chunks_shape} are not rows of "
+                f"{hq} heads of {d}")
+    if row % LANES:
+        return f"a cache row of {row} is not whole lane tiles ({LANES})"
+    if two_leaf_block(window_shape, chunks_shape, dtype) is None:
+        return (f"{rows} and {chunks_shape[1]} rows share no block in whole "
+                f"sublane tiles")
+    return None
+
+
+def _merged_seen(ref, seen):
+    """`_merged(ref)` with the block's rows at and past `seen` zeroed: what
+    lies above a stop is an earlier occupant's, and a weight of exactly 0
+    keeps nothing out of a product if the value is not finite. In words,
+    as `_merged` reads them; the fetch hides it (my chip run, PR 50)."""
+    _, t, kv, row = ref.shape
+    pack = 4 // ref.dtype.itemsize
+    if pack > 1 and kv % pack == 0:
+        kv, image = kv // pack, jnp.uint32
+    else:
+        pack, image = 1, ref.dtype
+    rows = ref.bitcast(image).reshape(t * kv, row)[...]
+    at = jax.lax.broadcasted_iota(jnp.int32, (t * kv, 1), 0) // kv
+    rows = jnp.where(at < seen, rows, jnp.zeros_like(rows))
+    return pltpu.bitcast(rows, ref.dtype) if pack > 1 else rows
+
+
+def _two_leaf_kernel(stop_w_ref, stop_c_ref, slot_ref, at_ref, _held_w_ref,
+                     _held_c_ref, q_ref, kw_ref, vw_ref, kc_ref, vc_ref,
+                     o_ref, m_ref, l_ref, acc_ref, *, scale: float,
+                     block: int):
+    """One entry of the grid: entry `at_ref[i]` of slot `slot_ref[i]`, whose
+    entries are its window blocks and then its summary blocks. q_ref/o_ref
+    [H, D]; kw/vw and kc/vc [1, block, H, D] as the two leaves hold them,
+    of which an entry reads one pair (the other names the block its
+    pipeline already holds); m/l [H, 128] (every lane the same), acc [H,
+    D]: ONE online softmax over both leaves' blocks."""
+    i = pl.program_id(0)
+    j = at_ref[i]
+    stop_w, stop_c = stop_w_ref[slot_ref[i]], stop_c_ref[slot_ref[i]]
+    ahead = (stop_w + block - 1) // block  # the slot's window entries
+    hq = q_ref.shape[0]
+    cols = block * hq
+
+    @pl.when(j == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def rows(k_ref, v_ref, seen):
+        """`_ragged_kernel`'s `_block` for heads on key/value heads of
+        their own (its `exact` arm: the float32 walk's arithmetic up to the
+        order of its sums), the block's first `seen` rows visible."""
+        k, v = _merged(k_ref), _merged_seen(v_ref, seen)
+        s = jax.lax.dot_general(
+            q_ref[...], k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        # column c of the merged matrix is row c // H of the block, head
+        # c % H: a query head reads the visible rows of its own
+        col = jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1)
+        head = jnp.where(col // hq < seen, col % hq, -1)
+        own = jax.lax.broadcasted_iota(jnp.int32, (hq, 1), 0)
+        s = jnp.where(head == own, s, NEG_INF)
+        # (the block holds a visible row, so every head's max is finite)
+        m_prev, l_prev = m_ref[:, 0:1], l_ref[:, 0:1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+        weigh = lambda w: jax.lax.dot_general(  # noqa: E731
+            w, v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        hi = p.astype(v.dtype)
+        pv = weigh(hi)
+        if hi.dtype != p.dtype:
+            # float32 probabilities against V's own dtype, as two products
+            pv = pv + weigh((p - hi.astype(jnp.float32)).astype(v.dtype))
+        acc_ref[...] = acc_ref[...] * alpha + pv
+        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+
+    @pl.when(j < ahead)
+    def _window():
+        rows(kw_ref, vw_ref, stop_w - j * block)
+
+    @pl.when((j >= ahead) & ((j - ahead) * block < stop_c))
+    def _chunks():
+        rows(kc_ref, vc_ref, stop_c - (j - ahead) * block)
+
+    @pl.when((j + 1 - ahead) * block >= stop_c)  # the slot's last entry
+    def _finish():
+        l = l_ref[:, 0:1]
+        l = jnp.where(l == 0.0, 1.0, l)  # a free slot: zeros
+        o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
+
+
+def ragged_two_leaf_attention(q, window, chunks, stop_w, stop_c, *,
+                              interpret: bool = False):
+    """q [B, H, D] against `window` = (K, V) rows `[0, stop_w[b])` and
+    `chunks` = (Kbar, Vbar) rows `[0, stop_c[b])`, leaves `[B, rows, H, D]`
+    of heads of their own, under ONE softmax -> [B, H, D]; a slot with both
+    stops 0 reads nothing and gets zeros.
+
+    The grid is `ragged_latent_attention`'s: ONE list of the row blocks
+    that hold a visible row, slot after slot, a slot's window blocks and
+    then its summary blocks, its length a traced scalar (one program
+    whatever it is). The four leaves are four operands read where they lie
+    in blocks of one shape; the pair an entry does not read names the block
+    its pipeline already holds and fetches nothing, as a free slot's one
+    entry does for both. One online softmax in float32 VMEM scratch runs
+    over all of a slot's entries, so nothing is left to merge. bf16
+    operands on the MXU, float32 sums, the float32 probabilities against
+    bf16 values as two products: `partial_walk`'s precision."""
+    block = two_leaf_block(window[0].shape, chunks[0].shape, window[0].dtype)
+    if not block or window[0].shape[2:] != q.shape[1:]:
+        raise ValueError(f"q {q.shape} against leaves {window[0].shape} and "
+                         f"{chunks[0].shape} in blocks of {block} rows "
+                         f"(`two_leaf_refusal` says so beforehand)")
+    return _ragged_two_leaf(q, *window, *chunks, stop_w, stop_c, block=block,
+                            interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("block", "interpret"))
+def _ragged_two_leaf(q, kw, vw, kc, vc, stop_w, stop_c, *, block: int,
+                     interpret: bool):
+    """`ragged_two_leaf_attention` in row blocks of `block`
+    (`two_leaf_block`'s, static: the layers of a model share one trace)."""
+    b, hq, d = q.shape
+    n_w, n_c = kw.shape[1] // block, kc.shape[1] // block
+    stop_w = jnp.clip(stop_w.astype(jnp.int32), 0, kw.shape[1])
+    stop_c = jnp.clip(stop_c.astype(jnp.int32), 0, kc.shape[1])
+    # The grid's entries, as tables a scalar prefetch carries: whose entry
+    # it is, which of its entries (its window blocks come first), and the
+    # block each leaf's operands hold while it passes, `slot * blocks +
+    # block`: its own, or the one before it (the first of all, if none).
+    # Entries past the grid's length are never looked at.
+    need_w, need_c = -(-stop_w // block), -(-stop_c // block)
+    need = jnp.maximum(need_w + need_c, 1)
+    ends = jnp.cumsum(need)
+    entry = jnp.arange(b * (n_w + n_c), dtype=jnp.int32)
+    slot = jnp.minimum(jnp.sum(entry[:, None] >= ends[None, :], axis=1,
+                               dtype=jnp.int32), b - 1)
+    at = entry - (ends - need)[slot]
+    behind = at - need_w[slot]  # which summary block, of a summaries' entry
+    held = lambda own: jnp.maximum(jax.lax.cummax(own), 0)  # noqa: E731
+    held_w = held(jnp.where(behind < 0, slot * n_w + at, -1))
+    held_c = held(jnp.where((behind >= 0) & (behind < need_c[slot]),
+                            slot * n_c + behind, -1))
+
+    # (134 MB of summaries a layer will not be staged in fast memory, a
+    # window leaf might: `_ragged`'s reason)
+    in_hbm = (lambda leaf: leaf) if interpret else functools.partial(
+        pltpu.with_memory_space_constraint, memory_space=pltpu.HBM)
+    tables = (stop_w, stop_c, slot, at, held_w, held_c)
+    head = pl.BlockSpec((None, hq, d), lambda i, *refs: (refs[2][i], 0, 0))
+
+    def leaf(held_at: int, n: int):
+        """A leaf's operands: the block `tables[held_at]` names, of `n`."""
+        return pl.BlockSpec((1, block, hq, d), lambda i, *refs: (
+            refs[held_at][i] // n, refs[held_at][i] % n, 0, 0))
+
+    leaf_w, leaf_c = leaf(4, n_w), leaf(5, n_c)
+    return pl.pallas_call(
+        functools.partial(_two_leaf_kernel, scale=d ** -0.5, block=block),
+        out_shape=jax.ShapeDtypeStruct((b, hq, d), q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(tables),
+            grid=(ends[-1],),
+            in_specs=[head, leaf_w, leaf_w, leaf_c, leaf_c],
+            out_specs=head,
+            scratch_shapes=[
+                pltpu.VMEM((hq, LANES), jnp.float32),  # max
+                pltpu.VMEM((hq, LANES), jnp.float32),  # denominator
+                pltpu.VMEM((hq, d), jnp.float32),  # accumulator
+            ]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=64 << 20),
+        interpret=interpret,
+    )(*tables, q, in_hbm(kw), in_hbm(vw), in_hbm(kc), in_hbm(vc))
+
+
 def two_leaf_decode_attention(q, window, chunks, stop_w, stop_c, *,
                               bounded: bool):
     """One new token a sequence against `window` = (K, V) rows `[0,
     stop_w[b])` and `chunks` = (Kbar, Vbar) rows `[0, stop_c[b])`, under one
-    softmax. A free slot comes with both stops 0 and gets zeros. `bounded`:
-    each leaf is walked to the static prefix that holds its longest stop;
-    else whole."""
+    softmax. A free slot comes with both stops 0 and gets zeros. `bounded`
+    on a TPU: the ragged kernel, every slot to its own rows of both leaves
+    (`two_leaf_refusal` is the rule; each choice is stated once at INFO).
+    `bounded` elsewhere: each leaf is walked to the static prefix that
+    holds its longest stop; else whole."""
     with jax.named_scope("decode_attention"):
+        if bounded:
+            reason = two_leaf_refusal(q.shape, window[0].shape,
+                                      chunks[0].shape, window[0].dtype)
+            if reason is None:
+                rule.state_once("two-leaf decode attention: ragged Pallas "
+                                "kernel")
+                return ragged_two_leaf_attention(q, window, chunks, stop_w,
+                                                 stop_c)
+            if reason != rule.NOT_ASKED:
+                rule.state_once(f"two-leaf decode attention: XLA walks to a "
+                                f"quarter prefix ({reason})")
         parts = []
         for (k, v), stop in ((window, stop_w), (chunks, stop_c)):
             bound = jnp.max(stop) if bounded else jnp.int32(k.shape[1])

@@ -354,7 +354,7 @@ def test_the_stats_name_both_kinds_of_leaf_of_one_layer(engine):
         assert 0 < kind["live_share"] <= kind["walk_share"] <= 1
     assert st["kv_walk_share"] == kinds["window"]["walk_share"]
     assert st["eva_summaries_total"] > 0 and st["eva_restarts_total"] > 0
-    assert st["decode_steps_kernel"] == 0  # no kernel for two leaves yet
+    assert st["decode_steps_kernel"] == 0  # (off the chip: the two walks)
 
 
 @pytest.fixture

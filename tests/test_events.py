@@ -176,8 +176,12 @@ def test_event_persistence_segments_and_rotation(tmp_path, shutdown_only,
         except OSError:
             return []
 
+    # (the last full segment of the 100 pumped events ends at seq 95 or
+    # later: an earlier look can find the first segments before rotation
+    # has dropped them, the head's own `node_register` among their rows)
     segs = _wait_for(
-        lambda: s if len(s := _segments()) and len(s) <= 3 else None,
+        lambda: s if len(s := _segments()) and len(s) <= 3
+        and int(s[-1][len("seg-"):-len(".jsonl")]) >= 95 else None,
         what="rotated segments")
     # keep-last-K rotation: 100 events / 16 per segment > 3 kept.
     assert 1 <= len(segs) <= 3

@@ -788,7 +788,9 @@ def test_v5e_eva_chunk_program_walks_two_kinds_of_leaf_and_copies_no_rows(
     chunk's summary, and copies no leaf, whole or any prefix of its rows.
     (Gathered with a vmapped `dynamic_slice`, the chunk's last 16 rows made
     the v5e's compiler copy every window leaf into another layout in every
-    step: PERF.md section 6, PR 49.) No kernel serves two leaves yet."""
+    step: PERF.md section 6, PR 49.) This process is held to the CPU, so
+    the dispatcher asks for no kernel: on the chip the two walks are ONE
+    (the tests below)."""
     eng = build_compiled(chip, cfg=EVA, max_batch=16, decode_chunk=16)
     assert eng.model.cfg.cache_row == 0
     assert eng.cache_boundary_copies == 0
@@ -814,13 +816,90 @@ def test_v5e_eva_chunk_program_walks_two_kinds_of_leaf_and_copies_no_rows(
         (1, 384, 32, 128), (1, 2048, 32, 128)]
 
 
-def test_v5e_evabyte_as_benchmarked_fits_the_chip(chip):
+def test_v5e_eva_chunk_program_with_the_two_leaf_kernel_moves_no_rows(
+        chip, monkeypatch):
+    """On the chip (the dispatcher's question about the backend answered as
+    the chip would) the bounded step of an "eva" layer is
+    `ragged_two_leaf_attention`: ONE Mosaic call a layer in the step's body
+    where two `conditional`s of `over_kv_prefix` stood, handed all four
+    leaves as the step's own update of the window left them, in HBM; no
+    loop but the chunk's own (`trace_reduce.loop_steps`); no leaf copied
+    into another layout (`cache_boundary_copies` 0, PR 49's pin), and
+    outside the fusions no `copy`, `slice`, `transpose` or
+    `bitcast-convert` of a leaf or of any prefix of its rows but the 16
+    rows a finished chunk is pooled from, which the XLA form gathers too.
+    The engine counts its decode steps under the kernel, in blocks of 128
+    rows of both leaves."""
+    from ray_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "on_tpu", lambda: True)
+    eng = build_compiled(chip, cfg=EVA, max_batch=16, decode_chunk=16)
+    assert eng.cache_boundary_copies == 0
+    assert (eng._kernel_blocks, eng._decode_form) == (
+        {"window": 128, "chunks": 128}, "kernel")
+    text = eng._chunk.lower(*eng._chunk_shapes(
+        eng.params, eng._cache_spec, True)).compile().as_text()
+    calls = re.findall(r"%([a-z_]+)[.\d]* = \S+ custom-call\((.*?)\), "
+                       r"custom_call_target=\"tpu_custom_call\"", text)
+    assert [kernel for kernel, _ in calls] == [
+        "_ragged_two_leaf"] * EVA.n_layers
+    assert " conditional(" not in text
+    assert len(re.findall(r" while\(", text)) == 1
+    moved = moved_rows(eng, text, "copy|slice|transpose|bitcast-convert|"
+                                  "copy-start|copy-done")
+    assert set(moved) <= {("transpose", (16, 16, 32, 128))}, moved
+    for _, operands in calls:
+        # (the grid's length, six tables, q, K, V, Kbar, Vbar)
+        *_, k, v, kbar, vbar = operands.split(", ")
+        assert not any("copy" in leaf for leaf in (k, v, kbar, vbar)), operands
+
+
+def test_v5e_eva_chunk_sharded_over_tp_keeps_the_two_leaf_kernel_out(
+        chips, monkeypatch):
+    """`test_v5e_chunk_sharded_over_tp_keeps_the_unpartitioned_kernel_out`
+    for an "eva" model: given a `tp` mesh the engine traces its step with
+    that mesh in context, `two_leaf_refusal` answers the trace what it
+    answered `_decode_blocks`, and the chunk compiles for four chips over
+    window and summaries leaves sharded on the head axis with the two
+    walks in it (two `conditional`s a layer) and no Mosaic call."""
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from ray_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "on_tpu", lambda: True)
+    mesh = Mesh(np.array(chips), ("tp",))
+    eng = build_compiled(chips[0], cfg=EVA, mesh=mesh, max_batch=16,
+                         decode_chunk=16)
+    assert (eng._kernel_blocks, eng._decode_form) == ({}, "xla")
+    everywhere = lambda s: s if getattr(  # noqa: E731
+        s, "sharding", None) is not None or not hasattr(s, "shape") else (
+        jax.ShapeDtypeStruct(s.shape, s.dtype,
+                             sharding=NamedSharding(mesh, P())))
+    shapes = eng._chunk_shapes(eng.params, eng._cache_spec, True)
+    text = eng._chunk.lower(*(jax.tree.map(everywhere, a)
+                              for a in shapes)).compile().as_text()
+    assert "tpu_custom_call" not in text
+    assert len(re.findall(r" conditional\(", text)) == 2 * EVA.n_layers
+
+
+@pytest.mark.parametrize("form", ["xla", "kernel"])
+def test_v5e_evabyte_as_benchmarked_fits_the_chip(chip, form, monkeypatch):
     """`benchmark/configs/evabyte-pp4-8l.json` as the cell runs it, 8 layers
     and 16 slots: the 16-step chunk program and the longest prefill the mix
     reaches (12288 rows), each beside everything else the device holds,
-    within the chip's 16 GB. Compile-only: no parameter is made."""
+    within the chip's 16 GB, with the two walks (what this process, held to
+    the CPU, is given) and as the chip builds it: the two-leaf kernel, one
+    Mosaic call a layer and no `conditional` from `over_kv_prefix`.
+    Compile-only: no parameter is made."""
     import json
 
+    from ray_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "on_tpu", lambda: form == "kernel")
+    # (the prefill keeps the form this file's other sizes were read with)
+    monkeypatch.setattr(attention, "kernel_refusal",
+                        lambda *shapes, **kw: attention.NOT_ASKED)
     with open(os.path.join(os.path.dirname(os.path.dirname(
             os.path.abspath(__file__))), "benchmark", "configs",
             "evabyte-pp4-8l.json")) as f:
@@ -841,6 +920,13 @@ def test_v5e_evabyte_as_benchmarked_fits_the_chip(chip):
     chunk = eng._chunk.lower(*eng._chunk_shapes(
         eng.params, eng._cache_spec, False)).compile()
     assert 9.7e9 < held(chunk) < 11.5e9  # weights 3.26 + cache 6.44 + temps
+    assert eng._decode_form == form
+    text = chunk.as_text()
+    assert len(re.findall(r" custom-call\(.*tpu_custom_call", text)) == (
+        8 if form == "kernel" else 0)
+    # (the sampled program: the sampler has branches of its own)
+    assert len(re.findall(r" conditional\(.*decode_attention", text)) == (
+        0 if form == "kernel" else 2 * 8)
     on_chip = lambda *dims: jax.ShapeDtypeStruct(  # noqa: E731
         dims, jnp.int32, sharding=SingleDeviceSharding(chip))
     prefill = eng._prefill.lower(eng.params, on_chip(1, 12288),
