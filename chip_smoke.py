@@ -249,7 +249,10 @@ class ChipProbe:
         of 32, on 4096 rows of 640), ragged lengths and free rows. The
         two-leaf kernel (an "eva" layer's bounded step) against the two
         walks merged, at EvaByte's cell's leaves (16 slots of 32 heads on a
-        window of 2048 rows and 1024 summaries). The
+        window of 2048 rows and 1024 summaries). The two-source kernel (an
+        "eva" layer's prefill past its first window) against the tile scan
+        `eva_sequence`, at the bucket of the cell's check prompt (6144
+        rows of 32 heads of 128, a prompt of 5000). The
         occupied-experts kernel (`ops/expert_decode.py`, every expert
         layer's decode step on the chip) against the dense arm of the same
         layer, at the four expert cells' shapes (LongCat-Flash's 32 rows
@@ -267,6 +270,7 @@ class ChipProbe:
         import jax.numpy as jnp
         import numpy as np
 
+        from ray_tpu.models.eva import eva_sequence, summaries
         from ray_tpu.models.mla import _latent_attention
         from ray_tpu.ops.attention import kernel_refusal, prefill_attention
         from ray_tpu.ops.decode_attention import (_xla_decode_attention,
@@ -279,6 +283,8 @@ class ChipProbe:
                                                   two_leaf_refusal,
                                                   walk_refusal)
         from ray_tpu.ops.flash_attention import flash_attention
+        from ray_tpu.ops.two_source_attention import (two_source_attention,
+                                                      two_source_refusal)
 
         dev = jax.devices()[0]
         heads = model["n_heads"]
@@ -388,6 +394,26 @@ class ChipProbe:
         two_leaf = two_leaf_refusal(q.shape, leaves[0].shape, leaves[2].shape)
         del leaves
 
+        # An "eva" layer's prefill past its first window: three windows of
+        # EvaByte's, the prompt ending inside the third.
+        s, chunk, plen = 6144, 16, 5000
+        ks = jax.random.split(jax.random.PRNGKey(51), 5)
+        q, k, v = (jax.random.normal(key, (1, s, h, d), jnp.bfloat16)
+                   for key in ks[:3])
+        kbar, vbar = (t.astype(jnp.bfloat16) for t in jax.jit(
+            lambda k, v, mu, phi: summaries(k, v, mu, phi, chunk))(
+                k, v, *(jax.random.normal(key, (h, d)) * d ** -0.5
+                        for key in ks[3:])))
+        compare(f"two_source_attention b1 s{s} h{h} d{d} window {window} "
+                f"chunk {chunk} prompt {plen}",
+                lambda: two_source_attention(
+                    q, k, v, kbar, vbar, window=window, chunk=chunk,
+                    q_len=jnp.full((1,), plen, jnp.int32)),
+                lambda: jax.jit(lambda *rows: eva_sequence(
+                    *rows, window, chunk))(q, k, v, kbar, vbar), plen)
+        two_source = two_source_refusal(q.shape, window, chunk)
+        del q, k, v, kbar, vbar
+
         # The expert decode step at the four expert cells' layers: a router
         # narrow enough that every held expert gets rows (dense routing),
         # and one sixteen times as wide, of which this share holds a
@@ -454,6 +480,7 @@ class ChipProbe:
                 "latent_kernel_refusal": latent_refusal(
                     (32, 4096, row), rank),
                 "two_leaf_kernel_refusal": two_leaf,
+                "two_source_kernel_refusal": two_source,
                 "prefill_kernel_refusal": kernel_refusal(
                     (1, 128, heads, hd), (1, 128, heads, hd)),
                 "decode_kernel_refusal": walk_refusal(
@@ -639,8 +666,9 @@ def chip_phase(ray_tpu, greedy: list) -> None:
     try:
         ref = (ray_tpu.get(probe.reference.remote(MODEL, greedy),
                            timeout=left(300)) if greedy else [])
+        # (21 rows, each a kernel and its XLA form compiled: 5 min at PR 50)
         rep = ray_tpu.get(probe.kernels.remote(MODEL),
-                          timeout=left(300))
+                          timeout=left(480))
     finally:
         ray_tpu.kill(probe)
 
@@ -678,6 +706,11 @@ def chip_phase(ray_tpu, greedy: list) -> None:
         + ("the Pallas flash kernel"
            if rep["prefill_kernel_refusal"] is None
            else f"the XLA form ({rep['prefill_kernel_refusal']})")
+        + (", and an \"eva\" layer's past its first window through the "
+           "two-source kernel"
+           if rep["two_source_kernel_refusal"] is None
+           else f", and an \"eva\" layer's past its first window through "
+                f"the tile scan ({rep['two_source_kernel_refusal']})")
         + "; each replica's line above counts the steps and rows either "
         "form served")
     require(rep["platform"] == "tpu", "chip actor did not run on a TPU")

@@ -599,7 +599,7 @@ class ContinuousEngine:
         self.splices_in_flight = 0
         self.pipeline_dry = 0
         # Rows of the prefill buckets dispatched since start, and those of
-        # buckets whose program's attention is the flash kernel
+        # buckets whose program's attention is a Pallas kernel
         # (`_prefill_form`).
         self.prefill_rows = 0
         self.prefill_rows_kernel = 0
@@ -969,7 +969,7 @@ class ContinuousEngine:
         the scheduler's passes that began with occupants seated and no
         chunk in flight (`pipeline_dry`); and the rows of the prefill
         buckets dispatched (`prefill_rows`) beside those whose program's
-        attention is the flash kernel (`prefill_rows_kernel`); and the
+        attention is a Pallas kernel (`prefill_rows_kernel`); and the
         decode steps dispatched in the sampled program (`sampler_steps`)
         beside those in which the sampler selected and sorted nothing
         (`sampler_steps_select`); and all decode steps dispatched
@@ -1152,22 +1152,34 @@ class ContinuousEngine:
 
     def _prefill_form(self, bucket: int) -> str:
         """`kernel` where every layer's attention of this bucket's prefill
-        program is the flash kernel, else `xla`: the dispatcher's own rule
-        (`ops/attention.py` `kernel_refusal`) put to the shapes the program
-        was traced with. Nothing is read back from the device. Latent
-        attention has a prefill of its own (`models/mla.py`)."""
+        program is a Pallas kernel that keeps its scores on the chip, else
+        `xla`: the dispatchers' own rules put to the shapes the program
+        was traced with (`ops/attention.py` `kernel_refusal`, the flash
+        kernel; for an "eva" model that one up to a window and, past it,
+        `ops/two_source_attention.py` `two_source_refusal` at the bucket
+        padded to whole windows, as `models/eva.py` pads it). Nothing is
+        read back from the device. Latent attention has a prefill of its
+        own (`models/mla.py`)."""
         from ray_tpu.ops.attention import kernel_refusal
+        from ray_tpu.ops.two_source_attention import two_source_refusal
 
         if bucket not in self._prefill_form_of:
             mcfg = self.model.cfg
+            q = (1, bucket, mcfg.n_heads, mcfg.head_dim)
             with self._mesh_scope():
-                kernel = not mcfg.mixers and all(
-                    kernel_refusal(
-                        (1, bucket, mcfg.n_heads, mcfg.head_dim),
-                        (1, bucket, mcfg.n_kv_heads, mcfg.head_dim),
-                        window=window) is None
-                    for window in {mcfg.window_of(i)
-                                   for i in range(mcfg.n_layers)})
+                if set(mcfg.mixers) == {"eva"}:
+                    window = mcfg.eva_window
+                    kernel = (kernel_refusal(q, q) if bucket <= window
+                              else two_source_refusal(
+                                  (1, -(-bucket // window) * window, *q[2:]),
+                                  window, mcfg.eva_chunk)) is None
+                else:
+                    kernel = not mcfg.mixers and all(
+                        kernel_refusal(
+                            q, (1, bucket, mcfg.n_kv_heads, mcfg.head_dim),
+                            window=window) is None
+                        for window in {mcfg.window_of(i)
+                                       for i in range(mcfg.n_layers)})
             self._prefill_form_of[bucket] = "kernel" if kernel else "xla"
         return self._prefill_form_of[bucket]
 

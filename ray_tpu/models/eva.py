@@ -154,7 +154,7 @@ class EVA(nn.Module):
             out = dot_product_attention(q, k, v, causal=True, q_len=q_len)
         else:
             with jax.named_scope("eva_window"):
-                out = eva_sequence(q, k, v, kbar, vbar, window, chunk)[:, :s]
+                out = eva_attention(q, k, v, kbar, vbar, window, q_len)[:, :s]
         return out.astype(cfg.dtype), (k, v, kbar, vbar)
 
     def _hand_on(self, k, v, kbar, vbar, prompt_len, s: int):
@@ -221,3 +221,54 @@ class EVA(nn.Module):
         cbv.value = cbv.value.at[slot, row].set(
             vbar[:, 0].astype(cfg.dtype), mode="drop")
         return out[:, None].astype(cfg.dtype)
+
+
+# (A kernel's program names the line of this file that first traced a jitted
+# helper its body reuses, `EVA._step`'s among them: what follows was added
+# at the END so that no line above moved. ROADMAP D15.)
+import functools  # noqa: E402
+
+from ray_tpu.ops.attention import NOT_ASKED, state_once  # noqa: E402
+from ray_tpu.ops.two_source_attention import (  # noqa: E402
+    two_source_attention, two_source_refusal)
+
+
+def eva_attention(q, k, v, kbar, vbar, window: int, q_len=None):
+    """`eva_sequence`'s result (the chunk is S over the summaries' rows) by
+    the form chosen up front from what can be observed, each choice stated
+    once at INFO (`ops/two_source_attention.py` `two_source_refusal` is the
+    rule): on a TPU, with no mesh of several devices in context, heads of
+    whole lane tiles and a window that holds whole 128-row blocks of
+    summaries, ONE Pallas call that keeps its scores on the chip
+    (`two_source_attention`; rows at or past `q_len` ([B]) are read by
+    nobody, so it skips them); under differentiation the tile scan, for the
+    forward and the backward pass (the kernel has no VJP); everywhere else
+    the tile scan."""
+    chunk = q.shape[1] // kbar.shape[1]
+    reason = two_source_refusal(q.shape, window, chunk)
+    if reason is None:
+        return _kernel_or_xla_grad(q, k, v, kbar, vbar, q_len, window, chunk)
+    if reason != NOT_ASKED:
+        state_once(f"eva attention past a window: XLA tile scan ({reason})")
+    return eva_sequence(q, k, v, kbar, vbar, window, chunk)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _kernel_or_xla_grad(q, k, v, kbar, vbar, q_len, window, chunk):
+    state_once("eva attention past a window: Pallas two-source kernel")
+    return two_source_attention(q, k, v, kbar, vbar, window=window,
+                                chunk=chunk, q_len=q_len)
+
+
+def _kernel_or_xla_grad_fwd(q, k, v, kbar, vbar, q_len, window, chunk):
+    state_once("eva attention past a window: XLA tile scan under "
+               "differentiation (the two-source kernel has no VJP)")
+    return jax.vjp(functools.partial(eva_sequence, window=window,
+                                     chunk=chunk), q, k, v, kbar, vbar)
+
+
+def _kernel_or_xla_grad_bwd(window, chunk, vjp, g):
+    return (*vjp(g), None)
+
+
+_kernel_or_xla_grad.defvjp(_kernel_or_xla_grad_fwd, _kernel_or_xla_grad_bwd)

@@ -221,39 +221,70 @@ def test_the_stats_and_the_chunk_spans_name_the_samplers_path(engine, spans):
         steps + sum(n for _path, n in sort), steps]
 
 
+#: `tests/test_eva.py`'s toy: a window of 32 positions that starts over
+#: beside a summary for every chunk of 4, 128 positions a slot.
+EVA = dict(CFG, max_seq=128, dtype="float32", arch={
+    "model_type": "evabyte", "attention_class": "eva", "chunk_size": 4,
+    "window_size": 32, "num_chunks": None, "num_key_value_heads": 4,
+    "intermediate_size": 96, "hidden_act": "silu", "attention_bias": False,
+    "rope_theta": 100000, "rope_scaling": None, "rms_norm_eps": 1e-5,
+    "norm_add_unit_offset": True, "fp32_skip_add": True,
+    "fp32_logits": True, "num_pred_heads": 8, "tie_word_embeddings": False,
+    "pool_init_std": 4.0})
+
+
+@pytest.mark.parametrize("family", ["mha", "eva"])
 def test_the_stats_count_prefill_rows_and_which_attention_served_them(
-        engine, spans, monkeypatch):
+        family, spans, monkeypatch):
     """`prefill_rows` and `prefill_rows_kernel` of `cache_stats` (what
     `/v1/stats` reports) beside the attribute `attention` of the span
     `engine.prefill`: the rows of the buckets dispatched, and those whose
-    program's attention is the flash kernel by the dispatcher's own rule
+    program's attention is a Pallas kernel by the dispatcher's own rule
     for the bucket's shape. On the CPU that is none of them; where the rule
     says the kernel (asked of it here, nothing is run), a bucket dispatched
-    from then on counts under it."""
-    from ray_tpu.ops import attention
+    from then on counts under it. An "eva" model's buckets up to a window
+    are put to the flash kernel's rule, those past it to the two-source
+    kernel's, at whole windows."""
+    from ray_tpu.ops import attention, two_source_attention
 
-    keys = ("prefill_rows", "prefill_rows_kernel")
-    assert [engine.cache_stats()[k] for k in keys] == [0, 0]
-    serve(engine, 3)
-    prefills = [s for s in spans if s["n"] == "engine.prefill"]
-    assert [s["at"]["attention"] for s in prefills] == ["xla"] * 3
-    assert [engine.cache_stats()[k] for k in keys] == [
-        sum(s["at"]["bucket"] for s in prefills), 0] == [24, 0]
-    # the engine asks the rule once a bucket: a bucket it has not seen
-    asked = []
-    with monkeypatch.context() as patch:
-        patch.setattr(
-            attention, "kernel_refusal",
-            lambda q, k, **kw: asked.append((q, k, kw)))  # None: the kernel
-        assert engine._prefill_form(8) == "xla"  # remembered
-        assert engine._prefill_form(16) == "kernel"
-    assert asked == [((1, 16, 4, 16), (1, 16, 4, 16), {"window": 0})]
-    tracing._ctx.set(("e" * 32, "f" * 16))
-    engine.submit(list(range(1, 12)), SamplingParams(max_tokens=2)).tokens()
-    tracing._ctx.set(None)
-    last = [s for s in spans if s["n"] == "engine.prefill"][-1]["at"]
-    assert (last["bucket"], last["attention"]) == (16, "kernel")
-    assert [engine.cache_stats()[k] for k in keys] == [40, 16]
+    engine = ContinuousEngine(LLMConfig(**(CFG if family == "mha" else EVA)),
+                              max_batch=2, decode_chunk=4)
+    try:
+        keys = ("prefill_rows", "prefill_rows_kernel")
+        assert [engine.cache_stats()[k] for k in keys] == [0, 0]
+        serve(engine, 3)
+        prefills = [s for s in spans if s["n"] == "engine.prefill"]
+        assert [s["at"]["attention"] for s in prefills] == ["xla"] * 3
+        assert [engine.cache_stats()[k] for k in keys] == [
+            sum(s["at"]["bucket"] for s in prefills), 0] == [24, 0]
+        # the engine asks the rule once a bucket: a bucket it has not seen
+        asked = []
+        with monkeypatch.context() as patch:
+            patch.setattr(  # None: the kernel
+                attention, "kernel_refusal",
+                lambda q, k, **kw: asked.append((q, k, kw)))
+            patch.setattr(
+                two_source_attention, "two_source_refusal",
+                lambda q, window, chunk: asked.append((q, window, chunk)))
+            assert engine._prefill_form(8) == "xla"  # remembered
+            assert engine._prefill_form(16) == "kernel"
+            if family == "eva":
+                assert engine._prefill_form(64) == "kernel"
+        assert asked == [((1, 16, 4, 16), (1, 16, 4, 16), {"window": 0})] \
+            if family == "mha" else asked == [
+                ((1, 16, 4, 16), (1, 16, 4, 16), {}),
+                ((1, 64, 4, 16), 32, 4)]
+        tracing._ctx.set(("e" * 32, "f" * 16))
+        rows = 16 if family == "mha" else 64
+        engine.submit(list(range(1, rows - 4)),
+                      SamplingParams(max_tokens=2)).tokens()
+        tracing._ctx.set(None)
+        last = [s for s in spans if s["n"] == "engine.prefill"][-1]["at"]
+        assert (last["bucket"], last["attention"]) == (rows, "kernel")
+        assert [engine.cache_stats()[k] for k in keys] == [24 + rows, rows]
+    finally:
+        engine.shutdown()
+    assert not any(t.is_alive() for t in engine._threads)
 
 
 @pytest.mark.parametrize("form,name", [("xla", "mha"), ("kernel", "mha"),

@@ -883,6 +883,70 @@ def test_v5e_eva_chunk_sharded_over_tp_keeps_the_two_leaf_kernel_out(
     assert len(re.findall(r" conditional\(", text)) == 2 * EVA.n_layers
 
 
+def test_v5e_eva_prefill_past_a_window_keeps_its_scores_in_the_kernel(
+        chip, chips, monkeypatch):
+    """On the chip (the dispatchers' question about the backend answered as
+    the chip would) the 6144-row prefill program of `evabyte-pp4-8l`, the
+    bucket of the cell's check prompt of 5000 bytes, attends through
+    `two_source_attention`: ONE Mosaic call a layer for its three windows,
+    the prompt's length prefetched, no loop over tiles and no float32 tile
+    of scores `[1, 32 heads, 512 queries, keys]`, which the tile scan of
+    the same bucket (what this process, held to the CPU, is given) writes
+    to memory at 2,432 keys a query. The engine says `kernel` for every
+    bucket the mix reaches and for the flash kernel's up to a window, and
+    `xla` for all of them off the chip. Under a `tp` mesh of the four
+    chips the rule refuses and the tile scan is there."""
+    import json
+
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from ray_tpu.ops import attention
+
+    on_chip = lambda *dims: jax.ShapeDtypeStruct(  # noqa: E731
+        dims, jnp.int32, sharding=SingleDeviceSharding(chip))
+    scores = r"f32\[1,32,512,\d+\]"
+    buckets = (2048, 4096, 6144, 8192, 12288)
+    off = build_compiled(chip, cfg=EVA, max_batch=16, decode_chunk=16)
+    assert [off._prefill_form(b) for b in buckets] == ["xla"] * 5
+    before = off._prefill.lower(off.params, on_chip(1, 6144),
+                                on_chip()).compile()
+    assert "f32[1,32,512,2432]" in before.as_text()
+    assert "tpu_custom_call" not in before.as_text()
+    monkeypatch.setattr(attention, "on_tpu", lambda: True)
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmark", "configs",
+            "evabyte-pp4-8l.json")) as f:
+        config = json.load(f)
+    app = config["app_kwargs"]
+    eng = build_compiled(chip, cfg=LLMConfig(**config["llm_config"]),
+                         max_batch=app["max_batch"],
+                         decode_chunk=app["decode_chunk"])
+    assert [eng._prefill_form(b) for b in buckets] == ["kernel"] * 5
+    after = eng._prefill.lower(eng.params, on_chip(1, 6144),
+                               on_chip()).compile()
+    text = after.as_text()
+    calls = re.findall(r"%([a-z_]+)[.\d]* = \S+ custom-call\(.*?\), "
+                       r"custom_call_target=\"tpu_custom_call\"", text)
+    assert calls == ["two_source_attention"] * 8
+    assert not re.search(scores, text)
+    assert " while(" not in text
+    # the score tiles were the program's largest temporaries: eight layers
+    # without them need less than two layers with them
+    assert (after.memory_analysis().temp_size_in_bytes
+            < before.memory_analysis().temp_size_in_bytes - 100e6)
+    mesh = Mesh(np.array(chips), ("tp",))
+    sharded = build_compiled(chips[0], cfg=EVA, mesh=mesh, max_batch=16,
+                             decode_chunk=16)
+    assert [sharded._prefill_form(b) for b in buckets] == ["xla"] * 5
+    everywhere = lambda *dims: jax.ShapeDtypeStruct(  # noqa: E731
+        dims, jnp.int32, sharding=NamedSharding(mesh, P()))
+    text = sharded._prefill.lower(sharded.params, everywhere(1, 6144),
+                                  everywhere()).compile().as_text()
+    assert "tpu_custom_call" not in text and " while(" in text
+    assert re.search(r"f32\[1,8,512,2432\]", text)  # 8 heads a chip
+
+
 @pytest.mark.parametrize("form", ["xla", "kernel"])
 def test_v5e_evabyte_as_benchmarked_fits_the_chip(chip, form, monkeypatch):
     """`benchmark/configs/evabyte-pp4-8l.json` as the cell runs it, 8 layers
@@ -890,14 +954,16 @@ def test_v5e_evabyte_as_benchmarked_fits_the_chip(chip, form, monkeypatch):
     reaches (12288 rows), each beside everything else the device holds,
     within the chip's 16 GB, with the two walks (what this process, held to
     the CPU, is given) and as the chip builds it: the two-leaf kernel, one
-    Mosaic call a layer and no `conditional` from `over_kv_prefix`.
+    Mosaic call a layer and no `conditional` from `over_kv_prefix`, and in
+    the prefill one two-source call a layer for its six windows.
     Compile-only: no parameter is made."""
     import json
 
     from ray_tpu.ops import attention
 
     monkeypatch.setattr(attention, "on_tpu", lambda: form == "kernel")
-    # (the prefill keeps the form this file's other sizes were read with)
+    # (up to a window the prefill keeps the form this file's other sizes
+    # were read with; past one, the two-source rule answers with `form`)
     monkeypatch.setattr(attention, "kernel_refusal",
                         lambda *shapes, **kw: attention.NOT_ASKED)
     with open(os.path.join(os.path.dirname(os.path.dirname(
@@ -931,6 +997,8 @@ def test_v5e_evabyte_as_benchmarked_fits_the_chip(chip, form, monkeypatch):
         dims, jnp.int32, sharding=SingleDeviceSharding(chip))
     prefill = eng._prefill.lower(eng.params, on_chip(1, 12288),
                                  on_chip()).compile()
+    assert len(re.findall(r" custom-call\(.*tpu_custom_call",
+                          prefill.as_text())) == (8 if form == "kernel" else 0)
     # beside the cache, the chunk program's temporaries and a quarter of
     # the cache in parked slices (`_park_budget`)
     assert held(prefill) + held(chunk) - 3.26e9 + cache / 4 < 15e9

@@ -89,7 +89,8 @@ def main() -> int:
     cases, stats = serve(config, params, bodies)
     print(f"served in {time.monotonic() - t0:.0f} s; "
           + ", ".join(f"{k} {stats[k]}" for k in sorted(stats)
-                      if k.startswith("moe_") or k.startswith("decode_steps")),
+                      if k.startswith(("moe_", "decode_steps",
+                                       "prefill_rows"))),
           flush=True)
     jax.clear_caches()
     t0 = time.monotonic()
