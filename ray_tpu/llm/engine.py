@@ -20,7 +20,11 @@ engine workers via vllm_models.py:123-137). TPU-native design:
   tail: models/kda.py). The cache is the model's flax collection; the
   engine asks each LEAF's kind (`_leaf_kind`) and goes by it wherever the
   kinds differ: what a prefill hands on, what a parked request holds, how
-  a leaf is sharded, what the stats count.
+  a leaf is sharded, what the stats count. Under a looped stack
+  (`ut_steps` > 1: the layers run several times a token with one set of
+  weights) a layer keeps a K and V pair FOR EACH PASS, all of one kind,
+  and a step walks them one after the other: a leaf's rows are read once a
+  step, a layer's leaves `ut_steps` times.
 - **Cache layout**: the cache crosses every program boundary in the
   on-device layout the decode loop computes in. The engine asks the
   compiler for it once, and where rows as wide as their tiles make it the
@@ -369,6 +373,12 @@ def _moe_counters(mcfg) -> int:
     return held + len(_PICK_COUNTERS) * bool(held)
 
 
+def _passes(mcfg) -> int:
+    """The passes of a looped stack (`ut_steps` > 1: the layers run several
+    times a token); 0 for a stack that runs once."""
+    return mcfg.ut_steps if mcfg.ut_steps > 1 else 0
+
+
 def _counted(stats, so_far):
     """`so_far` plus what one step's expert layers sowed into `stats`: the
     rows of each held expert, then the four of `zero_counts`. `so_far` as
@@ -400,13 +410,13 @@ def _rows_from_columns(columns: np.ndarray, held: int) -> np.ndarray:
     return columns.T.reshape(-1)[:held]
 
 
-def _count_metric(name: str, n: int) -> None:
+def _count_metric(name: str, n, tags: Optional[dict] = None) -> None:
     """`ray_tpu.util.metrics.<name>` up by n; never the caller's problem."""
-    if n:
+    if n > 0:
         try:
             from ray_tpu.util import metrics as _metrics
 
-            getattr(_metrics, name).inc(n)
+            getattr(_metrics, name).inc(n, tags)
         except Exception:
             pass
 
@@ -582,6 +592,14 @@ class ContinuousEngine:
         self._eva_cols = -(-len(_EVA_COUNTERS) // self.max_batch) * self._eva
         for name in _PICK_COUNTERS + _EVA_COUNTERS:
             setattr(self, f"{name}_total", 0)
+        # A looped stack: the passes a token runs (0: the stack runs once,
+        # no loop), the columns behind those (a chunk's exit mass by pass,
+        # float32 carried as its bits), and since start the passes run and
+        # the exit mass by pass.
+        self._passes = _passes(self.model.cfg)
+        self._loop_cols = -(-self._passes // self.max_batch)
+        self.loop_passes_total = 0
+        self.exit_mass_total = [0.0] * self._passes
         # The decode steps dispatched since start, those whose attention
         # over K and V, latent rows or an "eva" layer's two leaves is a
         # ragged kernel (`_decode_blocks`),
@@ -614,6 +632,9 @@ class ContinuousEngine:
             held = _moe_counters(model.cfg)  # counters a step carries on
             eva = (model.cfg.eva_window, model.cfg.eva_chunk) \
                 if "eva" in model.cfg.mixers else None
+            passes = _passes(model.cfg)
+            mutable = ["cache"] + ["stats"] * bool(held) + ["loop"] * bool(
+                passes)
 
             def chunk(params, cache, toks, lengths, keys, temp, top_k, top_p,
                       n: int, greedy: bool, kv_bound=None, live=None):
@@ -639,7 +660,10 @@ class ContinuousEngine:
                 host in the read that brings the tokens; a model with
                 "eva" layers, behind those, `_EVA_COUNTERS`' two: the live
                 slots' steps that ended a chunk (each eva layer wrote a
-                summary) and those that began a window after the first."""
+                summary) and those that began a window after the first; a
+                looped stack, behind those, the sum over the live slots'
+                steps of each pass's exit probability (`exit_p`: float32,
+                carried as its bits)."""
                 def step(carry, _):
                     cache, tok, lens, keys, *rows = carry
                     # (the mesh in context, as the prefill's: what
@@ -648,12 +672,15 @@ class ContinuousEngine:
                         logits, vars_out = model.apply(
                             {"params": params, "cache": cache}, tok[:, None],
                             positions=lens[:, None], decode=True,
-                            kv_bound=kv_bound, live=live,
-                            mutable=(["cache", "stats"] if held
-                                     else ["cache"]))
+                            kv_bound=kv_bound, live=live, mutable=mutable)
                     if held:
-                        rows = [_counted(vars_out.get("stats", {}),
-                                         rows[0])]
+                        rows[0] = _counted(vars_out.get("stats", {}),
+                                           rows[0])
+                    if passes:
+                        left = vars_out["loop"]["exit_p"][:, 0]  # [B, passes]
+                        if live is not None:
+                            left = jnp.where(live[:, None], left, 0.0)
+                        rows[-1] = rows[-1] + left.sum(0)
                     if greedy:
                         nxt = jnp.argmax(
                             logits[:, -1], axis=-1).astype(jnp.int32)
@@ -665,10 +692,15 @@ class ContinuousEngine:
                     return (vars_out["cache"], nxt, lens + 1, keys, *rows), nxt
 
                 rows0 = [jnp.zeros((held,), jnp.int32)] if held else []
+                if passes:
+                    rows0.append(jnp.zeros((passes,), jnp.float32))
                 (cache, _tok, lens, keys, *rows), out = jax.lax.scan(
                     step, (cache, toks, lengths, keys, *rows0), None,
                     length=n)
                 block = jnp.moveaxis(out, 0, 1)
+                if passes:
+                    rows[-1] = jax.lax.bitcast_convert_type(rows[-1],
+                                                            jnp.int32)
                 if eva:
                     # the positions the live slots stepped, [B, n]
                     window, piece = eva
@@ -713,6 +745,8 @@ class ContinuousEngine:
             self._cache_kinds[kind] = {
                 "layers": len({i for i, _leaf in found}),
                 "leaves": len(leaves),
+                # (a looped stack: a layer's K and V pair, once a pass)
+                **({"passes": self._passes} if self._passes else {}),
                 **({"bytes_per_slot": nbytes // self.max_batch}
                    if kind == "state" else {"rows": leaves[0].shape[1]}),
                 "bytes": nbytes}
@@ -975,7 +1009,13 @@ class ContinuousEngine:
         (`sampler_steps_select`); and all decode steps dispatched
         (`decode_steps`) beside those whose attention is the ragged kernel
         (`decode_steps_kernel`), for which the walked share is the live
-        slots' own rows rounded up to the kernel's row block."""
+        slots' own rows rounded up to the kernel's row block. Under a
+        looped stack `cache_kinds.full` says `passes` (its `leaves` are 2 x
+        passes x layers; the two shares stay ONE leaf's rows a step, which
+        a reader multiplies by `ut_steps`), and at the top come `ut_steps`,
+        `loop_passes_total` (the live slots' decode steps x the passes each
+        ran) and `exit_mass_total` (by pass, the sum over those steps of
+        the probability of leaving the loop there)."""
         mcfg = self.model.cfg
         steps = max(1, self.decode_steps)
         kinds = {kind: k if kind == "state" else {
@@ -1009,6 +1049,11 @@ class ContinuousEngine:
         if self._eva:
             out.update({f"{name}_total": getattr(self, f"{name}_total")
                         for name in _EVA_COUNTERS})
+        if self._passes:
+            out.update(ut_steps=self._passes,
+                       loop_passes_total=self.loop_passes_total,
+                       exit_mass_total=[round(m, 3)
+                                        for m in self.exit_mass_total])
         if self._moe_held:
             out.update(self._moe_stats())
         return out
@@ -1041,6 +1086,25 @@ class ContinuousEngine:
             setattr(self, f"{name}_total", getattr(self, f"{name}_total") + n)
             _count_metric(f"LLM_{name.upper()}", n)
         return got
+
+    def _count_loop(self, block: np.ndarray, at: int, slot_steps: int
+                    ) -> dict:
+        """A looped stack's counts of the chunk just read: its exit mass by
+        pass (columns `at` on of its block, float32 as its bits) and the
+        passes its `slot_steps` live slots' steps ran (every pass, while
+        nothing leaves the loop early): added to the totals, and returned
+        as the attributes `engine.host_sync` carries."""
+        mass = _rows_from_columns(
+            block[:, at:at + self._loop_cols], self._passes
+        ).astype(np.int32).view(np.float32).tolist()
+        passes = slot_steps * self._passes
+        self.loop_passes_total += passes
+        _count_metric("LLM_LOOP_PASSES", passes)
+        for t, m in enumerate(mass):
+            self.exit_mass_total[t] += m
+            _count_metric("LLM_EXIT_MASS", m, {"pass": str(t + 1)})
+        return {"exit_mass": [round(m, 4) for m in mass],
+                "loop_passes": passes}
 
     def _init_cache(self):
         """Zero cache for the full batch."""
@@ -1328,6 +1392,8 @@ class ContinuousEngine:
             mcfg = self.model.cfg
             attrs.update(scan_chunks=-(-lb // mcfg.kda_chunk), mixers=",".join(
                 f"{m}:{mcfg.mixers.count(m)}" for m in sorted(set(mcfg.mixers))))
+        if self._passes:
+            attrs["ut_steps"] = self._passes  # slices handed on a layer
         if self._eva:
             # what the prefill handed on: the windows it ran, and the
             # summaries of the whole chunks before the prompt's end
@@ -1733,6 +1799,10 @@ class ContinuousEngine:
                          "kv_rows": next(iter(rows.values()))[0]}
                 if self._state_rw_bytes:
                     attrs["state_rw_bytes"] = self._state_rw_bytes
+                if self._passes:
+                    # each of a layer's leaves is walked once a step: what
+                    # the rows below say of ONE leaf holds `ut_steps` times
+                    attrs["ut_steps"] = self._passes
                 if seq is not None:
                     # in_flight 0: the device had no chunk of ours queued
                     attrs.update(ahead, seq=seq,
@@ -1829,6 +1899,10 @@ class ContinuousEngine:
                if self._moe_cols and block is not None else {})
         if self._eva_cols and block is not None:
             moe.update(self._count_eva(block, n + self._moe_cols))
+        if self._loop_cols and block is not None:
+            moe.update(self._count_loop(
+                block, n + self._moe_cols + self._eva_cols,
+                n * len(occupants)))
         if sync_ctx is not None:
             t_end = t_end or time.time()
             _tracing.record_span_in(

@@ -105,6 +105,15 @@ def make_stage_net(mcfg, layers: tuple, first: bool, last: bool):
 
     from ray_tpu.models.transformer import Block, RMSNorm, output_head
 
+    if mcfg.ut_steps > 1:
+        raise NotImplementedError(
+            f"a stage applies its layers once a token: a looped stack "
+            f"(`ut_steps` {mcfg.ut_steps}) gets the LAST stage's output "
+            f"back as the first stage's input {mcfg.ut_steps - 1} times a "
+            f"token, each pass with cache leaves of its own, and a net "
+            f"that ran one pass would be another model; it is served by "
+            f"ContinuousEngine only")
+
     if any(mcfg.mixer_of(i) == "eva" for i in layers):
         raise NotImplementedError(
             "pipeline stages keep one kind of cache leaf, max_seq rows a "

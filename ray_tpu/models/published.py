@@ -249,6 +249,40 @@ def _evabyte(cfg, arch: dict) -> dict:
         pred_heads=int(arch.get("num_pred_heads", 1)))
 
 
+def _ouro(cfg, arch: dict) -> dict:
+    """The fields of a `model_type: ouro` decoder (ByteDance's Ouro, a looped
+    language model) beyond the six sizes: the WHOLE stack applied
+    `total_ut_steps` times a token with one set of weights, the final norm
+    inside that loop, an exit gate after it (`models/transformer.py`
+    `ExitGate`); full attention in every layer at a published head size, no
+    bias; four norms a layer (each sublayer's output is normed before it
+    joins the stream); SwiGLU; an untied head (ISSUE 53). Leaving the loop
+    early (`early_exit_threshold` under 1: slots of one batch that run
+    different numbers of passes) is not built and refused. `max_window_layers`
+    bears on nothing while no layer has a window and is not read."""
+    _refuse_unbuilt(arch, {
+        "early_exit_threshold": 1.0, "use_sliding_window": False,
+        "sliding_window": None, "rope_scaling": None, "hidden_act": "silu",
+        "attention_bias": False})
+    steps = int(arch["total_ut_steps"])
+    if steps < 1:
+        raise ValueError(f"not built: total_ut_steps {steps} (at least 1)")
+    kinds = list(arch["layer_types"])[:cfg.n_layers]
+    if len(kinds) < cfg.n_layers or set(kinds) - {"full_attention"}:
+        raise ValueError(f"not built: layer_types must name {cfg.n_layers} "
+                         f"layers as full_attention: {kinds}")
+    kv_heads = int(arch["num_key_value_heads"])
+    if kv_heads < 1 or cfg.n_heads % kv_heads:
+        raise ValueError(f"not built: {cfg.n_heads} heads do not share "
+                         f"{kv_heads} key/value heads evenly")
+    return dict(
+        n_kv_heads=kv_heads, head_size=int(arch["head_dim"]),
+        d_ff=int(arch["intermediate_size"]),
+        rope_theta=float(arch["rope_theta"]),
+        tie_embeddings=bool(arch["tie_word_embeddings"]),
+        sandwich_norm=True, ut_steps=steps)
+
+
 _ARMS = {"evabyte": _evabyte, "kimi_k2": _deepseek_v3, "deepseek_v3": _deepseek_v3,
          "afmoe": _afmoe, "kimi_linear": _kimi_linear,
-         "longcat_flash": _longcat_flash}
+         "longcat_flash": _longcat_flash, "ouro": _ouro}

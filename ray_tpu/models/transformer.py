@@ -35,9 +35,9 @@ from ray_tpu.ops import dot_product_attention
 from ray_tpu.ops.attention import prefill_attention
 from ray_tpu.parallel.mesh import context_mesh_shape, spec_tree_like
 
-__all__ = ["Attention", "Block", "EVA", "KDA", "MLA", "MoE", "RMSNorm", "SwiGLU",
-           "Transformer", "TransformerConfig", "YarnScaling", "loss_fn",
-           "param_specs", "prefill_attention"]
+__all__ = ["Attention", "Block", "EVA", "ExitGate", "KDA", "MLA", "MoE",
+           "RMSNorm", "SwiGLU", "Transformer", "TransformerConfig",
+           "YarnScaling", "loss_fn", "param_specs", "prefill_attention"]
 
 
 @dataclass(frozen=True)
@@ -144,12 +144,12 @@ class TransformerConfig:
     #: Rows of one expert in a tile of the expert layer's grouped path; the
     #: serving prefill takes that path above two tiles' worth of rows.
     moe_group_tile: int = 128
-    #: Width of a row of a cache leaf: the leaf's own when 0 (head_dim for K
-    #: and V, kv_lora_rank + qk_rope_head_dim for a latent), else that and then
-    #: zeros that are never read. The serving engine sets it to the width the
-    #: device's compiler lays such a row out in (llm/engine.py
-    #: `_probe_cache_row`): the cache's default layout is then the loop's own.
+    #: Width of a cache leaf's row: the leaf's own when 0 (head_dim for K, V;
+    #: kv_lora_rank + qk_rope_head_dim for a latent), else that and then unread
+    #: zeros: the width the device's compiler lays a row out in, so that the
+    #: cache's default layout is the loop's own (engine `_probe_cache_row`).
     cache_row: int = 0
+    ut_steps: int = 1  #: passes of the WHOLE stack a token (`looped_stack`)
 
     @property
     def head_dim(self) -> int:
@@ -200,7 +200,7 @@ class Attention(nn.Module):
 
     @nn.compact
     def __call__(self, x, positions, decode: bool = False, kv_bound=None,
-                 prompt_len=None, live=None):
+                 prompt_len=None, live=None, ut_step: int = 0):
         cfg = self.cfg
         hd = cfg.head_dim
         dense = lambda feats, name: nn.DenseGeneral(  # noqa: E731
@@ -222,7 +222,7 @@ class Attention(nn.Module):
                              else "full_attention"):
             if decode:
                 out = self._cached_attention(q, k, v, positions, kv_bound,
-                                             prompt_len, live)
+                                             prompt_len, live, ut_step)
             else:
                 out = dot_product_attention(q, k, v, causal=True,
                                             window=self.window)
@@ -233,7 +233,7 @@ class Attention(nn.Module):
                                dtype=cfg.dtype, param_dtype=cfg.param_dtype)(out)
 
     def _cached_attention(self, q, k, v, positions, kv_bound=None,
-                          prompt_len=None, live=None):
+                          prompt_len=None, live=None, ut_step: int = 0):
         """Autoregressive KV-cache attention with PER-SEQUENCE positions
         (reference role: vLLM's paged KV cache; here slot-per-sequence):
         new k/v rows go into fixed per-slot buffers at each sequence's own
@@ -276,8 +276,8 @@ class Attention(nn.Module):
         row = max(cfg.cache_row, d)
         rows = self.window or cfg.max_seq
         shape = (b, rows, cfg.n_kv_heads, row)
-        ck = self.variable("cache", "k", lambda: jnp.zeros(shape, cfg.dtype))
-        cv = self.variable("cache", "v", lambda: jnp.zeros(shape, cfg.dtype))
+        ck = self.variable("cache", *pass_leaf("k", ut_step, shape, cfg.dtype))
+        cv = self.variable("cache", *pass_leaf("v", ut_step, shape, cfg.dtype))
         pos = positions.astype(jnp.int32)
         bidx = jnp.arange(b)[:, None]
         k, v = k.astype(cfg.dtype), v.astype(cfg.dtype)
@@ -325,7 +325,7 @@ class Block(nn.Module):
 
     @nn.compact
     def __call__(self, x, positions, decode: bool = False, kv_bound=None,
-                 prompt_len=None, live=None):
+                 prompt_len=None, live=None, ut_step: int = 0):
         cfg = self.cfg
         norm = lambda name: RMSNorm(  # noqa: E731
             cfg.norm_eps, unit_offset=cfg.norm_unit_offset, name=name)
@@ -347,8 +347,8 @@ class Block(nn.Module):
                 live=live)
         else:
             a = Attention(cfg, window=self.window, name="attn")(
-                norm("attn_norm")(x), positions, decode=decode,
-                kv_bound=kv_bound, prompt_len=prompt_len, live=live)
+                norm("attn_norm")(x), positions, decode=decode, live=live,
+                kv_bound=kv_bound, prompt_len=prompt_len, ut_step=ut_step)
         x = x + (norm("post_attn_norm")(a) if cfg.sandwich_norm else a)
         h = norm("mlp_norm")(x)
         f = (MoE(cfg, name="moe")(h, serving=decode) if self.moe
@@ -381,16 +381,16 @@ class Transformer(nn.Module):
     @nn.compact
     def __call__(self, tokens, positions=None, decode: bool = False,
                  kv_bound=None, prompt_len=None, live=None):
-        """tokens: [B, S] int32 -> logits [B, S, vocab] (f32).
-
-        decode=True uses per-layer caches (flax "cache" collection): pass
-        `positions` (absolute) and apply with mutable=["cache"]. A
-        single-token decode step may also be told `kv_bound`, how many
-        cache rows its longest sequence of interest has, and `live` [B]
-        bool, which rows of the batch have an occupant (a free row's cache
-        need not be read), and a prefill padded to a bucket `prompt_len`
-        [B], where its prompts end (`Attention._cached_attention`,
-        `models/kda.py`)."""
+        """tokens: [B, S] int32 -> logits [B, S, vocab] (f32). decode=True
+        uses per-layer caches (flax "cache" collection): pass `positions`
+        (absolute) and apply with mutable=["cache"]. A single-token decode
+        step may also be told `kv_bound`, how many cache rows its longest
+        sequence of interest has, and `live` [B] bool, which rows of the batch
+        have an occupant (a free row's cache need not be read), and a padded
+        prefill `prompt_len` [B], where prompts end (`_cached_attention`)."""
+        if self.cfg.ut_steps > 1:  # a path of its own, at this file's end
+            return looped_stack(self, tokens, positions, decode, kv_bound,
+                                prompt_len, live)
         cfg = self.cfg
         emb = self.param("tok_emb", nn.initializers.normal(0.02),
                          (cfg.vocab_size, cfg.d_model), cfg.param_dtype)
@@ -483,3 +483,96 @@ def loss_fn(model: Transformer, params, tokens):
     logp = jax.nn.log_softmax(logits, axis=-1)
     nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
     return nll.mean()
+
+
+# ---------------------------------------------------------------------------
+# A looped stack (`ut_steps` > 1; a looped language model's `total_ut_steps`):
+# the WHOLE stack of layers applied several times to every token with ONE set
+# of weights. Everything of it stands here, at the file's end: a kernel's
+# program names the line that first traced a helper its body reuses (the
+# ring's `pos % rows` above: `tools/lowered.py` shows it), so no line above
+# may move.
+
+def pass_leaf(name: str, ut_step: int, shape, dtype) -> tuple:
+    """(the name, the initialiser) of pass `ut_step`'s cache leaf `name` in an
+    "mha" layer. A looped stack applies `Attention` once a pass with the SAME
+    weights to ANOTHER hidden state, so each pass keeps a K and V pair of its
+    own: `k`, `v` for pass 0 (the names every other model has: its cache tree
+    and its programs are what they were) and `k_<t>`, `v_<t>` for pass t,
+    each a leaf of its own that the step's walk is handed where it lies (one
+    leaf with a leading pass axis would have to be sliced a pass, which the
+    v5e's compiler answers with a copy). Pass t never sees another pass's
+    rows. The leaf's kind is its layer's whatever its pass
+    (`TransformerConfig.cache_kind_of`)."""
+    return (f"{name}_{ut_step}" if ut_step else name,
+            lambda: jnp.zeros(shape, dtype))
+
+
+class ExitGate(nn.Module):
+    """A looped stack's exit gate: `sigmoid(h . w + b)` of a pass's normed
+    output, the probability of leaving the loop there, [B, S] float32. One
+    linear unit with a bias, the same after every pass."""
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, h):
+        cfg = self.cfg
+        w = self.param("kernel", nn.initializers.normal(0.02),
+                       (cfg.d_model,), cfg.param_dtype)
+        b = self.param("bias", nn.initializers.zeros, (), cfg.param_dtype)
+        logit = jnp.einsum("bsd,d->bs", h.astype(cfg.dtype),
+                           w.astype(cfg.dtype),
+                           preferred_element_type=jnp.float32)
+        return jax.nn.sigmoid(logit + b.astype(jnp.float32))
+
+
+def looped_stack(module: Transformer, tokens, positions, decode, kv_bound,
+                 prompt_len, live):
+    """`Transformer.__call__` for `cfg.ut_steps` > 1: `layer_i`, `final_norm`
+    and `exit_gate` are made ONCE (the tree of an 8-layer model plus the
+    gate) and applied `ut_steps` times: `final_norm` closes each pass, its
+    output is that pass's state AND the next pass's input, the gate reads it,
+    and the head reads the last pass's alone. The exit distribution `[B, S,
+    ut_steps]` (p_t = lam_t prod_{s<t} (1 - lam_s), the last pass taking what
+    is left) is sown into the collection `loop` for whoever makes it mutable
+    (the engine's chunk program, the tests); it decides nothing here: every
+    token runs every pass (a threshold under 1 is refused where the
+    configuration is read, `models/published.py`).
+
+    The passes are WRITTEN OUT, in every program, not rolled into a loop
+    inside the step: whoever reads a device trace by the most often started
+    operation (`benchmark/trace_reduce.py` `loop_steps`) would count four
+    steps for one (PERF.md section 7 (l))."""
+    cfg = module.cfg
+    if cfg.mixers or cfg.moe_shortcut:
+        raise NotImplementedError(
+            "a looped stack (`ut_steps` > 1) keeps a pair of cache leaves a "
+            "pass in `Attention` only: no other mixer is applied twice")
+    emb = module.param("tok_emb", nn.initializers.normal(0.02),
+                       (cfg.vocab_size, cfg.d_model), cfg.param_dtype)
+    x = emb[tokens].astype(jnp.float32 if cfg.residual_f32 else cfg.dtype)
+    if cfg.emb_scale != 1.0:
+        x = x * jnp.asarray(cfg.emb_scale, cfg.dtype)
+    if positions is None:
+        positions = jnp.broadcast_to(jnp.arange(tokens.shape[1]),
+                                     tokens.shape)
+    blocks = [Block(cfg, moe=cfg.is_moe_layer(i), window=cfg.window_of(i),
+                    name=f"layer_{i}") for i in range(cfg.n_layers)]
+    final_norm = RMSNorm(cfg.norm_eps, unit_offset=cfg.norm_unit_offset,
+                         name="final_norm")
+    gate = ExitGate(cfg, name="exit_gate")
+    stay, exits = 1.0, []  # the share of a token still in the loop, [B, S]
+    for t in range(cfg.ut_steps):
+        for block in blocks:
+            if not decode:
+                x = _seq_shard(x)
+            x = block(x, positions, decode=decode, kv_bound=kv_bound,
+                      prompt_len=prompt_len, live=live, ut_step=t)
+        x = final_norm(x)
+        with jax.named_scope("exit_distribution"):
+            lam = gate(x)
+            exits.append(stay if t == cfg.ut_steps - 1 else stay * lam)
+            stay = stay * (1.0 - lam)
+    module.sow("loop", "exit_p", jnp.stack(exits, -1),
+               reduce_fn=lambda _old, new: new, init_fn=lambda: None)
+    return output_head(module, cfg, x, emb)

@@ -507,6 +507,21 @@ LLM_EVA_RESTARTS = Counter(
     description="decode steps of live slots at which an eva layer's window "
                 "started over")
 
+#: A looped stack's decode steps (`ut_steps` > 1: the layers run several
+#: times a token), read with a chunk's tokens (llm/engine.py `_count_loop`):
+#: the passes the live slots' steps ran, and by pass the sum over those steps
+#: of the probability of leaving the loop there (the exit gate's; it decides
+#: nothing while the published threshold is 1).
+LLM_LOOP_PASSES = Counter(
+    "rt_llm_loop_passes_total",
+    description="passes over the stack run by live slots' decode steps of "
+                "a looped model")
+LLM_EXIT_MASS = Counter(
+    "rt_llm_exit_mass_total",
+    description="sum over live slots' decode steps of the exit gate's "
+                "probability of leaving the loop after a pass",
+    tag_keys=("pass",))
+
 #: Pipeline-parallel serving (README "Pipeline-parallel serving"), drained
 #: each flush tick in processes hosting a PipelineStage: occupancy is the
 #: stage's busy fraction of the tick window, bubble its complement. A

@@ -296,7 +296,10 @@ def test_the_four_older_arms_build_their_configs_as_before(name):
         "first_expert", "moe_group_tile", "cache_row",
         # PR 49's, for the `evabyte` arm alone (tests/test_eva.py)
         "eva_window", "eva_chunk", "eva_pool_std", "norm_unit_offset",
-        "residual_f32", "pred_heads"}
+        "residual_f32", "pred_heads",
+        # PR 53's, for the `ouro` arm alone (tests/test_ouro.py)
+        "ut_steps"}
+    assert cfg.ut_steps == 1
     assert (cfg.eva_window, cfg.eva_chunk, cfg.norm_unit_offset,
             cfg.residual_f32, cfg.pred_heads) == (0, 0, False, False, 1)
 

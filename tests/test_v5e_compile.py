@@ -9,6 +9,7 @@ file loads the library (README "Serving hot loop"; the `on-chip-measurement`
 guide, section 2).
 """
 
+import hashlib
 import os
 import re
 import sys
@@ -1002,3 +1003,156 @@ def test_v5e_evabyte_as_benchmarked_fits_the_chip(chip, form, monkeypatch):
     # beside the cache, the chunk program's temporaries and a quarter of
     # the cache in parked slices (`_park_budget`)
     assert held(prefill) + held(chunk) - 3.26e9 + cache / 4 < 15e9
+
+
+# ---------------------------------------------------------------------------
+# A looped stack (`model_type` `ouro`): the layers run several times a token.
+
+def test_v5e_ouro_as_benchmarked_walks_32_leaf_pairs_where_they_lie(
+        chip, monkeypatch):
+    """`benchmark/configs/ouro-2.6b-8l.json` as the cell runs it, 8 layers
+    run 4 times a token and 16 slots, as the chip builds it: the 16-step
+    chunk program's step is 32 layer bodies WRITTEN OUT, each with one
+    Mosaic call (the `mha` family's ragged kernel) handed its pass's own K
+    and V where they lie: no `copy`, `slice`, `transpose` or
+    `bitcast-convert` whose result has a leaf's dimensions or any prefix of
+    its rows, no leaf staged ahead of a call, and NO loop inside the step
+    (the chunk's own `while` is the only one: `trace_reduce.loop_steps`
+    counts the most often started operation name inside a `jit_chunk`
+    execution as its steps, so a loop over the passes would make every
+    step count four times). The programs fit the chip beside a quarter of
+    the cache in parked slices. Compile-only: no parameter is made."""
+    import json
+
+    from ray_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "on_tpu", lambda: True)
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmark", "configs",
+            "ouro-2.6b-8l.json")) as f:
+        config = json.load(f)
+    app = config["app_kwargs"]
+    eng = build_compiled(chip, cfg=LLMConfig(**config["llm_config"]),
+                         max_batch=app["max_batch"],
+                         decode_chunk=app["decode_chunk"])
+    assert eng.cache_boundary_copies == 0
+    assert (eng._kernel_blocks, eng._decode_form) == ({"full": 256}, "kernel")
+    full = eng.cache_stats()["cache_kinds"]["full"]
+    assert (full["layers"], full["leaves"], full["passes"], full["rows"]) == (
+        8, 64, 4, 2048)
+    assert round(full["bytes"] / 1e9, 2) == 8.59
+    assert sorted(eng.params) == sorted(
+        ["tok_emb", "lm_head", "final_norm", "exit_gate"]
+        + [f"layer_{i}" for i in range(8)])  # ONE set of weights
+
+    def held(compiled):
+        m = compiled.memory_analysis()
+        return (m.argument_size_in_bytes + m.output_size_in_bytes
+                - m.alias_size_in_bytes + m.temp_size_in_bytes)
+
+    chunk = eng._chunk.lower(*eng._chunk_shapes(
+        eng.params, eng._cache_spec, False)).compile()
+    assert 9.8e9 < held(chunk) < 10.6e9  # weights 1.22 + cache 8.59 + temps
+    text = chunk.as_text()
+    calls = re.findall(r" custom-call\((.*?)\), custom_call_target="
+                       r"\"tpu_custom_call\"", text)
+    assert len(calls) == 4 * 8
+    for line in calls:  # (the grid's extent, stop, slot, last, q, K, V)
+        *_, k, v = line.split(", ")
+        assert "copy" not in k and "copy" not in v, line
+    assert len(re.findall(r" while\(", text)) == 1
+    # (the sampled program: the sampler has branches of its own)
+    assert not re.findall(r" conditional\(.*decode_attention", text)
+    assert not moved_rows(eng, text, "copy|slice|transpose|bitcast-convert"
+                                     "|copy-start|copy-done")
+    on_chip = lambda *dims: jax.ShapeDtypeStruct(  # noqa: E731
+        dims, jnp.int32, sharding=SingleDeviceSharding(chip))
+    prefill = eng._prefill.lower(eng.params, on_chip(1, 512),
+                                 on_chip()).compile()
+    assert eng._prefill_form(512) == "kernel"
+    assert len(re.findall(r" custom-call\(.*tpu_custom_call",
+                          prefill.as_text())) == 4 * 8
+    assert " while(" not in prefill.as_text()
+    # a prefill of 512 rows hands on 512 rows of all 64 leaves
+    assert eng._slice_bytes(512) == 64 * 512 * 16 * 128 * 2
+    assert eng._park_budget == full["bytes"] // 4
+    assert held(prefill) + held(chunk) - 1.225e9 + full["bytes"] / 4 < 15e9
+
+
+#: sha256 (first 16 hex digits) of the lowered texts of the six older
+#: families' bounded decode step and padded prefill (this file's small
+#: configurations, the XLA forms this process, held to the CPU, is given:
+#: a kernel's payload names its file by its path) as the PARENT commit of
+#: the PR that brought the looped stack lowered them, taken there with
+#: `model_texts`. With `ut_steps` 1 every older model is the program it was.
+#: Whoever changes a model's step on purpose takes the digests again.
+PARENT_MODEL_TEXTS = {
+    "CFG": ("2b6caf2debfc03df", "944a55043376bd38"),
+    "LATENT": ("330a15cb907e0e4a", "76b1f3e186072919"),
+    "SWA": ("c0658ac94bf0ecc3", "cdf881d54c379bf4"),
+    "KDA": ("792b1f6eab233891", "4131f226d58542b2"),
+    "SCMOE": ("82fd1a9d78f8c0ea", "2ac08893b209dc96"),
+    "EVA": ("00f120a2aa60f557", "ff757b158ab71e49")}
+
+
+def model_texts(chip, cfg, slots=4, bucket=256):
+    """The lowered texts of a model's bounded decode step and of its padded
+    prefill for `chip`, from shapes."""
+    from ray_tpu.models.published import model_config
+    from ray_tpu.models.transformer import Transformer
+
+    model = Transformer(model_config(cfg))
+    on_chip = lambda dtype, *dims: jax.ShapeDtypeStruct(  # noqa: E731
+        dims, dtype, sharding=SingleDeviceSharding(chip))
+    placed = lambda tree: jax.tree.map(  # noqa: E731
+        lambda leaf: on_chip(leaf.dtype, *leaf.shape), tree)
+    params = placed(jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]))
+    toks = jnp.zeros((slots, 1), jnp.int32)
+    cache = placed(jax.eval_shape(
+        lambda p: model.apply({"params": p}, toks, positions=toks,
+                              decode=True, mutable=["cache"])[1]["cache"],
+        params))
+
+    def step(p, c, t, pos, kb, live):
+        return model.apply({"params": p, "cache": c}, t, positions=pos,
+                           decode=True, kv_bound=kb, live=live,
+                           mutable=["cache"])
+
+    def prefill(p, t, plen):
+        return model.apply({"params": p}, t,
+                           positions=jnp.arange(bucket)[None], decode=True,
+                           prompt_len=jnp.reshape(plen, (1,)),
+                           mutable=["cache"])
+
+    i32 = jnp.int32
+    return (jax.jit(step).lower(
+        params, cache, on_chip(i32, slots, 1), on_chip(i32, slots, 1),
+        on_chip(i32), on_chip(jnp.bool_, slots)).as_text(),
+        jax.jit(prefill).lower(params, on_chip(i32, 1, bucket),
+                               on_chip(i32)).as_text())
+
+
+@pytest.mark.parametrize("name", list(PARENT_MODEL_TEXTS))
+def test_v5e_older_families_lower_to_the_text_they_had_before_the_loop(
+        chip, name):
+    texts = model_texts(chip, globals()[name])
+    assert "tpu_custom_call" not in "".join(texts)
+    assert tuple(hashlib.sha256(text.encode()).hexdigest()[:16]
+                 for text in texts) == PARENT_MODEL_TEXTS[name]
+
+
+def test_the_line_a_ring_layers_kernel_programs_name_has_not_moved():
+    """A kernel's payload names the line that FIRST traced a jitted helper
+    its body reuses: every chunk and kernel-prefill program of a model with
+    window layers carries `models/transformer.py:304`, the ring's `pos %
+    rows` (read from the payload's bytes at PR 53, whose first form moved it
+    by 17 lines and so changed 18 of Trinity-Mini's program texts;
+    `tools/lowered.py` on both trees shows it, a digest here cannot: the
+    payload also names the checkout's path). New code goes to that file's
+    END."""
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "ray_tpu", "models",
+            "transformer.py")) as f:
+        lines = f.read().split("\n")
+    assert lines[303].strip() == "at = pos % rows if self.window else pos"
