@@ -326,10 +326,12 @@ class ChipProbe:
                                             window=window, q_len=q_len),
                     lambda: prefill_attention(q, k, v, window), plen)
 
-        # Decode: Phi-3's leaf, Trinity-Mini's full leaf and its ring.
+        # Decode: Phi-3's leaf, Trinity-Mini's full leaf and its ring,
+        # Ouro's leaf.
         for b, rows_, h, kv, d in [(8, 2048, 32, 32, 96),
                                    (16, 8192, 32, 4, 128),
-                                   (16, 2048, 32, 4, 128)]:
+                                   (16, 2048, 32, 4, 128),
+                                   (16, 2048, 16, 16, 128)]:
             ks = jax.random.split(jax.random.PRNGKey(rows_ + kv), 4)
             q = jax.random.normal(ks[0], (b, h, d), jnp.bfloat16)
             wide = ((0, 0),) * 3 + ((0, 128 - d),)

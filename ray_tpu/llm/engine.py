@@ -757,7 +757,11 @@ class ContinuousEngine:
         # row block by kind of leaf ({} where the XLA walk does), and what
         # a chunk's span calls that: `kernel`, `xla`, or `mixed` where the
         # dispatcher takes one kind of leaf and refuses the other.
-        self._kernel_blocks, self._decode_form = self._decode_blocks()
+        # `_kernel_pieces`: the rows a live slot's fetch is rounded up to,
+        # by kind: the block, or under the `mha` family's kernel the piece
+        # of its last block.
+        (self._kernel_blocks, self._kernel_pieces,
+         self._decode_form) = self._decode_blocks()
         # A request parked in `_ready` holds its prefill's cache slices on
         # the device. The lane runs ahead of the scheduler only while what
         # is parked stays under a quarter of the cache's own bytes.
@@ -1008,8 +1012,10 @@ class ContinuousEngine:
         beside those in which the sampler selected and sorted nothing
         (`sampler_steps_select`); and all decode steps dispatched
         (`decode_steps`) beside those whose attention is the ragged kernel
-        (`decode_steps_kernel`), for which the walked share is the live
-        slots' own rows rounded up to the kernel's row block. Under a
+        (`decode_steps_kernel`), for which the walked share is what the
+        kernel fetches: the live slots' own rows rounded up to its row
+        block, or under the `mha` family's to the piece of a slot's last
+        block (`_kernel_pieces`). Under a
         looped stack `cache_kinds.full` says `passes` (its `leaves` are 2 x
         passes x layers; the two shares stay ONE leaf's rows a step, which
         a reader multiplies by `ut_steps`), and at the top come `ut_steps`,
@@ -1247,11 +1253,12 @@ class ContinuousEngine:
             self._prefill_form_of[bucket] = "kernel" if kernel else "xla"
         return self._prefill_form_of[bucket]
 
-    def _decode_blocks(self) -> tuple[dict, str]:
+    def _decode_blocks(self) -> tuple[dict, dict, str]:
         """The ragged kernels' row block by kind of rows leaf (`full`,
         `window`, `chunks`) whose layers' bounded decode step takes a kernel
         (a kind the dispatcher refuses is left out, and {} is the XLA walk
-        throughout), and the name of that: `kernel`, `xla`, `mixed`. The
+        throughout), the rows a slot's fetch is rounded up to by the same
+        kinds, and the name of that: `kernel`, `xla`, `mixed`. The
         dispatchers' own rules (`ops/decode_attention.py`: `walk_refusal`
         for the K and V leaves of `mha` layers, `latent_refusal` for the
         latent leaf of `mla` layers, each deciding leaf by leaf, and
@@ -1264,6 +1271,7 @@ class ContinuousEngine:
 
         from ray_tpu.ops.decode_attention import (latent_block,
                                                   latent_refusal, row_block,
+                                                  row_granule,
                                                   two_leaf_block,
                                                   two_leaf_refusal,
                                                   walk_refusal)
@@ -1272,15 +1280,18 @@ class ContinuousEngine:
         q = (self.max_batch, mcfg.n_heads, mcfg.head_dim)
 
         def block_of(mixer, shape, dtype):
-            """A leaf's row block, None where its rule refuses it."""
+            """A leaf's row block and the rows a slot's fetch is rounded up
+            to, None where its rule refuses it."""
             if mixer == "mla":
                 refused = latent_refusal(shape, mcfg.kv_lora_rank, dtype)
-                return None if refused else latent_block(shape, dtype)
+                return None if refused else (latent_block(shape, dtype),) * 2
             if mixer == "eva":  # (the window leaf's shape, the chunks')
                 refused = two_leaf_refusal(q, *shape, dtype)
-                return None if refused else two_leaf_block(*shape, dtype)
+                return None if refused else (
+                    two_leaf_block(*shape, dtype),) * 2
             refused = walk_refusal(q, shape, dtype)
-            return None if refused else row_block(shape, dtype)
+            return None if refused else (row_block(shape, dtype),
+                                         row_granule(shape, dtype))
 
         leaves: dict = {}  # kind -> EVERY leaf of its layers, as the rules ask
         for i in range(mcfg.n_layers):
@@ -1301,8 +1312,10 @@ class ContinuousEngine:
             blocks = {kind: _agreed({block_of(*leaf) for leaf in asked})
                       for kind, asked in leaves.items()}
         blocks = {kind: block for kind, block in blocks.items() if block}
-        return blocks, ("xla" if not blocks else "kernel"
-                        if len(blocks) == len(leaves) else "mixed")
+        return ({kind: block for kind, (block, _) in blocks.items()},
+                {kind: piece for kind, (_, piece) in blocks.items()},
+                ("xla" if not blocks else "kernel"
+                 if len(blocks) == len(leaves) else "mixed"))
 
     def _mesh_scope(self):
         """The engine's mesh as the mesh in context, for what is traced
@@ -1677,13 +1690,14 @@ class ContinuousEngine:
         the chunk's span says it, as read). The XLA walk reads the static
         prefix `kv_bound` picks, every slot alike (the chunk's one bound,
         or under "eva" each step's own, [steps]: a step's mean then). The
-        ragged kernel reads
-        each live slot's own rows rounded up to its row block: `/v1/stats`
-        sums their mean as it is, and the span carries it as a whole
-        multiple of the block (ISSUE 37 asked for that form: a reader that
-        groups chunks by the rows walked, `benchmark/device_account.py`'s
-        classes, needs few distinct values until it buckets them itself,
-        ROADMAP M0 (j))."""
+        ragged kernel fetches
+        each live slot's own rows rounded up to `_kernel_pieces`' rows (its
+        row block, or the piece of its last block): `/v1/stats` sums their
+        mean as it is, and the span carries the mean of the slots' BLOCKS
+        as a whole multiple of the block (ISSUE 37 asked for that form: a
+        reader that groups chunks by the rows walked,
+        `benchmark/device_account.py`'s classes, needs few distinct values
+        until it buckets them itself, ROADMAP M0 (j))."""
         from ray_tpu.ops.decode_attention import kv_prefix_rows
 
         block = self._kernel_blocks.get(kind)
@@ -1692,8 +1706,9 @@ class ContinuousEngine:
                 kv_prefix_rows(int(bound), self._cache_kinds[kind]["rows"])
                 for bound in np.atleast_1d(kv_bound)]))
             return (int(rows) if rows.is_integer() else round(rows, 2)), rows
-        blocks = float(np.ceil(seen / block).mean())
-        return int(round(blocks)) * block, blocks * block
+        piece = self._kernel_pieces[kind]
+        return (int(round(float(np.ceil(seen / block).mean()))) * block,
+                float(np.ceil(seen / piece).mean()) * piece)
 
     def _fill_pipeline(self, ph) -> tuple:
         """Phases `admit` and `dispatch`, as often as they alternate: hand

@@ -174,11 +174,18 @@ def _xla_decode_walk(q, k_cache, v_cache, lengths, kv_bound):
     return over_kv_prefix(attend, (k_cache, v_cache), kv_bound)
 
 
-#: Bytes of K (and as many of V) one grid step of the ragged kernel holds:
-#: the row block is the largest divisor of the leaf's rows at or under this
-#: many bytes of rows (Phi-3's rows of 32 heads of 128: 128 rows; Trinity's
-#: of 4: 1024). A slot's walk is rounded up to it.
+#: Bytes of K (and as many of V) one block of a ragged kernel holds: the row
+#: block is the largest divisor of the leaf's rows at or under this many
+#: bytes of rows (Phi-3's rows of 32 heads of 128: 128 rows; Trinity's of 4:
+#: 1024). The latent and the two-leaf kernels round a slot's walk up to it.
 BLOCK_BYTES = 1 << 20
+
+#: Bytes of K (and as many of V) one piece of a slot's LAST block in the
+#: `mha` family's kernel: the block is fetched to the slot's own rows rounded
+#: up to the largest divisor of the block at or under this many bytes of
+#: rows (an eighth of a block: 16 rows of Phi-3's leaves, 32 of Ouro's, 128
+#: of Trinity's).
+GRANULE_BYTES = 128 << 10
 
 
 def row_block(leaf_shape, dtype) -> int | None:
@@ -188,6 +195,16 @@ def row_block(leaf_shape, dtype) -> int | None:
     _, rows, kv, row = leaf_shape
     most = max(8, BLOCK_BYTES // (kv * row * jnp.dtype(dtype).itemsize))
     return rows if rows <= most else auto_block(rows, most, 8)
+
+
+def row_granule(leaf_shape, dtype) -> int | None:
+    """Rows of one piece of a slot's last block (`GRANULE_BYTES`): a divisor
+    of `row_block`'s block, which is what a slot's fetch is rounded up to.
+    (Rows are a leaf's untiled axis: a piece of any rows is whole tiles.)"""
+    block = row_block(leaf_shape, dtype)
+    _, _, kv, row = leaf_shape
+    most = max(1, GRANULE_BYTES // (kv * row * jnp.dtype(dtype).itemsize))
+    return block and auto_block(block, most, 1)
 
 
 def walk_refusal(q_shape, leaf_shape, dtype=jnp.bfloat16) -> str | None:
@@ -228,20 +245,112 @@ def _merged(ref):
     return ref[0].reshape(t * kv, row)
 
 
-def _ragged_kernel(stop_ref, _slot_ref, _last_ref, q_ref, k_ref, v_ref, o_ref,
-                   m_ref, l_ref, acc_ref, *, scale: float, block: int,
-                   kv: int, group: int, exact: bool):
-    """One (slot, row block) of the grid. q_ref/o_ref [H, row]; k_ref/v_ref
-    [1, block, KV, row] as the leaf holds them; m/l [H, 128] (every lane the
-    same), acc [H, row]. The block's rows of all KV heads are ONE matrix
-    `[block x KV, row]` (merging the two leading axes of the block moves
-    nothing in VMEM), every query head is multiplied against all of it on
-    the MXU, and the mask keeps, for each query head, the visible rows of
-    its own key/value head."""
-    b, j = pl.program_id(0), pl.program_id(1)
-    stop = stop_ref[b]
-    hq = q_ref.shape[0]
-    cols = block * kv
+def _merged_seen(ref, seen):
+    """`_merged(ref)` with the block's rows at and past `seen` zeroed: what
+    lies above a stop is an earlier occupant's, and a weight of exactly 0
+    keeps nothing out of a product if the value is not finite. In words,
+    as `_merged` reads them; the fetch hides it (my chip run, PR 50)."""
+    _, t, kv, row = ref.shape
+    pack = 4 // ref.dtype.itemsize
+    if pack > 1 and kv % pack == 0:
+        kv, image = kv // pack, jnp.uint32
+    else:
+        pack, image = 1, ref.dtype
+    rows = ref.bitcast(image).reshape(t * kv, row)[...]
+    at = jax.lax.broadcasted_iota(jnp.int32, (t * kv, 1), 0) // kv
+    rows = jnp.where(at < seen, rows, jnp.zeros_like(rows))
+    return pltpu.bitcast(rows, ref.dtype) if pack > 1 else rows
+
+
+def _attend(q_ref, k_ref, v_ref, seen, m_ref, l_ref, acc_ref, *,
+            scale: float, group: int, exact: bool):
+    """One block of a slot's online softmax, the `mha` family's and the
+    two-leaf kernel's: q_ref [H, row] against the first `seen` rows of the
+    block k_ref/v_ref `[1, T, KV, row]` hold (what lies past them may be
+    anything: the scores there are masked and V's rows zeroed); m/l [H,
+    128] (every lane the same), acc [H, row]. The block's rows of all KV
+    heads are ONE matrix `[T x KV, row]` (`_merged`), every query head is
+    multiplied against all of it on the MXU, and the mask keeps, for each
+    query head, the visible rows of its own key/value head (head h reads
+    key/value head h // `group`). bf16 operands, float32 sums; `exact`:
+    the float32 probabilities against V's own dtype, as two products."""
+    (hq, _), (_, t, kv, _) = q_ref.shape, k_ref.shape
+    k, v = _merged(k_ref), _merged_seen(v_ref, seen)
+    s = jax.lax.dot_general(
+        q_ref[...], k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale
+    # column c of the merged matrix is row c // KV of the block, key/value
+    # head c % KV
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, t * kv), 1)
+    head = jnp.where(col // kv < seen, col % kv, -1)
+    own = jax.lax.broadcasted_iota(jnp.int32, (hq, 1), 0)
+    s = jnp.where(head == (own // group if group > 1 else own), s, NEG_INF)
+    # (the block holds a visible row, so every head's max is finite)
+    m_prev, l_prev = m_ref[:, 0:1], l_ref[:, 0:1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    alpha = jnp.exp(m_prev - m_new)
+    l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+    weigh = lambda w: jax.lax.dot_general(  # noqa: E731
+        w, v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    hi = p.astype(v.dtype)
+    pv = weigh(hi)
+    if exact and hi.dtype != p.dtype:
+        pv = pv + weigh((p - hi.astype(jnp.float32)).astype(v.dtype))
+    acc_ref[...] = acc_ref[...] * alpha + pv
+    m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+    l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+
+
+def _ragged_kernel(stop_ref, slot_ref, at_ref, q_ref, k_hbm, v_hbm, o_ref,
+                   k_buf, v_buf, sems, m_ref, l_ref, acc_ref, *, scale: float,
+                   block: int, granule: int, group: int, exact: bool):
+    """One entry of the grid: row block `at_ref[i]` of slot `slot_ref[i]`.
+    q_ref/o_ref [H, row]; k_hbm/v_hbm the leaves `[slots, rows, KV, row]`
+    where they lie; k_buf/v_buf [2, block, KV, row], the entry's block in
+    buffer `i % 2` while the next entry's is on its way into the other
+    (`sems` [2, 2]: buffer, K or V); m/l/acc `_attend`'s. An entry fetches
+    the rows of its block its slot shows, a whole block as one copy and a
+    slot's last as pieces of `granule` rows: what lies past them in the
+    buffer is an earlier entry's, or nothing's, and `_attend` lets none of
+    it through. Every copy an entry starts (the first its own, each the
+    next one's) is waited for by the entry it is for, under the same
+    condition: the last entry leaves none in flight."""
+    i = pl.program_id(0)
+
+    def fetch(entry, act):
+        """Start, or wait for, the copies of `entry`'s rows into its
+        buffer: none for a free slot's entry."""
+        b, first = slot_ref[entry], at_ref[entry] * block
+        seen = stop_ref[b] - first
+
+        def copies(at, rows):
+            """K's and V's rows `[at, at + rows)` of the block."""
+            for c, (hbm, buf) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf))):
+                act(pltpu.make_async_copy(
+                    hbm.at[b, pl.ds(first + at, rows)],
+                    buf.at[entry % 2, pl.ds(at, rows)], sems.at[entry % 2, c]))
+
+        @pl.when(seen >= block)
+        def _whole():
+            copies(0, block)
+
+        @pl.when(seen < block)
+        def _last():
+            jax.lax.fori_loop(0, (seen + granule - 1) // granule,
+                              lambda n, _: copies(n * granule, granule), None)
+
+    @pl.when(i == 0)
+    def _first():
+        fetch(i, lambda copy: copy.start())
+
+    @pl.when(i + 1 < pl.num_programs(0))  # (the last entry starts none)
+    def _ahead():
+        fetch(i + 1, lambda copy: copy.start())
+
+    j = at_ref[i]
+    stop = stop_ref[slot_ref[i]]
 
     @pl.when(j == 0)
     def _init():
@@ -251,36 +360,12 @@ def _ragged_kernel(stop_ref, _slot_ref, _last_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(j * block < stop)
     def _block():
-        k, v = _merged(k_ref), _merged(v_ref)
-        s = jax.lax.dot_general(
-            q_ref[...], k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        # column c of the merged matrix is position j * block + c // KV of
-        # key/value head c % KV; query head h reads key/value head
-        # h // group, up to the slot's stop
-        col = jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1)
-        head = jnp.where(col // kv < stop - j * block, col % kv, -1)
-        own = jax.lax.broadcasted_iota(jnp.int32, (hq, 1), 0) // group
-        s = jnp.where(head == own, s, NEG_INF)
-        # (the block holds a visible row, so every head's max is finite)
-        m_prev, l_prev = m_ref[:, 0:1], l_ref[:, 0:1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
-        weigh = lambda w: jax.lax.dot_general(  # noqa: E731
-            w, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        hi = p.astype(v.dtype)
-        pv = weigh(hi)
-        if exact and hi.dtype != p.dtype:
-            # float32 probabilities against V's own dtype, as two products
-            pv = pv + weigh((p - hi.astype(jnp.float32)).astype(v.dtype))
-        acc_ref[...] = acc_ref[...] * alpha + pv
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+        fetch(i, lambda copy: copy.wait())
+        here = pl.ds(i % 2, 1)
+        _attend(q_ref, k_buf.at[here], v_buf.at[here], stop - j * block,
+                m_ref, l_ref, acc_ref, scale=scale, group=group, exact=exact)
 
-    @pl.when(j == pl.num_programs(1) - 1)
+    @pl.when((j + 1) * block >= stop)  # the slot's last entry
     def _finish():
         l = l_ref[:, 0:1]
         l = jnp.where(l == 0.0, 1.0, l)  # a free slot: zeros
@@ -295,16 +380,17 @@ def ragged_decode_attention(q, k_cache, v_cache, lengths, live=None, *,
     care), and NO row of a slot `live` [B] bool marks free, whose output is
     zeros (its device-side length is stale and keeps growing). -> [B, H, D].
 
-    Grid (slot, row block), as many row blocks as the longest live slot
-    shows (the grid's extent is a traced scalar: one program whatever it
-    is), online softmax in float32 VMEM scratch. A slot's stop is
-    prefetched as a scalar. A block at or past it does no work and fetches
-    nothing: its index map names the block the pipeline already holds, the
-    slot's last visible one or, for a free slot, the last live slot's. The
-    leaf is read in the shape and layout it has. bf16 operands, float32
-    sums; the probabilities go to the weighted sum in V's dtype where heads
-    share key/value heads, and as float32 (two products) where each has its
-    own, as the XLA walk of each family does."""
+    The grid is ONE list of the row blocks that hold a visible row, slot
+    after slot, its length a traced scalar (one program whatever it is):
+    no entry passes without work but a free slot's one, which fetches
+    nothing and writes the zeros. The leaves stay in HBM, read in the shape
+    and layout they have, and the kernel fetches for itself, the next
+    entry's rows on their way while this entry's are multiplied: a whole
+    block in one copy, a slot's last block to the slot's own rows rounded
+    up to `row_granule`'s piece. Online softmax in float32 VMEM scratch.
+    bf16 operands, float32 sums; the probabilities go to the weighted sum
+    in V's dtype where heads share key/value heads, and as float32 (two
+    products) where each has its own, as the XLA walk of each family does."""
     (_, hq, d), (_, _, kv, row) = q.shape, k_cache.shape
     block = row_block(k_cache.shape, k_cache.dtype)
     if not block or hq % kv or row < d:
@@ -312,27 +398,40 @@ def ragged_decode_attention(q, k_cache, v_cache, lengths, live=None, *,
                          f"blocks of {block} rows (`walk_refusal` says so "
                          f"beforehand)")
     return _ragged(q, k_cache, v_cache, lengths, live, block=block,
+                   granule=row_granule(k_cache.shape, k_cache.dtype),
                    interpret=interpret)
 
 
-@functools.partial(jax.jit, static_argnames=("block", "interpret"))
-def _ragged(q, k_cache, v_cache, lengths, live, *, block: int,
+def work_list(stop, block: int, n_blocks: int):
+    """The grid's entries for slots that show `stop` [B] rows of leaves of
+    `n_blocks` blocks of `block` rows, as two tables a scalar prefetch
+    carries: whose entry it is and which of its row blocks, slot after
+    slot, one entry for each block that holds a visible row and one for a
+    slot that shows none; and the entries counted up to each slot's last
+    [B], whose last is the grid's length: entries past it are never looked
+    at."""
+    b = stop.shape[0]
+    need = jnp.maximum(-(-stop // block), 1)
+    ends = jnp.cumsum(need)
+    entry = jnp.arange(b * n_blocks, dtype=jnp.int32)
+    slot = jnp.minimum(jnp.sum(entry[:, None] >= ends[None, :], axis=1,
+                               dtype=jnp.int32), b - 1)
+    return slot, entry - (ends - need)[slot], ends
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("block", "granule", "interpret"))
+def _ragged(q, k_cache, v_cache, lengths, live, *, block: int, granule: int,
             interpret: bool):
-    """`ragged_decode_attention` in row blocks of `block` (`row_block`'s,
-    static: the layers of a model that share a leaf's shape share one
-    trace)."""
+    """`ragged_decode_attention` in row blocks of `block` and last pieces of
+    `granule` (`row_block`'s, `row_granule`'s; static: the layers of a model
+    that share a leaf's shape share one trace)."""
     b, hq, d = q.shape
     _, rows, kv, row = k_cache.shape
-    n_blocks = rows // block
     stop = jnp.minimum(lengths.astype(jnp.int32), rows)
     if live is not None:
         stop = jnp.where(live, stop, 0)
-    # what the pipeline holds while a free slot's steps pass: the last
-    # block of the nearest live slot before it (slot 0's first, if none)
-    held = jnp.maximum(jax.lax.cummax(jnp.where(
-        stop > 0, jnp.arange(b, dtype=jnp.int32) * n_blocks
-        + (stop - 1) // block, -1)), 0)
-    slot, last = held // n_blocks, held % n_blocks
+    slot, at, ends = work_list(stop, block, rows // block)
     if row > d:
         q = jnp.pad(q, ((0, 0), (0, 0), (0, row - d)))
 
@@ -341,35 +440,33 @@ def _ragged(q, k_cache, v_cache, lengths, live, *, block: int,
     # every row of every slot, live or not)
     in_hbm = (lambda leaf: leaf) if interpret else functools.partial(
         pltpu.with_memory_space_constraint, memory_space=pltpu.HBM)
-
-    def kv_index(bi, j, stop, slot, last):
-        return (slot[bi],
-                jnp.where(stop[bi] > 0, jnp.minimum(j, last[bi]), last[bi]),
-                0, 0)
-
     kernel = functools.partial(
-        _ragged_kernel, scale=d ** -0.5, block=block, kv=kv, group=hq // kv,
-        exact=hq == kv)
-    leaf = pl.BlockSpec((1, block, kv, row), kv_index)
-    head = pl.BlockSpec((None, hq, row), lambda bi, j, *_: (bi, 0, 0))
+        _ragged_kernel, scale=d ** -0.5, block=block, granule=granule,
+        group=hq // kv, exact=hq == kv)
+    leaf = pl.BlockSpec(memory_space=pl.ANY)
+    head = pl.BlockSpec((None, hq, row), lambda i, stop, slot, at: (
+        slot[i], 0, 0))
     out = pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((b, hq, row), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(b, jnp.maximum(-(-jnp.max(stop) // block), 1)),
+            grid=(ends[-1],),
             in_specs=[head, leaf, leaf],
             out_specs=head,
             scratch_shapes=[
+                pltpu.VMEM((2, block, kv, row), k_cache.dtype),
+                pltpu.VMEM((2, block, kv, row), v_cache.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
                 pltpu.VMEM((hq, LANES), jnp.float32),  # max
                 pltpu.VMEM((hq, LANES), jnp.float32),  # denominator
                 pltpu.VMEM((hq, row), jnp.float32),  # accumulator
             ]),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
+            dimension_semantics=("arbitrary",),
             vmem_limit_bytes=64 << 20),
         interpret=interpret,
-    )(stop, slot, last, q, in_hbm(k_cache), in_hbm(v_cache))
+    )(stop, slot, at, q, in_hbm(k_cache), in_hbm(v_cache))
     return out[..., :d]
 
 
@@ -528,17 +625,10 @@ def _ragged_latent(q, latents, lengths, live, *, rank: int, scale: float,
     stop = jnp.minimum(lengths.astype(jnp.int32), rows)
     if live is not None:
         stop = jnp.where(live, stop, 0)
-    # The grid's entries, as three tables a scalar prefetch carries: whose
-    # entry it is, which of its row blocks, and the block to hold while it
-    # passes, `slot * n_blocks + block`: its own, or for a free slot's
-    # entry the one before it (the first of all, if none). Entries past
-    # the grid's length are never looked at.
-    need = jnp.maximum(-(-stop // block), 1)
-    ends = jnp.cumsum(need)
-    entry = jnp.arange(b * n_blocks, dtype=jnp.int32)
-    slot = jnp.minimum(jnp.sum(entry[:, None] >= ends[None, :], axis=1,
-                               dtype=jnp.int32), b - 1)
-    at = entry - (ends - need)[slot]
+    # The grid's entries (`work_list`), and as a third table the block to
+    # hold while an entry passes, `slot * n_blocks + block`: its own, or
+    # for a free slot's entry the one before it (the first of all, if none).
+    slot, at, ends = work_list(stop, block, n_blocks)
     held = jnp.maximum(jax.lax.cummax(jnp.where(
         stop[slot] > 0, slot * n_blocks + at, -1)), 0)
     if row > width:
@@ -663,23 +753,6 @@ def two_leaf_refusal(q_shape, window_shape, chunks_shape,
     return None
 
 
-def _merged_seen(ref, seen):
-    """`_merged(ref)` with the block's rows at and past `seen` zeroed: what
-    lies above a stop is an earlier occupant's, and a weight of exactly 0
-    keeps nothing out of a product if the value is not finite. In words,
-    as `_merged` reads them; the fetch hides it (my chip run, PR 50)."""
-    _, t, kv, row = ref.shape
-    pack = 4 // ref.dtype.itemsize
-    if pack > 1 and kv % pack == 0:
-        kv, image = kv // pack, jnp.uint32
-    else:
-        pack, image = 1, ref.dtype
-    rows = ref.bitcast(image).reshape(t * kv, row)[...]
-    at = jax.lax.broadcasted_iota(jnp.int32, (t * kv, 1), 0) // kv
-    rows = jnp.where(at < seen, rows, jnp.zeros_like(rows))
-    return pltpu.bitcast(rows, ref.dtype) if pack > 1 else rows
-
-
 def _two_leaf_kernel(stop_w_ref, stop_c_ref, slot_ref, at_ref, _held_w_ref,
                      _held_c_ref, q_ref, kw_ref, vw_ref, kc_ref, vc_ref,
                      o_ref, m_ref, l_ref, acc_ref, *, scale: float,
@@ -694,8 +767,6 @@ def _two_leaf_kernel(stop_w_ref, stop_c_ref, slot_ref, at_ref, _held_w_ref,
     j = at_ref[i]
     stop_w, stop_c = stop_w_ref[slot_ref[i]], stop_c_ref[slot_ref[i]]
     ahead = (stop_w + block - 1) // block  # the slot's window entries
-    hq = q_ref.shape[0]
-    cols = block * hq
 
     @pl.when(j == 0)
     def _init():
@@ -704,36 +775,10 @@ def _two_leaf_kernel(stop_w_ref, stop_c_ref, slot_ref, at_ref, _held_w_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     def rows(k_ref, v_ref, seen):
-        """`_ragged_kernel`'s `_block` for heads on key/value heads of
-        their own (its `exact` arm: the float32 walk's arithmetic up to the
-        order of its sums), the block's first `seen` rows visible."""
-        k, v = _merged(k_ref), _merged_seen(v_ref, seen)
-        s = jax.lax.dot_general(
-            q_ref[...], k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        # column c of the merged matrix is row c // H of the block, head
-        # c % H: a query head reads the visible rows of its own
-        col = jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1)
-        head = jnp.where(col // hq < seen, col % hq, -1)
-        own = jax.lax.broadcasted_iota(jnp.int32, (hq, 1), 0)
-        s = jnp.where(head == own, s, NEG_INF)
-        # (the block holds a visible row, so every head's max is finite)
-        m_prev, l_prev = m_ref[:, 0:1], l_ref[:, 0:1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
-        weigh = lambda w: jax.lax.dot_general(  # noqa: E731
-            w, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        hi = p.astype(v.dtype)
-        pv = weigh(hi)
-        if hi.dtype != p.dtype:
-            # float32 probabilities against V's own dtype, as two products
-            pv = pv + weigh((p - hi.astype(jnp.float32)).astype(v.dtype))
-        acc_ref[...] = acc_ref[...] * alpha + pv
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+        """Heads on key/value heads of their own: the float32 walk's
+        arithmetic up to the order of its sums."""
+        _attend(q_ref, k_ref, v_ref, seen, m_ref, l_ref, acc_ref,
+                scale=scale, group=1, exact=True)
 
     @pl.when(j < ahead)
     def _window():

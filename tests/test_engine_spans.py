@@ -15,6 +15,7 @@ import re
 import threading
 import time
 
+import numpy as np
 import pytest
 
 from ray_tpu._private import tracing
@@ -317,6 +318,9 @@ def test_the_chunk_spans_and_the_stats_say_which_attention_served(
                                           on_the_chip)  # heads of 128
 
     block, ring = 8, 20 if form == "mixed" else 16
+    # (the `mha` kernel fetches a slot's last block in `on_the_chip`'s
+    # pieces, a quarter of a block; the latent kernel whole blocks)
+    piece = block if name == "mla" else block // 4
     if form != "xla" and name == "mla":
         latent_on_the_chip(monkeypatch, block)
     elif form != "xla":
@@ -349,10 +353,51 @@ def test_the_chunk_spans_and_the_stats_say_which_attention_served(
                 assert live - block / 2 <= walked <= live + 1.5 * block
     if form != "xla":
         # the slots' own lengths, not the longest one's quarter; the
-        # stats' share is the mean as it is: under a block over the live
+        # stats' share is the mean of what is FETCHED as it is: under a
+        # piece over the live
         assert len({at["kv_rows_full"] for at in chunks}) > 2
         assert 0 < st["kv_live_share"] <= st["kv_walk_share"] < (
-            st["kv_live_share"] + block / 64)
+            st["kv_live_share"] + piece / 64)
+
+
+@pytest.mark.parametrize("name", ["mha", "swa"])
+def test_the_walk_share_is_the_rows_the_kernel_fetches(name, spans,
+                                                       monkeypatch):
+    """One request alone on its slot under the `mha` family's kernel
+    (forced, in interpret mode, blocks of 8 rows fetched in pieces of 2):
+    step by step its rows rounded up to the PIECE are what `/v1/stats`
+    `walk_share` sums, for full leaves and rings, while the span's
+    `kv_rows_<kind>` stays the chunk's mean in whole BLOCKS."""
+    from tests.test_ragged_decode import MHA, SWA, on_the_chip
+
+    block, piece = 8, 2
+    on_the_chip(monkeypatch, block * 2 * 128 * 4)
+    eng = ContinuousEngine({"mha": MHA, "swa": SWA}[name], max_batch=2,
+                           decode_chunk=4)
+    try:
+        serve(eng, 1, max_tokens=30)
+        st = eng.cache_stats()
+    finally:
+        eng.shutdown()
+    assert (eng._kernel_blocks["full"], eng._kernel_pieces["full"]) == (
+        block, piece)
+    chunks = [s["at"] for s in spans if s["n"] == "engine.dispatch_chunk"]
+    assert st["decode_steps"] == sum(at["tokens"] for at in chunks) > 0
+    for kind, k in st["cache_kinds"].items():
+        fetched = blocks = 0
+        for at in chunks:
+            # one live slot: step j of the chunk sees bound - n + j + 1 rows
+            seen = np.minimum(np.arange(
+                at["kv_bound"] - at["tokens"] + 1, at["kv_bound"] + 1),
+                k["rows"])
+            fetched += np.ceil(seen / piece).sum() * piece
+            assert at["kv_rows_" + kind] == round(
+                np.ceil(seen / block).mean()) * block
+            blocks += np.ceil(seen / block).sum() * block
+        assert k["walk_share"] == pytest.approx(
+            fetched / (st["decode_steps"] * k["rows"]))
+        assert k["live_share"] < k["walk_share"] < blocks / (
+            st["decode_steps"] * k["rows"])
 
 
 def test_idle_time_is_carried_by_the_next_recorded_pass(engine, spans):

@@ -27,7 +27,8 @@ from ray_tpu.ops import attention
 # function, which the package pins over it on first use)
 da = importlib.import_module("ray_tpu.ops.decode_attention")
 
-ROWS, BLOCK = 64, 16
+#: (a slot's last block is fetched in pieces of GRANULE rows)
+ROWS, BLOCK, GRANULE = 64, 16, 4
 
 #: name -> (query heads, key/value heads, head size, cache row)
 FAMILIES = {
@@ -35,6 +36,8 @@ FAMILIES = {
     "heads_of_96_in_rows_of_128": (32, 32, 96, 128),
     # Trinity-Mini: eight query heads a key/value head of 128
     "32_heads_on_4_of_128": (32, 4, 128, 128),
+    # Ouro: a head of its own, as wide as its row
+    "16_heads_of_128": (16, 16, 128, 128),
 }
 
 #: name -> (each slot's length as the device holds it, its `live` mark).
@@ -53,6 +56,16 @@ CASES = {
     "free_rows_first_and_last": ([9 * ROWS, 2 * BLOCK, 5, 4 * ROWS],
                                  [False, True, True, False]),
     "all_rows_free": ([5, 10 * ROWS, BLOCK, ROWS], [False] * 4),
+    # (the work list at its longest: `slots x blocks` entries)
+    "every_slot_shows_every_row": ([ROWS] * 4, [True] * 4),
+    "stops_at_whole_blocks_and_one_row_past_them": (
+        [2 * BLOCK, 2 * BLOCK + 1, 3 * BLOCK, 3 * BLOCK + 1], [True] * 4),
+    "stops_at_whole_pieces_and_one_row_past_them": (
+        [GRANULE, GRANULE + 1, BLOCK + 3 * GRANULE, BLOCK + 3 * GRANULE + 1],
+        [True] * 4),
+    "a_blocks_last_piece_and_the_row_before_it": (
+        [BLOCK - GRANULE, BLOCK - GRANULE + 1, BLOCK - 1, ROWS - 1],
+        [True, True, True, False]),
 }
 
 
@@ -68,9 +81,10 @@ def on_the_chip(monkeypatch, block_bytes: int) -> None:
     monkeypatch.setattr(da, "ragged_decode_attention", functools.partial(
         da.ragged_decode_attention, interpret=True))
     monkeypatch.setattr(da, "BLOCK_BYTES", block_bytes)
+    monkeypatch.setattr(da, "GRANULE_BYTES", block_bytes // 4)
 
 
-def leaves(family: str, dtype, poison=()):
+def leaves(family: str, dtype, poison=(), stops=None):
     hq, kv, d, row = FAMILIES[family]
     keys = jax.random.split(jax.random.PRNGKey(len(family)), 3)
     q = jax.random.normal(keys[0], (4, hq, d), dtype)
@@ -79,6 +93,10 @@ def leaves(family: str, dtype, poison=()):
             for key in keys[1:])
     for slot in poison:  # what a free row's stale steps may have left
         k, v = k.at[slot].set(jnp.nan), v.at[slot].set(jnp.nan)
+    if stops is not None:  # and an earlier occupant above a live slot's stop
+        past = (jnp.arange(ROWS)[None, :] >= jnp.asarray(stops)[:, None])[
+            ..., None, None]
+        k, v = jnp.where(past, jnp.nan, k), jnp.where(past, jnp.nan, v)
     return q, k, v, d
 
 
@@ -91,14 +109,19 @@ def test_the_kernel_reads_each_slots_own_rows_and_none_of_a_free_one(
     """Against `_xla_decode_attention` over the rows a slot shows
     (`min(length, rows)`). A free row's cache is never read: filled with
     NaN here, it leaves its neighbours' outputs as they were, and its own
-    output, which nobody reads, is zeros."""
+    output, which nobody reads, is zeros. Nor does a row past a live slot's
+    stop reach the output, fetched (the rest of its piece) or not: every
+    such row is NaN here too, K and V."""
     lens, live = CASES[case]
     free = [i for i, seated in enumerate(live) if not seated]
-    q, k, v, d = leaves(family, dtype, poison=free)
-    # (the block is derived from the row's bytes: BLOCK rows of this leaf)
-    monkeypatch.setattr(da, "BLOCK_BYTES",
-                        BLOCK * k.shape[2] * k.shape[3] * k.dtype.itemsize)
+    q, k, v, d = leaves(family, dtype, poison=free, stops=lens)
+    # (block and piece are derived from the row's bytes: BLOCK and GRANULE
+    # rows of this leaf)
+    row_bytes = k.shape[2] * k.shape[3] * k.dtype.itemsize
+    monkeypatch.setattr(da, "BLOCK_BYTES", BLOCK * row_bytes)
+    monkeypatch.setattr(da, "GRANULE_BYTES", GRANULE * row_bytes)
     assert da.row_block(k.shape, k.dtype) == BLOCK
+    assert da.row_granule(k.shape, k.dtype) == GRANULE
     got = da.ragged_decode_attention(
         q, k, v, jnp.asarray(lens, jnp.int32), jnp.asarray(live),
         interpret=True)
@@ -139,6 +162,37 @@ def test_without_a_live_mask_every_row_counts_and_the_block_is_derived():
     assert da.row_block((16, 2048, 4, 128), jnp.bfloat16) == 1024
     assert da.row_block((2, 8 * 1031, 32, 128), jnp.bfloat16) == 8
     assert da.row_block((2, 2 * 1031, 32, 128), jnp.bfloat16) is None
+    # a slot's last block goes in pieces of 128 KiB of K: an eighth of it
+    assert da.row_granule((8, 2048, 32, 128), jnp.bfloat16) == 16
+    assert da.row_granule((16, 2048, 16, 128), jnp.bfloat16) == 32
+    assert da.row_granule((16, 8192, 4, 128), jnp.bfloat16) == 128
+    assert da.row_granule(k.shape, k.dtype) == ROWS  # (a short leaf: one)
+    assert da.row_granule((2, 8 * 1031, 32, 128), jnp.bfloat16) == 8
+    assert da.row_granule((2, 2 * 1031, 32, 128), jnp.bfloat16) is None
+
+
+#: name -> each slot's visible rows (0: a free slot), leaves of four blocks
+WORK_LISTS = {
+    "all_slots_free_an_entry_each": [0, 0, 0],
+    "one_slot_one_entry": [BLOCK - 1],
+    "every_slot_full_slots_x_blocks_entries": [4 * BLOCK] * 3,
+    "free_slots_between_and_a_blocks_edge": [0, BLOCK, 0, BLOCK + 1, 0],
+    "one_row_and_all_rows": [1, 4 * BLOCK, 2 * BLOCK],
+}
+
+
+@pytest.mark.parametrize("name", list(WORK_LISTS))
+def test_the_work_list_against_a_plain_loop(name):
+    """`work_list`: one entry for each row block of a slot that holds a
+    visible row, slot after slot, and one for a slot that shows none."""
+    stops = WORK_LISTS[name]
+    want = [(slot, at) for slot, stop in enumerate(stops)
+            for at in range(max(-(-stop // BLOCK), 1))]
+    slot, at, ends = da.work_list(jnp.asarray(stops, jnp.int32), BLOCK, 4)
+    assert slot.shape == at.shape == (len(stops) * 4,)
+    assert int(ends[-1]) == len(want)
+    assert list(zip(np.asarray(slot)[:len(want)].tolist(),
+                    np.asarray(at)[:len(want)].tolist())) == want
 
 
 @pytest.mark.parametrize("name,q,leaf,mesh,why", [
@@ -224,9 +278,10 @@ def test_the_engine_serves_the_same_tokens_through_the_kernel(
     # the two runs' counters are not compared with each other)
     assert kernel["decode_steps_kernel"] == kernel["decode_steps"] > 0
     for k in kernel["cache_kinds"].values():
-        # a slot's rows rounded up to a block: under a block more
+        # a slot's rows rounded up to a piece of 4 rows (the ring of 16 is
+        # one block of 4 pieces too): under a piece more
         assert k["live_share"] <= k["walk_share"] < (
-            k["live_share"] + 16 / k["rows"])
+            k["live_share"] + 4 / k["rows"])
     assert kernel["kv_walk_share"] < 1.0
 
 

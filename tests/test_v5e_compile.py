@@ -456,6 +456,7 @@ def test_v5e_chunk_sharded_over_tp_keeps_the_unpartitioned_kernel_out(
     assert len(re.findall(r" conditional\(", text)) == cfg.n_layers
     one = build_compiled(chips[0], cfg=cfg)
     assert (one._kernel_blocks, one._decode_form) == ({"full": 256}, "kernel")
+    assert one._kernel_pieces == {"full": 64}  # 128 KiB of 8 heads of 128
 
 
 def moved_rows(eng, text: str, ops: str = "copy|slice") -> list:
@@ -544,6 +545,8 @@ def test_v5e_chunk_program_with_the_ragged_kernel_moves_no_rows(
     assert eng._kernel_blocks == {
         "kv": {"full": 256},  # a short leaf of 4 heads: one block
         "swa": {"full": 1024, "window": 1024}}[name]
+    # a slot's last block is fetched in pieces of 128 KiB of K
+    assert eng._kernel_pieces == dict.fromkeys(eng._kernel_blocks, 128)
     text = eng._chunk.lower(*eng._chunk_shapes(
         eng.params, eng._cache_spec, True)).compile().as_text()
     assert len(re.findall(r" custom-call\(.*tpu_custom_call", text)) \
@@ -558,7 +561,7 @@ def test_v5e_chunk_program_with_the_ragged_kernel_moves_no_rows(
     # and all: `copy-start` / `copy-done` of `[slots, 2048, 4, 128]`)
     for line in re.findall(r" custom-call\((.*?)\), custom_call_target="
                            r"\"tpu_custom_call\"", text):
-        # (the grid's extent, stop, slot, last, q, K, V)
+        # (the work list's length, stop, slot, at, q, K, V)
         *_, k, v = line.split(", ")
         assert "copy" not in k and "copy" not in v, line
     assert not moved_rows(eng, text, "copy-start|copy-done"), name
@@ -1037,6 +1040,7 @@ def test_v5e_ouro_as_benchmarked_walks_32_leaf_pairs_where_they_lie(
                          decode_chunk=app["decode_chunk"])
     assert eng.cache_boundary_copies == 0
     assert (eng._kernel_blocks, eng._decode_form) == ({"full": 256}, "kernel")
+    assert eng._kernel_pieces == {"full": 32}  # 128 KiB of 16 heads of 128
     full = eng.cache_stats()["cache_kinds"]["full"]
     assert (full["layers"], full["leaves"], full["passes"], full["rows"]) == (
         8, 64, 4, 2048)
@@ -1057,7 +1061,7 @@ def test_v5e_ouro_as_benchmarked_walks_32_leaf_pairs_where_they_lie(
     calls = re.findall(r" custom-call\((.*?)\), custom_call_target="
                        r"\"tpu_custom_call\"", text)
     assert len(calls) == 4 * 8
-    for line in calls:  # (the grid's extent, stop, slot, last, q, K, V)
+    for line in calls:  # (the work list's length, stop, slot, at, q, K, V)
         *_, k, v = line.split(", ")
         assert "copy" not in k and "copy" not in v, line
     assert len(re.findall(r" while\(", text)) == 1
