@@ -29,12 +29,17 @@ through sys.modules gates so a process that never imported them never pays
 
 from __future__ import annotations
 
+import collections
+import contextlib
+import json
 import os
+import re
 import sys
 import threading
 import time
 from typing import Callable, Optional
 
+from ray_tpu._private import tracing
 from ray_tpu._private.rtconfig import CONFIG
 
 
@@ -164,48 +169,321 @@ def disk_percent(path: str) -> float:
     return round(100.0 * (1.0 - free / total), 2)
 
 
-# --------------------------------------------------------- compile events
-# Live jax compile telemetry: a jax.monitoring duration listener counts
-# backend compiles and their cumulative seconds from the moment the worker
-# sampler first observes jax imported. Registration is idempotent and
-# NEVER imports jax itself (sys.modules gate — pool workers that stay
-# jax-free must not pay the jax import for a gauge).
-_compile_lock = threading.Lock()
-_compile_stats = {"count": 0, "seconds": 0.0}
-_compile_listener_installed = False
-
+# ------------------------------------------------------- the set-up account
+# ONE account per process of what its set-up was spent on (README "Tracing
+# & timeline"): the STAGES the program goes through before it can serve
+# (`setup_stage`: `replica.start`, `runtime.init`, `engine.init`, ...) and
+# one record per program BUILD, made from JAX's own monitoring events: the
+# time-span form of its three compile events, which carry the program's
+# name, and the four events of its compilation cache. `compile_stats()`,
+# the sampler's `compile_count` / `compile_s` and `/v1/stats` are views of
+# it. Registration is idempotent and NEVER imports jax itself (sys.modules
+# gate — pool workers that stay jax-free must not pay the jax import for a
+# gauge). The listeners are called at builds only; a stage is two stamps.
+# With RT_TRACING=1 a stage and a build are also spans and the account is
+# written to `<RT_SESSION_DIR>/setup/<pid>.json`; unset, nothing is.
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "hit",
+                 "/jax/compilation_cache/cache_misses": "miss"}
+_CACHE_SECONDS = {
+    "/jax/compilation_cache/cache_retrieval_time_sec": "retrieval_s",
+    "/jax/compilation_cache/compile_time_saved_sec": "saved_s"}
+
+#: Build records and stages kept (a serving process builds a few dozen
+#: programs; the sums by name stay exact past the bound).
+_MAX_RECORDS = 4096
+
+_SUMMED = ("trace_s", "lower_s", "compile_s", "retrieval_s")
 
 
-def _on_compile_event(event: str, duration: float, **kw) -> None:
-    if event != _COMPILE_EVENT:
-        return
-    with _compile_lock:
-        _compile_stats["count"] += 1
-        _compile_stats["seconds"] += float(duration)
+def program_name(fun_name: str) -> str:
+    """`jit(chunk)`, as JAX 0.9 names a program to its listeners, in the
+    form its module carries on the device and in a trace: `jit_chunk`."""
+    if fun_name.startswith("jit(") and fun_name.endswith(")"):
+        return "jit_" + re.sub(r"\W", "_", fun_name[4:-1])
+    return fun_name
+
+
+class SetupAccount:
+    """A process's stages and builds. Events of one build arrive on the
+    thread that builds, in the order trace, lowering, backend compile (each
+    when it ENDS; the cache's events inside the compile), so everything
+    still open is kept per thread."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._here = threading.local()
+        self.process_start = time.time()
+        self.stages: collections.deque = collections.deque(
+            maxlen=_MAX_RECORDS)
+        self.builds: collections.deque = collections.deque(
+            maxlen=_MAX_RECORDS)
+        self.by_name: dict[str, dict] = {}
+        self.compile_count = 0
+        self.compile_s = 0.0
+        self._engine_up = False  # `engine.init` has ended: the file exists
+        self._file_lock = threading.Lock()  # one writer of the file at a time
+
+    def _thread(self):
+        h = self._here
+        if not hasattr(h, "traces"):
+            h.traces, h.lower, h.cache = {}, None, {}
+            h.stages, h.ctx, h.call = [], None, None
+        return h
+
+    # ------------------------------------------------------------- events
+    def on_time_span(self, event: str, start: float, end: float,
+                     fun_name: str = "", **_kw) -> None:
+        if event == _TRACE_EVENT:
+            # The last trace of each name since the thread's last lowering:
+            # a trace ends after the traces of what it calls, so the jit's
+            # own is the last of its name. One that is never lowered (its
+            # jaxpr met a compiled program) goes with the next lowering.
+            self._thread().traces[fun_name] = (fun_name, start, end)
+        elif event == _LOWER_EVENT:
+            h = self._thread()
+            trace = h.traces.get(fun_name[4:-1])  # "jit(<name>)"
+            h.traces = {}  # and what the lowering itself traced
+            if trace is not None and trace[2] > start:
+                trace = None
+            h.lower = (fun_name, start, end, trace)
+        elif event == _COMPILE_EVENT:
+            self._build_ended(fun_name, start, end)
+
+    def on_event(self, event: str, **_kw) -> None:
+        what = _CACHE_EVENTS.get(event)
+        if what:
+            self._thread().cache["cache"] = what
+
+    def on_duration(self, event: str, duration: float, **_kw) -> None:
+        key = _CACHE_SECONDS.get(event)
+        if key:
+            self._thread().cache[key] = float(duration)
+
+    def _build_ended(self, fun_name: str, start: float, end: float) -> None:
+        h = self._thread()
+        lower, h.lower = h.lower, None
+        cache, h.cache = h.cache, {}
+        if lower is not None and (lower[0] != fun_name or lower[2] > start):
+            lower = None  # another program's, lowered and never compiled
+        trace = lower[3] if lower else None
+        rec = {"fun_name": program_name(fun_name),
+               "a": (trace or lower or (None, start))[1], "b": end,
+               "trace_s": trace[2] - trace[1] if trace else 0.0,
+               "lower_s": lower[2] - lower[1] if lower else 0.0,
+               "compile_s": end - start,
+               # `off`: the cache held nothing of it and was given nothing
+               # (no directory, or a compile under its owner's floor)
+               "cache": cache.get("cache", "off"),
+               "retrieval_s": cache.get("retrieval_s", 0.0),
+               "saved_s": cache.get("saved_s", 0.0),
+               "stage": h.stages[-1]["n"] if h.stages else None}
+        with self._lock:
+            self.compile_count += 1
+            self.compile_s += rec["compile_s"]
+            self.builds.append(rec)
+            tot = self.by_name.get(rec["fun_name"])
+            if tot is None:
+                tot = self.by_name[rec["fun_name"]] = {
+                    "builds": 0, "hits": 0, "misses": 0,
+                    **dict.fromkeys(_SUMMED, 0.0)}
+            tot["builds"] += 1
+            tot["hits"] += rec["cache"] == "hit"
+            tot["misses"] += rec["cache"] == "miss"
+            for k in _SUMMED:
+                tot[k] += rec[k]
+        if not tracing.enabled():
+            return
+        ctx = h.stages[-1].get("ctx") if h.stages else h.ctx
+        if h.call is not None:
+            rec["ctx"] = ctx  # its span waits for `builds_ready`
+            h.call[1].append(rec)
+        else:
+            self._build_span(rec, ctx)
+        self.write()
+
+    # ------------------------------------------------------------- stages
+    @contextlib.contextmanager
+    def stage(self, name: str, **attrs):
+        """A stage of the process's set-up: its start and end are kept
+        whether tracing is on or off, and with tracing on it is a span of
+        kind `setup`, a child of the stage it lies in (else of the thread's
+        build context, else a root)."""
+        h = self._thread()
+        rec = {"n": name, "a": time.time(), "b": None,
+               "p": h.stages[-1]["n"] if h.stages else None}
+        if attrs:
+            rec["at"] = attrs
+        ctx = None
+        if tracing.enabled():
+            up = h.stages[-1].get("ctx") if h.stages else h.ctx
+            ctx = rec["ctx"] = (up[0] if up else tracing._new_id(16),
+                                tracing._new_id(8))  # what children hang on
+        h.stages.append(rec)
+        try:
+            yield
+        finally:
+            h.stages.pop()
+            rec["b"] = time.time()
+            rec.pop("ctx", None)
+            with self._lock:
+                self.stages.append(rec)
+            if ctx is not None:
+                tracing.record_span(ctx[0], ctx[1], up[1] if up else None,
+                                    name, "setup", rec["a"], rec["b"],
+                                    attrs or None)
+                self._engine_up = self._engine_up or name == "engine.init"
+                self.write()
+
+    def stage_seconds(self) -> dict:
+        """name -> seconds of the stages that have ended (the last of a
+        name, should one be run again)."""
+        with self._lock:
+            return {s["n"]: s["b"] - s["a"] for s in self.stages}
+
+    # -------------------------------------------- the engine's part (traced)
+    # Called only under the engine's `_tracing.enabled()` branches: what
+    # JAX cannot know of a build is the call it happened in and when that
+    # call's result was first on the host.
+    def begin_call(self, ctx: Optional[tuple]) -> None:
+        """A jitted call begins on this thread: a build inside it is a
+        child of `ctx`, a wire (trace_id, span_id), and so is every later
+        build of the thread until the next call."""
+        self.close_call()
+        h = self._thread()
+        h.ctx, h.call = ctx, (time.time(), [])
+
+    def close_call(self) -> None:
+        """End the thread's open call, if any, whose result nobody reads
+        (the hand-over program's: its builds get no `ready_s`)."""
+        built = self.end_call()
+        if built:
+            self.builds_ready(built, None)
+
+    def end_call(self, **attrs) -> Optional[list]:
+        """The call has returned. The builds inside it, each with `call_a`
+        (the call's start on the wall clock: builds of one call share it),
+        `call_s` and `attrs`; None where it built nothing. They go to
+        `builds_ready` once the call's result is on the host."""
+        h = self._thread()
+        call, h.call = h.call, None
+        if not call or not call[1]:
+            return None
+        now = time.time()
+        with self._lock:  # `write` reads the records on other threads
+            for rec in call[1]:
+                rec.update(attrs, call_a=call[0], call_s=now - call[0])
+        return call[1]
+
+    def builds_ready(self, built: list, ready: Optional[float]) -> None:
+        """The result of the call that built `built` was on the host at
+        wall time `ready` (None: it is never read): their spans are
+        recorded and the file rewritten."""
+        for rec in built:
+            with self._lock:
+                if ready is not None:
+                    rec["ready_s"] = ready - rec["call_a"]
+                ctx = rec.pop("ctx", None)
+            self._build_span(rec, ctx)
+        self.write()
+
+    @staticmethod
+    def _build_span(rec: dict, ctx: Optional[tuple]) -> None:
+        tracing.record_span_in(
+            ctx, "program.build", "engine", rec["a"], rec["b"],
+            {k: v for k, v in rec.items() if k not in ("a", "b", "ctx")})
+
+    # ------------------------------------------------------------- views
+    def summary(self) -> dict:
+        """For /v1/stats `setup`: the stages' seconds, and per program name
+        the builds, the cache's hits and misses and the summed parts."""
+        with self._lock:
+            programs = {name: {k: round(v, 4) if isinstance(v, float) else v
+                               for k, v in tot.items()}
+                        for name, tot in self.by_name.items()}
+        return {"stages": {k: round(v, 3)
+                           for k, v in self.stage_seconds().items()},
+                "builds": sum(p["builds"] for p in programs.values()),
+                "programs": programs}
+
+    def one_line(self) -> str:
+        """builds, hits, misses and the four sums, for a builder's eye."""
+        with self._lock:
+            tots = list(self.by_name.values())
+        return (f"{sum(t['builds'] for t in tots)} builds "
+                f"({sum(t['hits'] for t in tots)} cache hits, "
+                f"{sum(t['misses'] for t in tots)} misses): " + ", ".join(
+                    f"{k} {sum(t[k] for t in tots):.2f}" for k in _SUMMED))
+
+    def write(self) -> None:
+        """The account as a file, for readers outside the process (tracing
+        on only; not before `engine.init` has ended: a process that serves
+        no engine writes none)."""
+        if not self._engine_up:
+            return
+        d = os.path.join(CONFIG.session_dir, "setup")
+        with self._file_lock:  # the lane and the scheduler both build
+            with self._lock:
+                doc = {"pid": os.getpid(),
+                       "process_start": self.process_start,
+                       "written": time.time(),
+                       "compile_count": self.compile_count,
+                       "compile_s": self.compile_s,
+                       "stages": list(self.stages),
+                       "builds": [{k: v for k, v in b.items() if k != "ctx"}
+                                  for b in self.builds]}
+            try:
+                os.makedirs(d, exist_ok=True)
+                tmp = os.path.join(d, f".{doc['pid']}.tmp")
+                with open(tmp, "w") as f:
+                    json.dump(doc, f)
+                os.replace(tmp, os.path.join(d, f"{doc['pid']}.json"))
+            except OSError:
+                pass  # a full or read-only disk must not fail a build
+
+
+ACCOUNT = SetupAccount()
+setup_stage = ACCOUNT.stage
+_listeners_installed = False
+_install_lock = threading.Lock()
 
 
 def ensure_compile_listener() -> bool:
-    """Register the compile-duration listener iff jax is ALREADY imported.
-    Returns True once installed. Compiles that happened before the first
-    armed sample are not counted (the listener cannot observe the past)."""
-    global _compile_listener_installed
-    if _compile_listener_installed:
+    """Register the account's listeners iff jax is ALREADY imported.
+    Returns True once installed. Builds that happened before are not
+    counted (the listeners cannot observe the past). ONCE, whoever asks:
+    the sampler's thread asks on every tick and a server's constructor
+    asks too, and a listener registered twice counts every build twice
+    (PERF.md section 6, PR 56). A jax still being imported by another
+    thread has no `monitoring` yet: not installed, and nobody waits for it."""
+    global _listeners_installed
+    if _listeners_installed:
         return True
-    jax = sys.modules.get("jax")
-    if jax is None:
+    monitoring = getattr(sys.modules.get("jax"), "monitoring", None)
+    if monitoring is None:
         return False
-    try:
-        jax.monitoring.register_event_duration_secs_listener(_on_compile_event)
-    except Exception:
-        return False
-    _compile_listener_installed = True
+    with _install_lock:
+        if _listeners_installed:
+            return True
+        try:
+            monitoring.register_event_time_span_listener(
+                ACCOUNT.on_time_span)
+            monitoring.register_event_listener(ACCOUNT.on_event)
+            monitoring.register_event_duration_secs_listener(
+                ACCOUNT.on_duration)
+        except Exception:
+            return False
+        _listeners_installed = True
     return True
 
 
 def compile_stats() -> dict:
-    with _compile_lock:
-        return dict(_compile_stats)
+    """Count and summed seconds of the backend-compile events so far."""
+    with ACCOUNT._lock:
+        return {"count": ACCOUNT.compile_count,
+                "seconds": ACCOUNT.compile_s}
 
 
 # ------------------------------------------------------- worker-side sampler

@@ -183,13 +183,16 @@ def record_span(trace_id: str, span_id: str, parent: Optional[str],
 
 def record_span_in(wire_ctx: Optional[tuple], name: str, kind: str,
                    start: float, end: float,
-                   attrs: Optional[dict] = None) -> None:
+                   attrs: Optional[dict] = None,
+                   span_id: Optional[str] = None) -> None:
     """Record a span parented to an explicit wire context — for threads that
-    carry no contextvar (the llm engine scheduler, the checkpoint writer)."""
+    carry no contextvar (the llm engine scheduler, the checkpoint writer).
+    `span_id`: the id it was promised under, where children were recorded
+    before it ended (a request's `engine.prefill` and the builds in it)."""
     if wire_ctx is None or not enabled():
         return
-    record_span(wire_ctx[0], _new_id(8), wire_ctx[1], name, kind, start, end,
-                attrs)
+    record_span(wire_ctx[0], span_id or _new_id(8), wire_ctx[1], name, kind,
+                start, end, attrs)
 
 
 def record_instant(wire_ctx: Optional[tuple], name: str, kind: str,

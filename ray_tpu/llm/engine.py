@@ -74,7 +74,7 @@ from typing import Optional
 
 import numpy as np
 
-from ray_tpu._private import compile_cache, tracing as _tracing
+from ray_tpu._private import compile_cache, telemetry, tracing as _tracing
 from ray_tpu.llm.sampler import _make_sampler, _sampler_path
 from ray_tpu.models.published import model_config
 
@@ -135,6 +135,12 @@ class GenStream:
     surface on top of the same queue."""
 
     _DONE = object()
+    #: Set only while `trace` is: the id its `engine.prefill` span will
+    #: carry, so that a program built for it can be recorded as that span's
+    #: child before it ends, and the builds whose call's result its first
+    #: token is (the set-up account, README "Tracing & timeline").
+    prefill_span: Optional[str] = None
+    _built: Optional[list] = None
 
     def __init__(self, request_id: int, prompt_len: int):
         self.request_id = request_id
@@ -473,24 +479,28 @@ class ContinuousEngine:
                 lambda x: x.astype(jnp.bfloat16)
                 if x.dtype == jnp.float32 else x, params)
 
-        if cfg.params is not None:
-            params = serving_dtype(
-                cfg.params["params"] if "params" in cfg.params else cfg.params)
-        else:
-            # One program makes each leaf and casts it: the float32 tree is
-            # never held whole beside its bf16 copy (6 bytes a parameter),
-            # only a leaf at a time. The values are those of an eager init
-            # followed by the cast.
-            self._make_params = jax.jit(lambda key: serving_dtype(
-                self.model.init(key, jnp.zeros((1, 8), jnp.int32))["params"]))
-            params = self._make_params(jax.random.PRNGKey(cfg.seed))
-        if mesh is not None:
-            params = self._shard_params(params, mesh)
+        with telemetry.setup_stage("engine.params"):
+            if cfg.params is not None:
+                params = serving_dtype(
+                    cfg.params["params"] if "params" in cfg.params
+                    else cfg.params)
+            else:
+                # One program makes each leaf and casts it: the float32 tree
+                # is never held whole beside its bf16 copy (6 bytes a
+                # parameter), only a leaf at a time. The values are those of
+                # an eager init followed by the cast.
+                self._make_params = jax.jit(lambda key: serving_dtype(
+                    self.model.init(
+                        key, jnp.zeros((1, 8), jnp.int32))["params"]))
+                params = self._make_params(jax.random.PRNGKey(cfg.seed))
+            if mesh is not None:
+                params = self._shard_params(params, mesh)
         self.params = params
         self._sampler = _make_sampler(cfg.vocab_size)
         self._jax = jax
         self._jnp = jnp
-        self._build_compiled()
+        with telemetry.setup_stage("engine.programs"):
+            self._build_compiled()
 
         # Host scheduler state.
         self._lock = threading.Condition()
@@ -524,6 +534,7 @@ class ContinuousEngine:
         self._prefills_since: collections.deque = collections.deque()
         self._prefill_in_call = False
         self._splices_at_chunk = 0
+        self._built_at: dict = {}  # chunk ordinal -> programs built for it
         self._toks_dev = jnp.zeros(max_batch, jnp.int32)
         self._lens_dev = jnp.zeros(max_batch, jnp.int32)
         # Every GenStream not yet _DONE, independent of slot state: the
@@ -1122,7 +1133,8 @@ class ContinuousEngine:
             return (z if leaf.sharding is None
                     else jax.device_put(z, leaf.sharding))
 
-        return jax.tree.map(zeros, self._cache_spec)
+        with telemetry.setup_stage("engine.cache_alloc"):
+            return jax.tree.map(zeros, self._cache_spec)
 
     # -------------------------------------------------------------- public
     def submit(self, prompt_tokens, sampling: Optional[SamplingParams] = None
@@ -1139,6 +1151,7 @@ class ContinuousEngine:
         stream = GenStream(next(self._req_counter), len(prompt))
         if _tracing.enabled():
             stream.trace = _tracing.current()
+            stream.prefill_span = _tracing._new_id(8)
         # The _running check and the enqueue must be ONE atomic step
         # against shutdown()'s flag flip: a submit that slips between the
         # check and the put could otherwise queue a stream after the
@@ -1367,11 +1380,18 @@ class ContinuousEngine:
             if account:
                 done0 = self._chunks_done
                 self._prefill_in_call = True
+                # The set-up account: a program built in one of this
+                # request's calls is a child of its `engine.prefill`.
+                ctx = stream.trace and (stream.trace[0], stream.prefill_span)
+                telemetry.ACCOUNT.begin_call(ctx)
             try:
                 last_logits, cache_slice = self._prefill(
                     self.params, toks_dev, plen)
                 if account:
                     self._prefills_since.append(lb)
+                    built = telemetry.ACCOUNT.end_call(
+                        bucket=lb, kernel=self._prefill_form(lb) == "kernel")
+                    telemetry.ACCOUNT.begin_call(ctx)  # key and first token
             finally:
                 if account:
                     self._prefill_in_call = False
@@ -1400,6 +1420,9 @@ class ContinuousEngine:
         if account:
             # the last chunk surely enqueued before the prefill
             attrs["after_seq"] = done0 - 1
+            # what was built for it waits for its first token's read
+            stream._built = (built or []) + (
+                telemetry.ACCOUNT.end_call(bucket=lb) or [])
         if self._state_rw_bytes:
             # a state layer's prefill is a scan over chunks of the bucket
             mcfg = self.model.cfg
@@ -1413,7 +1436,7 @@ class ContinuousEngine:
             attrs.update(windows=-(-lb // self._window),
                          summaries=plen // self.model.cfg.eva_chunk)
         _tracing.record_span_in(stream.trace, "engine.prefill", "engine",
-                                t_adm, t_end, attrs)
+                                t_adm, t_end, attrs, stream.prefill_span)
         return first, cache_slice, self._jax.random.fold_in(key, 1)
 
     def _prefill_loop(self):
@@ -1489,6 +1512,9 @@ class ContinuousEngine:
                        active=self._n_active)
             _stage_begin(stream, now, slot=slot,
                          chunks_in_flight=len(self._q_chunks))
+            # the hand-over program, and the cache at the first hand-over
+            telemetry.ACCOUNT.begin_call(
+                (stream.trace[0], stream.prefill_span))
         if self._cache is None:
             self._cache = self._init_cache()
         mirrors = (self._toks_dev, self._lens_dev, self._keys,
@@ -1765,6 +1791,7 @@ class ContinuousEngine:
                 seq = self._chunks_begun
                 self._chunks_begun = seq + 1
                 ahead = self._account_ahead()
+                telemetry.ACCOUNT.begin_call(tctx)
             try:
                 t_disp = time.time()
                 self._cache, self._keys, toks_out, lens_out = \
@@ -1828,6 +1855,12 @@ class ContinuousEngine:
                         # chunk, and the next chunk counts it ahead of
                         # itself. Said, not guessed.
                         attrs["prefills_beside"] = True
+                    # a chunk program built in the call waits for the read
+                    # of this chunk's block
+                    built = telemetry.ACCOUNT.end_call(
+                        tokens=n, sampler=path, kernel=form == "kernel")
+                    if built:
+                        self._built_at[seq] = built
                 for kind, (walked, read, visible) in rows.items():
                     attrs["kv_rows_" + kind] = walked
                     attrs["kv_live_" + kind] = round(visible, 2)
@@ -1883,6 +1916,11 @@ class ContinuousEngine:
             sync_ctx = next(
                 (st.stream.trace for st in owed
                  if not st.done and st.stream.trace is not None), None)
+            # The set-up account: a hand-over's call ends here at the
+            # latest, and nobody waits for a chunk past its occupants' ends.
+            telemetry.ACCOUNT.close_call()
+            if sync_ctx is None and seq in self._built_at:
+                telemetry.ACCOUNT.builds_ready(self._built_at.pop(seq), None)
         t_sync = ph.begin("sync") if ph is not None else time.time()
         # The account (a traced sync only): the instant each of the two
         # reads returned, and whether it had to wait. A read that waited
@@ -1929,6 +1967,13 @@ class ContinuousEngine:
                 for st, _f in firsts:
                     if st.stream._stage is not None:
                         st.stream._stage[1]["sync_seq"] = seq
+            # The set-up account: the programs built in the calls whose
+            # results these reads brought to the host.
+            for built, ready in [
+                    (self._built_at.pop(seq, None), "block_ready")] + [
+                    (st.stream._built, "firsts_ready") for st, _f in firsts]:
+                if built:
+                    telemetry.ACCOUNT.builds_ready(built, acct.get(ready))
             try:
                 from ray_tpu.util import metrics as _metrics
 

@@ -96,18 +96,17 @@ class OpenAIServer:
         self.default_max_tokens = default_max_tokens
         self.tok = ByteTokenizer()
         self._served = 0
-        # Set-up times, reported by /v1/stats: starting the device runtime
-        # (the first call that needs a device) and building the engine
-        # (parameters made and placed; the serving programs compile on the
-        # first request of each shape).
+        # Set-up, told by the process's account (telemetry.py; /v1/stats
+        # reads it): starting the device runtime (the first call that needs
+        # a device) and building the engine (parameters made and placed;
+        # the serving programs compile on the first request of each shape).
         import jax
 
         from ray_tpu._private import telemetry
 
         telemetry.ensure_compile_listener()
-        t0 = time.monotonic()
-        jax.local_devices()
-        self._runtime_init_s = time.monotonic() - t0
+        with telemetry.setup_stage("runtime.init"):
+            jax.local_devices()
         # pipeline_stages > 1 swaps in the pipeline-parallel engine
         # (README "Pipeline-parallel serving"); None defers to RT_PP_STAGES
         # so a deployment can be re-pointed without a code change. The two
@@ -117,15 +116,15 @@ class OpenAIServer:
 
         stages = (int(CONFIG.pp_stages) if pipeline_stages is None
                   else int(pipeline_stages))
-        if stages > 1:
-            from ray_tpu.llm.pipeline import PipelinedEngine
+        with telemetry.setup_stage("engine.init"):
+            if stages > 1:
+                from ray_tpu.llm.pipeline import PipelinedEngine
 
-            self.engine = PipelinedEngine(
-                cfg, n_stages=stages, max_batch=max_batch)
-        else:
-            self.engine = ContinuousEngine(
-                cfg, max_batch=max_batch, decode_chunk=decode_chunk)
-        self._engine_init_s = time.monotonic() - t0 - self._runtime_init_s
+                self.engine = PipelinedEngine(
+                    cfg, n_stages=stages, max_batch=max_batch)
+            else:
+                self.engine = ContinuousEngine(
+                    cfg, max_batch=max_batch, decode_chunk=decode_chunk)
 
     # ------------------------------------------------------------ helpers
     def _encode_prompt(self, body: dict) -> list[int]:
@@ -168,11 +167,14 @@ class OpenAIServer:
             # `served` counts the requests this replica has taken, and the
             # device fields are what JAX reports inside this process — the
             # proof of which chip a replica really runs on.
+            from ray_tpu._private import telemetry
+
+            setup = telemetry.ACCOUNT.summary()
             out = {"pid": os.getpid(), "active": self.engine.num_active,
                    "running": self.engine._running, "served": self._served,
-                   "runtime_init_s": round(self._runtime_init_s, 3),
-                   "engine_init_s": round(self._engine_init_s, 3),
-                   **_device_report()}
+                   "runtime_init_s": setup["stages"]["runtime.init"],
+                   "engine_init_s": setup["stages"]["engine.init"],
+                   "setup": setup, **_device_report()}
             stages = getattr(self.engine, "n_stages", 0)
             if stages:
                 out["pipeline_stages"] = stages

@@ -18,6 +18,8 @@ import threading
 import time
 from typing import Any, Optional
 
+from ray_tpu._private import telemetry
+
 #: Model id of the request currently being handled (reference
 #: serve.get_multiplexed_model_id / _serve_request_context).
 _multiplexed_model_id: contextvars.ContextVar[str] = contextvars.ContextVar(
@@ -56,7 +58,13 @@ class Replica:
         self.deployment = deployment
         self.replica_id = replica_id
         if isinstance(callable_or_class, type):
-            self.callable = callable_or_class(*init_args, **(init_kwargs or {}))
+            # The replica's boundary in the process's set-up account
+            # (README "Tracing & timeline"): any deployment has this stage,
+            # and what its constructor does lies inside it.
+            with telemetry.setup_stage("replica.start", deployment=deployment,
+                                       replica_id=replica_id):
+                self.callable = callable_or_class(
+                    *init_args, **(init_kwargs or {}))
         else:
             self.callable = callable_or_class
         self.ongoing = 0

@@ -64,6 +64,7 @@ def main() -> int:
     import jax
 
     from benchmark import manifest, traffic
+    from ray_tpu._private import telemetry
     from ray_tpu.ops import attention
     from ray_tpu.ops import expert_decode
 
@@ -85,6 +86,7 @@ def main() -> int:
     reference = manifest.load_module(config["reference"])
     params = reference.served_params(llm)
     reference.served_params = lambda llm: params
+    telemetry.ensure_compile_listener()  # the engine's builds, not the tree's
     t0 = time.monotonic()
     cases, stats = serve(config, params, bodies)
     print(f"served in {time.monotonic() - t0:.0f} s; "
@@ -92,6 +94,9 @@ def main() -> int:
                       if k.startswith(("moe_", "decode_steps",
                                        "prefill_rows"))),
           flush=True)
+    # What the programs cost this start (README "Tracing & timeline", the
+    # set-up account): a warm start's `compile_s` is the cache's read.
+    print(f"set-up account: {telemetry.ACCOUNT.one_line()}", flush=True)
     jax.clear_caches()
     t0 = time.monotonic()
     res = reference.check(llm, cases)
