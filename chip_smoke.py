@@ -577,7 +577,8 @@ def serve_phase(serve, LLMConfig, build_openai_app, chips: int) -> dict:
             f"(live rows {st['kv_live_share']:.3f}); {st['splices']} "
             f"hand-overs of a batch row, {st['splices_in_flight']} behind "
             f"a chunk in flight, {st['pipeline_dry']} passes began with "
-            f"nothing in flight; {st['prefill_rows']} rows of prefill "
+            f"nothing in flight, {st['cover_chunks']} cover chunks; "
+            f"{st['prefill_rows']} rows of prefill "
             f"buckets, {st['prefill_rows_kernel']} of them through the "
             f"flash kernel; {st['decode_steps']} decode steps, "
             f"{st['decode_steps_kernel']} of them through the ragged "

@@ -240,7 +240,7 @@ class _Slot:
     request while chunks that stepped this one are still in flight."""
 
     __slots__ = ("slot", "stream", "sampling", "remaining", "emitted",
-                 "in_flight", "done")
+                 "in_flight", "done", "covered")
 
     def __init__(self, slot: int, stream: GenStream,
                  sampling: SamplingParams):
@@ -251,6 +251,7 @@ class _Slot:
         self.emitted = 0
         self.in_flight = 0  # decode steps dispatched for it and not read
         self.done = False   # its stream has ended (`_retire`)
+        self.covered = False  # a cover chunk went past its unread last step
 
 
 # ------------------------------------------------------ engine tracing
@@ -521,7 +522,8 @@ class ContinuousEngine:
         # the occupants it stepped; first tokens dispatched by the prefill
         # lane and not read; and device-resident next-token/length mirrors
         # so steady-state chunk dispatch needs NO host->device transfer.
-        self._q_chunks: list = []  # [(tokens_device, occupants, n, seq)]
+        # [(tokens_device, occupants, n, cover, seq)]
+        self._q_chunks: list = []
         self._pending_firsts: list = []  # [(occupant, first_token_device)]
         # The device's account (RT_TRACING=1 only; README "Tracing &
         # timeline"): the chunks whose dispatch has begun and ended, the
@@ -622,11 +624,14 @@ class ContinuousEngine:
         self._kv_live = dict.fromkeys(_KINDS[:3], 0.0)
         # Hand-overs of a batch row since start, those dispatched behind at
         # least one decode chunk still in flight, and the scheduler's
-        # passes that began with occupants seated and no chunk in flight:
-        # the pipeline drained to a retirement (`cache_stats`).
+        # passes that began with occupants seated and no chunk in flight
+        # (the pipeline drained to a retirement), and the cover chunks: one
+        # step for the occupants who go on, dispatched past a known last
+        # step that a parked request waits behind (`cache_stats`).
         self.splices = 0
         self.splices_in_flight = 0
         self.pipeline_dry = 0
+        self.cover_chunks = 0
         # Rows of the prefill buckets dispatched since start, and those of
         # buckets whose program's attention is a Pallas kernel
         # (`_prefill_form`).
@@ -1016,7 +1021,12 @@ class ContinuousEngine:
         hand-overs (`splices`), those whose program was dispatched behind
         at least one decode chunk in flight (`splices_in_flight`), and
         the scheduler's passes that began with occupants seated and no
-        chunk in flight (`pipeline_dry`); and the rows of the prefill
+        chunk in flight (`pipeline_dry`), and the one-step chunks
+        dispatched past an occupant's known last step for the occupants who
+        go on, a parked request waiting for the row (`cover_chunks`: at
+        saturation about one a hand-over, `splices_in_flight` near
+        `splices` and `pipeline_dry` near none; below capacity none); and
+        the rows of the prefill
         buckets dispatched (`prefill_rows`) beside those whose program's
         attention is a Pallas kernel (`prefill_rows_kernel`); and the
         decode steps dispatched in the sampled program (`sampler_steps`)
@@ -1055,6 +1065,7 @@ class ContinuousEngine:
                "splices": self.splices,
                "splices_in_flight": self.splices_in_flight,
                "pipeline_dry": self.pipeline_dry,
+               "cover_chunks": self.cover_chunks,
                "prefill_rows": self.prefill_rows,
                "prefill_rows_kernel": self.prefill_rows_kernel,
                "sampler_steps": self.sampler_steps,
@@ -1620,18 +1631,31 @@ class ContinuousEngine:
 
         How a batch row changes hands: through ONE device program
         (`_splice`), where its occupant's last token is READ. A chunk is cut
-        to the fewest steps any seated occupant still needs, so once
-        somebody's last step is in flight nothing more is dispatched until
-        it is read; the newcomer then joins a pipeline that has drained to
-        that point, and its first token and its first chunk's tokens reach
-        the client one drain apart. (Handing the row on at DISPATCH time,
+        to the fewest steps any seated occupant still needs, and nobody is
+        stepped past a known end that nobody waits behind: with no request
+        parked the pipeline drains to that chunk, and an arrival's prefill
+        queues behind nothing. With a request parked in `_ready` (every row
+        is taken: it waits for this one) ONE cover chunk goes past the end:
+        one step, the shortest program there is, for the occupants who go
+        on, so that the device steps them while the host reads the block,
+        hands out its tokens, retires the occupant and splices the
+        newcomer. The cover records only those occupants; the finished row
+        steps in it as a free row does (`live` off: what it decodes is
+        handed to nobody), and its `kv_bound` is theirs alone. No second
+        chunk goes past an end that has not been read (`_Slot.covered`), so
+        the host's reads pace the chain and the newcomer's `place` queues
+        behind one step at most. The newcomer's first token is read with
+        the first chunk that steps it, not with the cover (`_drain`): the
+        two reach the client in one drain, as they do after a hand-over
+        into a drained pipeline. (Handing the row on at DISPATCH time,
         with the pipeline kept at its depth, was measured and left out:
         the newcomer's first step then queues behind up to three chunks,
-        PERF.md section 6, PR 31.) A chunk records its occupants
-        (`_Slot`), not their rows, so a row given up early (a stop token,
-        a consumer gone) is the next request's at once, whatever chunks
-        still step it: device program order alone protects the cache
-        (place/chunk chain through the cache handle and the mirrors).
+        PERF.md section 6, PR 31; the cover is PR 57's.) A chunk records
+        its occupants (`_Slot`), not their rows, so a row given up early (a
+        stop token, a consumer gone) is the next request's at once,
+        whatever chunks still step it: device program order alone protects
+        the cache (place/chunk chain through the cache handle and the
+        mirrors).
 
         One pass: `_fill_pipeline` with hand-overs and decode chunks,
         `_drain` the oldest chunk."""
@@ -1742,29 +1766,47 @@ class ContinuousEngine:
         occupants seated, until PIPELINE_DEPTH chunks are in flight
         (dispatches are asynchronous and nearly free; only the readback
         costs a round trip). A request that is parked while the loop runs
-        joins before its next chunk. Returns the requests spliced, the chunks
-        dispatched and the first traced request a chunk's span was bound
-        to, if any. Entered in phase `admit`."""
+        joins before its next chunk. Past an occupant's known last step
+        nothing is dispatched until that step is read, but for ONE cover
+        chunk: one step for the occupants who go on, and only while a
+        parked request waits for the row (`_run_scheduler`). Returns the
+        requests spliced, the chunks dispatched and the first traced
+        request a chunk's span was bound to, if any. Entered in phase
+        `admit`."""
         max_seq = self.cfg.max_seq
         iter_ctx = None
         spliced = dispatched = 0
         while True:
             spliced += self._admit()
-            active = [s for s in self._slots if s is not None]
-            if not active or len(self._q_chunks) >= PIPELINE_DEPTH:
+            seated = [s for s in self._slots if s is not None]
+            if not seated or len(self._q_chunks) >= PIPELINE_DEPTH:
+                break
+            # Who still needs a step; the others' known last step is in
+            # flight, and each one's row changes hands where that is read.
+            ended = [s for s in seated if s.remaining - s.in_flight < 1]
+            active = [s for s in seated if s not in ended]
+            cover = bool(ended)
+            if not active or cover and (
+                    not self._ready or any(s.covered for s in ended)):
+                # Nobody is stepped past an end that nobody waits behind
+                # (every row that was free has been given out: whoever is
+                # still parked waits for this one), and no second chunk
+                # goes past an end that has not been read: the host's
+                # reads pace the chain, and a newcomer's `place` queues
+                # behind one step at most.
                 break
             live = [int(self._lengths[s.slot]) for s in active]
             budget = int(min(min(s.remaining - s.in_flight for s in active),
                              max_seq - max(live)))
             if budget < 1:
-                # Somebody's last step is in flight: its row changes hands
-                # where that is read, and nobody is stepped past it.
-                break
+                break  # a row at max_seq: `submit` refuses what gets there
             # Power-of-2 chunk sizes only: each distinct scan length
             # is its own compiled program, and an arbitrary shrinking
-            # budget would recompile on nearly every call.
-            n = max(1, min(self.decode_chunk,
-                           1 << (budget.bit_length() - 1)))
+            # budget would recompile on nearly every call. The cover is
+            # the shortest of them: it has the host's pass to cover, and
+            # the newcomer's first step waits behind it.
+            n = 1 if cover else max(1, min(
+                self.decode_chunk, 1 << (budget.bit_length() - 1)))
             path = _sampler_path(s.sampling for s in active)
             live_rows = np.zeros(self.max_batch, bool)
             live_rows[[s.slot for s in active]] = True
@@ -1845,6 +1887,10 @@ class ContinuousEngine:
                     # each of a layer's leaves is walked once a step: what
                     # the rows below say of ONE leaf holds `ut_steps` times
                     attrs["ut_steps"] = self._passes
+                if cover:
+                    # the occupants who go on, stepped while the host
+                    # reads the others' last step (said only where true)
+                    attrs["cover"] = True
                 if seq is not None:
                     # in_flight 0: the device had no chunk of ours queued
                     attrs.update(ahead, seq=seq,
@@ -1878,11 +1924,15 @@ class ContinuousEngine:
                 self._toks_dev = toks_out[:, n - 1]
                 self._lens_dev = lens_out
                 self._lengths = self._lengths + n
-                self._q_chunks.append((toks_out, active, n, seq))
+                self._q_chunks.append((toks_out, active, n, cover, seq))
                 dispatched += 1
                 iter_ctx = iter_ctx or tctx
                 for s in active:
                     s.in_flight += n
+                if cover:
+                    self.cover_chunks += 1
+                    for s in ended:
+                        s.covered = True
             except Exception as e:
                 logger.exception("llm engine decode chunk failed")
                 self._fail(active, e)
@@ -1896,16 +1946,29 @@ class ContinuousEngine:
     def _drain(self, ph):
         """Phases `sync` and `deliver`: read the OLDEST in-flight chunk
         (plus the first tokens of the hand-overs since the last drain, whose
-        host copies the prefill lane started), leaving the younger chunks
+        host copies the prefill lane started; behind a cover chunk, those
+        of the occupants it stepped), leaving the younger chunks
         executing — the double buffer — and hand the tokens to the
         occupants the chunk recorded. One host_sync per chunk: a request's
         span count is bounded by its CHUNK count, never its token count.
         The chunk's block is read first and the first tokens after it, so
         that a traced sync can stamp the block's arrival alone.
         Returns the traced request the sync's span is bound to, if any."""
-        toks_dev, occupants, n, seq = (
-            self._q_chunks.pop(0) if self._q_chunks else (None, [], 0, None))
+        toks_dev, occupants, n, cover, seq = (
+            self._q_chunks.pop(0) if self._q_chunks
+            else (None, [], 0, False, None))
         firsts, self._pending_firsts = self._pending_firsts, []
+        if cover:
+            # A hand-over behind a cover: the cover did not step the
+            # newcomer, whose first token is read with the first chunk that
+            # did, as it is after a hand-over into a drained pipeline.
+            # (Read here it would start the client's clock between two
+            # tokens a step or more early; ROADMAP S2 (d).)
+            self._pending_firsts = [
+                (st, f) for st, f in firsts
+                if not (st.done or st in occupants)]
+            firsts = [(st, f) for st, f in firsts
+                      if st.done or st in occupants]
         owed = occupants + [st for st, _f in firsts]
         # The host-sync readback: THE per-iteration host-link round
         # trip the decode loop pays (once one per TOKEN; now one per

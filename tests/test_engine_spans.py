@@ -158,12 +158,13 @@ def test_an_iteration_spans_phases_add_up(engine, spans):
 
 
 def test_the_stats_count_how_rows_changed_hands(engine, spans):
-    """`splices`, `splices_in_flight`, `pipeline_dry` of `cache_stats` (what
-    `/v1/stats` reports) against what the spans saw: the traced stage
-    `engine.first_token` carries the chunks in flight at its hand-over, and
-    a pass that began dry dispatched into an empty pipeline."""
-    keys = ("splices", "splices_in_flight", "pipeline_dry")
-    assert [engine.cache_stats()[k] for k in keys] == [0, 0, 0]
+    """`splices`, `splices_in_flight`, `pipeline_dry`, `cover_chunks` of
+    `cache_stats` (what `/v1/stats` reports) against what the spans saw: the
+    traced stage `engine.first_token` carries the chunks in flight at its
+    hand-over, a pass that began dry dispatched into an empty pipeline, and
+    a cover chunk's `engine.dispatch_chunk` says `cover`."""
+    keys = ("splices", "splices_in_flight", "pipeline_dry", "cover_chunks")
+    assert [engine.cache_stats()[k] for k in keys] == [0, 0, 0, 0]
     serve(engine, 5)
     # a request that joins a neighbour in mid-flight: behind its chunks
     tracing._ctx.set(("c" * 32, "d" * 16))
@@ -180,8 +181,138 @@ def test_the_stats_count_how_rows_changed_hands(engine, spans):
         1 for s in firsts if s["at"]["chunks_in_flight"] > 0) >= 1
     # A row changes hands where its occupant's last token is read: the
     # pipeline has drained to there, and where a neighbour is seated the
-    # next pass begins with nothing in flight.
-    assert 1 <= st["pipeline_dry"] <= st["splices"]
+    # next pass begins with nothing in flight, or with the neighbour's one
+    # cover step if a request was parked for the row.
+    assert 0 <= st["pipeline_dry"] <= st["splices"]
+    chunks = [s["at"] for s in spans if s["n"] == "engine.dispatch_chunk"]
+    assert st["cover_chunks"] == sum(1 for at in chunks if "cover" in at)
+
+
+def saturated(eng, requests, park: int = 4) -> list:
+    """Two rows at saturation, as the spans record it: the first `park`
+    of `requests` [(prompt, max_tokens)] are parked before a row is given
+    out (a quarter of this cache holds 32 rows of slices), the rest are
+    submitted once the first token is out, so that their prefills lie
+    ahead of later chunks. Every request is traced. Returns the streams,
+    drained."""
+    streams = []
+
+    def submit(i, prompt, max_tokens):
+        tracing._ctx.set((f"{i:032x}", f"{i:016x}"))
+        streams.append(eng.submit(prompt, SamplingParams(
+            max_tokens=max_tokens, temperature=0.0)))
+        tracing._ctx.set(None)
+
+    def until(cond, what):
+        deadline = time.monotonic() + WAIT_S
+        while not cond():
+            assert time.monotonic() < deadline, what
+            time.sleep(0.005)
+
+    free, eng._free_slot = eng._free_slot, lambda: None
+    try:
+        for i, request in enumerate(requests[:park]):
+            submit(i, *request)
+        until(lambda: len(eng._ready) == len(requests[:park]),
+              "the prefills")
+    finally:
+        eng._free_slot = free
+    first = streams[0].next(timeout=WAIT_S)
+    for i, request in enumerate(requests[park:], park):
+        submit(i, *request)
+    got = [s.tokens() for s in streams]
+    assert [len(g) + (s is streams[0]) for g, s in zip(got, streams)] == [
+        n for _p, n in requests], first
+    settle(eng)
+    return streams
+
+
+def test_a_cover_chunks_span_says_so_and_carries_its_own_occupants_rows(
+        engine, spans):
+    """A long prompt's last step is in flight, a request is parked, and the
+    neighbour with a short prompt goes on: the cover's
+    `engine.dispatch_chunk` carries `cover` (no other chunk has the key),
+    `tokens` 1, `active` 1, and `kv_bound`, `kv_rows_full`, `kv_live_full`
+    of the short occupant ALONE, though the finished row steps in the
+    program beside it."""
+    from ray_tpu.ops.decode_attention import kv_prefix_rows
+
+    long_prompt = list(range(1, 13))
+    saturated(engine, [(long_prompt, 22), ([5, 6, 7], 40), ([8, 9], 8)],
+              park=3)
+    chunks, _reads, _others, _prefills = account(spans)
+    covers = [c["at"] for c in chunks if "cover" in c["at"]]
+    assert covers and engine.cache_stats()["cover_chunks"] == len(covers)
+    at = covers[0]
+    before = chunks[at["seq"] - 1]["at"]
+    assert at["active"] == 1
+    # the chunk before stepped both, the 21st time: its bound is the long
+    # prompt's rows; the cover's is the short one's, stepped once more
+    assert before["active"] == 2 and before["kv_bound"] == 12 + 21
+    assert at["kv_bound"] == 3 + 21 + 1 == at["kv_live_full"]
+    assert at["kv_rows"] == at["kv_rows_full"] == kv_prefix_rows(
+        at["kv_bound"], CFG["max_seq"]) < before["kv_rows"]
+    assert all(c["at"]["cover"] is True and c["at"]["tokens"] == 1
+               for c in chunks if "cover" in c["at"])
+
+
+def test_the_device_account_pairs_its_intervals_across_cover_chunks(
+        engine, spans):
+    """`benchmark/device_account.py` over a saturated sequence as the engine
+    records it (ordinals, steps, covers, what was enqueued ahead), on a
+    clock of the test's own: 10 ms a step, 4 ms a prefill, 2 ms a `place`,
+    every block read with a wait but a cover's, which the host finds ready
+    (it ran while the host read the chunk before it, and gives no stamp).
+    Every chunk after the first lies in one paired interval, a cover's runs
+    on to the next stamp, the hand-overs behind a cover leave no dry gap,
+    and the four readers of the whole window all speak."""
+    from benchmark import device_account as da, manifest
+
+    saturated(engine, [([1 + i, 2, 3, 4 + i], n) for i, n in enumerate(
+        [21, 30, 27, 25, 22, 26])])
+    chunks, reads, _others, _prefills = account(spans)
+    assert len(reads) == len(chunks)
+    covers = {c["at"]["seq"] for c in chunks if "cover" in c["at"]}
+    assert len(covers) >= 2
+    laid, now = [], 1.0
+    for c, r in zip(chunks, sorted(reads, key=lambda r: r["at"]["seq"])):
+        at = c["at"]
+        ahead = sum(da.parse_buckets(at["prefill_buckets_ahead"]).values())
+        if not at["in_flight"]:
+            now += 0.004  # the host's pass, the device standing still
+        start, now = now, (now + 0.004 * ahead + 0.002 * at["places_ahead"]
+                           + 0.010 * at["tokens"])
+        laid.append({**c, "pid": 1, "a": start - 0.02 * at["in_flight"],
+                     "b": start - 0.02 * at["in_flight"] + 0.0005})
+        laid.append({**r, "pid": 1, "a": now - 0.003, "b": now + 0.0005,
+                     "at": {"seq": at["seq"], "tokens": at["tokens"],
+                            "block_ready": now,
+                            "block_waited": at["seq"] not in covers}})
+    window = (laid[1]["at"]["block_ready"], now + 0.001)
+    run = {"spans": laid, "window_wall": window, "profile": None,
+           "records": [], "device": {"kind": "cpu"},
+           "config": {"llm_config": {"n_layers": 2},
+                      "app_kwargs": {"max_batch": 2}}}
+    ivs = da.paired(run)
+    assert sorted(c.seq for iv in ivs for c in iv.chunks) == list(
+        range(1, len(chunks)))
+    for iv in ivs:
+        assert iv.chunks[-1].seq not in covers
+        assert all(c.seq in covers for c in iv.chunks[:-1]) or any(
+            c.beside for c in iv.chunks[:-1])
+    assert da.coverage(run, *window) > 0.95
+    # a hand-over behind a cover: a `place` ahead, a chunk in flight, no gap
+    behind = [c for iv in ivs for c in iv.chunks
+              if c.places and c.seq - 1 in covers]
+    assert behind and all(c.in_flight >= 1 for c in behind)
+    got = {name: manifest.layer_reader(name)(run) for name in (
+        "decode_step_window_ms", "admit_dev_share_window", "admit_dev_ms",
+        "handover_gap_share_window")}
+    assert None not in got.values(), got
+    assert got["decode_step_window_ms"] == pytest.approx(10.0, abs=0.5)
+    dry = [c for c in chunks[1:] if not c["at"]["in_flight"]]
+    assert got["handover_gap_share_window"] == pytest.approx(
+        100 * 0.004 * len(dry) / (window[1] - window[0]), rel=0.05)
 
 
 def test_the_stats_and_the_chunk_spans_name_the_samplers_path(engine, spans):

@@ -230,7 +230,7 @@ def test_cache_stats_name_the_layout_and_count_boundary_copies(engine):
     assert set(st) == {"cache_layout", "cache_boundary_copies",
                        "cache_kind", "cache_bytes", "kv_walk_share",
                        "kv_live_share", "splices", "splices_in_flight",
-                       "pipeline_dry", "cache_kinds", "kv_heads",
+                       "pipeline_dry", "cover_chunks", "cache_kinds", "kv_heads",
                        "prefill_rows", "prefill_rows_kernel",
                        "sampler_steps", "sampler_steps_select",
                        "decode_steps", "decode_steps_kernel"}
@@ -477,9 +477,11 @@ def test_shutdown_ends_requests_parked_in_ready_and_in_pending():
 # ---------------------------------------------------------------------------
 # How a batch row changes hands (README "Serving hot loop"): through one device
 # program, where its occupant's last token is read; a chunk records its
-# occupants, so a row given up early is the next request's at once. Two rows,
-# chunks of four steps; every request is parked in `_ready` before a row is
-# given out, so that the order of the hand-overs is the order of the submits.
+# occupants, so a row given up early is the next request's at once; while a
+# known last step is read and a parked request waits for the row, ONE cover
+# chunk of one step steps the occupants who go on. Two rows, chunks of four
+# steps; every request is parked in `_ready` before a row is given out, so
+# that the order of the hand-overs is the order of the submits.
 
 GREEDY = dict(temperature=0.0)
 
@@ -503,8 +505,7 @@ def parked(eng, monkeypatch, requests) -> list:
     dispatched beyond it, and a hand-over behind THAT chunk is none the
     test arranged (on a loaded host the scheduler's thread can stand still
     that long)."""
-    until(lambda: not (eng._q_chunks or eng._pending_firsts
-                       or eng.num_active), "the engine to come to rest")
+    at_rest(eng)
     with monkeypatch.context() as full:
         full.setattr(eng, "_free_slot", lambda: None)
         streams = [eng.submit(p, sp) for p, sp in requests]
@@ -515,11 +516,14 @@ def parked(eng, monkeypatch, requests) -> list:
 
 def hand_overs(eng, monkeypatch) -> dict:
     """Every `_splice` as stream -> (row, chunks in flight behind which its
-    program was dispatched)."""
+    program was dispatched, the steps of each, the passes that had begun
+    dry by then)."""
     seen, splice = {}, eng._splice
 
     def spy(slot, plen, sampling, stream, *rest):
-        seen[stream] = (slot, len(eng._q_chunks))
+        seen[stream] = (slot, len(eng._q_chunks),
+                        [n for _toks, _occ, n, *_rest in eng._q_chunks],
+                        eng.pipeline_dry)
         return splice(slot, plen, sampling, stream, *rest)
 
     monkeypatch.setattr(eng, "_splice", spy)
@@ -528,8 +532,13 @@ def hand_overs(eng, monkeypatch) -> dict:
 
 def counted(eng, before: dict) -> dict:
     now = eng.cache_stats()
-    return {k: now[k] - before[k]
-            for k in ("splices", "splices_in_flight", "pipeline_dry")}
+    return {k: now[k] - before[k] for k in (
+        "splices", "splices_in_flight", "pipeline_dry", "cover_chunks")}
+
+
+def at_rest(eng):
+    until(lambda: not (eng._q_chunks or eng._pending_firsts
+                       or eng.num_active), "the engine to come to rest")
 
 
 def test_six_requests_through_two_rows_each_get_the_tokens_they_get_alone(
@@ -537,9 +546,10 @@ def test_six_requests_through_two_rows_each_get_the_tokens_they_get_alone(
     """Requests of different lengths, so that rows change hands at six
     different chunk boundaries beside a live neighbour: each stream is the
     one its request gets alone, every row was handed on where its
-    occupant's last token was read (the pipeline had drained to there, and
-    the next pass began with the neighbour seated and nothing or one step
-    in flight), and the counters say so."""
+    occupant's last token was read (the pipeline had drained to there but
+    for chunks of ONE step: the neighbour's cover, and the step too many of
+    an answer so short that it was dispatched whole before its first token
+    was read), and the counters say so."""
     lengths = [5, 9, 14, 3, 7, 11]
     prompts = [[1 + i, 2, 3 + i] for i in range(len(lengths))]
     want = [alone(pair, p, max_tokens=n) for p, n in zip(prompts, lengths)]
@@ -555,12 +565,16 @@ def test_six_requests_through_two_rows_each_get_the_tokens_they_get_alone(
     # (a request's steps are counted before its first token is: the one
     # step too many may still be in flight when its last token is read)
     behind = [seen[s][1] for s in streams]
-    assert behind[:2] == [0, 0] and max(behind) <= 1
+    assert behind[:2] == [0, 0] and max(behind) <= 2
+    assert {n for s in streams for n in seen[s][2]} == {1}
     assert {seen[s][0] for s in streams} == {0, 1}
     got = counted(pair, before)
     assert got["splices"] == 6
-    assert got["splices_in_flight"] == sum(behind)
-    assert 1 <= got["pipeline_dry"] <= 6
+    assert got["splices_in_flight"] == sum(1 for b in behind if b)
+    # (a cover where a neighbour went on and somebody was parked: the next
+    # pass did not begin dry)
+    assert 1 <= got["cover_chunks"] <= 4
+    assert 0 <= got["pipeline_dry"] <= 6 - got["cover_chunks"]
     assert not pair._q_chunks and not pair._pending_firsts
 
 
@@ -613,6 +627,175 @@ def test_a_stream_closed_mid_decode_ends_cancelled_and_frees_its_row(
     assert seen[nxt][0] == seen[gone][0] and seen[nxt][1] >= 1
     until(lambda: pair.num_active == 0, "the rows to be given back")
     assert gone not in pair._streams and not any(pair._slots)
+
+
+def watched(eng, monkeypatch) -> tuple:
+    """The scheduler's chunks as they are dispatched and read: `log` holds
+    ("chunk", steps, cover, streams stepped, the block) and ("read", the
+    block read or None, streams whose first token was read there), and
+    `faults` whatever broke a rule of the hand-over, at any chunk:
+
+    - a chunk dispatched while a seated occupant's known last step is in
+      flight is a cover: one step, for the occupants who go on and nobody
+      else, with a request parked; any other chunk steps everybody seated;
+    - no second chunk goes past an end that has not been read;
+    - a cover's read brings no first token but its own occupants'."""
+    log, faults = [], []
+    passed = {}  # a cover's block -> the occupants whose end it went past
+
+    class Chunks(list):
+        def append(self, entry):
+            toks, occupants, n, cover, _seq = entry
+            seated = [s for s in eng._slots if s is not None]
+            ended = [s for s in seated if s.remaining - s.in_flight < 1]
+            going = [s for s in seated if s not in ended]
+            if occupants != going or cover != bool(ended):
+                faults.append(("steps the wrong occupants", n, cover))
+            if cover and not (n == 1 and eng._ready):
+                faults.append(("a cover nobody waits behind", n,
+                               len(eng._ready)))
+            if cover and any(not e.done for _t, *_r in self
+                             for e in passed.get(id(_t), ())):
+                faults.append(("two chunks past one unread end", n))
+            if cover:
+                passed[id(toks)] = ended
+            log.append(("chunk", n, cover, [s.stream for s in occupants],
+                        id(toks)))
+            list.append(self, entry)
+
+    drain_ = eng._drain
+
+    def reads(ph):
+        head = eng._q_chunks[0] if eng._q_chunks else None
+        pending = [st for st, _f in eng._pending_firsts]
+        out = drain_(ph)
+        read = [st for st in pending
+                if all(st is not s for s, _f in eng._pending_firsts)]
+        if head is not None and head[3] and any(
+                st not in head[1] and not st.done for st in read):
+            faults.append(("a first token read at a cover's drain",))
+        log.append(("read", head and id(head[0]),
+                    [st.stream for st in read]))
+        return out
+
+    monkeypatch.setattr(eng, "_q_chunks", Chunks())
+    monkeypatch.setattr(eng, "_drain", reads)
+    return log, faults
+
+
+def first_with_its_chunk(log, stream) -> bool:
+    """The stream's first token was read in the drain that read the first
+    chunk that stepped it."""
+    block = next(e[4] for e in log if e[0] == "chunk" and stream in e[3])
+    return [e[1] for e in log if e[0] == "read" and stream in e[2]] == [block]
+
+
+@pytest.mark.parametrize("case", [
+    "a_parked_request_rides_behind_one_cover_step",
+    "nobody_parked_nobody_stepped_past_the_end",
+    "an_end_inside_a_cover_and_one_cover_past_it",
+    "a_row_given_up_at_a_stop_token",
+    "a_stream_closed_mid_decode"])
+def test_a_row_changes_hands_behind_at_most_one_cover_step(
+        case, pair, monkeypatch):
+    """While the host reads an occupant's KNOWN last step, one chunk of one
+    step steps the occupants who go on, and only if a parked request waits
+    for the row (`watched` has the rules, held at every chunk of every
+    case); each stream is the one its request gets alone.
+
+    - parked: six requests, answers long enough that a first token is read
+      before the answer's last step is dispatched. Each of the four later
+      hand-overs rides behind exactly one chunk of one step, no pass before
+      the last of them began dry, and every newcomer's first token came
+      with its first chunk's tokens, in one drain, not at the cover's.
+      With the last request seated nobody is parked: the next end is read
+      with nothing past it and the pass after it begins dry.
+    - nobody parked: two requests on two rows. No cover; the pipeline
+      drains to the first end as it did.
+    - an end inside a cover: the neighbour's last step is the cover's own.
+      The next cover goes past THAT end once the first one's is read: two
+      covers in flight, one past each end; the newcomer between them reads
+      its first token with the second, which steps it.
+    - a stop token, a closed stream: nobody's end was known, so nothing
+      covers it, and the newcomer takes the row behind the chunks in flight
+      and reads its first token at the next drain, as before."""
+    def greedy(n, **kw):
+        return SamplingParams(**GREEDY, max_tokens=n, **kw)
+
+    requests = {
+        "a_parked_request_rides_behind_one_cover_step": [
+            ([1 + i, 2, 3 + i], greedy(n))
+            for i, n in enumerate([21, 30, 44, 27, 39, 34])],
+        "nobody_parked_nobody_stepped_past_the_end": [
+            ([1, 2, 3], greedy(21)), ([2, 2, 4], greedy(30))],
+        "an_end_inside_a_cover_and_one_cover_past_it": [
+            ([1 + i, 2, 3 + i], greedy(n))
+            for i, n in enumerate([21, 22, 30, 33])],
+        "a_row_given_up_at_a_stop_token": [
+            ([7, 7, 7], greedy(90)), ([4, 5], greedy(40)),
+            ([9, 8], greedy(13))],
+        "a_stream_closed_mid_decode": [
+            ([6], greedy(70)), ([3, 1, 4], greedy(60)),
+            ([2, 7, 1, 8], greedy(10))]}[case]
+    want = [alone(pair, p, max_tokens=sp.max_tokens) for p, sp in requests]
+    if case == "a_row_given_up_at_a_stop_token":
+        stop = want[1][5]
+        want[1] = want[1][:want[1].index(stop) + 1]
+        requests[1] = (requests[1][0], greedy(40, stop_token=stop))
+    at_rest(pair)
+    before = pair.cache_stats()
+    seen = hand_overs(pair, monkeypatch)
+    log, faults = watched(pair, monkeypatch)
+    streams = parked(pair, monkeypatch, requests)
+    if case == "a_stream_closed_mid_decode":
+        got = [streams[1].next(timeout=WAIT_S)]
+        streams[1].close()
+        got += drain(streams[1])
+        assert got == want[1][:len(got)] and len(got) < 60
+        want[1] = []
+    assert [drain(s) for s in streams] == want
+    at_rest(pair)
+    assert faults == []
+    got = counted(pair, before)
+    assert got["splices"] == len(streams)
+    covers = [e for e in log if e[0] == "chunk" and e[2]]
+    assert got["cover_chunks"] == len(covers)
+    behind = [seen[s][1:3] for s in streams]
+    last = streams[-1]
+    if case == "a_parked_request_rides_behind_one_cover_step":
+        assert behind == [(0, [])] * 2 + [(1, [1])] * 4
+        assert got["cover_chunks"] == 4 == got["splices_in_flight"]
+        assert seen[last][3] == before["pipeline_dry"]
+        assert got["pipeline_dry"] == 1  # the end after it: nobody parked
+        assert all(first_with_its_chunk(log, s) for s in streams)
+    elif case == "nobody_parked_nobody_stepped_past_the_end":
+        assert behind == [(0, [])] * 2 and not covers
+        assert got["pipeline_dry"] == 1 and got["splices_in_flight"] == 0
+        # the chunk that holds the first end is read before another goes
+        order = [e[0] for e in log]
+        ends = [i for i, e in enumerate(log)
+                if e[0] == "chunk" and len(e[3]) == 2][-1]
+        assert order[ends + 1:ends + 5] == ["read"] * 4
+    elif case == "an_end_inside_a_cover_and_one_cover_past_it":
+        assert behind == [(0, []), (0, []), (1, [1]), (1, [1])]
+        first, second = covers[:2]
+        assert first[3] == [streams[1]] and second[3] == [streams[2]]
+        assert [e[1] for e in log if e[0] == "read" and streams[2] in e[2]
+                ] == [second[4]]
+        assert all(first_with_its_chunk(log, s) for s in streams)
+        # both covers were in flight at once, the second behind the first
+        i, j = log.index(first), log.index(second)
+        assert not any(e[0] == "read" and e[1] == first[4]
+                       for e in log[i:j])
+    else:
+        assert not covers and got["pipeline_dry"] >= 1
+        assert seen[last][0] == seen[streams[1]][0]
+        assert seen[last][1] >= 1 and got["splices_in_flight"] == 1
+        # the first token at the very next drain, whatever chunk it reads
+        at = next(i for i, e in enumerate(log)
+                  if e[0] == "chunk" and last in e[3])
+        nxt = next(e for e in log[at:] if e[0] == "read")
+        assert last in nxt[2]
 
 
 @pytest.mark.parametrize("how", ["shutdown", "scheduler_error"])
