@@ -6,7 +6,11 @@ deployments/llm/llm_server.py — a Serve deployment wrapping an engine).
 The reference delegates the engine to vLLM; here the engine is the native
 flagship Transformer with KV-cached greedy decoding: one prefill pass
 fills per-layer caches, then every generated token is a fixed-shape
-compiled step under lax.scan (see LLMEngine).
+compiled step under lax.scan (see LLMEngine). A step yields one token a
+sequence for every model but one kind: a model that generates by diffusion
+over blocks (`model_type: sdar_moe`) is stepped a FORWARD of a whole block a
+sequence, several forwards finish a block and a forward yields no token or
+several (`ContinuousEngine` alone serves it: llm/engine.py).
 """
 
 from __future__ import annotations
@@ -64,6 +68,10 @@ class LLMEngine:
 
         self.cfg = cfg
         self.model = Transformer(model_config(cfg))
+        if self.model.cfg.block_length:
+            raise NotImplementedError(
+                "LLMEngine decodes one token a step: a model that generates "
+                "by diffusion over blocks is served by ContinuousEngine")
         if cfg.params is not None:
             self.params = cfg.params
         else:

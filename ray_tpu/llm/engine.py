@@ -41,6 +41,12 @@ engine workers via vllm_models.py:123-137). TPU-native design:
   reads each live slot's own rows (K and V, or latent rows) and nothing of
   a free one; elsewhere the step reads a static prefix of the slot cache
   chosen inside the program from `kv_bound`.
+- **Steps that carry a block** (`_make_block_chunk`): a model that
+  generates by diffusion over blocks of L positions is stepped a FORWARD of
+  [B, L] a scan step; several forwards finish a block, only the last one's
+  cache rows stay, and a forward gives a slot no token or several. The
+  same scheduler, manager and builder: a chunk counts forwards, and the
+  host counts forwards and tokens apart (`_Slot.steps_left`).
 - **In-graph sampling** (`llm/sampler.py`): per slot, inside the compiled
   step; a step sorts the vocabulary only where a live row has a nucleus.
 - **TP over a mesh**: pass `mesh` (axis "tp") and params/caches shard via
@@ -75,7 +81,7 @@ from typing import Optional
 import numpy as np
 
 from ray_tpu._private import compile_cache, telemetry, tracing as _tracing
-from ray_tpu.llm.sampler import _make_sampler, _sampler_path
+from ray_tpu.llm.sampler import _make_sampler, _sampler_path, token_prob
 from ray_tpu.models.published import model_config
 
 logger = logging.getLogger(__name__)
@@ -240,10 +246,10 @@ class _Slot:
     request while chunks that stepped this one are still in flight."""
 
     __slots__ = ("slot", "stream", "sampling", "remaining", "emitted",
-                 "in_flight", "done", "covered")
+                 "in_flight", "done", "covered", "state", "forwards")
 
     def __init__(self, slot: int, stream: GenStream,
-                 sampling: SamplingParams):
+                 sampling: SamplingParams, state=None, forwards: int = 0):
         self.slot = slot
         self.stream = stream
         self.sampling = sampling
@@ -252,6 +258,20 @@ class _Slot:
         self.in_flight = 0  # decode steps dispatched for it and not read
         self.done = False   # its stream has ended (`_retire`)
         self.covered = False  # a cover chunk went past its unread last step
+        # Generation by blocks (`ContinuousEngine._make_block_chunk`): the
+        # row's state as last read (at first as seated), and the most
+        # forwards the request still takes from there. A step of such a
+        # model is a forward and yields no token or several, so forwards
+        # and tokens are counted apart.
+        self.state = state
+        self.forwards = forwards
+
+    def steps_left(self) -> int:
+        """Steps still to dispatch for it: one a token it is owed, or, by
+        blocks, the most forwards it can still take (with a confidence
+        threshold in play only that bound is known)."""
+        return (self.remaining if self.state is None
+                else self.forwards) - self.in_flight
 
 
 # ------------------------------------------------------ engine tracing
@@ -364,6 +384,14 @@ def _leaves_by_kind(mcfg, cache) -> dict:
 _EVA_COUNTERS = ("eva_summaries", "eva_restarts")
 
 
+#: What a model that generates by blocks counts on the device beside its
+#: tokens, slot by slot: the forwards live slots ran, those of them that
+#: committed a block, the answer tokens given out and the positions that
+#: denoising forwards freed: `<name>` on `engine.host_sync`, `<name>_total`
+#: in `/v1/stats`. Tokens over forwards is the model's acceptance rate.
+_BD_COUNTERS = ("bd_forwards", "bd_commits", "bd_tokens", "bd_freed")
+
+
 #: `models/moe.py` `zero_counts`' four, by the names they go by from here
 #: on: `<name>` on `engine.host_sync`, `<name>_total` in `/v1/stats`,
 #: `LLM_<NAME>` (`rt_llm_<name>_total`) in `util/metrics.py`. One less
@@ -457,6 +485,10 @@ HALF_STEP_ABOVE = 4096
 class ContinuousEngine:
     """In-flight-batching engine over the flagship Transformer."""
 
+    #: Positions a block of a model that generates by diffusion over blocks
+    #: (`_build_compiled` reads it from the model); 0: a token a step.
+    _blocks = 0
+
     def __init__(self, cfg, *, max_batch: int = 8, decode_chunk: int = 8,
                  mesh=None):
         import jax
@@ -537,7 +569,11 @@ class ContinuousEngine:
         self._prefill_in_call = False
         self._splices_at_chunk = 0
         self._built_at: dict = {}  # chunk ordinal -> programs built for it
-        self._toks_dev = jnp.zeros(max_batch, jnp.int32)
+        # (by blocks the "next token" is a row's whole state: its open block,
+        # the answer tokens out, those wanted, the denoising forwards done)
+        self._toks_dev = jnp.zeros(
+            (max_batch, self._blocks + 3) if self._blocks else max_batch,
+            jnp.int32)
         self._lens_dev = jnp.zeros(max_batch, jnp.int32)
         # Every GenStream not yet _DONE, independent of slot state: the
         # scheduler-death safety net terminates these with an attributed
@@ -603,7 +639,12 @@ class ContinuousEngine:
         # "eva" layers: the columns behind those, `_EVA_COUNTERS`' two.
         self._eva = "eva" in self.model.cfg.mixers
         self._eva_cols = -(-len(_EVA_COUNTERS) // self.max_batch) * self._eva
-        for name in _PICK_COUNTERS + _EVA_COUNTERS:
+        # Generation by blocks: positions a block (0: a token a step), the
+        # columns of `_BD_COUNTERS`' four and, last, of a row's state.
+        self._blocks = self.model.cfg.block_length
+        self._bd_cols = -(-len(_BD_COUNTERS) // self.max_batch) * bool(
+            self._blocks)
+        for name in _PICK_COUNTERS + _EVA_COUNTERS + _BD_COUNTERS:
             setattr(self, f"{name}_total", 0)
         # A looped stack: the passes a token runs (0: the stack runs once,
         # no loop), the columns behind those (a chunk's exit mass by pass,
@@ -645,6 +686,8 @@ class ContinuousEngine:
         self._prefill_form_of: dict = {}
 
         def make_chunk(model):
+            if model.cfg.block_length:  # a step is a forward of a block
+                return self._make_block_chunk(model)
             held = _moe_counters(model.cfg)  # counters a step carries on
             eva = (model.cfg.eva_window, model.cfg.eva_chunk) \
                 if "eva" in model.cfg.mixers else None
@@ -763,6 +806,9 @@ class ContinuousEngine:
                 "leaves": len(leaves),
                 # (a looped stack: a layer's K and V pair, once a pass)
                 **({"passes": self._passes} if self._passes else {}),
+                # (visibility by blocks of so many rows: a block's forwards
+                # rewrite its rows until the commit's write stays)
+                **({"block_length": self._blocks} if self._blocks else {}),
                 **({"bytes_per_slot": nbytes // self.max_batch}
                    if kind == "state" else {"rows": leaves[0].shape[1]}),
                 "bytes": nbytes}
@@ -787,7 +833,8 @@ class ContinuousEngine:
 
         def prefill(params, toks, plen):
             """toks [1, Lb] -> (last-position logits [V], each cache leaf's
-            first min(Lb, its rows) rows, a state leaf whole). A full leaf's
+            first min(Lb, its rows) rows, a state leaf whole); by blocks the
+            slices alone. A full leaf's
             rows beyond the bucket were not written; a ring shorter than
             the bucket comes whole, holding the last positions before `plen`
             at their ring places (`Attention._cached_attention`); a state is
@@ -801,12 +848,21 @@ class ContinuousEngine:
                     {"params": params}, toks, positions=positions,
                     decode=True, prompt_len=jnp.reshape(plen, (1,)),
                     mutable=["cache"])
+            def slices():
+                return self._by_kind(
+                    lambda kind, c: c if kind == "state"
+                    else c[:, :self._slice_rows(kind, lb, c.shape[1])],
+                    vars_out["cache"])
+
+            if self._blocks:
+                # No logit of such a prefill is read: `plen` is the prompt's
+                # whole blocks, its rows are committed, and the prompt's
+                # remainder opens the first block (`_prefill_dispatch`).
+                return slices()
+            # (the logits first: a program's text is its operations' order)
             last = jax.lax.dynamic_index_in_dim(
                 logits[0].astype(jnp.float32), plen - 1, 0, keepdims=False)
-            return last, self._by_kind(
-                lambda kind, c: c if kind == "state"
-                else c[:, :self._slice_rows(kind, lb, c.shape[1])],
-                vars_out["cache"])
+            return last, slices()
 
         def place(cache, slice_cache, mirrors, first, key, ints, floats):
             """The hand-over of batch row `slot` to a prefilled request, as
@@ -821,7 +877,9 @@ class ContinuousEngine:
             step that first makes it visible (Attention._cached_attention).
             A state leaf's slice is the slot's whole block: nothing of the
             last occupant's state, or of what chunks in flight made of it
-            since, is left."""
+            since, is left. By blocks `first` is the row's whole first
+            state ([L + 3]: its open block and the counts behind it) and
+            the length the rows the prefill committed."""
             slot = ints[0]
             cache = jax.tree.map(
                 lambda big, small: jax.lax.dynamic_update_slice(
@@ -842,6 +900,142 @@ class ContinuousEngine:
         self._chunk = jax.jit(make_chunk(model), static_argnums=(8, 9),
                               donate_argnums=(1,))
         self._count_boundary_copies()
+
+    def _make_block_chunk(self, model):
+        """`make_chunk`'s program for a model that generates by diffusion
+        over blocks of L positions (`TransformerConfig.block_length`,
+        `Denoising`): a chunk of n FORWARDS under one scan, the same
+        arguments and results as every chunk program, with a row's `toks`
+        its whole state [B, L + 3]: the open block's content (a token or
+        the mask a position), the answer tokens given out so far (negative
+        while the open block still holds the prompt's last tokens), those
+        wanted, and the denoising forwards the open block has had.
+        `lengths` are the committed rows.
+
+        A scan step is ONE forward of [B, L] at each slot's own depth (the
+        block's keys and values go to rows `lengths` on, every forward
+        rewrites them: `BlockAttention`), then, by each slot's own state:
+
+        - a block that holds a mask was DENOISED: every masked position
+          draws a token by the slot's sampling (`llm/sampler.py`, the
+          token's probability beside it), and the positions freed are the
+          schedule's count of highest confidence, or under
+          `low_confidence_dynamic` every one above the threshold where
+          those are at least as many;
+        - a block without a mask was COMMITTED: the forward's write of its
+          rows stays, `lengths` moves on by L, its tokens that lie past the
+          prompt and before the answer's end are given out, and the next
+          block is all mask.
+
+        Slots in different phases share a forward. A slot whose answer is
+        out is frozen (and reads no cache row), so the host may dispatch
+        past an end it only knows a bound of. The block that rides to the
+        host is [B, n * L] tokens, forward by forward, -1 where none was
+        given out; behind them the expert layers' columns, `_BD_COUNTERS`'
+        four, and the rows' states after the chunk. No loop inside a step."""
+        import jax
+        import jax.numpy as jnp
+
+        mcfg, sampler = model.cfg, self._sampler
+        size, how = mcfg.block_length, mcfg.denoising
+        held = _moe_counters(mcfg)
+        mutable = ["cache"] + ["stats"] * bool(held)
+
+        def chunk(params, cache, state, lengths, keys, temp, top_k, top_p,
+                  n: int, greedy: bool, kv_bound=None, live=None):
+            b = state.shape[0]
+            at = jnp.arange(size, dtype=jnp.int32)
+            schedule = jnp.asarray(how.counts(size), jnp.int32)
+
+            def step(carry, _):
+                cache, state, lens, keys, tally, *rows = carry
+                blk, given, want, had = (state[:, :size], state[:, size],
+                                         state[:, size + 1],
+                                         state[:, size + 2])
+                on = given < want
+                if live is not None:
+                    on = on & live
+                with self._mesh_scope():
+                    logits, vars_out = model.apply(
+                        {"params": params, "cache": cache}, blk,
+                        positions=lens[:, None] + at[None], decode=True,
+                        kv_bound=kv_bound, live=on, mutable=mutable)
+                if held:
+                    rows[0] = _counted(vars_out.get("stats", {}), rows[0])
+                # (the mask is never drawn: a position that drew it would
+                # stay masked, and a greedy slot's last one for ever)
+                flat = logits.reshape(b * size, -1).astype(
+                    jnp.float32).at[:, how.mask_token].set(-jnp.inf)
+                if greedy:
+                    x0 = jnp.argmax(flat, axis=-1).astype(jnp.int32)
+                    conf = token_prob(flat, x0)
+                else:
+                    split = jax.vmap(
+                        lambda key: jax.random.split(key, size + 1))(keys)
+                    keys = split[:, 0]
+                    x0, conf = sampler(
+                        flat, split[:, 1:].reshape(b * size, 2),
+                        *(jnp.repeat(a, size)
+                          for a in (temp, top_k, top_p, on)), with_prob=True)
+                x0, conf = x0.reshape(b, size), conf.reshape(b, size)
+                masked = blk == how.mask_token
+                conf = jnp.where(masked, conf, -jnp.inf)
+                # positions ahead of position i by confidence, the earlier
+                # first among equals: [B, i, j]
+                ahead = (conf[:, None, :] > conf[:, :, None]) | (
+                    (conf[:, None, :] == conf[:, :, None])
+                    & (at[None, None, :] < at[None, :, None]))
+                count = schedule[jnp.minimum(had, how.steps - 1)]
+                free = masked & (ahead.sum(-1) < count[:, None])
+                if how.strategy == "low_confidence_dynamic":
+                    sure = masked & (conf > how.threshold)
+                    free = jnp.where((sure.sum(-1) >= count)[:, None], sure,
+                                     free)
+                commit = on & ~masked.any(-1)
+                free = free & on[:, None]
+                place = given[:, None] + at[None]  # of the answer, from 0
+                out = jnp.where(commit[:, None] & (place >= 0)
+                                & (place < want[:, None]), blk, -1)
+                moved = commit.astype(jnp.int32) * size
+                state = jnp.concatenate([
+                    jnp.where(commit[:, None], how.mask_token,
+                              jnp.where(free, x0, blk)),
+                    (given + moved)[:, None], want[:, None],
+                    jnp.where(commit, 0, had + (on & ~commit))[:, None]],
+                    axis=1)
+                tally = tally + jnp.stack([
+                    on.sum(), commit.sum(), (out >= 0).sum(),
+                    free.sum()]).astype(jnp.int32)
+                return (vars_out["cache"], state, lens + moved, keys, tally,
+                        *rows), out
+
+            rows0 = [jnp.zeros((held,), jnp.int32)] if held else []
+            (cache, state, lens, keys, tally, *rows), out = jax.lax.scan(
+                step, (cache, state, lengths, keys,
+                       jnp.zeros((len(_BD_COUNTERS),), jnp.int32), *rows0),
+                None, length=n)
+            block = jnp.concatenate(
+                [jnp.moveaxis(out, 0, 1).reshape(b, n * size),
+                 *(_rows_columns(r, b) for r in (*rows, tally)), state],
+                axis=1)
+            return cache, keys, block, lens
+
+        return chunk
+
+    def _forwards_left(self, state) -> int:
+        """The most forwards the request whose row shows `state` (`[L + 3]`,
+        as `_make_block_chunk` lays it out) still takes: what its open block
+        can take from where it is, and a whole block's for each block its
+        answer still needs behind that one. Exact where no confidence
+        passes the threshold."""
+        mcfg = self.model.cfg
+        size, how = mcfg.block_length, mcfg.denoising
+        given, want, had = (int(v) for v in state[size:])
+        if given >= want:
+            return 0
+        later = -(-max(0, want - given - size) // size)
+        return (how.forwards(size, int((state[:size] == how.mask_token).sum()),
+                             had) + later * how.forwards(size, size))
 
     # ------------------------------------------------------- cache layout
     # The KV cache crosses every program boundary in the on-device layout
@@ -912,7 +1106,9 @@ class ContinuousEngine:
 
         b = self.max_batch
         return (params, cache,
-                jax.ShapeDtypeStruct((b,), jnp.int32),
+                jax.ShapeDtypeStruct(
+                    (b, self._blocks + 3) if self._blocks else (b,),
+                    jnp.int32),
                 jax.ShapeDtypeStruct((b,), jnp.int32),
                 jax.ShapeDtypeStruct((b, 2), jnp.uint32),
                 jax.ShapeDtypeStruct((b,), jnp.float32),
@@ -1077,6 +1273,11 @@ class ContinuousEngine:
         if self._eva:
             out.update({f"{name}_total": getattr(self, f"{name}_total")
                         for name in _EVA_COUNTERS})
+        if self._blocks:
+            # (`decode_steps` are forwards; a forward yields no token or
+            # several: `bd_tokens_total` / `bd_forwards_total` a live slot)
+            out.update({f"{name}_total": getattr(self, f"{name}_total")
+                        for name in _BD_COUNTERS})
         if self._passes:
             out.update(ut_steps=self._passes,
                        loop_passes_total=self.loop_passes_total,
@@ -1086,11 +1287,11 @@ class ContinuousEngine:
             out.update(self._moe_stats())
         return out
 
-    def _count_moe(self, block: np.ndarray, n: int) -> dict:
+    def _count_moe(self, block: np.ndarray, at: int, n: int) -> dict:
         """The expert layers' counts of the n-step chunk just read (they
-        ride behind its tokens): added to the totals, and returned as the
-        attributes `engine.host_sync` carries."""
-        counts = _rows_from_columns(block[:, n:n + self._moe_cols],
+        ride behind its tokens, columns `at` on of its block): added to the
+        totals, and returned as the attributes `engine.host_sync` carries."""
+        counts = _rows_from_columns(block[:, at:at + self._moe_cols],
                                     _moe_counters(self.model.cfg))
         rows = counts[:self._moe_held]
         total = int(rows.sum())
@@ -1104,12 +1305,14 @@ class ContinuousEngine:
         attrs.update(self._count_picks(*counts[len(rows):]))
         return attrs
 
-    def _count_eva(self, block: np.ndarray, at: int) -> dict:
-        """`_EVA_COUNTERS`' two of the chunk just read (columns `at` on of
-        its block): added to the totals, and returned as the attributes
-        `engine.host_sync` carries."""
-        got = dict(zip(_EVA_COUNTERS, map(int, _rows_from_columns(
-            block[:, at:at + self._eva_cols], len(_EVA_COUNTERS)))))
+    def _count_named(self, names: tuple, block: np.ndarray, at: int) -> dict:
+        """The counters `names` (`_EVA_COUNTERS`, `_BD_COUNTERS`) of the
+        chunk just read (columns `at` on of its block): added to the totals
+        and, where `util/metrics.py` has them, the process's metrics, and
+        returned as the attributes `engine.host_sync` carries."""
+        cols = -(-len(names) // self.max_batch)
+        got = dict(zip(names, map(int, _rows_from_columns(
+            block[:, at:at + cols], len(names)))))
         for name, n in got.items():
             setattr(self, f"{name}_total", getattr(self, f"{name}_total") + n)
             _count_metric(f"LLM_{name.upper()}", n)
@@ -1236,13 +1439,20 @@ class ContinuousEngine:
     def _bucket(self, plen: int) -> int:
         """Rows a prompt's prefill is padded to: the next power of two and,
         above HALF_STEP_ABOVE, three quarters of it where the prompt fits
-        (..., 2048, 4096, 6144, 8192, 12288, ...)."""
+        (..., 2048, 4096, 6144, 8192, 12288, ...). By blocks a prefill
+        runs the prompt's whole blocks; the rest opens the first block."""
+        plen = self._committed(plen)
         b = 8
         while b < plen:
             b *= 2
         if b > HALF_STEP_ABOVE and plen <= b // 4 * 3:
             b = b // 4 * 3
         return min(b, self.cfg.max_seq)
+
+    def _committed(self, plen: int) -> int:
+        """Rows a prompt's prefill commits: the prompt, or by blocks its
+        whole blocks (the rest opens the first block)."""
+        return plen - plen % self._blocks if self._blocks else plen
 
     def _prefill_form(self, bucket: int) -> str:
         """`kernel` where every layer's attention of this bucket's prefill
@@ -1271,7 +1481,9 @@ class ContinuousEngine:
                     kernel = not mcfg.mixers and all(
                         kernel_refusal(
                             q, (1, bucket, mcfg.n_kv_heads, mcfg.head_dim),
-                            window=window) is None
+                            window=window, **(
+                                {"blocks": self._blocks} if self._blocks
+                                else {})) is None
                         for window in {mcfg.window_of(i)
                                        for i in range(mcfg.n_layers)})
             self._prefill_form_of[bucket] = "kernel" if kernel else "xla"
@@ -1371,8 +1583,12 @@ class ContinuousEngine:
 
         plen = len(prompt)
         lb = self._bucket(plen)
+        # By blocks the prefill runs the prompt's whole blocks (none at all
+        # of a prompt shorter than one: its bucket's rows are then padding,
+        # written and never seen), and the rest opens the first block.
+        held_back = plen - self._committed(plen)
         toks = np.zeros((1, lb), np.int32)
-        toks[0, :plen] = prompt
+        toks[0, :plen - held_back] = prompt[:plen - held_back]
         ann = None
         if stream.trace is not None:
             ann = self._jax.profiler.TraceAnnotation(
@@ -1396,8 +1612,9 @@ class ContinuousEngine:
                 ctx = stream.trace and (stream.trace[0], stream.prefill_span)
                 telemetry.ACCOUNT.begin_call(ctx)
             try:
-                last_logits, cache_slice = self._prefill(
-                    self.params, toks_dev, plen)
+                got = self._prefill(self.params, toks_dev,
+                                    plen - held_back)
+                last_logits, cache_slice = (None, got) if self._blocks else got
                 if account:
                     self._prefills_since.append(lb)
                     built = telemetry.ACCOUNT.end_call(
@@ -1408,13 +1625,23 @@ class ContinuousEngine:
                     self._prefill_in_call = False
             key = self._jax.random.fold_in(
                 self._jax.random.PRNGKey(sampling.seed), stream.request_id)
-            first = self._sample1(
-                last_logits, key,
-                jnp.float32(sampling.temperature),
-                jnp.int32(sampling.top_k), jnp.float32(sampling.top_p))
-            # The scheduler reads it at the drain after the hand-over:
-            # the copy is under way by then, and nothing is built there.
-            _start_host_copy(first)
+            if self._blocks:
+                # the row's first state: the prompt's last tokens and the
+                # mask behind them, so many tokens short of an answer's
+                # first, `max_tokens` wanted, no denoising forward yet
+                mask = self.model.cfg.denoising.mask_token
+                first = np.array(
+                    [*prompt[plen - held_back:],
+                     *[mask] * (self._blocks - held_back),
+                     -held_back, sampling.max_tokens, 0], np.int32)
+            else:
+                first = self._sample1(
+                    last_logits, key,
+                    jnp.float32(sampling.temperature),
+                    jnp.int32(sampling.top_k), jnp.float32(sampling.top_p))
+                # The scheduler reads it at the drain after the hand-over:
+                # the copy is under way by then, and nothing is built there.
+                _start_host_copy(first)
         finally:
             if ann is not None:
                 ann.__exit__(None, None, None)
@@ -1441,6 +1668,10 @@ class ContinuousEngine:
                 f"{m}:{mcfg.mixers.count(m)}" for m in sorted(set(mcfg.mixers))))
         if self._passes:
             attrs["ut_steps"] = self._passes  # slices handed on a layer
+        if self._blocks:
+            # the rows the prefill committed, and the prompt's tokens that
+            # open the first block
+            attrs.update(committed=plen - held_back, open=held_back)
         if self._eva:
             # what the prefill handed on: the windows it ran, and the
             # summaries of the whole chunks before the prompt's end
@@ -1532,15 +1763,22 @@ class ContinuousEngine:
                    self._temps_dev, self._topks_dev, self._topps_dev)
         self._cache, mirrors = self._place(
             self._cache, cache_slice, mirrors, first, key,
-            np.array([slot, plen, sampling.top_k], np.int32),
+            # (by blocks the row's length is what the prefill committed)
+            np.array([slot, self._committed(plen), sampling.top_k], np.int32),
             np.array([sampling.temperature, sampling.top_p], np.float32))
         (self._toks_dev, self._lens_dev, self._keys, self._temps_dev,
          self._topks_dev, self._topps_dev) = mirrors
-        st = _Slot(slot, stream, sampling)
+        if self._blocks:
+            # (its first tokens come from its first commit, in a chunk's
+            # block: there is no first token to read)
+            st = _Slot(slot, stream, sampling, first,
+                       self._forwards_left(first))
+        else:
+            st = _Slot(slot, stream, sampling)
+            self._pending_firsts.append((st, first))
         self._slots[slot] = st
         self._n_active += 1
         self._lengths[slot] = plen
-        self._pending_firsts.append((st, first))
         self.splices += 1
         if self._q_chunks:
             self.splices_in_flight += 1
@@ -1760,6 +1998,19 @@ class ContinuousEngine:
         return (int(round(float(np.ceil(seen / block).mean()))) * block,
                 float(np.ceil(seen / piece).mean()) * piece)
 
+    def _block_rows(self, st: _Slot, n: int) -> np.ndarray:
+        """The most rows `st`'s attention reads in each of the next n
+        forwards, [n]: the rows committed when its state was last read, its
+        open block's, and a block's more for every two forwards since (a
+        block takes a denoising forward and a commit at least), never past
+        its answer's last block."""
+        size = self._blocks
+        given, want, had = (int(v) for v in st.state[size:])
+        ahead = had + st.in_flight + np.arange(n)  # forwards before each
+        rows = st.stream.prompt_len + given + size * (1 + ahead // 2)
+        return np.minimum(rows, st.stream.prompt_len + given
+                          + -(-(want - given) // size) * size)
+
     def _fill_pipeline(self, ph) -> tuple:
         """Phases `admit` and `dispatch`, as often as they alternate: hand
         every free row to a waiting request, then dispatch a chunk for the
@@ -1783,7 +2034,7 @@ class ContinuousEngine:
                 break
             # Who still needs a step; the others' known last step is in
             # flight, and each one's row changes hands where that is read.
-            ended = [s for s in seated if s.remaining - s.in_flight < 1]
+            ended = [s for s in seated if s.steps_left() < 1]
             active = [s for s in seated if s not in ended]
             cover = bool(ended)
             if not active or cover and (
@@ -1795,9 +2046,16 @@ class ContinuousEngine:
                 # reads pace the chain, and a newcomer's `place` queues
                 # behind one step at most.
                 break
-            live = [int(self._lengths[s.slot]) for s in active]
-            budget = int(min(min(s.remaining - s.in_flight for s in active),
-                             max_seq - max(live)))
+            if self._blocks:
+                # (rows as last read; nobody's answer passes `max_seq`:
+                # `submit` refuses it, and a slot holds whole blocks)
+                live = [s.stream.prompt_len + int(s.state[self._blocks])
+                        for s in active]
+                budget = min(s.steps_left() for s in active)
+            else:
+                live = [int(self._lengths[s.slot]) for s in active]
+                budget = int(min(min(s.steps_left() for s in active),
+                                 max_seq - max(live)))
             if budget < 1:
                 break  # a row at max_seq: `submit` refuses what gets there
             # Power-of-2 chunk sizes only: each distinct scan length
@@ -1821,9 +2079,18 @@ class ContinuousEngine:
             # say it, from integers it holds: a free row's device-side
             # length is stale and keeps growing, and what such a row
             # decodes is handed to nobody.
-            kv_bound = max(live) + n
+            if self._blocks:
+                # Forwards, not rows: a slot's rows after them are known
+                # only as a bound (`_block_rows`).
+                seen = np.stack([self._block_rows(s, n) for s in active])
+                kv_bound = int(seen.max())
+            else:
+                # step j sees length + j + 1 rows
+                seen = np.add.outer(live, np.arange(1, n + 1))
+                kv_bound = max(live) + n
             assert kv_bound <= max_seq and all(
-                length + n <= kv_bound for length in live), (live, n)
+                length + n <= kv_bound for length in live
+                if not self._blocks), (live, n)
             seq = None
             if ph is not None:
                 # wall_ns ties the spans' wall clock to the trace's own.
@@ -1849,10 +2116,8 @@ class ContinuousEngine:
                 # chunks' execution instead of serializing after it.
                 _start_host_copy(toks_out)
                 # Rows a slot's attention walks in each step of the chunk,
-                # and rows a live slot has to show (step j sees length +
-                # j + 1 of them, a ring at most its own length): by kind
-                # of leaf, a step's mean.
-                seen = np.add.outer(live, np.arange(1, n + 1))
+                # and rows a live slot has to show (`seen`, a ring at most
+                # its own length): by kind of leaf, a step's mean.
                 rows = {}
                 if "full" in self._cache_kinds:
                     rows["full"] = (
@@ -1887,6 +2152,9 @@ class ContinuousEngine:
                     # each of a layer's leaves is walked once a step: what
                     # the rows below say of ONE leaf holds `ut_steps` times
                     attrs["ut_steps"] = self._passes
+                if self._blocks:
+                    # `tokens` are FORWARDS of so many positions a slot
+                    attrs["block_length"] = self._blocks
                 if cover:
                     # the occupants who go on, stepped while the host
                     # reads the others' last step (said only where true)
@@ -1921,9 +2189,12 @@ class ContinuousEngine:
                 self.sampler_steps_select += n * (path == "select")
                 # Chain on device; mirror lengths on host (every row
                 # steps n times — deterministic, no read needed).
-                self._toks_dev = toks_out[:, n - 1]
+                if self._blocks:  # the rows' states ride last in the block
+                    self._toks_dev = toks_out[:, -(self._blocks + 3):]
+                else:
+                    self._toks_dev = toks_out[:, n - 1]
+                    self._lengths = self._lengths + n
                 self._lens_dev = lens_out
-                self._lengths = self._lengths + n
                 self._q_chunks.append((toks_out, active, n, cover, seq))
                 dispatched += 1
                 iter_ctx = iter_ctx or tctx
@@ -2011,13 +2282,18 @@ class ContinuousEngine:
             return sync_ctx
         # sync_ms of the pass is engine.host_sync's own interval.
         t_end = ph.begin("deliver") if ph is not None else None
-        moe = (self._count_moe(block, n)
+        width = n * max(1, self._blocks)  # the block's columns of tokens
+        moe = (self._count_moe(block, width, n)
                if self._moe_cols and block is not None else {})
         if self._eva_cols and block is not None:
-            moe.update(self._count_eva(block, n + self._moe_cols))
+            moe.update(self._count_named(_EVA_COUNTERS, block,
+                                         width + self._moe_cols))
+        if self._bd_cols and block is not None:
+            moe.update(self._count_named(_BD_COUNTERS, block,
+                                         width + self._moe_cols))
         if self._loop_cols and block is not None:
             moe.update(self._count_loop(
-                block, n + self._moe_cols + self._eva_cols,
+                block, width + self._moe_cols + self._eva_cols,
                 n * len(occupants)))
         if sync_ctx is not None:
             t_end = t_end or time.time()
@@ -2032,11 +2308,19 @@ class ContinuousEngine:
                         st.stream._stage[1]["sync_seq"] = seq
             # The set-up account: the programs built in the calls whose
             # results these reads brought to the host.
+            # (by blocks no first token is read: what a request's prefill
+            # and hand-over built is ready with the first block that
+            # stepped it)
+            fresh = ([st for st in occupants if st.stream._built]
+                     if self._blocks else [])
             for built, ready in [
                     (self._built_at.pop(seq, None), "block_ready")] + [
-                    (st.stream._built, "firsts_ready") for st, _f in firsts]:
+                    (st.stream._built, "firsts_ready") for st, _f in firsts
+                    ] + [(st.stream._built, "block_ready") for st in fresh]:
                 if built:
                     telemetry.ACCOUNT.builds_ready(built, acct.get(ready))
+            for st in fresh:
+                st.stream._built = None
             try:
                 from ray_tpu.util import metrics as _metrics
 
@@ -2047,7 +2331,14 @@ class ContinuousEngine:
             self._deliver(st, [tok])
         for st in occupants:
             st.in_flight -= n
-            self._deliver(st, block[st.slot, :n].tolist())
+            given = block[st.slot, :width]
+            if self._blocks:
+                # the tokens its commits gave out, forward by forward, and
+                # where the row stands after the chunk
+                given = given[given >= 0]
+                st.state = block[st.slot, -(self._blocks + 3):]
+                st.forwards = self._forwards_left(st.state)
+            self._deliver(st, given.tolist())
         return sync_ctx
 
     # ------------------------------------------------ expert layers' counts

@@ -199,7 +199,10 @@ class OpenAIServer:
         """Generator of OpenAI SSE chunk dicts — one per token BATCH
         (GenStream.next_batch drains every token available per wakeup, so
         a chunk of decode output is one dict, one downstream flush — not
-        one wakeup and one SSE event per token)."""
+        one wakeup and one SSE event per token). A model that generates by
+        blocks gives a block's tokens when the block commits, so an event
+        holds the tokens of the blocks a chunk committed; `usage` and
+        `finish_reason` are what they are for every model."""
         def gen():
             decode = self.tok.stream_decoder()
             try:

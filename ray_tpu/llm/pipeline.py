@@ -114,6 +114,14 @@ def make_stage_net(mcfg, layers: tuple, first: bool, last: bool):
             f"that ran one pass would be another model; it is served by "
             f"ContinuousEngine only")
 
+    if mcfg.block_length:
+        raise NotImplementedError(
+            f"a stage steps one position a slot and hands on one token a "
+            f"step (llm/pipeline.py `_run_scheduler`): a model that generates "
+            f"by diffusion over blocks of {mcfg.block_length} positions "
+            f"(several forwards a block, the last one's cache rows kept) is "
+            f"served by ContinuousEngine only")
+
     if any(mcfg.mixer_of(i) == "eva" for i in layers):
         raise NotImplementedError(
             "pipeline stages keep one kind of cache leaf, max_seq rows a "

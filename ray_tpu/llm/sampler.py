@@ -96,20 +96,39 @@ def _kept_logits(logits, temp, top_k, top_p, live=None):
     return lax.cond(jnp.any(by_p), nucleus, select)
 
 
+def token_prob(logits, token):
+    """softmax(logits)[token] a row, float32: logits [B, V] (-inf where a
+    token is not kept), token [B]."""
+    import jax
+    import jax.numpy as jnp
+
+    at = jnp.take_along_axis(logits, token[:, None], axis=-1)[:, 0]
+    return jnp.exp(at - jax.nn.logsumexp(logits, axis=-1))
+
+
 def _make_sampler(vocab: int):
     import jax
     import jax.numpy as jnp
 
     @jax.named_scope("sampler")  # its name in a device trace
-    def sample(logits, keys, temp, top_k, top_p, live=None):
+    def sample(logits, keys, temp, top_k, top_p, live=None,
+               with_prob: bool = False):
         """logits [B, V] f32; keys [B, 2] uint32; temp/top_k/top_p [B];
         live [B] bool, the rows somebody reads (all of them without it).
         temp <= 0 -> greedy. top_k <= 0 -> disabled. top_p >= 1 -> disabled.
-        The draw is `categorical` over `_kept_logits`."""
+        The draw is `categorical` over `_kept_logits`. `with_prob` (a block
+        step's rows, B slots x L positions): also the drawn token's
+        probability under the distribution it was drawn from, the kept,
+        tempered one, and for a greedy row the argmax's under the plain
+        softmax; (tokens, probabilities [B] float32)."""
         assert logits.shape[-1] == vocab
         greedy = jnp.argmax(logits, axis=-1)
-        drawn = jax.vmap(jax.random.categorical)(
-            keys, _kept_logits(logits, temp, top_k, top_p, live))
-        return jnp.where(temp <= 0.0, greedy, drawn).astype(jnp.int32)
+        kept = _kept_logits(logits, temp, top_k, top_p, live)
+        drawn = jax.vmap(jax.random.categorical)(keys, kept)
+        token = jnp.where(temp <= 0.0, greedy, drawn).astype(jnp.int32)
+        if not with_prob:
+            return token
+        return token, token_prob(
+            jnp.where((temp <= 0.0)[:, None], logits, kept), token)
 
     return sample

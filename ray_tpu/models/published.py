@@ -283,6 +283,61 @@ def _ouro(cfg, arch: dict) -> dict:
         sandwich_norm=True, ut_steps=steps)
 
 
-_ARMS = {"evabyte": _evabyte, "kimi_k2": _deepseek_v3, "deepseek_v3": _deepseek_v3,
+def _sdar_moe(cfg, arch: dict) -> dict:
+    """The fields of a `model_type: sdar_moe` decoder (JetLM's SDAR, a
+    block-diffusion language model) beyond the six sizes: a Qwen3-MoE layer
+    (grouped-query attention at a published head size, ONE query and one key
+    norm weight for all heads, rotary embedding, no bias; every layer's
+    feed-forward a softmax router over all experts, the selected weights
+    renormalised, no shared expert; an untied head) whose attention goes by
+    BLOCKS of `block_length` positions (`models/transformer.py`
+    `BlockAttention`), and the five values of its generation, which no key of
+    the published `config.json` states and `arch` therefore has to
+    (`Denoising`; ISSUE 58). `intermediate_size` is the width of a dense
+    layer the model does not have (`mlp_only_layers` empty,
+    `decoder_sparse_step` 1) and `max_window_layers` bears on nothing while
+    no layer has a window: neither is read."""
+    from ray_tpu.models.transformer import Denoising
+
+    _refuse_unbuilt(arch, {
+        "attention_bias": False, "rope_scaling": None,
+        "use_sliding_window": False, "sliding_window": None,
+        "decoder_sparse_step": 1, "mlp_only_layers": [],
+        "norm_topk_prob": True, "tie_word_embeddings": False,
+        "hidden_act": "silu"})
+    kv_heads = int(arch["num_key_value_heads"])
+    if kv_heads < 1 or cfg.n_heads % kv_heads:
+        raise ValueError(f"not built: {cfg.n_heads} heads do not share "
+                         f"{kv_heads} key/value heads evenly")
+    length, steps = int(arch["block_length"]), int(arch["denoising_steps"])
+    strategy = arch["remasking_strategy"]
+    if strategy not in ("low_confidence_dynamic", "low_confidence_static"):
+        raise ValueError(f"not built: remasking_strategy {strategy!r} (built: "
+                         f"low_confidence_dynamic, low_confidence_static)")
+    if length < 1 or not 1 <= steps <= length or cfg.max_seq % length:
+        raise ValueError(
+            f"not built: blocks of {length} positions in {steps} denoising "
+            f"steps over {cfg.max_seq} positions a slot (a step frees at "
+            f"least one position, and a slot holds whole blocks)")
+    mask = int(arch["mask_token_id"])
+    if not 0 <= mask < cfg.vocab_size:
+        raise ValueError(f"not built: mask_token_id {mask} is not among the "
+                         f"{cfg.vocab_size} tokens")
+    experts = _experts(cfg, int(arch["num_experts"]),
+                       moe_top_k=int(arch["num_experts_per_tok"]),
+                       moe_d_ff=int(arch["moe_intermediate_size"]),
+                       moe_scoring="softmax", moe_norm_topk=True)
+    return dict(
+        experts, moe_score_bias=False,  # selection by the scores alone
+        n_kv_heads=kv_heads, head_size=int(arch["head_dim"]),
+        d_ff=int(arch["intermediate_size"]),
+        rope_theta=float(arch["rope_theta"]), tie_embeddings=False,
+        qk_norm=True, block_length=length,
+        denoising=Denoising(
+            steps=steps, strategy=strategy,
+            threshold=float(arch["confidence_threshold"]), mask_token=mask))
+
+
+_ARMS = {"evabyte": _evabyte, "sdar_moe": _sdar_moe, "kimi_k2": _deepseek_v3, "deepseek_v3": _deepseek_v3,
          "afmoe": _afmoe, "kimi_linear": _kimi_linear,
          "longcat_flash": _longcat_flash, "ouro": _ouro}

@@ -144,13 +144,13 @@ class TransformerConfig:
     #: Rows of one expert in a tile of the expert layer's grouped path; the
     #: serving prefill takes that path above two tiles' worth of rows.
     moe_group_tile: int = 128
-    #: Width of a cache leaf's row: the leaf's own when 0 (head_dim for K, V;
+    #: Width of a cache leaf's row: its own when 0 (head_dim for K, V;
     #: kv_lora_rank + qk_rope_head_dim for a latent), else that and then unread
-    #: zeros: the width the device's compiler lays a row out in, so that the
-    #: cache's default layout is the loop's own (engine `_probe_cache_row`).
+    #: zeros: the width the compiler lays a row out in (`_probe_cache_row`).
     cache_row: int = 0
     ut_steps: int = 1  #: passes of the WHOLE stack a token (`looped_stack`)
-
+    block_length: int = 0  #: >0: generation by diffusion over blocks of so
+    denoising: Optional["Denoising"] = None  #: many positions (file's end)
     @property
     def head_dim(self) -> int:
         return self.head_size or self.d_model // self.n_heads
@@ -225,7 +225,7 @@ class Attention(nn.Module):
                                              prompt_len, live, ut_step)
             else:
                 out = dot_product_attention(q, k, v, causal=True,
-                                            window=self.window)
+                    window=self.window, blocks=cfg.block_length)
         if cfg.attn_gate:
             with jax.named_scope("attn_gate"):
                 out = out * jax.nn.sigmoid(dense((cfg.n_heads, hd), "wg")(x))
@@ -346,7 +346,7 @@ class Block(nn.Module):
                 bounded=kv_bound is not None, prompt_len=prompt_len,
                 live=live)
         else:
-            a = Attention(cfg, window=self.window, name="attn")(
+            a = attention_of(cfg)(cfg, window=self.window, name="attn")(
                 norm("attn_norm")(x), positions, decode=decode, live=live,
                 kv_bound=kv_bound, prompt_len=prompt_len, ut_step=ut_step)
         x = x + (norm("post_attn_norm")(a) if cfg.sandwich_norm else a)
@@ -576,3 +576,111 @@ def looped_stack(module: Transformer, tokens, positions, decode, kv_bound,
     module.sow("loop", "exit_p", jnp.stack(exits, -1),
                reduce_fn=lambda _old, new: new, init_fn=lambda: None)
     return output_head(module, cfg, x, emb)
+
+
+# ---------------------------------------------------------------------------
+# Generation by diffusion over blocks (`block_length` > 0; a block-diffusion
+# language model's `block_length`): the decoder's one departure is WHO SEES
+# WHOM. Position i belongs to block i // L, and key j is visible to query i
+# iff j // L <= i // L: every earlier block whole and the query's own block
+# whole, the positions after it included. The logits at position i are the
+# distribution of the token AT position i. Everything of it stands here, at
+# the file's end, as a path of its own beside the causal one (no line above
+# may move, see `looped_stack`).
+
+@dataclass(frozen=True)
+class Denoising:
+    """How a block leaves the mask (the engine's chunk program reads it; the
+    model's arithmetic does not): a block of `block_length` positions starts
+    as `mask_token` everywhere past the prompt, a denoising forward frees the
+    schedule's count of positions by confidence (`counts`), or under
+    `low_confidence_dynamic` every masked position whose confidence passes
+    `threshold` where those are at least as many, and a block without a mask
+    is committed by one more forward."""
+    steps: int
+    strategy: str  # "low_confidence_dynamic" or "low_confidence_static"
+    threshold: float
+    mask_token: int
+
+    def counts(self, block_length: int) -> tuple:
+        """Positions a denoising forward frees at least, by its number in the
+        block: `block_length // steps` each, the remainder one each over the
+        first forwards."""
+        base, extra = divmod(block_length, self.steps)
+        return tuple(base + (t < extra) for t in range(self.steps))
+
+    def forwards(self, block_length: int, masked: int, done: int = 0) -> int:
+        """The most forwards a block still takes that has `masked` positions
+        under the mask after `done` denoising forwards: those until the
+        schedule's counts cover them, and the commit."""
+        counts = self.counts(block_length)
+        n = 0
+        while masked > 0:
+            masked -= counts[min(done + n, self.steps - 1)]
+            n += 1
+        return n + 1
+
+
+def attention_of(cfg: TransformerConfig):
+    """The class of an "mha" layer's attention: `Attention`, or under
+    `block_length` its form whose cached steps go by blocks."""
+    return BlockAttention if cfg.block_length else Attention
+
+
+class BlockAttention(Attention):
+    """`Attention` where visibility goes by blocks of `cfg.block_length`."""
+
+    def _cached_attention(self, q, k, v, positions, kv_bound=None,
+                          prompt_len=None, live=None, ut_step: int = 0):
+        """Two forms, full leaves only (`max_seq` rows a slot, position p in
+        row p).
+
+        A prefill (`prompt_len` given: the call is a slot's first positions,
+        from 0) attends over its own rows, causal BY BLOCKS
+        (`dot_product_attention(blocks=L)`), and writes them. Padding behind
+        the prompt's whole blocks changes nothing before it.
+
+        A BLOCK STEP (no `prompt_len`): the call's s positions a slot stand
+        at the slot's own depth, `positions[b]` = c_b .. c_b + s - 1 with c_b
+        the slot's committed length. Their keys and values are written to
+        rows c_b .. c_b + s - 1 FIRST, then all s queries of a slot read rows
+        [0, c_b + s): the committed rows and each other, one stop a slot
+        (`ops/decode_attention.py` `block_decode_attention`). Rows at and
+        past c_b are visible to no other call, so a block's forwards may
+        rewrite them as often as they like: the last write before the
+        scheduler moves c_b on is the one that stays."""
+        from ray_tpu.ops.decode_attention import block_decode_attention
+
+        cfg = self.cfg
+        if self.window or ut_step:
+            raise NotImplementedError(
+                "visibility by blocks is built for full layers of a stack "
+                "that runs once")
+        b, s = q.shape[0], q.shape[1]
+        d = cfg.head_dim
+        row = max(cfg.cache_row, d)
+        shape = (b, cfg.max_seq, cfg.n_kv_heads, row)
+        ck = self.variable("cache", "k", lambda: jnp.zeros(shape, cfg.dtype))
+        cv = self.variable("cache", "v", lambda: jnp.zeros(shape, cfg.dtype))
+        kc, vc = k.astype(cfg.dtype), v.astype(cfg.dtype)
+        if row > d:
+            tail = ((0, 0),) * 3 + ((0, row - d),)
+            kc, vc = jnp.pad(kc, tail), jnp.pad(vc, tail)
+        if prompt_len is not None:
+            with jax.named_scope("prefill_attention"):
+                out = dot_product_attention(
+                    q, k.astype(cfg.dtype), v.astype(cfg.dtype), causal=True,
+                    q_len=prompt_len, blocks=cfg.block_length)
+            ck.value = jax.lax.dynamic_update_slice(ck.value, kc, (0, 0, 0, 0))
+            cv.value = jax.lax.dynamic_update_slice(cv.value, vc, (0, 0, 0, 0))
+            return out.astype(cfg.dtype)
+        pos = positions.astype(jnp.int32)
+        bidx = jnp.arange(b)[:, None]
+        ck.value = ck.value.at[bidx, pos].set(kc)
+        cv.value = cv.value.at[bidx, pos].set(vc)
+        keys, vals = ck.value, cv.value
+        if row > d and kv_bound is None:
+            keys, vals = keys[..., :d], vals[..., :d]
+        out = block_decode_attention(q, keys, vals, pos[:, -1] + 1,
+                                     kv_bound=kv_bound, live=live)
+        return out.astype(cfg.dtype)

@@ -20,7 +20,9 @@ import math
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops.flash_attention import flash_attention, unsupported_reason
+from ray_tpu.ops.flash_attention import (block_causal_attention,
+                                         blocks_unsupported_reason,
+                                         flash_attention, unsupported_reason)
 from ray_tpu.parallel.mesh import context_mesh_shape
 
 logger = logging.getLogger(__name__)
@@ -47,7 +49,8 @@ def on_tpu() -> bool:
 
 
 def kernel_refusal(q_shape, k_shape, *, causal: bool = True, window: int = 0,
-                   use_pallas: bool | None = None) -> str | None:
+                   use_pallas: bool | None = None,
+                   blocks: int = 0) -> str | None:
     """Why `dot_product_attention` takes an XLA form for q [B, Sq, Hq, D]
     against k/v [B, Sk, Hkv, D] outside differentiation, or None when it
     takes the kernel: the dispatcher's own rule, for whoever wants to know
@@ -55,6 +58,9 @@ def kernel_refusal(q_shape, k_shape, *, causal: bool = True, window: int = 0,
     rows either way)."""
     if not (on_tpu() if use_pallas is None else use_pallas):
         return NOT_ASKED
+    if blocks:  # causal by blocks: a kernel of its own
+        return mesh_refusal() or blocks_unsupported_reason(
+            q_shape, k_shape, blocks)
     return mesh_refusal() or unsupported_reason(
         q_shape, k_shape, causal=causal, window=window)
 
@@ -70,7 +76,8 @@ def mesh_refusal() -> str | None:
 
 
 def dot_product_attention(q, k, v, *, causal: bool = True, window: int = 0,
-                          q_len=None, use_pallas: bool | None = None):
+                          q_len=None, use_pallas: bool | None = None,
+                          blocks: int = 0):
     """q: [B, Sq, Hq, D], k/v: [B, Sk, Hkv, D] (GQA when Hq > Hkv).
 
     Returns [B, Sq, Hq, D]. Softmax in f32 regardless of input dtype
@@ -78,7 +85,10 @@ def dot_product_attention(q, k, v, *, causal: bool = True, window: int = 0,
     of a call over its own rows only): key j is visible to query i iff
     0 <= i - j < window. `q_len` ([B] int32): rows at or past it are read
     by nobody, so the kernel may skip them (they come back as zeros or as
-    what they would be).
+    what they would be). `blocks` > 0 (a call over its own rows, no window):
+    causal BY BLOCKS of so many positions, key j visible to query i iff
+    j < blocks * (i // blocks + 1): every earlier block and the query's own,
+    whole (a block-diffusion model's prefill; serving only, no gradient).
 
     The implementation is chosen up front from what can be observed, and
     each choice is stated once at INFO (`kernel_refusal` is the rule):
@@ -94,18 +104,24 @@ def dot_product_attention(q, k, v, *, causal: bool = True, window: int = 0,
     if window and not (causal and q.shape[1] == k.shape[1]):
         raise ValueError("a window is for causal attention of a call over "
                          "its own rows")
+    if blocks and (window or not causal or q.shape[1] != k.shape[1]):
+        raise ValueError("visibility by blocks is for causal attention of a "
+                         "call over its own rows, without a window")
     reason = kernel_refusal(q.shape, k.shape, causal=causal, window=window,
-                            use_pallas=use_pallas)
+                            use_pallas=use_pallas, blocks=blocks)
+    if reason is None and blocks:
+        state_once("attention: Pallas flash kernel, causal by blocks")
+        return block_causal_attention(q, k, v, blocks=blocks, q_len=q_len)
     if reason is None:
         return _kernel_or_xla_grad(q, k, v, q_len, causal, window)
     if reason != NOT_ASKED:
         state_once(f"attention: XLA path, O(Sq*Sk) memory ({reason})")
-    return _xla_form(q, k, v, causal, window)
+    return _xla_form(q, k, v, causal, window, blocks)
 
 
-def _xla_form(q, k, v, causal: bool, window: int):
+def _xla_form(q, k, v, causal: bool, window: int, blocks: int = 0):
     if causal and q.shape[1] == k.shape[1]:
-        return prefill_attention(q, k, v, window)
+        return prefill_attention(q, k, v, window, blocks)
     return _xla_attention(q, k, v, causal=causal)
 
 
@@ -145,11 +161,13 @@ def _xla_attention(q, k, v, *, causal: bool):
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
-def prefill_attention(q, k, v, window: int = 0):
+def prefill_attention(q, k, v, window: int = 0, blocks: int = 0):
     """Causal attention of a call over ITS OWN rows, positions 0..S-1 in
     order (a prefill, or a training batch of a model with window layers):
-    q [B, S, H, D], k and v [B, S, KV, D] -> [B, S, H, D]. Queries go in
-    tiles so that a tile's float32 scores stay small. The query heads that
+    q [B, S, H, D], k and v [B, S, KV, D] -> [B, S, H, D]; with `blocks`
+    causal by blocks of so many positions (a query sees its own block whole:
+    a tile is whole blocks, so its keys still end where it ends). Queries go
+    in tiles so that a tile's float32 scores stay small. The query heads that
     share a key/value head are one matrix product against it: K and V are
     never copied per query head.
 
@@ -176,12 +194,17 @@ def prefill_attention(q, k, v, window: int = 0):
     tile = s if fits(s) else s & -s
     while tile > 8 and not fits(tile):
         tile //= 2
+    if blocks and (window or tile % blocks or s % blocks):
+        raise ValueError(f"blocks of {blocks} positions against tiles of "
+                         f"{tile} of {s} rows (window {window})")
 
     def attend(qt, kt, vt, i, j):
         """One tile: queries at positions i [T] against keys at j [K]."""
         scores = jnp.einsum("bsngd,btnd->bngst", qt, kt,
                             preferred_element_type=jnp.float32) / (d ** 0.5)
         back = i[:, None] - j[None, :]
+        if blocks:  # the query's block end stands in for its own position
+            back = back + (blocks - 1 - i[:, None] % blocks)
         visible = (back >= 0) & (j[None, :] >= 0)
         if window:
             visible = visible & (back < window)

@@ -907,3 +907,30 @@ def two_leaf_decode_attention(q, window, chunks, stop_w, stop_c, *,
             bound = jnp.max(stop) if bounded else jnp.int32(k.shape[1])
             parts.append(partial_walk(q, k, v, stop, bound))
         return merge_partials(*parts).astype(q.dtype)
+
+
+# ---------------------------------------------------------------------------
+# A block step (`models/transformer.py` `BlockAttention`): L queries a slot
+# with ONE stop a slot. All L x H query rows of a slot read the same rows
+# [0, stop) of its leaves, so to every form above they are L x (H / KV) query
+# heads on each key/value head where a single-token step has H / KV: the
+# ragged kernel's work list, copies and pieces, the walks' prefixes and
+# masks are what they were; only the query matrix has more rows.
+
+def block_decode_attention(q, k_cache, v_cache, stop, *, kv_bound=None,
+                           live=None):
+    """q [B, L, H, D]: the L positions of a slot's open block, each of whose
+    H heads reads the slot's rows `[0, stop[b])` of the leaves [B, rows, KV,
+    row] (the block's own rows among them: the caller wrote them first).
+    -> [B, L, H, D]. `kv_bound` and `live` as `decode_attention` takes them,
+    which serves it: the rows are laid out key/value head by key/value head
+    (head h of position l is row kv * L * G + l * G + g of G = H / KV), so
+    that a query row's key/value head is its index over L x G."""
+    b, l, hq, d = q.shape
+    kv = k_cache.shape[2]
+    g = hq // kv
+    rows = q.reshape(b, l, kv, g, d).transpose(0, 2, 1, 3, 4)
+    out = decode_attention(rows.reshape(b, kv * l * g, d), k_cache, v_cache,
+                           stop, kv_bound=kv_bound, live=live)
+    return out.reshape(b, kv, l, g, d).transpose(0, 2, 1, 3, 4).reshape(
+        b, l, hq, d)

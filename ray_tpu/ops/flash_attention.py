@@ -319,3 +319,166 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     )(q_len.astype(jnp.int32), q.reshape(b, sq, hq * d),
       k.reshape(b, sk, hkv * d), v.reshape(b, sk, hkv * d))
     return out.reshape(b, sq, hq, d)
+
+
+# ---------------------------------------------------------------------------
+# Causal BY BLOCKS of L positions (a block-diffusion model's prefill,
+# `models/transformer.py` `BlockAttention`): key j is visible to query i iff
+# j < L * (i // L + 1), every earlier block and the query's own, whole. With
+# query blocks of whole L only the masks of the tiles on the diagonal differ
+# from the causal kernel's, and a query block visits the same key blocks. A
+# kernel of its own, at the file's end: the causal kernel's program names its
+# body's lines, so nothing above may move.
+
+def _blocks_tiles(q_shape, k_shape, blocks: int, block_q=None, block_k=None):
+    """((block_q, block_k), None) as `flash_attention` derives them, else
+    (None, reason); query blocks must be whole blocks of `blocks`."""
+    group = q_shape[2] // k_shape[2]
+    tiles, reason = _block_reasons(
+        q_shape[1], k_shape[1],
+        block_q or max(8, min(DEFAULT_BLOCK_Q, GROUP_ROWS // group)), block_k)
+    if tiles is not None and (tiles[0] % blocks or q_shape[1] % blocks):
+        return None, (f"query blocks of {tiles[0]} rows are not whole "
+                      f"blocks of {blocks} positions")
+    return tiles, reason
+
+
+def blocks_unsupported_reason(q_shape, k_shape, blocks: int) -> str | None:
+    """`unsupported_reason` for `block_causal_attention`."""
+    if q_shape[1] != k_shape[1]:
+        return f"Sq={q_shape[1]} != Sk={k_shape[1]}: not a call's own rows"
+    return (unsupported_reason(q_shape, k_shape)
+            or _blocks_tiles(q_shape, k_shape, blocks)[1])
+
+
+def _block_causal_kernel(qlen_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
+                         acc_ref, *, scale: float, blocks: int, group: int,
+                         d: int, block_q: int, block_k: int, n_k: int,
+                         n_steps: int):
+    """`_flash_kernel` (its refs, its scratch, its grid) for visibility by
+    blocks: no window, query row i at key position i."""
+    bi, qi, kj = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+    q_start, k_start = qi * block_q, kj * block_k
+    _, last = _visible_blocks(qi, block_q=block_q, block_k=block_k, n_k=n_k,
+                              off=0, causal=True, window=0)
+    live = jnp.logical_and(kj <= last, q_start < qlen_ref[bi])
+
+    @pl.when(kj == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def update(masked: bool):
+        k, v = k_ref[...], v_ref[...]
+        if masked:
+            at = q_start + jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 0)
+            key = k_start + jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 1)
+            visible = key < at - at % blocks + blocks
+
+        def one_head(g, _):
+            q = q_ref[:, pl.ds(pl.multiple_of(g * d, d), d)]
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            m_prev, l_prev = m_ref[g, :, 0:1], l_ref[g, :, 0:1]
+            if masked:
+                s = jnp.where(visible, s, NEG_INF)
+            # (key 0 is visible to every row, so every max is finite)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+            acc_ref[g] = acc_ref[g] * alpha + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_ref[g] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+            l_ref[g] = jnp.broadcast_to(l_new, l_ref.shape[1:])
+
+        jax.lax.fori_loop(0, group, one_head, None)
+
+    # A key block that ends at or before the end of the FIRST row's block of
+    # positions is visible to every row of the query block.
+    inside = k_start + block_k <= q_start + blocks
+    pl.when(jnp.logical_and(live, inside))(lambda: update(False))
+    pl.when(jnp.logical_and(live, jnp.logical_not(inside)))(
+        lambda: update(True))
+
+    @pl.when(kj == n_steps - 1)
+    def _finish():
+        def one_head(g, _):
+            l = l_ref[g, :, 0:1]
+            l = jnp.where(l == 0.0, 1.0, l)  # a skipped query block: zeros
+            o_ref[:, pl.ds(pl.multiple_of(g * d, d), d)] = (
+                acc_ref[g] / l).astype(o_ref.dtype)
+
+        jax.lax.fori_loop(0, group, one_head, None)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("blocks", "block_q", "block_k", "interpret"))
+def block_causal_attention(q, k, v, *, blocks: int, q_len=None,
+                           block_q: int | None = None,
+                           block_k: int | None = None,
+                           interpret: bool = False):
+    """q [B, S, Hq, D], k/v [B, S, Hkv, D] -> [B, S, Hq, D]: a call over its
+    own rows, key j visible to query i iff j < blocks * (i // blocks + 1).
+    `q_len`, `block_q`, `block_k` and `interpret` as `flash_attention` takes
+    them. Raises ValueError where `blocks_unsupported_reason` has one."""
+    b, sq, hq, d = q.shape
+    _, sk, hkv, _ = k.shape
+    reason = _shape_reason(q.shape, k.shape, True, 0)
+    tiles, tiling = _blocks_tiles(q.shape, k.shape, blocks, block_q, block_k)
+    if reason or sq != sk or tiles is None:
+        raise ValueError(reason or tiling or f"Sq={sq} != Sk={sk}")
+    group = hq // hkv
+    block_q, block_k = tiles
+    n_q, n_k = sq // block_q, sk // block_k
+    where = dict(block_q=block_q, block_k=block_k, n_k=n_k, off=0,
+                 causal=True, window=0)
+    n_steps = max(_visible_blocks(qi, **where, lo=max, hi=min)[1] + 1
+                  for qi in range(n_q))
+    if q_len is None:
+        q_len = jnp.full((b,), sq, jnp.int32)
+
+    def q_block(bi, qi, qlen):  # past the prompt: the last block of it
+        return jnp.minimum(qi, jnp.maximum(qlen[bi] - 1, 0) // block_q)
+
+    def kv_index(bi, hi, qi, kj, qlen):
+        at = q_block(bi, qi, qlen)
+        last = _visible_blocks(at, **where)[1]
+        return (bi, jnp.where(qi > at, last, jnp.minimum(kj, last)), hi)
+
+    kernel = functools.partial(
+        _block_causal_kernel, scale=d ** -0.5, blocks=blocks, group=group,
+        d=d, block_q=block_q, block_k=block_k, n_k=n_k, n_steps=n_steps)
+    out = pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((b, sq, hq * d), q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, hkv, n_q, n_steps),
+            in_specs=[
+                pl.BlockSpec((None, block_q, group * d),
+                             lambda bi, hi, qi, kj, qlen: (
+                                 bi, q_block(bi, qi, qlen), hi)),
+                pl.BlockSpec((None, block_k, d), kv_index),
+                pl.BlockSpec((None, block_k, d), kv_index),
+            ],
+            out_specs=pl.BlockSpec((None, block_q, group * d),
+                                   lambda bi, hi, qi, kj, qlen: (bi, qi, hi)),
+            scratch_shapes=[
+                pltpu.VMEM((group, block_q, LANES), jnp.float32),  # max
+                pltpu.VMEM((group, block_q, LANES), jnp.float32),  # denom
+                pltpu.VMEM((group, block_q, d), jnp.float32),  # accumulator
+            ]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary"),
+            vmem_limit_bytes=64 << 20),
+        interpret=interpret,
+    )(q_len.astype(jnp.int32), q.reshape(b, sq, hq * d),
+      k.reshape(b, sk, hkv * d), v.reshape(b, sk, hkv * d))
+    return out.reshape(b, sq, hq, d)

@@ -495,7 +495,7 @@ LLM_MOE_FETCHED = Counter(
                 "expert layers and steps")
 
 #: An "eva" model's decode steps (models/eva.py), counted on the device
-#: inside the chunks and read with their tokens (llm/engine.py `_count_eva`):
+#: inside the chunks and read with their tokens (llm/engine.py `_count_named`):
 #: a live slot's step that ended a chunk of positions (every eva layer then
 #: wrote one summary row), and one that began a window after the first.
 LLM_EVA_SUMMARIES = Counter(

@@ -298,8 +298,11 @@ def test_the_four_older_arms_build_their_configs_as_before(name):
         "eva_window", "eva_chunk", "eva_pool_std", "norm_unit_offset",
         "residual_f32", "pred_heads",
         # PR 53's, for the `ouro` arm alone (tests/test_ouro.py)
-        "ut_steps"}
+        "ut_steps",
+        # PR 58's, for the `sdar_moe` arm alone (tests/test_blockdiff.py)
+        "block_length", "denoising"}
     assert cfg.ut_steps == 1
+    assert (cfg.block_length, cfg.denoising) == (0, None)
     assert (cfg.eva_window, cfg.eva_chunk, cfg.norm_unit_offset,
             cfg.residual_f32, cfg.pred_heads) == (0, 0, False, False, 1)
 

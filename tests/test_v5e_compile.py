@@ -1160,3 +1160,84 @@ def test_the_line_a_ring_layers_kernel_programs_name_has_not_moved():
             "transformer.py")) as f:
         lines = f.read().split("\n")
     assert lines[303].strip() == "at = pos % rows if self.window else pos"
+
+
+# ---------------------------------------------------------------------------
+# Generation by diffusion over blocks (`model_type` `sdar_moe`): a step is a
+# forward of L positions a slot.
+
+def test_v5e_sdar_as_benchmarked_steps_a_block_a_slot_where_the_rows_lie(
+        chip, monkeypatch):
+    """`benchmark/configs/sdar-30b-a3b-pp8-6l.json` as the cell runs it, 6
+    layers, all 128 experts, the whole vocabulary and 32 slots, as the chip
+    builds it: the 16-FORWARD chunk program is one `while` (no loop inside
+    a forward: `trace_reduce.loop_steps` counts the most often started
+    operation of a `jit_chunk` execution as its steps) whose step holds one
+    Mosaic call a layer, the `mha` family's ragged kernel handed 4 x 8 query
+    rows a key/value head and K and V where they lie: no `copy`, `slice`,
+    `transpose` or `bitcast-convert` whose result has a leaf's dimensions or
+    any prefix of its rows. Its two `conditional`s are the sampler's. The
+    prefill of a bucket the flash kernel can tile is causal BY BLOCKS in a
+    kernel of its own and hands on no logits: the last layer's attention
+    and experts, whose output nobody reads, are not in the program. The
+    programs fit the chip beside a quarter of the cache in parked slices.
+    Compile-only: no parameter is made."""
+    import json
+
+    from ray_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "on_tpu", lambda: True)
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmark", "configs",
+            "sdar-30b-a3b-pp8-6l.json")) as f:
+        config = json.load(f)
+    app = config["app_kwargs"]
+    eng = build_compiled(chip, cfg=LLMConfig(**config["llm_config"]),
+                         max_batch=app["max_batch"],
+                         decode_chunk=app["decode_chunk"])
+    assert eng.cache_boundary_copies == 0
+    assert (eng._kernel_blocks, eng._decode_form) == ({"full": 1024}, "kernel")
+    assert eng._kernel_pieces == {"full": 128}  # 128 KiB of 4 heads of 128
+    full = eng.cache_stats()["cache_kinds"]["full"]
+    assert (full["layers"], full["leaves"], full["rows"],
+            full["block_length"]) == (6, 12, 2048, 4)
+    assert round(full["bytes"] / 1e9, 2) == 0.81
+
+    def held(compiled):
+        m = compiled.memory_analysis()
+        return (m.argument_size_in_bytes + m.output_size_in_bytes
+                - m.alias_size_in_bytes + m.temp_size_in_bytes)
+
+    chunk = eng._chunk.lower(*eng._chunk_shapes(
+        eng.params, eng._cache_spec, False)).compile()
+    assert 9.6e9 < held(chunk) < 10.6e9  # weights 8.72 + cache 0.81 + temps
+    text = chunk.as_text()
+    calls = re.findall(r" custom-call\((.*?)\), custom_call_target="
+                       r"\"tpu_custom_call\"", text)
+    assert len(calls) == 6
+    for line in calls:  # (the work list's length, stop, slot, at, q, K, V)
+        *_, q, k, v = line.split(", ")
+        assert "copy" not in k and "copy" not in v, line
+    assert "[32,128,128]" in text  # 4 positions x 32 heads a slot
+    assert len(re.findall(r" while\(", text)) == 1
+    assert len(re.findall(r" conditional\(", text)) == 2
+    assert not re.findall(r" conditional\(.*decode_attention", text)
+    # (what has 4 rows a slot is the open block's NEW keys and values on
+    # their way into the leaf, not rows of it)
+    assert not [m for m in moved_rows(
+        eng, text, "copy|slice|transpose|bitcast-convert|copy-start"
+                   "|copy-done") if m[1][1] != 4]
+    on_chip = lambda *dims: jax.ShapeDtypeStruct(  # noqa: E731
+        dims, jnp.int32, sharding=SingleDeviceSharding(chip))
+    assert [eng._prefill_form(b) for b in (64, 128, 1024)] == [
+        "xla", "kernel", "kernel"]  # (64 keys are no lane tile)
+    assert eng._bucket(1023) == 1024 and eng._bucket(1027) == 1024
+    prefill = eng._prefill.lower(eng.params, on_chip(1, 1024),
+                                 on_chip()).compile()
+    assert len(re.findall(r" custom-call\(.*tpu_custom_call",
+                          prefill.as_text())) == 6 - 1
+    slices = jax.eval_shape(eng._prefill, eng.params, on_chip(1, 1024), 5)
+    assert {leaf.shape for leaf in jax.tree.leaves(slices)} == {
+        (1, 1024, 4, 128)}  # slices alone: no logits
+    assert eng._slice_bytes(1024) == 12 * 1024 * 4 * 128 * 2
+    assert held(prefill) + held(chunk) - 8.72e9 + full["bytes"] / 4 < 15e9

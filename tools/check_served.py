@@ -4,7 +4,10 @@ own path: prefill, place, chunk programs of the configuration's batch), and
 read by the configuration's own plain reference. Two minutes where the cell
 takes five to thirteen, and the same rows: the check's prompts and the
 weights come from the configuration, not from a run's seed, so a program
-text reads the same rows in every run (PERF.md section 6, PR 47).
+text reads the same rows in every run (PERF.md section 6, PR 47). Whatever
+the reference's `check` does with a case is done here too: for
+`sdar.solve-saturated` (`reference/sdar.py`) that is the replay of a served
+answer decision by decision, and `/v1/stats`' `bd_*` counts are printed.
 
     chiprun -- python3 tools/check_served.py kimi-k2.code-saturated
     chiprun -- python3 tools/check_served.py kimi-k2.code-saturated --dense
@@ -92,7 +95,7 @@ def main() -> int:
     print(f"served in {time.monotonic() - t0:.0f} s; "
           + ", ".join(f"{k} {stats[k]}" for k in sorted(stats)
                       if k.startswith(("moe_", "decode_steps",
-                                       "prefill_rows"))),
+                                       "prefill_rows", "bd_"))),
           flush=True)
     # What the programs cost this start (README "Tracing & timeline", the
     # set-up account): a warm start's `compile_s` is the cache's read.

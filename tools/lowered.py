@@ -99,17 +99,23 @@ def programs(eng, chip):
             yield (f"chunk {n} {'greedy' if greedy else 'sampled'}",
                    eng._chunk.lower(*args))
     b = eng.max_batch
-    mirrors = (on_chip(i32, b), on_chip(i32, b), on_chip(u32, b, 2),
+    # (by blocks a row's "next token" is its whole state, a prefill hands on
+    # its slices alone, and no first token is sampled: `llm/engine.py`)
+    first = (eng._blocks + 3,) if eng._blocks else ()
+    mirrors = (on_chip(i32, b, *first), on_chip(i32, b), on_chip(u32, b, 2),
                on_chip(f32, b), on_chip(i32, b), on_chip(f32, b))
     for bucket in sorted({eng._bucket(n)
                           for n in range(1, eng.cfg.max_seq + 1)}):
         toks = on_chip(i32, 1, bucket)
         yield (f"prefill {bucket} {eng._prefill_form(bucket)}",
                eng._prefill.lower(eng.params, toks, on_chip(i32)))
-        handed = placed(jax.eval_shape(eng._prefill, eng.params, toks, 5)[1])
+        handed = jax.eval_shape(eng._prefill, eng.params, toks, 5)
+        handed = placed(handed if eng._blocks else handed[1])
         yield f"place {bucket}", eng._place.lower(
-            cache, handed, mirrors, on_chip(i32), on_chip(u32, 2),
+            cache, handed, mirrors, on_chip(i32, *first), on_chip(u32, 2),
             on_chip(i32, 3), on_chip(f32, 2))
+    if eng._blocks:
+        return
     yield "sample1", eng._sample1.lower(
         on_chip(f32, eng.cfg.vocab_size), on_chip(u32, 2), on_chip(f32),
         on_chip(i32), on_chip(f32))
