@@ -78,6 +78,18 @@ def apply(env: MutableMapping[str, str] | None = None) -> str:
     return path
 
 
+def lists_dir() -> str:
+    """Where a start leaves the list of the serving programs it asked for,
+    for the next start to build ahead from (`llm/programs.py`): `programs/`
+    under the directory THIS process's JAX keeps its compiled programs in,
+    beside the entries the list's programs are read from; "" where it keeps
+    none (the CPU rule above: no cache, no list)."""
+    jax = sys.modules.get("jax")
+    path = (os.environ.get(DIR_ENV) if jax is None
+            else jax.config.jax_compilation_cache_dir)
+    return os.path.join(path, "programs") if path else ""
+
+
 def entries(path: str) -> int:
     """Number of compiled programs stored under `path` (0 if absent)."""
     try:

@@ -210,7 +210,10 @@ class SetupAccount:
     """A process's stages and builds. Events of one build arrive on the
     thread that builds, in the order trace, lowering, backend compile (each
     when it ENDS; the cache's events inside the compile), so everything
-    still open is kept per thread."""
+    still open is kept per thread. A build whose compile ends on another
+    thread than its lowering did (`llm/programs.py`: one thread lowers, a
+    pool compiles) is carried across by whoever moves it (`hand_over`,
+    `take_over`) and is still ONE record with its three parts."""
 
     def __init__(self):
         self._lock = threading.Lock()
@@ -231,6 +234,7 @@ class SetupAccount:
         if not hasattr(h, "traces"):
             h.traces, h.lower, h.cache = {}, None, {}
             h.stages, h.ctx, h.call = [], None, None
+            h.held, h.last = False, None
         return h
 
     # ------------------------------------------------------------- events
@@ -294,6 +298,13 @@ class SetupAccount:
             tot["misses"] += rec["cache"] == "miss"
             for k in _SUMMED:
                 tot[k] += rec[k]
+        if h.held:
+            # a build handed over to this thread: whoever first calls the
+            # program is its cause (`first_call`), and has not come yet
+            h.held, h.last = False, rec
+            if tracing.enabled():
+                self.write()
+            return
         if not tracing.enabled():
             return
         ctx = h.stages[-1].get("ctx") if h.stages else h.ctx
@@ -342,6 +353,52 @@ class SetupAccount:
         name, should one be run again)."""
         with self._lock:
             return {s["n"]: s["b"] - s["a"] for s in self.stages}
+
+    # ---------------------------------------- a build that changes threads
+    def hand_over(self):
+        """The lowering this thread has just ended, taken from it: another
+        thread compiles it (`take_over`)."""
+        h = self._thread()
+        lower, h.lower = h.lower, None
+        return lower
+
+    def take_over(self, lower) -> None:
+        """This thread's next compile is of `lower`, handed over by the
+        thread that lowered it. The record it ends (`built`) is nobody's
+        yet: no span, no call, until `first_call`."""
+        h = self._thread()
+        h.lower, h.cache, h.held, h.last = lower, {}, True, None
+
+    def built(self) -> Optional[dict]:
+        """The record of the build `take_over` began on this thread, None
+        where no listener is installed (nothing is recorded then)."""
+        h = self._thread()
+        rec, h.held, h.last = h.last, False, None
+        return rec
+
+    def ahead(self, rec: dict) -> None:
+        """`rec`'s build ended before anyone had asked for its program."""
+        with self._lock:
+            rec["ahead"] = True
+
+    def first_call(self, rec: dict) -> None:
+        """This thread is the first to call the program of `rec`, a build
+        that ended on another thread: the record joins the thread's open
+        call as if built inside it (`call_a`, `call_s`, `ready_s`; with
+        a build ahead, `call_a` lies after `b`), or, outside any call, is
+        a child of the stage the thread is in."""
+        h = self._thread()
+        with self._lock:
+            rec["stage"] = h.stages[-1]["n"] if h.stages else None
+        if not tracing.enabled():
+            return
+        ctx = h.stages[-1].get("ctx") if h.stages else h.ctx
+        if h.call is not None:
+            rec["ctx"] = ctx  # its span waits for `builds_ready`
+            h.call[1].append(rec)
+        else:
+            self._build_span(rec, ctx)
+            self.write()
 
     # -------------------------------------------- the engine's part (traced)
     # Called only under the engine's `_tracing.enabled()` branches: what

@@ -47,6 +47,20 @@ engine workers via vllm_models.py:123-137). TPU-native design:
   cache rows stay, and a forward gives a slot no token or several. The
   same scheduler, manager and builder: a chunk counts forwards, and the
   host counts forwards and tokens apart (`_Slot.steps_left`).
+- **One table of programs** (`llm/programs.py`): every serving program
+  (`chunk` by `(n, greedy)`, `prefill` and `place` by bucket, `sample1`,
+  the layout probe) is built by one route, `jitted.lower(<abstract
+  arguments>).compile()` (`_lower`), and kept in one table; the four call
+  sites (`_Kind`) take their program from it and call it, so no `jax.jit`
+  dispatch lies on a serving call. One thread traces and lowers, a small
+  pool compiles. What a start asked for, in the order it asked, is left as
+  a list under the compile cache's directory
+  (`<directory>/programs/<hash of all that decides the texts>.json`), and
+  a start that finds its list builds AHEAD, in the last start's order,
+  while the constructor and the first requests go on; without a cache
+  directory (the CPU) there is no list and everything is built when first
+  asked for. `/v1/stats` `setup` says which: `programs_ahead`,
+  `programs_waited`, `programs_on_demand`, `list_unused`.
 - **In-graph sampling** (`llm/sampler.py`): per slot, inside the compiled
   step; a step sorts the vocabulary only where a live row has a nucleus.
 - **TP over a mesh**: pass `mesh` (axis "tp") and params/caches shard via
@@ -81,6 +95,7 @@ from typing import Optional
 import numpy as np
 
 from ray_tpu._private import compile_cache, telemetry, tracing as _tracing
+from ray_tpu.llm.programs import ProgramTable
 from ray_tpu.llm.sampler import _make_sampler, _sampler_path, token_prob
 from ray_tpu.models.published import model_config
 
@@ -482,6 +497,28 @@ PIPELINE_DEPTH = 4
 HALF_STEP_ABOVE = 4096
 
 
+class _Kind:
+    """One kind of serving program (`chunk`, `prefill`, `place`, `sample1`).
+    Called with the arguments of the jitted function its programs are
+    lowered from, it takes the program of THIS call's key (the kind and the
+    few integers `key_of` reads off the arguments) from the engine's table
+    and calls that: no `jax.jit` dispatch, and so no trace, lies on a
+    serving call. `lower` is the jitted function's own."""
+
+    __slots__ = ("name", "jitted", "_get", "_key_of")
+
+    def __init__(self, name: str, jitted, get, key_of):
+        self.name, self.jitted = name, jitted
+        self._get, self._key_of = get, key_of
+
+    def __call__(self, *args):
+        ints, args = self._key_of(args)
+        return self._get((self.name, *ints))(*args)
+
+    def lower(self, *args):
+        return self.jitted.lower(*args)
+
+
 class ContinuousEngine:
     """In-flight-batching engine over the flagship Transformer."""
 
@@ -626,11 +663,25 @@ class ContinuousEngine:
     def _build_compiled(self):
         import jax
         import jax.numpy as jnp
+        import jaxlib
 
         from ray_tpu.models.transformer import Transformer
 
         compile_cache.program_identity()  # whoever made this process
         sampler = self._sampler
+        # Every program from here on is built by ONE route and kept in ONE
+        # table (`llm/programs.py`): `_lower(key)` on the table's lowering
+        # thread, the compile on its pool. The list of what the last start
+        # like this one asked for is named by all that decides the texts.
+        device = jax.devices()[0]
+        self._programs = ProgramTable(
+            self._lower, compile_cache.lists_dir(), repr((
+                self.model.cfg, self.max_batch, self.decode_chunk,
+                self.cfg.max_seq,
+                self.mesh and (dict(self.mesh.shape),
+                               self.mesh.devices.flat[0].device_kind),
+                jax.__version__, jaxlib.__version__, device.platform,
+                device.device_kind, device.client.platform_version)))
         # Expert layers: the columns behind a chunk's tokens, and since start
         # the rows routed to the held experts and `_PICK_COUNTERS`' four.
         self._moe_held = self.model.cfg.held_experts
@@ -894,12 +945,105 @@ class ContinuousEngine:
             return sampler(logits[None], key[None], temp[None], top_k[None],
                            top_p[None])[0]
 
-        self._prefill = jax.jit(prefill)
-        self._place = jax.jit(place, donate_argnums=(0,))
-        self._sample1 = jax.jit(sample1)
-        self._chunk = jax.jit(make_chunk(model), static_argnums=(8, 9),
-                              donate_argnums=(1,))
+        # Under a mesh every program says where its results lie (the cache
+        # as `_cache_shapes` shards it, a slice as its leaf, the rest whole
+        # on every device): a loaded program takes its arguments where it
+        # was compiled to find them, so what one program hands the next has
+        # to be settled, not the compiler's choice. Without a mesh nothing
+        # is said, and the texts are what they were.
+        where = dict.fromkeys(("prefill", "place", "sample1", "chunk"), {})
+        if self.mesh is not None:
+            rep = self._replicated()
+            cache, slices = (
+                jax.tree.map(lambda leaf: leaf.sharding, tree)
+                for tree in (self._cache_spec, self._slice_shapes(8)))
+            where = {kind: {"out_shardings": trees} for kind, trees in (
+                ("prefill", slices if self._blocks else (rep, slices)),
+                ("place", (cache, (rep,) * 6)), ("sample1", rep),
+                ("chunk", (cache, rep, rep, rep)))}
+        get = self._programs.get
+        self._prefill = _Kind(
+            "prefill", jax.jit(prefill, **where["prefill"]), get,
+            lambda a: ((int(a[1].shape[1]),), a))
+        self._place = _Kind(
+            "place", jax.jit(place, donate_argnums=(0,), **where["place"]),
+            # (the bucket of the prompt whose length the hand-over sets)
+            get, lambda a: ((self._bucket(int(a[5][1])),), a))
+        self._sample1 = _Kind(
+            "sample1", jax.jit(sample1, **where["sample1"]), get,
+            lambda a: ((), a))
+        self._chunk = _Kind(
+            "chunk", jax.jit(make_chunk(model), static_argnums=(8, 9),
+                             donate_argnums=(1,), **where["chunk"]),
+            # (the statics are the key; the program takes the rest)
+            get, lambda a: ((int(a[8]), bool(a[9])), a[:8] + a[10:]))
+        # (what `_lower` lowers from, whatever stands in for a call site)
+        self._kinds = {kind.name: kind for kind in (
+            self._prefill, self._place, self._sample1, self._chunk)}
+        # From here the table has its shapes: what the last start like this
+        # one asked for is built AHEAD of the calls, in that start's order.
+        self._programs.build_ahead()
         self._count_boundary_copies()
+
+    def _replicated(self):
+        """Under a mesh, whole on every device; None without one."""
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        return None if self.mesh is None else NamedSharding(self.mesh, P())
+
+    def _arg(self, dtype, *dims, **kw):
+        """A small argument of a serving program, as a shape: whole on
+        every device under a mesh."""
+        return self._jax.ShapeDtypeStruct(
+            dims, dtype, sharding=self._replicated(), **kw)
+
+    def _slice_shapes(self, bucket: int):
+        """What a prefill of this bucket hands on, as shapes: one slot's
+        first rows of every rows leaf (`_slice_rows`), a state leaf's whole
+        block of one slot; each sharded as its leaf."""
+        import jax
+
+        return self._by_kind(
+            lambda kind, leaf: jax.ShapeDtypeStruct(
+                (1, *leaf.shape[1:]) if kind == "state" else
+                (1, self._slice_rows(kind, bucket, leaf.shape[1]),
+                 *leaf.shape[2:]), leaf.dtype, sharding=leaf.sharding),
+            self._cache_spec)
+
+    def _lower(self, key: tuple):
+        """The lowering of the program of `key` from ABSTRACT arguments:
+        exactly what the program's call site passes (the dtypes, the weak
+        type of the prompt's length, a Python integer there, `kv_bound`'s
+        `int32[]`, `live`'s `bool[B]`, by blocks `[B, L + 3]`, under a mesh
+        the shardings). Called on the table's lowering thread only."""
+        import jax.numpy as jnp
+
+        kind, *ints = key
+        if kind == "probe":  # (`_probe_cache_row`'s, while it asks)
+            jitted, args = self._probe
+            return jitted.lower(*args)
+        if kind not in self._kinds:
+            raise KeyError(f"no serving program is called {key!r}")
+        lower = self._kinds[kind].jitted.lower
+        arg, b = self._arg, self.max_batch
+        first = (self._blocks + 3,) if self._blocks else ()
+        i32, u32, f32 = jnp.int32, jnp.uint32, jnp.float32
+        if kind == "chunk":
+            n, greedy = ints
+            return lower(*self._chunk_shapes(
+                self.params, self._cache_spec, greedy, n))
+        if kind == "prefill":
+            return lower(
+                self.params, arg(i32, 1, *ints), arg(i32, weak_type=True))
+        if kind == "place":
+            mirrors = (arg(i32, b, *first), arg(i32, b), arg(u32, b, 2),
+                       arg(f32, b), arg(i32, b), arg(f32, b))
+            return lower(
+                self._cache_spec, self._slice_shapes(*ints), mirrors,
+                arg(i32, *first), arg(u32, 2), arg(i32, 3), arg(f32, 2))
+        assert kind == "sample1" and not ints, key
+        return lower(arg(f32, self.cfg.vocab_size), arg(u32, 2), arg(f32),
+                     arg(i32), arg(f32))
 
     def _make_block_chunk(self, model):
         """`make_chunk`'s program for a model that generates by diffusion
@@ -1099,24 +1243,19 @@ class ContinuousEngine:
             bucket = max(1, bucket // self.model.cfg.eva_chunk)
         return min(bucket, rows)
 
-    def _chunk_shapes(self, params, cache, greedy: bool) -> tuple:
-        """Arguments to lower a chunk program of decode_chunk steps from."""
-        import jax
+    def _chunk_shapes(self, params, cache, greedy: bool,
+                      n: Optional[int] = None) -> tuple:
+        """Arguments to lower a chunk program of n steps from (of
+        `decode_chunk` steps, the longest, where no n is given)."""
         import jax.numpy as jnp
 
-        b = self.max_batch
+        b, arg = self.max_batch, self._arg
         return (params, cache,
-                jax.ShapeDtypeStruct(
-                    (b, self._blocks + 3) if self._blocks else (b,),
-                    jnp.int32),
-                jax.ShapeDtypeStruct((b,), jnp.int32),
-                jax.ShapeDtypeStruct((b, 2), jnp.uint32),
-                jax.ShapeDtypeStruct((b,), jnp.float32),
-                jax.ShapeDtypeStruct((b,), jnp.int32),
-                jax.ShapeDtypeStruct((b,), jnp.float32),
-                self.decode_chunk, greedy,
-                jax.ShapeDtypeStruct((), jnp.int32),
-                jax.ShapeDtypeStruct((b,), jnp.bool_))
+                arg(jnp.int32, b, *((self._blocks + 3,) * bool(self._blocks))),
+                arg(jnp.int32, b), arg(jnp.uint32, b, 2),
+                arg(jnp.float32, b), arg(jnp.int32, b), arg(jnp.float32, b),
+                n or self.decode_chunk, greedy, arg(jnp.int32),
+                arg(jnp.bool_, b))
 
     def _probe_cache_row(self, make_chunk) -> int:
         """The row width the compiler wants for the cache, 0 for the one it
@@ -1156,9 +1295,9 @@ class ContinuousEngine:
                         donate_argnums=(1,),
                         in_shardings=(None, auto) + (None,) * 8,
                         out_shardings=(auto, None, None, None))
-        wanted = probe.lower(
-            *self._chunk_shapes(params, cache, True)
-        ).compile().input_formats[0][1]
+        self._probe = (probe, self._chunk_shapes(params, cache, True))
+        wanted = self._programs.get(("probe",)).input_formats[0][1]
+        del self._probe
         rows = set()
         for leaf, fmt in zip(jax.tree.leaves(cache), jax.tree.leaves(wanted)):
             lay, width = fmt.layout, leaf.shape[-1]
@@ -1170,13 +1309,11 @@ class ContinuousEngine:
 
     def _count_boundary_copies(self):
         """Read from the compiled text of the longest sampled chunk program
-        what it does with the cache at its boundary. The same lowering
-        serves this variant's first call: it is compiled once either
-        way."""
+        what it does with the cache at its boundary. The table's program:
+        the one this variant's first call takes too."""
         import jax
 
-        compiled = self._chunk.lower(*self._chunk_shapes(
-            self.params, self._cache_spec, False)).compile()
+        compiled = self._programs.get(("chunk", self.decode_chunk, False))
         text = compiled.as_text()
         leaves = jax.tree.leaves(self._cache_spec)
         formats = jax.tree.leaves(compiled.input_formats[0][1])
@@ -1192,6 +1329,16 @@ class ContinuousEngine:
         logger.info("kv cache %s: %d whole-leaf copies in the %d-step chunk "
                     "program", self.cache_layout, self.cache_boundary_copies,
                     self.decode_chunk)
+
+    def program_stats(self) -> dict:
+        """For /v1/stats `setup`: how the serving programs came to be. With
+        the last start's list found, `programs_ahead` were built from it
+        before anyone asked and `programs_waited` were asked for while its
+        build had them in hand; `programs_on_demand` were on no list (all of
+        them where there was none) and `list_unused` were built from the
+        list and never asked for. A warm start that the list covers reads
+        `programs_on_demand` 0."""
+        return self._programs.stats()
 
     def cache_stats(self) -> dict:
         """For /v1/stats: the cache's leaves and their on-device layout, how
@@ -1392,6 +1539,7 @@ class ContinuousEngine:
             self._running = False
             self._lock.notify_all()
         self._pending.put(None)  # wake the prefill lane past its get()
+        self._programs.close()  # and whoever waits for a program
         for t in self._threads:
             t.join(timeout=10)
         # Belt and braces after the join: the scheduler thread drains
@@ -1564,14 +1712,9 @@ class ContinuousEngine:
         import jax
 
         if bucket not in self._slice_bytes_of:
-            # a state leaf whole, a rows leaf up to the bucket
-            self._slice_bytes_of[bucket] = sum(jax.tree.leaves(self._by_kind(
-                lambda kind, leaf: leaf.size // self.max_batch
-                * leaf.dtype.itemsize if kind == "state"
-                else leaf.size // (self.max_batch * leaf.shape[1])
-                * self._slice_rows(kind, bucket, leaf.shape[1])
-                * leaf.dtype.itemsize,
-                self._cache_spec)))
+            self._slice_bytes_of[bucket] = sum(
+                leaf.size * leaf.dtype.itemsize
+                for leaf in jax.tree.leaves(self._slice_shapes(bucket)))
         return self._slice_bytes_of[bucket]
 
     def _prefill_dispatch(self, prompt, sampling, stream):

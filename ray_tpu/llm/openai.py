@@ -99,7 +99,8 @@ class OpenAIServer:
         # Set-up, told by the process's account (telemetry.py; /v1/stats
         # reads it): starting the device runtime (the first call that needs
         # a device) and building the engine (parameters made and placed;
-        # the serving programs compile on the first request of each shape).
+        # the serving programs are built from the last start's list, or on
+        # the first request of each shape: `llm/programs.py`).
         import jax
 
         from ray_tpu._private import telemetry
@@ -181,6 +182,8 @@ class OpenAIServer:
                 out["stages"] = self.engine.stage_devices()
             else:
                 out.update(self.engine.cache_stats())
+                # how the serving programs came to be (`llm/programs.py`)
+                setup.update(self.engine.program_stats())
             return out
         self._served += 1
         body = request.json() or {}

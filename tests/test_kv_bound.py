@@ -210,8 +210,12 @@ def test_every_chunk_is_bounded_by_its_live_slots_in_a_run_of_mixed_lengths():
     for live, n, bound in seen:
         assert live and all(length + n <= bound for length in live)
         assert bound == max(live) + n <= MAX_SEQ
-    assert len({bound for _l, _n, bound in seen}) > chunk._cache_size()
-    assert chunk._cache_size() <= 3  # n = 1, 2, 4; every request is greedy
+    # (the table's chunk programs: n = 1, 2, 4, every request greedy, and the
+    # longest sampled one, which the engine reads the cache's layout from)
+    built = {key for key in eng._programs.keys() if key[0] == "chunk"}
+    assert built <= {("chunk", n, True) for n in (1, 2, 4)} | {
+        ("chunk", 4, False)}
+    assert len({bound for _l, _n, bound in seen}) > len(built)
     steps = sum(n for _l, n, _b in seen)
     walked = sum(n * kv_prefix_rows(b, MAX_SEQ) for _l, n, b in seen)
     lived = sum(n * (sum(live) / len(live) + (n + 1) / 2)

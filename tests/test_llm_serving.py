@@ -575,7 +575,9 @@ def test_six_requests_through_two_rows_each_get_the_tokens_they_get_alone(
     # pass did not begin dry)
     assert 1 <= got["cover_chunks"] <= 4
     assert 0 <= got["pipeline_dry"] <= 6 - got["cover_chunks"]
-    assert not pair._q_chunks and not pair._pending_firsts
+    # (nothing stays in flight: the one step too many is read a pass after
+    # the last stream ended, which a loaded host can be a while in coming to)
+    at_rest(pair)
 
 
 def test_a_row_given_up_at_a_stop_token_is_the_next_requests_at_once(

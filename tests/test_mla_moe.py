@@ -447,7 +447,7 @@ def test_a_model_without_expert_layers_carries_no_extra_columns():
         assert eng._moe_cols == 0 and eng._moe_held == 0
         eng._cache = eng._init_cache()
         out = jax.eval_shape(
-            eng._chunk, eng.params, eng._cache, eng._toks_dev, eng._lens_dev,
+            eng._chunk.jitted, eng.params, eng._cache, eng._toks_dev, eng._lens_dev,
             eng._keys, eng._temps_dev, eng._topks_dev, eng._topps_dev, 4,
             False)
         assert out[2].shape == (2, 4)

@@ -121,6 +121,112 @@ def test_the_summary_adds_up_by_name(built):
     assert f"{got['builds']} builds" in line and "compile_s" in line
 
 
+# ------------------------------------------- a build that changes threads
+def on_a_thread(fn):
+    import threading
+
+    t = threading.Thread(target=fn)
+    t.start()
+    t.join(timeout=120)
+    assert not t.is_alive()
+
+
+@pytest.fixture
+def handed_over():
+    """A program traced and lowered on one thread and compiled on another,
+    as `llm/programs.py` builds: (its records in the account, the record the
+    compiling thread was given, what the lowering thread still held)."""
+    import jax
+    import jax.numpy as jnp
+
+    assert telemetry.ensure_compile_listener()
+    acct, box = telemetry.ACCOUNT, {}
+    n0 = len(acct.builds)
+
+    def moved_between_threads(x):
+        return jnp.tanh(x @ x.T).sum()
+
+    def lower():
+        box["lowered"] = jax.jit(moved_between_threads).lower(
+            jax.ShapeDtypeStruct((32, 32), jnp.float32))
+        box["handed"] = acct.hand_over()
+        box["left"] = acct._thread().lower
+
+    def compile_():
+        acct.take_over(box["handed"])
+        box["lowered"].compile()
+        box["rec"] = acct.built()
+        box["after"] = acct.built()
+
+    on_a_thread(lower)
+    on_a_thread(compile_)
+    box["recs"] = [b for b in list(acct.builds)[n0:]
+                   if b["fun_name"] == "jit_moved_between_threads"]
+    return box
+
+
+def test_a_build_whose_compile_ends_on_another_thread_is_one_record(
+        handed_over):
+    (rec,) = handed_over["recs"]
+    assert rec is handed_over["rec"]
+    assert handed_over["left"] is None and handed_over["after"] is None
+    assert rec["trace_s"] > 0 and rec["lower_s"] > 0 and rec["compile_s"] > 0
+    # `a` the trace's start, `b` the compile's end, whatever lay between
+    assert (rec["trace_s"] + rec["lower_s"] + rec["compile_s"]
+            <= rec["b"] - rec["a"] + 1e-6)
+    assert rec["cache"] in ("hit", "miss", "off") and "ahead" not in rec
+    assert telemetry.ACCOUNT.by_name["jit_moved_between_threads"][
+        "builds"] == 1
+
+
+def test_a_first_call_of_a_program_built_ahead_is_tied_to_its_build(
+        handed_over, monkeypatch):
+    """Nobody had asked when the build ended (`ahead`); the first call that
+    takes the program is still the build's call: `call_a`, `call_s` and,
+    once its result is read, `ready_s`, on the record and on its span."""
+    import time
+
+    from ray_tpu._private import tracing
+
+    acct, rec, spans = telemetry.ACCOUNT, handed_over["rec"], []
+    monkeypatch.setattr(tracing, "_ON", True)
+    monkeypatch.setattr(tracing, "record_span",
+                        lambda *a: spans.append(a))
+    monkeypatch.setattr(acct, "write", lambda: None)
+    acct.ahead(rec)
+    ctx = ("ab" * 16, "cd" * 8)
+    acct.begin_call(ctx)
+    acct.first_call(rec)  # where the call site takes it from the table
+    time.sleep(0.01)
+    built = acct.end_call(bucket=8, kernel=False)
+    assert built == [rec] and spans == []  # the span waits for the read
+    assert rec["call_a"] >= rec["b"]  # built before anyone called
+    assert rec["call_s"] >= 0.01 and rec["bucket"] == 8
+    acct.builds_ready(built, rec["call_a"] + 0.25)
+    assert rec["ready_s"] == pytest.approx(0.25) and "ctx" not in rec
+    ((trace, _span, parent, name, kind, a, b, attrs),) = spans
+    assert (trace, parent, name, kind) == (*ctx, "program.build", "engine")
+    assert (a, b) == (rec["a"], rec["b"])
+    assert attrs["ahead"] is True and attrs["ready_s"] == rec["ready_s"]
+    assert attrs["call_a"] == rec["call_a"]
+
+
+def test_a_first_call_outside_any_call_is_a_child_of_the_stage_it_is_in(
+        handed_over, monkeypatch):
+    from ray_tpu._private import tracing
+
+    acct, rec, spans = telemetry.ACCOUNT, handed_over["rec"], []
+    monkeypatch.setattr(tracing, "_ON", True)
+    monkeypatch.setattr(tracing, "record_span", lambda *a: spans.append(a))
+    monkeypatch.setattr(acct, "write", lambda: None)
+    with telemetry.setup_stage("engine.programs"):
+        acct.first_call(rec)  # as `_count_boundary_copies` takes its program
+    assert rec["stage"] == "engine.programs" and "call_a" not in rec
+    build = next(s for s in spans if s[3] == "program.build")
+    stage = next(s for s in spans if s[3] == "engine.programs")
+    assert build[2] == stage[1] and build[0] == stage[0]
+
+
 class _Stats:
     path = "/v1/stats"
 
@@ -151,6 +257,11 @@ def test_v1_stats_keeps_its_keys_and_gains_setup():
     assert st["compile_count"] == setup["builds"] > 0
     assert st["compile_s"] == pytest.approx(sum(
         p["compile_s"] for p in setup["programs"].values()), abs=0.01)
+    # how the serving programs came to be (`llm/programs.py`): no list here
+    assert {k: setup[k] for k in (
+        "programs_ahead", "programs_waited", "programs_on_demand",
+        "list_unused")} == {"programs_ahead": 0, "programs_waited": 0,
+                            "programs_on_demand": 2, "list_unused": 0}
     json.dumps(st)  # what the proxy has to serialise
 
 
@@ -327,3 +438,47 @@ def test_tracing_on_a_build_is_a_child_of_what_caused_it(tmp_path):
         "engine.programs", "engine.cache_alloc"}
     assert [b["fun_name"] for b in late["builds"]].count("jit_prefill") == 2
     assert all("ctx" not in b for b in late["builds"])
+
+
+def test_a_traced_start_from_a_list_accounts_for_every_first_call(tmp_path):
+    """A second start on the same cache directory finds the first's list and
+    builds AHEAD (`llm/programs.py`): a build is still one record, those that
+    ended before anyone asked say `ahead`, and the first call of each
+    serving program still carries `call_a` / `call_s` / `ready_s`, so
+    `setup_first_run_s` goes on reading the first calls' waits."""
+    env = {"RT_TRACING": "1",
+           "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "cache"),
+           "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0",
+           "JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES": "0"}
+    runs = []
+    for name in ("first", "second"):
+        session = tmp_path / name
+        session.mkdir()
+        runs.append(run_py(SERVED, {**env, "RT_SESSION_DIR": str(session)}))
+    (lists,) = os.listdir(tmp_path / "cache" / "programs")
+    serving = ("jit_prefill", "jit_place", "jit_sample1", "jit_chunk")
+    for got, ahead in zip(runs, (False, True)):
+        builds = [b for b in got["late"]["builds"]
+                  if b["fun_name"] in serving]
+        assert len(got["late"]["builds"]) == got["count"]
+        assert any(b.get("ahead") for b in builds) is ahead
+        for b in builds:
+            assert b["trace_s"] > 0 and b["lower_s"] > 0 and b["compile_s"] > 0
+            # every one was called (the probe and the longest sampled chunk
+            # inside `engine.programs`, the others by a request)
+            assert "call_a" in b or b["stage"] == "engine.programs", b
+            # (a hand-over's result is never read, nor that of a chunk whose
+            # occupants had all ended when its block came: no `ready_s`)
+            if b["fun_name"] in ("jit_prefill", "jit_sample1"):
+                assert b["ready_s"] >= b["call_s"] > 0
+            assert b.get("ready_s", b.get("call_s", 1)) > 0
+            if b.get("ahead"):  # (its call may have BEGUN before it ended:
+                assert b["cache"] == "hit"  # the site asks inside the call)
+        spans = [s for s in got["spans"] if s["n"] == "program.build"
+                 and s["at"]["fun_name"] in serving]
+        assert len(spans) == len(builds)
+        assert sum(bool(s["at"].get("ahead")) for s in spans) == sum(
+            bool(b.get("ahead")) for b in builds)
+    names = [[b["fun_name"] for b in got["late"]["builds"]
+              if b["fun_name"] in serving] for got in runs]
+    assert sorted(names[0]) == sorted(names[1])  # the same programs, once each

@@ -67,7 +67,7 @@ def test_v5e_chunk_program_takes_and_returns_the_cache_without_a_copy(chip):
     # a prefill's slice (its bucket's rows) arrives at the same width, so
     # placing it is a plain update of a slot's first rows
     cache = eng._cache_spec
-    one = jax.eval_shape(eng._prefill, eng.params,
+    one = jax.eval_shape(eng._prefill.jitted, eng.params,
                          jax.ShapeDtypeStruct((1, 64), jnp.int32), 5)[1]
     assert {leaf.shape for leaf in jax.tree.leaves(one)} == {
         (1, 64, CFG.n_heads, 128)}
@@ -134,13 +134,13 @@ def test_v5e_latent_cache_rows_are_widened_to_their_tiles(chip):
         f"Layout(major_to_minor=(0, 1, 2), tiling=(")
     assert st["cache_bytes"] == 2 * MAX_BATCH * LATENT.max_seq * 640 * 2
     assert (st["experts_held"], st["experts_published"]) == (4, 16)
-    one = jax.eval_shape(eng._prefill, eng.params,
+    one = jax.eval_shape(eng._prefill.jitted, eng.params,
                          jax.ShapeDtypeStruct((1, 64), jnp.int32), 5)[1]
     assert {leaf.shape for leaf in jax.tree.leaves(one)} == {(1, 64, 640)}
     # the chunk's token block carries the held experts' row counts: 4
     # counts in one more column of 8 slots
     block = jax.eval_shape(
-        eng._chunk, *eng._chunk_shapes(eng.params, eng._cache_spec, False))[2]
+        eng._chunk.jitted, *eng._chunk_shapes(eng.params, eng._cache_spec, False))[2]
     assert block.shape == (MAX_BATCH, 4 + 1)
 
 
@@ -192,7 +192,7 @@ def test_v5e_window_and_full_leaves_of_four_heads_cross_without_a_copy(chip):
     mem = prefill.memory_analysis()
     resident = mem.argument_size_in_bytes  # the parameters
     assert mem.temp_size_in_bytes + mem.output_size_in_bytes < 2.5e9
-    one = jax.eval_shape(eng._prefill, eng.params,
+    one = jax.eval_shape(eng._prefill.jitted, eng.params,
                          jax.ShapeDtypeStruct((1, 8192), jnp.int32), 5)[1]
     assert sorted({leaf.shape for leaf in jax.tree.leaves(one)}) == [
         (1, 2048, 4, 128), (1, 8192, 4, 128)]
@@ -304,7 +304,7 @@ def test_v5e_prefill_of_2048_rows_hands_on_a_state_and_place_replaces_it(
     mem = prefill.memory_analysis()
     assert mem.temp_size_in_bytes + mem.output_size_in_bytes < 1e9
     assert len(re.findall(r" while\(", prefill.as_text())) >= 3  # the scans
-    one = jax.eval_shape(eng._prefill, eng.params,
+    one = jax.eval_shape(eng._prefill.jitted, eng.params,
                          jax.ShapeDtypeStruct((1, 2048), jnp.int32), 5)[1]
     assert sorted({leaf.shape for leaf in jax.tree.leaves(one)}) == [
         (1, 3, 4096), (1, 3, 12288), (1, 32, 128, 128), (1, 2048, 640)]
@@ -342,7 +342,7 @@ def test_v5e_prefill_of_6144_rows_goes_in_its_neighbours_tiles(chip):
     assert "f32[1,4,8,512,2560]" in text and "f32[1,4,8,256,6400]" in text
     mem = prefill.memory_analysis()
     assert mem.temp_size_in_bytes + mem.output_size_in_bytes < 2.5e9
-    one = jax.eval_shape(eng._prefill, eng.params,
+    one = jax.eval_shape(eng._prefill.jitted, eng.params,
                          jax.ShapeDtypeStruct((1, 6144), jnp.int32), 5)[1]
     assert sorted({leaf.shape for leaf in jax.tree.leaves(one)}) == [
         (1, 2048, 4, 128), (1, 6144, 4, 128)]
@@ -811,10 +811,10 @@ def test_v5e_eva_chunk_program_walks_two_kinds_of_leaf_and_copies_no_rows(
     assert "tpu_custom_call" not in text
     assert not [m for m in moved_rows(eng, text) if m[0] == "copy"]
     # the block the tokens ride in: 16 steps and one column of counters
-    assert jax.eval_shape(eng._chunk, *shapes)[2].shape == (16, 16 + 1)
+    assert jax.eval_shape(eng._chunk.jitted, *shapes)[2].shape == (16, 16 + 1)
     # a prefill of 6144 rows hands on ONE window's rows and a summary row
     # for every 16 positions of its bucket
-    one = jax.eval_shape(eng._prefill, eng.params,
+    one = jax.eval_shape(eng._prefill.jitted, eng.params,
                          jax.ShapeDtypeStruct((1, 6144), jnp.int32), 5000)[1]
     assert sorted({leaf.shape for leaf in jax.tree.leaves(one)}) == [
         (1, 384, 32, 128), (1, 2048, 32, 128)]
@@ -1236,7 +1236,7 @@ def test_v5e_sdar_as_benchmarked_steps_a_block_a_slot_where_the_rows_lie(
                                  on_chip()).compile()
     assert len(re.findall(r" custom-call\(.*tpu_custom_call",
                           prefill.as_text())) == 6 - 1
-    slices = jax.eval_shape(eng._prefill, eng.params, on_chip(1, 1024), 5)
+    slices = jax.eval_shape(eng._prefill.jitted, eng.params, on_chip(1, 1024), 5)
     assert {leaf.shape for leaf in jax.tree.leaves(slices)} == {
         (1, 1024, 4, 128)}  # slices alone: no logits
     assert eng._slice_bytes(1024) == 12 * 1024 * 4 * 128 * 2

@@ -94,10 +94,9 @@ def programs(eng, chip):
     cache = placed(eng._cache_spec)
     for n in (1 << i for i in range(eng.decode_chunk.bit_length())):
         for greedy in (True, False):
-            args = list(eng._chunk_shapes(eng.params, cache, greedy))
-            args[8] = n
             yield (f"chunk {n} {'greedy' if greedy else 'sampled'}",
-                   eng._chunk.lower(*args))
+                   eng._chunk.lower(*eng._chunk_shapes(
+                       eng.params, cache, greedy, n)))
     b = eng.max_batch
     # (by blocks a row's "next token" is its whole state, a prefill hands on
     # its slices alone, and no first token is sampled: `llm/engine.py`)
@@ -109,11 +108,10 @@ def programs(eng, chip):
         toks = on_chip(i32, 1, bucket)
         yield (f"prefill {bucket} {eng._prefill_form(bucket)}",
                eng._prefill.lower(eng.params, toks, on_chip(i32)))
-        handed = jax.eval_shape(eng._prefill, eng.params, toks, 5)
-        handed = placed(handed if eng._blocks else handed[1])
         yield f"place {bucket}", eng._place.lower(
-            cache, handed, mirrors, on_chip(i32, *first), on_chip(u32, 2),
-            on_chip(i32, 3), on_chip(f32, 2))
+            cache, placed(eng._slice_shapes(bucket)), mirrors,
+            on_chip(i32, *first), on_chip(u32, 2), on_chip(i32, 3),
+            on_chip(f32, 2))
     if eng._blocks:
         return
     yield "sample1", eng._sample1.lower(
